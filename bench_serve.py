@@ -1212,12 +1212,8 @@ def _run_tp_ab(args):
     are genuinely partitioned.
     """
     import dataclasses as _dc
-    import glob as _glob
     import os
 
-    if not os.environ.get("JAX_PLATFORMS") and \
-            not _glob.glob("/dev/accel*") and not _glob.glob("/dev/vfio/*"):
-        os.environ["JAX_PLATFORMS"] = "cpu"
     if os.environ.get("JAX_PLATFORMS", "").startswith("cpu"):
         flags = os.environ.get("XLA_FLAGS", "")
         if "xla_force_host_platform_device_count" not in flags:
@@ -1319,8 +1315,9 @@ def _run_tp_ab(args):
     tp_res = {
         "label": "tp_shard_ab",
         "model": tp_cfg.model_id,
-        "env": ("cpu-tiny" if os.environ.get(
-            "JAX_PLATFORMS", "").startswith("cpu") else "tpu"),
+        # the platform the two engines ran on, as jax reports it
+        "env": ("cpu-tiny" if jax.devices()[0].platform == "cpu"
+                else jax.devices()[0].platform),
         "requests": len(prompts),
         "shared_prefix_tokens": len(shared),
         "greedy_identical": identical,
@@ -2359,8 +2356,14 @@ def main():
     ray_tpu.init(num_cpus=bench_cpus, _system_config=_ab_cfg)
     has_tpu = any(n.get("resources", {}).get("TPU", 0) > 0
                   for n in ray_tpu.nodes())
+    if not args.tiny and not has_tpu:
+        raise SystemExit(
+            "bench_serve.py: no TPU found on this node (no chip device "
+            "nodes, or JAX_PLATFORMS pins jax to the cpu); pass --tiny for "
+            "the cpu-tiny control-flow run — there is no silent CPU "
+            "substitute for the chip configuration")
 
-    if args.tiny or not has_tpu:
+    if args.tiny:
         model_cfg = llama.llama_tiny(vocab_size=2048)
         # the shared-prefix point carries 1024-token prompts: size the
         # window and the page pool for 8 concurrent long requests plus
@@ -2398,6 +2401,20 @@ def main():
     serve.run(app, name="llm-bench", route_prefix="/v1")
     proxy = serve.start_http_proxy(port=0)
     base = f"http://127.0.0.1:{proxy.port}/v1/completions"
+
+    # label every row with the device the engine actually ran on (its own
+    # report through /v1/stats), not with what the node advertised
+    with urllib.request.urlopen(base.replace("/completions", "/stats"),
+                                timeout=600) as _r:
+        _dev = json.loads(_r.read())
+    if not args.tiny and (_dev.get("device_platform") != "tpu"
+                          or _dev.get("attn_interpret")):
+        raise SystemExit(
+            f"bench_serve.py: the engine reports platform="
+            f"{_dev.get('device_platform')!r} attn_interpret="
+            f"{_dev.get('attn_interpret')!r}; the chip configuration must "
+            f"run compiled on a TPU")
+    env_label = "cpu-tiny" if args.tiny else _dev["device_platform"]
 
     prompt = "the quick brown fox jumps over the lazy dog " * (
         max(1, args.prompt_tokens // 9))
@@ -2724,7 +2741,7 @@ def main():
             "label": "shared_prefix_1024",
             "prefix_tokens": len(prefix_text),
             "model": llm_cfg.model_id,
-            "env": "tpu" if (has_tpu and not args.tiny) else "cpu-tiny",
+            "env": env_label,
             "cache_on": on_row,
             "cache_off": off_row,
             "cache_hit_rate": on_row.get("cache_hit_rate"),
@@ -2746,7 +2763,7 @@ def main():
     if args.spec_ab:
         import dataclasses as _dc
 
-        if args.tiny or not has_tpu:
+        if args.tiny:
             spec_cfg = LLMConfig(
                 model_id="llama-tiny-d256",
                 model_config=llama.llama_tiny(
@@ -2826,7 +2843,7 @@ def main():
         spec_decode = {
             "label": "spec_repetitive_suffix",
             "model": spec_cfg.model_id,
-            "env": "tpu" if (has_tpu and not args.tiny) else "cpu-tiny",
+            "env": env_label,
             "draft_len": spec_cfg.spec_draft_len,
             "greedy_identical": identical,
             "spec_rounds": rounds,
@@ -2850,7 +2867,7 @@ def main():
         # tests/test_paged_kernels.py preflight, so the slow duplicate is
         # skipped and recorded as such.
         from ray_tpu.serve.llm import kv_cache as _kvc
-        if has_tpu and not args.tiny and _kvc.resolve_attention_backend(
+        if not args.tiny and _kvc.resolve_attention_backend(
                 "auto", spec_cfg.llama(), spec_cfg.page_size) == "pallas":
             g_row = spec_arm(True, attn="gather")
             p_row = spec_arm(True, attn="pallas")
@@ -3010,7 +3027,7 @@ def main():
         # interpret-mode equivalent ran in the tests/test_paged_kernels.py
         # preflight.
         from ray_tpu.serve.llm import kv_cache as _kvc
-        if has_tpu and not args.tiny and _kvc.resolve_attention_backend(
+        if not args.tiny and _kvc.resolve_attention_backend(
                 "auto", kvt_cfg.llama(), kvt_cfg.page_size) == "pallas":
             pal = kvt_pair("lossless", attn="pallas")
             pallas_leg = {
@@ -3033,7 +3050,7 @@ def main():
         kv_tier = {
             "label": "kv_tier_cross_replica",
             "model": kvt_cfg.model_id,
-            "env": "tpu" if (has_tpu and not args.tiny) else "cpu-tiny",
+            "env": env_label,
             "requests": len(kv_prompts),
             "shared_prefix_tokens": len(shared),
             "greedy_identical": identical,

@@ -5,22 +5,31 @@ where the reference's is (SURVEY.md §2.1): this package holds the C++
 shared-memory arena object store (plasma equivalent —
 /root/reference/src/ray/object_manager/plasma/) built as `librtpu_shm.so`.
 
-Build model: `ensure_built()` compiles the .so with g++ on first use (cached
-by source mtime under _native/build/); callers fall back to the pure-python
-store when no toolchain is available.
+Build model: `ensure_built()` compiles the .so with g++ on first use, cached
+under _native/build/ (git-ignored) in a file named by the hash of
+`shm_store.cc`'s content — a build directory that rode along from another
+tree, or outlived an edit, is never loaded for this source; callers fall
+back to the pure-python store when no toolchain is available.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _BUILD_DIR = os.path.join(_HERE, "build")
-_SO_PATH = os.path.join(_BUILD_DIR, "librtpu_shm.so")
 _SRC = os.path.join(_HERE, "shm_store.cc")
+
+
+def _so_path() -> str:
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    return os.path.join(_BUILD_DIR, f"librtpu_shm-{digest}.so")
+
 
 _lock = threading.Lock()
 _lib = None
@@ -31,18 +40,21 @@ def ensure_built():  # graftlint: disable=lock-discipline — the build lock's p
     """Compile the native library if needed; returns the .so path or None."""
     global _build_error
     with _lock:
-        if os.path.exists(_SO_PATH) and \
-                os.path.getmtime(_SO_PATH) >= os.path.getmtime(_SRC):
-            return _SO_PATH
+        so_path = _so_path()
+        if os.path.exists(so_path):
+            return so_path
         if _build_error is not None:
             return None
         os.makedirs(_BUILD_DIR, exist_ok=True)
+        # per-process temp name: concurrent first users (agent + workers)
+        # each build, and the atomic rename makes any one of them win
+        tmp = f"{so_path}.{os.getpid()}.tmp"
         cmd = ["g++", "-O2", "-shared", "-fPIC", "-std=c++17",
-               "-o", _SO_PATH + ".tmp", _SRC, "-lrt", "-pthread"]
+               "-o", tmp, _SRC, "-lrt", "-pthread"]
         try:
             subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-            os.replace(_SO_PATH + ".tmp", _SO_PATH)
-            return _SO_PATH
+            os.replace(tmp, so_path)
+            return so_path
         except (subprocess.CalledProcessError, FileNotFoundError,
                 subprocess.TimeoutExpired) as e:
             _build_error = getattr(e, "stderr", b"") or str(e)

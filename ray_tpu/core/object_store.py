@@ -794,8 +794,13 @@ def make_store(capacity_bytes: int, prefix: str = "rtpu"):
                 "pure-python store", e)
     if backend is None:
         backend = ShmStore(capacity_bytes, prefix)
+    store = backend
     if cfg.enable_object_spilling:
         spill_dir = os.path.join(cfg.spill_dir or "/tmp/ray_tpu_spill",
                                  prefix)
-        return SpillingStore(backend, spill_dir, capacity_bytes)
-    return backend
+        store = SpillingStore(backend, spill_dir, capacity_bytes)
+    # which implementation this node ended up with ("NativeShmStore" or the
+    # python "ShmStore"), so a run can report the switch instead of only
+    # logging it
+    store.backend_name = type(backend).__name__
+    return store

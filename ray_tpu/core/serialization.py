@@ -211,14 +211,18 @@ def _reduce_jax_array(arr):
 
 
 def _restore_jax_array(host, dtype, committed):
-    # Only device_put if this process has already initialized jax: TPU chips
-    # admit a single attached process (SURVEY.md §7 hard-part 7), so a worker
-    # that never touched jax must not grab the device as a side effect of a get.
+    # device_put only where it cannot TAKE a chip: TPU chips admit a single
+    # attached process (SURVEY.md §7 hard-part 7), and a process that has
+    # merely imported jax — a driver that must stay off the chip — would
+    # grab it on its first get() of an array. So: a backend this process
+    # already initialised, or a process pinned to the CPU platform.
     import sys
-    if "jax" in sys.modules:
-        try:
-            import jax
-            return jax.device_put(host)
-        except Exception:
-            return host
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return host
+    from jax._src import xla_bridge
+
+    from ray_tpu.core.cpu_env import pinned_to_cpu
+    if xla_bridge.backends_are_initialized() or pinned_to_cpu():
+        return jax.device_put(host)
     return host

@@ -263,7 +263,21 @@ def _attention(q, k, v, cfg: LlamaConfig, mesh, *, positions_offset=0):
         return ring_attention(q, k, v, mesh, causal=True)
     if cfg.attn_impl == "flash":
         from ray_tpu.ops.attention import flash_attention
-        return flash_attention(q, k, v, causal=True)
+        attn = functools.partial(flash_attention, causal=True)
+        if mesh is not None and mesh.size > 1:
+            # a compiled Pallas kernel is opaque to GSPMD ("Mosaic kernels
+            # cannot be automatically partitioned"): run it per shard.
+            # Attention is independent per sequence and per head, so batch
+            # splits over the data-parallel axes and heads over "tensor"
+            # with no collective.
+            from jax.sharding import PartitionSpec as P
+
+            from ray_tpu.parallel.sharding import batch_sharding
+            heads = "tensor" if mesh.shape.get("tensor", 1) > 1 else None
+            spec = P(batch_sharding(mesh).spec[0], None, heads, None)
+            attn = jax.shard_map(attn, mesh=mesh, in_specs=(spec,) * 3,
+                                 out_specs=spec, check_vma=False)
+        return attn(q, k, v)
     sm = cfg.head_dim ** -0.5
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) * sm
     t_q, t_k = q.shape[1], k.shape[1]

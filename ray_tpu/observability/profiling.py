@@ -254,12 +254,13 @@ class EngineProfiler:
         DEVICE_MEMORY.set(self.kv_pool_bytes, {"component": "kv_pool"})
 
     def memory_stats(self, used_pages: Optional[int] = None,
-                     total_pages: Optional[int] = None) -> dict:
+                     total_pages: Optional[int] = None,
+                     devices=None) -> dict:
         occ = None
         if used_pages is not None and total_pages:
             occ = round(used_pages / total_pages, 4)
             KV_OCCUPANCY.set(occ)
-        in_use, peak = device_memory_stats()
+        in_use, peak = device_memory_stats(devices)
         if in_use is not None:
             DEVICE_MEMORY.set(in_use, {"component": "in_use"})
         if peak is not None:
@@ -287,19 +288,21 @@ def tree_bytes(tree) -> int:
     return total
 
 
-def device_memory_stats() -> tuple[Optional[int], Optional[int]]:
-    """(bytes_in_use, peak_bytes_in_use) from the default device's
-    allocator, or (None, None) where the backend doesn't report (cpu)."""
-    try:
-        import jax
+def device_memory_stats(devices=None) -> tuple[Optional[int], Optional[int]]:
+    """(bytes_in_use, peak_bytes_in_use) of the FULLEST of ``devices``
+    (default: the process default device) — one chip's view, the one
+    closest to its HBM limit; a tensor-parallel replica passes every chip
+    of its mesh so device 0 is not read as the whole replica. (None, None)
+    where the allocator reports nothing (the cpu backend)."""
+    import jax
 
-        dev = jax.devices()[0]
-        stats = dev.memory_stats()
-    except Exception:  # noqa: BLE001 - stats are strictly best-effort
-        return None, None
+    if devices is None:
+        devices = jax.devices()[:1]
+    stats = [s for s in (d.memory_stats() for d in devices) if s]
     if not stats:
         return None, None
-    return (stats.get("bytes_in_use"), stats.get("peak_bytes_in_use"))
+    return (max(s.get("bytes_in_use", 0) for s in stats),
+            max(s.get("peak_bytes_in_use", 0) for s in stats))
 
 
 # ---------------------------------------------------------------------------

@@ -50,9 +50,30 @@ from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
 
 _NEG_INF = -1e30
-# jax renamed TPUCompilerParams -> CompilerParams across versions
-_COMPILER_PARAMS = getattr(pltpu, "CompilerParams",
-                           getattr(pltpu, "TPUCompilerParams", None))
+# one [rows, L] float32 score tile the kernel may hold; the mask, exp and
+# probability temporaries are each about this size again
+_SCORE_TILE_BYTES = 2 * 1024 * 1024
+_MAX_ROW_TILE = 256
+
+
+def interpret_default() -> bool:
+    """Whether these kernels run interpreted in this process: true
+    everywhere but on a TPU backend (the CPU tests' only way to run
+    them). The engine reports it as ``attn_interpret``."""
+    return jax.default_backend() != "tpu"
+
+
+def sublane_tile(dtype) -> int:
+    """Rows of one native TPU tile for ``dtype``: (8, 128) at 4 bytes,
+    (16, 128) at 2, (32, 128) at 1."""
+    return 32 // jnp.dtype(dtype).itemsize
+
+
+def can_tile(head_dim: int, page_size: int, dtype) -> bool:
+    """Shapes Mosaic compiles these kernels for: head_dim fills whole
+    128-lane vectors and a page is whole sublane tiles, so the page copy
+    into scratch and both matmuls stay tile-aligned."""
+    return head_dim % 128 == 0 and page_size % sublane_tile(dtype) == 0
 
 
 def tp_shard_specs(q_rank: int, n_replicated: int, axis: str = "tensor"):
@@ -74,41 +95,68 @@ def tp_shard_specs(q_rank: int, n_replicated: int, axis: str = "tensor"):
     return in_specs, q_spec
 
 
+def _row_tiling(r: int, max_len: int, dtype) -> tuple[int, int]:
+    """(padded row count, row tile). Rows pad to whole sublane tiles —
+    Mosaic rejects a 2-row bf16 matmul operand — and are processed in
+    tiles small enough that one [tile, L] float32 score block plus its
+    softmax temporaries fits scoped VMEM at any sequence limit."""
+    sub = sublane_tile(dtype)
+    cap = min(_MAX_ROW_TILE, _SCORE_TILE_BYTES // (4 * max_len))
+    cap = max(sub, cap // sub * sub)
+    r_sub = -(-r // sub) * sub
+    if r_sub <= cap:
+        return r_sub, r_sub
+    return -(-r // cap) * cap, cap
+
+
 def _paged_attn_kernel(pt_ref, base_ref, limit_ref,     # scalar prefetch
                        q_ref, k_ref, v_ref, o_ref, k_scr, v_scr, *,
                        sm_scale: float, page_size: int, num_pages: int,
-                       t_span: int):
+                       t_span: int, row_tile: int):
     """Grid (B, Hkv, num_pages); one (slot, kv-head) pair accumulates its
     pages into VMEM scratch and computes dense attention on the last page.
 
-    q_ref: [1, 1, R, D] where R = n_rep * t_span, row r = rep * t_span + t
+    q_ref: [1, 1, R, D] where R >= n_rep * t_span, row r = rep * t_span + t
     (GQA heads grouped per kv head, query positions innermost — matches
-    ``_gqa_expand``'s kv-major head order). k_ref/v_ref: this grid step's
+    ``_gqa_expand``'s kv-major head order); rows past n_rep * t_span are
+    zero padding the wrapper slices off. k_ref/v_ref: this grid step's
     pool page [1, 1, page, D], selected by the block index map through the
     scalar-prefetched page table — the read IS the gather.
     """
     b = pl.program_id(0)
     p = pl.program_id(2)
 
-    k_scr[pl.ds(p * page_size, page_size)] = k_ref[0, 0]
-    v_scr[pl.ds(p * page_size, page_size)] = v_ref[0, 0]
+    off = pl.multiple_of(p * page_size, page_size)
+    k_scr[pl.ds(off, page_size)] = k_ref[0, 0]
+    v_scr[pl.ds(off, page_size)] = v_ref[0, 0]
 
     @pl.when(p == num_pages - 1)
     def _compute():
-        q = q_ref[0, 0]                                       # [R, D]
-        # q.dtype contraction then fp32 scale — exactly the gather path's
-        # einsum(...).astype(f32) * sm
-        s = jax.lax.dot_general(
-            q, k_scr[:], (((1,), (1,)), ((), ())))            # [R, L]
-        s = s.astype(jnp.float32) * sm_scale
-        col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        t = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) % t_span
-        pos = base_ref[b] + t
-        valid = (col <= pos) & (col < limit_ref[b])
-        s = jnp.where(valid, s, _NEG_INF)
-        w = jax.nn.softmax(s, axis=-1).astype(q.dtype)
-        o_ref[0, 0] = jax.lax.dot_general(
-            w, v_scr[:], (((1,), (0,)), ((), ())))            # [R, D]
+        base = base_ref[b]
+        limit = limit_ref[b]
+
+        def rows(i, carry):
+            r0 = pl.multiple_of(i * row_tile, row_tile)
+            q = q_ref[0, 0, pl.ds(r0, row_tile), :]           # [TR, D]
+            # fp32 MXU accumulation rounded to q.dtype, then the fp32
+            # scale — what the gather path's einsum(...).astype(f32) * sm
+            # computes (Mosaic only accepts a 32-bit accumulator)
+            s = jax.lax.dot_general(
+                q, k_scr[:], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32).astype(q.dtype)
+            s = s.astype(jnp.float32) * sm_scale              # [TR, L]
+            col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            row = r0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+            pos = base + row % t_span
+            valid = (col <= pos) & (col < limit)
+            s = jnp.where(valid, s, _NEG_INF)
+            w = jax.nn.softmax(s, axis=-1).astype(q.dtype)
+            o_ref[0, 0, pl.ds(r0, row_tile), :] = jax.lax.dot_general(
+                w, v_scr[:], (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32).astype(o_ref.dtype)
+            return carry
+
+        jax.lax.fori_loop(0, q_ref.shape[2] // row_tile, rows, None)
 
 
 def paged_attention(q, k_pages, v_pages, page_tables, base, limit=None, *,
@@ -133,26 +181,36 @@ def paged_attention(q, k_pages, v_pages, page_tables, base, limit=None, *,
     if sm_scale is None:
         sm_scale = d ** -0.5
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_default()
     if limit is None:
         limit = jnp.full((b,), max_len, jnp.int32)
     r = n_rep * t
+    r_pad, row_tile = _row_tiling(r, max_len, q.dtype)
     # [B, T, H, D] -> [B, Hkv, n_rep*T, D]: kv-major head split (matches
     # _gqa_expand), query positions innermost so the kernel recovers t as
     # row % t_span
     qg = q.reshape(b, t, hkv, n_rep, d).transpose(0, 2, 3, 1, 4).reshape(
         b, hkv, r, d)
+    if r_pad != r:
+        qg = jnp.pad(qg, ((0, 0), (0, 0), (0, r_pad - r), (0, 0)))
 
+    isz = jnp.dtype(q.dtype).itemsize
+    # scratch + double-buffered q/o/page blocks + the row tile's score,
+    # mask, exp and probability temporaries, with headroom: the default
+    # scoped limit (16 MiB on v5e) is below the chunked-prefill call's need
+    vmem = (2 * max_len * d * jnp.dtype(k_pages.dtype).itemsize
+            + 4 * r_pad * d * isz + 4 * page_size * d * isz
+            + 8 * row_tile * max_len * 4)
     kernel = functools.partial(
         _paged_attn_kernel, sm_scale=sm_scale, page_size=page_size,
-        num_pages=max_pages, t_span=t)
+        num_pages=max_pages, t_span=t, row_tile=row_tile)
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(b, hkv, max_pages),
             in_specs=[
-                pl.BlockSpec((1, 1, r, d),
+                pl.BlockSpec((1, 1, r_pad, d),
                              lambda bi, hi, pi, pt, bs, lim: (bi, hi, 0, 0)),
                 # the paged read: block index pt[bi, pi] picks the pool
                 # page straight off the scalar-prefetched table
@@ -164,20 +222,21 @@ def paged_attention(q, k_pages, v_pages, page_tables, base, limit=None, *,
                              (hi, pt[bi, pi], 0, 0)),
             ],
             out_specs=pl.BlockSpec(
-                (1, 1, r, d),
+                (1, 1, r_pad, d),
                 lambda bi, hi, pi, pt, bs, lim: (bi, hi, 0, 0)),
             scratch_shapes=[
                 pltpu.VMEM((max_len, d), k_pages.dtype),
                 pltpu.VMEM((max_len, d), v_pages.dtype),
             ]),
-        out_shape=jax.ShapeDtypeStruct((b, hkv, r, d), q.dtype),
-        compiler_params=_COMPILER_PARAMS(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        out_shape=jax.ShapeDtypeStruct((b, hkv, r_pad, d), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=max(32 * 1024 * 1024, 2 * vmem)),
         interpret=interpret,
     )(page_tables.astype(jnp.int32), base.astype(jnp.int32),
       limit.astype(jnp.int32), qg, k_pages, v_pages)
-    return out.reshape(b, hkv, n_rep, t, d).transpose(0, 3, 1, 2, 4).reshape(
-        b, t, h, d)
+    return out[:, :, :r].reshape(b, hkv, n_rep, t, d).transpose(
+        0, 3, 1, 2, 4).reshape(b, t, h, d)
 
 
 def paged_decode_attention(q, k_pages, v_pages, page_tables, pos, *,

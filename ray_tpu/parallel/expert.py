@@ -14,8 +14,7 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-
-from ray_tpu.parallel.sharding import shard_map_compat as shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -89,7 +88,7 @@ def moe_layer(x, gate_w, expert_fn: Callable, expert_params, mesh: Mesh, *,
         param_specs = jax.tree.map(lambda _: P(axis_name), expert_params)
         expert_out = shard_map(
             sharded, mesh=mesh, in_specs=(P(), param_specs), out_specs=P(),
-            check=False)(expert_in, expert_params)
+            check_vma=False)(expert_in, expert_params)
     else:
         expert_out = jax.vmap(expert_fn)(expert_params, expert_in)
 
@@ -136,4 +135,4 @@ def moe_layer_tokens_sharded(x, gate_w, expert_fn: Callable, expert_params,
     return shard_map(
         sharded, mesh=mesh,
         in_specs=(P(axis_name), P(), param_specs), out_specs=P(axis_name),
-        check=False)(x, gate_w, expert_params)
+        check_vma=False)(x, gate_w, expert_params)

@@ -21,7 +21,6 @@ import math
 from dataclasses import dataclass, field
 
 import jax
-import numpy as np
 from jax.experimental import mesh_utils
 from jax.sharding import Mesh
 
@@ -103,10 +102,7 @@ def build_mesh(spec: MeshSpec, devices=None) -> Mesh:
         dev_array = mesh_utils.create_hybrid_device_mesh(
             ici_shape, dcn_shape, devices=devices)
     else:
-        try:
-            dev_array = mesh_utils.create_device_mesh(shape, devices=devices)
-        except Exception:
-            dev_array = np.asarray(devices).reshape(shape)
+        dev_array = mesh_utils.create_device_mesh(shape, devices=devices)
     return Mesh(dev_array, names)
 
 

@@ -18,8 +18,7 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-
-from ray_tpu.parallel.sharding import shard_map_compat as shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -83,5 +82,5 @@ def pipeline_apply(stage_fn: Callable, stage_params, x, mesh: Mesh, *,
     param_specs = jax.tree.map(lambda _: P(axis_name), stage_params)
     out = shard_map(
         sharded, mesh=mesh, in_specs=(param_specs, P()), out_specs=P(),
-        check=False)(stage_params, xs)
+        check_vma=False)(stage_params, xs)
     return out.reshape(batch, *x.shape[1:])

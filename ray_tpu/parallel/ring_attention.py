@@ -20,8 +20,7 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-
-from ray_tpu.parallel.sharding import shard_map_compat as shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
@@ -109,7 +108,7 @@ def ring_attention(q, k, v, mesh: Mesh, *, axis_name: str = "context",
                            causal=causal, sm_scale=sm_scale, block_fn=block_fn)
     return shard_map(
         fn, mesh=mesh, in_specs=(seq_spec, seq_spec, seq_spec),
-        out_specs=seq_spec, check=False)(q, k, v)
+        out_specs=seq_spec, check_vma=False)(q, k, v)
 
 
 def ulysses_attention(q, k, v, mesh: Mesh, *, axis_name: str = "context",
@@ -149,4 +148,4 @@ def ulysses_attention(q, k, v, mesh: Mesh, *, axis_name: str = "context",
 
     seq_spec = P(None, axis_name, None, None)
     return shard_map(inner, mesh=mesh, in_specs=(seq_spec,) * 3,
-                         out_specs=seq_spec, check=False)(q, k, v)
+                         out_specs=seq_spec, check_vma=False)(q, k, v)

@@ -24,23 +24,6 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:  # shard_map moved out of jax.experimental in newer releases
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _SHARD_MAP_CHECK_KW = "check_rep"
-except ImportError:  # pragma: no cover - newer jax
-    from jax import shard_map as _shard_map
-    _SHARD_MAP_CHECK_KW = "check_vma"
-
-
-def shard_map_compat(fn, *, mesh, in_specs, out_specs, check=False):
-    """``shard_map`` across jax versions: the replication-check kwarg was
-    renamed ``check_rep`` → ``check_vma`` when shard_map left
-    jax.experimental. Callers pass ``check=``; we translate to whatever
-    this jax spells it."""
-    return _shard_map(fn, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs,
-                      **{_SHARD_MAP_CHECK_KW: check})
-
 # default logical-axis rule table (megatron-style TP + fsdp weight sharding)
 DEFAULT_RULES: tuple[tuple[str, str | None], ...] = (
     ("batch", "data"),

@@ -195,8 +195,12 @@ def run(app: Application, *, name: str = "default",
             "route_prefix": route_prefix if node is ingress else None,
             "is_ingress": node is ingress,
         })
-    ok = ray_tpu.get(controller.deploy_application.remote(name, specs),
-                     timeout=120.0)
+    # the controller waits for the slowest deployment's replicas (see
+    # deploy_application); allow that plus its own bookkeeping
+    ok = ray_tpu.get(
+        controller.deploy_application.remote(name, specs),
+        timeout=60.0 + max([60.0] + [
+            sp["config"].health_check_timeout_s for sp in specs]))
     if not ok:
         raise RuntimeError(f"application {name!r} failed to deploy")
     _reset_routers()

@@ -207,8 +207,13 @@ class ServeController:
         for key in [k for k in self._deployments
                     if k.startswith(app_name + "#") and k not in new_names]:
             await self._drain_deployment(self._deployments.pop(key))
-        # wait until all deployments have their target replicas up
-        deadline = time.monotonic() + 60.0
+        # wait until all deployments have their target replicas up — at
+        # least a minute, and as long as the slowest deployment says its
+        # replicas may go without answering a health check (an LLM engine
+        # compiles its programs for minutes inside its constructor)
+        deadline = time.monotonic() + max(
+            [60.0] + [d["config"].health_check_timeout_s
+                      for d in deployments])
         while time.monotonic() < deadline:
             if all(len(s.replicas) >= s.target
                    for s in self._deployments.values()
@@ -438,6 +443,8 @@ class ServeController:
                         "attention_backend", "attn_backend_pallas",
                         "attn_kernel_compiles", "attn_decode_dispatches",
                         "attn_verify_dispatches", "attn_chunk_dispatches",
+                        "device_platform", "device_kind", "device_count",
+                        "attn_interpret",
                         "tp_degree", "mesh_shape", "kv_shard_pool_bytes",
                         "kv_shard_page_occupancy",
                         "itl_s", "compile_events", "mid_traffic_compiles",

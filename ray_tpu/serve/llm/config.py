@@ -60,9 +60,9 @@ class LLMConfig:
     # never exchange incompatible pages.
     tp_degree: int = 1
     # decode steps fused into one dispatched program when the batch is
-    # steady (multi-step decode): token cost ~ dispatch_RTT/decode_block,
-    # which matters enormously when the chip sits behind a network tunnel.
-    # Streaming granularity and stop-token lag grow with it.
+    # steady (multi-step decode): per-token dispatch cost is one
+    # dispatch/decode_block. Streaming granularity and stop-token lag grow
+    # with it.
     decode_block: int = 8
     # decode block while requests queue for slots (slot-starved): smaller
     # blocks detect stop tokens (and free slots for the queue) sooner, at

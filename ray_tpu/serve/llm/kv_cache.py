@@ -19,8 +19,9 @@ Design choices:
   numerics bit-exactly; ``"gather"`` materializes the full per-slot view
   + dense softmax (measured 84 ms/step vs a paged kernel's 25 ms for a
   1.2B model at B=32). Auto resolution picks pallas on TPU (when the
-  kernel's tiling accepts the shapes) and gather elsewhere; tests force
-  the pallas backend in interpreter mode on CPU;
+  kernel's tiling accepts the shapes) and gather elsewhere; an explicit
+  "pallas" the kernel cannot tile raises; tests force the pallas backend
+  in interpreter mode on CPU;
 - writes are scatters at (page, offset) index pairs; inactive slots write to
   a reserved trash page (page 0), keeping the step free of dynamic shapes
   and `lax.cond`s;
@@ -433,14 +434,16 @@ def _write_token_kv(k_cache, v_cache, k_new, v_new, page_idx, offset):
 
 def _use_pallas_decode(cfg=None, page_size: int = 0) -> bool:
     """Kernel path gate: TPU backend + shapes the Pallas paged-attention
-    kernels' tiling accepts (head_dim a multiple of 128, page a multiple of
-    8). Tiny test models (head_dim 16-64) fall back to the gather path on
-    real TPUs; in interpreter mode (CPU) every shape runs."""
+    kernels tile (``paged_attention.can_tile``). Tiny test models
+    (head_dim 16-64) take the gather path on real TPUs; in interpreter
+    mode (CPU) every shape runs."""
     if jax.default_backend() != "tpu":
         return False
     if cfg is None:
         return True
-    return cfg.head_dim % 128 == 0 and page_size % 8 == 0
+    from ray_tpu.ops.paged_attention import can_tile
+    return can_tile(cfg.head_dim, page_size,
+                    getattr(cfg, "dtype", jnp.bfloat16))
 
 
 def resolve_attention_backend(choice, cfg=None, page_size: int = 0) -> str:
@@ -450,9 +453,9 @@ def resolve_attention_backend(choice, cfg=None, page_size: int = 0) -> str:
     accepts the model's shapes and ``"gather"`` everywhere else (the
     interpreter-mode kernels are a correctness vehicle, not a CPU win).
     An explicit ``"pallas"`` is honored off-TPU (interpret mode — how
-    tests gate the kernels on CPU) but degrades to ``"gather"`` on a TPU
-    whose shapes the kernel can't tile, with a warning — serving a model
-    beats serving an error."""
+    tests gate the kernels on CPU) and raises on a TPU whose shapes the
+    kernel can't tile: whoever named the kernel must not be served by
+    another path under its name."""
     if choice in (None, "", "auto"):
         return "pallas" if _use_pallas_decode(cfg, page_size) else "gather"
     if choice not in ("gather", "pallas"):
@@ -461,12 +464,11 @@ def resolve_attention_backend(choice, cfg=None, page_size: int = 0) -> str:
             f"got {choice!r}")
     if choice == "pallas" and jax.default_backend() == "tpu" \
             and not _use_pallas_decode(cfg, page_size):
-        logger.warning(
-            "attention_kernel='pallas' requested but head_dim=%s/"
-            "page_size=%s don't satisfy the kernel tiling; falling back "
-            "to the gather backend", getattr(cfg, "head_dim", "?"),
-            page_size)
-        return "gather"
+        raise ValueError(
+            f"attention_kernel='pallas' cannot tile head_dim="
+            f"{getattr(cfg, 'head_dim', '?')} / page_size={page_size} on "
+            f"TPU (needs head_dim % 128 == 0 and whole sublane tiles per "
+            f"page); use 'auto' or 'gather'")
     return choice
 
 
@@ -483,11 +485,10 @@ def _tp_pallas(fn, mesh, in_specs, out_specs):
     """Wrap a Pallas paged-attention call for a TP mesh: GSPMD cannot
     partition an opaque pallas_call, so the kernel family runs under
     ``shard_map`` with the pool split per-KV-head and q split into the
-    matching kv-head groups. check=False: the kernel writes nothing
+    matching kv-head groups. check_vma=False: the kernel writes nothing
     replicated, and rep inference can't see through pallas anyway."""
-    from ray_tpu.parallel.sharding import shard_map_compat
-    return shard_map_compat(fn, mesh=mesh, in_specs=in_specs,
-                            out_specs=out_specs, check=False)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def _decode_attention(q, k_cache, v_cache, page_tables, pos, cfg, page_size,

@@ -56,6 +56,10 @@ _EXPORTED_STATS = (
     "attention_backend", "attn_backend_pallas", "attn_kernel_compiles",
     "attn_decode_dispatches", "attn_verify_dispatches",
     "attn_chunk_dispatches",
+    # the device the engine ran on, as jax reports it (strings one-hot
+    # like attention_backend), and whether the pallas kernels are being
+    # interpreted rather than compiled
+    "device_platform", "device_kind", "device_count", "attn_interpret",
     # tensor parallelism (ISSUE 20): sharding degree + mesh shape (string
     # — one-hot export like attention_backend) and one chip's slice of
     # the KV pool in bytes (page counts elsewhere stay whole-replica)
@@ -441,6 +445,11 @@ class LLMServer:
         return self.engine.prefetch_hint(digests)
 
     def check_health(self) -> bool:
+        """Raises (the replica's unhealthy signal) once the engine loop
+        has died: its in-flight requests were failed and it can serve no
+        more, so the controller must replace the replica."""
+        if self.engine.loop_error is not None:
+            raise RuntimeError(self.engine.loop_error)
         # periodic health checks double as the metrics heartbeat: every
         # probe refreshes this replica's engine gauges on the CP
         _export_engine_stats(self.cfg.model_id, self.engine.engine_stats())

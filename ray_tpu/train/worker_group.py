@@ -225,9 +225,10 @@ class WorkerGroup:
 
 
 def _actor_resource_opts(per: dict) -> dict:
-    opts = {}
-    if "CPU" in per:
-        opts["num_cpus"] = per["CPU"]
+    # the rank actor draws EXACTLY its bundle: left unset, num_cpus defaults
+    # to 1 and a {"TPU": n} bundle (ScalingConfig(use_tpu=True)) can never
+    # grant the lease — the gang would wait forever
+    opts = {"num_cpus": per.get("CPU", 0)}
     if "TPU" in per:
         opts["num_tpus"] = per["TPU"]
     rest = {k: v for k, v in per.items() if k not in ("CPU", "TPU")}

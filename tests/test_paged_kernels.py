@@ -15,8 +15,8 @@ Pins the PR's acceptance invariants:
   compiles under pallas;
 - ``resolve_attention_backend`` picks gather off-TPU on auto, honors an
   explicit pallas (interpret mode — this file's whole execution story on
-  CPU), degrades pallas to gather on TPU-unfriendly shapes, and rejects
-  unknown names;
+  CPU), raises for an explicit pallas on TPU-unfriendly shapes, and
+  rejects unknown names;
 - the backend and its dispatch/compile counters are exported through
   ``engine_stats()`` -> llm_server ``_EXPORTED_STATS`` -> controller
   ``_ENGINE_KEYS``.
@@ -203,7 +203,8 @@ def test_resolve_unknown_raises():
 
 def test_resolve_on_tpu_shape_gate(monkeypatch):
     """On TPU, auto picks pallas only when the kernel tiling fits; an
-    explicit pallas on unfriendly shapes degrades to gather (warned)."""
+    explicit pallas on shapes the kernel cannot tile raises — it is never
+    served by gather under the kernel's name."""
     import types
 
     monkeypatch.setattr(kv_cache.jax, "default_backend", lambda: "tpu")
@@ -211,8 +212,10 @@ def test_resolve_on_tpu_shape_gate(monkeypatch):
     tiny = types.SimpleNamespace(head_dim=16)
     assert kv_cache.resolve_attention_backend("auto", good, 16) == "pallas"
     assert kv_cache.resolve_attention_backend("auto", tiny, 16) == "gather"
-    assert kv_cache.resolve_attention_backend("pallas", tiny, 16) \
-        == "gather"
+    with pytest.raises(ValueError, match="cannot tile"):
+        kv_cache.resolve_attention_backend("pallas", tiny, 16)
+    with pytest.raises(ValueError, match="cannot tile"):
+        kv_cache.resolve_attention_backend("pallas", good, 7)
     assert kv_cache.resolve_attention_backend("pallas", good, 16) \
         == "pallas"
     assert kv_cache.resolve_attention_backend("auto", good, 7) == "gather"

@@ -437,30 +437,38 @@ def test_bucket_width_padding():
     assert small._bucket_width(3) == 3
 
 
-def test_engine_serves_without_is_ready_api():
-    """Satellite regression: on jax builds without Array.is_ready() the
-    engine must fall back to a BOUNDED harvest (pop the oldest block while
-    a newer one is in flight), not silently disable eager harvest — and
-    outputs stay identical."""
-    from ray_tpu.serve.llm import LLMEngine
+def test_engine_loop_exception_fails_stranded_requests_and_health():
+    """The loop thread has no other handler: an exception out of an
+    iteration must fail the requests it strands (not leave them to their
+    timeouts), refuse new ones, reach the log, and flip the replica's
+    health check."""
+    import time
 
-    want_eng = LLMEngine(_tiny_cfg(max_tokens=16), rng_seed=0)
-    want_eng.start()
-    try:
-        want = want_eng.generate("fallback probe", max_tokens=16,
-                                 temperature=0.0)["tokens"]
-    finally:
-        want_eng.shutdown()
+    from ray_tpu.serve.llm import LLMEngine
+    from ray_tpu.serve.llm.llm_server import LLMServer
 
     eng = LLMEngine(_tiny_cfg(max_tokens=16), rng_seed=0)
-    eng._is_ready_supported = False  # simulate the probe failing
-    assert eng._ready(object()) is False  # never touches the array
+    srv = LLMServer.__new__(LLMServer)      # the server around THIS engine
+    srv.cfg, srv.engine = eng.cfg, eng
     eng.start()
     try:
-        rids = [eng.submit("fallback probe", max_tokens=16,
-                           temperature=0.0) for _ in range(3)]
-        outs = [eng.result(r, timeout=120.0) for r in rids]
-        assert all(o["error"] is None for o in outs)
-        assert all(o["tokens"] == want for o in outs)
+        assert eng.generate("healthy", max_tokens=4)["error"] is None
+        assert srv.check_health() is True
+
+        def boom():
+            raise RuntimeError("injected device fault")
+
+        eng._decode_step = boom
+        rid = eng.submit("stranded", max_tokens=16)
+        t0 = time.monotonic()
+        out = eng.result(rid, timeout=60.0)
+        assert time.monotonic() - t0 < 30.0     # failed, not timed out
+        assert "engine loop failed" in out["error"]
+        assert "injected device fault" in out["error"]
+        assert "injected device fault" in eng.loop_error
+        with pytest.raises(RuntimeError, match="engine loop failed"):
+            eng.submit("after the fault")
+        with pytest.raises(RuntimeError, match="engine loop failed"):
+            srv.check_health()
     finally:
         eng.shutdown()

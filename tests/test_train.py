@@ -245,6 +245,29 @@ def test_jax_trainer_cpu_spmd(ray_start_regular, tmp_path):
     assert result.metrics["loss"] < 1.0
 
 
+def test_jax_trainer_tpu_bundle_places_its_worker(tmp_path):
+    """ScalingConfig(use_tpu=True, resources_per_worker={"TPU": n}) — the
+    normal TPU entry — reserves a {"TPU": n} bundle; the rank actor must
+    ask for exactly that (no default CPU on top), or its lease never fits
+    the bundle and fit() waits forever. The TPU here is a declared
+    resource on a CPU node: placement is what is under test."""
+    ray_tpu.shutdown()
+    ray_tpu.init(num_cpus=4, resources={"TPU": 1})
+    try:
+        def train_fn():
+            rt_train.report({"node": ray_tpu.get_runtime_context().node_id})
+
+        result = JaxTrainer(
+            train_fn,
+            scaling_config=ScalingConfig(use_tpu=True,
+                                         resources_per_worker={"TPU": 1}),
+            run_config=_run_cfg(tmp_path)).fit()
+        assert result.error is None
+        assert result.metrics["node"]
+    finally:
+        ray_tpu.shutdown()
+
+
 def test_worker_group_execute(ray_start_regular):
     from ray_tpu.train.worker_group import WorkerGroup
 
