@@ -18,8 +18,8 @@ full width of ``llama.llama3_1b()`` (dim 2048, 16 layers, 16/8 heads of
 
 This process never initialises a JAX backend: one process holds the chip at
 a time, and each holder is gone before the next starts. Any phase failing
-ends the run with a non-zero exit code, no result line, and the tail of the
-chip-holding worker's stderr. Without a TPU (``JAX_PLATFORMS=cpu``, or no
+ends the run with a non-zero exit code, no ``"ok": true`` line, and the tail
+of the chip-holding worker's stderr. Without a TPU (``JAX_PLATFORMS=cpu``, or no
 chip device nodes) it exits non-zero at once: there is no CPU mode.
 ``--cpu-rehearsal`` is a debugging aid that walks the same control flow at
 toy sizes on the CPU and says ``"platform": "cpu"``; it is never what the
@@ -28,9 +28,14 @@ default does and its numbers are not device numbers.
     python chip_smoke.py              # one chip
     python chip_smoke.py --chips 4    # TP=4 serving, fsdp=4 training
 
-The last line of standard output is one JSON object beginning
-``{"ok": true, "device": {"platform": "tpu", "kind": ..., "count": ...}``
-and ending ``"claim": null``.
+Standard output ends with two JSON lines. The second to last is the report
+(``{"report": {...versions, per-phase compile/run times, kernel errors,
+mid_traffic_compiles, object store..., "claim": null}}``). The LAST line is
+the result and holds exactly
+``{"ok": true, "device": {"platform": "tpu", "kind": ..., "count": ...}}``
+with the device as jax reported it in the chip-holding processes. A failed
+phase on a found device ends with ``{"ok": false, "device": {...}}`` instead
+and a non-zero exit code.
 """
 
 from __future__ import annotations
@@ -709,6 +714,8 @@ def main() -> int:
     args = ap.parse_args()
 
     try:
+        if not os.path.isdir(os.path.join(HERE, "ray_tpu")):
+            raise ImportError("no ray_tpu/ beside chip_smoke.py")
         import ray_tpu  # noqa: F401
     except ImportError:
         print("chip_smoke.py: the ray_tpu package is not beside this "
@@ -745,6 +752,7 @@ def main() -> int:
     t0 = time.perf_counter()
     sz = sizes(rehearsal, args.chips)
     tmp = tempfile.mkdtemp(prefix="chip_smoke_")
+    device = None                # as jax reports it in the kernels child
     try:
         kern = within(400, "kernels", run_kernels, rehearsal, args.chips,
                       tmp)
@@ -779,14 +787,15 @@ def main() -> int:
             traceback.print_exc()
         print_worker_stderr(tmp, started)
         _kill_descendants()
+        if device is not None and (rehearsal or device["platform"] == "tpu"):
+            print_result(False, device)
         return 1
     finally:
         watchdog.cancel()
 
-    print(json.dumps({
-        "ok": True,
-        "device": device,
+    print(json.dumps({"report": {
         "chips": args.chips,
+        "device": device,
         "jax": kern["jax"], "jaxlib": kern["jaxlib"],
         "libtpu": kern["libtpu"],
         "wall_s": round(time.perf_counter() - t0, 1),
@@ -806,8 +815,18 @@ def main() -> int:
         "mid_traffic_compiles": serve_res["stats"]["mid_traffic_compiles"],
         "object_store": serve_res["store"],
         "claim": None,
-    }))
+    }}))
+    print_result(True, device)
     return 0
+
+
+def print_result(ok: bool, device: dict) -> None:
+    """The last line of standard output: exactly ``ok`` and ``device``, the
+    device exactly ``platform``, ``kind``, ``count``."""
+    sys.stderr.flush()
+    print(json.dumps({"ok": bool(ok), "device": {
+        "platform": str(device["platform"]), "kind": str(device["kind"]),
+        "count": int(device["count"])}}), flush=True)
 
 
 if __name__ == "__main__":

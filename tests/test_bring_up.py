@@ -146,6 +146,23 @@ def test_chip_smoke_without_a_chip_exits_nonzero_at_once():
     assert len(proc.stderr.strip().splitlines()) == 1   # one sentence
 
 
+def test_chip_smoke_result_line_has_exactly_the_contract_keys(capsys):
+    """The checker reads the LAST stdout line and wants exactly ``ok`` and
+    ``device`` = ``platform``/``kind``/``count``; the detailed report is a
+    separate, earlier line."""
+    import importlib.util
+    import json
+    spec = importlib.util.spec_from_file_location(
+        "chip_smoke", os.path.join(REPO, "chip_smoke.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    mod.print_result(True, {"platform": "tpu", "kind": "TPU v5 lite",
+                            "count": 1, "extra": "dropped"})
+    last = capsys.readouterr().out.strip().splitlines()[-1]
+    assert json.loads(last) == {"ok": True, "device": {
+        "platform": "tpu", "kind": "TPU v5 lite", "count": 1}}
+
+
 # ---- flash attention under a multi-device mesh ---------------------------
 
 def test_flash_attention_runs_per_shard_on_a_mesh(jax_cpu_mesh):
