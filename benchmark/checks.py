@@ -1,0 +1,318 @@
+"""What decides ``correct``. Outputs only, and the same function of code
+and seed everywhere.
+
+1. logits_check: the engine's own paged programs (whole prefill, chunked
+   prefill, then decode through the cache) against the float32 reference,
+   at the published widths and a shallow depth, weights and tokens from the
+   seed. Max absolute logit error within the configuration's tolerance.
+2. served_tokens_check: tokens the replica served, teacher-forced through
+   the float32 reference at the served depth with the served weights.
+   Every served token's reference logit lies within the configuration's
+   margin of the reference maximum at its position. Never one greedy run
+   against another.
+3. structure_check: every completed stream delivered its max_tokens or
+   fewer (the engine swallows the stop token, so fewer means it stopped);
+   every token id inside the vocabulary; nothing NaN.
+(4. the device and the compiled kernel: a run without them exits non-zero;
+   see ``require_device``.)
+
+Run as a script this is the child that holds the chip after the replica
+has gone: ``python benchmark/checks.py <spec.json>`` prints one JSON line.
+"""
+
+from __future__ import annotations
+
+import functools
+import json
+import math
+import os
+import sys
+import time
+
+if __name__ == "__main__":
+    sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+from benchmark import common  # noqa: E402
+
+
+def llama_config(sz: dict, n_layers: int | None = None, **kw):
+    import jax.numpy as jnp
+
+    from ray_tpu.models import llama
+    dtype = {"bfloat16": jnp.bfloat16, "float32": jnp.float32}[sz["dtype"]]
+    return llama.LlamaConfig(
+        vocab_size=sz["vocab_size"], dim=sz["dim"],
+        n_layers=n_layers or sz["n_layers"], n_heads=sz["n_heads"],
+        n_kv_heads=sz["n_kv_heads"], ffn_dim=sz["ffn_dim"],
+        max_seq_len=sz["max_seq_len"], rope_theta=sz["rope_theta"],
+        norm_eps=sz.get("norm_eps", 1e-5), dtype=dtype, **kw)
+
+
+def require_device(chips: int, rehearsal: bool) -> dict:
+    """The device as jax reports it; SystemExit(3) unless it is ``chips``
+    TPUs (a rehearsal takes what there is and says so)."""
+    import jax
+    devs = jax.devices()
+    info = {"platform": devs[0].platform, "kind": devs[0].device_kind,
+            "count": len(devs)}
+    if not rehearsal and (info["platform"] != "tpu" or len(devs) < chips):
+        print(f"benchmark: jax found {info}, the cell needs {chips} TPU "
+              f"chip(s)", file=sys.stderr)
+        raise SystemExit(3)
+    return info
+
+
+# ---- 1. logits ---------------------------------------------------------------
+
+@functools.lru_cache(maxsize=8)
+def _paged_programs(cfg, page: int, backend: str):
+    """The engine's paged programs (kv_cache.py), jitted once per shape."""
+    import jax
+
+    from ray_tpu.serve.llm import kv_cache as kvc
+    return (
+        jax.jit(lambda p, kv, t, x, n: kvc.paged_prefill(
+            p, kv, t, x, n, cfg, page)),
+        jax.jit(lambda p, kv, t, x, s, n: kvc.paged_prefill_chunk(
+            p, kv, t, x, s, n, cfg, page, backend)),
+        jax.jit(lambda p, kv, t, sl, x: kvc.paged_decode_step(
+            p, kv, t, sl, x, cfg, page, backend)))
+
+
+def logits_check(sz: dict, engine: dict, spec: dict, seed: int,
+                 mutate=None, use_rope: bool = True) -> dict:
+    """``mutate(params) -> params`` and ``use_rope`` are the negative
+    controls' hooks (tests): the ENGINE side runs the mutated weights while
+    the reference keeps the originals; ``use_rope=False`` compares the
+    engine with a reference that leaves the rotary embedding out."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from benchmark.reference import llama_f32
+    from ray_tpu.models import llama
+    from ray_tpu.serve.llm import kv_cache as kvc
+
+    cfg = llama_config(sz, n_layers=spec["depth"])
+    page, cap = engine["page_size"], engine["max_prompt_len"]
+    chunk = engine["prefill_chunk"]
+    max_pages = -(-engine["max_seq_len"] // page)
+    backend = kvc.resolve_attention_backend(
+        engine.get("attention_kernel", "auto"), cfg, page)
+    key = common.fold_seed(seed)
+    k_w, k_t = jax.random.split(key)
+    params = llama.init_params(k_w, cfg)
+    served = mutate(params) if mutate else params
+    pa, pb, d = (spec["whole_prompt_tokens"], spec["chunked_prompt_tokens"],
+                 spec["decode_steps"])
+    if not (pa <= chunk < pb <= cap):
+        raise ValueError("logits check needs whole <= prefill_chunk < chunked")
+    toks = np.asarray(jax.random.randint(
+        k_t, (2, max(pa, pb) + d), 0, cfg.vocab_size), np.int32)
+    seq_a, seq_b = toks[0, :pa + d], toks[1, :pb + d]
+
+    n_pages = 2 * max_pages + 1
+    kv = kvc.init_paged_cache(cfg, n_pages, page)
+    tables = np.zeros((4, max_pages), np.int32)
+    tables[0] = 1 + np.arange(max_pages)
+    tables[1] = 1 + max_pages + np.arange(max_pages)
+
+    prefill, chunk_fn, decode = _paged_programs(cfg, page, backend)
+
+    def padded(seg, width):
+        out = np.zeros((1, width), np.int32)
+        out[0, :len(seg)] = seg
+        return jnp.asarray(out)
+
+    t0 = time.perf_counter()
+    got_a, got_b = [], []
+    lg, kv = prefill(served, kv, jnp.asarray(tables[0]),
+                     padded(seq_a[:pa], common.prefill_bucket(pa, cap)),
+                     jnp.int32(pa))
+    got_a.append(lg)
+    start = 0
+    while pb - start > chunk:
+        _, kv = chunk_fn(served, kv, jnp.asarray(tables[1]),
+                         padded(seq_b[start:start + chunk], chunk),
+                         jnp.int32(start), jnp.int32(pb))
+        start += chunk
+    lg, kv = chunk_fn(served, kv, jnp.asarray(tables[1]),
+                      padded(seq_b[start:pb],
+                             common.prefill_bucket(pb - start, cap)),
+                      jnp.int32(start), jnp.int32(pb))
+    got_b.append(lg)
+    lens = jnp.asarray([pa, pb, 0, 0], jnp.int32)
+    for i in range(d):
+        cur = jnp.asarray([seq_a[pa + i], seq_b[pb + i], 0, 0], jnp.int32)
+        lg, kv, lens = decode(served, kv, jnp.asarray(tables), lens, cur)
+        got_a.append(lg[0])
+        got_b.append(lg[1])
+    got_a, got_b = jnp.stack(got_a), jnp.stack(got_b)
+    jax.block_until_ready(got_b)
+    program_s = time.perf_counter() - t0
+
+    t0 = time.perf_counter()
+    ref = dict(theta=float(cfg.rope_theta), eps=float(cfg.norm_eps),
+               use_rope=use_rope)
+    want_a = llama_f32.logits_at(params, seq_a[None], np.arange(pa - 1, pa + d),
+                                 **ref)[0]
+    want_b = llama_f32.logits_at(params, seq_b[None], np.arange(pb - 1, pb + d),
+                                 **ref)[0]
+    errs = {}
+    for name, got, want in (("whole_prefill+decode", got_a, want_a),
+                            ("chunked_prefill+decode", got_b, want_b)):
+        diff = jnp.abs(got.astype(jnp.float32) - want)
+        errs[name] = {
+            "max_abs_err": float(jnp.max(diff)),
+            "prefill_max_abs_err": float(jnp.max(diff[0])),
+            "ref_max_abs": float(jnp.max(jnp.abs(want))),
+            "finite": bool(jnp.all(jnp.isfinite(got.astype(jnp.float32))))}
+    worst = max(e["max_abs_err"] for e in errs.values())
+    finite = all(e["finite"] for e in errs.values())
+    return {"ok": bool(finite and worst <= spec["tolerance"]),
+            "max_abs_err": worst, "tolerance": spec["tolerance"],
+            "depth": spec["depth"], "backend": backend, "errors": errs,
+            "program_s": program_s, "reference_s": time.perf_counter() - t0}
+
+
+# ---- 2. served tokens, teacher-forced ---------------------------------------------
+
+def served_tokens_check(params, samples: list[dict], margin: float, *,
+                        theta: float, eps: float, eos: int | None,
+                        width: int | None = None,
+                        out_width: int | None = None) -> dict:
+    """samples: [{"prompt_ids", "tokens", "max_tokens"}]. For each served
+    token at position t: reference max logit at t-1 minus the reference
+    logit of the served token. A stream that stopped short is checked on
+    the stop token it must have produced. ``width`` / ``out_width`` fix
+    the padded shapes (prompt + output, output + 1) so that every run of a
+    cell compiles the same reference programs."""
+    import numpy as np
+
+    from benchmark.reference import llama_f32
+
+    if not samples:
+        return {"ok": False, "reason": "no served sample to check"}
+    seqs, spans = [], []
+    for s in samples:
+        out = list(s["tokens"])
+        if len(out) < s["max_tokens"] and eos is not None:
+            out = out + [eos]          # swallowed by the engine, but produced
+        seqs.append(list(s["prompt_ids"]) + out)
+        spans.append((len(s["prompt_ids"]), len(out)))
+    out_width = max([out_width or 0] + [n for _, n in spans])
+    width = max([width or 0] + [len(q) for q in seqs])
+    width = -(-(width + out_width) // 64) * 64   # room for the padded slice
+    toks = np.zeros((len(seqs), width), np.int32)
+    for i, q in enumerate(seqs):
+        toks[i, :len(q)] = q
+    hidden = llama_f32.hidden(params, toks, theta=theta, eps=eps)
+    per_sample, worst, finite = [], 0.0, True
+    for i, (plen, n) in enumerate(spans):
+        if n == 0:
+            per_sample.append(0.0)
+            continue
+        served = np.zeros((out_width,), np.int32)
+        served[:n] = seqs[i][plen: plen + n]
+        deficit, ok = llama_f32.deficits(
+            hidden[i], plen - 1, served, n, params["final_norm"],
+            params["lm_head"], eps)
+        finite &= bool(ok)
+        per_sample.append(float(deficit))
+        worst = max(worst, per_sample[-1])
+    return {"ok": bool(finite and worst <= margin), "max_deficit": worst,
+            "margin": margin, "per_sample_max_deficit": per_sample,
+            "tokens_checked": sum(n for _, n in spans), "finite": finite}
+
+
+# ---- 3. structure ----------------------------------------------------------------
+
+def structure_check(records: list[dict], samples: list[dict],
+                    vocab_size: int) -> dict:
+    """records: the window's completed streams (client side: counts only —
+    the proxy keeps token ids to itself); samples: served requests with
+    their token ids."""
+    bad = []
+    early = 0
+    for r in records:
+        n, cap = r.get("completion_tokens", 0), r["max_tokens"]
+        if not 0 <= n <= cap:
+            bad.append(f"request {r['index']}: {n} tokens of {cap}")
+        early += int(n < cap)
+    for i, s in enumerate(samples):
+        toks = s["tokens"]
+        if len(toks) > s["max_tokens"]:
+            bad.append(f"sample {i}: {len(toks)} tokens of {s['max_tokens']}")
+        if any((not isinstance(t, int)) or t < 0 or t >= vocab_size
+               for t in toks):
+            bad.append(f"sample {i}: token outside the vocabulary")
+    return {"ok": not bad, "problems": bad[:5], "streams": len(records),
+            "stopped_early": early}
+
+
+def train_structure_check(losses: list[float], first_ref: float,
+                          tolerance: float, last_same_batch: float) -> dict:
+    """Every loss finite; the first step's loss equal to the reference's on
+    the same batch and initial parameters; the last loss on that same
+    batch (the data is a cycle) below the first."""
+    finite = all(isinstance(x, float) and math.isfinite(x) for x in losses)
+    err = abs(losses[0] - first_ref) if losses else float("inf")
+    falls = len(losses) >= 2 and last_same_batch < losses[0]
+    return {"ok": bool(finite and err <= tolerance and falls),
+            "finite": finite, "first_loss": losses[0] if losses else None,
+            "reference_first_loss": first_ref, "abs_err": err,
+            "tolerance": tolerance, "last_loss_same_batch": last_same_batch,
+            "falls": falls}
+
+
+# ---- the child ---------------------------------------------------------------------
+
+def serve_child(spec: dict) -> dict:
+    """Checks 1 and 2 of a serve cell in one process that holds the chip
+    (after the replica released it)."""
+    import jax
+
+    from ray_tpu.models import llama
+    from ray_tpu.ops import paged_attention as paged_ops
+
+    rehearsal = spec["rehearsal"]
+    device = require_device(spec["chips"], rehearsal)
+    sz, engine = spec["sizes"], spec["engine"]
+    out = {"device": device, "interpret": bool(paged_ops.interpret_default())}
+    t0 = time.perf_counter()
+    out["logits"] = logits_check(sz, engine, spec["checks"]["logits"],
+                                 spec["seed"])
+    if not rehearsal and (out["logits"]["backend"] != "pallas"
+                          or out["interpret"]):
+        print(f"benchmark: attention backend {out['logits']['backend']!r}, "
+              f"interpret={out['interpret']}: not the compiled kernel",
+              file=sys.stderr)
+        raise SystemExit(3)
+    out["logits_s"] = time.perf_counter() - t0
+    t0 = time.perf_counter()
+    cfg = llama_config(sz)
+    # the served weights: LLMEngine(cfg) -> init_params(PRNGKey(0), model)
+    params = jax.block_until_ready(
+        llama.init_params(jax.random.PRNGKey(0), cfg))
+    out["served_weights_s"] = time.perf_counter() - t0
+    t0 = time.perf_counter()
+    out["served_tokens"] = served_tokens_check(
+        params, spec["samples"], spec["checks"]["served_tokens"]["margin"],
+        theta=float(cfg.rope_theta), eps=float(cfg.norm_eps),
+        eos=common.BYTE_EOS, **spec.get("shape", {}))
+    out["served_tokens_s"] = time.perf_counter() - t0
+    return out
+
+
+def main(argv: list[str]) -> int:
+    from ray_tpu.core import compile_cache
+    compile_cache.configure()
+    with open(argv[1]) as f:
+        spec = json.load(f)
+    out = serve_child(spec)
+    print(json.dumps(out))
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main(sys.argv))

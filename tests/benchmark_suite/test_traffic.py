@@ -1,0 +1,186 @@
+"""Traffic generators: pure functions of (parameters, seed); lengths inside
+the file's clamps; the same work for every seed in another order; the open
+loop times from the due time and reports lateness."""
+
+import json
+import time
+
+import pytest
+
+from benchmark import common
+from benchmark.traffic import closed_loop, open_loop, train_batches
+
+CELLS = ["mistral7b-serve-chat", "mistral7b-serve-peak"]
+
+
+def _traffic(cell):
+    return common.load_json(common.bench_dir(), "workloads",
+                            f"{cell}.json")["traffic"]
+
+
+def _plan(cell, seed, seconds=51.0):
+    params = _traffic(cell)
+    return common.load_module("traffic", params["generator"]).plan(
+        params, seed, seconds)
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_plan_is_a_pure_function_of_parameters_and_seed(cell):
+    a, b = _plan(cell, 2**31 + 12345), _plan(cell, 2**31 + 12345)
+    assert json.dumps(a, sort_keys=True) == json.dumps(b, sort_keys=True)
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_two_seeds_differ_in_order_and_text_not_in_work(cell):
+    a, b = _plan(cell, 11)["requests"], _plan(cell, 2**31 + 7)["requests"]
+    assert [r["prompt"] for r in a] != [r["prompt"] for r in b]
+    assert [r["prompt_tokens"] for r in a] != [r["prompt_tokens"] for r in b]
+    for key in ("prompt_tokens", "max_tokens"):
+        assert sorted(r[key] for r in a) == sorted(r[key] for r in b)
+
+
+@pytest.mark.parametrize("cell", CELLS)
+@pytest.mark.parametrize("seed", [0, 7, 2**31 + 99])
+def test_lengths_stay_inside_the_clamps(cell, seed):
+    params = _traffic(cell)
+    for r in _plan(cell, seed)["requests"]:
+        p, o = params["prompt_tokens"], params["output_tokens"]
+        assert p["min"] <= r["prompt_tokens"] <= p["max"]
+        assert o["min"] <= r["max_tokens"] <= o["max"]
+        # BOS + one id per character
+        assert len(common.byte_encode(r["prompt"])) == r["prompt_tokens"]
+
+
+def test_open_loop_schedule_fills_the_window_at_the_rate():
+    params = _traffic("mistral7b-serve-chat")
+    plan = _plan("mistral7b-serve-chat", 5)
+    due = [r["due_s"] for r in plan["requests"]]
+    assert len(due) == round(params["rate_per_s"] * 51)
+    assert due == sorted(due) and 0 < due[0] and due[-1] < 51.0
+    # ten samples beyond the 90th percentile
+    assert len(due) - int(0.9 * len(due)) >= 10
+
+
+def test_open_loop_times_from_the_due_time_and_reports_lateness():
+    plan = open_loop.plan({"rate_per_s": 40.0, "schedule_seed": 1,
+                           "prompt_tokens": {"median": 8, "sigma": 0.1,
+                                             "min": 4, "max": 16},
+                           "output_tokens": {"median": 4, "sigma": 0.1,
+                                             "min": 2, "max": 8},
+                           "drain_timeout_s": 5}, 1, 0.5)
+
+    def send(req, due, stop_at=None):
+        sent = time.monotonic()
+        time.sleep(0.01)
+        return {"index": req["index"], "due": due, "sent": sent,
+                "first": time.monotonic(), "done": time.monotonic(),
+                "error": None}
+
+    t0 = time.monotonic() + 0.05
+    recs = open_loop.drive(plan, send, t0, 0.5)
+    assert [r["index"] for r in recs] == list(range(len(plan["requests"])))
+    for r, q in zip(recs, plan["requests"]):
+        assert r["due"] == pytest.approx(t0 + q["due_s"])
+        # never early; how late is the machine's business (the suite runs
+        # six workers wide) and is what the record reports
+        assert 0.0 <= r["sent"] - r["due"] < 30.0
+        assert r["first"] - r["due"] >= 0.01          # latency from due
+
+
+def test_closed_loop_keeps_its_callers_busy_through_the_cool_down():
+    plan = closed_loop.plan({"clients": 4, "ramp_s": 0.0, "cooldown_s": 0.2,
+                             "pool": 64, "schedule_seed": 1,
+                             "prompt_tokens": {"median": 8, "sigma": 0.1,
+                                               "min": 4, "max": 16},
+                             "output_tokens": {"median": 4, "sigma": 0.1,
+                                               "min": 2, "max": 8}}, 1, 1.0)
+    live, peak, stops = [0], [0], set()
+
+    def send(req, due, stop_at=None):
+        live[0] += 1
+        peak[0] = max(peak[0], live[0])
+        stops.add(stop_at)
+        time.sleep(0.02)
+        live[0] -= 1
+        return {"index": req["index"], "due": due, "done": time.monotonic(),
+                "error": None}
+
+    t0 = time.monotonic()
+    recs = closed_loop.drive(plan, send, t0, 0.3)
+    assert peak[0] <= 4 and 8 <= len(recs) <= 64
+    # new requests are taken through the cool-down and not after it
+    assert stops == {t0 + 0.3 + 0.2}
+    assert t0 + 0.3 < max(r["due"] for r in recs) < t0 + 0.5
+
+
+def _stream(first, last, n, chunks=5, **kw):
+    times = [first + (last - first) * i / (chunks - 1) for i in range(chunks)]
+    return {"first": first, "chunk_times": times, "done": last + 0.001,
+            "completion_tokens": n, "max_tokens": n, "error": None,
+            "abandoned": False, **kw}
+
+
+@pytest.mark.parametrize("record,expected", [
+    (_stream(12.0, 18.0, 60), 60.0),                 # wholly inside
+    (_stream(8.0, 12.0, 40), 20.0),                  # straddles the start
+    (_stream(58.0, 62.0, 40), 20.0),                 # straddles the end
+    (_stream(5.0, 65.0, 120), 100.0),                # longer than the window
+    (_stream(2.0, 9.0, 50), 0.0),                    # before it
+    (_stream(61.0, 70.0, 50), 0.0),                  # after it
+    (_stream(30.0, 30.0, 1, chunks=2), 1.0),         # one token, one instant
+    (dict(_stream(30.0, 40.0, 9), error="HTTP 500"), 0.0),
+    # cut at the end of the cool-down, begun inside the window: max_tokens
+    # ending at the cut
+    (dict(_stream(50.0, 0.0, 0), done=None, abandoned=True,
+          abandoned_at=90.0, max_tokens=80), 20.0),
+    # cut before its first token
+    ({"first": None, "chunk_times": [], "done": None, "abandoned": True,
+      "abandoned_at": 90.0, "max_tokens": 80, "completion_tokens": 0,
+      "error": None}, 0.0),
+])
+def test_tokens_inside_the_window_by_hand(record, expected):
+    mod = common.load_module("metrics", "serve_tokens_per_s")
+    assert mod.window_tokens([record], 10.0, 60.0) == pytest.approx(expected)
+    run = {"records": [record, record],
+           "window": {"t0": 10.0, "t1": 60.0, "seconds": 50.0}}
+    assert mod.reduce(run) == pytest.approx(2 * expected / 50.0)
+
+
+def test_train_batches_are_pure_and_in_vocabulary():
+    a = train_batches.batch(2**31 + 5, 3, 8, 64, 512)
+    b = train_batches.batch(2**31 + 5, 3, 8, 64, 512)
+    assert a.shape == (8, 65) and (a == b).all()
+    assert a.min() >= 0 and a.max() < 512
+    assert not (a == train_batches.batch(2**31 + 5, 4, 8, 64, 512)).all()
+    assert not (a == train_batches.batch(2**31 + 6, 3, 8, 64, 512)).all()
+    cell = common.load_json(common.bench_dir(), "workloads",
+                            "mistral7b-train-fsdp4.json")
+    assert train_batches.plan(cell["traffic"], 9, 51.0) == {
+        "mode": "train", "seed": 9, "distinct_batches": 1}
+
+
+def test_every_seed_replays_one_schedule_from_another_start():
+    """The chat cell's schedule: every seed sees the same cyclic sequence of
+    (gap, prompt, output) triples, from another starting point."""
+    params = _traffic("mistral7b-serve-chat")
+    a = open_loop.plan(params, 5, 51.0)["requests"]
+    b = open_loop.plan(params, 2**31 + 9, 51.0)["requests"]
+
+    def triples(reqs):
+        gaps = [r["due_s"] - (reqs[i - 1]["due_s"] if i else 0.0)
+                for i, r in enumerate(reqs)]
+        return [(round(g, 9), r["prompt_tokens"], r["max_tokens"])
+                for g, r in zip(gaps, reqs)]
+
+    ta, tb = triples(a), triples(b)
+    k = tb.index(ta[0])
+    assert k != 0 and tb[k:] + tb[:k] == ta
+    assert [r["prompt"] for r in a] != [r["prompt"] for r in b]
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_both_serve_cells_order_their_lengths_by_the_one_rule(cell):
+    a, b = _plan(cell, 5)["requests"], _plan(cell, 2**31 + 9)["requests"]
+    pa = [(r["prompt_tokens"], r["max_tokens"]) for r in a]
+    pb = [(r["prompt_tokens"], r["max_tokens"]) for r in b]
+    assert pa != pb and any(pb[k:] + pb[:k] == pa for k in range(len(pb)))
