@@ -1,0 +1,11 @@
+"""Over the whole window: 1 - (time the engine loop spent parked in
+``loop_wait`` or blocked in ``harvest``) / window, from the running totals
+``phase_<p>_s_total`` of /v1/stats read at the window's edges. Near 100 %
+means the host loop sets the pace. program_counter."""
+
+from benchmark import span_reduce
+
+
+def reduce(run):
+    return span_reduce.engine_loop_busy_share(
+        run["stats_before"], run["stats_after"], run["window"]["seconds"])

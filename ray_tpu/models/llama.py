@@ -289,26 +289,34 @@ def _attention(q, k, v, cfg: LlamaConfig, mesh, *, positions_offset=0):
 
 
 def _layer_fwd(x, layer, cos, sin, cfg: LlamaConfig, mesh):
+    # the named scopes (norm / attn / mlp here, embed / lm_head / loss
+    # around them) are what a profiler trace names an op's layer by; the
+    # serving steps in serve/llm/kv_cache.py use the same names
     from jax.ad_checkpoint import checkpoint_name
-    h = rms_norm(x, layer["attn_norm"], cfg.norm_eps)
-    q = checkpoint_name(
-        jnp.einsum("btd,dhk->bthk", h, layer["attn"]["wq"]), "q_proj")
-    k = checkpoint_name(
-        jnp.einsum("btd,dhk->bthk", h, layer["attn"]["wk"]), "k_proj")
-    v = checkpoint_name(
-        jnp.einsum("btd,dhk->bthk", h, layer["attn"]["wv"]), "v_proj")
-    q = apply_rope(q, cos, sin)
-    k = apply_rope(k, cos, sin)
-    attn = checkpoint_name(_attention(q, k, v, cfg, mesh), "attn")
-    attn_out = checkpoint_name(
-        jnp.einsum("bthk,hkd->btd", attn, layer["attn"]["wo"]), "attn_out")
-    x = x + attn_out
-    h = checkpoint_name(
-        rms_norm(x, layer["mlp_norm"], cfg.norm_eps), "mlp_in")
-    gate = jax.nn.silu(_mlp_matmul(h, layer["mlp"]["w_gate"], cfg))
-    up = _mlp_matmul(h, layer["mlp"]["w_up"], cfg)
-    x = x + checkpoint_name(
-        _mlp_matmul(gate * up, layer["mlp"]["w_down"], cfg), "mlp_out")
+    with jax.named_scope("norm"):
+        h = rms_norm(x, layer["attn_norm"], cfg.norm_eps)
+    with jax.named_scope("attn"):
+        q = checkpoint_name(
+            jnp.einsum("btd,dhk->bthk", h, layer["attn"]["wq"]), "q_proj")
+        k = checkpoint_name(
+            jnp.einsum("btd,dhk->bthk", h, layer["attn"]["wk"]), "k_proj")
+        v = checkpoint_name(
+            jnp.einsum("btd,dhk->bthk", h, layer["attn"]["wv"]), "v_proj")
+        q = apply_rope(q, cos, sin)
+        k = apply_rope(k, cos, sin)
+        attn = checkpoint_name(_attention(q, k, v, cfg, mesh), "attn")
+        attn_out = checkpoint_name(
+            jnp.einsum("bthk,hkd->btd", attn, layer["attn"]["wo"]),
+            "attn_out")
+        x = x + attn_out
+    with jax.named_scope("norm"):
+        h = checkpoint_name(
+            rms_norm(x, layer["mlp_norm"], cfg.norm_eps), "mlp_in")
+    with jax.named_scope("mlp"):
+        gate = jax.nn.silu(_mlp_matmul(h, layer["mlp"]["w_gate"], cfg))
+        up = _mlp_matmul(h, layer["mlp"]["w_up"], cfg)
+        x = x + checkpoint_name(
+            _mlp_matmul(gate * up, layer["mlp"]["w_down"], cfg), "mlp_out")
     return x
 
 
@@ -350,15 +358,17 @@ def _remat(body, cfg: LlamaConfig):
 def forward(params, tokens, cfg: LlamaConfig, mesh=None):
     """tokens [B, T] → logits [B, T, vocab]."""
     x = hidden_states(params, tokens, cfg, mesh)
-    return (x @ params["lm_head"]).astype(jnp.float32)
+    with jax.named_scope("lm_head"):
+        return (x @ params["lm_head"]).astype(jnp.float32)
 
 
 def hidden_states(params, tokens, cfg: LlamaConfig, mesh=None):
     """tokens [B, T] → final-norm hidden states [B, T, D] (no lm_head)."""
     b, t = tokens.shape
-    x = params["embed"][tokens].astype(cfg.dtype)
-    positions = jnp.broadcast_to(jnp.arange(t), (b, t))
-    cos, sin = rope_freqs(cfg, positions)
+    with jax.named_scope("embed"):
+        x = params["embed"][tokens].astype(cfg.dtype)
+        positions = jnp.broadcast_to(jnp.arange(t), (b, t))
+        cos, sin = rope_freqs(cfg, positions)
 
     def body(x, layer):
         return _layer_fwd(x, layer, cos, sin, cfg, mesh), None
@@ -366,7 +376,8 @@ def hidden_states(params, tokens, cfg: LlamaConfig, mesh=None):
     if cfg.remat:
         body = _remat(body, cfg)
     x, _ = jax.lax.scan(body, x, params["layers"])
-    return rms_norm(x, params["final_norm"], cfg.norm_eps)
+    with jax.named_scope("norm"):
+        return rms_norm(x, params["final_norm"], cfg.norm_eps)
 
 
 def chunked_cross_entropy(lm_head, hidden, targets, chunk: int = 256,
@@ -397,7 +408,8 @@ def chunked_cross_entropy(lm_head, hidden, targets, chunk: int = 256,
 
     def body(acc, xs):
         h, y = xs
-        logits = (h @ lm_head).astype(jnp.float32)       # [B, chunk, V]
+        with jax.named_scope("lm_head"):
+            logits = (h @ lm_head).astype(jnp.float32)   # [B, chunk, V]
         lse = jax.nn.logsumexp(logits, axis=-1)
         ll = jnp.take_along_axis(
             logits, jnp.maximum(y, 0)[..., None], axis=-1)[..., 0] - lse
@@ -417,8 +429,9 @@ def loss_fn(params, batch, cfg: LlamaConfig, mesh=None):
     tokens = batch["tokens"] if isinstance(batch, dict) else batch
     inputs, targets = tokens[:, :-1], tokens[:, 1:]
     hidden = hidden_states(params, inputs, cfg, mesh)
-    return chunked_cross_entropy(params["lm_head"], hidden, targets,
-                                 chunk=cfg.ce_chunk, remat=cfg.ce_remat)
+    with jax.named_scope("loss"):
+        return chunked_cross_entropy(params["lm_head"], hidden, targets,
+                                     chunk=cfg.ce_chunk, remat=cfg.ce_remat)
 
 
 # ---------------------------------------------------------------------------

@@ -1,5 +1,5 @@
 """Observability subsystems: distributed tracing (tracing.py) and
-performance introspection — engine phase timers, compile-event tracking,
+performance introspection — engine phase spans, compile-event tracking,
 device-memory accounting, on-demand XProf capture, and the local
-context-manager profiling helpers (profiling.py — ray_tpu.util.profiling
-re-exports them for compatibility)."""
+context-manager profiling helpers (profiling.py; ray_tpu.util exports
+profile_trace / annotate)."""

@@ -5,13 +5,17 @@ argument: in-situ diagnostics are a precondition for operating a fleet —
 offline benching found the 138 ms/step residual cost, production needs the
 runtime to find the next one):
 
-- **Phase timers** (`EngineProfiler.record`): the engine loop stamps each
-  phase — admit / prefill / chunk_prefill / decode_dispatch /
-  verify_dispatch / harvest — into bounded rings and a tagged Histogram.
-  Dispatch phases measure host-side dispatch cost (the loop never blocks
-  on the device); `harvest` is where the device sync lives
-  (`np.asarray` on the oldest in-flight block), so device slowness shows
-  up there, attributed, instead of smeared across the loop.
+- **Phase spans** (`EngineProfiler.span`): the one way the engine loop
+  times a phase. Every span feeds a bounded ring, a tagged Histogram and
+  a running total (seconds, count) per phase; while an XProf capture is
+  active in this process it is also a `jax.profiler.TraceAnnotation`
+  named `rt/<phase>` with the span's arguments, so host phases land in
+  the trace's host plane on the clock the device ops are on. Dispatch
+  phases measure host-side dispatch cost (the loop never blocks on the
+  device); `harvest` is where the device sync lives (`np.asarray` on the
+  oldest in-flight block), so device slowness shows up there,
+  attributed, instead of smeared across the loop; `loop_wait` is the
+  loop parked with nothing to do.
 - **Compile-event tracking** (`compile_scope`): every jit entry point's
   first dispatch per static signature (prefill bucket, chunk length,
   decode (width, block), verify width) is timed as a compile event.
@@ -28,8 +32,7 @@ Plus the **capture controller**: a process-wide start/stop pair around
 `jax.profiler` XPlane tracing, callable from an RPC handler, so
 `ray-tpu profile --node <id>` captures a trace on any live worker and the
 dashboard serves the artifact. The local context-manager helpers
-(`profile_trace` / `annotate` / `profile_step` / `dump_thread_stacks`)
-live here too — `ray_tpu.util.profiling` is a compatibility re-export.
+(`profile_trace` / `annotate` / `dump_thread_stacks`) live here too.
 """
 
 from __future__ import annotations
@@ -40,16 +43,23 @@ import logging
 import os
 import threading
 import time
-from typing import Any, Optional
+from typing import Optional
 
 from ray_tpu.util import metrics as _metrics
 
 logger = logging.getLogger(__name__)
 
-# engine phases, in loop order (the drift-guard test and README table key
-# off this tuple — extend it and both follow)
-PHASES = ("queue_wait", "admit", "prefill", "chunk_prefill",
-          "decode_dispatch", "verify_dispatch", "harvest")
+# engine phases (the drift-guard test, the controller's key list and the
+# README table key off this tuple — extend it and all follow).
+# `queue_wait` is a per-request duration fed through `record`; the rest
+# are spans on the engine-loop thread, nested by containment under
+# `loop_pass` (`patch_flush` inside a dispatch; `restore`, `kv_tier_flush`
+# only with the kv tier on).
+PHASES = ("queue_wait", "loop_pass", "admit", "restore", "prefill",
+          "chunk_prefill", "decode_dispatch", "verify_dispatch",
+          "patch_flush", "harvest", "emit", "kv_tier_flush", "loop_wait")
+# span names in the profiler's trace: "rt/<phase>"
+SPAN_PREFIX = "rt/"
 
 _PHASE_BOUNDS = (0.0001, 0.0003, 0.001, 0.003, 0.01, 0.03,
                  0.1, 0.3, 1.0, 3.0, 10.0)
@@ -109,6 +119,51 @@ class _Noop:
 _NOOP = _Noop()
 
 
+class _Span:
+    """One timed phase of the engine loop (`EngineProfiler.span`)."""
+
+    __slots__ = ("_prof", "_name", "_ann", "_t0")
+
+    def __init__(self, prof: "EngineProfiler", name: str, ann):
+        self._prof = prof
+        self._name = name
+        self._ann = ann
+
+    def set(self, **args) -> None:
+        """Arguments known only once the phase has run (how many were
+        admitted, how many tokens were emitted). A no-op unless a capture
+        is recording this span."""
+        if self._ann is not None:
+            self._ann.set_metadata(**args)
+
+    def __enter__(self):
+        if self._ann is not None:
+            self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        dt = time.perf_counter() - self._t0
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+        self._prof.record(self._name, dt)
+        return False
+
+
+class _NoSpan(_Noop):
+    """What `span` returns when nothing listens: profiling disabled and
+    no capture active."""
+
+    def __enter__(self):
+        return self
+
+    def set(self, **args) -> None:
+        return None
+
+
+_NO_SPAN = _NoSpan()
+
+
 class _CompileScope:
     def __init__(self, prof: "EngineProfiler", kind: str, sig,
                  mid_traffic: bool):
@@ -130,14 +185,13 @@ class _CompileScope:
 
 
 class EngineProfiler:
-    """Per-engine introspection state: phase rings, compile tracker, ITL
-    ring, memory layout. All mutating entry points are cheap enough to
-    sit on the engine loop's hot path; `enabled=False` reduces phase/ITL
-    recording to a single attribute check (the `--profile-ab` bench
-    bounds the enabled-path overhead). Compile tracking stays on either
-    way — it only does work on the FIRST dispatch of a new signature,
-    and a silent mid-traffic compile is exactly what this exists to
-    catch."""
+    """Per-engine introspection state: phase rings and totals, compile
+    tracker, ITL ring, memory layout. All mutating entry points are cheap
+    enough to sit on the engine loop's hot path; `enabled=False` reduces
+    phase/ITL recording to an attribute check (two for a span: this one
+    and the capture flag). Compile tracking stays on either way — it only
+    does work on the FIRST dispatch of a new signature, and a silent
+    mid-traffic compile is exactly what this exists to catch."""
 
     def __init__(self, enabled: bool = True, ring_size: int = 256,
                  itl_ring_size: int = 2048):
@@ -145,6 +199,10 @@ class EngineProfiler:
         self._lock = threading.Lock()
         self._rings: dict[str, collections.deque] = {
             p: collections.deque(maxlen=ring_size) for p in PHASES}
+        # running totals per phase: [seconds, count]. Written by the loop
+        # thread alone, read by engine_stats(); deltas over any interval
+        # give host time by phase.
+        self._totals: dict[str, list] = {p: [0.0, 0] for p in PHASES}
         self._itl: collections.deque = collections.deque(maxlen=itl_ring_size)
         self._seen: set = set()
         self.compile_events = 0
@@ -159,20 +217,27 @@ class EngineProfiler:
         if not self.enabled:
             return
         self._rings[phase].append(dt)
+        tot = self._totals[phase]
+        tot[0] += dt
+        tot[1] += 1
         PHASE_SECONDS.observe(dt, {"phase": phase})
 
-    @contextlib.contextmanager
-    def phase(self, name: str):
-        """Time a block as one phase sample (skips the clock reads
-        entirely when disabled)."""
+    def span(self, name: str, **args):
+        """Context manager around one phase of the engine loop: a ring
+        sample, a histogram observation and the phase's running total
+        when `enabled`; while a capture is active in this process, also a
+        `TraceAnnotation("rt/<name>", **args)` in the profiler's trace.
+        Enter and leave it OUTSIDE the engine lock (the histogram
+        observation must not run under it). `.set(**args)` on the
+        returned span adds arguments known only at the end."""
+        if _capture.active:
+            import jax
+
+            return _Span(self, name, jax.profiler.TraceAnnotation(
+                SPAN_PREFIX + name, **args))
         if not self.enabled:
-            yield
-            return
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            self.record(name, time.perf_counter() - t0)
+            return _NO_SPAN
+        return _Span(self, name, None)
 
     def record_itl(self, gap_s: float) -> None:
         if not self.enabled:
@@ -181,8 +246,10 @@ class EngineProfiler:
         ITL_SECONDS.observe(gap_s)
 
     def phase_stats(self) -> dict:
-        """`phase_<name>_p50_ms` / `_p95_ms` per phase plus `itl_s` (p50);
-        None where no samples exist yet (or profiling is disabled)."""
+        """Per phase `phase_<name>_p50_ms` / `_p95_ms` over the ring (None
+        where no samples exist yet, or profiling is disabled) and the
+        running totals `phase_<name>_s_total` / `phase_<name>_n`; plus
+        `itl_s` (p50)."""
         out: dict[str, Optional[float]] = {}
         for p in PHASES:
             vals = sorted(self._rings[p])
@@ -190,6 +257,9 @@ class EngineProfiler:
                 round(_pct(vals, 0.5) * 1e3, 4) if vals else None)
             out[f"phase_{p}_p95_ms"] = (
                 round(_pct(vals, 0.95) * 1e3, 4) if vals else None)
+            seconds, n = self._totals[p]
+            out[f"phase_{p}_s_total"] = round(seconds, 6)
+            out[f"phase_{p}_n"] = n
         itl = sorted(self._itl)
         out["itl_s"] = round(_pct(itl, 0.5), 6) if itl else None
         return out
@@ -312,12 +382,14 @@ def device_memory_stats(devices=None) -> tuple[Optional[int], Optional[int]]:
 class CaptureController:
     """Process-wide start/stop around `jax.profiler` tracing. jax allows
     ONE active trace per process, so this serializes: a second start while
-    active raises instead of corrupting the run."""
+    active raises instead of corrupting the run. `active` is the plain
+    attribute `EngineProfiler.span` reads to decide whether to annotate."""
 
     def __init__(self):
         self._lock = threading.Lock()
         self._logdir: Optional[str] = None
         self._started_at: Optional[float] = None
+        self.active = False
 
     def start(self, logdir: Optional[str] = None) -> dict:
         import jax
@@ -328,12 +400,16 @@ class CaptureController:
                     f"capture already active (logdir={self._logdir})")
             if not logdir:
                 logdir = os.path.join(
-                    "/tmp", "ray_tpu_xprof",
-                    f"{int(time.time())}-{os.getpid()}")
+                    "/tmp", "ray_tpu_xprof", str(int(time.time())))
+            # a subdirectory of its own per process: the profiler names
+            # its file by host and second, so two workers given one
+            # directory would overwrite each other's trace
+            logdir = os.path.join(logdir, str(os.getpid()))
             os.makedirs(logdir, exist_ok=True)
             jax.profiler.start_trace(logdir, create_perfetto_link=False)
             self._logdir = logdir
             self._started_at = time.time()
+            self.active = True
             return {"logdir": logdir, "pid": os.getpid()}
 
     def stop(self) -> dict:
@@ -342,6 +418,7 @@ class CaptureController:
         with self._lock:
             if self._logdir is None:
                 raise RuntimeError("no capture active")
+            self.active = False
             jax.profiler.stop_trace()
             logdir, self._logdir = self._logdir, None
             dur = time.time() - (self._started_at or time.time())
@@ -385,8 +462,8 @@ def save_device_memory_profile(path: Optional[str] = None) -> str:
 
 
 # ---------------------------------------------------------------------------
-# local context-manager helpers (driver/train-fn ergonomics; formerly
-# ray_tpu.util.profiling, which now re-exports from here)
+# local context-manager helpers (driver/train-fn ergonomics; exported as
+# ray_tpu.util.profile_trace / annotate)
 # ---------------------------------------------------------------------------
 
 
@@ -417,16 +494,6 @@ def annotate(name: str):
     import jax
 
     return jax.profiler.TraceAnnotation(name)
-
-
-def profile_step(fn, *args, logdir: str = "/tmp/ray_tpu_prof", **kwargs):
-    """One-shot: trace a single call of `fn` and return its result."""
-    with profile_trace(logdir):
-        out = fn(*args, **kwargs)
-        import jax
-
-        jax.block_until_ready(out)
-    return out
 
 
 def dump_thread_stacks() -> str:

@@ -114,6 +114,7 @@ def _flash_bh(q, k, v, *, causal: bool, sm_scale: float, block_q: int,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_fwd",
     )(q, k, v)
 
 
@@ -261,6 +262,7 @@ def _flash_bwd_bh(q, k, v, g, lse, delta, *, causal: bool, sm_scale: float,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_bwd_dkdv",
     )(q, k, v, g, lse, delta)
 
     dq_specs = [
@@ -283,6 +285,7 @@ def _flash_bwd_bh(q, k, v, g, lse, delta, *, causal: bool, sm_scale: float,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_bwd_dq",
     )(q, k, v, g, lse, delta)
     return dq, dk, dv
 

@@ -161,7 +161,8 @@ def _paged_attn_kernel(pt_ref, base_ref, limit_ref,     # scalar prefetch
 
 def paged_attention(q, k_pages, v_pages, page_tables, base, limit=None, *,
                     sm_scale: float | None = None,
-                    interpret: bool | None = None):
+                    interpret: bool | None = None,
+                    name: str = "paged_attention"):
     """Fused paged attention over the whole query span.
 
     q: [B, T, H, D] — query position of q[:, t] is ``base + t`` (causal
@@ -169,7 +170,9 @@ def paged_attention(q, k_pages, v_pages, page_tables, base, limit=None, *,
     k_pages/v_pages: [Hkv, P, page, D] pool. page_tables: [B, max_pages].
     base: [B] int32 first-query positions. limit: [B] int32 exclusive key
     bound (None = the whole table span) — chunked prefill passes
-    ``true_len`` so padded tail pages stay masked.
+    ``true_len`` so padded tail pages stay masked. name: the kernel's
+    name in the compiled program and in a profiler trace (each of the
+    three callers below passes its own).
     Returns [B, T, H, D] in q.dtype.
     """
     b, t, h, d = q.shape
@@ -233,6 +236,7 @@ def paged_attention(q, k_pages, v_pages, page_tables, base, limit=None, *,
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=max(32 * 1024 * 1024, 2 * vmem)),
         interpret=interpret,
+        name=name,
     )(page_tables.astype(jnp.int32), base.astype(jnp.int32),
       limit.astype(jnp.int32), qg, k_pages, v_pages)
     return out[:, :, :r].reshape(b, hkv, n_rep, t, d).transpose(
@@ -246,7 +250,8 @@ def paged_decode_attention(q, k_pages, v_pages, page_tables, pos, *,
     ``pos[b]`` (attends 0..pos inclusive — its own k/v is already written
     to the pool). Returns [B, H, D]."""
     out = paged_attention(q[:, None], k_pages, v_pages, page_tables, pos,
-                          sm_scale=sm_scale, interpret=interpret)
+                          sm_scale=sm_scale, interpret=interpret,
+                          name="paged_decode_attention")
     return out[:, 0]
 
 
@@ -258,7 +263,8 @@ def paged_verify_attention(q, k_pages, v_pages, page_tables, seq_lens, *,
     span, full attention over the slot's cached pages (all T spans' k/v
     are pre-written). Returns [B, T, H, D]."""
     return paged_attention(q, k_pages, v_pages, page_tables, seq_lens,
-                           sm_scale=sm_scale, interpret=interpret)
+                           sm_scale=sm_scale, interpret=interpret,
+                           name="paged_verify_attention")
 
 
 def paged_chunk_attention(q, k_pages, v_pages, page_table, start, true_len,
@@ -271,4 +277,5 @@ def paged_chunk_attention(q, k_pages, v_pages, page_table, start, true_len,
     base = jnp.reshape(start, (1,)).astype(jnp.int32)
     limit = jnp.reshape(true_len, (1,)).astype(jnp.int32)
     return paged_attention(q, k_pages, v_pages, page_table[None], base,
-                           limit, sm_scale=sm_scale, interpret=interpret)
+                           limit, sm_scale=sm_scale, interpret=interpret,
+                           name="paged_chunk_attention")

@@ -417,6 +417,7 @@ class ServeController:
         # counters per replica — plus the ISSUE-6 introspection surface
         # (per-phase p50/p95, ITL, compile events, device memory) that the
         # dashboard /profiling panel renders; anything else probes to None
+        from ray_tpu.observability.profiling import PHASES
         _ENGINE_KEYS = ("steps", "prefills", "tokens_out", "requests",
                         "shed_expired",
                         "active_slots", "waiting", "free_pages",
@@ -451,11 +452,8 @@ class ServeController:
                         "compile_s", "weights_bytes", "kv_pool_bytes",
                         "kv_page_occupancy", "device_bytes_in_use",
                         "device_peak_bytes") + tuple(
-                            f"phase_{p}_{q}_ms"
-                            for p in ("queue_wait", "admit", "prefill",
-                                      "chunk_prefill", "decode_dispatch",
-                                      "verify_dispatch", "harvest")
-                            for q in ("p50", "p95"))
+                            f"phase_{p}_{q}" for p in PHASES
+                            for q in ("p50_ms", "p95_ms", "s_total", "n"))
 
         async def probe_engine(replica):
             try:

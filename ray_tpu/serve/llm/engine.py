@@ -354,7 +354,11 @@ class LLMEngine:
         # tracks step execution time instead of the host<->device round
         # trip.
         self.PIPELINE_DEPTH = cfg.pipeline_depth
-        self._pending: list = []   # [(dev_tokens, [(col, slot, req)], k)]
+        # [(dev_tokens, [(col, slot, req)], k, seq)]; seq numbers decode and
+        # verify blocks (the same in a block's dispatch and harvest spans),
+        # -1 for a prefill's first token
+        self._pending: list = []
+        self._block_seq = 0
         self._dev_tokens = None    # [B+1] device array (incl. trash row)
         self._overrides: dict[int, int] = {}  # slot -> first token (prefill)
         # device-resident decode state (page tables / seq lens / temps);
@@ -491,29 +495,37 @@ class LLMEngine:
         sampled tokens [K, W] plus the full-size carried state."""
         jax = self._jax
         jnp = self._jnp
-        pt = pt_full[idx]
-        lens0 = sl_full[idx]
-        toks0 = toks_full[idx]
-        temps = temps_full[idx]
+        # named scopes (compile-time metadata): a trace names the
+        # program's device ops decode_block/{gather_state, decode_step/
+        # <layer scope>, scatter_state}
+        with jax.named_scope("decode_block"):
+            with jax.named_scope("gather_state"):
+                pt = pt_full[idx]
+                lens0 = sl_full[idx]
+                toks0 = toks_full[idx]
+                temps = temps_full[idx]
 
-        def one(carry, _):
-            kv_c, lens, toks, key = carry
-            key, sub = jax.random.split(key)
-            logits, kv_c, lens = self._kvc.paged_decode_step(
-                params, kv_c, pt, lens, toks, self.model_cfg,
-                self.cfg.page_size, self._attn_backend, mesh=self._mesh)
-            toks = self._kvc.sample_tokens(
-                logits, sub, temps, self.cfg.top_k)
-            return (kv_c, lens, toks, key), toks
+            @jax.named_scope("decode_step")
+            def one(carry, _):
+                kv_c, lens, toks, key = carry
+                key, sub = jax.random.split(key)
+                logits, kv_c, lens = self._kvc.paged_decode_step(
+                    params, kv_c, pt, lens, toks, self.model_cfg,
+                    self.cfg.page_size, self._attn_backend, mesh=self._mesh)
+                toks = self._kvc.sample_tokens(
+                    logits, sub, temps, self.cfg.top_k)
+                return (kv_c, lens, toks, key), toks
 
-        (kv, new_lens, last, rng), all_toks = jax.lax.scan(
-            one, (kv, lens0, toks0, rng), None, length=num_steps)
-        # padding lanes must not accumulate garbage into the trash row
-        # (its seq_len would creep toward int32 overflow on a long-lived
-        # engine): pin it back to zero on scatter
-        trash = self.cfg.max_batch_size
-        sl_full = sl_full.at[idx].set(jnp.where(idx == trash, 0, new_lens))
-        toks_full = toks_full.at[idx].set(last)
+            (kv, new_lens, last, rng), all_toks = jax.lax.scan(
+                one, (kv, lens0, toks0, rng), None, length=num_steps)
+            # padding lanes must not accumulate garbage into the trash row
+            # (its seq_len would creep toward int32 overflow on a
+            # long-lived engine): pin it back to zero on scatter
+            trash = self.cfg.max_batch_size
+            with jax.named_scope("scatter_state"):
+                sl_full = sl_full.at[idx].set(
+                    jnp.where(idx == trash, 0, new_lens))
+                toks_full = toks_full.at[idx].set(last)
         return all_toks, toks_full, kv, sl_full, rng
 
     def _verify_impl(self, params, kv, pt_full, sl_full, toks_full, rng,
@@ -1154,69 +1166,78 @@ class LLMEngine:
             req.done_event.set()
 
     def _run_loop(self):
-        prof = self._prof
         while not self._stop.is_set():
-            # admit timing covers the whole admission pass (including the
-            # async prefill dispatches of short prompts, which are ALSO
-            # sampled individually as "prefill"); idle passes that admit
-            # nothing are not recorded — the ring holds work, not waiting
-            if prof.enabled:
-                t0 = time.perf_counter()
-                if self._admit():
-                    prof.record("admit", time.perf_counter() - t0)
-            else:
-                self._admit()
-            # streaming tier restores first: a chunk that landed since
-            # the last pass injects before this pass's prefill chunks
-            # dispatch, and a stream that just finished routes its
-            # request into _prefilling in time for THIS pass
-            restored = self._restore_steps() if self._kv_tier_on else 0
-            chunks = self._prefill_chunks()
-            if self._spill_req is not None:
-                # drain-time eager spill (ISSUE 14): gather + flush on
-                # THIS thread, then release the waiter — its return must
-                # mean the chains are actually in the tier
-                ev, box = self._spill_req
-                self._spill_req = None
-                try:
-                    box.append(self._spill_inflight_now())
-                    self._kv_tier_flush()
-                finally:
-                    ev.set()
-            if self._warm_req is not None:
-                # cache-warm scale-up (ISSUE 17): restore the fleet's
-                # hottest tier chains into the local prefix cache on THIS
-                # thread — the replica is pre-routing-table, so the loop
-                # has no traffic to stall
-                ev, box, w_mb, w_bs = self._warm_req
-                self._warm_req = None
-                try:
-                    box.append(self._warm_start_now(w_mb, w_bs))
-                finally:
-                    ev.set()
-            # chunk dispatches count as progress: an otherwise-idle engine
-            # mid-chunked-prefill must not sleep between chunks. Restore
-            # progress counts too; a stream WAITING on fetches does not —
-            # the idle wait below parks on _wake, which the stream's
-            # on_ready sets the moment new pages land
-            dispatched = self._step() or chunks > 0 or restored > 0
-            if self._kv_tier_on:
-                # spill gathers captured by evictions this pass: their
-                # device->host copies were started at dispatch, so this
-                # is mostly bookkeeping + an object-store put
+            with self._prof.span("loop_pass"):
+                self._loop_pass()
+
+    def _loop_pass(self):
+        """One pass of the engine loop. Every phase of it is a span
+        (observability/profiling.py) nested under `loop_pass`."""
+        prof = self._prof
+        # admit covers the whole admission pass (including the async
+        # prefill dispatches of short prompts, which are ALSO sampled
+        # individually as "prefill"), on every pass, idle ones too
+        with prof.span("admit") as sp:
+            admitted = self._admit()
+            sp.set(admitted=admitted, waiting=len(self._waiting))
+        # streaming tier restores first: a chunk that landed since
+        # the last pass injects before this pass's prefill chunks
+        # dispatch, and a stream that just finished routes its
+        # request into _prefilling in time for THIS pass
+        restored = 0
+        if self._kv_tier_on:
+            with prof.span("restore"):
+                restored = self._restore_steps()
+        chunks = self._prefill_chunks()
+        if self._spill_req is not None:
+            # drain-time eager spill (ISSUE 14): gather + flush on
+            # THIS thread, then release the waiter — its return must
+            # mean the chains are actually in the tier
+            ev, box = self._spill_req
+            self._spill_req = None
+            try:
+                box.append(self._spill_inflight_now())
                 self._kv_tier_flush()
-            # Eager harvest: pop every block whose device result already
-            # landed (is_ready) — holding computed tokens unharvested just
-            # adds their age to TTFT/ITL. The blocking PIPELINE_DEPTH trim
-            # in _step still bounds the queue when results are slow.
-            while self._pending and self._pending[0][0].is_ready():
-                self._harvest_one()
-            if not dispatched:
-                if self._pending:
-                    self._harvest_one()  # drain the pipeline tail
-                    continue
+            finally:
+                ev.set()
+        if self._warm_req is not None:
+            # cache-warm scale-up (ISSUE 17): restore the fleet's
+            # hottest tier chains into the local prefix cache on THIS
+            # thread — the replica is pre-routing-table, so the loop
+            # has no traffic to stall
+            ev, box, w_mb, w_bs = self._warm_req
+            self._warm_req = None
+            try:
+                box.append(self._warm_start_now(w_mb, w_bs))
+            finally:
+                ev.set()
+        # chunk dispatches count as progress: an otherwise-idle engine
+        # mid-chunked-prefill must not sleep between chunks. Restore
+        # progress counts too; a stream WAITING on fetches does not —
+        # the idle wait below parks on _wake, which the stream's
+        # on_ready sets the moment new pages land
+        dispatched = self._step() or chunks > 0 or restored > 0
+        if self._kv_tier_on:
+            # spill gathers captured by evictions this pass: their
+            # device->host copies were started at dispatch, so this
+            # is mostly bookkeeping + an object-store put
+            with prof.span("kv_tier_flush"):
+                self._kv_tier_flush()
+        # Eager harvest: pop every block whose device result already
+        # landed (is_ready) — holding computed tokens unharvested just
+        # adds their age to TTFT/ITL. The blocking PIPELINE_DEPTH trim
+        # in _step still bounds the queue when results are slow.
+        while self._pending and self._pending[0][0].is_ready():
+            self._harvest_one()
+        if not dispatched:
+            if self._pending:
+                self._harvest_one()  # drain the pipeline tail
+                return
+            # the one place the loop sleeps: what tells "the host had
+            # nothing to do" from "the host was busy"
+            with prof.span("loop_wait"):
                 self._wake.wait(timeout=0.05)
-                self._wake.clear()
+            self._wake.clear()
 
     @staticmethod
     def _start_fetch(dev_arr) -> None:
@@ -1802,7 +1823,8 @@ class LLMEngine:
         # a first-use prefill bucket compiles HERE, with a live request
         # waiting on it — warmup doesn't cover prompt buckets, so this is
         # always a mid-traffic compile when it fires
-        with self._prof.phase("prefill"), self._prof.compile_scope(
+        with self._prof.span("prefill", rid=req.request_id, bucket=bucket,
+                             tokens=plen), self._prof.compile_scope(
                 "prefill", ("prefill", bucket),
                 mid_traffic=self.stats["requests"] > 0):
             tok_dev, self.kv = fn(
@@ -1824,7 +1846,7 @@ class LLMEngine:
             self.slot_req[req.slot] = req
             self._dirty_slots[req.slot] = (plen, req.temperature)
             self._overrides[req.slot] = tok_dev
-            self._pending.append((tok_dev, [(0, req.slot, req)], 1))
+            self._pending.append((tok_dev, [(0, req.slot, req)], 1, -1))
         if self._prefix_cache_on:
             # Index the prompt's FULL pages now (not at completion): the
             # writes are merely dispatched, but any matcher's reads are
@@ -1873,7 +1895,10 @@ class LLMEngine:
             table[: len(req.pages)] = req.pages
             fn = self._chunk_fn(clen)
             self._rng, sub = self._jax.random.split(self._rng)
-            with self._prof.phase("chunk_prefill"), self._prof.compile_scope(
+            with self._prof.span(
+                    "chunk_prefill", rid=req.request_id, clen=clen,
+                    start=start, tokens=len(seg), last=int(final)), \
+                    self._prof.compile_scope(
                     "chunk", ("chunk", clen),
                     mid_traffic=self.stats["requests"] > 0):
                 tok_dev, self.kv = fn(
@@ -1978,42 +2003,46 @@ class LLMEngine:
         row padded — see the compile-stall note on _patch_state) and
         return the patched device token vector. Shared by the decode and
         verify-k dispatch paths; loop thread only."""
-        jnp = self._jnp
-        trash_row = self.cfg.max_batch_size
-        if dirty:
-            # fixed-shape patch: pad to B+1 rows onto the trash row (whose
-            # state is all-zeros by invariant), so ONE compiled scatter
-            # covers every dirty-count
-            order = sorted(dirty)
-            pad = (trash_row + 1) - len(order)
-            didx = jnp.asarray(order + [trash_row] * pad, jnp.int32)
-            ptv = np.zeros((trash_row + 1, self.max_pages_per_seq), np.int32)
-            ptv[: len(order)] = self.page_tables[order]
-            slv = np.zeros((trash_row + 1,), np.int32)
-            slv[: len(order)] = [dirty[i][0] for i in order]
-            tv = np.zeros((trash_row + 1,), np.float32)
-            tv[: len(order)] = [dirty[i][1] for i in order]
-            self._pt_dev, self._sl_dev, self._temps_dev = self._patch_state(
-                self._pt_dev, self._sl_dev, self._temps_dev, didx,
-                jnp.asarray(ptv), jnp.asarray(slv), jnp.asarray(tv))
-        toks = self._dev_tokens
-        if toks is None:
-            toks = jnp.zeros((self.cfg.max_batch_size + 1,), jnp.int32)
-        if overrides:
-            # values are device scalars from async prefills (or host ints
-            # from verify-round acceptance): stacking and scattering stays
-            # on device — no host sync. Same fixed-shape padding (trash-row
-            # writes of 0) as the state patch.
-            if self._zero_tok is None:
-                self._zero_tok = jnp.int32(0)
-            pad = (trash_row + 1) - len(overrides)
-            oidx = jnp.asarray(
-                list(overrides.keys()) + [trash_row] * pad, jnp.int32)
-            ovals = jnp.stack(
-                [jnp.asarray(v, jnp.int32) for v in overrides.values()]
-                + [self._zero_tok] * pad)
-            toks = self._patch_toks(toks, oidx, ovals)
-        return toks
+        with self._prof.span("patch_flush", dirty=len(dirty),
+                             overrides=len(overrides)):
+            jnp = self._jnp
+            trash_row = self.cfg.max_batch_size
+            if dirty:
+                # fixed-shape patch: pad to B+1 rows onto the trash row
+                # (whose state is all-zeros by invariant), so ONE compiled
+                # scatter covers every dirty-count
+                order = sorted(dirty)
+                pad = (trash_row + 1) - len(order)
+                didx = jnp.asarray(order + [trash_row] * pad, jnp.int32)
+                ptv = np.zeros((trash_row + 1, self.max_pages_per_seq),
+                               np.int32)
+                ptv[: len(order)] = self.page_tables[order]
+                slv = np.zeros((trash_row + 1,), np.int32)
+                slv[: len(order)] = [dirty[i][0] for i in order]
+                tv = np.zeros((trash_row + 1,), np.float32)
+                tv[: len(order)] = [dirty[i][1] for i in order]
+                self._pt_dev, self._sl_dev, self._temps_dev = \
+                    self._patch_state(
+                        self._pt_dev, self._sl_dev, self._temps_dev, didx,
+                        jnp.asarray(ptv), jnp.asarray(slv), jnp.asarray(tv))
+            toks = self._dev_tokens
+            if toks is None:
+                toks = jnp.zeros((self.cfg.max_batch_size + 1,), jnp.int32)
+            if overrides:
+                # values are device scalars from async prefills (or host
+                # ints from verify-round acceptance): stacking and scattering
+                # stays on device — no host sync. Same fixed-shape padding
+                # (trash-row writes of 0) as the state patch.
+                if self._zero_tok is None:
+                    self._zero_tok = jnp.int32(0)
+                pad = (trash_row + 1) - len(overrides)
+                oidx = jnp.asarray(
+                    list(overrides.keys()) + [trash_row] * pad, jnp.int32)
+                ovals = jnp.stack(
+                    [jnp.asarray(v, jnp.int32) for v in overrides.values()]
+                    + [self._zero_tok] * pad)
+                toks = self._patch_toks(toks, oidx, ovals)
+            return toks
 
     def _step(self) -> bool:
         """Dispatch the iteration's device work: a speculative verify-k
@@ -2050,36 +2079,41 @@ class LLMEngine:
             self._last_block = k
             dirty, self._dirty_slots = self._dirty_slots, {}
             overrides, self._overrides = self._overrides, {}
+            # tokens in the cache of the block's slots as its first step
+            # starts (what the device's seq_lens hold): the live context
+            ctx_tokens = 0
             for _col, _slot, req in snapshot:
+                ctx_tokens += len(req.prompt_tokens) + req.dispatched - 1
                 req.dispatched += k
-        # decode_dispatch times the HOST cost of getting the block onto
-        # the device stream (patch flush + jit dispatch); the result sync
-        # is the harvest phase. The pipeline-trim harvest below is
-        # excluded — it's already sampled inside _harvest_one.
-        t0 = time.perf_counter() if self._prof.enabled else 0.0
-        toks = self._flush_slot_patches(dirty, overrides)
         # bucketed width: pack the active slots, pad with the trash row —
         # a lightly loaded engine runs a narrow program
         active_slots = [slot for _c, slot, _r in snapshot]
         w = self._bucket_width(len(active_slots))
-        trash = self.cfg.max_batch_size
-        idx = jnp.asarray(
-            active_slots + [trash] * (w - len(active_slots)), jnp.int32)
-        snapshot = [(col, slot, req)
-                    for col, (_c, slot, req) in enumerate(snapshot)]
-        with self._prof.compile_scope(
-                "decode", ("decode", w, k),
-                mid_traffic=self.stats["requests"] > 0):
-            all_toks, self._dev_tokens, self.kv, self._sl_dev, self._rng = \
-                self._decode(self.params, self.kv, self._pt_dev,
-                             self._sl_dev, toks, self._rng,
-                             self._temps_dev, idx, k)
-        self._start_fetch(all_toks)
-        self._pending.append((all_toks, snapshot, k))
-        self.stats["steps"] += k
-        self.stats["attn_decode_dispatches"] += 1
-        if self._prof.enabled:
-            self._prof.record("decode_dispatch", time.perf_counter() - t0)
+        self._block_seq = seq = self._block_seq + 1
+        # decode_dispatch times the HOST cost of getting the block onto
+        # the device stream (patch flush + jit dispatch); the result sync
+        # is the harvest phase. The pipeline-trim harvest below is
+        # excluded — it's already sampled inside _harvest_one.
+        with self._prof.span("decode_dispatch", seq=seq, k=k, w=w,
+                             active=len(active_slots),
+                             ctx_tokens=ctx_tokens):
+            toks = self._flush_slot_patches(dirty, overrides)
+            trash = self.cfg.max_batch_size
+            idx = jnp.asarray(
+                active_slots + [trash] * (w - len(active_slots)), jnp.int32)
+            snapshot = [(col, slot, req)
+                        for col, (_c, slot, req) in enumerate(snapshot)]
+            with self._prof.compile_scope(
+                    "decode", ("decode", w, k),
+                    mid_traffic=self.stats["requests"] > 0):
+                all_toks, self._dev_tokens, self.kv, self._sl_dev, \
+                    self._rng = self._decode(
+                        self.params, self.kv, self._pt_dev, self._sl_dev,
+                        toks, self._rng, self._temps_dev, idx, k)
+            self._start_fetch(all_toks)
+            self._pending.append((all_toks, snapshot, k, seq))
+            self.stats["steps"] += k
+            self.stats["attn_decode_dispatches"] += 1
         if len(self._pending) > self.PIPELINE_DEPTH:
             self._harvest_one()
         return True
@@ -2114,31 +2148,31 @@ class LLMEngine:
                 req.dispatched += k + 1
             dirty, self._dirty_slots = self._dirty_slots, {}
             overrides, self._overrides = self._overrides, {}
-        t0 = time.perf_counter() if self._prof.enabled else 0.0
-        toks = self._flush_slot_patches(dirty, overrides)
         spec_slots = [slot for slot, _r, _d, _b in rows]
         w = self._bucket_width(len(spec_slots))
-        trash = self.cfg.max_batch_size
-        idx = jnp.asarray(
-            spec_slots + [trash] * (w - len(spec_slots)), jnp.int32)
-        draft_mat = np.full((w, k), -1, np.int32)
-        entry = []  # (col, slot, req, draft, base_len)
-        for col, (slot, req, draft, base_len) in enumerate(rows):
-            draft_mat[col, : len(draft)] = draft
-            entry.append((col, slot, req, draft, base_len))
-        with self._prof.compile_scope(
-                "verify", ("verify", w, k),
-                mid_traffic=self.stats["requests"] > 0):
-            all_toks, self._dev_tokens, self.kv, self._sl_dev, self._rng = \
-                self._verify(self.params, self.kv, self._pt_dev,
-                             self._sl_dev, toks, self._rng,
-                             self._temps_dev, idx, jnp.asarray(draft_mat))
-        self._start_fetch(all_toks)
-        self._pending.append((all_toks, entry, ("spec", k)))
-        self.stats["steps"] += k + 1
-        self.stats["attn_verify_dispatches"] += 1
-        if self._prof.enabled:
-            self._prof.record("verify_dispatch", time.perf_counter() - t0)
+        self._block_seq = seq = self._block_seq + 1
+        with self._prof.span("verify_dispatch", seq=seq, k=k, w=w):
+            toks = self._flush_slot_patches(dirty, overrides)
+            trash = self.cfg.max_batch_size
+            idx = jnp.asarray(
+                spec_slots + [trash] * (w - len(spec_slots)), jnp.int32)
+            draft_mat = np.full((w, k), -1, np.int32)
+            entry = []  # (col, slot, req, draft, base_len)
+            for col, (slot, req, draft, base_len) in enumerate(rows):
+                draft_mat[col, : len(draft)] = draft
+                entry.append((col, slot, req, draft, base_len))
+            with self._prof.compile_scope(
+                    "verify", ("verify", w, k),
+                    mid_traffic=self.stats["requests"] > 0):
+                all_toks, self._dev_tokens, self.kv, self._sl_dev, \
+                    self._rng = self._verify(
+                        self.params, self.kv, self._pt_dev, self._sl_dev,
+                        toks, self._rng, self._temps_dev, idx,
+                        jnp.asarray(draft_mat))
+            self._start_fetch(all_toks)
+            self._pending.append((all_toks, entry, ("spec", k), seq))
+            self.stats["steps"] += k + 1
+            self.stats["attn_verify_dispatches"] += 1
 
     def _spec_step(self) -> bool:
         """TRANSITION decode-mode slots with drafts into verify rounds.
@@ -2195,7 +2229,7 @@ class LLMEngine:
         self._dispatch_verify(rows)
         return True
 
-    def _apply_verify(self, dev_toks, rows, k: int) -> None:
+    def _apply_verify(self, dev_toks, rows, k: int, seq: int) -> None:
         """Record a verify round: per slot, accept the longest draft
         prefix matching the per-position outputs, emit accepted+1 tokens
         through _record_token (stream ordering unchanged), and roll the
@@ -2208,16 +2242,23 @@ class LLMEngine:
         Slots whose fresh context drafts again chain straight into the
         next verify round (their just-harvested host state is exact — no
         pipeline drain needed); the rest drop back to decode blocks."""
-        from ray_tpu.serve.llm import spec_decode
-        if self._prof.enabled:
-            t0 = time.perf_counter()
+        with self._prof.span("harvest", seq=seq, k=k + 1):
             host = np.asarray(dev_toks)  # device sync (oldest round)
-            self._prof.record("harvest", time.perf_counter() - t0)
-            host = host.reshape(k + 1, -1)
-        else:
-            host = np.asarray(dev_toks).reshape(k + 1, -1)
+        host = host.reshape(k + 1, -1)
+        with self._prof.span("emit", seq=seq) as sp:
+            chain, tokens, finished = self._emit_verified(host, rows, k)
+            sp.set(tokens=tokens, finished=finished)
+        if chain:
+            self._dispatch_verify(chain)
+
+    def _emit_verified(self, host, rows, k: int) -> tuple[list, int, int]:
+        """The host half of a verify round (see _apply_verify): returns
+        the rows that chain into the next round, the tokens emitted and
+        the requests finished."""
+        from ray_tpu.serve.llm import spec_decode
         finished: list[_Request] = []
         chain = []  # (slot, req, draft, base_len)
+        tokens = 0
         with self._lock:
             self.stats["spec_rounds"] += 1
             for col, slot, req, draft, base_len in rows:
@@ -2232,6 +2273,7 @@ class LLMEngine:
                         break  # stop token inside the accepted run
                     self._record_token(req, tok)
                     emitted += 1
+                tokens += emitted
                 if req.done:
                     finished.append(req)
                     if self.slot_req[slot] is req:
@@ -2252,9 +2294,8 @@ class LLMEngine:
                 nxt = self._propose_locked(req)
                 if nxt:
                     chain.append((slot, req, nxt, new_len))
-        if chain:
-            self._dispatch_verify(chain)
         self._finish_requests(finished)
+        return chain, tokens, len(finished)
 
     def _harvest_one(self) -> None:
         """Block on the OLDEST in-flight block's tokens and record them.
@@ -2268,39 +2309,42 @@ class LLMEngine:
         with self._lock:
             if not self._pending:
                 return
-            dev_toks, snapshot, k = self._pending.pop(0)
+            dev_toks, snapshot, k, seq = self._pending.pop(0)
         if isinstance(k, tuple):  # ("spec", draft_len) verify round
-            self._apply_verify(dev_toks, snapshot, k[1])
+            self._apply_verify(dev_toks, snapshot, k[1], seq)
             return
-        if self._prof.enabled:
-            # THE device sync: all device slowness (or a fetch that wasn't
-            # prefetched) surfaces here, attributed as "harvest" instead
-            # of smeared across the loop
-            t0 = time.perf_counter()
+        # THE device sync: all device slowness (or a fetch that wasn't
+        # prefetched) surfaces here, attributed as "harvest" instead of
+        # smeared across the loop
+        with self._prof.span("harvest", seq=seq, k=k):
             host_toks = np.asarray(dev_toks)  # sync point: oldest block only
-            self._prof.record("harvest", time.perf_counter() - t0)
-        else:
-            host_toks = np.asarray(dev_toks)
         host_toks = host_toks.reshape(k, -1)
-        finished: list[_Request] = []
-        with self._lock:
-            for step in range(k):
-                for col, slot, req in snapshot:
-                    if req.done:
-                        continue  # stop/max lag: discard overshoot tokens
-                    self._record_token(req, int(host_toks[step, col]))
-                    if req.done:
-                        finished.append(req)
-                        if self.slot_req[slot] is req:
-                            self.slot_req[slot] = None
-                            self.free_slots.append(slot)
-                            self.page_tables[slot] = 0
-                            self.seq_lens[slot] = 0
-                            # invalidate the DEVICE row too: a stale device
-                            # page table keeps scattering this slot's junk
-                            # KV into pages after they're reallocated
-                            self._dirty_slots[slot] = (0, 0.0)
-        self._finish_requests(finished)
+        # emit: what follows the sync on the host — up to k x w
+        # _record_token calls under the lock, then the completion tail
+        with self._prof.span("emit", seq=seq) as sp:
+            finished: list[_Request] = []
+            tokens = 0
+            with self._lock:
+                for step in range(k):
+                    for col, slot, req in snapshot:
+                        if req.done:
+                            continue  # stop/max lag: discard overshoot
+                        self._record_token(req, int(host_toks[step, col]))
+                        tokens += 1
+                        if req.done:
+                            finished.append(req)
+                            if self.slot_req[slot] is req:
+                                self.slot_req[slot] = None
+                                self.free_slots.append(slot)
+                                self.page_tables[slot] = 0
+                                self.seq_lens[slot] = 0
+                                # invalidate the DEVICE row too: a stale
+                                # device page table keeps scattering this
+                                # slot's junk KV into pages after they're
+                                # reallocated
+                                self._dirty_slots[slot] = (0, 0.0)
+            self._finish_requests(finished)
+            sp.set(tokens=tokens, finished=len(finished))
 
     def _finish_requests(self, finished: list[_Request]) -> None:
         """Completion tail shared by decode and verify harvests: free

@@ -418,6 +418,44 @@ class PageAllocator:
 # ---------------------------------------------------------------------------
 
 
+# The paged steps below share these pieces of the transformer block. Each
+# sits under a jax.named_scope so that a profiler trace says which layer
+# an op belongs to (`norm`, `attn`, `kv_write` = the page-pool update
+# only, `mlp`, `embed`, `lm_head`, `sample`); the scopes are compile-time
+# metadata and change no executable.
+
+def _qkv(x, layer, cos, sin, cfg: LlamaConfig):
+    """Pre-attention norm, the q/k/v projections and RoPE. x: [B,T,D]."""
+    with jax.named_scope("norm"):
+        h = rms_norm(x, layer["attn_norm"], cfg.norm_eps)
+    with jax.named_scope("attn"):
+        q = jnp.einsum("btd,dhk->bthk", h, layer["attn"]["wq"])
+        k = jnp.einsum("btd,dhk->bthk", h, layer["attn"]["wk"])
+        v = jnp.einsum("btd,dhk->bthk", h, layer["attn"]["wv"])
+        return apply_rope(q, cos, sin), apply_rope(k, cos, sin), v
+
+
+def _mlp(x, layer, cfg: LlamaConfig):
+    """x + SwiGLU(norm(x))."""
+    with jax.named_scope("norm"):
+        h2 = rms_norm(x, layer["mlp_norm"], cfg.norm_eps)
+    with jax.named_scope("mlp"):
+        gate = jax.nn.silu(h2 @ layer["mlp"]["w_gate"])
+        up = h2 @ layer["mlp"]["w_up"]
+        return x + (gate * up) @ layer["mlp"]["w_down"]
+
+
+def _final_norm(x, params, cfg: LlamaConfig):
+    with jax.named_scope("norm"):
+        return rms_norm(x, params["final_norm"], cfg.norm_eps)
+
+
+def _lm_head(x, params):
+    """The output projection, float32 logits."""
+    with jax.named_scope("lm_head"):
+        return (x @ params["lm_head"]).astype(jnp.float32)
+
+
 def _write_token_kv(k_cache, v_cache, k_new, v_new, page_idx, offset):
     """Scatter one token's k/v per slot into the layer's page pool.
 
@@ -554,8 +592,9 @@ def paged_decode_step(params, kv, page_tables, seq_lens, tokens,
     carry seq_lens pointing at trash-page positions; their logits are junk
     and the engine ignores them.
     """
-    x = params["embed"][tokens[:, None]].astype(cfg.dtype)       # [B,1,D]
-    cos, sin = rope_freqs(cfg, seq_lens[:, None])                # position = len
+    with jax.named_scope("embed"):
+        x = params["embed"][tokens[:, None]].astype(cfg.dtype)   # [B,1,D]
+        cos, sin = rope_freqs(cfg, seq_lens[:, None])           # position = len
     pos = seq_lens
     page_idx = jnp.take_along_axis(
         page_tables, (pos // page_size)[:, None], axis=1)[:, 0]  # [B]
@@ -564,31 +603,25 @@ def paged_decode_step(params, kv, page_tables, seq_lens, tokens,
     def body(carry, inputs):
         (x,) = carry
         layer, k_cache, v_cache = inputs
-        h = rms_norm(x, layer["attn_norm"], cfg.norm_eps)
-        q = jnp.einsum("btd,dhk->bthk", h, layer["attn"]["wq"])
-        k = jnp.einsum("btd,dhk->bthk", h, layer["attn"]["wk"])
-        v = jnp.einsum("btd,dhk->bthk", h, layer["attn"]["wv"])
-        q = apply_rope(q, cos, sin)
-        k = apply_rope(k, cos, sin)
-        k_cache, v_cache = _write_token_kv(
-            k_cache, v_cache, k[:, 0], v[:, 0], page_idx, offset)
-        attn = _decode_attention(
-            q[:, 0], k_cache, v_cache, page_tables, pos, cfg,
-            page_size, attn_backend, mesh)                        # [B,H,D]
-        x = x + jnp.einsum("bhk,hkd->bd", attn, layer["attn"]["wo"])[:, None]
-        h2 = rms_norm(x, layer["mlp_norm"], cfg.norm_eps)
-        gate = jax.nn.silu(h2 @ layer["mlp"]["w_gate"])
-        up = h2 @ layer["mlp"]["w_up"]
-        x = x + (gate * up) @ layer["mlp"]["w_down"]
-        return (x,), (k_cache, v_cache)
+        q, k, v = _qkv(x, layer, cos, sin, cfg)
+        with jax.named_scope("kv_write"):
+            k_cache, v_cache = _write_token_kv(
+                k_cache, v_cache, k[:, 0], v[:, 0], page_idx, offset)
+        with jax.named_scope("attn"):
+            attn = _decode_attention(
+                q[:, 0], k_cache, v_cache, page_tables, pos, cfg,
+                page_size, attn_backend, mesh)                    # [B,H,D]
+            x = x + jnp.einsum(
+                "bhk,hkd->bd", attn, layer["attn"]["wo"])[:, None]
+        return (_mlp(x, layer, cfg),), (k_cache, v_cache)
 
     (x,), (new_k, new_v) = jax.lax.scan(
         body, (x,), (params["layers"], kv["k"], kv["v"]))
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = (x[:, 0] @ params["lm_head"]).astype(jnp.float32)
-    return logits, {"k": new_k, "v": new_v}, seq_lens + 1
+    x = _final_norm(x, params, cfg)
+    return _lm_head(x[:, 0], params), {"k": new_k, "v": new_v}, seq_lens + 1
 
 
+@jax.named_scope("verify")
 def paged_verify_step(params, kv, page_tables, seq_lens, tokens,
                       cfg: LlamaConfig, page_size: int,
                       attn_backend: str = "gather", mesh=None):
@@ -616,9 +649,10 @@ def paged_verify_step(params, kv, page_tables, seq_lens, tokens,
     max_pages = page_tables.shape[1]
     max_len = max_pages * page_size
 
-    x = params["embed"][tokens].astype(cfg.dtype)                 # [B,T,D]
     pos = seq_lens[:, None] + jnp.arange(t)[None, :]              # [B,T]
-    cos, sin = rope_freqs(cfg, pos)
+    with jax.named_scope("embed"):
+        x = params["embed"][tokens].astype(cfg.dtype)             # [B,T,D]
+        cos, sin = rope_freqs(cfg, pos)
     page_idx = jnp.take_along_axis(page_tables, pos // page_size,
                                    axis=1)                        # [B,T]
     offset = pos % page_size
@@ -631,63 +665,56 @@ def paged_verify_step(params, kv, page_tables, seq_lens, tokens,
     def body(carry, inputs):
         (x,) = carry
         layer, k_cache, v_cache = inputs
-        h = rms_norm(x, layer["attn_norm"], cfg.norm_eps)
-        q = jnp.einsum("btd,dhk->bthk", h, layer["attn"]["wq"])
-        k = jnp.einsum("btd,dhk->bthk", h, layer["attn"]["wk"])
-        v = jnp.einsum("btd,dhk->bthk", h, layer["attn"]["wv"])
-        q = apply_rope(q, cos, sin)
-        k = apply_rope(k, cos, sin)
-        # write all T tokens' k/v, then attend through the paged view —
-        # same write-then-gather shape as paged_prefill_chunk, batched.
-        # Distinct slots write distinct pages and distinct t distinct
-        # offsets, so the scatter is conflict-free for real slots.
-        k_cache = k_cache.at[:, page_idx, offset].set(
-            jnp.moveaxis(k, 2, 0).astype(k_cache.dtype))
-        v_cache = v_cache.at[:, page_idx, offset].set(
-            jnp.moveaxis(v, 2, 0).astype(v_cache.dtype))
-        if attn_backend == "pallas":
-            from ray_tpu.ops import paged_attention as paged_ops
+        q, k, v = _qkv(x, layer, cos, sin, cfg)
+        with jax.named_scope("kv_write"):
+            # write all T tokens' k/v, then attend through the paged view —
+            # same write-then-gather shape as paged_prefill_chunk, batched.
+            # Distinct slots write distinct pages and distinct t distinct
+            # offsets, so the scatter is conflict-free for real slots.
+            k_cache = k_cache.at[:, page_idx, offset].set(
+                jnp.moveaxis(k, 2, 0).astype(k_cache.dtype))
+            v_cache = v_cache.at[:, page_idx, offset].set(
+                jnp.moveaxis(v, 2, 0).astype(v_cache.dtype))
+        with jax.named_scope("attn"):
+            if attn_backend == "pallas":
+                from ray_tpu.ops import paged_attention as paged_ops
 
-            def kernel(q, k_cache, v_cache, page_tables, seq_lens):
-                return paged_ops.paged_verify_attention(
-                    q, k_cache, v_cache, page_tables, seq_lens,
-                    sm_scale=sm)
+                def kernel(q, k_cache, v_cache, page_tables, seq_lens):
+                    return paged_ops.paged_verify_attention(
+                        q, k_cache, v_cache, page_tables, seq_lens,
+                        sm_scale=sm)
 
-            if tp_degree(mesh) > 1:
-                in_specs, out_spec = paged_ops.tp_shard_specs(
-                    q_rank=4, n_replicated=2)
-                attn = _tp_pallas(kernel, mesh, in_specs, out_spec)(
-                    q, k_cache, v_cache, page_tables, seq_lens)
+                if tp_degree(mesh) > 1:
+                    in_specs, out_spec = paged_ops.tp_shard_specs(
+                        q_rank=4, n_replicated=2)
+                    attn = _tp_pallas(kernel, mesh, in_specs, out_spec)(
+                        q, k_cache, v_cache, page_tables, seq_lens)
+                else:
+                    attn = kernel(q, k_cache, v_cache, page_tables, seq_lens)
             else:
-                attn = kernel(q, k_cache, v_cache, page_tables, seq_lens)
-        else:
-            k_seq = jnp.moveaxis(
-                jnp.take(k_cache, page_tables, axis=1), 0, 3).reshape(
-                b, max_len, cfg.n_kv_heads, cfg.head_dim)
-            v_seq = jnp.moveaxis(
-                jnp.take(v_cache, page_tables, axis=1), 0, 3).reshape(
-                b, max_len, cfg.n_kv_heads, cfg.head_dim)
-            k_full = _gqa_expand(k_seq, n_rep)
-            v_full = _gqa_expand(v_seq, n_rep)
-            logits = jnp.einsum("bqhd,bkhd->bhqk", q, k_full).astype(
-                jnp.float32) * sm
-            logits = jnp.where(valid[:, None], logits, -1e30)
-            p = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
-            attn = jnp.einsum("bhqk,bkhd->bqhd", p, v_full)
-        x = x + jnp.einsum("bthk,hkd->btd", attn, layer["attn"]["wo"])
-        h2 = rms_norm(x, layer["mlp_norm"], cfg.norm_eps)
-        gate = jax.nn.silu(h2 @ layer["mlp"]["w_gate"])
-        up = h2 @ layer["mlp"]["w_up"]
-        x = x + (gate * up) @ layer["mlp"]["w_down"]
-        return (x,), (k_cache, v_cache)
+                k_seq = jnp.moveaxis(
+                    jnp.take(k_cache, page_tables, axis=1), 0, 3).reshape(
+                    b, max_len, cfg.n_kv_heads, cfg.head_dim)
+                v_seq = jnp.moveaxis(
+                    jnp.take(v_cache, page_tables, axis=1), 0, 3).reshape(
+                    b, max_len, cfg.n_kv_heads, cfg.head_dim)
+                k_full = _gqa_expand(k_seq, n_rep)
+                v_full = _gqa_expand(v_seq, n_rep)
+                logits = jnp.einsum("bqhd,bkhd->bhqk", q, k_full).astype(
+                    jnp.float32) * sm
+                logits = jnp.where(valid[:, None], logits, -1e30)
+                p = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
+                attn = jnp.einsum("bhqk,bkhd->bqhd", p, v_full)
+            x = x + jnp.einsum("bthk,hkd->btd", attn, layer["attn"]["wo"])
+        return (_mlp(x, layer, cfg),), (k_cache, v_cache)
 
     (x,), (new_k, new_v) = jax.lax.scan(
         body, (x,), (params["layers"], kv["k"], kv["v"]))
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = (x @ params["lm_head"]).astype(jnp.float32)          # [B,T,V]
+    logits = _lm_head(_final_norm(x, params, cfg), params)        # [B,T,V]
     return logits, {"k": new_k, "v": new_v}, seq_lens + t
 
 
+@jax.named_scope("prefill")
 def paged_prefill(params, kv, page_table, tokens, true_len,
                   cfg: LlamaConfig, page_size: int):
     """Prefill ONE slot's prompt into its pages.
@@ -698,9 +725,9 @@ def paged_prefill(params, kv, page_table, tokens, true_len,
     page via index clamping, so junk never lands in real pages.
     """
     t = tokens.shape[1]
-    x = params["embed"][tokens].astype(cfg.dtype)                 # [1,T,D]
-    positions = jnp.arange(t)[None, :]
-    cos, sin = rope_freqs(cfg, positions)
+    with jax.named_scope("embed"):
+        x = params["embed"][tokens].astype(cfg.dtype)             # [1,T,D]
+        cos, sin = rope_freqs(cfg, jnp.arange(t)[None, :])
     pos = jnp.arange(t)
     in_range = pos < true_len
     page_idx = jnp.where(in_range, jnp.take(page_table, pos // page_size), 0)
@@ -713,42 +740,37 @@ def paged_prefill(params, kv, page_table, tokens, true_len,
     def body(carry, inputs):
         (x,) = carry
         layer, k_cache, v_cache = inputs
-        h = rms_norm(x, layer["attn_norm"], cfg.norm_eps)
-        q = jnp.einsum("btd,dhk->bthk", h, layer["attn"]["wq"])
-        k = jnp.einsum("btd,dhk->bthk", h, layer["attn"]["wk"])
-        v = jnp.einsum("btd,dhk->bthk", h, layer["attn"]["wv"])
-        q = apply_rope(q, cos, sin)
-        k = apply_rope(k, cos, sin)
-        # dense causal attention within the prompt (prefill is compute-bound
-        # and contiguous — no need to read back through pages)
-        k_full = _gqa_expand(k, n_rep)
-        v_full = _gqa_expand(v, n_rep)
-        logits = jnp.einsum("bqhd,bkhd->bhqk", q, k_full).astype(
-            jnp.float32) * sm
-        logits = jnp.where(causal[None, None], logits, -1e30)
-        p = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
-        attn = jnp.einsum("bhqk,bkhd->bqhd", p, v_full)
-        x = x + jnp.einsum("bthk,hkd->btd", attn, layer["attn"]["wo"])
-        h2 = rms_norm(x, layer["mlp_norm"], cfg.norm_eps)
-        gate = jax.nn.silu(h2 @ layer["mlp"]["w_gate"])
-        up = h2 @ layer["mlp"]["w_up"]
-        x = x + (gate * up) @ layer["mlp"]["w_down"]
-        # scatter the prompt's k/v into this slot's pages
-        k_cache = k_cache.at[:, page_idx, offset].set(
-            jnp.swapaxes(k[0], 0, 1).astype(k_cache.dtype))
-        v_cache = v_cache.at[:, page_idx, offset].set(
-            jnp.swapaxes(v[0], 0, 1).astype(v_cache.dtype))
+        q, k, v = _qkv(x, layer, cos, sin, cfg)
+        with jax.named_scope("attn"):
+            # dense causal attention within the prompt (prefill is
+            # compute-bound and contiguous — no need to read back through
+            # pages)
+            k_full = _gqa_expand(k, n_rep)
+            v_full = _gqa_expand(v, n_rep)
+            logits = jnp.einsum("bqhd,bkhd->bhqk", q, k_full).astype(
+                jnp.float32) * sm
+            logits = jnp.where(causal[None, None], logits, -1e30)
+            p = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
+            attn = jnp.einsum("bhqk,bkhd->bqhd", p, v_full)
+            x = x + jnp.einsum("bthk,hkd->btd", attn, layer["attn"]["wo"])
+        x = _mlp(x, layer, cfg)
+        with jax.named_scope("kv_write"):
+            # scatter the prompt's k/v into this slot's pages
+            k_cache = k_cache.at[:, page_idx, offset].set(
+                jnp.swapaxes(k[0], 0, 1).astype(k_cache.dtype))
+            v_cache = v_cache.at[:, page_idx, offset].set(
+                jnp.swapaxes(v[0], 0, 1).astype(v_cache.dtype))
         return (x,), (k_cache, v_cache)
 
     (x,), (new_k, new_v) = jax.lax.scan(
         body, (x,), (params["layers"], kv["k"], kv["v"]))
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+    x = _final_norm(x, params, cfg)
     last = jnp.take_along_axis(
         x, jnp.maximum(true_len - 1, 0)[None, None, None], axis=1)[:, 0]
-    logits = (last @ params["lm_head"]).astype(jnp.float32)[0]
-    return logits, {"k": new_k, "v": new_v}
+    return _lm_head(last, params)[0], {"k": new_k, "v": new_v}
 
 
+@jax.named_scope("prefill_chunk")
 def paged_prefill_chunk(params, kv, page_table, tokens, start, true_len,
                         cfg: LlamaConfig, page_size: int,
                         attn_backend: str = "gather", mesh=None):
@@ -772,9 +794,10 @@ def paged_prefill_chunk(params, kv, page_table, tokens, start, true_len,
     max_pages = page_table.shape[0]
     max_len = max_pages * page_size
 
-    x = params["embed"][tokens].astype(cfg.dtype)                 # [1,C,D]
     pos = start + jnp.arange(c)                                   # [C]
-    cos, sin = rope_freqs(cfg, pos[None, :])
+    with jax.named_scope("embed"):
+        x = params["embed"][tokens].astype(cfg.dtype)             # [1,C,D]
+        cos, sin = rope_freqs(cfg, pos[None, :])
     in_range = pos < true_len
     page_idx = jnp.where(in_range, jnp.take(page_table, pos // page_size), 0)
     offset = pos % page_size
@@ -787,68 +810,61 @@ def paged_prefill_chunk(params, kv, page_table, tokens, start, true_len,
     def body(carry, inputs):
         (x,) = carry
         layer, k_cache, v_cache = inputs
-        h = rms_norm(x, layer["attn_norm"], cfg.norm_eps)
-        q = jnp.einsum("btd,dhk->bthk", h, layer["attn"]["wq"])
-        k = jnp.einsum("btd,dhk->bthk", h, layer["attn"]["wk"])
-        v = jnp.einsum("btd,dhk->bthk", h, layer["attn"]["wv"])
-        q = apply_rope(q, cos, sin)
-        k = apply_rope(k, cos, sin)
-        # write the chunk's k/v first, then attend through the paged view —
-        # the same write-then-gather shape as the decode fallback, so the
-        # chunk sees earlier chunks AND itself causally. B=1 here, so the
-        # gathered view is small (unlike batched decode, where the
-        # materialized gather is why the Pallas kernel exists).
-        k_cache = k_cache.at[:, page_idx, offset].set(
-            jnp.swapaxes(k[0], 0, 1).astype(k_cache.dtype))
-        v_cache = v_cache.at[:, page_idx, offset].set(
-            jnp.swapaxes(v[0], 0, 1).astype(v_cache.dtype))
-        if attn_backend == "pallas":
-            from ray_tpu.ops import paged_attention as paged_ops
+        q, k, v = _qkv(x, layer, cos, sin, cfg)
+        with jax.named_scope("kv_write"):
+            # write the chunk's k/v first, then attend through the paged view —
+            # the same write-then-gather shape as the decode fallback, so the
+            # chunk sees earlier chunks AND itself causally. B=1 here, so the
+            # gathered view is small (unlike batched decode, where the
+            # materialized gather is why the Pallas kernel exists).
+            k_cache = k_cache.at[:, page_idx, offset].set(
+                jnp.swapaxes(k[0], 0, 1).astype(k_cache.dtype))
+            v_cache = v_cache.at[:, page_idx, offset].set(
+                jnp.swapaxes(v[0], 0, 1).astype(v_cache.dtype))
+        with jax.named_scope("attn"):
+            if attn_backend == "pallas":
+                from ray_tpu.ops import paged_attention as paged_ops
 
-            def kernel(q, k_cache, v_cache, page_table, start, true_len):
-                return paged_ops.paged_chunk_attention(
-                    q, k_cache, v_cache, page_table, start, true_len,
-                    sm_scale=sm)
+                def kernel(q, k_cache, v_cache, page_table, start, true_len):
+                    return paged_ops.paged_chunk_attention(
+                        q, k_cache, v_cache, page_table, start, true_len,
+                        sm_scale=sm)
 
-            if tp_degree(mesh) > 1:
-                in_specs, out_spec = paged_ops.tp_shard_specs(
-                    q_rank=4, n_replicated=3)
-                attn = _tp_pallas(kernel, mesh, in_specs, out_spec)(
-                    q, k_cache, v_cache, page_table, start, true_len)
+                if tp_degree(mesh) > 1:
+                    in_specs, out_spec = paged_ops.tp_shard_specs(
+                        q_rank=4, n_replicated=3)
+                    attn = _tp_pallas(kernel, mesh, in_specs, out_spec)(
+                        q, k_cache, v_cache, page_table, start, true_len)
+                else:
+                    attn = kernel(q, k_cache, v_cache, page_table, start,
+                                  true_len)
             else:
-                attn = kernel(q, k_cache, v_cache, page_table, start,
-                              true_len)
-        else:
-            k_seq = jnp.swapaxes(
-                jnp.take(k_cache, page_table, axis=1).reshape(
-                    cfg.n_kv_heads, max_len, cfg.head_dim), 0, 1)[None]
-            v_seq = jnp.swapaxes(
-                jnp.take(v_cache, page_table, axis=1).reshape(
-                    cfg.n_kv_heads, max_len, cfg.head_dim), 0, 1)[None]
-            k_full = _gqa_expand(k_seq, n_rep)
-            v_full = _gqa_expand(v_seq, n_rep)
-            logits = jnp.einsum("bqhd,bkhd->bhqk", q, k_full).astype(
-                jnp.float32) * sm
-            logits = jnp.where(valid[None, None], logits, -1e30)
-            p = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
-            attn = jnp.einsum("bhqk,bkhd->bqhd", p, v_full)
-        x = x + jnp.einsum("bthk,hkd->btd", attn, layer["attn"]["wo"])
-        h2 = rms_norm(x, layer["mlp_norm"], cfg.norm_eps)
-        gate = jax.nn.silu(h2 @ layer["mlp"]["w_gate"])
-        up = h2 @ layer["mlp"]["w_up"]
-        x = x + (gate * up) @ layer["mlp"]["w_down"]
-        return (x,), (k_cache, v_cache)
+                k_seq = jnp.swapaxes(
+                    jnp.take(k_cache, page_table, axis=1).reshape(
+                        cfg.n_kv_heads, max_len, cfg.head_dim), 0, 1)[None]
+                v_seq = jnp.swapaxes(
+                    jnp.take(v_cache, page_table, axis=1).reshape(
+                        cfg.n_kv_heads, max_len, cfg.head_dim), 0, 1)[None]
+                k_full = _gqa_expand(k_seq, n_rep)
+                v_full = _gqa_expand(v_seq, n_rep)
+                logits = jnp.einsum("bqhd,bkhd->bhqk", q, k_full).astype(
+                    jnp.float32) * sm
+                logits = jnp.where(valid[None, None], logits, -1e30)
+                p = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
+                attn = jnp.einsum("bhqk,bkhd->bqhd", p, v_full)
+            x = x + jnp.einsum("bthk,hkd->btd", attn, layer["attn"]["wo"])
+        return (_mlp(x, layer, cfg),), (k_cache, v_cache)
 
     (x,), (new_k, new_v) = jax.lax.scan(
         body, (x,), (params["layers"], kv["k"], kv["v"]))
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+    x = _final_norm(x, params, cfg)
     # last REAL token's position relative to this chunk's start
     rel = jnp.clip(true_len - 1 - start, 0, c - 1)
     last = jnp.take_along_axis(x, rel[None, None, None], axis=1)[:, 0]
-    logits = (last @ params["lm_head"]).astype(jnp.float32)[0]
-    return logits, {"k": new_k, "v": new_v}
+    return _lm_head(last, params)[0], {"k": new_k, "v": new_v}
 
 
+@jax.named_scope("sample")
 def sample_tokens(logits, rng, temperature, top_k: int = 0):
     """Greedy/temperature/top-k sampling on device. logits: [B, V];
     temperature: [B] (0 → greedy)."""

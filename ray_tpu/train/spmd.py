@@ -155,10 +155,14 @@ def make_train_step(loss_fn: Callable, optimizer: optax.GradientTransformation,
 
     def step(state: TrainState, batch):
         loss, grads = jax.value_and_grad(loss_fn)(state.params, batch)
-        updates, opt_state = optimizer.update(
-            grads, state.opt_state, state.params)
-        params = optax.apply_updates(state.params, updates)
-        gnorm = optax.global_norm(grads)
+        # a profiler trace names the update's device ops by this scope (the
+        # model's own, embed / norm / attn / mlp / lm_head / loss, are in
+        # models/llama.py)
+        with jax.named_scope("optimizer"):
+            updates, opt_state = optimizer.update(
+                grads, state.opt_state, state.params)
+            params = optax.apply_updates(state.params, updates)
+            gnorm = optax.global_norm(grads)
         new = TrainState(params=params, opt_state=opt_state,
                          step=state.step + 1)
         return new, {"loss": loss, "grad_norm": gnorm, "step": new.step}

@@ -157,8 +157,10 @@ def test_capture_round_trip_local(tmp_path):
 
     from ray_tpu.observability import profiling as prof
 
-    logdir = str(tmp_path / "xprof")
-    info = prof.start_capture(logdir)
+    given = str(tmp_path / "xprof")
+    info = prof.start_capture(given)
+    # each process writes under a subdirectory of its own
+    logdir = os.path.join(given, str(os.getpid()))
     assert info["logdir"] == logdir
     assert prof.capture_status()["active"]
     # double-start is refused while a capture is live
@@ -222,6 +224,272 @@ def test_cluster_memory_profile(ray_start_regular, tmp_path):
                for w in (n.get("workers") or {}).values()]
     assert workers
     assert any(isinstance(w, dict) and w.get("ok") for w in workers), out
+
+
+def test_two_captures_in_one_directory_keep_both_files(tmp_path, monkeypatch):
+    """The profiler names its file by host and second, so two workers told
+    to capture into one directory used to overwrite each other's trace.
+    Each process now writes under <logdir>/<pid>."""
+    import glob
+
+    import jax.numpy as jnp
+
+    from ray_tpu.observability import profiling as prof
+
+    given = str(tmp_path / "shared")
+    for pid in (os.getpid(), os.getpid() + 1):   # a second worker
+        monkeypatch.setattr(prof.os, "getpid", lambda pid=pid: pid)
+        info = prof.start_capture(given)
+        assert info["logdir"] == os.path.join(given, str(pid))
+        (jnp.ones((8, 8)) @ jnp.ones((8, 8))).block_until_ready()
+        prof.stop_capture()
+    files = glob.glob(os.path.join(given, "**", "*.xplane.pb"),
+                      recursive=True)
+    assert len(files) == 2
+    assert len({os.path.relpath(f, given).split(os.sep)[0]
+                for f in files}) == 2
+
+
+# ---- spans ------------------------------------------------------------
+
+
+def _host_spans(logdir):
+    """rt/ events of a capture: [(name, start_ns, end_ns, args, line)]."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    out = []
+    for path in glob.glob(os.path.join(logdir, "**", "*.xplane.pb"),
+                          recursive=True):
+        for plane in ProfileData.from_file(path).planes:
+            for li, line in enumerate(plane.lines):
+                for ev in line.events:
+                    if ev.name.startswith("rt/"):
+                        out.append((ev.name, ev.start_ns,
+                                    ev.start_ns + ev.duration_ns,
+                                    dict(ev.stats), (plane.name, li)))
+    return out
+
+
+def test_span_without_capture_makes_no_annotation(monkeypatch):
+    """With no capture active a span is a ring sample, a histogram
+    observation and two additions: no TraceAnnotation is created."""
+    import jax
+
+    from ray_tpu.observability import profiling as prof
+
+    def boom(*a, **kw):
+        raise AssertionError("TraceAnnotation created with no capture")
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", boom)
+    assert not prof.capture_status()["active"]
+    p = prof.EngineProfiler(enabled=True)
+    for i in range(3):
+        with p.span("decode_dispatch", seq=i, k=8) as sp:
+            time.sleep(0.002)
+            sp.set(tokens=1)          # a no-op without a capture
+    st = p.phase_stats()
+    assert st["phase_decode_dispatch_n"] == 3
+    assert st["phase_decode_dispatch_s_total"] >= 0.006
+    assert st["phase_decode_dispatch_p50_ms"] >= 2.0
+    assert st["phase_harvest_n"] == 0 and st["phase_harvest_p50_ms"] is None
+    # disabled and no capture: the shared no-op, nothing recorded
+    off = prof.EngineProfiler(enabled=False)
+    with off.span("harvest", seq=1) as sp:
+        sp.set(tokens=2)
+    assert off.span("harvest") is off.span("emit")
+    assert off.phase_stats()["phase_harvest_n"] == 0
+
+
+def test_capture_holds_engine_spans_with_args(tmp_path):
+    """While a capture is active the loop's spans land in the profiler's
+    host plane as rt/<phase> with their arguments, on one thread, nested
+    under rt/loop_pass; a block's seq is the same in its dispatch and its
+    harvest."""
+    from ray_tpu.observability import profiling as prof
+
+    eng = _mk_engine()
+    try:
+        eng.generate("warm the programs up first", max_tokens=4)
+        info = prof.start_capture(str(tmp_path / "xprof"))
+        eng.generate("the quick brown fox jumps over", max_tokens=8)
+        eng.generate("a second request for good measure", max_tokens=8)
+        time.sleep(0.12)              # a loop_wait or two
+        prof.stop_capture()
+    finally:
+        eng.shutdown()
+    spans = _host_spans(info["logdir"])
+    names = {n for n, *_ in spans}
+    assert {"rt/loop_pass", "rt/admit", "rt/prefill", "rt/decode_dispatch",
+            "rt/patch_flush", "rt/harvest", "rt/emit",
+            "rt/loop_wait"} <= names, names
+    assert len({line for *_x, line in spans}) == 1   # the loop thread
+    disp = [a for n, _s, _e, a, _l in spans if n == "rt/decode_dispatch"]
+    assert disp and all(
+        {"seq", "k", "w", "active", "ctx_tokens"} <= set(a) for a in disp)
+    assert all(a["active"] >= 1 and a["ctx_tokens"] >= a["active"]
+               and a["w"] >= a["active"] for a in disp)
+    harvested = {a["seq"]: a["k"] for n, _s, _e, a, _l in spans
+                 if n == "rt/harvest" and a["seq"] >= 0}
+    matched = [a for a in disp if a["seq"] in harvested]
+    assert matched and all(harvested[a["seq"]] == a["k"] for a in matched)
+    pre = [a for n, _s, _e, a, _l in spans if n == "rt/prefill"]
+    assert pre and all(a["tokens"] <= a["bucket"] and a["rid"] for a in pre)
+    emit = [a for n, _s, _e, a, _l in spans if n == "rt/emit"]
+    assert sum(a["tokens"] for a in emit) >= 8
+    # nesting by containment: every other span lies inside a loop_pass
+    passes = [(s, e) for n, s, e, _a, _l in spans if n == "rt/loop_pass"]
+    first, last = min(s for s, _ in passes), max(e for _, e in passes)
+    for n, s, e, _a, _l in spans:
+        if n != "rt/loop_pass" and first <= s and e <= last:
+            assert any(ps <= s and e <= pe for ps, pe in passes), n
+    # patch_flush is a child of a dispatch
+    dis = [(s, e) for n, s, e, _a, _l in spans
+           if n in ("rt/decode_dispatch", "rt/verify_dispatch")]
+    for n, s, e, _a, _l in spans:
+        if n == "rt/patch_flush" and first <= s and e <= last:
+            assert any(ds <= s and e <= de for ds, de in dis)
+
+
+def test_phase_totals_monotone_and_add_up_to_the_loops_wall():
+    """phase_<p>_s_total / _n only grow; over an interval the loop_pass
+    delta is the loop's wall time, and the spans directly under it
+    account for nearly all of it."""
+    from ray_tpu.observability.profiling import PHASES
+
+    eng = _mk_engine()
+    try:
+        eng.generate("get every program compiled", max_tokens=8)
+        t0, a = time.perf_counter(), eng.engine_stats()
+        for i in range(4):
+            eng.generate(f"request number {i} of the interval", max_tokens=8)
+        time.sleep(0.5)               # and an idle stretch
+        t1, b = time.perf_counter(), eng.engine_stats()
+    finally:
+        eng.shutdown()
+    for p in PHASES:
+        assert b[f"phase_{p}_s_total"] >= a[f"phase_{p}_s_total"] >= 0.0, p
+        assert b[f"phase_{p}_n"] >= a[f"phase_{p}_n"] >= 0, p
+    delta = {p: b[f"phase_{p}_s_total"] - a[f"phase_{p}_s_total"]
+             for p in PHASES}
+    wall = t1 - t0
+    # a pass under way at either reading (a 50 ms wait at most) is the slack
+    assert abs(delta["loop_pass"] - wall) <= 0.06 + 0.02 * wall, (delta, wall)
+    direct = sum(delta[p] for p in (
+        "admit", "restore", "chunk_prefill", "decode_dispatch",
+        "verify_dispatch", "harvest", "emit", "kv_tier_flush", "loop_wait"))
+    assert 0.9 * delta["loop_pass"] - 0.06 <= direct \
+        <= delta["loop_pass"] + 0.06, (delta, wall)
+    assert delta["loop_wait"] > 0.3 and b["phase_emit_n"] > a["phase_emit_n"]
+
+
+# ---- names on device work ----------------------------------------------
+
+
+def _scopes_and_kernels(fn, *args):
+    """(scope names in the lowered text's locations, names of the Pallas
+    kernels in the traced program)."""
+    import jax
+
+    text = jax.jit(fn).lower(*args).as_text(debug_info=True)
+    scopes = set()
+    for loc in re.findall(r'loc\("([^"]+)"', text):
+        # under autodiff a scope reads jvp(attn) or transpose(jvp(attn))
+        scopes.update(re.sub(r"^(?:\w+\()+|\)+$", "", part)
+                      for part in loc.split("/"))
+
+    def kernels(jaxpr, out):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                out.add(eqn.params["name"])
+            for v in eqn.params.values():
+                for j in (v if isinstance(v, (list, tuple)) else [v]):
+                    inner = getattr(j, "jaxpr", j)
+                    if hasattr(inner, "eqns"):
+                        kernels(inner, out)
+        return out
+
+    return scopes, kernels(jax.make_jaxpr(fn)(*args).jaxpr, set())
+
+
+LAYER_SCOPES = {"embed", "norm", "attn", "kv_write", "mlp", "lm_head"}
+
+
+def _paged_engine(**kw):
+    from ray_tpu.serve.llm import LLMEngine
+
+    return LLMEngine(_tiny_cfg(attention_kernel="pallas",
+                               warmup_compile=False, **kw))
+
+
+def test_decode_program_carries_scope_and_kernel_names():
+    import jax.numpy as jnp
+
+    eng = _paged_engine()
+    idx = jnp.arange(4, dtype=jnp.int32)
+    scopes, kernels = _scopes_and_kernels(
+        lambda *a: eng._decode_impl(*a, 2), eng.params, eng.kv, eng._pt_dev,
+        eng._sl_dev, jnp.zeros((5,), jnp.int32), eng._rng, eng._temps_dev,
+        idx)
+    assert LAYER_SCOPES | {"decode_block", "gather_state", "decode_step",
+                           "scatter_state", "sample"} <= scopes
+    assert kernels == {"paged_decode_attention"}
+    # the jitted functions keep their Python names: the benchmark's
+    # accepted readers find programs as jit__lambda / jit_impl / jit_step
+    assert eng._decode.__name__ == "<lambda>"
+    assert eng._chunk_fn(16).__name__ == "impl"
+    assert eng._prefill_fn(16).__name__ == "impl"
+
+
+def test_prefill_chunk_and_verify_programs_carry_scope_and_kernel_names():
+    import jax.numpy as jnp
+
+    eng = _paged_engine(spec_decode_enabled=True, spec_draft_len=2)
+    mc, ps = eng.model_cfg, eng.cfg.page_size
+    table = jnp.zeros((eng.max_pages_per_seq,), jnp.int32)
+    toks = jnp.zeros((1, 16), jnp.int32)
+    scopes, kernels = _scopes_and_kernels(
+        lambda p, kv: eng._kvc.paged_prefill_chunk(
+            p, kv, table, toks, jnp.int32(16), jnp.int32(30), mc, ps,
+            "pallas"), eng.params, eng.kv)
+    assert LAYER_SCOPES | {"prefill_chunk"} <= scopes
+    assert kernels == {"paged_chunk_attention"}
+    scopes, kernels = _scopes_and_kernels(
+        lambda p, kv: eng._kvc.paged_prefill(
+            p, kv, table, toks, jnp.int32(12), mc, ps), eng.params, eng.kv)
+    assert LAYER_SCOPES | {"prefill"} <= scopes and not kernels
+    idx = jnp.arange(4, dtype=jnp.int32)
+    scopes, kernels = _scopes_and_kernels(
+        eng._verify_impl, eng.params, eng.kv, eng._pt_dev, eng._sl_dev,
+        jnp.zeros((5,), jnp.int32), eng._rng, eng._temps_dev, idx,
+        jnp.zeros((4, 2), jnp.int32))
+    assert LAYER_SCOPES | {"verify", "sample"} <= scopes
+    assert kernels == {"paged_verify_attention"}
+
+
+def test_train_step_carries_scope_and_kernel_names():
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models import llama
+    from ray_tpu.train import spmd
+
+    cfg = llama.llama_tiny(attn_impl="flash", max_seq_len=32)
+    mesh = spmd.make_mesh(1, devices=jax.devices()[:1])
+    opt = spmd.default_optimizer()
+    state, sh = spmd.sharded_create_state(
+        lambda: llama.init_params(jax.random.PRNGKey(0), cfg), opt, mesh,
+        llama.logical_axes(cfg))
+    step = spmd.make_train_step(
+        lambda p, b: llama.loss_fn(p, b, cfg, mesh), opt, mesh, sh)
+    assert step.__name__ == "step"
+    batch = {"tokens": jnp.zeros((2, 33), jnp.int32)}
+    scopes, kernels = _scopes_and_kernels(
+        step.__wrapped__, state, batch)
+    assert {"embed", "norm", "attn", "mlp", "lm_head", "loss",
+            "optimizer"} <= scopes
+    assert kernels == {"flash_fwd", "flash_bwd_dkdv", "flash_bwd_dq"}
 
 
 # ---- README drift guard -----------------------------------------------
