@@ -1,12 +1,14 @@
 """Fused paged-attention kernel family (Pallas TPU) for the serving path.
 
 The serving engine's gather attention materializes each slot's full
-[max_len, Hkv, D] K/V view from the page pool every layer of every step
-(~17 GB/step of HBM traffic for a 1.2B model at B=32 — see
-serve/llm/kv_cache.py). These kernels read the pool pages DIRECTLY via
-the slot page table (scalar-prefetch block index maps, the canonical
-TPU paged-attention pattern): the per-slot view is assembled page by
-page in VMEM scratch, never in HBM.
+[max_len, Hkv, D] K/V view from the page pool every layer of every step.
+These kernels read the pool pages DIRECTLY via the layer index and the
+slot page table (scalar-prefetch block index maps, the canonical TPU
+paged-attention pattern): the per-slot view is assembled page by page in
+VMEM scratch, never in HBM. They take the WHOLE pool
+[L, Hkv, P, page, D] and pick the layer inside the index map, so the
+serving programs (serve/llm/kv_cache.py) can carry the pool through
+their loops in place and never slice a layer out of it.
 
 One core kernel covers the whole family — decode (T=1), multi-query
 speculative verify (T=k+1 causal within the span), and chunked prefill
@@ -32,8 +34,9 @@ ops/attention.py.
 Tensor parallelism (ISSUE 20): a pallas_call is opaque to GSPMD, so on a
 TP mesh the serving engine runs these kernels under ``shard_map`` with
 the canonical per-KV-head partitioning from :func:`tp_shard_specs` —
-pool axis 0 (Hkv) and q's H axis split by the "tensor" mesh axis. The
-kv-major GQA head order above is what makes that split clean: each
+pool axis 1 (Hkv of [L, Hkv, P, page, D]) and q's H axis split by the
+"tensor" mesh axis. The kv-major GQA head order above is what makes that
+split clean: each
 shard's kernel invocation is exactly a single-chip call over Hkv/tp
 kv heads with their n_rep q heads, no kernel-internal changes and no
 in-kernel collectives.
@@ -81,9 +84,10 @@ def tp_shard_specs(q_rank: int, n_replicated: int, axis: str = "tensor"):
     tensor-parallel mesh.
 
     Operand order is the family's wrapper signature: ``(q, k_pages,
-    v_pages, <n_replicated trailing operands>)`` — page tables and scalar
-    position/length operands are replicated. q of rank ``q_rank`` is split
-    on its H axis (second-to-last); the pools on axis 0 (Hkv). Because
+    v_pages, <n_replicated trailing operands>)`` — page tables, scalar
+    position/length operands and the layer index are replicated. q of
+    rank ``q_rank`` is split on its H axis (second-to-last); the
+    layer-indexed pools [L, Hkv, P, page, D] on axis 1 (Hkv). Because
     ``paged_attention`` derives ``hkv``/``n_rep`` from operand shapes and
     splits heads kv-major, each shard's launch is a self-consistent
     single-chip call over its Hkv/tp kv-head groups.
@@ -91,7 +95,8 @@ def tp_shard_specs(q_rank: int, n_replicated: int, axis: str = "tensor"):
     Returns ``(in_specs, out_spec)``; the output follows q's split.
     """
     q_spec = P(*([None] * (q_rank - 2) + [axis, None]))
-    in_specs = (q_spec, P(axis), P(axis)) + (P(),) * n_replicated
+    in_specs = (q_spec, P(None, axis), P(None, axis)) \
+        + (P(),) * n_replicated
     return in_specs, q_spec
 
 
@@ -109,7 +114,7 @@ def _row_tiling(r: int, max_len: int, dtype) -> tuple[int, int]:
     return -(-r // cap) * cap, cap
 
 
-def _paged_attn_kernel(pt_ref, base_ref, limit_ref,     # scalar prefetch
+def _paged_attn_kernel(pt_ref, base_ref, limit_ref, layer_ref,  # prefetch
                        q_ref, k_ref, v_ref, o_ref, k_scr, v_scr, *,
                        sm_scale: float, page_size: int, num_pages: int,
                        t_span: int, row_tile: int):
@@ -120,15 +125,17 @@ def _paged_attn_kernel(pt_ref, base_ref, limit_ref,     # scalar prefetch
     (GQA heads grouped per kv head, query positions innermost — matches
     ``_gqa_expand``'s kv-major head order); rows past n_rep * t_span are
     zero padding the wrapper slices off. k_ref/v_ref: this grid step's
-    pool page [1, 1, page, D], selected by the block index map through the
-    scalar-prefetched page table — the read IS the gather.
+    pool page [page, D] (layer, kv-head and page block dimensions squeezed),
+    selected by the block index map through the scalar-prefetched layer
+    index and page table — the read IS the gather. ``layer_ref`` is read
+    by the index maps only.
     """
     b = pl.program_id(0)
     p = pl.program_id(2)
 
     off = pl.multiple_of(p * page_size, page_size)
-    k_scr[pl.ds(off, page_size)] = k_ref[0, 0]
-    v_scr[pl.ds(off, page_size)] = v_ref[0, 0]
+    k_scr[pl.ds(off, page_size)] = k_ref[...]
+    v_scr[pl.ds(off, page_size)] = v_ref[...]
 
     @pl.when(p == num_pages - 1)
     def _compute():
@@ -159,26 +166,34 @@ def _paged_attn_kernel(pt_ref, base_ref, limit_ref,     # scalar prefetch
         jax.lax.fori_loop(0, q_ref.shape[2] // row_tile, rows, None)
 
 
-def paged_attention(q, k_pages, v_pages, page_tables, base, limit=None, *,
-                    sm_scale: float | None = None,
+def paged_attention(q, k_pages, v_pages, page_tables, base, limit=None,
+                    layer=None, *, sm_scale: float | None = None,
                     interpret: bool | None = None,
                     name: str = "paged_attention"):
     """Fused paged attention over the whole query span.
 
     q: [B, T, H, D] — query position of q[:, t] is ``base + t`` (causal
     within the span, full attention over the paged cache below it).
-    k_pages/v_pages: [Hkv, P, page, D] pool. page_tables: [B, max_pages].
-    base: [B] int32 first-query positions. limit: [B] int32 exclusive key
-    bound (None = the whole table span) — chunked prefill passes
-    ``true_len`` so padded tail pages stay masked. name: the kernel's
-    name in the compiled program and in a profiler trace (each of the
-    three callers below passes its own).
+    k_pages/v_pages: the whole pool [L, Hkv, P, page, D], of which the
+    kernel reads layer ``layer`` (int32 scalar, traced or not): the index
+    rides in as a scalar-prefetch operand and the K/V block index maps
+    pick ``(layer, head, page)``, so the caller never slices ``pool[l]``
+    out (a Pallas operand is a materialised buffer: that slice would be a
+    copy of the layer's pool). A pool with no layer axis [Hkv, P, page, D]
+    (``layer`` None) is read as a one-layer pool. page_tables:
+    [B, max_pages]. base: [B] int32 first-query positions. limit: [B]
+    int32 exclusive key bound (None = the whole table span) — chunked
+    prefill passes ``true_len`` so padded tail pages stay masked. name:
+    the kernel's name in the compiled program and in a profiler trace
+    (each of the three callers below passes its own).
     Returns [B, T, H, D] in q.dtype.
     """
     b, t, h, d = q.shape
-    hkv = k_pages.shape[0]
+    if k_pages.ndim == 4:
+        k_pages, v_pages, layer = k_pages[None], v_pages[None], 0
+    hkv = k_pages.shape[1]
     n_rep = h // hkv
-    page_size = k_pages.shape[2]
+    page_size = k_pages.shape[3]
     max_pages = page_tables.shape[1]
     max_len = max_pages * page_size
     if sm_scale is None:
@@ -210,23 +225,25 @@ def paged_attention(q, k_pages, v_pages, page_tables, base, limit=None, *,
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
+            num_scalar_prefetch=4,
             grid=(b, hkv, max_pages),
             in_specs=[
                 pl.BlockSpec((1, 1, r_pad, d),
-                             lambda bi, hi, pi, pt, bs, lim: (bi, hi, 0, 0)),
-                # the paged read: block index pt[bi, pi] picks the pool
-                # page straight off the scalar-prefetched table
-                pl.BlockSpec((1, 1, page_size, d),
-                             lambda bi, hi, pi, pt, bs, lim:
-                             (hi, pt[bi, pi], 0, 0)),
-                pl.BlockSpec((1, 1, page_size, d),
-                             lambda bi, hi, pi, pt, bs, lim:
-                             (hi, pt[bi, pi], 0, 0)),
+                             lambda bi, hi, pi, pt, bs, lim, lyr:
+                             (bi, hi, 0, 0)),
+                # the paged read: block index (lyr[0], hi, pt[bi, pi])
+                # picks the layer's pool page straight off the
+                # scalar-prefetched layer index and table
+                pl.BlockSpec((None, None, None, page_size, d),
+                             lambda bi, hi, pi, pt, bs, lim, lyr:
+                             (lyr[0], hi, pt[bi, pi], 0, 0)),
+                pl.BlockSpec((None, None, None, page_size, d),
+                             lambda bi, hi, pi, pt, bs, lim, lyr:
+                             (lyr[0], hi, pt[bi, pi], 0, 0)),
             ],
             out_specs=pl.BlockSpec(
                 (1, 1, r_pad, d),
-                lambda bi, hi, pi, pt, bs, lim: (bi, hi, 0, 0)),
+                lambda bi, hi, pi, pt, bs, lim, lyr: (bi, hi, 0, 0)),
             scratch_shapes=[
                 pltpu.VMEM((max_len, d), k_pages.dtype),
                 pltpu.VMEM((max_len, d), v_pages.dtype),
@@ -238,37 +255,40 @@ def paged_attention(q, k_pages, v_pages, page_tables, base, limit=None, *,
         interpret=interpret,
         name=name,
     )(page_tables.astype(jnp.int32), base.astype(jnp.int32),
-      limit.astype(jnp.int32), qg, k_pages, v_pages)
+      limit.astype(jnp.int32), jnp.reshape(layer, (1,)).astype(jnp.int32),
+      qg, k_pages, v_pages)
     return out[:, :, :r].reshape(b, hkv, n_rep, t, d).transpose(
         0, 3, 1, 2, 4).reshape(b, t, h, d)
 
 
-def paged_decode_attention(q, k_pages, v_pages, page_tables, pos, *,
-                           sm_scale: float | None = None,
+def paged_decode_attention(q, k_pages, v_pages, page_tables, pos,
+                           layer=None, *, sm_scale: float | None = None,
                            interpret: bool | None = None):
     """Single-token decode attention: q [B, H, D], new token at position
     ``pos[b]`` (attends 0..pos inclusive — its own k/v is already written
-    to the pool). Returns [B, H, D]."""
+    to the pool). Pool and ``layer`` as in :func:`paged_attention`.
+    Returns [B, H, D]."""
     out = paged_attention(q[:, None], k_pages, v_pages, page_tables, pos,
-                          sm_scale=sm_scale, interpret=interpret,
+                          layer=layer, sm_scale=sm_scale, interpret=interpret,
                           name="paged_decode_attention")
     return out[:, 0]
 
 
-def paged_verify_attention(q, k_pages, v_pages, page_tables, seq_lens, *,
-                           sm_scale: float | None = None,
+def paged_verify_attention(q, k_pages, v_pages, page_tables, seq_lens,
+                           layer=None, *, sm_scale: float | None = None,
                            interpret: bool | None = None):
     """Multi-query speculative verify: q [B, T, H, D], T = k+1 draft span
     per slot, q[b, t] at position ``seq_lens[b] + t`` — causal within the
     span, full attention over the slot's cached pages (all T spans' k/v
     are pre-written). Returns [B, T, H, D]."""
     return paged_attention(q, k_pages, v_pages, page_tables, seq_lens,
-                           sm_scale=sm_scale, interpret=interpret,
+                           layer=layer, sm_scale=sm_scale,
+                           interpret=interpret,
                            name="paged_verify_attention")
 
 
 def paged_chunk_attention(q, k_pages, v_pages, page_table, start, true_len,
-                          *, sm_scale: float | None = None,
+                          layer=None, *, sm_scale: float | None = None,
                           interpret: bool | None = None):
     """Chunked-prefill attention for ONE slot: q [1, C, H, D] chunk whose
     first token sits at position ``start``; keys are the slot's whole
@@ -277,5 +297,6 @@ def paged_chunk_attention(q, k_pages, v_pages, page_table, start, true_len,
     base = jnp.reshape(start, (1,)).astype(jnp.int32)
     limit = jnp.reshape(true_len, (1,)).astype(jnp.int32)
     return paged_attention(q, k_pages, v_pages, page_table[None], base,
-                           limit, sm_scale=sm_scale, interpret=interpret,
+                           limit, layer, sm_scale=sm_scale,
+                           interpret=interpret,
                            name="paged_chunk_attention")

@@ -382,10 +382,15 @@ class LLMEngine:
                 (self._pt_dev, self._sl_dev, self._temps_dev), rep)
         self._dirty_slots: dict[int, tuple] = {}  # slot -> (seq_len, temp)
 
-        # jitted programs. The KV pool is DONATED: it's the dominant HBM
-        # allocation and the step rewrites it in place — without donation
-        # every step would materialize a second full pool (2x HBM + a full
-        # pool copy of bandwidth per token). The decode program gathers the
+        # jitted programs. The KV pool is DONATED, and every paged program
+        # (kv_cache.paged_*) carries it through its loops as a carry that
+        # is only ever scattered into — the scan over layers, and around it
+        # here the scan over a block's steps — so the donated argument, the
+        # loop carries and the returned pool are ONE buffer: no program
+        # copies, slices or restacks the pool, and a program's temporaries
+        # are megabytes beside the pool's gigabytes (tests/test_pool_carry.py
+        # holds the structure). Without donation every dispatch would
+        # materialize a second full pool. The decode program gathers the
         # packed active rows by index on device, runs the fused block at the
         # PACKED width, and scatters the carried state back — one program
         # per (bucket width, block length), so a lightly loaded engine pays

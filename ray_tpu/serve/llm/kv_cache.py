@@ -17,14 +17,22 @@ Design choices:
   slot page table (scalar-prefetch block index maps; no materialized
   gather per layer per step) and reproduce the gather path's dense-softmax
   numerics bit-exactly; ``"gather"`` materializes the full per-slot view
-  + dense softmax (measured 84 ms/step vs a paged kernel's 25 ms for a
-  1.2B model at B=32). Auto resolution picks pallas on TPU (when the
+  + dense softmax. Auto resolution picks pallas on TPU (when the
   kernel's tiling accepts the shapes) and gather elsewhere; an explicit
   "pallas" the kernel cannot tile raises; tests force the pallas backend
   in interpreter mode on CPU;
-- writes are scatters at (page, offset) index pairs; inactive slots write to
-  a reserved trash page (page 0), keeping the step free of dynamic shapes
-  and `lax.cond`s;
+- the pool is LAYER-INDEXED AND CARRIED IN PLACE: inside every paged
+  program the whole pool [n_layers, Hkv, P, page, D] is a carry of the
+  scan over layers (and, in the engine's decode block, of the scan over
+  steps around it), never one of its ``xs``/``ys``. The layer is a dynamic
+  index into the carry — the token write is one scatter of rows at
+  ``[l, h, page, offset]``, the kernels pick the layer in their block
+  index maps, the gather backend gathers ``pool[l, :, page_tables]`` — so
+  no program slices a layer's pool out of the stack, restacks it, or
+  copies the pool to update it (tests/test_pool_carry.py holds that);
+- writes are scatters at (layer, head, page, offset) indices; inactive
+  slots write to a reserved trash page (page 0), keeping the step free of
+  dynamic shapes and `lax.cond`s;
 - full (non-chunked) prefill stays dense within the prompt: it runs at
   B=1 per admission with no cached prefix to read back;
 - tensor parallelism (ISSUE 20): every step function takes an optional
@@ -42,6 +50,7 @@ Page 0 is RESERVED as the trash page; the allocator never hands it out.
 
 from __future__ import annotations
 
+import functools
 import hashlib
 import logging
 import threading
@@ -66,10 +75,12 @@ logger = logging.getLogger(__name__)
 def init_paged_cache(cfg: LlamaConfig, num_pages: int, page_size: int):
     """KV pool: [n_layers, n_kv_heads, num_pages, page_size, head_dim].
 
-    The head-major page layout is what the Pallas paged-attention decode
-    kernel consumes directly (jax.experimental.pallas.ops.tpu.paged_attention
-    — per layer [Hkv, P, page, D]), so decode on TPU runs the kernel with no
-    relayout; the CPU fallback gathers through the same pool."""
+    The head-major page layout is what the Pallas paged-attention kernels
+    (ops/paged_attention.py) consume directly — the whole pool plus a layer
+    index, per layer [Hkv, P, page, D] — so the paged programs run them with
+    no relayout and no per-layer slice; the gather backend indexes the same
+    pool. The paged programs carry both arrays through their loops and only
+    ever update them in place (see the module docstring)."""
     shape = (cfg.n_layers, cfg.n_kv_heads, num_pages, page_size, cfg.head_dim)
     return {"k": jnp.zeros(shape, cfg.dtype), "v": jnp.zeros(shape, cfg.dtype)}
 
@@ -456,18 +467,55 @@ def _lm_head(x, params):
         return (x @ params["lm_head"]).astype(jnp.float32)
 
 
-def _write_token_kv(k_cache, v_cache, k_new, v_new, page_idx, offset):
-    """Scatter one token's k/v per slot into the layer's page pool.
+def _write_token_kv(k_pool, v_pool, layer, k_new, v_new, page_idx, offset):
+    """Scatter new tokens' k/v into layer ``layer`` of the page pool.
 
-    k_cache: [Hkv, P, page, D]; k_new: [B, Hkv, D]; page_idx/offset: [B].
-    Slots write distinct pages (or the shared trash page), so the scatter is
+    k_pool: the whole pool [L, Hkv, P, page, D], a loop carry; layer: int32
+    scalar; page_idx/offset: [...] (decode [B], verify [B, T], prefill
+    [T]); k_new: [..., Hkv, D]. ONE scatter on the 5-D carry, which XLA
+    performs in place (the carry has no other live reader) — never
+    ``pool[layer]`` updated and put back. The scatter's rows are single
+    [D] vectors at ``[layer, h, page_idx, offset]``: with the head among
+    the scattered indices the update window is the pool's minor-most
+    dimension, so the compiler keeps the pool in the row-major layout the
+    kernels read. (A ``[layer, :, page_idx, offset]`` scatter has [Hkv, D]
+    windows, for which the TPU compiler moves Hkv next to D in the carry's
+    layout and copies the whole pool back to row-major for every kernel
+    call.) Distinct slots write distinct pages and distinct positions
+    distinct offsets (or the shared trash page), so the scatter is
     conflict-free for real slots.
     """
-    k_cache = k_cache.at[:, page_idx, offset].set(
-        jnp.swapaxes(k_new, 0, 1).astype(k_cache.dtype))
-    v_cache = v_cache.at[:, page_idx, offset].set(
-        jnp.swapaxes(v_new, 0, 1).astype(v_cache.dtype))
-    return k_cache, v_cache
+    heads = jnp.arange(k_pool.shape[1])
+    idx = (layer, heads, page_idx[..., None], offset[..., None])
+    return (k_pool.at[idx].set(k_new.astype(k_pool.dtype)),
+            v_pool.at[idx].set(v_new.astype(v_pool.dtype)))
+
+
+def _gather_seq(pool, layer, page_tables):
+    """The gather backend's read: layer ``layer`` of the slots' pages as
+    one contiguous sequence. pool: [L, Hkv, P, page, D]; page_tables:
+    [..., MP]. ONE gather on the 5-D pool (no ``pool[layer]`` first).
+    Returns [..., MP * page, Hkv, D]."""
+    pages = pool[layer, :, page_tables]          # [..., MP, Hkv, page, D]
+    hkv, page_size, d = pages.shape[-3:]
+    return jnp.swapaxes(pages, -3, -2).reshape(
+        page_tables.shape[:-1] + (page_tables.shape[-1] * page_size, hkv, d))
+
+
+def _scan_layers(body, x, kv, params):
+    """Run ``body(x, k_pool, v_pool, layer_params, l)`` -> (x, k_pool,
+    v_pool) over the layers with the pool as a CARRY: the only ``xs`` are
+    the layer parameters and the layer index, and there are no ``ys``.
+    Returns (x, new_kv)."""
+    n_layers = kv["k"].shape[0]
+
+    def step(carry, inputs):
+        return body(*carry, *inputs), None
+
+    (x, k_pool, v_pool), _ = jax.lax.scan(
+        step, (x, kv["k"], kv["v"]),
+        (params["layers"], jnp.arange(n_layers, dtype=jnp.int32)))
+    return x, {"k": k_pool, "v": v_pool}
 
 
 def _use_pallas_decode(cfg=None, page_size: int = 0) -> bool:
@@ -519,59 +567,64 @@ def tp_degree(mesh) -> int:
     return int(mesh.shape["tensor"])
 
 
-def _tp_pallas(fn, mesh, in_specs, out_specs):
-    """Wrap a Pallas paged-attention call for a TP mesh: GSPMD cannot
-    partition an opaque pallas_call, so the kernel family runs under
-    ``shard_map`` with the pool split per-KV-head and q split into the
-    matching kv-head groups. check_vma=False: the kernel writes nothing
-    replicated, and rep inference can't see through pallas anyway."""
-    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_vma=False)
+def _dense_attention(q, k, v, mask, sm):
+    """Dense-softmax attention, the numerics every backend reproduces:
+    float32 logits scaled by ``sm``, masked with -1e30, full-row float32
+    softmax, probabilities cast back to q.dtype. q: [B, T, H, D]; k/v:
+    [B, L, Hkv, D]; mask: broadcastable to [B, H, T, L]."""
+    n_rep = q.shape[2] // k.shape[2]
+    k_full = _gqa_expand(k, n_rep)
+    v_full = _gqa_expand(v, n_rep)
+    logits = jnp.einsum("bqhd,bkhd->bhqk", q, k_full).astype(
+        jnp.float32) * sm
+    logits = jnp.where(mask, logits, -1e30)
+    p = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
+    return jnp.einsum("bhqk,bkhd->bqhd", p, v_full)
 
 
-def _decode_attention(q, k_cache, v_cache, page_tables, pos, cfg, page_size,
-                      attn_backend: str = "gather", mesh=None):
+def _paged_kernel(kernel, q, k_pool, v_pool, *rest, sm_scale, mesh=None):
+    """Call one of ops/paged_attention.py's wrappers on the whole pool:
+    ``kernel(q, k_pool, v_pool, *rest)`` where ``rest`` is the wrapper's
+    replicated operands (page tables, positions/lengths, the layer index
+    last).
+
+    On a TP mesh the call runs under ``shard_map`` (GSPMD cannot partition
+    an opaque pallas_call): q's H axis splits into whole kv-head groups
+    (kv-major GQA order) and the pools per KV head, so each shard's kernel
+    sees a self-contained (Hkv/tp heads, n_rep q-heads each) problem — no
+    collective. check_vma=False: the kernel writes nothing replicated, and
+    rep inference can't see through pallas anyway."""
+    from ray_tpu.ops import paged_attention as paged_ops
+
+    call = functools.partial(kernel, sm_scale=sm_scale)
+    if tp_degree(mesh) > 1:
+        in_specs, out_spec = paged_ops.tp_shard_specs(
+            q_rank=q.ndim, n_replicated=len(rest))
+        call = jax.shard_map(call, mesh=mesh, in_specs=in_specs,
+                             out_specs=out_spec, check_vma=False)
+    return call(q, k_pool, v_pool, *rest)
+
+
+def _decode_attention(q, k_pool, v_pool, layer, page_tables, pos, cfg,
+                      page_size, attn_backend: str = "gather", mesh=None):
     """Single-token attention over the paged KV for all slots.
 
-    q: [B, H, D]; k_cache/v_cache: [Hkv, P, page, D]; pos: [B] (the new
-    token's position — attend over 0..pos inclusive). The pallas backend
-    runs the fused paged kernel (ops/paged_attention.py — reads only each
-    sequence's live pages through the page table, same dense-softmax
-    numerics as the gather path); the gather backend materializes the full
-    [B, max_len] view — measured 84 ms/step for a 1.2B model at B=32 on
-    one v5e (~17 GB/step of HBM traffic), which is why the kernel path
-    exists."""
-    b = q.shape[0]
-    max_pages = page_tables.shape[1]
-    max_len = max_pages * page_size
+    q: [B, H, D]; k_pool/v_pool: the whole pool [L, Hkv, P, page, D], read
+    at layer ``layer``; pos: [B] (the new token's position — attend over
+    0..pos inclusive). The pallas backend runs the fused paged kernel
+    (ops/paged_attention.py — reads each sequence's pages through the page
+    table, same dense-softmax numerics as the gather path); the gather
+    backend materializes the full [B, max_len] view."""
+    max_len = page_tables.shape[1] * page_size
+    sm = cfg.head_dim ** -0.5
     if attn_backend == "pallas":
         from ray_tpu.ops import paged_attention as paged_ops
-
-        def kernel(q, k_cache, v_cache, page_tables, pos):
-            return paged_ops.paged_decode_attention(
-                q, k_cache, v_cache, page_tables, pos,
-                sm_scale=cfg.head_dim ** -0.5)
-
-        if tp_degree(mesh) > 1:
-            # q's H axis splits into whole kv-head groups (kv-major GQA
-            # order), so each shard's kernel sees a self-contained
-            # (Hkv/tp heads, n_rep q-heads each) problem — no collective
-            in_specs, out_spec = paged_ops.tp_shard_specs(
-                q_rank=3, n_replicated=2)
-            return _tp_pallas(kernel, mesh, in_specs, out_spec)(
-                q, k_cache, v_cache, page_tables, pos)
-        return kernel(q, k_cache, v_cache, page_tables, pos)
-    n_rep = q.shape[1] // k_cache.shape[0]
-    sm = cfg.head_dim ** -0.5
-    # gather: [Hkv, B, MP, page, D] -> [B, MP, page, Hkv, D] -> [B, L, Hkv, D]
-    k_seq = jnp.moveaxis(
-        jnp.take(k_cache, page_tables, axis=1), 0, 3).reshape(
-        b, max_len, k_cache.shape[0], cfg.head_dim)
-    v_seq = jnp.moveaxis(
-        jnp.take(v_cache, page_tables, axis=1), 0, 3).reshape(
-        b, max_len, v_cache.shape[0], cfg.head_dim)
-    k_full = _gqa_expand(k_seq, n_rep)
-    v_full = _gqa_expand(v_seq, n_rep)
+        return _paged_kernel(
+            paged_ops.paged_decode_attention, q, k_pool, v_pool,
+            page_tables, pos, layer, sm_scale=sm, mesh=mesh)
+    n_rep = q.shape[1] // k_pool.shape[1]
+    k_full = _gqa_expand(_gather_seq(k_pool, layer, page_tables), n_rep)
+    v_full = _gqa_expand(_gather_seq(v_pool, layer, page_tables), n_rep)
     valid = jnp.arange(max_len)[None, :] <= pos[:, None]          # [B, L]
     logits = jnp.einsum("bhd,bkhd->bhk", q, k_full).astype(
         jnp.float32) * sm
@@ -600,25 +653,22 @@ def paged_decode_step(params, kv, page_tables, seq_lens, tokens,
         page_tables, (pos // page_size)[:, None], axis=1)[:, 0]  # [B]
     offset = pos % page_size
 
-    def body(carry, inputs):
-        (x,) = carry
-        layer, k_cache, v_cache = inputs
+    def body(x, k_pool, v_pool, layer, l):
         q, k, v = _qkv(x, layer, cos, sin, cfg)
         with jax.named_scope("kv_write"):
-            k_cache, v_cache = _write_token_kv(
-                k_cache, v_cache, k[:, 0], v[:, 0], page_idx, offset)
+            k_pool, v_pool = _write_token_kv(
+                k_pool, v_pool, l, k[:, 0], v[:, 0], page_idx, offset)
         with jax.named_scope("attn"):
             attn = _decode_attention(
-                q[:, 0], k_cache, v_cache, page_tables, pos, cfg,
+                q[:, 0], k_pool, v_pool, l, page_tables, pos, cfg,
                 page_size, attn_backend, mesh)                    # [B,H,D]
             x = x + jnp.einsum(
                 "bhk,hkd->bd", attn, layer["attn"]["wo"])[:, None]
-        return (_mlp(x, layer, cfg),), (k_cache, v_cache)
+        return _mlp(x, layer, cfg), k_pool, v_pool
 
-    (x,), (new_k, new_v) = jax.lax.scan(
-        body, (x,), (params["layers"], kv["k"], kv["v"]))
+    x, kv = _scan_layers(body, x, kv, params)
     x = _final_norm(x, params, cfg)
-    return _lm_head(x[:, 0], params), {"k": new_k, "v": new_v}, seq_lens + 1
+    return _lm_head(x[:, 0], params), kv, seq_lens + 1
 
 
 @jax.named_scope("verify")
@@ -645,9 +695,8 @@ def paged_verify_step(params, kv, page_tables, seq_lens, tokens,
     traffic, bounded by small T (draft_len+1).
     Returns (logits [B, T, vocab], new_kv, seq_lens + T).
     """
-    b, t = tokens.shape
-    max_pages = page_tables.shape[1]
-    max_len = max_pages * page_size
+    t = tokens.shape[1]
+    max_len = page_tables.shape[1] * page_size
 
     pos = seq_lens[:, None] + jnp.arange(t)[None, :]              # [B,T]
     with jax.named_scope("embed"):
@@ -660,58 +709,30 @@ def paged_verify_step(params, kv, page_tables, seq_lens, tokens,
     # position t sees cache + the span's tokens 0..t (its own write)
     valid = kpos[None, None, :] <= pos[:, :, None]                # [B,T,L]
     sm = cfg.head_dim ** -0.5
-    n_rep = cfg.n_heads // cfg.n_kv_heads
 
-    def body(carry, inputs):
-        (x,) = carry
-        layer, k_cache, v_cache = inputs
+    def body(x, k_pool, v_pool, layer, l):
         q, k, v = _qkv(x, layer, cos, sin, cfg)
         with jax.named_scope("kv_write"):
             # write all T tokens' k/v, then attend through the paged view —
             # same write-then-gather shape as paged_prefill_chunk, batched.
-            # Distinct slots write distinct pages and distinct t distinct
-            # offsets, so the scatter is conflict-free for real slots.
-            k_cache = k_cache.at[:, page_idx, offset].set(
-                jnp.moveaxis(k, 2, 0).astype(k_cache.dtype))
-            v_cache = v_cache.at[:, page_idx, offset].set(
-                jnp.moveaxis(v, 2, 0).astype(v_cache.dtype))
+            k_pool, v_pool = _write_token_kv(
+                k_pool, v_pool, l, k, v, page_idx, offset)
         with jax.named_scope("attn"):
             if attn_backend == "pallas":
                 from ray_tpu.ops import paged_attention as paged_ops
-
-                def kernel(q, k_cache, v_cache, page_tables, seq_lens):
-                    return paged_ops.paged_verify_attention(
-                        q, k_cache, v_cache, page_tables, seq_lens,
-                        sm_scale=sm)
-
-                if tp_degree(mesh) > 1:
-                    in_specs, out_spec = paged_ops.tp_shard_specs(
-                        q_rank=4, n_replicated=2)
-                    attn = _tp_pallas(kernel, mesh, in_specs, out_spec)(
-                        q, k_cache, v_cache, page_tables, seq_lens)
-                else:
-                    attn = kernel(q, k_cache, v_cache, page_tables, seq_lens)
+                attn = _paged_kernel(
+                    paged_ops.paged_verify_attention, q, k_pool, v_pool,
+                    page_tables, seq_lens, l, sm_scale=sm, mesh=mesh)
             else:
-                k_seq = jnp.moveaxis(
-                    jnp.take(k_cache, page_tables, axis=1), 0, 3).reshape(
-                    b, max_len, cfg.n_kv_heads, cfg.head_dim)
-                v_seq = jnp.moveaxis(
-                    jnp.take(v_cache, page_tables, axis=1), 0, 3).reshape(
-                    b, max_len, cfg.n_kv_heads, cfg.head_dim)
-                k_full = _gqa_expand(k_seq, n_rep)
-                v_full = _gqa_expand(v_seq, n_rep)
-                logits = jnp.einsum("bqhd,bkhd->bhqk", q, k_full).astype(
-                    jnp.float32) * sm
-                logits = jnp.where(valid[:, None], logits, -1e30)
-                p = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
-                attn = jnp.einsum("bhqk,bkhd->bqhd", p, v_full)
+                attn = _dense_attention(
+                    q, _gather_seq(k_pool, l, page_tables),
+                    _gather_seq(v_pool, l, page_tables), valid[:, None], sm)
             x = x + jnp.einsum("bthk,hkd->btd", attn, layer["attn"]["wo"])
-        return (_mlp(x, layer, cfg),), (k_cache, v_cache)
+        return _mlp(x, layer, cfg), k_pool, v_pool
 
-    (x,), (new_k, new_v) = jax.lax.scan(
-        body, (x,), (params["layers"], kv["k"], kv["v"]))
+    x, kv = _scan_layers(body, x, kv, params)
     logits = _lm_head(_final_norm(x, params, cfg), params)        # [B,T,V]
-    return logits, {"k": new_k, "v": new_v}, seq_lens + t
+    return logits, kv, seq_lens + t
 
 
 @jax.named_scope("prefill")
@@ -735,39 +756,27 @@ def paged_prefill(params, kv, page_table, tokens, true_len,
     # causal mask for the in-prompt attention
     causal = pos[:, None] >= pos[None, :]
     sm = cfg.head_dim ** -0.5
-    n_rep = cfg.n_heads // cfg.n_kv_heads
 
-    def body(carry, inputs):
-        (x,) = carry
-        layer, k_cache, v_cache = inputs
+    def body(x, k_pool, v_pool, layer, l):
         q, k, v = _qkv(x, layer, cos, sin, cfg)
         with jax.named_scope("attn"):
             # dense causal attention within the prompt (prefill is
             # compute-bound and contiguous — no need to read back through
             # pages)
-            k_full = _gqa_expand(k, n_rep)
-            v_full = _gqa_expand(v, n_rep)
-            logits = jnp.einsum("bqhd,bkhd->bhqk", q, k_full).astype(
-                jnp.float32) * sm
-            logits = jnp.where(causal[None, None], logits, -1e30)
-            p = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
-            attn = jnp.einsum("bhqk,bkhd->bqhd", p, v_full)
+            attn = _dense_attention(q, k, v, causal[None, None], sm)
             x = x + jnp.einsum("bthk,hkd->btd", attn, layer["attn"]["wo"])
         x = _mlp(x, layer, cfg)
         with jax.named_scope("kv_write"):
             # scatter the prompt's k/v into this slot's pages
-            k_cache = k_cache.at[:, page_idx, offset].set(
-                jnp.swapaxes(k[0], 0, 1).astype(k_cache.dtype))
-            v_cache = v_cache.at[:, page_idx, offset].set(
-                jnp.swapaxes(v[0], 0, 1).astype(v_cache.dtype))
-        return (x,), (k_cache, v_cache)
+            k_pool, v_pool = _write_token_kv(
+                k_pool, v_pool, l, k[0], v[0], page_idx, offset)
+        return x, k_pool, v_pool
 
-    (x,), (new_k, new_v) = jax.lax.scan(
-        body, (x,), (params["layers"], kv["k"], kv["v"]))
+    x, kv = _scan_layers(body, x, kv, params)
     x = _final_norm(x, params, cfg)
     last = jnp.take_along_axis(
         x, jnp.maximum(true_len - 1, 0)[None, None, None], axis=1)[:, 0]
-    return _lm_head(last, params)[0], {"k": new_k, "v": new_v}
+    return _lm_head(last, params)[0], kv
 
 
 @jax.named_scope("prefill_chunk")
@@ -789,10 +798,8 @@ def paged_prefill_chunk(params, kv, page_table, tokens, start, true_len,
     the long-prompt suffix-prefill-after-tier-restore hot path. Returns
     (last-token logits [vocab] — meaningful only on the final chunk, new_kv).
     """
-    b = 1
     c = tokens.shape[1]
-    max_pages = page_table.shape[0]
-    max_len = max_pages * page_size
+    max_len = page_table.shape[0] * page_size
 
     pos = start + jnp.arange(c)                                   # [C]
     with jax.named_scope("embed"):
@@ -805,11 +812,8 @@ def paged_prefill_chunk(params, kv, page_table, tokens, start, true_len,
     kpos = jnp.arange(max_len)                                    # [L]
     valid = (kpos[None, :] <= pos[:, None]) & (kpos[None, :] < true_len)
     sm = cfg.head_dim ** -0.5
-    n_rep = cfg.n_heads // cfg.n_kv_heads
 
-    def body(carry, inputs):
-        (x,) = carry
-        layer, k_cache, v_cache = inputs
+    def body(x, k_pool, v_pool, layer, l):
         q, k, v = _qkv(x, layer, cos, sin, cfg)
         with jax.named_scope("kv_write"):
             # write the chunk's k/v first, then attend through the paged view —
@@ -817,51 +821,28 @@ def paged_prefill_chunk(params, kv, page_table, tokens, start, true_len,
             # chunk sees earlier chunks AND itself causally. B=1 here, so the
             # gathered view is small (unlike batched decode, where the
             # materialized gather is why the Pallas kernel exists).
-            k_cache = k_cache.at[:, page_idx, offset].set(
-                jnp.swapaxes(k[0], 0, 1).astype(k_cache.dtype))
-            v_cache = v_cache.at[:, page_idx, offset].set(
-                jnp.swapaxes(v[0], 0, 1).astype(v_cache.dtype))
+            k_pool, v_pool = _write_token_kv(
+                k_pool, v_pool, l, k[0], v[0], page_idx, offset)
         with jax.named_scope("attn"):
             if attn_backend == "pallas":
                 from ray_tpu.ops import paged_attention as paged_ops
-
-                def kernel(q, k_cache, v_cache, page_table, start, true_len):
-                    return paged_ops.paged_chunk_attention(
-                        q, k_cache, v_cache, page_table, start, true_len,
-                        sm_scale=sm)
-
-                if tp_degree(mesh) > 1:
-                    in_specs, out_spec = paged_ops.tp_shard_specs(
-                        q_rank=4, n_replicated=3)
-                    attn = _tp_pallas(kernel, mesh, in_specs, out_spec)(
-                        q, k_cache, v_cache, page_table, start, true_len)
-                else:
-                    attn = kernel(q, k_cache, v_cache, page_table, start,
-                                  true_len)
+                attn = _paged_kernel(
+                    paged_ops.paged_chunk_attention, q, k_pool, v_pool,
+                    page_table, start, true_len, l, sm_scale=sm, mesh=mesh)
             else:
-                k_seq = jnp.swapaxes(
-                    jnp.take(k_cache, page_table, axis=1).reshape(
-                        cfg.n_kv_heads, max_len, cfg.head_dim), 0, 1)[None]
-                v_seq = jnp.swapaxes(
-                    jnp.take(v_cache, page_table, axis=1).reshape(
-                        cfg.n_kv_heads, max_len, cfg.head_dim), 0, 1)[None]
-                k_full = _gqa_expand(k_seq, n_rep)
-                v_full = _gqa_expand(v_seq, n_rep)
-                logits = jnp.einsum("bqhd,bkhd->bhqk", q, k_full).astype(
-                    jnp.float32) * sm
-                logits = jnp.where(valid[None, None], logits, -1e30)
-                p = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
-                attn = jnp.einsum("bhqk,bkhd->bqhd", p, v_full)
+                attn = _dense_attention(
+                    q, _gather_seq(k_pool, l, page_table)[None],
+                    _gather_seq(v_pool, l, page_table)[None],
+                    valid[None, None], sm)
             x = x + jnp.einsum("bthk,hkd->btd", attn, layer["attn"]["wo"])
-        return (_mlp(x, layer, cfg),), (k_cache, v_cache)
+        return _mlp(x, layer, cfg), k_pool, v_pool
 
-    (x,), (new_k, new_v) = jax.lax.scan(
-        body, (x,), (params["layers"], kv["k"], kv["v"]))
+    x, kv = _scan_layers(body, x, kv, params)
     x = _final_norm(x, params, cfg)
     # last REAL token's position relative to this chunk's start
     rel = jnp.clip(true_len - 1 - start, 0, c - 1)
     last = jnp.take_along_axis(x, rel[None, None, None], axis=1)[:, 0]
-    return _lm_head(last, params)[0], {"k": new_k, "v": new_v}
+    return _lm_head(last, params)[0], kv
 
 
 @jax.named_scope("sample")

@@ -124,11 +124,19 @@ def test_decode_wrapper_matches_decode_attention_integration():
         jnp.int32)
     pos = jnp.asarray([0, 7, 13, 30], jnp.int32)
     cfg = types.SimpleNamespace(head_dim=d)
-    gather = kv_cache._decode_attention(q, k_pages, v_pages, page_tables,
-                                        pos, cfg, page, "gather")
-    pallas = kv_cache._decode_attention(q, k_pages, v_pages, page_tables,
-                                        pos, cfg, page, "pallas")
+    # the integration point takes the whole layer-indexed pool: the layer
+    # under test sits between two layers of other values
+    k_pool = jnp.stack([k_pages + 1.0, k_pages, k_pages - 1.0])
+    v_pool = jnp.stack([v_pages - 1.0, v_pages, v_pages + 1.0])
+    layer = jnp.int32(1)
+    gather = kv_cache._decode_attention(q, k_pool, v_pool, layer,
+                                        page_tables, pos, cfg, page, "gather")
+    pallas = kv_cache._decode_attention(q, k_pool, v_pool, layer,
+                                        page_tables, pos, cfg, page, "pallas")
     _assert_matches(pallas, gather)
+    _assert_matches(gather, _ref_attention(
+        q[:, None], k_pages, v_pages, page_tables, pos,
+        jnp.full((b,), mp * page, jnp.int32), d ** -0.5)[:, 0])
 
 
 def test_chunk_kernel_masks_padded_tail():
