@@ -2,7 +2,8 @@
 and seed everywhere.
 
 1. logits_check: the engine's own paged programs (whole prefill, chunked
-   prefill, then decode through the cache) against the float32 reference,
+   prefill, then decode through the cache) against the float32 reference
+   of the configuration's model family (benchmark/models/<family>.py),
    at the published widths and a shallow depth, weights and tokens from the
    seed. Max absolute logit error within the configuration's tolerance.
 2. served_tokens_check: tokens the replica served, teacher-forced through
@@ -12,6 +13,7 @@ and seed everywhere.
    against another.
 3. structure_check: every completed stream delivered its max_tokens or
    fewer (the engine swallows the stop token, so fewer means it stopped);
+   no prompt was cut (the server counted the tokens the client sent);
    every token id inside the vocabulary; nothing NaN.
 (4. the device and the compiled kernel: a run without them exits non-zero;
    see ``require_device``.)
@@ -33,19 +35,6 @@ if __name__ == "__main__":
     sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from benchmark import common  # noqa: E402
-
-
-def llama_config(sz: dict, n_layers: int | None = None, **kw):
-    import jax.numpy as jnp
-
-    from ray_tpu.models import llama
-    dtype = {"bfloat16": jnp.bfloat16, "float32": jnp.float32}[sz["dtype"]]
-    return llama.LlamaConfig(
-        vocab_size=sz["vocab_size"], dim=sz["dim"],
-        n_layers=n_layers or sz["n_layers"], n_heads=sz["n_heads"],
-        n_kv_heads=sz["n_kv_heads"], ffn_dim=sz["ffn_dim"],
-        max_seq_len=sz["max_seq_len"], rope_theta=sz["rope_theta"],
-        norm_eps=sz.get("norm_eps", 1e-5), dtype=dtype, **kw)
 
 
 def require_device(chips: int, rehearsal: bool) -> dict:
@@ -79,21 +68,22 @@ def _paged_programs(cfg, page: int, backend: str):
             p, kv, t, sl, x, cfg, page, backend)))
 
 
-def logits_check(sz: dict, engine: dict, spec: dict, seed: int,
-                 mutate=None, use_rope: bool = True) -> dict:
-    """``mutate(params) -> params`` and ``use_rope`` are the negative
-    controls' hooks (tests): the ENGINE side runs the mutated weights while
-    the reference keeps the originals; ``use_rope=False`` compares the
-    engine with a reference that leaves the rotary embedding out."""
+def logits_check(fam, sz: dict, engine: dict, spec: dict, seed: int,
+                 mutate=None, **reference_override) -> dict:
+    """``fam`` is the family's adapter (``common.family``).
+    ``mutate(params) -> params`` and ``reference_override`` are the
+    negative controls' hooks (tests): the ENGINE side runs the mutated
+    weights while the reference keeps the originals; an override (the
+    dense block's ``use_rope=False``) compares the engine with a reference
+    that leaves part of the mathematics out."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
-    from benchmark.reference import llama_f32
-    from ray_tpu.models import llama
     from ray_tpu.serve.llm import kv_cache as kvc
 
-    cfg = llama_config(sz, n_layers=spec["depth"])
+    ref_mod = common.reference(fam)
+    cfg = fam.model_config(sz, n_layers=spec["depth"])
     page, cap = engine["page_size"], engine["max_prompt_len"]
     chunk = engine["prefill_chunk"]
     max_pages = -(-engine["max_seq_len"] // page)
@@ -101,7 +91,7 @@ def logits_check(sz: dict, engine: dict, spec: dict, seed: int,
         engine.get("attention_kernel", "auto"), cfg, page)
     key = common.fold_seed(seed)
     k_w, k_t = jax.random.split(key)
-    params = llama.init_params(k_w, cfg)
+    params = fam.init_params(k_w, cfg)
     served = mutate(params) if mutate else params
     pa, pb, d = (spec["whole_prompt_tokens"], spec["chunked_prompt_tokens"],
                  spec["decode_steps"])
@@ -152,12 +142,11 @@ def logits_check(sz: dict, engine: dict, spec: dict, seed: int,
     program_s = time.perf_counter() - t0
 
     t0 = time.perf_counter()
-    ref = dict(theta=float(cfg.rope_theta), eps=float(cfg.norm_eps),
-               use_rope=use_rope)
-    want_a = llama_f32.logits_at(params, seq_a[None], np.arange(pa - 1, pa + d),
-                                 **ref)[0]
-    want_b = llama_f32.logits_at(params, seq_b[None], np.arange(pb - 1, pb + d),
-                                 **ref)[0]
+    ref = fam.reference_kwargs(cfg, **reference_override)
+    want_a = ref_mod.logits_at(params, seq_a[None],
+                               np.arange(pa - 1, pa + d), **ref)[0]
+    want_b = ref_mod.logits_at(params, seq_b[None],
+                               np.arange(pb - 1, pb + d), **ref)[0]
     errs = {}
     for name, got, want in (("whole_prefill+decode", got_a, want_a),
                             ("chunked_prefill+decode", got_b, want_b)):
@@ -177,19 +166,19 @@ def logits_check(sz: dict, engine: dict, spec: dict, seed: int,
 
 # ---- 2. served tokens, teacher-forced ---------------------------------------------
 
-def served_tokens_check(params, samples: list[dict], margin: float, *,
-                        theta: float, eps: float, eos: int | None,
+def served_tokens_check(ref_mod, ref: dict, params, samples: list[dict],
+                        margin: float, *, eos: int | None,
                         width: int | None = None,
                         out_width: int | None = None) -> dict:
-    """samples: [{"prompt_ids", "tokens", "max_tokens"}]. For each served
+    """``ref_mod`` is the family's reference module and ``ref`` the
+    keywords it takes (``fam.reference_kwargs(cfg)``).
+    samples: [{"prompt_ids", "tokens", "max_tokens"}]. For each served
     token at position t: reference max logit at t-1 minus the reference
     logit of the served token. A stream that stopped short is checked on
     the stop token it must have produced. ``width`` / ``out_width`` fix
     the padded shapes (prompt + output, output + 1) so that every run of a
     cell compiles the same reference programs."""
     import numpy as np
-
-    from benchmark.reference import llama_f32
 
     if not samples:
         return {"ok": False, "reason": "no served sample to check"}
@@ -206,7 +195,7 @@ def served_tokens_check(params, samples: list[dict], margin: float, *,
     toks = np.zeros((len(seqs), width), np.int32)
     for i, q in enumerate(seqs):
         toks[i, :len(q)] = q
-    hidden = llama_f32.hidden(params, toks, theta=theta, eps=eps)
+    hidden = ref_mod.hidden(params, toks, **ref)
     per_sample, worst, finite = [], 0.0, True
     for i, (plen, n) in enumerate(spans):
         if n == 0:
@@ -214,9 +203,8 @@ def served_tokens_check(params, samples: list[dict], margin: float, *,
             continue
         served = np.zeros((out_width,), np.int32)
         served[:n] = seqs[i][plen: plen + n]
-        deficit, ok = llama_f32.deficits(
-            hidden[i], plen - 1, served, n, params["final_norm"],
-            params["lm_head"], eps)
+        deficit, ok = ref_mod.deficits(params, hidden[i], plen - 1, served, n,
+                                       **ref)
         finite &= bool(ok)
         per_sample.append(float(deficit))
         worst = max(worst, per_sample[-1])
@@ -239,6 +227,12 @@ def structure_check(records: list[dict], samples: list[dict],
         if not 0 <= n <= cap:
             bad.append(f"request {r['index']}: {n} tokens of {cap}")
         early += int(n < cap)
+        # the engine cuts a prompt above its max_prompt_len silently: the
+        # server's count of prompt tokens has to be the client's
+        asked, got = r.get("prompt_tokens_asked"), r.get("prompt_tokens")
+        if asked is not None and got is not None and got != asked:
+            bad.append(f"request {r['index']}: prompt of {asked} tokens "
+                       f"served as {got}")
     for i, s in enumerate(samples):
         toks = s["tokens"]
         if len(toks) > s["max_tokens"]:
@@ -272,15 +266,15 @@ def serve_child(spec: dict) -> dict:
     (after the replica released it)."""
     import jax
 
-    from ray_tpu.models import llama
     from ray_tpu.ops import paged_attention as paged_ops
 
     rehearsal = spec["rehearsal"]
+    fam = common.load_module("models", spec["family"])
     device = require_device(spec["chips"], rehearsal)
     sz, engine = spec["sizes"], spec["engine"]
     out = {"device": device, "interpret": bool(paged_ops.interpret_default())}
     t0 = time.perf_counter()
-    out["logits"] = logits_check(sz, engine, spec["checks"]["logits"],
+    out["logits"] = logits_check(fam, sz, engine, spec["checks"]["logits"],
                                  spec["seed"])
     if not rehearsal and (out["logits"]["backend"] != "pallas"
                           or out["interpret"]):
@@ -290,15 +284,15 @@ def serve_child(spec: dict) -> dict:
         raise SystemExit(3)
     out["logits_s"] = time.perf_counter() - t0
     t0 = time.perf_counter()
-    cfg = llama_config(sz)
+    cfg = fam.model_config(sz)
     # the served weights: LLMEngine(cfg) -> init_params(PRNGKey(0), model)
     params = jax.block_until_ready(
-        llama.init_params(jax.random.PRNGKey(0), cfg))
+        fam.init_params(jax.random.PRNGKey(0), cfg))
     out["served_weights_s"] = time.perf_counter() - t0
     t0 = time.perf_counter()
     out["served_tokens"] = served_tokens_check(
-        params, spec["samples"], spec["checks"]["served_tokens"]["margin"],
-        theta=float(cfg.rope_theta), eps=float(cfg.norm_eps),
+        common.reference(fam), fam.reference_kwargs(cfg), params,
+        spec["samples"], spec["checks"]["served_tokens"]["margin"],
         eos=common.BYTE_EOS, **spec.get("shape", {}))
     out["served_tokens_s"] = time.perf_counter() - t0
     return out

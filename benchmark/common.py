@@ -7,6 +7,7 @@ backend (one process holds a chip at a time).
 
 from __future__ import annotations
 
+import functools
 import glob
 import importlib.util
 import json
@@ -54,9 +55,12 @@ def load_cell(name: str, root: str = ROOT) -> tuple[dict, dict, dict]:
     return entry, cell, config
 
 
+@functools.lru_cache(maxsize=None)
 def load_module(kind: str, name: str, root: str = ROOT):
-    """benchmark/<kind>/<name>.py; for a metric ``a.b`` without a file of
-    its own, the family's ``a.py`` (one reader, read in several cells)."""
+    """benchmark/<kind>/<name>.py, loaded once a process (a reference's
+    jitted functions keep their compiled programs); for a metric ``a.b``
+    without a file of its own, the family's ``a.py`` (one reader, read in
+    several cells)."""
     for cand in (name, name.split(".")[0]):
         path = os.path.join(bench_dir(root), kind, f"{cand}.py")
         if os.path.exists(path):
@@ -69,27 +73,25 @@ def load_module(kind: str, name: str, root: str = ROOT):
     raise BenchError(f"no benchmark/{kind}/{name}.py")
 
 
+def family(config: dict, root: str = ROOT):
+    """The adapter of the model family a configuration's file names
+    (``model_family``: benchmark/models/<family>.py). Everything the
+    harness knows about a block it asks of this module."""
+    if not config.get("model_family"):
+        raise BenchError("the configuration's file states no model_family")
+    return load_module("models", config["model_family"], root)
+
+
+def reference(fam, root: str = ROOT):
+    """The plain reference a family's adapter names (benchmark/reference/
+    __init__.py has the contract)."""
+    return load_module("reference", fam.REFERENCE, root)
+
+
 def cell_metrics(man: dict, cell_name: str, group: str) -> list[dict]:
     """The metrics of ``group`` (end_to_end | per_layer) read in a cell."""
     return [m for m in man[group]
             if "workloads" not in m or cell_name in m["workloads"]]
-
-
-def sizes(config: dict, rehearsal: bool) -> dict:
-    """Model sizes under the program's names, from the published keys (or
-    the rehearsal's tiny preset)."""
-    if rehearsal:
-        return dict(config["rehearsal"]["model"])
-    return {"vocab_size": config["vocab_size"], "dim": config["hidden_size"],
-            "n_layers": config["num_hidden_layers"],
-            "n_heads": config["num_attention_heads"],
-            "n_kv_heads": config["num_key_value_heads"],
-            "ffn_dim": config["intermediate_size"],
-            "max_seq_len": (config.get("engine") or {}).get(
-                "max_seq_len") or config["trainer"]["seq_len"],
-            "rope_theta": config["rope_theta"],
-            "norm_eps": config["rms_norm_eps"],
-            "dtype": config["torch_dtype"]}
 
 
 def section(config: dict, key: str, rehearsal: bool) -> dict:

@@ -1,26 +1,9 @@
-"""The operations and bytes the algorithm needs, from shapes. Kept with
-the benchmark so that no PR that claims a gain can change them."""
+"""The operations and bytes the kernels need, from shapes. Kept with the
+benchmark so that no PR that claims a gain can change them. A model
+family's own counts (parameters, FLOPs a token) are in its adapter under
+benchmark/models/."""
 
 from __future__ import annotations
-
-
-def dense_params(sz: dict) -> int:
-    """Parameters of the dense block stack, embedding and output head."""
-    hd = sz["dim"] // sz["n_heads"]
-    per_layer = (sz["dim"] * (sz["n_heads"] + 2 * sz["n_kv_heads"]) * hd
-                 + sz["n_heads"] * hd * sz["dim"]
-                 + 3 * sz["dim"] * sz["ffn_dim"] + 2 * sz["dim"])
-    return 2 * sz["vocab_size"] * sz["dim"] + sz["dim"] \
-        + sz["n_layers"] * per_layer
-
-
-def train_flops_per_token(sz: dict, seq_len: int) -> float:
-    """Forward + backward FLOPs one token needs: 6 per parameter that
-    multiplies it (the embedding table is a lookup, so it is left out)
-    plus causal attention, 6 * layers * seq_len * dim (QK^T and PV, half
-    the square, forward 2x + backward 4x). Recomputation is not counted."""
-    matmul_params = dense_params(sz) - sz["vocab_size"] * sz["dim"]
-    return 6.0 * matmul_params + 6.0 * sz["n_layers"] * seq_len * sz["dim"]
 
 
 def flash_attention_flops(batch: int, seq_len: int, n_heads: int,

@@ -2,9 +2,9 @@
 THOSE executions ran: calls of the kernel named ``paged_decode_attention``
 inside them over the layers, cross-checked against the ``k`` of the
 ``rt/decode_dispatch`` spans matched to them (benchmark/span_reduce.py).
-Both sides come from the one trace, where ``decode_step_ms`` divides the
-trace's device time by a counter another process reads over HTTP, up to
-pipeline_depth x decode_block steps ahead of the device. device_trace."""
+Both sides come from the one trace (a counter read over HTTP by another
+process runs up to pipeline_depth x decode_block steps ahead of the
+device). device_trace."""
 
 from benchmark import span_reduce
 

@@ -1,8 +1,8 @@
 """Device time of the prefill and prefill-chunk executions in the trace per
 thousand prompt tokens, the tokens being the ``tokens`` of the
 ``rt/prefill`` / ``rt/chunk_prefill`` spans that dispatched those very
-executions (``prefill_ms_per_ktok`` picks its tokens by the client's
-clock). device_trace + program_span."""
+executions (not tokens picked by the client's clock).
+device_trace + program_span."""
 
 from benchmark import span_reduce
 

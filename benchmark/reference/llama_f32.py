@@ -109,13 +109,20 @@ def logits_at(params, tokens, positions, *, theta: float, eps: float,
                      params["lm_head"], eps)
 
 
-@functools.partial(jax.jit, static_argnames=("eps",))
-def deficits(hidden_i, first, served, n, final_norm, lm_head, eps):
+def deficits(params, hidden_i, first, served, n, *, theta: float, eps: float,
+             use_rope: bool = True):
     """For one sequence's hidden states [T, D]: the largest (reference max
     logit - reference logit of the served token) over the n tokens served
     from position ``first`` + 1 on, and whether every logit is finite.
     ``served`` is padded to a fixed width; ``first`` and ``n`` are traced,
-    so one program serves every sample."""
+    so one program serves every sample. (``theta`` and ``use_rope`` are
+    the contract's keywords; the head needs neither.)"""
+    return _deficits(hidden_i, first, served, n, params["final_norm"],
+                     params["lm_head"], eps)
+
+
+@functools.partial(jax.jit, static_argnames=("eps",))
+def _deficits(hidden_i, first, served, n, final_norm, lm_head, eps):
     w = served.shape[0]
     with jax.default_matmul_precision("highest"):
         lg = _head(jax.lax.dynamic_slice_in_dim(hidden_i, first, w, axis=0),
@@ -127,11 +134,12 @@ def deficits(hidden_i, first, served, n, final_norm, lm_head, eps):
             jnp.all(jnp.where(live[:, None], jnp.isfinite(lg), True)))
 
 
-def loss(params, tokens, *, theta: float, eps: float):
+def loss(params, tokens, *, theta: float, eps: float, use_rope: bool = True):
     """Mean next-token cross-entropy of tokens [B, T + 1], sequence by
     sequence so that one sequence's float32 logits are live at a time."""
     tokens = jnp.asarray(tokens, jnp.int32)
-    x = hidden(params, tokens[:, :-1], theta=theta, eps=eps)
+    x = hidden(params, tokens[:, :-1], theta=theta, eps=eps,
+               use_rope=use_rope)
 
     with jax.default_matmul_precision("highest"):
         total = sum(float(_seq_nll(x[i], tokens[i, 1:], params["final_norm"],

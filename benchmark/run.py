@@ -2,9 +2,10 @@
 
     python benchmark/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1>
 
-Finds the cell, its configuration, its traffic generator and its metrics
-by name (BENCHMARK.json, benchmark/workloads, configs, traffic, metrics);
-runs the cell through the entry points users call; prints a
+Finds the cell, its configuration, the configuration's model family, its
+traffic generator and its metrics by name (BENCHMARK.json,
+benchmark/workloads, configs, models, traffic, metrics); runs the cell
+through the entry points users call; prints a
 ``{"report": ...}`` line and then, last, the result line:
 ``{"correct", "attempted", "failed", "metrics", "device"[, "breakdown"]}``.
 With --trace 0 the metrics are the cell's end-to-end metrics, with
@@ -14,6 +15,23 @@ Without the chips the cell asks for it exits non-zero and prints no
 result. ``--rehearsal`` walks the same control flow at the configuration's
 tiny preset on the CPU: its line says ``"platform": "cpu"`` and
 ``"rehearsal": true`` and none of its numbers is a device number.
+
+Adding a model family takes four new files and three manifest entries,
+and no edit of a file that is here:
+  benchmark/models/<family>.py     the adapter: published keys -> sizes,
+      the program's model config and init, the reference's name and
+      keywords, the train functions, counts, scopes (the docstring of the
+      family that is there lists the names)
+  benchmark/reference/<name>.py    the plain float32 reference
+      (contract: docstring of benchmark/reference/__init__.py)
+  benchmark/configs/<config>.json  ``model_family``, ``published`` (the
+      source's shape keys verbatim), the keys again as run, ``reduced``,
+      ``engine`` (with ``tp_degree`` = the cell's chips) or ``trainer``,
+      ``checks``, ``rehearsal``
+  benchmark/workloads/<cell>.json  the traffic, for a generator that exists
+and in BENCHMARK.json one entry each under ``configs`` and ``workloads``,
+and the cell's name under the ``workloads`` of the metrics it reports.
+tests/benchmark_suite/test_manifest.py does exactly this in a copy.
 """
 
 from __future__ import annotations
@@ -100,6 +118,7 @@ def main() -> int:
         mod = __import__(f"benchmark.{runner}", fromlist=["run"])
         run = mod.run(entry, cell, config, args, T_PROCESS)
         run["cell"], run["manifest"] = entry["name"], man
+        run["family"] = common.family(config)
         if args.trace and run.get("trace_dir"):
             from benchmark import trace_reduce
             run["trace"] = trace_reduce.summarise(

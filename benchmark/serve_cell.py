@@ -22,12 +22,22 @@ HOST = "127.0.0.1"
 
 
 def _engine_config(config: dict, rehearsal: bool, chips: int):
-    from benchmark.checks import llama_config
+    """(sizes, engine section, LLMConfig). The model is the family's, the
+    engine's layout the file's: every field of LLMConfig the harness sets
+    comes from the configuration's ``engine`` section. A cell runs one
+    replica, so a replica that spans another number of chips than the
+    cell's is refused here, before any process starts."""
     from ray_tpu.serve.llm import LLMConfig
-    sz = common.sizes(config, rehearsal)
+    fam = common.family(config)
+    sz = fam.sizes(config, rehearsal)
     eng = common.section(config, "engine", rehearsal)
+    tp = eng.get("tp_degree", 1)
+    if tp != chips:
+        raise common.BenchError(f"the configuration's engine spans "
+                                f"tp_degree={tp} chip(s), the cell asks "
+                                f"for {chips}")
     return sz, eng, LLMConfig(
-        model_config=llama_config(sz), tp_degree=1,
+        model_config=fam.model_config(sz),
         ray_actor_options={"resources": {"TPU": chips}}, **eng)
 
 
@@ -262,7 +272,7 @@ def run(entry: dict, cell: dict, config: dict, args, t_process: float) -> dict:
     t = time.monotonic()
     child = run_checks_child({
         "rehearsal": rehearsal, "chips": chips, "seed": args.seed,
-        "sizes": sz, "engine": eng, "samples": samples,
+        "family": config["model_family"], "sizes": sz, "engine": eng, "samples": samples,
         "shape": {"width": traffic["prompt_tokens"]["max"],
                   "out_width": traffic["output_tokens"]["max"] + 1},
         "checks": common.section(config, "checks", rehearsal)}, out_dir)

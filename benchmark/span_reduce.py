@@ -47,12 +47,12 @@ if __name__ == "__main__":
 from benchmark import trace_reduce  # noqa: E402
 
 SPAN_PREFIX = "rt/"
-# scopes of the model's own arithmetic; the rest of a program is cache and
-# state movement: kv_write, gather_state / scatter_state, what sits
-# directly under a program's scope (scan carries, the compiler's copies)
-# and ops with no scope at all
-MODEL_SCOPES = ("embed", "norm", "attn", "mlp", "lm_head", "sample", "loss",
-                "optimizer")
+# The scopes of a model's own arithmetic are its family's (``MODEL_SCOPES``
+# of benchmark/models/<family>.py; the functions below take them as
+# ``model_scopes``). The rest of a program is cache and state movement,
+# under the engine's scopes: kv_write, gather_state / scatter_state, what
+# sits directly under a program's scope (scan carries, the compiler's
+# copies), and ops with no scope at all.
 OTHER_SCOPES = ("kv_write", "gather_state", "scatter_state", "decode_step",
                 "decode_block", "prefill", "prefill_chunk", "verify")
 WAITING = ("loop_wait", "harvest")       # the host has nothing to run
@@ -326,11 +326,11 @@ def scope_path(tf_op: str) -> list[str]:
 
 
 @functools.lru_cache(maxsize=None)
-def layer_of(tf_op: str) -> str:
-    """The innermost scope this repo names, or "" (no scope: the
-    compiler's own, or an eager op)."""
+def layer_of(tf_op: str, model_scopes: tuple) -> str:
+    """The innermost scope this repo names (the family's or the engine's),
+    or "" (no scope: the compiler's own, or an eager op)."""
     for part in reversed(scope_path(tf_op)):
-        if part in MODEL_SCOPES or part in OTHER_SCOPES:
+        if part in model_scopes or part in OTHER_SCOPES:
             return part
     return ""
 
@@ -511,27 +511,52 @@ def prefill_traced_ms_per_ktok(trace: dict, n_layers: int) -> float | None:
         / (tokens / 1e3)
 
 
-def device_by_scope(trace: dict, is_main) -> dict[str, float]:
+def device_by_scope(trace: dict, is_main,
+                    model_scopes: tuple) -> dict[str, float]:
     """Seconds of the leaf ops inside the executions ``is_main(program,
     seconds)`` picks, by ``layer_of`` ("" = no scope of ours)."""
+    model_scopes = tuple(model_scopes)      # layer_of caches on it
     spans = [(s, e) for n, s, e in trace["modules"]
              if is_main(n, (e - s) / 1e9)]
     out: dict[str, float] = {}
     for (_n, s, e, tf, _c), _i in _within(
             [o for o in trace["ops"] if not o[4]], spans):
-        layer = layer_of(tf)
+        layer = layer_of(tf, model_scopes)
         out[layer] = out.get(layer, 0.0) + (e - s) / 1e9
     return out
 
 
-def model_op_share(trace: dict, is_main) -> float | None:
+def model_op_share(trace: dict, is_main, model_scopes: tuple) -> float | None:
     """Share (%) of the main programs' op time under a model scope."""
-    by = device_by_scope(trace, is_main)
+    by = device_by_scope(trace, is_main, model_scopes)
     named = sum(v for k, v in by.items() if k)
     if not named:
         return None                    # a program without scopes
-    return 100.0 * sum(by.get(k, 0.0) for k in MODEL_SCOPES) \
+    return 100.0 * sum(by.get(k, 0.0) for k in model_scopes) \
         / sum(by.values())
+
+
+def program_split(trace: dict, n_layers: int) -> dict[str, float]:
+    """The device's traced span as decode programs + prefill programs +
+    idle, in seconds (what is left of the span is the small programs
+    between them: slot patches, concatenations)."""
+    gaps, t_lo, t_hi = _gaps(trace)
+    ex = executions(trace, n_layers)
+    return {"span_s": (t_hi - t_lo) / 1e9,
+            "decode_s": sum(x["end"] - x["start"] for x in ex
+                            if x["kind"] != "prefill") / 1e9,
+            "prefill_s": sum(x["end"] - x["start"] for x in ex
+                             if x["kind"] == "prefill") / 1e9,
+            "idle_s": trace_reduce.union_s(gaps)}
+
+
+def prefill_program_share(trace: dict, n_layers: int) -> float | None:
+    """Share (%) of the device's traced span inside prefill and
+    prefill-chunk executions; None where the trace holds none."""
+    split = program_split(trace, n_layers)
+    if not split["prefill_s"]:
+        return None
+    return 100.0 * split["prefill_s"] / split["span_s"]
 
 
 def idle_by_span(trace: dict) -> dict[str, float]:
@@ -614,23 +639,22 @@ def engine_loop_busy_share(before: dict, after: dict,
     return 100.0 * (1.0 - waited / seconds)
 
 
-def report(trace: dict, n_layers: int, is_main) -> dict:
+def report(trace: dict, n_layers: int, is_main, model_scopes: tuple) -> dict:
     """What PERF.md's section 5 is written from."""
+    model_scopes = tuple(model_scopes)
     m = match_stream(trace, n_layers)
-    gaps, t_lo, t_hi = _gaps(trace)
-    window = (t_hi - t_lo) / 1e9
     idle = idle_by_span(trace)
-    by = device_by_scope(trace, is_main)
+    by = device_by_scope(trace, is_main, model_scopes)
     ops: dict[tuple, float] = {}
     spans = [(s, e) for n, s, e in trace["modules"]
              if is_main(n, (e - s) / 1e9)]
     for (n, s, e, tf, _c), _i in _within(
             [o for o in trace["ops"] if not o[4]], spans):
-        key = (n, layer_of(tf), kernel_of(tf), tf[-90:])
+        key = (n, layer_of(tf, model_scopes), kernel_of(tf), tf[-90:])
         ops[key] = ops.get(key, 0.0) + (e - s) / 1e9
     ex = m["executions"]
     return {
-        "window_s": window, "idle_s": trace_reduce.union_s(gaps),
+        "split_s": program_split(trace, n_layers),
         "idle_by_span_s": dict(sorted(idle.items(), key=lambda kv: -kv[1])),
         "device_by_scope_s": dict(sorted(by.items(), key=lambda kv: -kv[1])),
         "top_ops": [[*k, v] for k, v in sorted(
@@ -639,11 +663,7 @@ def report(trace: dict, n_layers: int, is_main) -> dict:
         "stream": {"executions": len(ex), "dispatches": len(m["dispatches"]),
                    "pairs": len(m["pairs"]), "unfit": m["unfit"],
                    "lead": m["lead"],
-                   "decode_s": sum(x["end"] - x["start"] for x in ex
-                                   if x["kind"] == "decode") / 1e9,
-                   "decode_steps": sum(x.get("steps", 0) for x in ex),
-                   "prefill_s": sum(x["end"] - x["start"] for x in ex
-                                    if x["kind"] == "prefill") / 1e9},
+                   "decode_steps": sum(x.get("steps", 0) for x in ex)},
         "programs_s": _programs(trace),
     }
 
@@ -656,11 +676,16 @@ def _programs(trace: dict) -> dict[str, float]:
 
 
 if __name__ == "__main__":
-    # python3 benchmark/span_reduce.py <trace dir> <layers> [train]
+    # python3 benchmark/span_reduce.py <trace dir> <cell>
     #     [--cut <from_s> <to_s> <out.json>]
+    # the cell's configuration gives the depth, the model family (its
+    # scopes) and which program is the main one
+    from benchmark import common
+    _entry, _cell, config = common.load_cell(sys.argv[2])
+    fam = common.family(config)
     tr = read_dir(sys.argv[1])
-    main = (lambda n, _s: n.startswith("jit_step")) if "train" in sys.argv \
-        else trace_reduce.is_decode_program
+    main = (lambda n, _s: n.startswith("jit_step")) \
+        if config["kind"] == "train" else trace_reduce.is_decode_program
     if "--cut" in sys.argv:
         i = sys.argv.index("--cut")
         lo = min(s for _n, s, _e, _t, _c in tr["ops"])
@@ -670,4 +695,5 @@ if __name__ == "__main__":
         for r in rows:
             r[2] -= base
         dump(rows, sys.argv[i + 3])
-    print(json.dumps(report(tr, int(sys.argv[2]), main), indent=1))
+    print(json.dumps(report(tr, fam.sizes(config, False)["n_layers"], main,
+                            fam.MODEL_SCOPES), indent=1))

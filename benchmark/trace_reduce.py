@@ -187,9 +187,6 @@ def summarise(events: list[list]) -> dict | None:
             "programs": programs, "ops": by_op,
             "kernel_calls": sum(1 for n, _, _ in ops if is_kernel(n)),
             "kernel_s": sum(u for n, _, u in ops if is_kernel(n)) / 1e9,
-            "modules": [(program_name(n), s, u)
-                        for n, s, u in d["modules"]],
-            "kernels": [(s, u) for n, s, u in ops if is_kernel(n)],
             "gaps": gaps}
     n = len(per_dev)
     first = next(iter(per_dev.values()))
@@ -205,31 +202,6 @@ def summarise(events: list[list]) -> dict | None:
                 first["ops"].items(), key=lambda kv: -kv[1][1])[:10]],
             "idle_gaps": [[k, v] for k, v in first["gaps"]]},
     }
-
-
-def program_time(summary: dict, predicate) -> tuple[int, float]:
-    """(executions, seconds) of the first device's programs for which
-    predicate(name, seconds_of_one_execution) holds."""
-    dev = next(iter(summary["per_device"].values()))
-    picked = [u / 1e9 for n, _s, u in dev["modules"] if predicate(n, u / 1e9)]
-    return len(picked), sum(picked)
-
-
-def kernel_time_within(summary: dict, predicate) -> tuple[int, float]:
-    """(calls, seconds) of kernel ops that ran inside the executions of the
-    programs predicate picks, on the first device."""
-    dev = next(iter(summary["per_device"].values()))
-    spans = [(s, s + u) for n, s, u in dev["modules"] if predicate(n, u / 1e9)]
-    calls, total = 0, 0.0
-    i = 0
-    spans.sort()
-    for s, u in sorted(dev["kernels"]):
-        while i < len(spans) and spans[i][1] <= s:
-            i += 1
-        if i < len(spans) and spans[i][0] <= s:
-            calls += 1
-            total += u / 1e9
-    return calls, total
 
 
 # how the serve engine's programs are named today (engine.py jits lambdas)

@@ -26,38 +26,39 @@ def train_loop(config: dict) -> None:
     rtrain.report(train_steps(config))
 
 
-def build(sz: dict, tr: dict, chips: int, rehearsal: bool) -> dict:
+def build(family: str, sz: dict, tr: dict, chips: int,
+          rehearsal: bool) -> dict:
     """Everything of a run that does not depend on the seed: the mesh, the
     optimizer, the shardings, the jitted init (the key is an argument) and
-    the jitted step. bench._make_step's recipe."""
+    the jitted step. bench._make_step's recipe, on the model the family's
+    adapter (benchmark/models/<family>.py) builds."""
     import jax
 
-    from benchmark.checks import llama_config, require_device
-    from ray_tpu.models import llama
+    from benchmark.checks import require_device
     from ray_tpu.train import spmd
 
+    fam = common.load_module("models", family)
     device = require_device(chips, rehearsal)
     devs = jax.devices()[:chips]
-    cfg = llama_config(sz, remat_policy=tr["remat_policy"],
-                       ce_chunk=tr["ce_chunk"], ce_remat=tr["ce_remat"],
-                       attn_impl=tr["attn_impl"])
+    cfg = fam.model_config(sz, trainer=tr)
     mesh = spmd.make_mesh(chips, devices=devs, **tr["mesh"])
     opt = spmd.default_optimizer(learning_rate=tr["learning_rate"],
                                  warmup_steps=10, decay_steps=1000,
                                  name=tr["optimizer"])
     shapes = jax.eval_shape(
-        lambda: llama.init_params(jax.random.PRNGKey(0), cfg))
-    sh = spmd.state_shardings(llama.logical_axes(cfg), shapes, mesh, opt)
+        lambda: fam.init_params(jax.random.PRNGKey(0), cfg))
+    sh = spmd.state_shardings(fam.logical_axes(cfg), shapes, mesh, opt)
     # weights made sharded on the devices in one jitted call from the key
     # (spmd.sharded_create_state's body, with the key as an argument)
     init_state = jax.jit(
-        lambda key: spmd.TrainState.create(llama.init_params(key, cfg), opt),
+        lambda key: spmd.TrainState.create(fam.init_params(key, cfg), opt),
         out_shardings=sh)
-    init_params = jax.jit(lambda key: llama.init_params(key, cfg),
+    init_params = jax.jit(lambda key: fam.init_params(key, cfg),
                           out_shardings=sh.params)
     step = spmd.make_train_step(
-        lambda p, b: llama.loss_fn(p, b, cfg, mesh), opt, mesh, sh)
-    return {"cfg": cfg, "mesh": mesh, "sh": sh, "init_state": init_state,
+        lambda p, b: fam.loss_fn(p, b, cfg, mesh), opt, mesh, sh)
+    return {"fam": fam, "cfg": cfg, "vocab_size": sz["vocab_size"],
+            "mesh": mesh, "sh": sh, "init_state": init_state,
             "init_params": init_params, "step": step, "compiled": None,
             "device": device, "devs": devs, "tr": tr}
 
@@ -68,18 +69,16 @@ def train_steps(config: dict, built: dict | None = None) -> dict:
     import jax
     import jax.numpy as jnp
 
-    from benchmark.reference import llama_f32
     from benchmark.traffic import train_batches
-    from ray_tpu.models import llama
     from ray_tpu.train import spmd
 
     t_enter = time.time()
     phases = {}
     t = time.perf_counter()
     if built is None:
-        built = build(config["sizes"], config["trainer"], config["chips"],
-                      config["rehearsal"])
-    cfg, mesh, tr = built["cfg"], built["mesh"], built["tr"]
+        built = build(config["family"], config["sizes"], config["trainer"],
+                      config["chips"], config["rehearsal"])
+    fam, cfg, mesh, tr = (built[k] for k in ("fam", "cfg", "mesh", "tr"))
     key = common.fold_seed(config["seed"])
     state = built["init_state"](key)
     gb, seq = tr["global_batch"], tr["seq_len"]
@@ -88,7 +87,7 @@ def train_steps(config: dict, built: dict | None = None) -> dict:
 
     def batch(i):
         return spmd.shard_batch({"tokens": jnp.asarray(train_batches.batch(
-            config["seed"], i % cycle, gb, seq, cfg.vocab_size))}, mesh)
+            config["seed"], i % cycle, gb, seq, built["vocab_size"]))}, mesh)
 
     jax.block_until_ready(state.step)
     phases["init_state"] = time.perf_counter() - t
@@ -141,10 +140,11 @@ def train_steps(config: dict, built: dict | None = None) -> dict:
     # (made again from the seed), float32, after the state has gone
     t = time.perf_counter()
     del state
-    ref0 = llama_f32.loss(
+    ref0 = common.reference(fam).loss(
         built["init_params"](key),
-        train_batches.batch(config["seed"], 0, gb, seq, cfg.vocab_size),
-        theta=float(cfg.rope_theta), eps=float(cfg.norm_eps))
+        train_batches.batch(config["seed"], 0, gb, seq,
+                            built["vocab_size"]),
+        **fam.reference_kwargs(cfg))
     phases["reference_loss"] = time.perf_counter() - t
     last_same = max(i for i in range(len(losses)) if i % cycle == 0)
     return {
@@ -156,7 +156,7 @@ def train_steps(config: dict, built: dict | None = None) -> dict:
         "t_enter": t_enter, "t_window": t_window, "phases_s": phases,
         "reference_first_loss": float(ref0),
         "collectives": built["collectives"],
-        "params": llama.num_params(cfg), "device": built["device"],
+        "params": fam.num_params(cfg), "device": built["device"],
         "mesh": {a: s for a, s in dict(mesh.shape).items() if s > 1},
         }
 
@@ -169,7 +169,7 @@ def run(entry: dict, cell: dict, config: dict, args, t_process: float) -> dict:
     rehearsal, chips = args.rehearsal, entry["chips"]
     tr = common.section(config, "trainer", rehearsal)
     traffic = (cell["rehearsal"] if rehearsal else cell)["traffic"]
-    sz = common.sizes(config, rehearsal)
+    sz = common.family(config).sizes(config, rehearsal)
     out_dir = os.path.join(common.ROOT, ".bench_out", entry["name"])
     os.makedirs(out_dir, exist_ok=True)
     trace_dir = None
@@ -190,7 +190,7 @@ def run(entry: dict, cell: dict, config: dict, args, t_process: float) -> dict:
         result = JaxTrainer(
             train_loop,
             train_loop_config={
-                "sizes": sz, "trainer": tr, "chips": chips,
+                "family": config["model_family"], "sizes": sz, "trainer": tr, "chips": chips,
                 "rehearsal": rehearsal, "seed": args.seed,
                 "seconds": args.seconds, "trace_dir": trace_dir,
                 "plan": common.load_module(

@@ -18,7 +18,9 @@ from benchmark import checks, common, train_cell
 SEEDS = [2**31 + 1009 * i + i * i for i in range(20)]
 SERVE_CELLS = ["mistral7b-serve-chat", "mistral7b-serve-peak"]
 _, _, SERVE_CONFIG = common.load_cell("mistral7b-serve-chat")
-SZ = common.sizes(SERVE_CONFIG, True)
+FAM = common.family(SERVE_CONFIG)
+REF = common.reference(FAM)
+SZ = FAM.sizes(SERVE_CONFIG, True)
 ENG = common.section(SERVE_CONFIG, "engine", True)
 CHK = common.section(SERVE_CONFIG, "checks", True)
 
@@ -27,20 +29,25 @@ CHK = common.section(SERVE_CONFIG, "checks", True)
 def engine():
     """The tiny engine, warmed with the closed set of the cells' files."""
     from ray_tpu.serve.llm import LLMConfig, LLMEngine
-    eng = LLMEngine(LLMConfig(model_config=checks.llama_config(SZ), **ENG))
+    eng = LLMEngine(LLMConfig(model_config=FAM.model_config(SZ), **ENG))
     eng.start()
-    t = common.load_cell(SERVE_CELLS[0])[1]["rehearsal"]["traffic"][
-        "prompt_tokens"]
-    for _n, text in common.warm_prompts(t["min"], t["max"], ENG):
-        eng.generate(text, max_tokens=2)
+    for cell in SERVE_CELLS:
+        t = common.load_cell(cell)[1]["rehearsal"]["traffic"]["prompt_tokens"]
+        for _n, text in common.warm_prompts(t["min"], t["max"], ENG):
+            eng.generate(text, max_tokens=2)
     yield eng
     eng.shutdown()
 
 
 @pytest.fixture(scope="module")
 def served_params():
-    from ray_tpu.models import llama
-    return llama.init_params(jax.random.PRNGKey(0), checks.llama_config(SZ))
+    return FAM.init_params(jax.random.PRNGKey(0), FAM.model_config(SZ))
+
+
+def _served_check(params, samples):
+    return checks.served_tokens_check(
+        REF, FAM.reference_kwargs(FAM.model_config(SZ)), params, samples,
+        CHK["served_tokens"]["margin"], eos=common.BYTE_EOS)
 
 
 def _serve(engine, cell, seed):
@@ -72,11 +79,7 @@ def test_serve_rehearsal_is_correct_and_compiles_nothing(engine,
     assert jax.devices()[0].platform == "cpu"      # a rehearsal, flagged
     records, samples, compiles = _serve(engine, cell, seed)
     assert compiles == 0
-    cfg = checks.llama_config(SZ)
-    served = checks.served_tokens_check(
-        served_params, samples, CHK["served_tokens"]["margin"],
-        theta=float(cfg.rope_theta), eps=float(cfg.norm_eps),
-        eos=common.BYTE_EOS)
+    served = _served_check(served_params, samples)
     structure = checks.structure_check(records, samples, SZ["vocab_size"])
     assert served["ok"], served
     assert structure["ok"], structure
@@ -84,7 +87,7 @@ def test_serve_rehearsal_is_correct_and_compiles_nothing(engine,
 
 @pytest.mark.parametrize("seed", SEEDS)
 def test_logits_check_holds_on_any_seed(seed):
-    out = checks.logits_check(SZ, ENG, CHK["logits"], seed)
+    out = checks.logits_check(FAM, SZ, ENG, CHK["logits"], seed)
     assert out["ok"], out
 
 
@@ -117,28 +120,25 @@ def _int8_mlp(params):
 
 
 def test_negative_control_int8_mlp_fails_the_logits_check():
-    out = checks.logits_check(SZ, ENG, CHK["logits"], SEEDS[0],
+    out = checks.logits_check(FAM, SZ, ENG, CHK["logits"], SEEDS[0],
                               mutate=_int8_mlp)
     assert not out["ok"] and out["max_abs_err"] > 5 * out["tolerance"]
 
 
 def test_negative_control_dropped_rope_fails_the_logits_check():
-    out = checks.logits_check(SZ, ENG, CHK["logits"], SEEDS[0], use_rope=False)
+    out = checks.logits_check(FAM, SZ, ENG, CHK["logits"], SEEDS[0],
+                              use_rope=False)
     assert not out["ok"] and out["max_abs_err"] > 5 * out["tolerance"]
 
 
 def test_negative_control_one_replaced_token_fails_the_served_check(
         engine, served_params):
     _r, samples, _c = _serve(engine, SERVE_CELLS[0], SEEDS[1])
-    cfg = checks.llama_config(SZ)
-    kw = dict(theta=float(cfg.rope_theta), eps=float(cfg.norm_eps),
-              eos=common.BYTE_EOS)
-    margin = CHK["served_tokens"]["margin"]
-    assert checks.served_tokens_check(served_params, samples, margin, **kw)["ok"]
+    assert _served_check(served_params, samples)["ok"]
     bad = [dict(s, tokens=list(s["tokens"])) for s in samples]
     bad[0]["tokens"][1] = (bad[0]["tokens"][1] + 7) % SZ["vocab_size"]
-    out = checks.served_tokens_check(served_params, bad, margin, **kw)
-    assert not out["ok"] and out["max_deficit"] > 10 * margin
+    out = _served_check(served_params, bad)
+    assert not out["ok"] and out["max_deficit"] > 10 * out["margin"]
 
 
 def test_structure_check_takes_an_early_stop_and_refuses_an_overrun():
@@ -150,6 +150,12 @@ def test_structure_check_takes_an_early_stop_and_refuses_an_overrun():
         [{"index": 0, "max_tokens": 8, "completion_tokens": 9}], [], 512)["ok"]
     assert not checks.structure_check(
         [], [{"tokens": [1, 999], "max_tokens": 4}], 512)["ok"]
+    # a prompt the engine cut (max_prompt_len) is not the work that was asked
+    rec = {"index": 0, "max_tokens": 8, "completion_tokens": 8,
+           "prompt_tokens_asked": 1500}
+    assert checks.structure_check([dict(rec, prompt_tokens=1500)], [], 512)["ok"]
+    cut = checks.structure_check([dict(rec, prompt_tokens=1024)], [], 512)
+    assert not cut["ok"] and "1500 tokens served as 1024" in cut["problems"][0]
 
 
 TRAIN_CONFIG = common.load_cell("mistral7b-train-fsdp4")[2]
@@ -159,9 +165,10 @@ TRAIN_CONFIG = common.load_cell("mistral7b-train-fsdp4")[2]
 def built_train():
     """Mesh, shardings and jitted programs of the tiny train cell on four
     of the virtual CPU devices: what does not depend on the seed."""
-    return train_cell.build(common.sizes(TRAIN_CONFIG, True),
-                            common.section(TRAIN_CONFIG, "trainer", True),
-                            4, True)
+    return train_cell.build(
+        TRAIN_CONFIG["model_family"],
+        common.family(TRAIN_CONFIG).sizes(TRAIN_CONFIG, True),
+        common.section(TRAIN_CONFIG, "trainer", True), 4, True)
 
 
 @pytest.mark.parametrize("seed", SEEDS)

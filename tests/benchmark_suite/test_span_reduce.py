@@ -20,6 +20,8 @@ PATH = "jit(<lambda>)/decode_block/while/body/closed_call/decode_step/" \
 KERNEL = PATH + "attn/paged_decode_attention/pallas_call:"
 MLP = PATH + "mlp/dot_general:"
 SIZES = {"dim": 256, "n_heads": 4, "n_kv_heads": 2, "n_layers": 2}
+FAM = common.family(common.load_cell("mistral7b-serve-chat")[2])
+SCOPES = tuple(FAM.MODEL_SCOPES)
 
 
 def _program(name, start, ops):
@@ -91,20 +93,27 @@ HAND = sr.from_rows(hand_rows())
 
 
 def test_scope_and_kernel_are_read_from_the_path():
-    assert sr.layer_of(KERNEL) == "attn" and sr.layer_of(MLP) == "mlp"
+    assert sr.layer_of(KERNEL, SCOPES) == "attn"
+    assert sr.layer_of(MLP, SCOPES) == "mlp"
+    # the model's scopes are the family's: another family's list tells
+    # another story of the same path
+    assert sr.layer_of(MLP, ("experts",)) == "decode_step"
     assert sr.kernel_of(KERNEL) == "paged_decode_attention"
     assert sr.kernel_of(MLP) == ""
-    assert sr.layer_of("jit(<lambda>)/decode_block/while:") == "decode_block"
-    assert sr.layer_of(PATH + "kv_write/scatter:") == "kv_write"
-    assert sr.layer_of("") == "" and sr.layer_of("jit(concatenate)/x:") == ""
+    assert sr.layer_of("jit(<lambda>)/decode_block/while:", SCOPES) \
+        == "decode_block"
+    assert sr.layer_of(PATH + "kv_write/scatter:", SCOPES) == "kv_write"
+    assert sr.layer_of("", SCOPES) == ""
+    assert sr.layer_of("jit(concatenate)/x:", SCOPES) == ""
     # under autodiff a scope is wrapped
     bwd = "jit(step)/jit(main)/transpose(jvp(attn))/shard_map/" \
         "flash_bwd_dq/pallas_call:"
-    assert sr.scope_path(bwd)[2] == "attn" and sr.layer_of(bwd) == "attn"
+    assert sr.scope_path(bwd)[2] == "attn"
+    assert sr.layer_of(bwd, SCOPES) == "attn"
     assert sr.kernel_of(bwd) == "flash_bwd_dq"
-    assert sr.layer_of("jit(step)/jvp(loss)/while/body/lm_head/dot:") \
-        == "lm_head"
-    assert sr.layer_of("jit(step)/optimizer/mul:") == "optimizer"
+    assert sr.layer_of("jit(step)/jvp(loss)/while/body/lm_head/dot:",
+                       SCOPES) == "lm_head"
+    assert sr.layer_of("jit(step)/optimizer/mul:", SCOPES) == "optimizer"
 
 
 def test_executions_and_dispatches_are_matched_as_one_stream():
@@ -124,9 +133,9 @@ def test_hand_counts_of_every_metric():
     # 0.4 ms for the 100 tokens of the one matched prefill
     assert sr.prefill_traced_ms_per_ktok(HAND, 2) == pytest.approx(4.0)
     # kernels 1000 + mlp 600 + sample 100 of 2400 us of ops
-    assert sr.model_op_share(HAND, trace_reduce.is_decode_program) \
+    assert sr.model_op_share(HAND, trace_reduce.is_decode_program, SCOPES) \
         == pytest.approx(100 * 1700 / 2400)
-    by = sr.device_by_scope(HAND, trace_reduce.is_decode_program)
+    by = sr.device_by_scope(HAND, trace_reduce.is_decode_program, SCOPES)
     assert by == pytest.approx({"attn": 1e-3, "mlp": 6e-4, "sample": 1e-4,
                                 "decode_block": 2e-4, "kv_write": 1e-4,
                                 "": 4e-4})
@@ -207,19 +216,56 @@ def test_recorded_chip_trace():
     assert sr.decode_step_traced_ms(t, 16) == pytest.approx(69.117, abs=1e-3)
     assert sr.prefill_traced_ms_per_ktok(t, 16) == pytest.approx(
         193.045, abs=1e-3)
-    assert sr.model_op_share(t, trace_reduce.is_decode_program) \
+    assert sr.model_op_share(t, trace_reduce.is_decode_program, SCOPES) \
         == pytest.approx(23.051, abs=1e-3)
     assert sr.idle_host_busy_share(t) == pytest.approx(1.4947, abs=1e-3)
     assert sr.paged_decode_roofline_traced(
         t, {"dim": 4096, "n_heads": 32, "n_kv_heads": 8, "n_layers": 16},
         common.peaks("TPU v5 lite")) == pytest.approx(9.618, abs=1e-2)
-    by = sr.device_by_scope(t, trace_reduce.is_decode_program)
+    by = sr.device_by_scope(t, trace_reduce.is_decode_program, SCOPES)
     # in these blocks of one step the pool's copies sit under the layer
     # scan (decode_step: the stacked pool sliced and written back) and
     # under kv_write, and outweigh the model's own ops
     assert by["decode_step"] > by["attn"] + by["mlp"] > 0.03
     assert by["kv_write"] > 0.01 and by["decode_block"] < 1e-6
     assert sr.name_idle_gaps(t)[0][0] == "patch_flush before jit_concatenate"
+
+
+def test_span_is_split_into_decode_prefill_and_idle():
+    """By hand: span 3100 us; decode blocks 1000 + 800 + 600 (the 10 us
+    slot patch is neither), the prefill 400, idle 50 + 40 + 200."""
+    assert sr.program_split(HAND, 2) == pytest.approx(
+        {"span_s": 3100e-6, "decode_s": 2400e-6, "prefill_s": 400e-6,
+         "idle_s": 290e-6})
+    assert sr.prefill_program_share(HAND, 2) == pytest.approx(100 * 400 / 3100)
+    # the reader prints what span_reduce's own summary prints
+    rep = sr.report(HAND, 2, trace_reduce.is_decode_program, SCOPES)
+    assert rep["split_s"] == sr.program_split(HAND, 2)
+
+
+def test_prefill_program_share_on_the_recorded_trace_and_on_none():
+    t = sr.load(RECORDED)
+    split = sr.report(t, 16, trace_reduce.is_decode_program, SCOPES)["split_s"]
+    reader = common.load_module("metrics", "prefill_program_share.chat")
+    run = {"span_trace": t, "sizes": {"n_layers": 16}}
+    assert reader.reduce(run) == pytest.approx(
+        100 * split["prefill_s"] / split["span_s"])
+    # seven prefill executions and two one-step decode blocks (PR 25's
+    # engine: a cut made between prefills), idle 2.7 %
+    assert split == pytest.approx({
+        "span_s": 0.634599, "decode_s": 0.138234, "prefill_s": 0.436739,
+        "idle_s": 0.016895}, abs=1e-6)
+    assert reader.reduce(run) == pytest.approx(68.8213, abs=1e-3)
+    assert split["decode_s"] + split["prefill_s"] + split["idle_s"] \
+        <= split["span_s"]
+    # a trace without a prefill program: nothing to read
+    rows = [r for r in hand_rows() if r[1] != "jit_impl"
+            and "jit(impl)" not in str(r[4])]
+    run = {"span_trace": sr.from_rows(rows), "sizes": {"n_layers": 2}}
+    assert sr.program_split(run["span_trace"], 2)["prefill_s"] == 0.0
+    assert reader.reduce(run) is None
+    assert reader.reduce({"span_trace": None, "sizes": {"n_layers": 2},
+                          "trace_dir": None}) is None
 
 
 # ---- the wire-format reader, on a file encoded here ------------------------
@@ -306,11 +352,12 @@ NEW = ["decode_step_traced_ms.chat", "decode_step_traced_ms.peak",
        "model_op_share.peak", "model_op_share.train",
        "idle_host_busy_share.chat", "idle_host_busy_share.peak",
        "engine_loop_busy_share.chat", "engine_loop_busy_share.peak",
-       "paged_decode_roofline_traced"]
+       "paged_decode_roofline_traced", "prefill_program_share.chat",
+       "prefill_program_share.peak"]
 
 
 def _run(trace, kind="serve"):
-    return {"kind": kind, "span_trace": trace, "sizes": SIZES,
+    return {"kind": kind, "span_trace": trace, "sizes": SIZES, "family": FAM,
             "device": {"kind": "TPU v5 lite"}, "window": {"seconds": 50.0},
             "stats_before": {"phase_loop_wait_s_total": 1.0,
                              "phase_harvest_s_total": 2.0, "steps": 1},
@@ -345,7 +392,8 @@ def test_metric_file_reads_the_hand_trace(metric):
             "harvest before jit__lambda", pytest.approx(200e-6)]
 
 
-@pytest.mark.parametrize("metric", NEW)
+@pytest.mark.parametrize("metric", [m for m in NEW if not m.startswith(
+    "prefill_program_share")])      # that one needs the programs' names only
 def test_metric_file_finds_nothing_in_a_program_without_spans_or_scopes(
         metric):
     """The parent commit: programs found by jit name only, no rt/ span, no

@@ -184,3 +184,52 @@ def test_both_serve_cells_order_their_lengths_by_the_one_rule(cell):
     pa = [(r["prompt_tokens"], r["max_tokens"]) for r in a]
     pb = [(r["prompt_tokens"], r["max_tokens"]) for r in b]
     assert pa != pb and any(pb[k:] + pb[:k] == pa for k in range(len(pb)))
+
+
+LONG = {"generator": "open_loop", "rate_per_s": 4.4, "schedule_seed": 24,
+        "prompt_tokens": {"median": 1408, "sigma": 0.25, "min": 1024,
+                          "max": 1920},
+        "output_tokens": {"median": 32, "sigma": 0.5, "min": 16, "max": 64},
+        "drain_timeout_s": 60}
+
+
+def test_the_serve_configuration_admits_long_prompts_uncut():
+    """The traffic of the queued cell mistral7b-serve-longprompt (PERF.md
+    section 7; measured by PR 27, not added) is a row of data for the
+    configuration as it stands: every prompt lies above the engine's
+    prefill_chunk (none uses a whole-prompt program) and within its
+    max_prompt_len (none is cut), prompt + output fits max_seq_len, and
+    the warm set holds every bucket a final chunk can fall into."""
+    config = common.load_cell("mistral7b-serve-chat")[2]
+    eng = config["engine"]
+    assert eng["prefill_chunk"] < LONG["prompt_tokens"]["min"]
+    assert LONG["prompt_tokens"]["max"] <= eng["max_prompt_len"]
+    assert LONG["prompt_tokens"]["max"] + LONG["output_tokens"]["max"] \
+        <= eng["max_seq_len"]
+    for seed in (3, 2**31 + 77):
+        reqs = open_loop.plan(LONG, seed, 51.0)["requests"]
+        assert len(reqs) == 224
+        for r in reqs:
+            assert 1024 <= r["prompt_tokens"] <= 1920
+            assert 16 <= r["max_tokens"] <= 64
+            progs = common.programs_for_prompt(r["prompt_tokens"], eng)
+            assert 2 <= len(progs) <= 4
+            assert {k for k, _b in progs} == {"chunk"}
+    warm = common.warm_prompt_lengths(1024, 1920, eng)
+    finals = {common.programs_for_prompt(n, eng)[-1] for n in warm}
+    assert finals == {("chunk", b) for b in (16, 32, 64, 128, 256, 512)}
+    assert all(1024 <= n <= 1920 for n in warm)
+
+
+def test_chat_and_peak_run_the_programs_they_ran_under_the_old_cap():
+    """max_prompt_len went 1024 -> 1920 (PR 27): no bucket up to
+    prefill_chunk is capped by either, so prompts up to 1024 use the same
+    programs, and the cells' warm sets are the same."""
+    eng = common.load_cell("mistral7b-serve-chat")[2]["engine"]
+    old = dict(eng, max_prompt_len=1024)
+    assert eng["max_prompt_len"] == 1920
+    for n in range(33, 1025):
+        assert common.programs_for_prompt(n, eng) \
+            == common.programs_for_prompt(n, old)
+    assert common.warm_prompt_lengths(33, 1024, eng) \
+        == common.warm_prompt_lengths(33, 1024, old)
