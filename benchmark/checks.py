@@ -6,17 +6,24 @@ and seed everywhere.
    of the configuration's model family (benchmark/models/<family>.py),
    at the published widths and a shallow depth, weights and tokens from the
    seed. Max absolute logit error within the configuration's tolerance.
+   The programs come through the adapter (``paged_programs``). For a
+   family with routed experts (its adapter has ``routing_taken``) the
+   programs' choice of experts is forced on the reference and itself held
+   to the reference's scores, and the root-mean-square logit error is
+   held beside the maximum (benchmark/reference/__init__.py says why).
 2. served_tokens_check: tokens the replica served, teacher-forced through
    the float32 reference at the served depth with the served weights.
    Every served token's reference logit lies within the configuration's
    margin of the reference maximum at its position. Never one greedy run
-   against another.
+   against another. (A routed family's tokens go through the reference's
+   own routing: the margin is wider and guards tokens, not precision.)
 3. structure_check: every completed stream delivered its max_tokens or
    fewer (the engine swallows the stop token, so fewer means it stopped);
    no prompt was cut (the server counted the tokens the client sent);
    every token id inside the vocabulary; nothing NaN.
 (4. the device and the compiled kernel: a run without them exits non-zero;
-   see ``require_device``.)
+   see ``require_device``, and ``stated_backend`` for which backend the
+   configuration's file holds check 1 of a chip run to.)
 
 Run as a script this is the child that holds the chip after the replica
 has gone: ``python benchmark/checks.py <spec.json>`` prints one JSON line.
@@ -24,7 +31,6 @@ has gone: ``python benchmark/checks.py <spec.json>`` prints one JSON line.
 
 from __future__ import annotations
 
-import functools
 import json
 import math
 import os
@@ -53,41 +59,38 @@ def require_device(chips: int, rehearsal: bool) -> dict:
 
 # ---- 1. logits ---------------------------------------------------------------
 
-@functools.lru_cache(maxsize=8)
-def _paged_programs(cfg, page: int, backend: str):
-    """The engine's paged programs (kv_cache.py), jitted once per shape."""
-    import jax
-
-    from ray_tpu.serve.llm import kv_cache as kvc
-    return (
-        jax.jit(lambda p, kv, t, x, n: kvc.paged_prefill(
-            p, kv, t, x, n, cfg, page)),
-        jax.jit(lambda p, kv, t, x, s, n: kvc.paged_prefill_chunk(
-            p, kv, t, x, s, n, cfg, page, backend)),
-        jax.jit(lambda p, kv, t, sl, x: kvc.paged_decode_step(
-            p, kv, t, sl, x, cfg, page, backend)))
-
-
 def logits_check(fam, sz: dict, engine: dict, spec: dict, seed: int,
                  mutate=None, **reference_override) -> dict:
-    """``fam`` is the family's adapter (``common.family``).
-    ``mutate(params) -> params`` and ``reference_override`` are the
-    negative controls' hooks (tests): the ENGINE side runs the mutated
-    weights while the reference keeps the originals; an override (the
-    dense block's ``use_rope=False``) compares the engine with a reference
-    that leaves part of the mathematics out."""
+    """``fam`` is the family's adapter (``common.family``); the programs
+    are the ones its ``paged_programs`` hands out, the cache whatever
+    pytree they keep. ``mutate(params) -> params`` and
+    ``reference_override`` are the negative controls' hooks (tests): the
+    ENGINE side runs the mutated weights while the reference keeps the
+    originals; an override (the dense block's ``use_rope=False``) compares
+    the engine with a reference that leaves part of the mathematics out.
+
+    A family whose adapter has ``routing_taken`` has its programs' choice
+    of experts read after every call and forced on the reference, whose
+    combine weights stay its own: the logits are then a continuous
+    function of the arithmetic again and ``tolerance`` can be as tight as
+    a dense family's. The choice itself is held to the reference's scores
+    (``routing_slack``: how far below the reference's k-th best the worst
+    chosen expert lies) by two limits of the configuration's file, and
+    the root-mean-square error over the compared logits to
+    ``rms_tolerance``: steady from seed to seed where a maximum over a
+    million logits swings, so it is the number that a lower precision of
+    the experts has to fail."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
-    from ray_tpu.serve.llm import kv_cache as kvc
-
     ref_mod = common.reference(fam)
+    taken = getattr(fam, "routing_taken", None)   # a family that routes
     cfg = fam.model_config(sz, n_layers=spec["depth"])
     page, cap = engine["page_size"], engine["max_prompt_len"]
     chunk = engine["prefill_chunk"]
     max_pages = -(-engine["max_seq_len"] // page)
-    backend = kvc.resolve_attention_backend(
+    backend = fam.attention_backend(
         engine.get("attention_kernel", "auto"), cfg, page)
     key = common.fold_seed(seed)
     k_w, k_t = jax.random.split(key)
@@ -101,18 +104,26 @@ def logits_check(fam, sz: dict, engine: dict, spec: dict, seed: int,
         k_t, (2, max(pa, pb) + d), 0, cfg.vocab_size), np.int32)
     seq_a, seq_b = toks[0, :pa + d], toks[1, :pb + d]
 
-    n_pages = 2 * max_pages + 1
-    kv = kvc.init_paged_cache(cfg, n_pages, page)
+    init_cache, prefill, chunk_fn, decode = fam.paged_programs(
+        cfg, page, backend)
+    kv = init_cache(2 * max_pages + 1)
     tables = np.zeros((4, max_pages), np.int32)
     tables[0] = 1 + np.arange(max_pages)
     tables[1] = 1 + max_pages + np.arange(max_pages)
-
-    prefill, chunk_fn, decode = _paged_programs(cfg, page, backend)
 
     def padded(seg, width):
         out = np.zeros((1, width), np.int32)
         out[0, :len(seg)] = seg
         return jnp.asarray(out)
+
+    # a routed family: the experts each call chose, [L_r, rows, k] a call,
+    # laid end to end along each sequence's positions
+    choice_a, choice_b = [], []
+
+    def chose(into, lo, hi):
+        """The experts rows lo..hi of the last call took, [L_r, rows, k]."""
+        if taken is not None:
+            into.append(np.asarray(taken(kv))[:, lo:hi])
 
     t0 = time.perf_counter()
     got_a, got_b = [], []
@@ -120,37 +131,48 @@ def logits_check(fam, sz: dict, engine: dict, spec: dict, seed: int,
                      padded(seq_a[:pa], common.prefill_bucket(pa, cap)),
                      jnp.int32(pa))
     got_a.append(lg)
+    chose(choice_a, 0, pa)
     start = 0
     while pb - start > chunk:
         _, kv = chunk_fn(served, kv, jnp.asarray(tables[1]),
                          padded(seq_b[start:start + chunk], chunk),
                          jnp.int32(start), jnp.int32(pb))
+        chose(choice_b, 0, chunk)
         start += chunk
     lg, kv = chunk_fn(served, kv, jnp.asarray(tables[1]),
                       padded(seq_b[start:pb],
                              common.prefill_bucket(pb - start, cap)),
                       jnp.int32(start), jnp.int32(pb))
     got_b.append(lg)
+    chose(choice_b, 0, pb - start)
     lens = jnp.asarray([pa, pb, 0, 0], jnp.int32)
     for i in range(d):
         cur = jnp.asarray([seq_a[pa + i], seq_b[pb + i], 0, 0], jnp.int32)
         lg, kv, lens = decode(served, kv, jnp.asarray(tables), lens, cur)
         got_a.append(lg[0])
         got_b.append(lg[1])
+        chose(choice_a, 0, 1)
+        chose(choice_b, 1, 2)
     got_a, got_b = jnp.stack(got_a), jnp.stack(got_b)
     jax.block_until_ready(got_b)
     program_s = time.perf_counter() - t0
 
     t0 = time.perf_counter()
     ref = fam.reference_kwargs(cfg, **reference_override)
-    want_a = ref_mod.logits_at(params, seq_a[None],
-                               np.arange(pa - 1, pa + d), **ref)[0]
-    want_b = ref_mod.logits_at(params, seq_b[None],
-                               np.arange(pb - 1, pb + d), **ref)[0]
-    errs = {}
-    for name, got, want in (("whole_prefill+decode", got_a, want_a),
-                            ("chunked_prefill+decode", got_b, want_b)):
+    errs, slack, squares = {}, [], []
+    for name, seq, n, got, choice in (
+            ("whole_prefill+decode", seq_a, pa, got_a, choice_a),
+            ("chunked_prefill+decode", seq_b, pb, got_b, choice_b)):
+        forced = {}
+        if taken is not None:
+            forced["routing"] = np.concatenate(choice, axis=1)
+            slack.append(np.asarray(ref_mod.routing_slack(
+                params, seq[None], forced["routing"], **ref)))
+        want = ref_mod.logits_at(params, seq[None], np.arange(n - 1, n + d),
+                                 **ref, **forced)[0]
         diff = jnp.abs(got.astype(jnp.float32) - want)
+        if taken is not None:
+            squares.append(float(jnp.mean(diff * diff)))
         errs[name] = {
             "max_abs_err": float(jnp.max(diff)),
             "prefill_max_abs_err": float(jnp.max(diff[0])),
@@ -158,10 +180,39 @@ def logits_check(fam, sz: dict, engine: dict, spec: dict, seed: int,
             "finite": bool(jnp.all(jnp.isfinite(got.astype(jnp.float32))))}
     worst = max(e["max_abs_err"] for e in errs.values())
     finite = all(e["finite"] for e in errs.values())
-    return {"ok": bool(finite and worst <= spec["tolerance"]),
-            "max_abs_err": worst, "tolerance": spec["tolerance"],
-            "depth": spec["depth"], "backend": backend, "errors": errs,
-            "program_s": program_s, "reference_s": time.perf_counter() - t0}
+    out = {"ok": bool(finite and worst <= spec["tolerance"]),
+           "max_abs_err": worst, "tolerance": spec["tolerance"],
+           "depth": spec["depth"], "backend": backend, "errors": errs}
+    if taken is not None:
+        out["rms_err"] = math.sqrt(sum(squares) / len(squares))
+        out["rms_tolerance"] = spec["rms_tolerance"]
+        out["routing"] = routing_verdict(
+            np.concatenate([s.ravel() for s in slack]),
+            spec["routing_slack"], spec["routing_flip_share_max"])
+        out["ok"] = bool(out["ok"] and out["routing"]["ok"]
+                         and out["rms_err"] <= out["rms_tolerance"])
+    out.update(program_s=program_s, reference_s=time.perf_counter() - t0)
+    return out
+
+
+def routing_verdict(slack, slack_limit: float, flip_share_max: float) -> dict:
+    """``slack``: one number a routing decision (a token in a layer that
+    routes), the reference's k-th largest selection score minus the
+    smallest among the experts the program chose: 0 where the program
+    chose the reference's top k, ``inf`` for an expert named twice or not
+    there. A decision with slack above 0 has turned over. Sound programs
+    turn a few near-ties over, by no more than their arithmetic's noise in
+    a score; a wrong rule turns many, or one by much."""
+    import numpy as np
+    slack = np.asarray(slack, np.float64)
+    flipped = int(np.sum(slack > 0))
+    worst = float(np.max(slack)) if slack.size else float("inf")
+    share = flipped / slack.size if slack.size else 1.0
+    return {"ok": bool(math.isfinite(worst) and worst <= slack_limit
+                       and share <= flip_share_max),
+            "decisions": int(slack.size), "flipped": flipped,
+            "flip_share": share, "max_slack": worst,
+            "slack_limit": slack_limit, "flip_share_max": flip_share_max}
 
 
 # ---- 2. served tokens, teacher-forced ---------------------------------------------
@@ -261,6 +312,23 @@ def train_structure_check(losses: list[float], first_ref: float,
 
 # ---- the child ---------------------------------------------------------------------
 
+def stated_backend(logits_spec: dict) -> str:
+    """The attention backend check 1 of a chip run must resolve to:
+    ``checks.logits.backend`` of the configuration's file, "pallas" where
+    the file says nothing. A file may state "gather" (a block the Pallas
+    kernel cannot tile: heads of 64, a latent cache) only with its reason.
+    The gate on the REPLICA (serve_cell) is not this one: the engine
+    serves through the compiled kernel or not at all."""
+    want = logits_spec.get("backend", "pallas")
+    if want not in ("pallas", "gather"):
+        raise common.BenchError(
+            f"checks.logits.backend is {want!r}: 'pallas' or 'gather'")
+    if want == "gather" and not logits_spec.get("backend_why"):
+        raise common.BenchError("checks.logits.backend is 'gather' and the "
+                                "file gives no backend_why")
+    return want
+
+
 def serve_child(spec: dict) -> dict:
     """Checks 1 and 2 of a serve cell in one process that holds the chip
     (after the replica released it)."""
@@ -274,12 +342,14 @@ def serve_child(spec: dict) -> dict:
     sz, engine = spec["sizes"], spec["engine"]
     out = {"device": device, "interpret": bool(paged_ops.interpret_default())}
     t0 = time.perf_counter()
+    stated = stated_backend(spec["checks"]["logits"])
     out["logits"] = logits_check(fam, sz, engine, spec["checks"]["logits"],
                                  spec["seed"])
-    if not rehearsal and (out["logits"]["backend"] != "pallas"
+    if not rehearsal and (out["logits"]["backend"] != stated
                           or out["interpret"]):
         print(f"benchmark: attention backend {out['logits']['backend']!r}, "
-              f"interpret={out['interpret']}: not the compiled kernel",
+              f"interpret={out['interpret']}: not the compiled "
+              f"{stated!r} the configuration's file states",
               file=sys.stderr)
         raise SystemExit(3)
     out["logits_s"] = time.perf_counter() - t0

@@ -82,9 +82,12 @@ def family(config: dict, root: str = ROOT):
     return load_module("models", config["model_family"], root)
 
 
-def reference(fam, root: str = ROOT):
+def reference(fam):
     """The plain reference a family's adapter names (benchmark/reference/
-    __init__.py has the contract)."""
+    __init__.py has the contract), from the checkout that holds the
+    adapter (benchmark/models/<family>.py)."""
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(fam.__file__))))
     return load_module("reference", fam.REFERENCE, root)
 
 

@@ -13,4 +13,5 @@ def reduce(run):
     trace = span_reduce.of_run(run)
     if trace is None:
         return None
-    return span_reduce.decode_step_traced_ms(trace, run["sizes"]["n_layers"])
+    return span_reduce.decode_step_traced_ms(
+        trace, span_reduce.attn_layers(run["sizes"]))

@@ -12,4 +12,5 @@ def reduce(run):
     trace = span_reduce.of_run(run)
     if trace is None:
         return None
-    return span_reduce.prefill_program_share(trace, run["sizes"]["n_layers"])
+    return span_reduce.prefill_program_share(
+        trace, span_reduce.attn_layers(run["sizes"]))

@@ -12,4 +12,4 @@ def reduce(run):
     if trace is None:
         return None
     return span_reduce.prefill_traced_ms_per_ktok(
-        trace, run["sizes"]["n_layers"])
+        trace, span_reduce.attn_layers(run["sizes"]))

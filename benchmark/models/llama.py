@@ -20,12 +20,58 @@ these names; the harness never edits this one):
                                       the train path
     params(sz), train_flops_per_token(sz, seq_len)
                                       the family's counts (``train_mfu``)
+    attention_backend(kind, cfg, page)
+                                      the backend the engine's
+                                      ``attention_kernel`` resolves to for
+                                      this block ("pallas" | "gather")
+    paged_programs(cfg, page, backend)
+        -> (init_cache(n_pages) -> cache,
+            prefill(params, cache, table [P], tokens [1, W], n)
+                -> (logits [V], cache),
+            chunk(params, cache, table [P], tokens [1, W], start, total)
+                -> (logits [V], cache),
+            decode(params, cache, tables [B, P], lens [B], tokens [B])
+                -> (logits [B, V], cache, lens + 1))
+                                      the programs check 1 drives, jitted,
+                                      the same objects on every call with
+                                      the same arguments; the cache is an
+                                      opaque pytree (pages, and whatever
+                                      state a block keeps beside them)
+
+``sizes()`` may also hold ``attn_layers``: how many of ``n_layers`` call the
+paged attention kernel (absent = all of them; the trace readers count a
+decode execution's steps as kernel calls over it). ``model_config(sz,
+n_layers=depth)`` is check 1's model: where layers differ in kind, the
+first ``depth`` of them have to hold every kind (the configuration's
+``checks.logits.depth`` is chosen so).
+
+A family with routed experts also provides (and only such a family: it is
+by this name that check 1 knows one, and without it none of check 1's
+routing code runs):
+
+    routing_taken(cache) -> int32 [L_r, rows, k]
+                                      the experts the LAST program call
+                                      chose for each of its token rows (a
+                                      prefill's or a chunk's W rows, a
+                                      decode's B rows; more rows than the
+                                      call had are ignored), L_r the layers
+                                      that route, in order; where the
+                                      program keeps the record is its matter
+
+Its reference takes ``routing=`` and provides ``routing_slack``
+(benchmark/reference/__init__.py), and its configuration's
+``checks.logits`` states ``rms_tolerance``, ``routing_slack`` and
+``routing_flip_share_max`` beside ``tolerance``. ``checks.logits.backend`` (absent = "pallas") is the
+attention backend a chip run must resolve to; a file that states "gather"
+says why in ``backend_why``.
 
 Which block the engine's paged programs run is the program's matter: the
 harness hands them the ``cfg`` built here.
 """
 
 from __future__ import annotations
+
+import functools
 
 REFERENCE = "llama_f32"
 MODEL_SCOPES = ("embed", "norm", "attn", "mlp", "lm_head", "sample", "loss",
@@ -69,6 +115,27 @@ def model_config(sz: dict, n_layers: int | None = None,
 def init_params(key, cfg):
     from ray_tpu.models import llama
     return llama.init_params(key, cfg)
+
+
+def attention_backend(kind, cfg, page: int) -> str:
+    from ray_tpu.serve.llm import kv_cache as kvc
+    return kvc.resolve_attention_backend(kind, cfg, page)
+
+
+@functools.lru_cache(maxsize=8)
+def paged_programs(cfg, page: int, backend: str):
+    """The engine's paged programs (kv_cache.py), jitted once per shape."""
+    import jax
+
+    from ray_tpu.serve.llm import kv_cache as kvc
+    return (
+        lambda n_pages: kvc.init_paged_cache(cfg, n_pages, page),
+        jax.jit(lambda p, kv, t, x, n: kvc.paged_prefill(
+            p, kv, t, x, n, cfg, page)),
+        jax.jit(lambda p, kv, t, x, s, n: kvc.paged_prefill_chunk(
+            p, kv, t, x, s, n, cfg, page, backend)),
+        jax.jit(lambda p, kv, t, sl, x: kvc.paged_decode_step(
+            p, kv, t, sl, x, cfg, page, backend)))
 
 
 def reference_kwargs(cfg, **override) -> dict:

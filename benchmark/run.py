@@ -18,20 +18,32 @@ tiny preset on the CPU: its line says ``"platform": "cpu"`` and
 
 Adding a model family takes four new files and three manifest entries,
 and no edit of a file that is here:
-  benchmark/models/<family>.py     the adapter: published keys -> sizes,
-      the program's model config and init, the reference's name and
-      keywords, the train functions, counts, scopes (the docstring of the
-      family that is there lists the names)
-  benchmark/reference/<name>.py    the plain float32 reference
+  benchmark/models/<family>.py     the adapter: published keys -> sizes
+      (with ``attn_layers`` where not every layer calls the paged kernel),
+      the program's model config and init (``model_config(sz,
+      n_layers=depth)`` at a depth that holds every kind of layer), the
+      reference's name and keywords, ``attention_backend`` and
+      ``paged_programs`` (the four programs check 1 drives; the cache is
+      the family's own pytree), the train functions, counts, scopes; for
+      routed experts ``routing_taken`` (the docstring of the family that
+      is there lists the names)
+  benchmark/reference/<name>.py    the plain float32 reference; for
+      routed experts it takes ``routing=`` and provides ``routing_slack``
       (contract: docstring of benchmark/reference/__init__.py)
   benchmark/configs/<config>.json  ``model_family``, ``published`` (the
       source's shape keys verbatim), the keys again as run, ``reduced``,
       ``engine`` (with ``tp_degree`` = the cell's chips) or ``trainer``,
-      ``checks``, ``rehearsal``
+      ``checks`` (for routed experts ``rms_tolerance``, ``routing_slack``
+      and ``routing_flip_share_max`` beside ``tolerance``; where the block
+      cannot take the Pallas kernel ``checks.logits.backend``: "gather"
+      with its ``backend_why``), ``rehearsal``
   benchmark/workloads/<cell>.json  the traffic, for a generator that exists
 and in BENCHMARK.json one entry each under ``configs`` and ``workloads``,
 and the cell's name under the ``workloads`` of the metrics it reports.
-tests/benchmark_suite/test_manifest.py does exactly this in a copy.
+tests/benchmark_suite/test_manifest.py does exactly this in a copy, and
+test_routed_family.py puts a family with routed experts through the checks.
+What the ENGINE can serve is another matter: it builds the dense block
+only (PERF.md section 7).
 """
 
 from __future__ import annotations
@@ -178,10 +190,30 @@ def main() -> int:
         "waited_for_chips_s": {"at_start": waited, "at_end": end_wait},
         "extra": run.get("extra"),
         "wall_s": time.time() - T_PROCESS})
+    for line in compared(run["checks"]):
+        print(f"benchmark: {line}", file=sys.stderr)
     sys.stderr.flush()
     print(json.dumps({"report": report}))
     print(json.dumps(result), flush=True)
     return 0
+
+
+def compared(checks: dict) -> list[str]:
+    """One line a check, the last lines of a run's standard error: every
+    scalar of the check's dictionary under the check's own names, so each
+    number compared lies beside its limit; a dictionary inside it one
+    level down as ``<name>.<key>``, a list cut to 120 characters. No
+    table of names: a check that brings a new number brings its line."""
+    def flat(d: dict, prefix: str = ""):
+        for k, v in d.items():
+            if isinstance(v, dict):
+                if not prefix:
+                    yield from flat(v, f"{k}.")
+            else:
+                yield f"{prefix}{k} " + (repr(v)[:120] if isinstance(v, list)
+                                         else repr(v))
+    return [f"check {name}: " + "; ".join(flat(c))
+            for name, c in checks.items()]
 
 
 def run_counts(run: dict) -> tuple[int, int]:

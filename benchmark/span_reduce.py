@@ -404,10 +404,18 @@ def leaf_spans(trace: dict) -> list[tuple[str, int, int]]:
 
 # ---- one ordered stream: executions and the spans that dispatched them ------
 
+def attn_layers(sizes: dict) -> int:
+    """How many of a model's layers call the paged attention kernel: what
+    a family's ``sizes()`` states as ``attn_layers`` (a hybrid block: 3 of
+    14), else every layer."""
+    return sizes.get("attn_layers", sizes["n_layers"])
+
+
 def executions(trace: dict, n_layers: int) -> list[dict]:
     """The engine's big programs in the order the device ran them: decode
     blocks and verify rounds (``jit__lambda``; the kind and the steps from
-    the calls of the kernel inside, over the layers) and prefills or
+    the calls of the kernel inside, over the layers that call it:
+    ``n_layers`` here and below is ``attn_layers(sizes)``) and prefills or
     prefill chunks (``jit_impl``). A ``jit__lambda`` without a kernel of
     ours inside (a program compiled before the kernels had names) stays a
     decode block of 0 steps."""
@@ -595,14 +603,15 @@ def paged_decode_roofline_traced(trace: dict, sizes: dict,
     from benchmark import costs
     need_s = took_s = 0.0
     hd = sizes["dim"] // sizes["n_heads"]
-    for x, d in match_stream(trace, sizes["n_layers"])["pairs"]:
+    layers = attn_layers(sizes)
+    for x, d in match_stream(trace, layers)["pairs"]:
         a = d["args"]
         if not (x["kind"] == "decode" == d["kind"]) \
                 or x["steps"] != a["k"] or not x["kernel_ns"]:
             continue
         for step in range(a["k"]):
             mean = (a["ctx_tokens"] + a["active"] * (step + 1)) / a["active"]
-            need_s += sizes["n_layers"] * costs.paged_decode_bytes(
+            need_s += layers * costs.paged_decode_bytes(
                 [mean] * a["active"], sizes["n_kv_heads"], hd,
                 sizes["n_heads"]) / peak["hbm_bytes_per_s"]
         took_s += x["kernel_ns"] / 1e9
@@ -678,7 +687,7 @@ def _programs(trace: dict) -> dict[str, float]:
 if __name__ == "__main__":
     # python3 benchmark/span_reduce.py <trace dir> <cell>
     #     [--cut <from_s> <to_s> <out.json>]
-    # the cell's configuration gives the depth, the model family (its
+    # the cell's configuration gives the layers, the model family (its
     # scopes) and which program is the main one
     from benchmark import common
     _entry, _cell, config = common.load_cell(sys.argv[2])
@@ -695,5 +704,5 @@ if __name__ == "__main__":
         for r in rows:
             r[2] -= base
         dump(rows, sys.argv[i + 3])
-    print(json.dumps(report(tr, fam.sizes(config, False)["n_layers"], main,
+    print(json.dumps(report(tr, attn_layers(fam.sizes(config, False)), main,
                             fam.MODEL_SCOPES), indent=1))

@@ -268,6 +268,75 @@ def test_prefill_program_share_on_the_recorded_trace_and_on_none():
                           "trace_dir": None}) is None
 
 
+# ---- a block of which only some layers call the paged kernel ---------------
+
+HYBRID = {"dim": 2048, "n_heads": 32, "n_kv_heads": 8, "n_layers": 14,
+          "attn_layers": 3}
+
+
+def hybrid_rows():
+    """3 attention layers of 14: a decode block of 8 steps makes 24 kernel
+    calls, one of 2 steps makes 6; then a prefill."""
+    kern = ("custom-call(kernel) bf16[4,8,8,64]", 10, KERNEL)
+    conv = ("fusion bf16[4,2048]", 20, PATH + "conv/mul:")
+    rows = []
+    for name, start, end, ops in [
+        ("jit__lambda", 0, 1120, ([kern] * 3 + [conv] * 4) * 8
+         + [("fusion bf16[4,512]", 240, MLP)]),
+        ("jit__lambda", 1200, 1800, ([kern] * 3 + [conv] * 4) * 2
+         + [("fusion bf16[4,512]", 380, MLP)]),
+        ("jit_impl", 1900, 2200, [("fusion bf16[1,128,32,64]", 300,
+                                   "jit(impl)/prefill/while/body/attn/dot:")]),
+    ]:
+        got, t = _program(name, start, ops)
+        assert t == end, (name, t)
+        rows += got + [["module", name, start * US, (end - start) * US, "", 0]]
+    rows += [
+        _span("loop_pass", 0, 2300),
+        _span("decode_dispatch", 10, 50, seq=1, k=8, w=4, active=4,
+              ctx_tokens=400),
+        _span("decode_dispatch", 60, 90, seq=2, k=2, w=4, active=4,
+              ctx_tokens=432),
+        _span("prefill", 100, 140, rid="c3", bucket=128, tokens=120),
+    ]
+    return rows
+
+
+def test_steps_are_kernel_calls_over_the_layers_that_call_the_kernel():
+    """ISSUE 29: 24 calls at ``attn_layers`` 3 are 8 steps; over the 14
+    layers of the block they are no whole number and the execution is
+    dropped, so every reader below would find nothing or the wrong thing."""
+    t = sr.from_rows(hybrid_rows())
+    assert sr.attn_layers(HYBRID) == 3 and sr.attn_layers(SIZES) == 2
+    ex = sr.executions(t, sr.attn_layers(HYBRID))
+    assert [(x["kind"], x.get("kernel_calls"), x.get("steps")) for x in ex] \
+        == [("decode", 24, 8.0), ("decode", 6, 2.0), ("prefill", None, None)]
+    assert [x["kind"] for x in sr.executions(t, 14)] == ["prefill"]
+    m = sr.match_stream(t, 3)
+    assert (m["lead"], m["unfit"], len(m["pairs"])) == (0, 0, 3)
+    run = _run(t)
+    run["sizes"] = HYBRID
+    read = lambda name: common.load_module("metrics", name).reduce(run)  # noqa: E731
+    assert read("decode_step_traced_ms.peak") == pytest.approx(1.72 / 10)
+    assert read("prefill_traced_ms_per_ktok") == pytest.approx(0.3 / 0.12)
+    assert read("prefill_program_share.peak") == pytest.approx(
+        100 * 300 / 2200)
+    # bytes: 3 layers a step, not 14; 4 slots, 400 (432) cached tokens as
+    # the block starts, one more a slot each step; 300 us of kernel time
+    hd = 64
+    need = sum(3 * (((ctx + 4 * (i + 1)) * 8 * hd * 2 * 2)
+                    + 4 * 32 * hd * 2 * 2)
+               for ctx, k in ((400, 8), (432, 2)) for i in range(k))
+    assert sr.paged_decode_roofline_traced(
+        t, HYBRID, {"hbm_bytes_per_s": 1e9}) == pytest.approx(
+            100 * need / 1e9 / 300e-6)
+    # a family that states nothing: every layer calls the kernel
+    run["sizes"] = {k: v for k, v in HYBRID.items() if k != "attn_layers"}
+    assert read("decode_step_traced_ms.peak") is None
+    assert sr.report(t, 3, trace_reduce.is_decode_program, SCOPES)[
+        "stream"]["decode_steps"] == 10
+
+
 # ---- the wire-format reader, on a file encoded here ------------------------
 
 def _vi(n):
