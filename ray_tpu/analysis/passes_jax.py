@@ -3,6 +3,10 @@
 host-sync guards the engine-loop design invariant from PR 6: dispatch
 phases are host-cost-only, and the device sync lives in the designated
 harvest methods (``_harvest_one`` / ``_apply_verify`` / the tier flush).
+Since PR 31 that includes the eager device ops that wait without saying
+so: ``jnp.stack`` / ``jnp.concatenate`` of device values and a tuple-
+unpacked ``random.split`` run on the calling thread, each behind whatever
+the device is doing.
 jit-hygiene guards against the mid-traffic-recompile class PR 6 had to
 build runtime detection for: jitted callables that close over mutable
 ``self`` state or branch in Python on traced values re-trace silently
@@ -21,12 +25,21 @@ from ray_tpu.analysis.core import ModuleSource, Pass, iter_functions, register
 # Harvest-designated methods (_harvest_one, _apply_verify), warmup, and
 # the tier spill/restore slow paths are exempt by name.
 HOT_METHOD_RE = re.compile(
-    r"^(_admit|_prefill|_prefill_chunks|_decode_step|_spec_step|"
+    r"^(_admit|_prefill|_prefill_chunks|_arm_slot|_decode_step|_spec_step|"
     r"_dispatch_verify|_select_block|_record_token|_flush_slot_patches|"
     r"_propose_locked|_shed_expired_waiting|_step|_loop|submit)$")
 
 # modules the host-sync pass applies to (the paged engine + its kin)
 HOT_PATH_RE = re.compile(r"serve/llm/")
+
+
+def _is_random_split(fn: ast.AST) -> bool:
+    """``jax.random.split`` / ``self._jax.random.split`` / ``random.split``."""
+    if not (isinstance(fn, ast.Attribute) and fn.attr == "split"):
+        return False
+    base = fn.value
+    return (isinstance(base, ast.Attribute) and base.attr == "random") \
+        or (isinstance(base, ast.Name) and base.id == "random")
 
 
 def _is_np_attr(fn: ast.AST, attrs: tuple) -> bool:
@@ -43,7 +56,11 @@ class HostSyncPass(Pass):
     ``jax.device_get`` and ``.block_until_ready()`` stall the engine loop
     on the device stream; they belong in the harvest phase (PR 6 phase
     timers attribute device wait there on purpose). ``jnp.asarray`` is
-    host->device and fine.
+    host->device and fine. ``jnp.stack`` / ``jnp.concatenate`` and
+    ``a, b = ...random.split(key)`` are eager device ops (one primitive an
+    operand, two slices of the split): on a busy device each blocks the
+    thread until the device reaches it. Build host values in numpy and
+    do device work inside a jitted program.
     """
 
     id = "host-sync"
@@ -60,16 +77,36 @@ class HostSyncPass(Pass):
             if cls is None or not HOT_METHOD_RE.match(fn.name):
                 continue
             for node in ast.walk(fn):
-                if not isinstance(node, ast.Call):
-                    continue
-                tag = self._sync_tag(node)
+                tag, what = self._tag(node)
                 if tag is not None:
                     findings.append(self.emit(
                         module, node, qualname,
-                        f"{tag} forces a device->host sync inside "
-                        f"{fn.name} (hot path)", tag,
+                        f"{tag} {what} inside {fn.name} (hot path)", tag,
                         extra_pragma_lines=(fn.lineno,)))
         return [f for f in findings if f is not None]
+
+    @classmethod
+    def _tag(cls, node: ast.AST) -> tuple:
+        """(tag, what it does) for a node the pass reports, else (None,
+        None)."""
+        if isinstance(node, ast.Call):
+            tag = cls._sync_tag(node)
+            if tag is not None:
+                return tag, "forces a device->host sync"
+            fn = node.func
+            if isinstance(fn, ast.Attribute) \
+                    and fn.attr in ("stack", "concatenate") \
+                    and isinstance(fn.value, ast.Name) \
+                    and fn.value.id == "jnp":
+                return (f"jnp.{fn.attr}",
+                        "is an eager device op on the loop thread")
+        elif isinstance(node, ast.Assign) \
+                and isinstance(node.targets[0], ast.Tuple) \
+                and isinstance(node.value, ast.Call) \
+                and _is_random_split(node.value.func):
+            return ("random.split", "unpacked eagerly slices a device "
+                    "array on the loop thread")
+        return None, None
 
     @staticmethod
     def _sync_tag(call: ast.Call) -> Optional[str]:

@@ -194,11 +194,13 @@ def prefill_only(eng: LLMEngine, prompt, *, temperature: float | None = None,
             padded = np.zeros((1, bucket), np.int32)
             padded[0, :plen] = toks
             fn = eng._prefill_fn(bucket)
-            eng._rng, sub = eng._jax.random.split(eng._rng)
-            tok_dev, eng.kv = fn(
-                eng.params, eng.kv, jnp.asarray(table), jnp.asarray(padded),
-                jnp.int32(plen), sub,
-                jnp.asarray([temperature], jnp.float32))
+            eng._rng, sub = eng._split_key(eng._rng)
+            # no slot is armed here: the program's first-token write goes
+            # to the trash row of the engine's token vector
+            tok_dev, eng._dev_tokens, eng.kv = fn(
+                eng.params, eng.kv, eng._dev_tokens, table, padded,
+                np.int32(plen), sub, np.full((1,), temperature, np.float32),
+                np.int32(eng.cfg.max_batch_size))
             # extract this request's pages to host (the handoff payload);
             # pool layout [L, Hkv, P, page, D] — pages are axis 2
             pidx = jnp.asarray(table[:n_pages], jnp.int32)

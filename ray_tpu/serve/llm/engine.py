@@ -350,17 +350,24 @@ class LLMEngine:
         # Pipelined decode (vLLM-style async token processing): each step's
         # input tokens are the previous step's on-device output, so steps
         # dispatch back-to-back without a host sync — the host harvests
-        # sampled tokens PIPELINE_DEPTH steps behind. Token latency then
-        # tracks step execution time instead of the host<->device round
-        # trip.
+        # sampled tokens at most PIPELINE_DEPTH entries behind (_step holds
+        # the bound). Token latency then tracks step execution time
+        # instead of the host<->device round trip.
         self.PIPELINE_DEPTH = cfg.pipeline_depth
         # [(dev_tokens, [(col, slot, req)], k, seq)]; seq numbers decode and
         # verify blocks (the same in a block's dispatch and harvest spans),
         # -1 for a prefill's first token
         self._pending: list = []
         self._block_seq = 0
-        self._dev_tokens = None    # [B+1] device array (incl. trash row)
-        self._overrides: dict[int, int] = {}  # slot -> first token (prefill)
+        # the token every slot's next step consumes: [B+1] on device (row b
+        # is the trash row, below). Only programs write it: a decode block
+        # or verify round its last samples, a prefill (or last prefill
+        # chunk) its first token at the row of the slot it arms.
+        self._dev_tokens = jnp.zeros((b + 1,), jnp.int32)
+        # slot -> a token the HOST knows and the device row does not hold:
+        # a verify round's rollback, a disaggregated adoption. Host ints
+        # only (a device value here would need an eager op to place).
+        self._overrides: dict[int, int] = {}
         # device-resident decode state (page tables / seq lens / temps);
         # slot admissions mark entries dirty and patch them with one small
         # update before the next dispatch. Row b (one past the last slot)
@@ -378,8 +385,9 @@ class LLMEngine:
             # guessed)
             from jax.sharding import NamedSharding, PartitionSpec as P
             rep = NamedSharding(self._mesh, P())
-            self._pt_dev, self._sl_dev, self._temps_dev = jax.device_put(
-                (self._pt_dev, self._sl_dev, self._temps_dev), rep)
+            self._pt_dev, self._sl_dev, self._temps_dev, self._dev_tokens = \
+                jax.device_put((self._pt_dev, self._sl_dev, self._temps_dev,
+                                self._dev_tokens), rep)
         self._dirty_slots: dict[int, tuple] = {}  # slot -> (seq_len, temp)
 
         # jitted programs. The KV pool is DONATED, and every paged program
@@ -422,7 +430,16 @@ class LLMEngine:
         self._patch_toks = jax.jit(
             lambda toks, idx, vals: toks.at[idx].set(vals),
             donate_argnums=(0,))
-        self._zero_tok = None  # device int32(0), padding for override stacks
+
+        # The sampling key of a prefill, split off the loop's key by ONE
+        # two-output program: an eager split is a primitive applied on the
+        # loop thread, and unpacking its result slices a device array
+        # twice more. Same values as ``key, sub = jax.random.split(key)``.
+        def split_key(key):
+            pair = jax.random.split(key)
+            return pair[0], pair[1]
+
+        self._split_key = jax.jit(split_key)
 
     # ---- tensor parallelism (ISSUE 20) ---------------------------------
     @staticmethod
@@ -589,7 +606,10 @@ class LLMEngine:
         loop never blocks on a host round trip per request (the old
         ``int(tok[0])`` sync serialized one device round trip per
         admission). The sampled token is returned as a device scalar; the
-        harvest pipeline records it.
+        harvest pipeline records it. The program also WRITES it where the
+        next decode block reads it: row ``slot`` (traced: one program a
+        bucket serves every slot) of the donated token vector. A caller
+        that arms no slot (disagg's prefill_only) passes the trash row.
 
         top_k is the ENGINE's (static — per-request values would compile a
         new program per distinct k, each stalling the loop; decode already
@@ -599,15 +619,16 @@ class LLMEngine:
             jax = self._jax
             top_k = self.cfg.top_k
 
-            def impl(params, kv, page_table, tokens, true_len, rng, temp):
+            def impl(params, kv, toks_full, page_table, tokens, true_len,
+                     rng, temp, slot):
                 logits, kv = self._kvc.paged_prefill(
                     params, kv, page_table, tokens, true_len,
                     self.model_cfg, self.cfg.page_size)
                 tok = self._kvc.sample_tokens(
                     logits[None, :], rng, temp, top_k)
-                return tok[0], kv
+                return tok[0], toks_full.at[slot].set(tok[0]), kv
 
-            fn = jax.jit(impl, donate_argnums=(1,))
+            fn = jax.jit(impl, donate_argnums=(1, 2))
             self._prefill_cache[bucket] = fn
         return fn
 
@@ -615,7 +636,9 @@ class LLMEngine:
         """Chunked-prefill program for a chunk of ``clen`` tokens: write the
         chunk's KV through the page pool, attend over everything cached so
         far, and sample a (candidate) next token on device — only the final
-        chunk's sample is used. One program per chunk bucket (full chunks
+        chunk's sample is used: it is written to row ``slot`` of the donated
+        token vector as in _prefill_fn, and every other chunk passes the
+        trash row. One program per chunk bucket (full chunks
         share one shape; the padded tail adds at most log2(prefill_chunk))."""
         key = ("chunk", clen)
         fn = self._prefill_cache.get(key)
@@ -623,17 +646,17 @@ class LLMEngine:
             jax = self._jax
             top_k = self.cfg.top_k
 
-            def impl(params, kv, page_table, tokens, start, true_len, rng,
-                     temp):
+            def impl(params, kv, toks_full, page_table, tokens, start,
+                     true_len, rng, temp, slot):
                 logits, kv = self._kvc.paged_prefill_chunk(
                     params, kv, page_table, tokens, start, true_len,
                     self.model_cfg, self.cfg.page_size,
                     self._attn_backend, mesh=self._mesh)
                 tok = self._kvc.sample_tokens(
                     logits[None, :], rng, temp, top_k)
-                return tok[0], kv
+                return tok[0], toks_full.at[slot].set(tok[0]), kv
 
-            fn = jax.jit(impl, donate_argnums=(1,))
+            fn = jax.jit(impl, donate_argnums=(1, 2))
             self._prefill_cache[key] = fn
         return fn
 
@@ -651,16 +674,14 @@ class LLMEngine:
         serving: a first-use compile mid-traffic stalls ALL active
         generations for the whole XLA compile (seconds to tens of
         seconds) and wrecks tail latency. All-trash index vectors
-        make the warmup dispatches write only into the trash page."""
-        jnp = self._jnp
+        make the warmup dispatches write only into the trash page. The
+        operands are built as the loop builds them (numpy, or what a
+        program returned), so traffic finds these very cache entries."""
         trash = self.cfg.max_batch_size
         # derive from _bucket_width so the warmed set can never diverge
         # from the widths _step actually dispatches
         widths = sorted({self._bucket_width(n)
                          for n in range(1, self.cfg.max_batch_size + 1)})
-        toks = self._dev_tokens
-        if toks is None:
-            toks = jnp.zeros((self.cfg.max_batch_size + 1,), jnp.int32)
         tiers = {1, max(1, min(self.cfg.pressure_decode_block,
                                self.cfg.decode_block)),
                  self.cfg.decode_block}
@@ -669,45 +690,47 @@ class LLMEngine:
             tiers.add(min(self.cfg.decode_block,
                           max(1, self.cfg.spec_draft_len)))
         for w in widths:
-            idx = jnp.full((w,), trash, jnp.int32)
+            idx = self._slot_index((), w)
             for k in tiers:
                 # compile_scope registers each (width, block) signature so
                 # the traffic-path scopes see it as already compiled; a
                 # warmup compile is by definition not mid-traffic
                 with self._prof.compile_scope("decode", ("decode", w, k)):
-                    _all, toks, self.kv, self._sl_dev, self._rng = \
-                        self._decode(
+                    _all, self._dev_tokens, self.kv, self._sl_dev, \
+                        self._rng = self._decode(
                             self.params, self.kv, self._pt_dev,
-                            self._sl_dev, toks, self._rng,
+                            self._sl_dev, self._dev_tokens, self._rng,
                             self._temps_dev, idx, k)
             if self._spec_on:
                 # the verify-k program per width too: an uncompiled verify
                 # stalls the first speculative round mid-traffic exactly
                 # like an uncompiled decode block would
-                drafts = jnp.full((w, self.cfg.spec_draft_len), -1,
-                                  jnp.int32)
+                drafts = np.full((w, self.cfg.spec_draft_len), -1,
+                                 np.int32)
                 with self._prof.compile_scope(
                         "verify", ("verify", w, self.cfg.spec_draft_len)):
-                    _all, toks, self.kv, self._sl_dev, self._rng = \
-                        self._verify(
+                    _all, self._dev_tokens, self.kv, self._sl_dev, \
+                        self._rng = self._verify(
                             self.params, self.kv, self._pt_dev,
-                            self._sl_dev, toks, self._rng,
+                            self._sl_dev, self._dev_tokens, self._rng,
                             self._temps_dev, idx, drafts)
         # the fixed-shape slot patches (all-trash write of zeros is a no-op)
-        didx = jnp.full((trash + 1,), trash, jnp.int32)
+        didx = self._slot_index((), trash + 1)
         self._pt_dev, self._sl_dev, self._temps_dev = self._patch_state(
             self._pt_dev, self._sl_dev, self._temps_dev, didx,
-            jnp.zeros((trash + 1, self.max_pages_per_seq), jnp.int32),
-            jnp.zeros((trash + 1,), jnp.int32),
-            jnp.zeros((trash + 1,), jnp.float32))
-        if self._zero_tok is None:
-            self._zero_tok = jnp.int32(0)
-        toks = self._patch_toks(
-            toks, didx, jnp.stack([self._zero_tok] * (trash + 1)))
+            np.zeros((trash + 1, self.max_pages_per_seq), np.int32),
+            np.zeros((trash + 1,), np.int32),
+            np.zeros((trash + 1,), np.float32))
+        self._dev_tokens = self._patch_toks(
+            self._dev_tokens, didx, np.zeros((trash + 1,), np.int32))
+        # the key split of the first prefill (both halves dropped: the
+        # loop's key is what it would be without this)
+        self._split_key(self._rng)
         if self._kv_tier_on:
             # the tier-restore scatter too: its one fixed shape would
             # otherwise compile on the first tier hit, mid-traffic (an
             # all-trash-page write of zeros is a no-op)
+            jnp = self._jnp
             mp = self.max_pages_per_seq
             zb = jnp.zeros(self.kv["k"].shape[:2] + (mp,)
                            + self.kv["k"].shape[3:], self.kv["k"].dtype)
@@ -715,8 +738,7 @@ class LLMEngine:
                                           ("kv_tier_inject", mp)):
                 self.kv = self._tier_inject(
                     self.kv, zb, zb, jnp.zeros((mp,), jnp.int32))
-        self._dev_tokens = toks
-        self._jax.block_until_ready(toks)
+        self._jax.block_until_ready(self._dev_tokens)
 
     def shutdown(self):
         self._stop.set()
@@ -1813,10 +1835,10 @@ class LLMEngine:
 
     def _prefill(self, req: _Request):
         """Dispatch prefill WITHOUT waiting for it: the sampled first token
-        stays on device (fed to the next decode block as a scatter) and is
+        stays on device (the program writes it to the slot's row of
+        _dev_tokens, where the next decode block reads it) and is
         recorded on the host by the harvest pipeline, in order, like any
         decode block's tokens."""
-        jnp = self._jnp
         plen = len(req.prompt_tokens)
         bucket = self._bucket(plen)
         toks = np.full((1, bucket), 0, np.int32)
@@ -1824,7 +1846,7 @@ class LLMEngine:
         table = np.zeros((self.max_pages_per_seq,), np.int32)
         table[: len(req.pages)] = req.pages
         fn = self._prefill_fn(bucket)
-        self._rng, sub = self._jax.random.split(self._rng)
+        self._rng, sub = self._split_key(self._rng)
         # a first-use prefill bucket compiles HERE, with a live request
         # waiting on it — warmup doesn't cover prompt buckets, so this is
         # always a mid-traffic compile when it fires
@@ -1832,17 +1854,18 @@ class LLMEngine:
                              tokens=plen), self._prof.compile_scope(
                 "prefill", ("prefill", bucket),
                 mid_traffic=self.stats["requests"] > 0):
-            tok_dev, self.kv = fn(
-                self.params, self.kv, jnp.asarray(table), jnp.asarray(toks),
-                jnp.int32(plen), sub,
-                jnp.asarray([req.temperature], jnp.float32))
+            tok_dev, self._dev_tokens, self.kv = fn(
+                self.params, self.kv, self._dev_tokens, table, toks,
+                np.int32(plen), sub,
+                np.full((1,), req.temperature, np.float32),
+                np.int32(req.slot))
         self._arm_slot(req, table, tok_dev, plen)
 
     def _arm_slot(self, req: _Request, table, tok_dev, plen: int) -> None:
         """Publish a freshly prefilled slot to the decode loop: host/device
-        state patch, first-token override (the on-device token carry knows
-        nothing about fresh prefills), and a harvest entry for the sampled
-        first token."""
+        state patch and a harvest entry for the sampled first token
+        ``tok_dev`` (which the prefill program has already written to the
+        slot's row of _dev_tokens: nothing to place here)."""
         self._start_fetch(tok_dev)
         with self._lock:
             req.dispatched = 1
@@ -1850,7 +1873,6 @@ class LLMEngine:
             self.seq_lens[req.slot] = plen
             self.slot_req[req.slot] = req
             self._dirty_slots[req.slot] = (plen, req.temperature)
-            self._overrides[req.slot] = tok_dev
             self._pending.append((tok_dev, [(0, req.slot, req)], 1, -1))
         if self._prefix_cache_on:
             # Index the prompt's FULL pages now (not at completion): the
@@ -1871,10 +1893,11 @@ class LLMEngine:
         """Dispatch ONE prefill chunk per in-progress chunked admission
         (loop thread). The final chunk's on-device sampled token arms the
         slot exactly like _prefill's; intermediate chunks only extend the
-        cached KV. Chunks are dispatched async — the decode block that
-        follows in this loop iteration queues behind them on the device
-        stream, which is the interleaving."""
-        jnp = self._jnp
+        cached KV (their sample goes to the trash row). Chunks are
+        dispatched async — the decode block that follows in this loop
+        iteration queues behind them on the device stream, which is the
+        interleaving."""
+        trash = self.cfg.max_batch_size
         with self._lock:
             active = list(self._prefilling)
         now = time.time()
@@ -1899,17 +1922,18 @@ class LLMEngine:
             table = np.zeros((self.max_pages_per_seq,), np.int32)
             table[: len(req.pages)] = req.pages
             fn = self._chunk_fn(clen)
-            self._rng, sub = self._jax.random.split(self._rng)
+            self._rng, sub = self._split_key(self._rng)
             with self._prof.span(
                     "chunk_prefill", rid=req.request_id, clen=clen,
                     start=start, tokens=len(seg), last=int(final)), \
                     self._prof.compile_scope(
                     "chunk", ("chunk", clen),
                     mid_traffic=self.stats["requests"] > 0):
-                tok_dev, self.kv = fn(
-                    self.params, self.kv, jnp.asarray(table),
-                    jnp.asarray(toks), jnp.int32(start), jnp.int32(plen),
-                    sub, jnp.asarray([req.temperature], jnp.float32))
+                tok_dev, self._dev_tokens, self.kv = fn(
+                    self.params, self.kv, self._dev_tokens, table, toks,
+                    np.int32(start), np.int32(plen), sub,
+                    np.full((1,), req.temperature, np.float32),
+                    np.int32(req.slot if final else trash))
             self.stats["attn_chunk_dispatches"] += 1
             req.prefill_pos = min(start + clen, plen)
             if req.prefill_pos >= plen:
@@ -2003,22 +2027,31 @@ class LLMEngine:
             k = min(k, max(1, self.cfg.spec_draft_len))
         return k
 
+    def _slot_index(self, slots, width: int):
+        """``slots`` as the index vector of a fixed-shape program: int32
+        [width], padded with the trash row. numpy on purpose: what the loop
+        thread hands a program is a host array or what a program returned,
+        never the result of an eager device op (which would run, and on a
+        busy device wait, on this thread)."""
+        idx = np.full((width,), self.cfg.max_batch_size, np.int32)
+        idx[: len(slots)] = slots
+        return idx
+
     def _flush_slot_patches(self, dirty: dict, overrides: dict):
         """Apply queued slot-state patches at the fixed B+1 shape (trash-
         row padded — see the compile-stall note on _patch_state) and
-        return the patched device token vector. Shared by the decode and
-        verify-k dispatch paths; loop thread only."""
+        return the patched device token vector. A patch carries slot state
+        and host-known tokens only: a prefill writes its first token
+        itself. Shared by the decode and verify-k dispatch paths; loop
+        thread only."""
         with self._prof.span("patch_flush", dirty=len(dirty),
                              overrides=len(overrides)):
-            jnp = self._jnp
             trash_row = self.cfg.max_batch_size
             if dirty:
                 # fixed-shape patch: pad to B+1 rows onto the trash row
                 # (whose state is all-zeros by invariant), so ONE compiled
                 # scatter covers every dirty-count
                 order = sorted(dirty)
-                pad = (trash_row + 1) - len(order)
-                didx = jnp.asarray(order + [trash_row] * pad, jnp.int32)
                 ptv = np.zeros((trash_row + 1, self.max_pages_per_seq),
                                np.int32)
                 ptv[: len(order)] = self.page_tables[order]
@@ -2028,33 +2061,43 @@ class LLMEngine:
                 tv[: len(order)] = [dirty[i][1] for i in order]
                 self._pt_dev, self._sl_dev, self._temps_dev = \
                     self._patch_state(
-                        self._pt_dev, self._sl_dev, self._temps_dev, didx,
-                        jnp.asarray(ptv), jnp.asarray(slv), jnp.asarray(tv))
+                        self._pt_dev, self._sl_dev, self._temps_dev,
+                        self._slot_index(order, trash_row + 1),
+                        ptv, slv, tv)
             toks = self._dev_tokens
-            if toks is None:
-                toks = jnp.zeros((self.cfg.max_batch_size + 1,), jnp.int32)
             if overrides:
-                # values are device scalars from async prefills (or host
-                # ints from verify-round acceptance): stacking and scattering
-                # stays on device — no host sync. Same fixed-shape padding
-                # (trash-row writes of 0) as the state patch.
-                if self._zero_tok is None:
-                    self._zero_tok = jnp.int32(0)
-                pad = (trash_row + 1) - len(overrides)
-                oidx = jnp.asarray(
-                    list(overrides.keys()) + [trash_row] * pad, jnp.int32)
-                ovals = jnp.stack(
-                    [jnp.asarray(v, jnp.int32) for v in overrides.values()]
-                    + [self._zero_tok] * pad)
-                toks = self._patch_toks(toks, oidx, ovals)
+                # host ints (a verify round's rollback, a disaggregated
+                # adoption), at the same fixed shape (trash-row writes of
+                # 0) as the state patch
+                ovals = np.zeros((trash_row + 1,), np.int32)
+                ovals[: len(overrides)] = list(overrides.values())
+                toks = self._patch_toks(
+                    toks, self._slot_index(list(overrides), trash_row + 1),
+                    ovals)
             return toks
 
     def _step(self) -> bool:
         """Dispatch the iteration's device work: a speculative verify-k
         round for slots with drafts (spec_decode_enabled), then one fused
-        decode block for the rest."""
+        decode block for the rest; then hold the loop to its lead.
+
+        HOW FAR THE LOOP RUNS AHEAD OF THE DEVICE IS DECIDED HERE AND
+        NOWHERE ELSE: at most PIPELINE_DEPTH entries (decode blocks,
+        verify rounds, prefills' first tokens) stay in flight, and the
+        harvests below block until the device has retired the rest.
+        Every admission queues an entry of its own beside the pass's
+        block, so trimming one entry a dispatch let the backlog grow by
+        one with each admission, and every stream's tokens reached its
+        caller that much later. The other half of the rule is that
+        nothing else on this thread waits for the device (_slot_index).
+        The excess is counted ONCE: a verify round's harvest can queue
+        its successor, and a loop on the length would follow that chain
+        for as long as its drafts hit."""
         did_spec = self._spec_on and self._spec_step()
-        return self._decode_step() or did_spec
+        dispatched = self._decode_step() or did_spec
+        for _ in range(len(self._pending) - self.PIPELINE_DEPTH):
+            self._harvest_one()
+        return dispatched
 
     def _decode_step(self) -> bool:
         """Dispatch one fused decode block (1..decode_block steps) without
@@ -2065,11 +2108,10 @@ class LLMEngine:
 
         Steady-state decode is ONE jitted call with all-device arguments
         (page tables, seq lens, temps, last tokens, rng all live on device;
-        slot admissions patch them with small eager updates). Block fusion
+        slot admissions patch them with one small jitted update). Block fusion
         brings the per-token dispatch cost to 1/decode_block of a
         dispatch; block size drops to 1 while admissions are pending so
         new requests don't wait a whole block."""
-        jnp = self._jnp
         with self._lock:
             snapshot = [(i, i, req) for i, req in enumerate(self.slot_req)
                         if req is not None
@@ -2097,15 +2139,19 @@ class LLMEngine:
         self._block_seq = seq = self._block_seq + 1
         # decode_dispatch times the HOST cost of getting the block onto
         # the device stream (patch flush + jit dispatch); the result sync
-        # is the harvest phase. The pipeline-trim harvest below is
-        # excluded — it's already sampled inside _harvest_one.
+        # is the harvest phase. The pipeline-trim harvests in _step are
+        # excluded — they're already sampled inside _harvest_one.
+        # inflight: entries pending as this block is dispatched; trimmed:
+        # the harvests the bound then forces (_step) — a trace says how
+        # often, and how hard, the bound engages.
+        inflight = len(self._pending)
         with self._prof.span("decode_dispatch", seq=seq, k=k, w=w,
                              active=len(active_slots),
-                             ctx_tokens=ctx_tokens):
+                             ctx_tokens=ctx_tokens, inflight=inflight,
+                             trimmed=max(
+                                 0, inflight + 1 - self.PIPELINE_DEPTH)):
             toks = self._flush_slot_patches(dirty, overrides)
-            trash = self.cfg.max_batch_size
-            idx = jnp.asarray(
-                active_slots + [trash] * (w - len(active_slots)), jnp.int32)
+            idx = self._slot_index(active_slots, w)
             snapshot = [(col, slot, req)
                         for col, (_c, slot, req) in enumerate(snapshot)]
             with self._prof.compile_scope(
@@ -2119,8 +2165,6 @@ class LLMEngine:
             self._pending.append((all_toks, snapshot, k, seq))
             self.stats["steps"] += k
             self.stats["attn_decode_dispatches"] += 1
-        if len(self._pending) > self.PIPELINE_DEPTH:
-            self._harvest_one()
         return True
 
     # ---- speculative decoding ------------------------------------------
@@ -2145,7 +2189,6 @@ class LLMEngine:
         """Dispatch ONE verify-k round for ``rows`` of (slot, req, draft,
         base_len) whose host state is exact (just drained or just
         harvested). Loop thread only; lock NOT held."""
-        jnp = self._jnp
         k = self.cfg.spec_draft_len
         with self._lock:
             for _slot, req, _draft, _base in rows:
@@ -2158,9 +2201,7 @@ class LLMEngine:
         self._block_seq = seq = self._block_seq + 1
         with self._prof.span("verify_dispatch", seq=seq, k=k, w=w):
             toks = self._flush_slot_patches(dirty, overrides)
-            trash = self.cfg.max_batch_size
-            idx = jnp.asarray(
-                spec_slots + [trash] * (w - len(spec_slots)), jnp.int32)
+            idx = self._slot_index(spec_slots, w)
             draft_mat = np.full((w, k), -1, np.int32)
             entry = []  # (col, slot, req, draft, base_len)
             for col, (slot, req, draft, base_len) in enumerate(rows):
@@ -2172,8 +2213,7 @@ class LLMEngine:
                 all_toks, self._dev_tokens, self.kv, self._sl_dev, \
                     self._rng = self._verify(
                         self.params, self.kv, self._pt_dev, self._sl_dev,
-                        toks, self._rng, self._temps_dev, idx,
-                        jnp.asarray(draft_mat))
+                        toks, self._rng, self._temps_dev, idx, draft_mat)
             self._start_fetch(all_toks)
             self._pending.append((all_toks, entry, ("spec", k), seq))
             self.stats["steps"] += k + 1

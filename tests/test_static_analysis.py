@@ -239,6 +239,54 @@ def test_host_sync_flags_item_and_block_until_ready():
                                                "block_until_ready"]
 
 
+def test_host_sync_flags_eager_stack_and_concatenate():
+    findings = _run(HostSyncPass(), """
+        class Engine:
+            def _flush_slot_patches(self, overrides):
+                jnp = self._jnp
+                vals = jnp.stack([jnp.asarray(v) for v in overrides])
+                return jnp.concatenate([vals, self._pad])
+        """, relpath="ray_tpu/serve/llm/engine.py")
+    assert sorted(f.tag for f in findings) == ["jnp.concatenate",
+                                               "jnp.stack"]
+    assert all("eager device op" in f.message for f in findings)
+
+
+def test_host_sync_flags_tuple_unpacked_random_split():
+    src = """
+        class Engine:
+            def _prefill(self, req):
+                self._rng, sub = %s(self._rng)
+                return sub
+        """
+    for call in ("self._jax.random.split", "jax.random.split",
+                 "random.split"):
+        findings = _run(HostSyncPass(), src % call,
+                        relpath="ray_tpu/serve/llm/engine.py")
+        assert [f.tag for f in findings] == ["random.split"], call
+    # one jitted two-output program is the way; numpy is not jnp; a split
+    # kept whole is the caller's to slice where it may
+    clean = _run(HostSyncPass(), """
+        class Engine:
+            def _prefill(self, req):
+                self._rng, sub = self._split_key(self._rng)
+                both = jax.random.split(sub)
+                idx = np.concatenate([a, b])
+                head, tail = text.split(",")
+                return np.stack([idx, idx]), both
+        """, relpath="ray_tpu/serve/llm/engine.py")
+    assert clean == []
+
+
+def test_host_sync_covers_arm_slot():
+    findings = _run(HostSyncPass(), """
+        class Engine:
+            def _arm_slot(self, req, tok_dev):
+                self._overrides[req.slot] = int(tok_dev[0])
+        """, relpath="ray_tpu/serve/llm/engine.py")
+    assert [f.tag for f in findings] == ["int(x[...])"]
+
+
 # ---------------------------------------------------------------------------
 # jit-hygiene
 
