@@ -8,9 +8,10 @@ full width of ``llama.llama3_1b()`` (dim 2048, 16 layers, 16/8 heads of
   family (decode, verify T=5, chunk C=512) and flash attention forward +
   backward, COMPILED, each against its float32 reference under a stated
   tolerance;
-- *train*: ``JaxTrainer.fit()`` on a TPU worker, the step built by
-  ``bench._make_step`` (flash attention, dots remat, adafactor, 4 x 2048),
-  five steps on one repeated batch: finite, falling loss;
+- *train*: ``JaxTrainer.fit()`` on a TPU worker, the benchmark's train
+  recipe (flash attention, dots remat, adafactor, fsdp over the chips it
+  is given, 4 x 2048), five steps on one repeated batch: finite, falling
+  loss;
 - *serve*: ``ray_tpu.init()`` -> ``serve.run(build_openai_app(cfg))`` ->
   ``serve.start_http_proxy``, then ``/v1/completions`` over HTTP: plain and
   SSE requests, eight concurrent streams, a chunked-prefill prompt and a
@@ -77,7 +78,7 @@ def check(cond, msg: str) -> None:
 # ---------------------------------------------------------------------------
 
 def sizes(rehearsal: bool, chips: int) -> dict:
-    """On the chip: bench_serve.py's chip configuration, nothing cut (a cold
+    """On the chip: a one-chip serving configuration, nothing cut (a cold
     run takes about five of the twenty minutes allowed). Toy sizes for the
     CPU rehearsal."""
     if rehearsal:
@@ -345,8 +346,11 @@ def within(seconds: float, what: str, fn, *args):
 # ---------------------------------------------------------------------------
 
 def train_loop(config: dict) -> None:
-    """Runs on the TPU worker JaxTrainer places. The step is bench.py's:
-    same recipe, same builder."""
+    """Runs on the TPU worker JaxTrainer places. The recipe is the one
+    benchmark/train_cell.build gives its cell: fsdp over every chip,
+    adafactor (adam's fp32 moments cost 8 bytes/param, most of one v5e's
+    HBM at this size; factored state frees it for the "dots" remat policy)
+    and weights made sharded on the devices."""
     import dataclasses
     import time
 
@@ -356,7 +360,6 @@ def train_loop(config: dict) -> None:
 
     import ray_tpu.train as rtrain
     from __graft_entry__ import collective_counts
-    from bench import _make_step
     from ray_tpu.models import llama
     from ray_tpu.train import spmd
 
@@ -373,7 +376,14 @@ def train_loop(config: dict) -> None:
         cfg = llama.llama3_1b(max_seq_len=2048, remat_policy="dots",
                               ce_chunk=2048, ce_remat=False,
                               attn_impl="flash")
-    mesh, state, step = _make_step(cfg, devs[:n], "adafactor")
+    mesh = spmd.make_mesh(n, devices=devs[:n])
+    opt = spmd.default_optimizer(warmup_steps=10, decay_steps=1000,
+                                 name="adafactor")
+    state, sh = spmd.sharded_create_state(
+        lambda: llama.init_params(jax.random.PRNGKey(0), cfg), opt, mesh,
+        params_logical_axes=llama.logical_axes(cfg))
+    step = spmd.make_train_step(
+        lambda p, b: llama.loss_fn(p, b, cfg, mesh), opt, mesh, sh)
     rng = np.random.default_rng(0)
     tokens = jnp.asarray(rng.integers(
         0, cfg.vocab_size, (config["batch"], config["seq"] + 1)), jnp.int32)

@@ -166,7 +166,7 @@ class Config:
     # Critical-path attribution (observability/attribution.py): per-request
     # stage timelines stamped at the proxy/router/engine; SLO-violating
     # requests persist full timelines to the CP exemplar store. Stamping is
-    # host-side dict appends (A/B-bounded by `bench_serve.py --slo-ab`).
+    # host-side dict appends.
     slo_attribution_enabled: bool = True
     # CP exemplar store cap: oldest records evict first past this
     slo_exemplar_max_records: int = 512
@@ -191,9 +191,8 @@ class Config:
     metrics_max_points_per_series: int = 1024
     # Flight recorder (observability/events.py): structured cluster
     # events batch-flushed to a bounded CP journal. Emit is a host-side
-    # dict append + queue push (A/B-bounded by `bench_serve.py
-    # --events-ab`); the flusher keeps unsent batches across CP outages,
-    # bounded to this many payloads with oldest-first eviction.
+    # dict append + queue push; the flusher keeps unsent batches across
+    # CP outages, bounded to this many payloads with oldest-first eviction.
     events_enabled: bool = True
     events_flush_interval_s: float = 2.0
     events_flush_buffer_max: int = 64

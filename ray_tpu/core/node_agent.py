@@ -61,6 +61,15 @@ _ENV_CACHE_GAUGE = _metrics.Gauge(
     "ray_tpu_node_agent_env_cache_entries",
     "materialized runtime-env cache entries on this node")
 
+# How a worker ends (NodeAgent._end_worker): asked to leave, killed once its
+# grace has run out, reaped. The limit on the wait after SIGKILL is there
+# only so that a wedged kernel cannot hang stop()'s caller for ever.
+_WORKER_EXIT_GRACE_S = 2.0
+_WORKER_REAP_LIMIT_S = 30.0
+# the longest a TPU lease waits for chip nodes some other process still
+# holds (benchmark/run.py's guard at its own start waits as long)
+_CHIP_WAIT_S = 60.0
+
 
 class _InProcHandle:
     """Process-like facade over an in-process WorkerRuntime, so the agent's
@@ -111,8 +120,11 @@ class _WorkerInfo:
     proc: subprocess.Popen | None = None
     pid: int = 0
     busy: bool = False
+    # set once the worker is dying (_end_worker): when it is to be killed
+    kill_at: float | None = None
     actor_id: ActorID | None = None
     is_tpu_worker: bool = False
+    chips_awaited: bool = False  # its first TPU lease waited for the chips
     env_key: str = ""  # runtime-env hash (worker pool keyed per env)
     idle_since: float = field(default_factory=time.monotonic)
     ready = None  # threading.Event
@@ -770,7 +782,8 @@ class NodeAgent:
                     return {"granted": False, "draining": True}
                 need_spawn = False
                 try_redirect = False
-                evict_proc = None
+                victim = grant = None
+                await_chips = False
                 with self._lock:
                     # reap spawns that died BEFORE registering (e.g. killed
                     # by chaos mid-boot): without this, `spawned` stays set
@@ -816,12 +829,14 @@ class NodeAgent:
                             # its view instead of subtracting (a subtract
                             # after our async report double-counts the lease
                             # and can wedge the view at 0)
-                            return {"granted": True, "lease_id": lease.lease_id,
-                                    "worker_id": worker.worker_id,
-                                    "worker_addr": worker.addr,
-                                    "available": dict(self.available),
-                                    "version": grant_version}
-                        if not spawned and self._can_spawn(for_tpu):
+                            grant = {"granted": True, "lease_id": lease.lease_id,
+                                     "worker_id": worker.worker_id,
+                                     "worker_addr": worker.addr,
+                                     "available": dict(self.available),
+                                     "version": grant_version}
+                            await_chips = for_tpu and not worker.chips_awaited
+                            worker.chips_awaited = True
+                        elif not spawned and self._can_spawn(for_tpu):
                             spawned = need_spawn = True
                         elif not spawned:
                             # pool is at its cap but holds idle workers for
@@ -836,17 +851,16 @@ class NodeAgent:
                                  and i.env_key != env_key), None)
                             if victim is not None:
                                 victim.busy = True  # unleaseable while dying
-                                del self._workers[victim.worker_id]
-                                self._unpin_worker_envs(victim.worker_id)
-                                evict_proc = victim.proc
                                 spawned = need_spawn = True
                     elif pg_id is None:
                         try_redirect = True
-                if evict_proc is not None:
-                    try:
-                        evict_proc.terminate()
-                    except Exception:  # noqa: BLE001 - already gone
-                        pass
+                if grant is not None:
+                    if await_chips:
+                        # the worker opens the chips in its first task
+                        self._await_chips(resources["TPU"], deadline)
+                    return grant
+                if victim is not None:
+                    self._end_worker(victim, _WORKER_EXIT_GRACE_S)
                 if need_spawn:
                     spawned_wid = self._spawn_worker(
                         for_tpu, runtime_env).worker_id
@@ -1205,33 +1219,99 @@ class NodeAgent:
                 with self._lock:
                     snapshot = list(self._workers.values())
                 self._memory_monitor.maybe_kill(snapshot)
+            # the one place a worker leaves _workers: reaped here, and
+            # only here is its death accounted for (_on_worker_dead)
             dead: list[_WorkerInfo] = []
+            ending: list[_WorkerInfo] = []
             with self._lock:
+                now = time.monotonic()
                 for info in list(self._workers.values()):
                     if info.proc is not None and info.proc.poll() is not None:
                         dead.append(info)
                         del self._workers[info.worker_id]
-                # reap long-idle workers
-                now = time.monotonic()
-                for info in list(self._workers.values()):
-                    if (not info.busy and info.actor_id is None
+                    elif info.kill_at is not None and now >= info.kill_at:
+                        ending.append(info)  # dying, and its grace is over
+                    elif (not info.busy and info.actor_id is None
                             and info.addr is not None
                             and now - info.idle_since > cfg.idle_worker_ttl_s):
-                        try:
-                            info.proc.terminate()
-                        except Exception:
-                            pass
+                        info.busy = True  # unleaseable while dying
+                        ending.append(info)
+            for info in ending:
+                self._end_worker(info, _WORKER_EXIT_GRACE_S)
             for info in dead:
                 self._on_worker_dead(info)
+
+    def _end_worker(self, info: _WorkerInfo, grace_s: float) -> None:
+        """The one way this agent ends a worker. The first call marks it
+        dying, so that it is never leased again, and asks it to leave:
+        ``exit_worker`` where it has an address, SIGTERM where it has not
+        registered one yet. A call once the grace is over (``grace_s`` 0: at
+        once) kills it. Never blocks, and never forgets the worker: it stays
+        in ``_workers`` until the monitor, or ``stop()``, has reaped it."""
+        with self._lock:
+            now = time.monotonic()
+            first = info.kill_at is None
+            info.busy = True
+            info.kill_at = (now + grace_s if first
+                            else min(info.kill_at, now + grace_s))
+            overdue = now >= info.kill_at
+        try:
+            if overdue:
+                if info.proc is not None:
+                    info.proc.kill()
+            elif first and info.addr is not None:
+                # a hint: the kill at the grace's end does not depend on it
+                # graftlint: fire-and-forget
+                self._pool.get(info.addr).notify(
+                    "exit_worker", {"worker_id": info.worker_id})
+            elif first and info.proc is not None:
+                info.proc.terminate()
+        except Exception:  # noqa: BLE001 - already gone
+            pass
+
+    def _reap_worker(self, info: _WorkerInfo) -> bool:
+        """Wait until a dying worker's process has been reaped, killing it
+        at its grace's end. False: it outlived SIGKILL by the limit."""
+        for limit in (info.kill_at - time.monotonic(), _WORKER_REAP_LIMIT_S):
+            try:
+                info.proc.wait(timeout=max(0.0, limit))
+            except subprocess.TimeoutExpired:
+                pass
+            if info.proc.poll() is not None:
+                return True
+            self._end_worker(info, 0.0)
+        logger.error("worker pid %s is still there %.0f s after SIGKILL",
+                     info.pid, _WORKER_REAP_LIMIT_S)
+        return False
+
+    def _await_chips(self, chips: float, deadline: float) -> None:
+        """A TPU worker is about to get its first task, in which it opens
+        the chips: wait until as many chip nodes can be opened as the lease
+        holds. A node may be held by a process that is already exiting (a
+        worker of the run before, killed and not yet through closing its
+        files). Past ``_CHIP_WAIT_S``, or the lease's own deadline, the
+        lease is granted all the same and the worker reports what it
+        finds."""
+        from ray_tpu.parallel import topology
+        present = topology.local_chip_count()
+        want = min(int(chips), present)
+        if want == 0:
+            return  # no chip nodes here: a CPU host, or a fake topology
+        t0 = time.monotonic()
+        end = min(deadline, t0 + _CHIP_WAIT_S)
+        while (found := topology.openable_chip_count()) < want \
+                and time.monotonic() < end and not self._stopped.is_set():
+            time.sleep(0.1)
+        waited = time.monotonic() - t0
+        logger.log(
+            logging.WARNING if found < want or waited > 1.0 else logging.INFO,
+            "TPU lease of %d chip(s): %d of %d node(s) can be opened after "
+            "%.3f s", want, found, present, waited)
 
     def _oom_kill_worker(self, info: _WorkerInfo, reason: str) -> None:
         """Hard-kill a worker under memory pressure; the normal dead-worker
         path (monitor loop) reaps it and notifies owners."""
-        try:
-            if info.proc is not None:
-                info.proc.kill()
-        except Exception:  # noqa: BLE001
-            pass
+        self._end_worker(info, 0.0)
 
     def _unpin_worker_envs(self, worker_id) -> None:
         """Release a reaped worker's runtime-env cache pins so the LRU GC
@@ -1248,7 +1328,7 @@ class NodeAgent:
         logger.info("worker %s (pid %s, actor=%s) died, exit code %s",
                     info.worker_id.hex()[:8], info.pid,
                     info.actor_id.hex()[:8] if info.actor_id else None, code)
-        to_kill = []
+        orphaned = []
         with self._lock:
             for lid, lease in list(self._leases.items()):
                 # release leases ON the dead worker and leases HELD BY it
@@ -1263,19 +1343,13 @@ class NodeAgent:
                             and w.actor_id is None:
                         # the worker may still be mid-execution of the dead
                         # lessee's orphaned task — marking it idle would
-                        # re-lease a busy CPU; terminate it instead (the
-                        # monitor reaps + a fresh worker spawns clean)
-                        to_kill.append(w.proc)
-                        del self._workers[w.worker_id]
-                        self._unpin_worker_envs(w.worker_id)
+                        # re-lease a busy CPU; end it instead (the monitor
+                        # reaps it and a fresh worker spawns clean)
+                        orphaned.append(w)
             self._lease_cv.notify_all()
         self._unpin_worker_envs(info.worker_id)
-        for proc in to_kill:
-            try:
-                if proc is not None:
-                    proc.terminate()
-            except Exception:  # noqa: BLE001 - already gone
-                pass
+        for w in orphaned:
+            self._end_worker(w, _WORKER_EXIT_GRACE_S)
         self._report_resources()
         # ALWAYS tell the CP (not just for actors): a dead worker's metric
         # series must be retracted from the time-series store / exposition
@@ -1300,29 +1374,27 @@ class NodeAgent:
         return {"ok": True}
 
     def stop(self):
+        """Stop the agent. Every worker is asked to leave, killed where it
+        has not left by the grace's end, and waited for: when this returns,
+        no process this agent spawned exists any more, as a zombie or
+        otherwise, and what a worker held (a TPU chip) has been given back.
+        The wait cannot be replaced by a look at /proc: a dead TPU worker
+        shows there as a zombie with no file open for as long as its chips
+        take to be released, and only then can it be reaped (PERF.md)."""
         self._stopped.set()
-        with self._lock:
-            workers = list(self._workers.values())
-        for info in workers:
-            if info.addr is not None:
-                try:
-                    # polite-exit hint only: the wait/kill loop below
-                    # reaps every worker past the deadline regardless
-                    # graftlint: fire-and-forget
-                    self._pool.get(info.addr).notify(
-                        "exit_worker", {"worker_id": info.worker_id})
-                except Exception:
-                    pass
-        deadline = time.monotonic() + 2.0
-        for info in workers:
-            if info.proc is not None:
-                try:
-                    info.proc.wait(timeout=max(0.1, deadline - time.monotonic()))
-                except Exception:
-                    try:
-                        info.proc.kill()
-                    except Exception:
-                        pass
+        lost: set[WorkerID] = set()
+        # again, for the spawn a lease handler was in the middle of
+        while True:
+            with self._lock:
+                live = [i for i in self._workers.values()
+                        if i.proc is not None and i.proc.poll() is None
+                        and i.worker_id not in lost]
+            if not live:
+                break
+            for info in live:
+                self._end_worker(info, _WORKER_EXIT_GRACE_S)
+            lost.update(info.worker_id for info in live
+                        if not self._reap_worker(info))
         # final metrics flush while the CP client pool is still open (clean
         # shutdown must not drop the last interval's deltas)
         if self._metrics_flusher is not None:

@@ -52,9 +52,8 @@ class LlamaConfig:
     # body). True = recompute the lm_head matmul in bwd, smallest peak HBM.
     # False = keep each chunk's fp32 logits as residuals — one extra
     # B*T*V fp32 tensor live across the backward, but the recompute matmul
-    # disappears: measured 33 ms/step (0.572 -> 0.60 MFU) at 1.5B/b4/
-    # seq2048 on one v5e where the 4.2 GB residual fits. Keep True for
-    # HBM-tight configs (bigger batch/model per chip).
+    # disappears. Keep True for HBM-tight configs (bigger batch/model per
+    # chip).
     ce_remat: bool = True
     # MLP matmul implementation for the TRAIN path: "bf16" (default) or
     # "int8" — dynamic per-tensor symmetric quantization of both operands
@@ -160,6 +159,36 @@ def logical_axes(cfg: LlamaConfig):
         "final_norm": (None,),
         "lm_head": ("embed", "vocab"),
     }
+
+
+def serve_partition_rules():
+    """Serve-side Megatron TP rules over the same tree, consumed by
+    parallel.sharding.rule_shardings (ordered; first re.search match
+    wins). Column-parallel qkv/gate/up, row-parallel wo/w_down (their
+    contractions psum across the axis), vocab-sharded lm_head (argmax
+    composes exactly across shards), everything else — embed, norms,
+    scalars — replicated. The attention split rides the kv-major GQA
+    head order: H/tp query heads are exactly (Hkv/tp) whole kv-head
+    groups, so per-head attention math never crosses a shard."""
+    from jax.sharding import PartitionSpec as P
+    return (
+        (r"layers/attn/w[qkv]$", P(None, None, "tensor", None)),
+        (r"layers/attn/wo$", P(None, "tensor", None, None)),
+        (r"layers/mlp/w_(gate|up)$", P(None, None, "tensor")),
+        (r"layers/mlp/w_down$", P(None, "tensor", None)),
+        (r"lm_head$", P(None, "tensor")),
+        (r".*", P()),
+    )
+
+
+def check_tp_divides(cfg: LlamaConfig, tp: int) -> None:
+    """Every dimension serve_partition_rules splits must divide by the
+    "tensor" axis size ``tp``."""
+    for name in ("n_kv_heads", "n_heads", "ffn_dim", "vocab_size"):
+        val = getattr(cfg, name)
+        if val % tp:
+            raise ValueError(
+                f"tp_degree={tp} must divide model {name}={val}")
 
 
 # ---------------------------------------------------------------------------
@@ -392,7 +421,7 @@ def chunked_cross_entropy(lm_head, hidden, targets, chunk: int = 256,
     ``remat=False`` drops the checkpoint: each chunk's fp32 logits persist
     as backward residuals (full B·T·V again, but live only across the CE
     backward region) in exchange for skipping the lm_head recompute matmul
-    — measured 33 ms/step at 1.5B/b4/seq2048 (see LlamaConfig.ce_remat).
+    (see LlamaConfig.ce_remat).
     """
     b, t, d = hidden.shape
     chunk = min(chunk, t)
@@ -432,108 +461,6 @@ def loss_fn(params, batch, cfg: LlamaConfig, mesh=None):
     with jax.named_scope("loss"):
         return chunked_cross_entropy(params["lm_head"], hidden, targets,
                                      chunk=cfg.ce_chunk, remat=cfg.ce_remat)
-
-
-# ---------------------------------------------------------------------------
-# decode path (serving): single-token step against a KV cache
-# ---------------------------------------------------------------------------
-
-def init_kv_cache(cfg: LlamaConfig, batch: int, max_len: int | None = None):
-    max_len = max_len or cfg.max_seq_len
-    shape = (cfg.n_layers, batch, max_len, cfg.n_kv_heads, cfg.head_dim)
-    return {"k": jnp.zeros(shape, cfg.dtype), "v": jnp.zeros(shape, cfg.dtype),
-            "length": jnp.zeros((batch,), jnp.int32)}
-
-
-def decode_step(params, cache, tokens, cfg: LlamaConfig):
-    """One decode step for a batch of sequences (continuous-batching inner op).
-
-    tokens: [B] current token per sequence; cache holds per-sequence lengths.
-    Returns (logits [B, vocab], new_cache).
-    """
-    b = tokens.shape[0]
-    x = params["embed"][tokens[:, None]].astype(cfg.dtype)  # [B,1,D]
-    positions = cache["length"][:, None]  # [B,1]
-    cos, sin = rope_freqs(cfg, positions)
-    max_len = cache["k"].shape[2]
-    pos_mask = jnp.arange(max_len)[None, :] <= cache["length"][:, None]  # [B,L]
-
-    def body(carry, inputs):
-        x, = carry
-        layer, k_cache, v_cache = inputs
-        h = rms_norm(x, layer["attn_norm"], cfg.norm_eps)
-        q = jnp.einsum("btd,dhk->bthk", h, layer["attn"]["wq"])
-        k = jnp.einsum("btd,dhk->bthk", h, layer["attn"]["wk"])
-        v = jnp.einsum("btd,dhk->bthk", h, layer["attn"]["wv"])
-        q = apply_rope(q, cos, sin)
-        k = apply_rope(k, cos, sin)
-        # write k/v at each sequence's current length
-        onehot = jax.nn.one_hot(cache["length"], max_len, dtype=k.dtype)  # [B,L]
-        k_cache = k_cache * (1 - onehot[..., None, None]) + (
-            onehot[..., None, None] * k[:, 0][:, None])
-        v_cache = v_cache * (1 - onehot[..., None, None]) + (
-            onehot[..., None, None] * v[:, 0][:, None])
-        n_rep = cfg.n_heads // cfg.n_kv_heads
-        k_full = _gqa_expand(k_cache, n_rep)
-        v_full = _gqa_expand(v_cache, n_rep)
-        sm = cfg.head_dim ** -0.5
-        logits = jnp.einsum("bqhd,bkhd->bhqk", q, k_full).astype(jnp.float32) * sm
-        logits = jnp.where(pos_mask[:, None, None, :], logits, -1e30)
-        p = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
-        attn = jnp.einsum("bhqk,bkhd->bqhd", p, v_full)
-        x = x + jnp.einsum("bthk,hkd->btd", attn, layer["attn"]["wo"])
-        h2 = rms_norm(x, layer["mlp_norm"], cfg.norm_eps)
-        gate = jax.nn.silu(h2 @ layer["mlp"]["w_gate"])
-        up = h2 @ layer["mlp"]["w_up"]
-        x = x + (gate * up) @ layer["mlp"]["w_down"]
-        return (x,), (k_cache, v_cache)
-
-    (x,), (new_k, new_v) = jax.lax.scan(
-        body, (x,), (params["layers"], cache["k"], cache["v"]))
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = (x[:, 0] @ params["lm_head"]).astype(jnp.float32)
-    new_cache = {"k": new_k, "v": new_v, "length": cache["length"] + 1}
-    return logits, new_cache
-
-
-def prefill(params, cache, tokens, cfg: LlamaConfig, lengths=None):
-    """Prefill the KV cache with prompt tokens [B, T_prompt]; returns logits of
-    the last position per sequence and the filled cache."""
-    b, t = tokens.shape
-    if lengths is None:
-        lengths = jnp.full((b,), t, jnp.int32)
-    x = params["embed"][tokens].astype(cfg.dtype)
-    positions = jnp.broadcast_to(jnp.arange(t), (b, t))
-    cos, sin = rope_freqs(cfg, positions)
-    max_len = cache["k"].shape[2]
-
-    def body(carry, inputs):
-        x, = carry
-        layer, k_cache, v_cache = inputs
-        h = rms_norm(x, layer["attn_norm"], cfg.norm_eps)
-        q = jnp.einsum("btd,dhk->bthk", h, layer["attn"]["wq"])
-        k = jnp.einsum("btd,dhk->bthk", h, layer["attn"]["wk"])
-        v = jnp.einsum("btd,dhk->bthk", h, layer["attn"]["wv"])
-        q = apply_rope(q, cos, sin)
-        k = apply_rope(k, cos, sin)
-        attn = _attention(q, k, v, cfg, None)
-        x = x + jnp.einsum("bthk,hkd->btd", attn, layer["attn"]["wo"])
-        h2 = rms_norm(x, layer["mlp_norm"], cfg.norm_eps)
-        gate = jax.nn.silu(h2 @ layer["mlp"]["w_gate"])
-        up = h2 @ layer["mlp"]["w_up"]
-        x = x + (gate * up) @ layer["mlp"]["w_down"]
-        k_cache = jax.lax.dynamic_update_slice(
-            k_cache, k.astype(k_cache.dtype), (0, 0, 0, 0))
-        v_cache = jax.lax.dynamic_update_slice(
-            v_cache, v.astype(v_cache.dtype), (0, 0, 0, 0))
-        return (x,), (k_cache, v_cache)
-
-    (x,), (new_k, new_v) = jax.lax.scan(
-        body, (x,), (params["layers"], cache["k"], cache["v"]))
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    last = jnp.take_along_axis(x, (lengths - 1)[:, None, None], axis=1)[:, 0]
-    logits = (last @ params["lm_head"]).astype(jnp.float32)
-    return logits, {"k": new_k, "v": new_v, "length": lengths}
 
 
 # ---------------------------------------------------------------------------

@@ -55,13 +55,32 @@ def _accelerator_chips_per_host(pod_type: str) -> int:
 
 
 def local_chip_count() -> int:
-    """TPU chips a process started from this environment can open: the
-    device nodes present, or 0 when ``JAX_PLATFORMS`` pins jax to the CPU
+    """TPU chips this host has for a process started from this environment:
+    the device nodes present, or 0 when ``JAX_PLATFORMS`` pins jax to the CPU
     (the chips are then unreachable, and advertising them would place TPU
-    work on a node that cannot run it)."""
+    work on a node that cannot run it). Present is not free: a node admits
+    one process at a time, and ``openable_chip_count`` says how many can be
+    opened now."""
     if pinned_to_cpu():
         return 0
     return sum(len(glob.glob(pat)) for pat in _CHIP_DEVICE_GLOBS)
+
+
+def openable_chip_count(globs: tuple[str, ...] = _CHIP_DEVICE_GLOBS) -> int:
+    """Chip nodes that can be opened at this moment. Each node is opened and
+    closed at once; one that another process holds fails with EBUSY, also
+    while that process is already dead and /proc shows it as a zombie with
+    no file open (a dead worker's chips take seconds to be released). A
+    host with no nodes answers 0 without opening anything."""
+    n = 0
+    for pat in globs:
+        for node in glob.glob(pat):
+            try:
+                os.close(os.open(node, os.O_RDWR | os.O_CLOEXEC))
+            except OSError:
+                continue
+            n += 1
+    return n
 
 
 def detect_local_topology() -> SliceTopology | None:

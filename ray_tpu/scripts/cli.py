@@ -1,9 +1,8 @@
-"""ray-tpu CLI: start / stop / status / submit / logs / jobs /
-microbenchmark / timeline.
+"""ray-tpu CLI: start / stop / status / submit / logs / jobs / timeline.
 
 TPU-native analog of the reference's CLI surface
 (/root/reference/python/ray/scripts/scripts.py — `ray start/stop/status/
-microbenchmark/timeline`; dashboard/modules/job/cli.py — `ray job submit`).
+timeline`; dashboard/modules/job/cli.py — `ray job submit`).
 
 Usage:
     python -m ray_tpu start --head [--port 6380] [--num-cpus 8] [--store-path p]
@@ -14,7 +13,6 @@ Usage:
     python -m ray_tpu jobs [--address ...]
     python -m ray_tpu logs JOB_ID [--address ...]
     python -m ray_tpu stop
-    python -m ray_tpu microbenchmark
     python -m ray_tpu timeline --out trace.json
     python -m ray_tpu metrics [NAME] [--tags k=v] [--since TS] [--watch]
 """
@@ -304,14 +302,6 @@ def cmd_logs(args) -> None:
 
     ray_tpu.init(address=_read_address(args.address))
     print(JobSubmissionClient().get_job_logs(args.job_id, tail=args.tail))
-
-
-def cmd_microbenchmark(args) -> None:
-    import runpy
-    sys.argv = ["microbench.py"] + (["--quick"] if args.quick else [])
-    path = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.dirname(os.path.abspath(__file__)))), "microbench.py")
-    runpy.run_path(path, run_name="__main__")
 
 
 def cmd_timeline(args) -> None:
@@ -704,10 +694,6 @@ def main(argv=None) -> None:
     sp.add_argument("--address", default=None)
     sp.add_argument("--tail", type=int, default=1000)
     sp.set_defaults(fn=cmd_logs)
-
-    sp = sub.add_parser("microbenchmark", help="run core microbenchmarks")
-    sp.add_argument("--quick", action="store_true")
-    sp.set_defaults(fn=cmd_microbenchmark)
 
     sp = sub.add_parser("timeline", help="dump a chrome trace of task events")
     sp.add_argument("--address", default=None)

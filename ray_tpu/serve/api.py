@@ -271,8 +271,8 @@ def start_http_proxy(host: str = "127.0.0.1", port: int = 8000,
                      router_config=None):
     """Start the node's HTTP ingress (reference: one HTTPProxy actor per
     node, proxy.py:706; here one aiohttp server in the driver process).
-    router_config overrides the proxy's RouterConfig (e.g. the affinity
-    A/B in bench_serve.py); ignored if a proxy is already running."""
+    router_config overrides the proxy's RouterConfig; ignored if a proxy
+    is already running."""
     global _proxy
     from ray_tpu.serve.proxy import HTTPProxy
     with _lock:

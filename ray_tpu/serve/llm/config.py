@@ -85,10 +85,10 @@ class LLMConfig:
     # phase timers (admit/prefill/chunk/decode/verify/harvest p50+p95),
     # inter-token-latency ring, and device-memory gauges in engine_stats().
     # Default ON — overhead is host-side clock reads on a loop that
-    # dispatches device work asynchronously, A/B-bounded by
-    # `bench_serve.py --profile-ab`. Compile-event tracking stays on even
-    # when this is False (it only does work on first-dispatch-per-shape,
-    # and silent mid-traffic compiles are the failure class it catches).
+    # dispatches device work asynchronously. Compile-event tracking stays
+    # on even when this is False (it only does work on first-dispatch-per-
+    # shape, and silent mid-traffic compiles are the failure class it
+    # catches).
     profiling_enabled: bool = True
 
     # Automatic prefix caching (RadixAttention/vLLM-style): full pages of
@@ -145,8 +145,7 @@ class LLMConfig:
     # "lossless" (byte-plane shuffle + DEFLATE) keeps greedy outputs
     # bit-identical; "int8" (per layer/kv-head scale quantization, ~4x
     # on fp32 before entropy coding) trades bounded reconstruction
-    # error for ratio — opt-in, divergence measured by
-    # `bench_serve.py --kv-tier-ab`; "none" is the raw PR 7 wire format.
+    # error for ratio — opt-in; "none" is the raw PR 7 wire format.
     kv_tier_codec: str = "lossless"              # "none"|"lossless"|"int8"
     # Streaming restore: pages land chunk-by-chunk and inject while
     # later chunks are still in flight. chunk_pages is the fetch
@@ -200,10 +199,11 @@ class LLMConfig:
     # kv_tier_codec so prefill and decode share a tier namespace).
     # "int8" here is governed by the quality policy below.
     disagg_wire_codec: str = "lossless"          # "none"|"lossless"|"int8"
-    # Quality policy gating int8 on the disagg wire: the bench A/B arm
-    # measures greedy-output divergence (fraction of positions where the
-    # int8-wire output differs from lossless) and int8 is only policy-
-    # approved when measured divergence <= this bound. 0.0 = int8 must
+    # Quality policy gating int8 on the disagg wire: whoever turns int8
+    # on measures greedy-output divergence (disagg.int8_wire_divergence:
+    # fraction of positions where the int8-wire output differs from
+    # lossless) and int8 is only policy-approved
+    # (disagg.int8_wire_allowed) when it is <= this bound. 0.0 = int8 must
     # be bit-identical to pass (i.e. effectively requires lossless).
     disagg_int8_max_divergence: float = 0.0
 

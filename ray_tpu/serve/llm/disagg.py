@@ -128,8 +128,8 @@ def _decode_state(state: dict) -> dict:
 def int8_wire_divergence(ref_tokens, got_tokens) -> float:
     """Greedy-output divergence between a lossless-wire reference and an
     int8-wire run: fraction of positions that differ (length mismatch
-    counts every unmatched position). The bench A/B arm feeds this to
-    :func:`int8_wire_allowed`."""
+    counts every unmatched position). What :func:`int8_wire_allowed`
+    takes."""
     ref = list(ref_tokens or [])
     got = list(got_tokens or [])
     n = max(len(ref), len(got), 1)
@@ -163,7 +163,6 @@ def prefill_only(eng: LLMEngine, prompt, *, temperature: float | None = None,
       {prompt_tokens, plen, n_pages, first_token, kv_k, kv_v,
        temperature, prefill_ttft_s}
     """
-    jnp = eng._jnp
     t0 = time.monotonic()
     if isinstance(prompt, str):
         toks = eng.tokenizer.encode(prompt)
@@ -201,11 +200,9 @@ def prefill_only(eng: LLMEngine, prompt, *, temperature: float | None = None,
                 eng.params, eng.kv, eng._dev_tokens, table, padded,
                 np.int32(plen), sub, np.full((1,), temperature, np.float32),
                 np.int32(eng.cfg.max_batch_size))
-            # extract this request's pages to host (the handoff payload);
-            # pool layout [L, Hkv, P, page, D] — pages are axis 2
-            pidx = jnp.asarray(table[:n_pages], jnp.int32)
-            kv_k = np.asarray(eng.kv["k"][:, :, pidx])
-            kv_v = np.asarray(eng.kv["v"][:, :, pidx])
+            # extract this request's pages to host (the handoff payload)
+            kv_k, kv_v = eng._kvc.fetch_pages(
+                *eng._kvc.gather_pages(eng.kv, pages), n_pages)
             first = int(tok_dev)
         finally:
             eng.allocator.free(pages)
@@ -231,7 +228,6 @@ class DecodeEngine(LLMEngine):
         super().__init__(_disable_prefix_cache(cfg), params=params,
                          rng_seed=rng_seed)
         self._inject_q: list[tuple[_Request, dict]] = []
-        self._inject_fn = None
 
     def submit_prefilled(self, state: dict, *,
                          max_tokens: Optional[int] = None,
@@ -295,31 +291,10 @@ class DecodeEngine(LLMEngine):
     def _inject(self, req: _Request, state: dict):
         """Scatter the handed-off KV pages into the local pool and arm the
         slot (loop thread only)."""
-        jnp = self._jnp
-        n_src = state["n_pages"]
         table = np.zeros((self.max_pages_per_seq,), np.int32)
         table[: len(req.pages)] = req.pages
-        # pad the blob to max_pages_per_seq so ONE program shape covers
-        # every prompt length (targets pad onto the trash page 0)
-        mp = self.max_pages_per_seq
-        # blob layout [L, Hkv, n_pages, page, D] — pad the page axis (2)
-        pad = ((0, 0), (0, 0), (0, mp - n_src), (0, 0), (0, 0))
-        blob_k = jnp.asarray(np.pad(state["kv_k"], pad))
-        blob_v = jnp.asarray(np.pad(state["kv_v"], pad))
-        tgt = np.zeros((mp,), np.int32)
-        tgt[:n_src] = req.pages[:n_src]
-        if self._inject_fn is None:
-            jax = self._jax
-
-            def impl(kv, bk, bv, pages):
-                # donated pool: injection rewrites the pages in place
-                # instead of copying the (GB-scale) pool per admission
-                return {"k": kv["k"].at[:, :, pages].set(bk),
-                        "v": kv["v"].at[:, :, pages].set(bv)}
-
-            self._inject_fn = jax.jit(impl, donate_argnums=(0,))
-        self.kv = self._inject_fn(self.kv, blob_k, blob_v,
-                                  jnp.asarray(tgt, jnp.int32))
+        self._inject_host_pages([(state["kv_k"], state["kv_v"])],
+                                req.pages[:state["n_pages"]])
         with self._lock:
             self.page_tables[req.slot] = table
             self.seq_lens[req.slot] = state["plen"]
@@ -369,7 +344,7 @@ class PrefillServer:
                     page_size=cfg.page_size,
                     namespace=kv_tier_namespace(
                         cfg, self.engine.model_cfg,
-                        self.engine.kv["k"].dtype),
+                        self.engine._kvc.pool_dtype(self.engine.kv)),
                     codec=cfg.kv_tier_codec)
             return self._tier
 
@@ -441,8 +416,7 @@ class PrefillServer:
         if mode == "none":
             return 1.0
         from ray_tpu.serve.llm import kv_codec
-        vocab = max(2, int(getattr(self.engine.model_cfg,
-                                   "vocab_size", 2)))
+        vocab = max(2, int(self.engine.tokenizer.vocab_size))
         toks = [(i * 37 + 11) % vocab
                 for i in range(max(1, self.cfg.max_prompt_len))]
         state = prefill_only(self.engine, toks, temperature=0.0)
@@ -468,10 +442,9 @@ class PrefillServer:
 def _handoff_channel_capacity(cfg: LLMConfig,
                               measured_ratio: float | None = None) -> int:
     """Channel capacity sized for the largest KV handoff blob this config
-    can produce (a max_prompt_len prompt's pages), not the default 8 MiB:
-    k+v arrays are [L, Hkv, n_pages, page, D] in the model dtype, and
-    Channel.write hard-fails on overflow — an undersized pipe would poison
-    every later request on it.
+    can produce (a max_prompt_len prompt's pages, k+v in the model dtype),
+    not the default 8 MiB: Channel.write hard-fails on overflow — an
+    undersized pipe would poison every later request on it.
 
     Since PR 15 the blob travels ENCODED (``disagg_wire_codec``), so raw
     model-dtype sizing over-provisions the channel by the codec ratio
@@ -481,11 +454,9 @@ def _handoff_channel_capacity(cfg: LLMConfig,
     never dropping below raw sizing: the probe samples one prompt, other
     prompts compress worse, and overflow poisons the pipe while idle
     headroom only costs shm."""
-    mc = cfg.llama()
+    from ray_tpu.serve.llm import kv_cache
     pages = -(-cfg.max_prompt_len // cfg.page_size)
-    itemsize = np.dtype(getattr(mc, "dtype", np.float32)).itemsize
-    kv_bytes = 2 * mc.n_layers * mc.n_kv_heads * pages * cfg.page_size \
-        * mc.head_dim * itemsize  # k+v in the model dtype
+    kv_bytes = pages * kv_cache.page_raw_nbytes(cfg.llama(), cfg.page_size)
     if cfg.disagg_wire_codec != "none":
         ratio = max(1.0, 0.5 * float(measured_ratio or 0.0))
         kv_bytes = int(kv_bytes / ratio)

@@ -322,22 +322,13 @@ class LLMEngine:
                 ttl_s=cfg.kv_tier_ttl_s,
                 page_size=cfg.page_size,
                 namespace=kv_tier_namespace(
-                    cfg, self.model_cfg, self.kv["k"].dtype, rng_seed),
+                    cfg, self.model_cfg, kvc.pool_dtype(self.kv), rng_seed),
                 codec=cfg.kv_tier_codec,
                 # per-shard encoded sub-payloads under ONE chain digest
                 # (ISSUE 20): the namespace above already carries |tp{N}
                 # so layouts never mix across stores
                 shards=self._tp)
             self.allocator.spill_hook = self._spill_capture
-            # restore scatter at ONE fixed shape (max_pages_per_seq,
-            # trash-page padded) — same donated-pool pattern as disagg's
-            # _inject; an eager per-count scatter would compile per
-            # distinct restored-page count
-            self._tier_inject = jax.jit(
-                lambda kv, bk, bv, pages: {
-                    "k": kv["k"].at[:, :, pages].set(bk),
-                    "v": kv["v"].at[:, :, pages].set(bv)},
-                donate_argnums=(0,))
         # Speculative decoding (spec_decode.py + the verify-k program
         # below): host-side n-gram drafts verified k-at-a-time in one
         # fused dispatch. Greedy-only guarantee — non-greedy slots are
@@ -417,6 +408,10 @@ class LLMEngine:
                               drafts),
             donate_argnums=(1, 3, 4))
         self._prefill_cache: dict[int, Any] = {}
+        # host pages into the donated pool (_inject_host_pages: the tier's
+        # restore and warm start, disagg's adoption) at ONE fixed shape; an
+        # eager per-count scatter would compile per distinct page count
+        self._inject_kv = jax.jit(kvc.scatter_pages, donate_argnums=(0,))
         # Slot-state patches run at ONE fixed shape (B+1 rows, trash-row
         # padded) through these jitted fns. Eager .at[idx].set() with a
         # dirty-count-sized idx compiled a fresh scatter per distinct count
@@ -442,30 +437,10 @@ class LLMEngine:
         self._split_key = jax.jit(split_key)
 
     # ---- tensor parallelism (ISSUE 20) ---------------------------------
-    @staticmethod
-    def tp_partition_rules():
-        """Serve-side Megatron TP rules, consumed by
-        parallel.sharding.rule_shardings (ordered; first re.search match
-        wins). Column-parallel qkv/gate/up, row-parallel wo/w_down (their
-        contractions psum across the axis), vocab-sharded lm_head (argmax
-        composes exactly across shards), everything else — embed, norms,
-        scalars — replicated. The attention split rides the kv-major GQA
-        head order: H/tp query heads are exactly (Hkv/tp) whole kv-head
-        groups, so per-head attention math never crosses a shard."""
-        from jax.sharding import PartitionSpec as P
-        return (
-            (r"layers/attn/w[qkv]$", P(None, None, "tensor", None)),
-            (r"layers/attn/wo$", P(None, "tensor", None, None)),
-            (r"layers/mlp/w_(gate|up)$", P(None, None, "tensor")),
-            (r"layers/mlp/w_down$", P(None, "tensor", None)),
-            (r"lm_head$", P(None, "tensor")),
-            (r".*", P()),
-        )
-
     def _setup_tp_mesh(self):
         """Build the tp_degree-device "tensor" mesh and commit the engine's
-        device state to it: params via the partition rules, the KV pool
-        split per-KV-head (axis 1 of [L, Hkv, P, page, D]). Committed
+        device state to it: params via the block's serve partition rules,
+        the KV pool by its module's spec (per KV head). Committed
         (device_put) shardings are what make every later jit — decode /
         verify / prefill / tier-inject — compile as a partitioned program
         without per-call annotations; donation then keeps the buffers
@@ -473,20 +448,14 @@ class LLMEngine:
         patches, restore blobs) stay uncommitted and are resharded by the
         compiled programs' input layouts."""
         jax = self._jax
-        from jax.sharding import NamedSharding, PartitionSpec as P
+        from jax.sharding import NamedSharding
 
+        from ray_tpu.models import llama
         from ray_tpu.parallel import sharding as shd
         from ray_tpu.parallel.mesh import MeshSpec, build_mesh
 
         tp = self._tp
-        mc = self.model_cfg
-        for name, val in (("n_kv_heads", mc.n_kv_heads),
-                          ("n_heads", mc.n_heads),
-                          ("ffn_dim", mc.ffn_dim),
-                          ("vocab_size", mc.vocab_size)):
-            if val % tp:
-                raise ValueError(
-                    f"tp_degree={tp} must divide model {name}={val}")
+        llama.check_tp_divides(self.model_cfg, tp)
         devices = jax.devices()
         if len(devices) < tp:
             raise ValueError(
@@ -494,13 +463,11 @@ class LLMEngine:
         mesh = build_mesh(MeshSpec(tensor=tp), devices[:tp])
         self.params = jax.device_put(
             self.params,
-            shd.rule_shardings(self.tp_partition_rules(), self.params,
+            shd.rule_shardings(llama.serve_partition_rules(), self.params,
                                mesh))
         self.kv = jax.device_put(
-            self.kv, NamedSharding(mesh, P(None, "tensor")))
-        logger.info("TP mesh up: %s over %d devices (pool %d kv heads"
-                    " -> %d per shard)", dict(mesh.shape), tp,
-                    mc.n_kv_heads, mc.n_kv_heads // tp)
+            self.kv, NamedSharding(mesh, self._kvc.pool_spec()))
+        logger.info("TP mesh up: %s over %d devices", dict(mesh.shape), tp)
         return mesh
 
     # ---- compiled impls ------------------------------------------------
@@ -730,14 +697,11 @@ class LLMEngine:
             # the tier-restore scatter too: its one fixed shape would
             # otherwise compile on the first tier hit, mid-traffic (an
             # all-trash-page write of zeros is a no-op)
-            jnp = self._jnp
             mp = self.max_pages_per_seq
-            zb = jnp.zeros(self.kv["k"].shape[:2] + (mp,)
-                           + self.kv["k"].shape[3:], self.kv["k"].dtype)
             with self._prof.compile_scope("kv_tier_inject",
                                           ("kv_tier_inject", mp)):
-                self.kv = self._tier_inject(
-                    self.kv, zb, zb, jnp.zeros((mp,), jnp.int32))
+                self._inject_host_pages(
+                    [self._kvc.zero_pages(self.kv, mp)], ())
         self._jax.block_until_ready(self._dev_tokens)
 
     def shutdown(self):
@@ -1108,7 +1072,7 @@ class LLMEngine:
         out["mesh_shape"] = ("none" if self._mesh is None else ",".join(
             f"{a}={n}" for a, n in dict(self._mesh.shape).items()
             if n > 1))
-        pool_bytes = int(self.kv["k"].nbytes + self.kv["v"].nbytes)
+        pool_bytes = self._kvc.pool_nbytes(self.kv)
         out["kv_shard_pool_bytes"] = pool_bytes // self._tp
         out["kv_shard_page_occupancy"] = (
             (self.cfg.num_pages - free) * pool_bytes
@@ -1416,7 +1380,6 @@ class LLMEngine:
         the pre-eviction KV on the ordered device stream. Only the
         dispatch happens here; the device->host copy is started async and
         harvested later by _kv_tier_flush, off the admission hot path."""
-        jnp = self._jnp
         ents = [(p, d, pos) for (p, d, pos) in evicted if pos is not None]
         if not ents:
             return
@@ -1426,11 +1389,8 @@ class LLMEngine:
             # pad the gather index to the fixed width with the trash page
             # (sliced off host-side) so spill batches of every size share
             # one compiled gather
-            pidx = jnp.asarray(
-                [p for p, _, _ in batch] + [0] * (w - len(batch)),
-                jnp.int32)
-            bk = jnp.take(self.kv["k"], pidx, axis=2)
-            bv = jnp.take(self.kv["v"], pidx, axis=2)
+            bk, bv = self._kvc.gather_pages(
+                self.kv, [p for p, _, _ in batch] + [0] * (w - len(batch)))
             self._start_fetch(bk)
             self._start_fetch(bv)
             self._tier_pending.append((bk, bv, batch))
@@ -1445,8 +1405,7 @@ class LLMEngine:
         pend, self._tier_pending = self._tier_pending, []
         for bk, bv, ents in pend:
             try:
-                k_np = np.asarray(bk)[:, :, :len(ents)]
-                v_np = np.asarray(bv)[:, :, :len(ents)]
+                k_np, v_np = self._kvc.fetch_pages(bk, bv, len(ents))
                 n = self._kv_tier.put(
                     k_np, v_np,
                     digests=[d.hex() for _, d, _ in ents],
@@ -1570,7 +1529,6 @@ class LLMEngine:
             logger.warning("warm start: chain enumeration failed",
                            exc_info=True)
             chains = []
-        jnp = self._jnp
         mp = self.max_pages_per_seq
         for chain in chains:
             if time.perf_counter() >= deadline \
@@ -1610,22 +1568,8 @@ class LLMEngine:
                     pgs = self.allocator.alloc(len(pairs))
                     if pgs is None:
                         break
-                    k_np = np.concatenate([k for k, _ in pairs], axis=2)
-                    v_np = np.concatenate([v for _, v in pairs], axis=2)
                     t = len(pairs)
-                    pad = np.zeros(k_np.shape[:2] + (mp - t,)
-                                   + k_np.shape[3:], k_np.dtype)
-                    with self._prof.compile_scope(
-                            "kv_tier_inject", ("kv_tier_inject", mp),
-                            mid_traffic=self.stats["requests"] > 0):
-                        self.kv = self._tier_inject(
-                            self.kv,
-                            jnp.asarray(np.concatenate([k_np, pad],
-                                                       axis=2)),
-                            jnp.asarray(np.concatenate([v_np, pad],
-                                                       axis=2)),
-                            jnp.asarray(list(pgs) + [0] * (mp - t),
-                                        jnp.int32))
+                    self._tier_inject(pairs, pgs)
                     self.allocator.insert_digest_chain(
                         digs[c:c + t], pgs, list(range(c, c + t)))
                     # decref to zero: registered pages park in the LRU,
@@ -1761,37 +1705,41 @@ class LLMEngine:
                 stream.abort()
         return progressed
 
+    def _inject_host_pages(self, pairs, pages) -> None:
+        """Write host page pairs (in order, one or more pages each) into
+        pool pages ``pages`` through the ONE fixed-shape donated program:
+        the blob zero-padded to max_pages_per_seq, its targets padded with
+        the trash page. Loop thread only (one driver per device stream)."""
+        mp = self.max_pages_per_seq
+        bk, bv = self._kvc.pack_pages(pairs, mp)
+        tgt = np.zeros((mp,), np.int32)
+        tgt[:len(pages)] = pages
+        self.kv = self._inject_kv(self.kv, bk, bv, tgt)
+
+    def _tier_inject(self, pairs, pages) -> None:
+        """_inject_host_pages for the tier's restore paths, which count a
+        first use after traffic began as a mid-traffic compile."""
+        with self._prof.compile_scope(
+                "kv_tier_inject",
+                ("kv_tier_inject", self.max_pages_per_seq),
+                mid_traffic=self.stats["requests"] > 0):
+            self._inject_host_pages(pairs, pages)
+
     def _inject_pages(self, req: _Request, pairs: list) -> int:
         """Scatter decoded chain pages (in chain order, continuing at
-        restore_page0 + restore_pages) into this request's pool pages —
-        the same ONE fixed-shape donated-pool program as the old whole-
-        chain restore, chunk-sized input zero-padded to it."""
-        jnp = self._jnp
+        restore_page0 + restore_pages) into this request's pool pages."""
         ps = self.cfg.page_size
-        mp = self.max_pages_per_seq
         pos0 = req.restore_page0 + req.restore_pages
         t = min(len(pairs), len(req.pages) - pos0)
         if t <= 0:
             return 0
-        k_np = np.concatenate([k for k, _ in pairs[:t]], axis=2)
-        v_np = np.concatenate([v for _, v in pairs[:t]], axis=2)
-        shape = k_np.shape
-        pad = np.zeros(shape[:2] + (mp - t,) + shape[3:], k_np.dtype)
-        pages_vec = jnp.asarray(
-            list(req.pages[pos0:pos0 + t]) + [0] * (mp - t), jnp.int32)
-        with self._prof.compile_scope(
-                "kv_tier_inject", ("kv_tier_inject", mp),
-                mid_traffic=self.stats["requests"] > 0):
-            self.kv = self._tier_inject(
-                self.kv,
-                jnp.asarray(np.concatenate([k_np, pad], axis=2)),
-                jnp.asarray(np.concatenate([v_np, pad], axis=2)),
-                pages_vec)
+        self._tier_inject(pairs[:t], req.pages[pos0:pos0 + t])
         req.restore_pages += t
         req.cached_tokens = (pos0 + t) * ps
         req.prefill_pos = req.cached_tokens
         req.restored_tokens += t * ps
-        req.restore_bytes += int(k_np.nbytes) + int(v_np.nbytes)
+        req.restore_bytes += t * self._kvc.page_raw_nbytes(
+            self.model_cfg, ps)
         self.stats["restored_pages"] += t
         self.stats["tier_hit_tokens"] += t * ps
         return t

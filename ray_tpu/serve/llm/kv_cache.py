@@ -46,6 +46,12 @@ Design choices:
   identical to the single-chip call on a pool with Hkv/tp heads.
 
 Page 0 is RESERVED as the trash page; the allocator never hands it out.
+
+This module alone knows the DEVICE pool's format (two arrays, pages on axis
+2, KV heads on axis 1): the engine, disaggregation and the tier path move
+pages through the page operations below ``init_paged_cache``. The HOST blobs
+that kv_tier.py, kv_codec.py and disagg's wire codec carry (pairs of arrays
+with pages on axis 2) keep their own format, which those modules own.
 """
 
 from __future__ import annotations
@@ -92,6 +98,66 @@ def page_raw_nbytes(cfg: LlamaConfig, page_size: int) -> int:
     prefetch window, chunk sizing) can size before any page exists."""
     per = cfg.n_layers * cfg.n_kv_heads * page_size * cfg.head_dim
     return 2 * per * np.dtype(cfg.dtype).itemsize
+
+
+def pool_nbytes(kv) -> int:
+    """Bytes the whole pool holds on the device(s), k + v."""
+    return int(kv["k"].nbytes + kv["v"].nbytes)
+
+
+def pool_dtype(kv):
+    """The dtype pages are stored in (and a spilled blob carries)."""
+    return kv["k"].dtype
+
+
+def pool_spec():
+    """PartitionSpec of both pool arrays on a "tensor" mesh: split per KV
+    head, whole pages on every shard (see the module docstring)."""
+    from jax.sharding import PartitionSpec as P
+    return P(None, "tensor")
+
+
+def gather_pages(kv, pages):
+    """Pool pages ``pages`` ([n] ints) as a device blob pair (bk, bv), each
+    [L, Hkv, n, page, D]."""
+    pidx = np.asarray(pages, np.int32)
+    return jnp.take(kv["k"], pidx, axis=2), jnp.take(kv["v"], pidx, axis=2)
+
+
+def scatter_pages(kv, bk, bv, pages):
+    """Write blob page i of (bk, bv) into pool page ``pages[i]``. The body of
+    the engine's donated inject program: the pool is rewritten in place. A
+    blob padded with zero pages targets the trash page with them."""
+    return {"k": kv["k"].at[:, :, pages].set(bk),
+            "v": kv["v"].at[:, :, pages].set(bv)}
+
+
+def fetch_pages(bk, bv, n: int):
+    """Host copy of the first ``n`` pages of a :func:`gather_pages` result
+    (a gather at a fixed width is padded with the trash page past them)."""
+    return np.asarray(bk)[:, :, :n], np.asarray(bv)[:, :, :n]
+
+
+def zero_pages(kv, n: int):
+    """A host blob pair of ``n`` zero pages in the pool's shape and dtype."""
+    shape = kv["k"].shape[:2] + (n,) + kv["k"].shape[3:]
+    return np.zeros(shape, kv["k"].dtype), np.zeros(shape, kv["v"].dtype)
+
+
+def pack_pages(pairs, width: int):
+    """Host page pairs [(k, v), ...] (each holding one or more pages) as ONE
+    blob pair of exactly ``width`` pages: laid down in order on the page
+    axis, zeros behind them — the fixed shape :func:`scatter_pages` is
+    compiled at."""
+    first = pairs[0][0]
+    shape = first.shape[:2] + (width,) + first.shape[3:]
+    bk, bv = np.zeros(shape, first.dtype), np.zeros(shape, first.dtype)
+    at = 0
+    for k, v in pairs:
+        n = k.shape[2]
+        bk[:, :, at:at + n], bv[:, :, at:at + n] = k, v
+        at += n
+    return bk, bv
 
 
 def _chain_digest(parent: bytes, chunk) -> bytes:

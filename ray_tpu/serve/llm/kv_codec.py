@@ -17,13 +17,11 @@ spill time and undoes at restore:
   unchanged. The ratio is data-dependent: narrow-range bf16 KV
   compresses hard, full-mantissa fp32 from random-init weights is
   entropy-bound near 1x on its mantissa planes.
-- ``int8`` (opt-in, divergence measured in ``bench_serve --kv-tier-ab``):
-  per-(layer, kv-head) symmetric scale quantization to int8, then
-  DEFLATE over the quantized planes. 4x from the width cut on fp32
+- ``int8`` (opt-in): per-(layer, kv-head) symmetric scale quantization to
+  int8, then DEFLATE over the quantized planes. 4x from the width cut on fp32
   before entropy coding; reconstruction error is bounded per element by
   ``amax / 127`` within its (layer, head) group. NOT bit-exact — greedy
-  outputs can diverge, which is why it is off by default and the bench
-  records the divergence instead of asserting identity.
+  outputs can diverge, which is why it is off by default.
 - ``none``: identity passthrough (the PR 7 raw-page wire format). Kept
   so a codec rollout can mix replicas: the tier's read path accepts
   both raw and encoded blobs regardless of its own write mode.

@@ -309,7 +309,7 @@ def test_dryrun_collective_accounting(jax_cpu_mesh):
 
 
 def test_int8_matmul_close_and_differentiable():
-    """int8_matmul (dynamic-quant MXU path, BENCH_NOTES r4): forward close
+    """int8_matmul (dynamic-quant MXU path): forward close
     to the fp matmul at int8 precision; gradients flow (straight-through)."""
     import jax
     import jax.numpy as jnp
@@ -423,10 +423,11 @@ def test_serve_and_train_share_rule_machinery():
     eng_src = inspect.getsource(LLMEngine._setup_tp_mesh)
     assert "rule_shardings" in eng_src
     # and the serve rules themselves are resolvable by the shared matcher
-    from ray_tpu.models.llama import init_params, llama_tiny
+    from ray_tpu.models.llama import (init_params, llama_tiny,
+                                      serve_partition_rules)
+    assert "serve_partition_rules" in eng_src
     params = init_params(jax.random.PRNGKey(0), llama_tiny())
-    specs = shd.match_partition_rules(LLMEngine.tp_partition_rules(),
-                                      params)
+    specs = shd.match_partition_rules(serve_partition_rules(), params)
     flat = jax.tree_util.tree_leaves(
         specs, is_leaf=lambda x: isinstance(x, jax.sharding.PartitionSpec))
     assert all(isinstance(s, jax.sharding.PartitionSpec) for s in flat)

@@ -254,3 +254,139 @@ def test_program_changes_only_the_positions_it_writes(params, name, backend):
     trash_offsets = sorted({o for p, o in written if p == 0})
     assert not mask[:, :, 0, [o for o in range(PAGE)
                               if o not in trash_offsets]].any()
+
+
+# ---------------------------------------------------------------------------
+# page operations: the engine, disaggregation and the tier move pages through
+# kv_cache's functions, and nothing else under ray_tpu/ knows the pool's format
+# ---------------------------------------------------------------------------
+
+# slot 1's pages of PAGE_TABLES: the shared prefix page first
+OWN_PAGES = [3, 6, 8, 9]
+
+
+def _assert_pools_equal(got, want):
+    for which in ("k", "v"):
+        assert got[which].dtype == want[which].dtype
+        np.testing.assert_array_equal(np.asarray(got[which]),
+                                      np.asarray(want[which]))
+
+
+def test_gather_then_scatter_returns_the_pool_bit_for_bit():
+    before = _patterned_pool()
+    bk, bv = kv_cache.gather_pages(before, OWN_PAGES)
+    assert bk.shape == POOL_SHAPE[:2] + (len(OWN_PAGES),) + POOL_SHAPE[3:]
+    k_np, v_np = kv_cache.fetch_pages(bk, bv, 3)
+    np.testing.assert_array_equal(
+        k_np, np.asarray(before["k"])[:, :, OWN_PAGES[:3]])
+    np.testing.assert_array_equal(
+        v_np, np.asarray(before["v"])[:, :, OWN_PAGES[:3]])
+    # wipe the pages, then put the gathered blob back
+    idx = jnp.asarray(OWN_PAGES, jnp.int32)
+    wiped = kv_cache.scatter_pages(
+        before, *kv_cache.zero_pages(before, len(OWN_PAGES)), idx)
+    assert not np.asarray(wiped["k"])[:, :, OWN_PAGES].any()
+    _assert_pools_equal(kv_cache.scatter_pages(wiped, bk, bv, idx), before)
+
+
+def test_scatter_padded_with_the_trash_page_changes_no_real_page():
+    before = _patterned_pool()
+    t = 2
+    pairs = [tuple(np.full(POOL_SHAPE[:2] + (1,) + POOL_SHAPE[3:], 7.0 + i,
+                           np.asarray(before["k"]).dtype)
+                   for _ in "kv") for i in range(t)]
+    bk, bv = kv_cache.pack_pages(pairs, MAX_PAGES)
+    tgt = np.zeros((MAX_PAGES,), np.int32)
+    tgt[:t] = OWN_PAGES[1:1 + t]
+    after = jax.jit(kv_cache.scatter_pages)(before, bk, bv, tgt)
+    untouched = [p for p in range(1, N_PAGES) if p not in tgt[:t]]
+    for which in ("k", "v"):
+        old, new = np.asarray(before[which]), np.asarray(after[which])
+        np.testing.assert_array_equal(new[:, :, untouched],
+                                      old[:, :, untouched])
+        for i in range(t):
+            assert (new[:, :, tgt[i]] == 7.0 + i).all()
+
+
+@pytest.mark.parametrize("sizes,width", [((1,), 4), ((1, 1, 1), 4),
+                                         ((1, 1, 1, 1), 4), ((3,), 4),
+                                         ((2, 1), 8)])
+def test_pack_pages_equals_concat_and_pad(sizes, width):
+    rng = np.random.default_rng(0)
+    pairs = [tuple(rng.standard_normal(
+        POOL_SHAPE[:2] + (n,) + POOL_SHAPE[3:]).astype(np.float32)
+        for _ in "kv") for n in sizes]
+    bk, bv = kv_cache.pack_pages(pairs, width)
+    # what the engine's restore paths and disagg's adoption each wrote out
+    t = sum(sizes)
+    for got, col in ((bk, 0), (bv, 1)):
+        cat = np.concatenate([p[col] for p in pairs], axis=2)
+        pad = np.zeros(cat.shape[:2] + (width - t,) + cat.shape[3:],
+                       cat.dtype)
+        np.testing.assert_array_equal(
+            got, np.concatenate([cat, pad], axis=2))
+        np.testing.assert_array_equal(got, np.pad(
+            cat, ((0, 0), (0, 0), (0, width - t), (0, 0), (0, 0))))
+        assert got.dtype == cat.dtype and got.shape[2] == width
+
+
+def test_pool_keeps_its_sharding_through_the_shared_inject_program():
+    """A 2-device "tensor" mesh (conftest forces 8 virtual CPU devices): the
+    ONE donated inject program the tier's restore, the warm start and
+    disagg's adoption share returns the pool split per KV head as it got
+    it, with the host pages in place."""
+    from ray_tpu.serve.llm import LLMConfig, LLMEngine
+
+    cfg = LLMConfig(model_config=CFG, tp_degree=2, max_batch_size=2,
+                    page_size=PAGE, num_pages=N_PAGES, max_prompt_len=16,
+                    max_seq_len=PAGE * MAX_PAGES, max_tokens=4)
+    eng = LLMEngine(cfg, rng_seed=0)
+    try:
+        sharding = eng.kv["k"].sharding
+        assert sharding.shard_shape(POOL_SHAPE)[1] == POOL_SHAPE[1] // 2
+        assert sharding.spec == kv_cache.pool_spec()
+        blob = np.arange(np.prod(POOL_SHAPE[:2] + (2,) + POOL_SHAPE[3:]),
+                         dtype=np.float32).reshape(
+            POOL_SHAPE[:2] + (2,) + POOL_SHAPE[3:])
+        eng._inject_host_pages([(blob[:, :, :1], -blob[:, :, :1]),
+                                (blob[:, :, 1:], -blob[:, :, 1:])], [5, 9])
+        for which, want in (("k", blob), ("v", -blob)):
+            assert eng.kv[which].sharding == sharding
+            got = np.asarray(eng.kv[which])
+            np.testing.assert_array_equal(got[:, :, [5, 9]], want)
+            assert not got[:, :, [p for p in range(1, N_PAGES)
+                                  if p not in (5, 9)]].any()
+        assert kv_cache.pool_nbytes(eng.kv) == 2 * blob.nbytes // 2 * N_PAGES
+        # one fixed shape: another count of pages compiles nothing new
+        compiled = eng._inject_kv._cache_size()
+        eng._inject_host_pages([(blob[:, :, :1], blob[:, :, :1])], [7])
+        assert eng._inject_kv._cache_size() == compiled
+    finally:
+        eng.shutdown()
+
+
+def test_only_kv_cache_subscripts_the_device_pool():
+    """What keeps the next edit from writing the pool's layout down in a
+    second module: under ray_tpu/ only kv_cache.py may take ``kv["k"]`` /
+    ``kv["v"]`` or index ``<engine>.kv[...]``. (The kernels of
+    ops/paged_attention.py take the two arrays by layout from kv_cache.py,
+    their only caller; host blobs have their own names.)"""
+    import pathlib
+    import re
+
+    import ray_tpu
+
+    pat = re.compile(r"""\bkv\[\s*["'][kv]["']\s*\]|\.kv\[""")
+    root = pathlib.Path(ray_tpu.__file__).parent
+    offenders = []
+    for path in sorted(root.rglob("*.py")):
+        if path.relative_to(root).as_posix() == "serve/llm/kv_cache.py":
+            continue
+        for n, line in enumerate(path.read_text().splitlines(), 1):
+            if pat.search(line):
+                offenders.append(f"{path.relative_to(root)}:{n}: "
+                                 f"{line.strip()}")
+    assert not offenders, "\n".join(offenders)
+    # the scan sees what it is meant to see
+    assert pat.search('bk = jnp.take(self.kv["k"], pidx, axis=2)')
+    assert pat.search("x = eng.kv['v'][:, :, pidx]")
