@@ -84,7 +84,8 @@ def sizes(rehearsal: bool, chips: int) -> dict:
     if rehearsal:
         return dict(
             kern=dict(hkv=2, h=4, d=16, page=8, max_pages=4, pool=40, b=4,
-                      chunk=16, flash=(2, 64, 4, 16)),
+                      chunk=16, flash=(2, 64, 4, 4, 16),
+                      flash_gqa=(2, 64, 4, 2, 16)),
             train=dict(batch=4, seq=64, steps=5),
             serve=dict(max_batch_size=8, page_size=8, num_pages=160,
                        max_prompt_len=96, max_seq_len=128, prefill_chunk=32,
@@ -94,7 +95,8 @@ def sizes(rehearsal: bool, chips: int) -> dict:
             prompt_tokens=8, long_tokens=70, shared_tokens=48)
     return dict(
         kern=dict(hkv=8, h=16, d=128, page=128, max_pages=16, pool=288, b=32,
-                  chunk=512, flash=(4, 2048, 16, 128)),
+                  chunk=512, flash=(4, 2048, 16, 16, 128),
+                  flash_gqa=(2, 2048, 32, 8, 128)),
         train=dict(batch=4, seq=2048, steps=5),
         serve=dict(max_batch_size=32, page_size=128, num_pages=288,
                    max_prompt_len=1024, max_seq_len=2048,
@@ -246,16 +248,16 @@ def kernels_child(rehearsal: bool, chips: int) -> int:
                                              true_len),
                 lambda: ref_paged(qc, pt[:1], start[None], true_len[None]))
 
-    fb, ft, fh, fd = k["flash"]
-    qf, kf, vf = (jax.random.normal(keys[5 + i], (fb, ft, fh, fd), dt)
-                  for i in range(3))
-
     def ref_flash(q, k_, v):
         # float32 inputs AND float32 matmul passes (the TPU default for a
-        # float32 dot is bf16 passes); the kernels keep their own precision
+        # float32 dot is bf16 passes); the kernels keep their own precision.
+        # Every query head is given its KV head: the kernels do that inside.
+        n_rep = q.shape[2] // k_.shape[2]
         with jax.default_matmul_precision("highest"):
             return flash_ops.reference_attention(
-                *(x.astype(jnp.float32) for x in (q, k_, v)))
+                q.astype(jnp.float32),
+                *(jnp.repeat(x.astype(jnp.float32), n_rep, axis=2)
+                  for x in (k_, v)))
 
     def loss(attn):
         return lambda q, k_, v: jnp.sum(
@@ -265,10 +267,17 @@ def kernels_child(rehearsal: bool, chips: int) -> int:
     flash_grad = jax.jit(jax.grad(loss(flash_ops.flash_attention),
                                   argnums=(0, 1, 2)))
     ref_grad = jax.jit(jax.grad(loss(ref_flash), argnums=(0, 1, 2)))
-    ok &= timed("flash_fwd", lambda: flash(qf, kf, vf),
-                lambda: ref_flash(qf, kf, vf))
-    ok &= timed("flash_bwd", lambda: flash_grad(qf, kf, vf),
-                lambda: ref_grad(qf, kf, vf))
+    # (batch, length, query heads, KV heads, head size): equal heads, then
+    # the grouped-query layout of the train cell, K and V unexpanded
+    for name in ("flash", "flash_gqa"):
+        fb, ft, fh, fhkv, fd = k[name]
+        qf = jax.random.normal(keys[5], (fb, ft, fh, fd), dt)
+        kf, vf = (jax.random.normal(keys[6 + i], (fb, ft, fhkv, fd), dt)
+                  for i in range(2))
+        ok &= timed(f"{name}_fwd", lambda: flash(qf, kf, vf),
+                    lambda: ref_flash(qf, kf, vf))
+        ok &= timed(f"{name}_bwd", lambda: flash_grad(qf, kf, vf),
+                    lambda: ref_grad(qf, kf, vf))
     if chips > 1:
         out["tp_decode_collectives"] = tp_decode_collectives(rehearsal, chips)
         ok &= out["tp_decode_collectives"].get("all-reduce", 0) > 0

@@ -9,7 +9,8 @@ delegates to torch/vLLM; here the model is native JAX so the whole stack
   any mesh (DP/FSDP/TP/CP) is a rule-table swap (ray_tpu.parallel.sharding);
 - the layer loop is `lax.scan` → O(1) compile size at any depth;
 - attention routes to ring attention over the "context" axis for long
-  sequences (SURVEY.md §5.7) and to the Pallas flash kernel on TPU;
+  sequences (SURVEY.md §5.7) and to the Pallas flash kernels on TPU, which
+  take K and V at their n_kv_heads;
 - GQA + RoPE + RMSNorm + SwiGLU, bf16 activations, fp32 RMSNorm accumulation
   (MXU-friendly shapes: head_dim 128, ffn multiples of 1024).
 """
@@ -285,11 +286,6 @@ def _attention(q, k, v, cfg: LlamaConfig, mesh, *, positions_offset=0):
     """Causal self-attention dispatch: ring over the context axis, Pallas
     flash on TPU, einsum fallback."""
     n_rep = cfg.n_heads // cfg.n_kv_heads
-    k = _gqa_expand(k, n_rep)
-    v = _gqa_expand(v, n_rep)
-    if cfg.attn_impl == "ring" and mesh is not None:
-        from ray_tpu.parallel.ring_attention import ring_attention
-        return ring_attention(q, k, v, mesh, causal=True)
     if cfg.attn_impl == "flash":
         from ray_tpu.ops.attention import flash_attention
         attn = functools.partial(flash_attention, causal=True)
@@ -302,11 +298,23 @@ def _attention(q, k, v, cfg: LlamaConfig, mesh, *, positions_offset=0):
             from jax.sharding import PartitionSpec as P
 
             from ray_tpu.parallel.sharding import batch_sharding
-            heads = "tensor" if mesh.shape.get("tensor", 1) > 1 else None
+            tensor = mesh.shape.get("tensor", 1)
+            if cfg.n_kv_heads % tensor:
+                # a shard of query heads must find its KV heads on its
+                # own chip; where they do not split, every head gets one
+                k, v = _gqa_expand(k, n_rep), _gqa_expand(v, n_rep)
+            heads = "tensor" if tensor > 1 else None
             spec = P(batch_sharding(mesh).spec[0], None, heads, None)
             attn = jax.shard_map(attn, mesh=mesh, in_specs=(spec,) * 3,
                                  out_specs=spec, check_vma=False)
+        # the kernels serve grouped queries themselves: K and V go in at
+        # the heads they have
         return attn(q, k, v)
+    k = _gqa_expand(k, n_rep)
+    v = _gqa_expand(v, n_rep)
+    if cfg.attn_impl == "ring" and mesh is not None:
+        from ray_tpu.parallel.ring_attention import ring_attention
+        return ring_attention(q, k, v, mesh, causal=True)
     sm = cfg.head_dim ** -0.5
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) * sm
     t_q, t_k = q.shape[1], k.shape[1]

@@ -7,10 +7,10 @@ the mesh "context" axis; each device holds a Q/K/V shard and K/V blocks rotate
 around the ring with `ppermute` while a streaming-softmax accumulator builds
 exact attention (blockwise attention à la Ring Attention, Liu et al.).
 
-The per-block kernel is `ray_tpu.ops.attention.block_attention` — a Pallas
-flash kernel on TPU, einsum fallback elsewhere — so the MXU does the FLOPs and
-the ICI rotation overlaps with compute (XLA schedules the ppermute
-asynchronously against the next block's matmuls).
+The per-block step is `_block_attn` below: two einsums and a softmax that XLA
+compiles (not the Pallas flash kernels of `ray_tpu.ops.attention`, which this
+module does not call); XLA schedules the ppermute asynchronously against the
+next block's matmuls.
 """
 
 from __future__ import annotations

@@ -1,0 +1,260 @@
+"""The flash kernels of ray_tpu/ops/attention.py, held directly: forward and
+jax.grad against XLA's attention in float32 `highest`, in the Pallas
+interpreter on the CPU, over head layouts, lengths, dtypes, masks and block
+sizes; what the kernels feed the MXU; that the model hands them K and V
+unexpanded; and (the on-chip-measurement guide's third rehearsal) that they
+compile for a described v5e at the train cell's shapes, which the interpreter
+cannot tell: it knows neither tiling nor VMEM."""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from ray_tpu.models import llama
+from ray_tpu.ops import attention as fa
+
+HEADS = [(4, 4), (8, 2), (8, 1)]
+
+
+def _qkv(h, hkv, t, dtype, d=32, b=2, seed=0):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    return (jax.random.normal(ks[0], (b, t, h, d), dtype),
+            jax.random.normal(ks[1], (b, t, hkv, d), dtype),
+            jax.random.normal(ks[2], (b, t, hkv, d), dtype))
+
+
+def _reference(q, k, v, causal=True):
+    """XLA's attention on float32 copies, every head given its KV head."""
+    n_rep = q.shape[2] // k.shape[2]
+    q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
+    with jax.default_matmul_precision("highest"):
+        return fa.reference_attention(q, jnp.repeat(k, n_rep, axis=2),
+                                      jnp.repeat(v, n_rep, axis=2),
+                                      causal=causal)
+
+
+def _loss(attn):
+    return lambda q, k, v: jnp.sum(attn(q, k, v).astype(jnp.float32) ** 2)
+
+
+def _assert_close(got, want, dtype):
+    tol = 1e-4 if dtype == jnp.float32 else 4e-2
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert g.shape == w.shape and g.dtype == dtype
+        err = float(jnp.max(jnp.abs(g.astype(jnp.float32) - w)))
+        assert err <= tol * max(1.0, float(jnp.max(jnp.abs(w)))), err
+
+
+@pytest.mark.parametrize("blocks", [None, (64, 32)],
+                         ids=["default_blocks", "blocks_64x32"])
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("t", [64, 256, 320])
+@pytest.mark.parametrize("h,hkv", HEADS)
+def test_forward_and_grad_match_reference(h, hkv, t, dtype, causal, blocks):
+    q, k, v = _qkv(h, hkv, t, dtype)
+    kw = {} if blocks is None else {"block_q": blocks[0],
+                                    "block_k": blocks[1]}
+    attn = lambda q, k, v: fa.flash_attention(q, k, v, causal=causal, **kw)
+    ref = lambda q, k, v: _reference(q, k, v, causal)
+    _assert_close(attn(q, k, v), ref(q, k, v), dtype)
+    # dk and dv come back at the KV heads, summed over their query heads
+    _assert_close(jax.grad(_loss(attn), argnums=(0, 1, 2))(q, k, v),
+                  jax.grad(_loss(ref), argnums=(0, 1, 2))(q, k, v), dtype)
+
+
+@pytest.mark.parametrize("h,hkv", HEADS)
+def test_default_blocks_loop_over_several_blocks(h, hkv):
+    """T = 1024 is two default blocks of 512: the k loop of the forward,
+    the k and q loops of the backward and the masked / unmasked split at
+    the sizes the chip runs."""
+    assert fa.default_blocks(1024, 1024, 128) == ((512, 512),) * 2
+    q, k, v = _qkv(h, hkv, 1024, jnp.float32, d=8, b=1)
+    _assert_close(
+        jax.grad(_loss(fa.flash_attention), argnums=(0, 1, 2))(q, k, v),
+        jax.grad(_loss(_reference), argnums=(0, 1, 2))(q, k, v), jnp.float32)
+
+
+@pytest.mark.parametrize("h,hkv", HEADS)
+@pytest.mark.parametrize("wrap", ["jit", "shard_map"])
+def test_under_jit_and_shard_map(wrap, h, hkv, jax_cpu_mesh):
+    q, k, v = _qkv(h, hkv, 128, jnp.float32, b=4)
+    grad = jax.grad(_loss(fa.flash_attention), argnums=(0, 1, 2))
+    if wrap == "shard_map":
+        from jax.sharding import Mesh, PartitionSpec as P
+        tensor = 2 if hkv % 2 == 0 else 1
+        mesh = Mesh(np.array(jax_cpu_mesh[:2 * tensor]).reshape(2, tensor),
+                    ("data", "tensor"))
+        spec = P("data", None, "tensor", None)
+        attn = jax.shard_map(fa.flash_attention, mesh=mesh,
+                             in_specs=(spec,) * 3, out_specs=spec,
+                             check_vma=False)
+        grad = jax.grad(_loss(attn), argnums=(0, 1, 2))
+    _assert_close(jax.jit(grad)(q, k, v),
+                  jax.grad(_loss(_reference), argnums=(0, 1, 2))(q, k, v),
+                  jnp.float32)
+
+
+@pytest.mark.parametrize("kw,match", [
+    ({"block_q": 128}, "must divide"),
+    ({"block_k": 192}, "must divide"),
+])
+def test_block_that_does_not_divide_raises(kw, match):
+    q, k, v = _qkv(4, 4, 320, jnp.float32)
+    with pytest.raises(ValueError, match=match):
+        fa.flash_attention(q, k, v, **kw)
+
+
+def test_sequence_past_vmem_raises_and_names_the_way_out():
+    """The backward keeps a head's Q, dO, K, V, dq, dk and dv whole in
+    VMEM: 32k tokens at D = 128 do not fit, and the error says so before
+    any compile."""
+    args = [jax.ShapeDtypeStruct((1, 32768, h, 128), jnp.bfloat16)
+            for h in (8, 2, 2)]
+    jax.eval_shape(fa.flash_attention, *args)
+    with pytest.raises(ValueError, match="does not fit VMEM.*ring_attention"):
+        jax.eval_shape(jax.grad(_loss(fa.flash_attention)), *args)
+
+
+def test_query_heads_must_be_a_multiple_of_kv_heads():
+    q, k, v = _qkv(4, 3, 64, jnp.float32)
+    with pytest.raises(ValueError, match="multiple"):
+        fa.flash_attention(q, k, v)
+
+
+@pytest.mark.parametrize("t,d,want", [
+    (2048, 128, 512),      # the train cell
+    (64, 16, 64),          # shorter than a block: the sequence itself
+    (320, 128, 320),
+    (640, 128, 128),       # longer than a block: a multiple of 128 divides
+    (2048, 256, 512),
+])
+def test_default_block_q_from_the_shapes(t, d, want):
+    blocks = fa.default_blocks(t, t, d)
+    assert all(bq == want for bq, _ in blocks)
+    assert all(t % bq == 0 and t % bk == 0 for bq, bk in blocks)
+
+
+def test_default_blocks_refuse_a_long_ragged_sequence():
+    with pytest.raises(ValueError, match="multiple of 128"):
+        fa.default_blocks(2048 + 64, 2048 + 64, 128)
+
+
+# ---- what the kernels feed the MXU ----------------------------------------
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs inside its equations
+    (pjit, custom_vjp, shard_map, scan, pallas_call bodies ...)."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for p in eqn.params.values():
+            for sub in (p if isinstance(p, (list, tuple)) else [p]):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _eqns(sub)
+
+
+@pytest.mark.parametrize("which", ["forward", "backward"])
+def test_bf16_kernels_hold_no_f32_product(which):
+    q, k, v = _qkv(8, 2, 256, jnp.bfloat16)
+    fn = fa.flash_attention if which == "forward" else jax.grad(
+        _loss(fa.flash_attention), argnums=(0, 1, 2))
+    kernels = [e for e in _eqns(jax.make_jaxpr(fn)(q, k, v).jaxpr)
+               if e.primitive.name == "pallas_call"]
+    assert [e.params["name"] for e in kernels] == (
+        ["flash_fwd"] if which == "forward" else ["flash_fwd", "flash_bwd"])
+    dots = [e for kern in kernels for e in _eqns(kern.params["jaxpr"])
+            if e.primitive.name == "dot_general"]
+    # forward 2 products, backward 5, each once per loop (masked and
+    # unmasked) the kernel holds
+    assert len(dots) == (4 if which == "forward" else 14)
+    for e in dots:
+        assert [x.aval.dtype for x in e.invars] == [jnp.bfloat16] * 2, e
+        assert e.outvars[0].aval.dtype == jnp.float32
+
+
+@pytest.mark.parametrize("impl", ["flash", "dense"])
+def test_model_hands_the_kernels_k_and_v_unexpanded(impl):
+    """llama.loss_fn with fewer KV heads than query heads: on the flash
+    path no K / V of n_heads exists before the kernel and the kernels read
+    [B * n_kv_heads, T, D]; the dense path still expands (which also shows
+    that this test would see an expansion)."""
+    cfg = llama.llama_tiny(attn_impl=impl, max_seq_len=32)
+    assert cfg.n_kv_heads < cfg.n_heads
+    params = llama.init_params(jax.random.PRNGKey(0), cfg)
+    batch = {"tokens": jnp.zeros((2, 33), jnp.int32)}
+    eqns = list(_eqns(jax.make_jaxpr(
+        lambda p: llama.loss_fn(p, batch, cfg, None))(params).jaxpr))
+    n_rep = cfg.n_heads // cfg.n_kv_heads
+    expanded = (2, 32, cfg.n_kv_heads, n_rep, cfg.head_dim)
+    expansions = [e for e in eqns if e.primitive.name == "broadcast_in_dim"
+                  and e.outvars[0].aval.shape == expanded]
+    assert len(expansions) == (0 if impl == "flash" else 2)
+    kernels = [e for e in eqns if e.primitive.name == "pallas_call"]
+    assert len(kernels) == (1 if impl == "flash" else 0)
+    for kern in kernels:
+        assert [x.aval.shape[0] for x in kern.invars] == [
+            2 * cfg.n_heads, 2 * cfg.n_kv_heads, 2 * cfg.n_kv_heads]
+
+
+def test_model_expands_where_kv_heads_do_not_split_over_tensor(jax_cpu_mesh):
+    """Under a mesh whose tensor degree does not divide the KV heads a
+    shard of query heads would not find its KV heads on its chip: the
+    model then expands as before, and the loss is the dense one."""
+    from ray_tpu.parallel.mesh import MeshSpec, build_mesh
+
+    mesh = build_mesh(MeshSpec(fsdp=2, tensor=4), jax_cpu_mesh[:8])
+    tokens = jnp.asarray(np.random.default_rng(0).integers(0, 256, (4, 33)),
+                         jnp.int32)
+    losses = {}
+    for impl in ("dense", "flash"):
+        cfg = llama.llama_tiny(attn_impl=impl, max_seq_len=32)  # 4 / 2 heads
+        params = llama.init_params(jax.random.PRNGKey(0), cfg)
+        losses[impl] = float(jax.jit(
+            lambda p, cfg=cfg: llama.loss_fn(p, {"tokens": tokens}, cfg,
+                                             mesh))(params))
+    assert abs(losses["flash"] - losses["dense"]) < 1e-4
+
+
+# ---- compiled for the chip, without the chip ------------------------------
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """One device of a described v5e 2x2. Described here and never at
+    import: only one process may load the TPU's library, and every xdist
+    worker imports this file."""
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a program compiled for a described chip is written to the persistent
+    # cache and cannot be read back without one: keep it out
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.mark.parametrize("which", ["forward", "backward"])
+def test_kernels_compile_for_v5e_at_the_train_cells_shapes(which, one_chip):
+    """mistral7b-train-fsdp4, one chip's share: q [2, 2048, 32, 128], k and
+    v [2, 2048, 8, 128], bf16, causal, default blocks."""
+    q, kv = ((2, 2048, heads, 128) for heads in (32, 8))
+    args = [jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=one_chip)
+            for s in (q, kv, kv)]
+    attn = lambda q, k, v: fa.flash_attention(q, k, v, interpret=False)
+    fn = attn if which == "forward" else jax.grad(_loss(attn),
+                                                  argnums=(0, 1, 2))
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert text.count("tpu_custom_call") >= (1 if which == "forward" else 2)

@@ -489,7 +489,7 @@ def test_train_step_carries_scope_and_kernel_names():
         step.__wrapped__, state, batch)
     assert {"embed", "norm", "attn", "mlp", "lm_head", "loss",
             "optimizer"} <= scopes
-    assert kernels == {"flash_fwd", "flash_bwd_dkdv", "flash_bwd_dq"}
+    assert kernels == {"flash_fwd", "flash_bwd"}
 
 
 # ---- README drift guard -----------------------------------------------
