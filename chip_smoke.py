@@ -85,7 +85,8 @@ def sizes(rehearsal: bool, chips: int) -> dict:
         return dict(
             kern=dict(hkv=2, h=4, d=16, page=8, max_pages=4, pool=40, b=4,
                       chunk=16, flash=(2, 64, 4, 4, 16),
-                      flash_gqa=(2, 64, 4, 2, 16)),
+                      flash_gqa=(2, 64, 4, 2, 16), heads64=(2, 4),
+                      experts=(16, 4, 32, 16)),
             train=dict(batch=4, seq=64, steps=5),
             serve=dict(max_batch_size=8, page_size=8, num_pages=160,
                        max_prompt_len=96, max_seq_len=128, prefill_chunk=32,
@@ -96,7 +97,8 @@ def sizes(rehearsal: bool, chips: int) -> dict:
     return dict(
         kern=dict(hkv=8, h=16, d=128, page=128, max_pages=16, pool=288, b=32,
                   chunk=512, flash=(4, 2048, 16, 16, 128),
-                  flash_gqa=(2, 2048, 32, 8, 128)),
+                  flash_gqa=(2, 2048, 32, 8, 128), heads64=(8, 32),
+                  experts=(256, 32, 2048, 1792)),
         train=dict(batch=4, seq=2048, steps=5),
         serve=dict(max_batch_size=32, page_size=128, num_pages=288,
                    max_prompt_len=1024, max_seq_len=2048,
@@ -180,20 +182,21 @@ def kernels_child(rehearsal: bool, chips: int) -> int:
     kp = jax.random.normal(keys[0], (k["hkv"], k["pool"], k["page"], k["d"]),
                            dt)
     vp = jax.random.normal(keys[1], kp.shape, dt)
-    sm = k["d"] ** -0.5
     max_len = k["max_pages"] * k["page"]
     hi = jax.lax.Precision.HIGHEST
 
-    def ref_paged(q, page_tables, base, limit):
+    def ref_paged(q, page_tables, base, limit, kp=kp, vp=vp):
         """The gather path's formula (kv_cache._decode_attention) in
-        float32 throughout."""
+        float32 throughout; kp / vp [Hkv, P, page, D]."""
         q, kf, vf = (x.astype(jnp.float32) for x in (q, kp, vp))
         b, t, h, d = q.shape
-        n_rep = h // k["hkv"]
+        hkv = kf.shape[0]
+        n_rep = h // hkv
+        sm = d ** -0.5
         ks = jnp.moveaxis(jnp.take(kf, page_tables, axis=1), 0, 3).reshape(
-            b, max_len, k["hkv"], d)
+            b, max_len, hkv, d)
         vs = jnp.moveaxis(jnp.take(vf, page_tables, axis=1), 0, 3).reshape(
-            b, max_len, k["hkv"], d)
+            b, max_len, hkv, d)
         ks, vs = (jnp.repeat(x, n_rep, axis=2) for x in (ks, vs))
         col = jnp.arange(max_len)
         pos = base[:, None] + jnp.arange(t)[None, :]
@@ -247,6 +250,61 @@ def kernels_child(rehearsal: bool, chips: int) -> int:
     ok &= timed("paged_chunk", lambda: chunk(qc, kp, vp, pt[0], start,
                                              true_len),
                 lambda: ref_paged(qc, pt[:1], start[None], true_len[None]))
+
+    # heads of 64: the pool holds two KV heads side by side in a 128-lane
+    # row (kv_cache.pool_heads_lanes); the same three kernels, same names
+    hkv64, h64 = k["heads64"]
+    k64 = jax.random.normal(keys[5], (hkv64, k["pool"], k["page"], 64), dt)
+    v64 = jax.random.normal(keys[6], k64.shape, dt)
+
+    def packed(x):          # [Hkv, P, page, 64] -> [Hkv / 2, P, page, 128]
+        return jnp.moveaxis(x.reshape(hkv64 // 2, 2, *x.shape[1:]), 1,
+                            3).reshape(hkv64 // 2, k["pool"], k["page"], 128)
+
+    kp64, vp64 = packed(k64), packed(v64)
+    q1, q5, qc = (jax.random.normal(keys[7], x.shape[:-2] + (h64, 64), dt)
+                  for x in (q1, q5, qc))
+    ok &= timed("paged_decode_h64", lambda: decode(q1, kp64, vp64, pt, pos),
+                lambda: ref_paged(q1[:, None], pt, pos, lim, k64, v64)[:, 0])
+    ok &= timed("paged_verify_t5_h64",
+                lambda: verify(q5, kp64, vp64, pt, pos),
+                lambda: ref_paged(q5, pt, pos, lim, k64, v64))
+    ok &= timed("paged_chunk_h64",
+                lambda: chunk(qc, kp64, vp64, pt[0], start, true_len),
+                lambda: ref_paged(qc, pt[:1], start[None], true_len[None],
+                                  k64, v64))
+
+    # the grouped expert product (rows sorted by expert, one group a held
+    # expert) against its plain form: every row through its own expert
+    from ray_tpu.parallel import expert as expert_mod
+    rows, n_exp, dim, width = k["experts"]
+    wk = jax.random.split(keys[4], 5)
+    w_gate, w_up = (jax.random.normal(wk[i], (n_exp, dim, width), dt)
+                    * dim ** -0.5 for i in range(2))
+    w_down = jax.random.normal(wk[2], (n_exp, width, dim), dt) * width ** -0.5
+    xs = jax.random.normal(wk[3], (rows, dim), dt)
+    owner = jnp.sort(jax.random.randint(wk[4], (rows,), 0, n_exp))
+    group_sizes = jnp.sum(owner[:, None] == jnp.arange(n_exp)[None, :],
+                          axis=0, dtype=jnp.int32)
+
+    def plain_experts():
+        """One expert at a time over every row, its own rows kept."""
+        x32 = xs.astype(jnp.float32)
+
+        def one(acc, e):
+            with jax.default_matmul_precision("highest"):
+                gate = x32 @ w_gate[e].astype(jnp.float32)
+                up = x32 @ w_up[e].astype(jnp.float32)
+                y = (jax.nn.silu(gate) * up) @ w_down[e].astype(jnp.float32)
+            return acc + jnp.where((owner == e)[:, None], y, 0.0), None
+
+        return jax.lax.scan(one, jnp.zeros((rows, dim), jnp.float32),
+                            jnp.arange(n_exp))[0]
+
+    grouped = jax.jit(expert_mod.grouped_swiglu)
+    ok &= timed("grouped_experts",
+                lambda: grouped(xs, w_gate, w_up, w_down, group_sizes),
+                plain_experts)
 
     def ref_flash(q, k_, v):
         # float32 inputs AND float32 matmul passes (the TPU default for a

@@ -1,0 +1,82 @@
+"""The seam between a model architecture and the serving engine.
+
+The engine (serve/llm/engine.py) and the cache manager (serve/llm/
+kv_cache.py) never name an architecture: they reach it through the module
+that defines the model configuration's class (:func:`block_of`). A module
+that can be served provides, at module level:
+
+    init_params(key, cfg), load_params(path, cfg)
+    check_tp_divides(cfg, tp), serve_partition_rules()
+    cache_spec(cfg) -> CacheSpec     what the cache manager must hold
+    serve_layers(cfg) -> None | tuple[LayerDef, ...]
+        None: every layer is the same, ``params["layers"]`` is one stacked
+        pytree and the paged programs scan it. A tuple: layers differ,
+        ``params["layers"]`` is a list, and the programs walk it in order.
+    rope_freqs(cfg, positions)
+    serve_embed(params, tokens, cfg) -> x
+    serve_qkv(x, layer, cos, sin, cfg) -> q, k, v        (attention mixer)
+    serve_attn_out(attn, layer) -> the mixer's output, before the residual
+    serve_conv(x, layer, prev, cfg) -> (x + mixer, ext)  (conv mixer only:
+        ``prev`` [B, K-1, D] the state the sequence's earlier tokens left,
+        ``ext`` [B, K-1+T, D] that state followed by this call's columns,
+        of which the manager keeps the last K-1 real ones)
+    serve_ffn(x, layer, cfg, ld) -> (x + ffn, choice | None)
+        ``choice`` int32 [rows, top_k]: the experts a routed layer took
+    serve_final_norm(x, params, cfg), serve_lm_head(x, params, cfg)
+
+``cfg.head_dim``, ``cfg.dtype`` and ``cfg.max_seq_len`` are read off the
+configuration itself.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import sys
+
+
+@dataclasses.dataclass(frozen=True)
+class CacheSpec:
+    """What a block keeps between calls, a sequence.
+
+    ``paged_layers`` layers write K and V of ``n_kv_heads`` x ``head_dim``
+    a token into pages. ``state_layers`` layers keep one array of
+    ``state_shape`` a SEQUENCE (not a token): state that survives between
+    decode steps, is carried from one prefill chunk to the next and cannot
+    be rebuilt from the pages. ``routed_layers`` layers choose ``top_k`` of
+    ``n_experts`` experts a token, and the programs record the choice."""
+    paged_layers: int
+    n_kv_heads: int
+    head_dim: int
+    state_layers: int = 0
+    state_shape: tuple = ()
+    routed_layers: int = 0
+    top_k: int = 0
+    n_experts: int = 0
+
+
+@dataclasses.dataclass(frozen=True)
+class LayerDef:
+    """One layer of a block whose layers differ: its mixer ("attn" |
+    "conv"), its feed-forward kind ("dense" | "routed"), and which row of
+    the pool, of the slot state and of the routing record is its own."""
+    mixer: str
+    ffn: str
+    page_layer: int = -1
+    state_layer: int = -1
+    routed_layer: int = -1
+
+
+def gqa_expand(k, n_rep):
+    """k [B, T, Hkv, D] -> [B, T, Hkv * n_rep, D], kv-major: query head
+    h reads KV head h // n_rep."""
+    if n_rep == 1:
+        return k
+    import jax.numpy as jnp
+    b, t, h, d = k.shape
+    return jnp.broadcast_to(k[:, :, :, None, :], (b, t, h, n_rep, d)).reshape(
+        b, t, h * n_rep, d)
+
+
+def block_of(cfg):
+    """The module that defines ``cfg``'s class: the architecture's block."""
+    return sys.modules[type(cfg).__module__]

@@ -25,6 +25,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ray_tpu.models.block import CacheSpec, gqa_expand
+
 
 @dataclasses.dataclass(frozen=True)
 class LlamaConfig:
@@ -222,12 +224,63 @@ def apply_rope(x, cos, sin):
     return out.astype(x.dtype)
 
 
-def _gqa_expand(k, n_rep):
-    if n_rep == 1:
-        return k
-    b, t, h, d = k.shape
-    return jnp.broadcast_to(k[:, :, :, None, :], (b, t, h, n_rep, d)).reshape(
-        b, t, h * n_rep, d)
+# ---------------------------------------------------------------------------
+# the serving block (models/block.py has the contract): the pieces of the
+# transformer block the paged programs (serve/llm/kv_cache.py) put together.
+# Each sits under a jax.named_scope so that a profiler trace says which
+# layer an op belongs to (`norm`, `attn`, `mlp`, `embed`, `lm_head`); the
+# scopes are compile-time metadata and change no executable.
+# ---------------------------------------------------------------------------
+
+def cache_spec(cfg: LlamaConfig):
+    return CacheSpec(paged_layers=cfg.n_layers, n_kv_heads=cfg.n_kv_heads,
+                     head_dim=cfg.head_dim)
+
+
+def serve_layers(cfg: LlamaConfig):
+    """Every layer is the same: the programs scan the stacked layers."""
+    return None
+
+
+def serve_embed(params, tokens, cfg: LlamaConfig):
+    return params["embed"][tokens].astype(cfg.dtype)
+
+
+def serve_qkv(x, layer, cos, sin, cfg: LlamaConfig):
+    """Pre-attention norm, the q/k/v projections and RoPE. x: [B,T,D]."""
+    with jax.named_scope("norm"):
+        h = rms_norm(x, layer["attn_norm"], cfg.norm_eps)
+    with jax.named_scope("attn"):
+        q = jnp.einsum("btd,dhk->bthk", h, layer["attn"]["wq"])
+        k = jnp.einsum("btd,dhk->bthk", h, layer["attn"]["wk"])
+        v = jnp.einsum("btd,dhk->bthk", h, layer["attn"]["wv"])
+        return apply_rope(q, cos, sin), apply_rope(k, cos, sin), v
+
+
+def serve_attn_out(attn, layer):
+    """The attention output projection of attn [..., H, hd]."""
+    return jnp.einsum("...hk,hkd->...d", attn, layer["attn"]["wo"])
+
+
+def serve_ffn(x, layer, cfg: LlamaConfig, ld=None):
+    """x + SwiGLU(norm(x)); no expert choice to report."""
+    with jax.named_scope("norm"):
+        h2 = rms_norm(x, layer["mlp_norm"], cfg.norm_eps)
+    with jax.named_scope("mlp"):
+        gate = jax.nn.silu(h2 @ layer["mlp"]["w_gate"])
+        up = h2 @ layer["mlp"]["w_up"]
+        return x + (gate * up) @ layer["mlp"]["w_down"], None
+
+
+def serve_final_norm(x, params, cfg: LlamaConfig):
+    with jax.named_scope("norm"):
+        return rms_norm(x, params["final_norm"], cfg.norm_eps)
+
+
+def serve_lm_head(x, params, cfg: LlamaConfig):
+    """The output projection, float32 logits."""
+    with jax.named_scope("lm_head"):
+        return (x @ params["lm_head"]).astype(jnp.float32)
 
 
 def _quantize_int8(t):
@@ -302,7 +355,7 @@ def _attention(q, k, v, cfg: LlamaConfig, mesh, *, positions_offset=0):
             if cfg.n_kv_heads % tensor:
                 # a shard of query heads must find its KV heads on its
                 # own chip; where they do not split, every head gets one
-                k, v = _gqa_expand(k, n_rep), _gqa_expand(v, n_rep)
+                k, v = gqa_expand(k, n_rep), gqa_expand(v, n_rep)
             heads = "tensor" if tensor > 1 else None
             spec = P(batch_sharding(mesh).spec[0], None, heads, None)
             attn = jax.shard_map(attn, mesh=mesh, in_specs=(spec,) * 3,
@@ -310,8 +363,8 @@ def _attention(q, k, v, cfg: LlamaConfig, mesh, *, positions_offset=0):
         # the kernels serve grouped queries themselves: K and V go in at
         # the heads they have
         return attn(q, k, v)
-    k = _gqa_expand(k, n_rep)
-    v = _gqa_expand(v, n_rep)
+    k = gqa_expand(k, n_rep)
+    v = gqa_expand(v, n_rep)
     if cfg.attn_impl == "ring" and mesh is not None:
         from ray_tpu.parallel.ring_attention import ring_attention
         return ring_attention(q, k, v, mesh, causal=True)

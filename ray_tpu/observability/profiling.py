@@ -406,7 +406,17 @@ class CaptureController:
             # directory would overwrite each other's trace
             logdir = os.path.join(logdir, str(os.getpid()))
             os.makedirs(logdir, exist_ok=True)
-            jax.profiler.start_trace(logdir, create_perfetto_link=False)
+            # no Python frames: the default capture hooks every Python
+            # call of every thread of the process (the engine loop's and
+            # each request handler's) and so slows the host it measures:
+            # a replica streaming 2,700 tokens/s left its chip idle 12.7 %
+            # of a traced span for it, 0.03 % without (PERF.md, PR 35).
+            # What reads a capture (benchmark/span_reduce.py) reads the
+            # rt/ spans and the device planes
+            options = jax.profiler.ProfileOptions()
+            options.python_tracer_level = 0
+            jax.profiler.start_trace(logdir, create_perfetto_link=False,
+                                     profiler_options=options)
             self._logdir = logdir
             self._started_at = time.time()
             self.active = True

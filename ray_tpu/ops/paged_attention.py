@@ -72,11 +72,19 @@ def sublane_tile(dtype) -> int:
     return 32 // jnp.dtype(dtype).itemsize
 
 
-def can_tile(head_dim: int, page_size: int, dtype) -> bool:
+def can_tile(head_dim: int, page_size: int, dtype,
+             n_kv_heads: int = 2) -> bool:
     """Shapes Mosaic compiles these kernels for: head_dim fills whole
-    128-lane vectors and a page is whole sublane tiles, so the page copy
-    into scratch and both matmuls stay tile-aligned."""
-    return head_dim % 128 == 0 and page_size % sublane_tile(dtype) == 0
+    128-lane vectors, or is the half vector of 64 lanes with an even
+    number of KV heads (``n_kv_heads``: a chip's share of them), which the
+    pool then stores two to a 128-lane row, so that a page block's last
+    dimension is whole vectors and HBM holds no padding (an odd count
+    cannot be packed, and a 64-lane pool is re-laid out by the compiler
+    around every token write); and a page is whole sublane tiles, so the
+    page copy into scratch and both matmuls stay tile-aligned."""
+    return (head_dim % 128 == 0
+            or (head_dim == 64 and n_kv_heads % 2 == 0)) \
+        and page_size % sublane_tile(dtype) == 0
 
 
 def tp_shard_specs(q_rank: int, n_replicated: int, axis: str = "tensor"):
@@ -123,7 +131,7 @@ def _paged_attn_kernel(pt_ref, base_ref, limit_ref, layer_ref,  # prefetch
 
     q_ref: [1, 1, R, D] where R >= n_rep * t_span, row r = rep * t_span + t
     (GQA heads grouped per kv head, query positions innermost — matches
-    ``_gqa_expand``'s kv-major head order); rows past n_rep * t_span are
+    ``gqa_expand``'s kv-major head order); rows past n_rep * t_span are
     zero padding the wrapper slices off. k_ref/v_ref: this grid step's
     pool page [page, D] (layer, kv-head and page block dimensions squeezed),
     selected by the block index map through the scalar-prefetched layer
@@ -191,6 +199,11 @@ def paged_attention(q, k_pages, v_pages, page_tables, base, limit=None,
     b, t, h, d = q.shape
     if k_pages.ndim == 4:
         k_pages, v_pages, layer = k_pages[None], v_pages[None], 0
+    if k_pages.shape[4] != d:
+        return _packed_heads(
+            q, k_pages, v_pages, page_tables, base, limit, layer,
+            sm_scale=d ** -0.5 if sm_scale is None else sm_scale,
+            interpret=interpret, name=name)
     hkv = k_pages.shape[1]
     n_rep = h // hkv
     page_size = k_pages.shape[3]
@@ -205,7 +218,7 @@ def paged_attention(q, k_pages, v_pages, page_tables, base, limit=None,
     r = n_rep * t
     r_pad, row_tile = _row_tiling(r, max_len, q.dtype)
     # [B, T, H, D] -> [B, Hkv, n_rep*T, D]: kv-major head split (matches
-    # _gqa_expand), query positions innermost so the kernel recovers t as
+    # gqa_expand), query positions innermost so the kernel recovers t as
     # row % t_span
     qg = q.reshape(b, t, hkv, n_rep, d).transpose(0, 2, 3, 1, 4).reshape(
         b, hkv, r, d)
@@ -259,6 +272,34 @@ def paged_attention(q, k_pages, v_pages, page_tables, base, limit=None,
       qg, k_pages, v_pages)
     return out[:, :, :r].reshape(b, hkv, n_rep, t, d).transpose(
         0, 3, 1, 2, 4).reshape(b, t, h, d)
+
+
+def _packed_heads(q, k_pages, v_pages, page_tables, base, limit, layer, *,
+                  sm_scale, interpret, name):
+    """Heads narrower than a pool row: the pool holds ``pack`` KV heads
+    side by side in one row of lanes ([L, Hkv / pack, P, page, pack * D]:
+    heads of 64 two to a 128-lane row, so HBM holds no padding and a page
+    block is whole vectors). The kernel runs as it is, on rows of
+    ``pack * D`` lanes: a query row of KV head j is laid into lanes
+    j * D .. (j + 1) * D of a zero row, so its scores are its own head's
+    (the other head's lanes meet zeros, which add nothing: the same
+    float32 sums), and of the output row, which is over both heads'
+    values, the same lanes are kept."""
+    b, t, h, d = q.shape
+    rows = k_pages.shape[1]
+    pack = k_pages.shape[4] // d
+    n_rep = h // (rows * pack)
+    # kv-major heads: h = ((row * pack) + j) * n_rep + rep
+    q6 = q.reshape(b, t, rows, pack, n_rep, d)
+    lane = jnp.eye(pack, dtype=q.dtype)                     # [j, j']
+    spread = (q6[..., None, :] * lane[:, None, :, None]).reshape(
+        b, t, h, pack * d)
+    out = paged_attention(spread, k_pages, v_pages, page_tables, base, limit,
+                          layer, sm_scale=sm_scale, interpret=interpret,
+                          name=name)                        # [B, T, H, pack*D]
+    out = out.reshape(b, t, rows, pack, n_rep, pack, d)
+    return jnp.stack([out[:, :, :, j, :, j] for j in range(pack)],
+                     axis=3).reshape(b, t, h, d)
 
 
 def paged_decode_attention(q, k_pages, v_pages, page_tables, pos,

@@ -136,3 +136,96 @@ def moe_layer_tokens_sharded(x, gate_w, expert_fn: Callable, expert_params,
         sharded, mesh=mesh,
         in_specs=(P(axis_name), P(), param_specs), out_specs=P(axis_name),
         check_vma=False)(x, gate_w, expert_params)
+
+
+# ---------------------------------------------------------------------------
+# serving: dropless routing, experts grouped and multiplied group by group
+# ---------------------------------------------------------------------------
+
+def route_sigmoid_top_k(g, router, bias, top_k: int, *,
+                        norm_topk_prob: bool = True, scaling: float = 1.0):
+    """Sigmoid scores over ALL experts, the ``top_k`` largest of score +
+    bias chosen, combine weights from the scores alone (the bias selects
+    and never weighs), normalised over the chosen. g: [N, D]; router:
+    [D, E]; bias: [E] float32 or None. Returns (idx [N, k] int32,
+    w [N, k] float32)."""
+    logits = jnp.dot(g, router, preferred_element_type=jnp.float32)
+    s = jax.nn.sigmoid(logits)
+    sel = s if bias is None else s + bias
+    _, idx = jax.lax.top_k(sel, top_k)
+    w = jnp.take_along_axis(s, idx, axis=-1)
+    if norm_topk_prob:
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-6)
+    return idx.astype(jnp.int32), w * scaling
+
+
+def _tile(n: int, cap: int) -> int:
+    """The whole dimension, or the largest ``n / 2**j`` under ``cap``."""
+    while n > cap and n % 2 == 0:
+        n //= 2
+    return n
+
+
+def grouped_swiglu(xs, w_gate, w_up, w_down, sizes):
+    """SwiGLU of rows sorted by expert: rows ``sum(sizes[:e])`` ..
+    ``sum(sizes[:e + 1])`` of xs [M, D] go through expert e's weights
+    (w_gate / w_up [E, D, F], w_down [E, F, D]).
+
+    Three calls of the Pallas grouped matmul ``gmm`` (jax.experimental.
+    pallas.ops.tpu.megablox), under the scope ``grouped_ffn``: it walks
+    (row tile, group) pairs, so an expert's weights are read once a row
+    tile that holds rows of it (once a call at decode widths) and an
+    expert with no row is never read. Chosen over ``jax.lax.ragged_dot``
+    by measurement on a v5e at the published widths (PERF.md section 6,
+    PR 35: 1.01 ms against 1.99 at 256 rows, 1.30 against 3.08 at 2,048).
+    Rows are padded to whole row tiles; rows past ``sum(sizes)`` (the
+    padding, picks of experts held elsewhere) are never visited and come
+    out as whatever memory held: the caller leaves them out. Off the TPU
+    the kernel runs interpreted. Returns [M, D] float32."""
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+    from ray_tpu.ops import paged_attention as paged_ops
+
+    m = xs.shape[0]
+    tm = min(128, -(-m // 16) * 16)
+    xs = jnp.pad(xs, ((0, -m % tm), (0, 0)))
+    interpret = paged_ops.interpret_default()   # one rule for every kernel
+
+    def product(a, w, dtype):
+        return gmm(a, w, sizes, preferred_element_type=dtype,
+                   tiling=(tm, _tile(w.shape[1], 2048),
+                           _tile(w.shape[2], 1024)), interpret=interpret)
+
+    with jax.named_scope("grouped_ffn"):
+        gate = product(xs, w_gate, xs.dtype)
+        up = product(xs, w_up, xs.dtype)
+        return product(jax.nn.silu(gate) * up, w_down, jnp.float32)[:m]
+
+
+def expert_share(g, idx, w, experts: dict, held: range):
+    """The part of a routed layer's output that the experts ``held`` give.
+
+    g [N, D] the normed tokens; idx / w [N, k] the routing over ALL
+    experts (:func:`route_sigmoid_top_k`: the router is counted once,
+    outside); experts: w_gate / w_up [len(held), D, F], w_down
+    [len(held), F, D]: only the held experts' weights. Dropless: every
+    (token, expert) pick with the expert in ``held`` is computed, however
+    many land on one expert. Picks are sorted by expert, multiplied group
+    by group, put back in token order and summed with their weights; picks
+    of experts held elsewhere sort last and are left out of the sum.
+    The shares of a partition of the experts add up to the whole layer.
+    Returns [N, D] float32."""
+    n, k = idx.shape
+    n_held = len(held)
+    local = idx - held.start
+    mine = (local >= 0) & (local < n_held)
+    key = jnp.where(mine, local, n_held).reshape(-1)              # [N*k]
+    order = jnp.argsort(key, stable=True)
+    sizes = jnp.sum(key[:, None] == jnp.arange(n_held)[None, :], axis=0,
+                    dtype=jnp.int32)
+    ys = grouped_swiglu(g[order // k], experts["w_gate"], experts["w_up"],
+                        experts["w_down"], sizes)                 # [N*k, D]
+    back = jnp.argsort(order)
+    picks = ys[back].reshape(n, k, -1)
+    return jnp.sum(jnp.where(mine[..., None], picks * w[..., None], 0.0),
+                   axis=1)

@@ -20,7 +20,7 @@ class LLMConfig:
 
     # model
     model_id: str = "llama-tiny"
-    model_config: Any = None          # ray_tpu.models.llama.LlamaConfig
+    model_config: Any = None          # LlamaConfig | Lfm2MoeConfig | ...
     checkpoint_path: Optional[str] = None  # llama.save_params npz; None = random init
     tokenizer: str = "byte"           # "byte" | HF tokenizer local path
 
@@ -231,8 +231,10 @@ class LLMConfig:
     slo_e2e_p99_ms: Optional[float] = None
     slo_sample_rate: float = 0.01
 
-    def llama(self):
-        from ray_tpu.models import llama
+    def model(self):
+        """The model configuration: any architecture whose module is a
+        serving block (models/block.py); a tiny Llama where none is set."""
         if self.model_config is not None:
             return self.model_config
+        from ray_tpu.models import llama
         return llama.llama_tiny()

@@ -164,6 +164,7 @@ def prefill_only(eng: LLMEngine, prompt, *, temperature: float | None = None,
        temperature, prefill_ttft_s}
     """
     t0 = time.monotonic()
+    eng.refuse_stateful("disaggregated prefill")
     if isinstance(prompt, str):
         toks = eng.tokenizer.encode(prompt)
     else:
@@ -232,6 +233,7 @@ class DecodeEngine(LLMEngine):
     def submit_prefilled(self, state: dict, *,
                          max_tokens: Optional[int] = None,
                          request_id: Optional[str] = None) -> str:
+        self.refuse_stateful("disaggregated adoption")
         state = _decode_state(state)  # wire-encoded blobs decode HERE
         toks = list(state["prompt_tokens"])
         req = _Request(
@@ -456,7 +458,7 @@ def _handoff_channel_capacity(cfg: LLMConfig,
     headroom only costs shm."""
     from ray_tpu.serve.llm import kv_cache
     pages = -(-cfg.max_prompt_len // cfg.page_size)
-    kv_bytes = pages * kv_cache.page_raw_nbytes(cfg.llama(), cfg.page_size)
+    kv_bytes = pages * kv_cache.page_raw_nbytes(cfg.model(), cfg.page_size)
     if cfg.disagg_wire_codec != "none":
         ratio = max(1.0, 0.5 * float(measured_ratio or 0.0))
         kv_bytes = int(kv_bytes / ratio)

@@ -177,11 +177,21 @@ class LLMEngine:
         import jax
         import jax.numpy as jnp
 
-        from ray_tpu.models import llama
+        from ray_tpu.models.block import block_of
         from ray_tpu.serve.llm import kv_cache as kvc
 
         self.cfg = cfg
-        self.model_cfg = cfg.llama()
+        self.model_cfg = cfg.model()
+        # the architecture, through the one seam (models/block.py): its
+        # initialiser, its partition rules, and the cache spec that says
+        # what the manager holds for it beside pages
+        self._block = block_of(self.model_cfg)
+        self._cache_spec = self._block.cache_spec(self.model_cfg)
+        # a block with slot state: prefix reuse, the kv tier, speculative
+        # rollback and disaggregated handoff all restore seq_len and pages
+        # only, so none of them happens for it; each is counted where it
+        # would have (<x>_bypassed_stateful)
+        self._stateful = kvc.has_slot_state(self.model_cfg)
         self.tokenizer = get_tokenizer(cfg.tokenizer)
         self._jax = jax
         self._jnp = jnp
@@ -190,22 +200,29 @@ class LLMEngine:
         # fused kernels vs the materialized-gather path). Static for the
         # engine's lifetime: it's baked into every compiled program, and
         # resolving here keeps the jitted impls free of backend probing.
+        self._tp = max(1, int(getattr(cfg, "tp_degree", 1)))
         self._attn_backend = kvc.resolve_attention_backend(
-            cfg.attention_kernel, self.model_cfg, cfg.page_size)
+            cfg.attention_kernel, self.model_cfg, cfg.page_size, self._tp)
 
         if params is None:
             if cfg.checkpoint_path:
-                params = llama.load_params(cfg.checkpoint_path,
-                                           self.model_cfg)
+                params = self._block.load_params(cfg.checkpoint_path,
+                                                 self.model_cfg)
             else:
-                params = llama.init_params(
+                params = self._block.init_params(
                     jax.random.PRNGKey(rng_seed), self.model_cfg)
         self.params = params
 
         b = cfg.max_batch_size
         self.max_pages_per_seq = -(-cfg.max_seq_len // cfg.page_size)
+        if self._cache_spec.routed_layers and b > self.model_cfg.max_seq_len:
+            # the routing record holds a call's rows, max_seq_len of them
+            raise ValueError(
+                f"max_batch_size={b} exceeds the model's max_seq_len="
+                f"{self.model_cfg.max_seq_len}: a routed block records "
+                f"its choice of experts for at most max_seq_len rows a call")
         self.kv = kvc.init_paged_cache(
-            self.model_cfg, cfg.num_pages, cfg.page_size)
+            self.model_cfg, cfg.num_pages, cfg.page_size, self._tp)
         # Tensor parallelism (ISSUE 20): one engine process drives a
         # tp_degree-chip "tensor" mesh. Weights get Megatron-style
         # partition-rule shardings (parallel/sharding.py — the SAME
@@ -215,7 +232,6 @@ class LLMEngine:
         # whole-replica logical state. tp_degree=1 builds no mesh and
         # compiles the exact single-chip programs (bit-identical to a
         # pre-TP engine).
-        self._tp = max(1, int(getattr(cfg, "tp_degree", 1)))
         self._mesh = None
         if self._tp > 1:
             self._mesh = self._setup_tp_mesh()
@@ -242,7 +258,8 @@ class LLMEngine:
         # host-side between steps — the page table indirection means shared
         # pages change WHICH pool pages a slot reads, never the compiled
         # programs or their shapes.
-        self._prefix_cache_on = bool(cfg.prefix_cache_enabled)
+        self._prefix_cache_on = bool(cfg.prefix_cache_enabled) \
+            and not self._stateful
         # one-shot log guard: ingress digests disagreeing with the local
         # recompute (tokenizer skew) warns once, not once per request
         self._ingress_skew_warned = False
@@ -294,7 +311,21 @@ class LLMEngine:
                       # visible per replica
                       "attn_decode_dispatches": 0,
                       "attn_verify_dispatches": 0,
-                      "attn_chunk_dispatches": 0}
+                      "attn_chunk_dispatches": 0,
+                      # routed experts (0 for a block without them): over
+                      # the decode blocks harvested, distinct experts
+                      # touched and (token, expert) rows multiplied, summed
+                      # over routed layers and steps, and those layer-steps
+                      "experts_touched_total": 0, "expert_rows_total": 0,
+                      "routed_layer_steps_total": 0,
+                      # what a block with slot state does not take part
+                      # in: admissions that skipped the prefix index / the
+                      # tier, requests speculation would have served,
+                      # disaggregated calls refused
+                      "prefix_bypassed_stateful": 0,
+                      "kv_tier_bypassed_stateful": 0,
+                      "spec_bypassed_stateful": 0,
+                      "disagg_refused_stateful": 0}
         # Tiered KV cache (kv_tier.py): evicted cached page chains spill
         # host-side into a shm/disk tier + cluster index instead of dying,
         # and _admit extends its longest-match search past the local index
@@ -333,7 +364,7 @@ class LLMEngine:
         # below): host-side n-gram drafts verified k-at-a-time in one
         # fused dispatch. Greedy-only guarantee — non-greedy slots are
         # never drafted and ride the normal decode path.
-        self._spec_on = bool(cfg.spec_decode_enabled)
+        self._spec_on = bool(cfg.spec_decode_enabled) and not self._stateful
         # last decode-block k actually dispatched + live pipeline depth
         # (engine_stats gauges: the k=1/pressure/full tier transitions are
         # observable instead of inferred from throughput wiggles)
@@ -345,9 +376,11 @@ class LLMEngine:
         # the bound). Token latency then tracks step execution time
         # instead of the host<->device round trip.
         self.PIPELINE_DEPTH = cfg.pipeline_depth
-        # [(dev_tokens, [(col, slot, req)], k, seq)]; seq numbers decode and
-        # verify blocks (the same in a block's dispatch and harvest spans),
-        # -1 for a prefill's first token
+        # [(dev_tokens, [(col, slot, req)], k, seq, dev_touched)]; seq
+        # numbers decode and verify blocks (the same in a block's dispatch
+        # and harvest spans), -1 for a prefill's first token; dev_touched:
+        # the device count of experts a decode block's steps touched (a
+        # block with routed experts), None otherwise
         self._pending: list = []
         self._block_seq = 0
         # the token every slot's next step consumes: [B+1] on device (row b
@@ -450,12 +483,11 @@ class LLMEngine:
         jax = self._jax
         from jax.sharding import NamedSharding
 
-        from ray_tpu.models import llama
         from ray_tpu.parallel import sharding as shd
         from ray_tpu.parallel.mesh import MeshSpec, build_mesh
 
         tp = self._tp
-        llama.check_tp_divides(self.model_cfg, tp)
+        self._block.check_tp_divides(self.model_cfg, tp)
         devices = jax.devices()
         if len(devices) < tp:
             raise ValueError(
@@ -463,8 +495,8 @@ class LLMEngine:
         mesh = build_mesh(MeshSpec(tensor=tp), devices[:tp])
         self.params = jax.device_put(
             self.params,
-            shd.rule_shardings(llama.serve_partition_rules(), self.params,
-                               mesh))
+            shd.rule_shardings(self._block.serve_partition_rules(),
+                               self.params, mesh))
         self.kv = jax.device_put(
             self.kv, NamedSharding(mesh, self._kvc.pool_spec()))
         logger.info("TP mesh up: %s over %d devices", dict(mesh.shape), tp)
@@ -503,9 +535,10 @@ class LLMEngine:
                     self.cfg.page_size, self._attn_backend, mesh=self._mesh)
                 toks = self._kvc.sample_tokens(
                     logits, sub, temps, self.cfg.top_k)
-                return (kv_c, lens, toks, key), toks
+                return (kv_c, lens, toks, key), (
+                    toks, self._experts_touched(kv_c, idx))
 
-            (kv, new_lens, last, rng), all_toks = jax.lax.scan(
+            (kv, new_lens, last, rng), (all_toks, touched) = jax.lax.scan(
                 one, (kv, lens0, toks0, rng), None, length=num_steps)
             # padding lanes must not accumulate garbage into the trash row
             # (its seq_len would creep toward int32 overflow on a
@@ -515,7 +548,26 @@ class LLMEngine:
                 sl_full = sl_full.at[idx].set(
                     jnp.where(idx == trash, 0, new_lens))
                 toks_full = toks_full.at[idx].set(last)
+        if touched is not None:
+            # a routed block: one more output, harvested with the tokens
+            return all_toks, toks_full, kv, sl_full, rng, jnp.sum(touched)
         return all_toks, toks_full, kv, sl_full, rng
+
+    def _experts_touched(self, kv, idx):
+        """Distinct experts the live rows of the last decode step chose,
+        summed over the routed layers (int32 scalar); None for a block
+        without routed experts. ``idx``: the packed slot index, whose
+        padding lanes (the trash row) do not count."""
+        spec = self._cache_spec
+        if not spec.routed_layers:
+            return None
+        jnp = self._jnp
+        with self._jax.named_scope("experts_touched"):
+            live = idx != self.cfg.max_batch_size                    # [W]
+            chosen = kv["routing"][:, :idx.shape[0]]            # [L_r, W, k]
+            hot = chosen[..., None] == jnp.arange(spec.n_experts)
+            hit = jnp.any(hot & live[None, :, None, None], axis=(1, 2))
+            return jnp.sum(hit, dtype=jnp.int32)
 
     def _verify_impl(self, params, kv, pt_full, sl_full, toks_full, rng,
                      temps_full, idx, drafts):
@@ -664,7 +716,7 @@ class LLMEngine:
                 # warmup compile is by definition not mid-traffic
                 with self._prof.compile_scope("decode", ("decode", w, k)):
                     _all, self._dev_tokens, self.kv, self._sl_dev, \
-                        self._rng = self._decode(
+                        self._rng, *_touched = self._decode(
                             self.params, self.kv, self._pt_dev,
                             self._sl_dev, self._dev_tokens, self._rng,
                             self._temps_dev, idx, k)
@@ -1033,6 +1085,10 @@ class LLMEngine:
                # (1 / pressure_decode_block / decode_block — admission
                # pressure made visible) and the live dispatched-but-
                # unharvested block count (vs cfg.pipeline_depth)
+               # sequences that hold a state row beside their pages (0
+               # for a block without slot state)
+               "state_slots_in_use": (active + prefilling + restoring
+                                      if self._stateful else 0),
                "decode_block_effective": self._last_block,
                "pending_pipeline_depth": len(self._pending)}
         # introspection (observability/profiling.py): per-phase p50/p95 +
@@ -1324,6 +1380,8 @@ class LLMEngine:
                     key = "prefix_hits" if matched else "prefix_misses"
                     self.stats[key] += 1
                     self.stats["prefix_hit_tokens"] += req.cached_tokens
+                if self._stateful:
+                    self._count_stateful_bypass(req)
             # queue-wait phase sample (submit→admit), recorded OUTSIDE the
             # lock: the profiler observes a metrics histogram, which must
             # never run under the engine lock (graftlint lock-discipline)
@@ -1351,6 +1409,28 @@ class LLMEngine:
                 self.stats["failover_restored_tokens"] += req.cached_tokens
             self._route_admitted(req)
             admitted += 1
+
+    def _count_stateful_bypass(self, req: _Request) -> None:
+        """What this admission would have taken part in had the block no
+        slot state (lock held): the configuration asks for it, the engine
+        does not do it, and the count says so."""
+        if self.cfg.prefix_cache_enabled \
+                and len(req.prompt_tokens) > self.cfg.page_size:
+            self.stats["prefix_bypassed_stateful"] += 1
+            if self.cfg.kv_tier_enabled:
+                self.stats["kv_tier_bypassed_stateful"] += 1
+        if self.cfg.spec_decode_enabled:
+            self.stats["spec_bypassed_stateful"] += 1
+
+    def refuse_stateful(self, what: str) -> None:
+        """Disaggregated handoff moves pages and a first token; a block
+        with slot state needs the state too, which no handoff carries."""
+        if self._stateful:
+            with self._lock:
+                self.stats["disagg_refused_stateful"] += 1
+            raise NotImplementedError(
+                f"{what}: the block keeps per-sequence state beside its "
+                f"pages, which a handoff does not carry")
 
     def _route_admitted(self, req: _Request) -> None:
         """Send an admitted request (prefix matched, tier restore — if
@@ -1821,7 +1901,8 @@ class LLMEngine:
             self.seq_lens[req.slot] = plen
             self.slot_req[req.slot] = req
             self._dirty_slots[req.slot] = (plen, req.temperature)
-            self._pending.append((tok_dev, [(0, req.slot, req)], 1, -1))
+            self._pending.append(
+                (tok_dev, [(0, req.slot, req)], 1, -1, None))
         if self._prefix_cache_on:
             # Index the prompt's FULL pages now (not at completion): the
             # writes are merely dispatched, but any matcher's reads are
@@ -2106,11 +2187,14 @@ class LLMEngine:
                     "decode", ("decode", w, k),
                     mid_traffic=self.stats["requests"] > 0):
                 all_toks, self._dev_tokens, self.kv, self._sl_dev, \
-                    self._rng = self._decode(
+                    self._rng, *touched = self._decode(
                         self.params, self.kv, self._pt_dev, self._sl_dev,
                         toks, self._rng, self._temps_dev, idx, k)
             self._start_fetch(all_toks)
-            self._pending.append((all_toks, snapshot, k, seq))
+            dev_touched = touched[0] if touched else None
+            if dev_touched is not None:  # a routed block's count of experts
+                self._start_fetch(dev_touched)
+            self._pending.append((all_toks, snapshot, k, seq, dev_touched))
             self.stats["steps"] += k
             self.stats["attn_decode_dispatches"] += 1
         return True
@@ -2163,7 +2247,7 @@ class LLMEngine:
                         self.params, self.kv, self._pt_dev, self._sl_dev,
                         toks, self._rng, self._temps_dev, idx, draft_mat)
             self._start_fetch(all_toks)
-            self._pending.append((all_toks, entry, ("spec", k), seq))
+            self._pending.append((all_toks, entry, ("spec", k), seq, None))
             self.stats["steps"] += k + 1
             self.stats["attn_verify_dispatches"] += 1
 
@@ -2302,15 +2386,25 @@ class LLMEngine:
         with self._lock:
             if not self._pending:
                 return
-            dev_toks, snapshot, k, seq = self._pending.pop(0)
+            dev_toks, snapshot, k, seq, dev_touched = self._pending.pop(0)
         if isinstance(k, tuple):  # ("spec", draft_len) verify round
             self._apply_verify(dev_toks, snapshot, k[1], seq)
             return
         # THE device sync: all device slowness (or a fetch that wasn't
         # prefetched) surfaces here, attributed as "harvest" instead of
         # smeared across the loop
-        with self._prof.span("harvest", seq=seq, k=k):
+        with self._prof.span("harvest", seq=seq, k=k) as sp:
             host_toks = np.asarray(dev_toks)  # sync point: oldest block only
+            # a routed block: the experts its steps touched, an output of
+            # the same program as the tokens (no sync of its own)
+            if dev_touched is not None:
+                touched = int(np.asarray(dev_touched))
+                layer_steps = k * self._cache_spec.routed_layers
+                sp.set(experts_touched=touched)
+                self.stats["experts_touched_total"] += touched
+                self.stats["routed_layer_steps_total"] += layer_steps
+                self.stats["expert_rows_total"] += (
+                    layer_steps * len(snapshot) * self._cache_spec.top_k)
         host_toks = host_toks.reshape(k, -1)
         # emit: what follows the sync on the host — up to k x w
         # _record_token calls under the lock, then the completion tail

@@ -48,8 +48,11 @@ Design choices:
 Page 0 is RESERVED as the trash page; the allocator never hands it out.
 
 This module alone knows the DEVICE pool's format (two arrays, pages on axis
-2, KV heads on axis 1): the engine, disaggregation and the tier path move
-pages through the page operations below ``init_paged_cache``. The HOST blobs
+2, KV heads on axis 1; beside them whatever else the block's cache spec
+asks for: ``init_paged_cache``): the engine, disaggregation and the tier
+path move pages through the page operations below ``init_paged_cache``.
+It names no architecture: the block comes with the model configuration
+(models/block.py), a layer definition that the programs below walk. The HOST blobs
 that kv_tier.py, kv_codec.py and disagg's wire codec carry (pairs of arrays
 with pages on axis 2) keep their own format, which those modules own.
 """
@@ -67,36 +70,86 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models.llama import (
-    LlamaConfig,
-    _gqa_expand,
-    apply_rope,
-    rms_norm,
-    rope_freqs,
-)
+from ray_tpu.models.block import block_of, gqa_expand
 
 logger = logging.getLogger(__name__)
 
 
-def init_paged_cache(cfg: LlamaConfig, num_pages: int, page_size: int):
-    """KV pool: [n_layers, n_kv_heads, num_pages, page_size, head_dim].
+def init_paged_cache(cfg, num_pages: int, page_size: int, tp: int = 1):
+    """What the block's cache spec (models/block.py) asks for, a pytree
+    (``tp``: the chips the pool's heads will be split over).
 
-    The head-major page layout is what the Pallas paged-attention kernels
-    (ops/paged_attention.py) consume directly — the whole pool plus a layer
+    ``k`` / ``v``: the KV pool [paged_layers, n_kv_heads, num_pages,
+    page_size, head_dim], one row a layer that attends. Heads of 64 lie
+    two to a 128-lane row, [paged_layers, n_kv_heads / 2, num_pages,
+    page_size, 128] (:func:`pool_heads_lanes`): a 64-lane minor dimension
+    would be padded to 128 in HBM, or re-laid out by the compiler around
+    every token write. The head-major page
+    layout is what the Pallas paged-attention kernels (ops/
+    paged_attention.py) consume directly — the whole pool plus a layer
     index, per layer [Hkv, P, page, D] — so the paged programs run them with
     no relayout and no per-layer slice; the gather backend indexes the same
     pool. The paged programs carry both arrays through their loops and only
-    ever update them in place (see the module docstring)."""
-    shape = (cfg.n_layers, cfg.n_kv_heads, num_pages, page_size, cfg.head_dim)
-    return {"k": jnp.zeros(shape, cfg.dtype), "v": jnp.zeros(shape, cfg.dtype)}
+    ever update them in place (see the module docstring).
+
+    ``state`` (a block with slot state only): one array [num_pages,
+    prod(state_shape)] a layer that keeps state (flat: a [2, D] row would
+    be padded to whole sublane tiles). A sequence's row is its FIRST
+    PAGE, so the state rides the page table: the allocator that hands a
+    sequence its pages has handed it its state row, the programs need no
+    slot argument, and inactive lanes (a page table of zeros) meet in the
+    trash page's row. Nothing zeroes a row: a program that starts a
+    sequence (a whole prefill, a chunk with ``start`` 0) reads zeros
+    instead of the row. Two sequences must never share a first page, so a
+    block with slot state takes no prefix reuse (:func:`has_slot_state`).
+
+    ``routing`` (a block with routed experts only): int32 [routed_layers,
+    max_seq_len, top_k], the experts the LAST program call chose for each
+    of its token rows."""
+    spec = block_of(cfg).cache_spec(cfg)
+    heads, lanes = pool_heads_lanes(spec.n_kv_heads, spec.head_dim, tp)
+    shape = (spec.paged_layers, heads, num_pages, page_size, lanes)
+    kv = {"k": jnp.zeros(shape, cfg.dtype), "v": jnp.zeros(shape, cfg.dtype)}
+    if spec.state_layers:
+        kv["state"] = tuple(
+            jnp.zeros((num_pages, int(np.prod(spec.state_shape))), cfg.dtype)
+            for _ in range(spec.state_layers))
+    if spec.routed_layers:
+        kv["routing"] = jnp.zeros(
+            (spec.routed_layers, cfg.max_seq_len, spec.top_k), jnp.int32)
+    return kv
 
 
-def page_raw_nbytes(cfg: LlamaConfig, page_size: int) -> int:
+def pool_heads_lanes(n_kv_heads: int, head_dim: int,
+                     tp: int = 1) -> tuple[int, int]:
+    """(rows of heads, lanes a row) of the pool's two minor-most head
+    dimensions: a head a row, except that heads of 64 lie two to a
+    128-lane row (KV head 2i in lanes 0-63, 2i + 1 in lanes 64-127)
+    wherever each of the ``tp`` chips the rows are split over then holds
+    whole rows: an odd share of heads stays a head a row (and off the
+    Pallas kernels: ``paged_attention.can_tile``). The token write and the
+    gather read reshape between the two views; the Pallas wrapper
+    (ops/paged_attention.py) reads the packed rows."""
+    if head_dim == 64 and n_kv_heads % (2 * tp) == 0:
+        return n_kv_heads // 2, 128
+    return n_kv_heads, head_dim
+
+
+def has_slot_state(cfg) -> bool:
+    """Whether the block keeps per-sequence state beside its pages: state
+    that pages, ``seq_len`` and a page table do not restore. Prefix reuse,
+    the kv tier, speculative rollback and disaggregated handoff move pages
+    only, so the engine does none of them for such a block (and counts)."""
+    return block_of(cfg).cache_spec(cfg).state_layers > 0
+
+
+def page_raw_nbytes(cfg, page_size: int) -> int:
     """Pre-codec bytes ONE pool page holds across all layers, k + v —
     the unit the tier spills and the restore stream lands. Derived from
     the pool spec (not a live array) so byte-budget callers (stream
     prefetch window, chunk sizing) can size before any page exists."""
-    per = cfg.n_layers * cfg.n_kv_heads * page_size * cfg.head_dim
+    spec = block_of(cfg).cache_spec(cfg)
+    per = spec.paged_layers * spec.n_kv_heads * page_size * spec.head_dim
     return 2 * per * np.dtype(cfg.dtype).itemsize
 
 
@@ -128,7 +181,7 @@ def scatter_pages(kv, bk, bv, pages):
     """Write blob page i of (bk, bv) into pool page ``pages[i]``. The body of
     the engine's donated inject program: the pool is rewritten in place. A
     blob padded with zero pages targets the trash page with them."""
-    return {"k": kv["k"].at[:, :, pages].set(bk),
+    return {**kv, "k": kv["k"].at[:, :, pages].set(bk),
             "v": kv["v"].at[:, :, pages].set(bv)}
 
 
@@ -495,43 +548,13 @@ class PageAllocator:
 # ---------------------------------------------------------------------------
 
 
-# The paged steps below share these pieces of the transformer block. Each
-# sits under a jax.named_scope so that a profiler trace says which layer
-# an op belongs to (`norm`, `attn`, `kv_write` = the page-pool update
-# only, `mlp`, `embed`, `lm_head`, `sample`); the scopes are compile-time
+# The paged steps below put a block's pieces together (models/block.py:
+# ``serve_qkv``, ``serve_attn_out``, ``serve_conv``, ``serve_ffn``, ...).
+# Each piece sits under a jax.named_scope so that a profiler trace says
+# which layer an op belongs to (`norm`, `attn`, `conv`, `mlp`, `router`,
+# `experts`, `embed`, `lm_head`, `sample`; `kv_write` / `state_write` = the
+# page-pool and slot-state updates only); the scopes are compile-time
 # metadata and change no executable.
-
-def _qkv(x, layer, cos, sin, cfg: LlamaConfig):
-    """Pre-attention norm, the q/k/v projections and RoPE. x: [B,T,D]."""
-    with jax.named_scope("norm"):
-        h = rms_norm(x, layer["attn_norm"], cfg.norm_eps)
-    with jax.named_scope("attn"):
-        q = jnp.einsum("btd,dhk->bthk", h, layer["attn"]["wq"])
-        k = jnp.einsum("btd,dhk->bthk", h, layer["attn"]["wk"])
-        v = jnp.einsum("btd,dhk->bthk", h, layer["attn"]["wv"])
-        return apply_rope(q, cos, sin), apply_rope(k, cos, sin), v
-
-
-def _mlp(x, layer, cfg: LlamaConfig):
-    """x + SwiGLU(norm(x))."""
-    with jax.named_scope("norm"):
-        h2 = rms_norm(x, layer["mlp_norm"], cfg.norm_eps)
-    with jax.named_scope("mlp"):
-        gate = jax.nn.silu(h2 @ layer["mlp"]["w_gate"])
-        up = h2 @ layer["mlp"]["w_up"]
-        return x + (gate * up) @ layer["mlp"]["w_down"]
-
-
-def _final_norm(x, params, cfg: LlamaConfig):
-    with jax.named_scope("norm"):
-        return rms_norm(x, params["final_norm"], cfg.norm_eps)
-
-
-def _lm_head(x, params):
-    """The output projection, float32 logits."""
-    with jax.named_scope("lm_head"):
-        return (x @ params["lm_head"]).astype(jnp.float32)
-
 
 def _write_token_kv(k_pool, v_pool, layer, k_new, v_new, page_idx, offset):
     """Scatter new tokens' k/v into layer ``layer`` of the page pool.
@@ -553,19 +576,24 @@ def _write_token_kv(k_pool, v_pool, layer, k_new, v_new, page_idx, offset):
     """
     heads = jnp.arange(k_pool.shape[1])
     idx = (layer, heads, page_idx[..., None], offset[..., None])
-    return (k_pool.at[idx].set(k_new.astype(k_pool.dtype)),
-            v_pool.at[idx].set(v_new.astype(v_pool.dtype)))
+    # the pool's own rows (heads of 64: two heads a row, same bytes)
+    rows = k_new.shape[:-2] + (k_pool.shape[1], k_pool.shape[4])
+    return (k_pool.at[idx].set(k_new.reshape(rows).astype(k_pool.dtype)),
+            v_pool.at[idx].set(v_new.reshape(rows).astype(v_pool.dtype)))
 
 
-def _gather_seq(pool, layer, page_tables):
+def _gather_seq(pool, layer, page_tables, head_dim: int | None = None):
     """The gather backend's read: layer ``layer`` of the slots' pages as
     one contiguous sequence. pool: [L, Hkv, P, page, D]; page_tables:
     [..., MP]. ONE gather on the 5-D pool (no ``pool[layer]`` first).
-    Returns [..., MP * page, Hkv, D]."""
+    Returns [..., MP * page, Hkv, D] (``head_dim``: the model's, where the
+    pool's rows hold two heads of 64)."""
     pages = pool[layer, :, page_tables]          # [..., MP, Hkv, page, D]
     hkv, page_size, d = pages.shape[-3:]
+    head_dim = head_dim or d
     return jnp.swapaxes(pages, -3, -2).reshape(
-        page_tables.shape[:-1] + (page_tables.shape[-1] * page_size, hkv, d))
+        page_tables.shape[:-1] + (page_tables.shape[-1] * page_size,
+                                  hkv * d // head_dim, head_dim))
 
 
 def _scan_layers(body, x, kv, params):
@@ -584,10 +612,67 @@ def _scan_layers(body, x, kv, params):
     return x, {"k": k_pool, "v": v_pool}
 
 
-def _use_pallas_decode(cfg=None, page_size: int = 0) -> bool:
+def _over_layers(step, x, kv, params, cfg):
+    """Run ``step(x, kv, layer_params, ld, l)`` -> (x, kv) over the
+    block's layers, the cache a carry that is only ever updated in place.
+    A block whose layers are all alike (``serve_layers`` None: stacked
+    parameters, ``ld`` None, ``l`` traced) is scanned; one whose layers
+    differ in kind is walked in order (``ld`` its :class:`LayerDef`, ``l``
+    its row of the pool), each layer's weights read where they lie."""
+    layers = block_of(cfg).serve_layers(cfg)
+    if layers is None:
+        def body(x, k_pool, v_pool, layer, l):
+            x, out = step(x, {"k": k_pool, "v": v_pool}, layer, None, l)
+            return x, out["k"], out["v"]
+        return _scan_layers(body, x, kv, params)
+    for ld, layer in zip(layers, params["layers"]):
+        x, kv = step(x, kv, layer, ld, ld.page_layer)
+    return x, kv
+
+
+def _attends(ld) -> bool:
+    return ld is None or ld.mixer == "attn"
+
+
+def _ffn(x, kv, layer, cfg, ld):
+    """The layer's feed-forward; a routed one leaves its rows' choice of
+    experts in the cache's ``routing`` record."""
+    x, choice = block_of(cfg).serve_ffn(x, layer, cfg, ld)
+    if choice is not None:
+        kv = {**kv, "routing": kv["routing"].at[
+            ld.routed_layer, :choice.shape[0]].set(choice)}
+    return x, kv
+
+
+def _conv_mixer(x, kv, layer, cfg, ld, rows, fresh=None, n_real=None):
+    """A mixer with slot state over x [B, T, D]. ``rows`` [B]: each
+    sequence's state row (its first page). ``fresh``: true where the call
+    starts its sequence, which then reads zeros and not the row. ``n_real``
+    [B]: how many of the T columns are the sequence's own (None = all):
+    the row keeps the state as of the last real one."""
+    i = ld.state_layer
+    state = kv["state"][i]                                 # [P, (K-1)*D]
+    prev = state[rows].reshape(x.shape[0], -1, x.shape[-1])
+    if fresh is not None:
+        prev = jnp.where(fresh, jnp.zeros_like(prev), prev)
+    x, ext = block_of(cfg).serve_conv(x, layer, prev, cfg)
+    with jax.named_scope("state_write"):
+        keep = prev.shape[1]
+        if n_real is None:
+            new = ext[:, ext.shape[1] - keep:]
+        else:
+            new = jax.vmap(lambda e, n: jax.lax.dynamic_slice_in_dim(
+                e, n, keep, axis=0))(ext, n_real)
+        state = state.at[rows].set(
+            new.reshape(new.shape[0], -1).astype(state.dtype))
+    return x, {**kv, "state": kv["state"][:i] + (state,)
+               + kv["state"][i + 1:]}
+
+
+def _use_pallas_decode(cfg=None, page_size: int = 0, tp: int = 1) -> bool:
     """Kernel path gate: TPU backend + shapes the Pallas paged-attention
     kernels tile (``paged_attention.can_tile``). Tiny test models
-    (head_dim 16-64) take the gather path on real TPUs; in interpreter
+    (head_dim 16-32) take the gather path on real TPUs; in interpreter
     mode (CPU) every shape runs."""
     if jax.default_backend() != "tpu":
         return False
@@ -595,11 +680,14 @@ def _use_pallas_decode(cfg=None, page_size: int = 0) -> bool:
         return True
     from ray_tpu.ops.paged_attention import can_tile
     return can_tile(cfg.head_dim, page_size,
-                    getattr(cfg, "dtype", jnp.bfloat16))
+                    getattr(cfg, "dtype", jnp.bfloat16),
+                    max(1, getattr(cfg, "n_kv_heads", 2) // tp))
 
 
-def resolve_attention_backend(choice, cfg=None, page_size: int = 0) -> str:
-    """Resolve ``LLMConfig.attention_kernel`` to a concrete backend.
+def resolve_attention_backend(choice, cfg=None, page_size: int = 0,
+                              tp: int = 1) -> str:
+    """Resolve ``LLMConfig.attention_kernel`` to a concrete backend
+    (``tp``: the chips the KV heads are split over).
 
     ``"auto"`` (default) picks ``"pallas"`` on TPU when the kernel tiling
     accepts the model's shapes and ``"gather"`` everywhere else (the
@@ -609,17 +697,19 @@ def resolve_attention_backend(choice, cfg=None, page_size: int = 0) -> str:
     kernel can't tile: whoever named the kernel must not be served by
     another path under its name."""
     if choice in (None, "", "auto"):
-        return "pallas" if _use_pallas_decode(cfg, page_size) else "gather"
+        return "pallas" if _use_pallas_decode(cfg, page_size, tp) \
+            else "gather"
     if choice not in ("gather", "pallas"):
         raise ValueError(
             f"attention_kernel must be 'auto', 'gather' or 'pallas', "
             f"got {choice!r}")
     if choice == "pallas" and jax.default_backend() == "tpu" \
-            and not _use_pallas_decode(cfg, page_size):
+            and not _use_pallas_decode(cfg, page_size, tp):
         raise ValueError(
             f"attention_kernel='pallas' cannot tile head_dim="
             f"{getattr(cfg, 'head_dim', '?')} / page_size={page_size} on "
-            f"TPU (needs head_dim % 128 == 0 and whole sublane tiles per "
+            f"TPU (needs head_dim a multiple of 128, or 64 with an even "
+            f"number of KV heads a chip, and whole sublane tiles per "
             f"page); use 'auto' or 'gather'")
     return choice
 
@@ -639,8 +729,8 @@ def _dense_attention(q, k, v, mask, sm):
     softmax, probabilities cast back to q.dtype. q: [B, T, H, D]; k/v:
     [B, L, Hkv, D]; mask: broadcastable to [B, H, T, L]."""
     n_rep = q.shape[2] // k.shape[2]
-    k_full = _gqa_expand(k, n_rep)
-    v_full = _gqa_expand(v, n_rep)
+    k_full = gqa_expand(k, n_rep)
+    v_full = gqa_expand(v, n_rep)
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k_full).astype(
         jnp.float32) * sm
     logits = jnp.where(mask, logits, -1e30)
@@ -688,9 +778,12 @@ def _decode_attention(q, k_pool, v_pool, layer, page_tables, pos, cfg,
         return _paged_kernel(
             paged_ops.paged_decode_attention, q, k_pool, v_pool,
             page_tables, pos, layer, sm_scale=sm, mesh=mesh)
-    n_rep = q.shape[1] // k_pool.shape[1]
-    k_full = _gqa_expand(_gather_seq(k_pool, layer, page_tables), n_rep)
-    v_full = _gqa_expand(_gather_seq(v_pool, layer, page_tables), n_rep)
+    # query heads a KV head (the pool's rows may hold two heads of 64)
+    n_rep = q.shape[1] * cfg.head_dim // (k_pool.shape[1] * k_pool.shape[4])
+    k_full = gqa_expand(
+        _gather_seq(k_pool, layer, page_tables, cfg.head_dim), n_rep)
+    v_full = gqa_expand(
+        _gather_seq(v_pool, layer, page_tables, cfg.head_dim), n_rep)
     valid = jnp.arange(max_len)[None, :] <= pos[:, None]          # [B, L]
     logits = jnp.einsum("bhd,bkhd->bhk", q, k_full).astype(
         jnp.float32) * sm
@@ -700,7 +793,7 @@ def _decode_attention(q, k_pool, v_pool, layer, page_tables, pos, cfg,
 
 
 def paged_decode_step(params, kv, page_tables, seq_lens, tokens,
-                      cfg: LlamaConfig, page_size: int,
+                      cfg, page_size: int,
                       attn_backend: str = "gather", mesh=None):
     """One fused decode step for all slots.
 
@@ -711,35 +804,38 @@ def paged_decode_step(params, kv, page_tables, seq_lens, tokens,
     carry seq_lens pointing at trash-page positions; their logits are junk
     and the engine ignores them.
     """
+    blk = block_of(cfg)
     with jax.named_scope("embed"):
-        x = params["embed"][tokens[:, None]].astype(cfg.dtype)   # [B,1,D]
-        cos, sin = rope_freqs(cfg, seq_lens[:, None])           # position = len
+        x = blk.serve_embed(params, tokens[:, None], cfg)        # [B,1,D]
+        cos, sin = blk.rope_freqs(cfg, seq_lens[:, None])      # position = len
     pos = seq_lens
     page_idx = jnp.take_along_axis(
         page_tables, (pos // page_size)[:, None], axis=1)[:, 0]  # [B]
     offset = pos % page_size
 
-    def body(x, k_pool, v_pool, layer, l):
-        q, k, v = _qkv(x, layer, cos, sin, cfg)
+    def step(x, kv, layer, ld, l):
+        if not _attends(ld):
+            x, kv = _conv_mixer(x, kv, layer, cfg, ld, page_tables[:, 0])
+            return _ffn(x, kv, layer, cfg, ld)
+        q, k, v = blk.serve_qkv(x, layer, cos, sin, cfg)
         with jax.named_scope("kv_write"):
             k_pool, v_pool = _write_token_kv(
-                k_pool, v_pool, l, k[:, 0], v[:, 0], page_idx, offset)
+                kv["k"], kv["v"], l, k[:, 0], v[:, 0], page_idx, offset)
         with jax.named_scope("attn"):
             attn = _decode_attention(
                 q[:, 0], k_pool, v_pool, l, page_tables, pos, cfg,
                 page_size, attn_backend, mesh)                    # [B,H,D]
-            x = x + jnp.einsum(
-                "bhk,hkd->bd", attn, layer["attn"]["wo"])[:, None]
-        return _mlp(x, layer, cfg), k_pool, v_pool
+            x = x + blk.serve_attn_out(attn, layer)[:, None]
+        return _ffn(x, {**kv, "k": k_pool, "v": v_pool}, layer, cfg, ld)
 
-    x, kv = _scan_layers(body, x, kv, params)
-    x = _final_norm(x, params, cfg)
-    return _lm_head(x[:, 0], params), kv, seq_lens + 1
+    x, kv = _over_layers(step, x, kv, params, cfg)
+    x = blk.serve_final_norm(x, params, cfg)
+    return blk.serve_lm_head(x[:, 0], params, cfg), kv, seq_lens + 1
 
 
 @jax.named_scope("verify")
 def paged_verify_step(params, kv, page_tables, seq_lens, tokens,
-                      cfg: LlamaConfig, page_size: int,
+                      cfg, page_size: int,
                       attn_backend: str = "gather", mesh=None):
     """Speculative verify: T tokens per slot in ONE fused pass.
 
@@ -760,14 +856,23 @@ def paged_verify_step(params, kv, page_tables, seq_lens, tokens,
     materializes the [B, T, L] view — T times the decode fallback's
     traffic, bounded by small T (draft_len+1).
     Returns (logits [B, T, vocab], new_kv, seq_lens + T).
+
+    A block with slot state has no verify program: a rejected draft's
+    columns would have to be taken out of the state again, which pages and
+    ``seq_len`` do not record (the engine turns speculation off for it).
     """
+    if has_slot_state(cfg):
+        raise NotImplementedError(
+            "speculative verify for a block with slot state: the state "
+            "after a rejected draft cannot be rolled back")
+    blk = block_of(cfg)
     t = tokens.shape[1]
     max_len = page_tables.shape[1] * page_size
 
     pos = seq_lens[:, None] + jnp.arange(t)[None, :]              # [B,T]
     with jax.named_scope("embed"):
-        x = params["embed"][tokens].astype(cfg.dtype)             # [B,T,D]
-        cos, sin = rope_freqs(cfg, pos)
+        x = blk.serve_embed(params, tokens, cfg)                  # [B,T,D]
+        cos, sin = blk.rope_freqs(cfg, pos)
     page_idx = jnp.take_along_axis(page_tables, pos // page_size,
                                    axis=1)                        # [B,T]
     offset = pos % page_size
@@ -776,13 +881,13 @@ def paged_verify_step(params, kv, page_tables, seq_lens, tokens,
     valid = kpos[None, None, :] <= pos[:, :, None]                # [B,T,L]
     sm = cfg.head_dim ** -0.5
 
-    def body(x, k_pool, v_pool, layer, l):
-        q, k, v = _qkv(x, layer, cos, sin, cfg)
+    def step(x, kv, layer, ld, l):
+        q, k, v = blk.serve_qkv(x, layer, cos, sin, cfg)
         with jax.named_scope("kv_write"):
             # write all T tokens' k/v, then attend through the paged view —
             # same write-then-gather shape as paged_prefill_chunk, batched.
             k_pool, v_pool = _write_token_kv(
-                k_pool, v_pool, l, k, v, page_idx, offset)
+                kv["k"], kv["v"], l, k, v, page_idx, offset)
         with jax.named_scope("attn"):
             if attn_backend == "pallas":
                 from ray_tpu.ops import paged_attention as paged_ops
@@ -791,30 +896,35 @@ def paged_verify_step(params, kv, page_tables, seq_lens, tokens,
                     page_tables, seq_lens, l, sm_scale=sm, mesh=mesh)
             else:
                 attn = _dense_attention(
-                    q, _gather_seq(k_pool, l, page_tables),
-                    _gather_seq(v_pool, l, page_tables), valid[:, None], sm)
-            x = x + jnp.einsum("bthk,hkd->btd", attn, layer["attn"]["wo"])
-        return _mlp(x, layer, cfg), k_pool, v_pool
+                    q, _gather_seq(k_pool, l, page_tables, cfg.head_dim),
+                    _gather_seq(v_pool, l, page_tables, cfg.head_dim),
+                    valid[:, None], sm)
+            x = x + blk.serve_attn_out(attn, layer)
+        return _ffn(x, {**kv, "k": k_pool, "v": v_pool}, layer, cfg, ld)
 
-    x, kv = _scan_layers(body, x, kv, params)
-    logits = _lm_head(_final_norm(x, params, cfg), params)        # [B,T,V]
+    x, kv = _over_layers(step, x, kv, params, cfg)
+    logits = blk.serve_lm_head(blk.serve_final_norm(x, params, cfg), params,
+                               cfg)                               # [B,T,V]
     return logits, kv, seq_lens + t
 
 
 @jax.named_scope("prefill")
 def paged_prefill(params, kv, page_table, tokens, true_len,
-                  cfg: LlamaConfig, page_size: int):
+                  cfg, page_size: int):
     """Prefill ONE slot's prompt into its pages.
 
     tokens: [1, T] (bucket-padded); page_table: [max_pages] for this slot;
     true_len: scalar actual prompt length. Returns (last-token logits
     [vocab], new_kv). Padding positions (>= true_len) write to the trash
-    page via index clamping, so junk never lands in real pages.
+    page via index clamping, so junk never lands in real pages. A layer
+    with slot state starts from zeros and leaves the state as of position
+    ``true_len - 1`` in the sequence's row.
     """
+    blk = block_of(cfg)
     t = tokens.shape[1]
     with jax.named_scope("embed"):
-        x = params["embed"][tokens].astype(cfg.dtype)             # [1,T,D]
-        cos, sin = rope_freqs(cfg, jnp.arange(t)[None, :])
+        x = blk.serve_embed(params, tokens, cfg)                  # [1,T,D]
+        cos, sin = blk.rope_freqs(cfg, jnp.arange(t)[None, :])
     pos = jnp.arange(t)
     in_range = pos < true_len
     page_idx = jnp.where(in_range, jnp.take(page_table, pos // page_size), 0)
@@ -823,31 +933,36 @@ def paged_prefill(params, kv, page_table, tokens, true_len,
     causal = pos[:, None] >= pos[None, :]
     sm = cfg.head_dim ** -0.5
 
-    def body(x, k_pool, v_pool, layer, l):
-        q, k, v = _qkv(x, layer, cos, sin, cfg)
+    def step(x, kv, layer, ld, l):
+        if not _attends(ld):
+            x, kv = _conv_mixer(
+                x, kv, layer, cfg, ld, page_table[:1], fresh=True,
+                n_real=jnp.reshape(true_len, (1,)).astype(jnp.int32))
+            return _ffn(x, kv, layer, cfg, ld)
+        q, k, v = blk.serve_qkv(x, layer, cos, sin, cfg)
         with jax.named_scope("attn"):
             # dense causal attention within the prompt (prefill is
             # compute-bound and contiguous — no need to read back through
             # pages)
             attn = _dense_attention(q, k, v, causal[None, None], sm)
-            x = x + jnp.einsum("bthk,hkd->btd", attn, layer["attn"]["wo"])
-        x = _mlp(x, layer, cfg)
+            x = x + blk.serve_attn_out(attn, layer)
+        x, kv = _ffn(x, kv, layer, cfg, ld)
         with jax.named_scope("kv_write"):
             # scatter the prompt's k/v into this slot's pages
             k_pool, v_pool = _write_token_kv(
-                k_pool, v_pool, l, k[0], v[0], page_idx, offset)
-        return x, k_pool, v_pool
+                kv["k"], kv["v"], l, k[0], v[0], page_idx, offset)
+        return x, {**kv, "k": k_pool, "v": v_pool}
 
-    x, kv = _scan_layers(body, x, kv, params)
-    x = _final_norm(x, params, cfg)
+    x, kv = _over_layers(step, x, kv, params, cfg)
+    x = blk.serve_final_norm(x, params, cfg)
     last = jnp.take_along_axis(
         x, jnp.maximum(true_len - 1, 0)[None, None, None], axis=1)[:, 0]
-    return _lm_head(last, params)[0], kv
+    return blk.serve_lm_head(last, params, cfg)[0], kv
 
 
 @jax.named_scope("prefill_chunk")
 def paged_prefill_chunk(params, kv, page_table, tokens, start, true_len,
-                        cfg: LlamaConfig, page_size: int,
+                        cfg, page_size: int,
                         attn_backend: str = "gather", mesh=None):
     """One CHUNK of a long prompt's prefill (chunked prefill: the engine
     interleaves prompt chunks with decode blocks so a long admission never
@@ -861,16 +976,19 @@ def paged_prefill_chunk(params, kv, page_table, tokens, start, true_len,
     back through the page pool) plus causally within the chunk. Under the
     pallas backend the cached prefix is read page-by-page inside the fused
     chunk kernel instead of gathering the full paged view every chunk —
-    the long-prompt suffix-prefill-after-tier-restore hot path. Returns
+    the long-prompt suffix-prefill-after-tier-restore hot path. A layer
+    with slot state reads the state its predecessor chunk left (zeros
+    where ``start`` is 0) and leaves its own. Returns
     (last-token logits [vocab] — meaningful only on the final chunk, new_kv).
     """
+    blk = block_of(cfg)
     c = tokens.shape[1]
     max_len = page_table.shape[0] * page_size
 
     pos = start + jnp.arange(c)                                   # [C]
     with jax.named_scope("embed"):
-        x = params["embed"][tokens].astype(cfg.dtype)             # [1,C,D]
-        cos, sin = rope_freqs(cfg, pos[None, :])
+        x = blk.serve_embed(params, tokens, cfg)                  # [1,C,D]
+        cos, sin = blk.rope_freqs(cfg, pos[None, :])
     in_range = pos < true_len
     page_idx = jnp.where(in_range, jnp.take(page_table, pos // page_size), 0)
     offset = pos % page_size
@@ -879,8 +997,14 @@ def paged_prefill_chunk(params, kv, page_table, tokens, start, true_len,
     valid = (kpos[None, :] <= pos[:, None]) & (kpos[None, :] < true_len)
     sm = cfg.head_dim ** -0.5
 
-    def body(x, k_pool, v_pool, layer, l):
-        q, k, v = _qkv(x, layer, cos, sin, cfg)
+    def step(x, kv, layer, ld, l):
+        if not _attends(ld):
+            x, kv = _conv_mixer(
+                x, kv, layer, cfg, ld, page_table[:1], fresh=start == 0,
+                n_real=jnp.reshape(jnp.clip(true_len - start, 0, c),
+                                   (1,)).astype(jnp.int32))
+            return _ffn(x, kv, layer, cfg, ld)
+        q, k, v = blk.serve_qkv(x, layer, cos, sin, cfg)
         with jax.named_scope("kv_write"):
             # write the chunk's k/v first, then attend through the paged view —
             # the same write-then-gather shape as the decode fallback, so the
@@ -888,7 +1012,7 @@ def paged_prefill_chunk(params, kv, page_table, tokens, start, true_len,
             # gathered view is small (unlike batched decode, where the
             # materialized gather is why the Pallas kernel exists).
             k_pool, v_pool = _write_token_kv(
-                k_pool, v_pool, l, k[0], v[0], page_idx, offset)
+                kv["k"], kv["v"], l, k[0], v[0], page_idx, offset)
         with jax.named_scope("attn"):
             if attn_backend == "pallas":
                 from ray_tpu.ops import paged_attention as paged_ops
@@ -897,18 +1021,18 @@ def paged_prefill_chunk(params, kv, page_table, tokens, start, true_len,
                     page_table, start, true_len, l, sm_scale=sm, mesh=mesh)
             else:
                 attn = _dense_attention(
-                    q, _gather_seq(k_pool, l, page_table)[None],
-                    _gather_seq(v_pool, l, page_table)[None],
+                    q, _gather_seq(k_pool, l, page_table, cfg.head_dim)[None],
+                    _gather_seq(v_pool, l, page_table, cfg.head_dim)[None],
                     valid[None, None], sm)
-            x = x + jnp.einsum("bthk,hkd->btd", attn, layer["attn"]["wo"])
-        return _mlp(x, layer, cfg), k_pool, v_pool
+            x = x + blk.serve_attn_out(attn, layer)
+        return _ffn(x, {**kv, "k": k_pool, "v": v_pool}, layer, cfg, ld)
 
-    x, kv = _scan_layers(body, x, kv, params)
-    x = _final_norm(x, params, cfg)
+    x, kv = _over_layers(step, x, kv, params, cfg)
+    x = blk.serve_final_norm(x, params, cfg)
     # last REAL token's position relative to this chunk's start
     rel = jnp.clip(true_len - 1 - start, 0, c - 1)
     last = jnp.take_along_axis(x, rel[None, None, None], axis=1)[:, 0]
-    return _lm_head(last, params)[0], kv
+    return blk.serve_lm_head(last, params, cfg)[0], kv
 
 
 @jax.named_scope("sample")

@@ -174,7 +174,7 @@ def test_next_dispatch_reads_the_sampled_first_token(chunk):
     eng._admit()
     while eng._prefilling:
         eng._prefill_chunks()
-    tok_dev, [(col, slot, req)], k, seq = eng._pending[-1]
+    tok_dev, [(col, slot, req)], k, seq, _touched = eng._pending[-1]
     assert (col, k, seq) == (0, 1, -1) and req.slot == slot
     assert _rows(eng)[slot] == int(tok_dev) == PARENT_GREEDY[2][0]
     # the handoff is the program's: nothing queued for the patch
@@ -232,7 +232,7 @@ def test_two_admissions_in_one_pass_land_in_their_own_rows():
     eng.submit(PROMPTS[1], temperature=0.0)
     eng.submit(PROMPTS[4], temperature=0.0)
     assert eng._admit() == 2
-    (t1, [(_c1, s1, r1)], _k1, _q1), (t2, [(_c2, s2, r2)], _k2, _q2) = \
+    (t1, [(_c1, s1, r1)], *_e1), (t2, [(_c2, s2, r2)], *_e2) = \
         eng._pending
     assert s1 != s2
     rows = _rows(eng)

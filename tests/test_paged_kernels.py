@@ -58,8 +58,8 @@ def _ref_attention(q, k_pages, v_pages, page_tables, base, limit, sm):
                          0, 3).reshape(b, max_len, hkv, d)
     v_seq = jnp.moveaxis(jnp.take(v_pages, page_tables, axis=1),
                          0, 3).reshape(b, max_len, hkv, d)
-    k_full = kv_cache._gqa_expand(k_seq, n_rep)
-    v_full = kv_cache._gqa_expand(v_seq, n_rep)
+    k_full = kv_cache.gqa_expand(k_seq, n_rep)
+    v_full = kv_cache.gqa_expand(v_seq, n_rep)
     col = jnp.arange(max_len)
     pos = base[:, None] + jnp.arange(t)[None, :]                  # [B,T]
     valid = (col[None, None, :] <= pos[:, :, None]) \

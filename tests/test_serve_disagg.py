@@ -33,7 +33,7 @@ def test_prefill_handoff_matches_monolithic():
     from ray_tpu.serve.llm.engine import LLMEngine
 
     cfg = _tiny_cfg(max_tokens=6)
-    mc = cfg.llama()
+    mc = cfg.model()
     params = llama.init_params(jax.random.PRNGKey(3), mc)
 
     mono = LLMEngine(cfg, params=params)
@@ -69,7 +69,7 @@ def test_disagg_decode_concurrency_and_page_recycling():
     from ray_tpu.serve.llm.engine import LLMEngine
 
     cfg = _tiny_cfg(max_batch_size=2, num_pages=32, max_tokens=5)
-    mc = cfg.llama()
+    mc = cfg.model()
     params = llama.init_params(jax.random.PRNGKey(5), mc)
     pre = LLMEngine(cfg, params=params)
     dec = DecodeEngine(cfg, params=params)

@@ -231,7 +231,7 @@ def test_disagg_decode_spec_identity():
     from ray_tpu.serve.llm.engine import LLMEngine
 
     cfg = _tiny_cfg(max_tokens=24)
-    mc = cfg.llama()
+    mc = cfg.model()
     params = llama.init_params(jax.random.PRNGKey(3), mc)
     prompt = [7, 3, 9, 1] * 5  # repetitive: drafts will fire
 

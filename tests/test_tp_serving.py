@@ -263,7 +263,7 @@ def test_tp_engine_spills_sharded_blobs():
 
 def test_tp_namespace_isolation():
     cfg1, cfg2 = _tp_cfg(tp=1), _tp_cfg(tp=2)
-    mc = cfg1.llama()
+    mc = cfg1.model()
     n1 = kv_tier_namespace(cfg1, mc, "float32")
     n2 = kv_tier_namespace(cfg2, mc, "float32")
     n2b = kv_tier_namespace(_tp_cfg(tp=2), mc, "float32")
