@@ -378,7 +378,149 @@ def _attention(q, k, v, cfg: LlamaConfig, mesh, *, positions_offset=0):
     return jnp.einsum("bhqk,bkhd->bqhd", p, v)
 
 
-def _layer_fwd(x, layer, cos, sin, cfg: LlamaConfig, mesh):
+_QKV = ("wq", "wk", "wv")
+
+
+def _project_qkv(h, w):
+    return tuple(jnp.einsum("btd,dhk->bthk", h, w[name]) for name in _QKV)
+
+
+def _pack_qkv(w):
+    """wq, wk and wv of one layer as one array [D, n_kv_heads, n_rep + 2,
+    hd]: a KV head's group of query heads, its key head and its value head
+    side by side, so that ONE collective moves all three (the chip's
+    compiler keeps one all-gather in flight and ran the second of three,
+    side by side, synchronously) and a split of the heads over "tensor"
+    stays whole groups on both sides."""
+    d, groups, hd = w["wk"].shape
+    return jnp.concatenate(
+        [w["wq"].reshape(d, groups, -1, hd),
+         w["wk"][:, :, None], w["wv"][:, :, None]], axis=2)
+
+
+def _unpack_qkv(packed):
+    d, _, group, hd = packed.shape
+    return {"wq": packed[:, :, :group - 2].reshape(d, -1, hd),
+            "wk": packed[:, :, group - 2], "wv": packed[:, :, group - 1]}
+
+
+def _batch_axes(mesh) -> tuple:
+    """The mesh axes that split the batch, where "fsdp" is one of them and
+    so splits the weights too; () where there is nothing to gather."""
+    if mesh is None:
+        return ()
+    from ray_tpu.parallel.sharding import batch_sharding
+    axes = batch_sharding(mesh).spec[0] or ()
+    axes = (axes,) if isinstance(axes, str) else tuple(axes)
+    return axes if "fsdp" in axes else ()
+
+
+def _per_shard(f, mesh, in_specs, out_specs):
+    """f(*blocks, axes) on every data-parallel shard's own blocks, the
+    other mesh axes (tensor, context) left to the compiler; on the whole
+    arrays, with no axis to name, where "fsdp" splits nothing. A spec names
+    what splits an argument's first dimension: "batch" or "fsdp"."""
+    from jax.sharding import PartitionSpec as P
+    axes = _batch_axes(mesh)
+    f = functools.partial(f, axes=axes)
+    if not axes:
+        return f
+    spec = {"batch": P(axes), "fsdp": P("fsdp")}
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=jax.tree.map(spec.get, in_specs),
+        out_specs=jax.tree.map(spec.get, out_specs),
+        axis_names=frozenset(axes), check_vma=False)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
+def _gather_block(packed, axes):
+    # [D / fsdp, ...] -> [1, D, ...]: the shard's own copy of the whole
+    if axes:
+        packed = jax.lax.all_gather(packed, "fsdp", axis=0, tiled=True)
+    return packed[None]
+
+
+def _gather_block_fwd(packed, axes):
+    return _gather_block(packed, axes), None
+
+
+def _gather_block_bwd(axes, _, ct):
+    """The gather's transpose, a reduce-scatter, as a ring of permutes:
+    the chip's compiler runs a reduce-scatter op synchronously wherever it
+    stands, and permutes behind the compute beside them. Shard d ends with
+    block d of the sum; at step s it adds its own block d - 1 - s to what
+    shard d - 1 hands it. bf16 sums a hop, as the compiler's own ring
+    inside a product keeps them."""
+    ct = ct[0]
+    if not axes:
+        return ct,
+    n = jax.lax.axis_size("fsdp")
+    me = jax.lax.axis_index("fsdp")
+    blocks = ct.reshape(n, ct.shape[0] // n, *ct.shape[1:])
+
+    def block(s):
+        return jax.lax.dynamic_index_in_dim(blocks, (me - 1 - s) % n,
+                                            keepdims=False)
+
+    acc = block(0)
+    for s in range(1, n):
+        acc = jax.lax.ppermute(
+            acc, "fsdp", [(j, (j + 1) % n) for j in range(n)]) + block(s)
+    return acc,
+
+
+_gather_block.defvjp(_gather_block_fwd, _gather_block_bwd)
+
+
+def _project_block(h, w_ahead, axes):
+    return _project_qkv(h, _unpack_qkv(w_ahead[0]))
+
+
+def _dw_block(h, ct, axes):
+    # the shard's own tokens' share of the weights' cotangent, not yet
+    # summed over "fsdp": that sum is the transpose of _gather_block
+    like = {name: jax.ShapeDtypeStruct((h.shape[-1],) + c.shape[2:], h.dtype)
+            for name, c in zip(_QKV, ct)}
+    dw, = jax.linear_transpose(lambda w: _project_qkv(h, w), like)(ct)
+    dw = _pack_qkv(dw)
+    others = tuple(a for a in axes if a != "fsdp")
+    return (jax.lax.psum(dw, others) if others else dw)[None]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _qkv_ahead(mesh, h, w, w_ahead):
+    """The q, k and v projections of h [B,T,D]. ``w_ahead`` [fsdp, D,
+    n_kv_heads, n_rep + 2, hd] is every "fsdp" shard's own copy of the
+    layer's packed wq / wk / wv, gathered while the layer before still ran
+    (hidden_states), so the forward products wait for nothing. The weights'
+    cotangent goes back the same way: each shard's share of it, unsummed,
+    is ``w_ahead``'s cotangent, the scan's transpose carries it into the
+    next layer's backward, and the gather's transpose sums and scatters it
+    there with that whole layer as cover (it is the LAST thing a layer's
+    backward produces; reduced in place it is waited for). ``w``, the
+    layer's shards as the parameters hold them, is the residual instead of
+    the gathered copy: dh is the product on ``w`` that the parent ran
+    (FSDP's second gather, the whole layer's backward in front of it), and
+    ``w`` itself gets no cotangent from here."""
+    return _per_shard(_project_block, mesh, ("batch", "fsdp"),
+                      "batch")(h, w_ahead)
+
+
+def _qkv_ahead_fwd(mesh, h, w, w_ahead):
+    return _qkv_ahead(mesh, h, w, w_ahead), (h, w)
+
+
+def _qkv_ahead_bwd(mesh, res, ct):
+    h, w = res
+    dh, = jax.vjp(lambda h: _project_qkv(h, w), h)[1](ct)
+    dw_ahead = _per_shard(_dw_block, mesh, ("batch", "batch"), "fsdp")(h, ct)
+    return dh, None, dw_ahead
+
+
+_qkv_ahead.defvjp(_qkv_ahead_fwd, _qkv_ahead_bwd)
+
+
+def _layer_fwd(x, layer, w_ahead, cos, sin, cfg: LlamaConfig, mesh):
     # the named scopes (norm / attn / mlp here, embed / lm_head / loss
     # around them) are what a profiler trace names an op's layer by; the
     # serving steps in serve/llm/kv_cache.py use the same names
@@ -386,12 +528,11 @@ def _layer_fwd(x, layer, cos, sin, cfg: LlamaConfig, mesh):
     with jax.named_scope("norm"):
         h = rms_norm(x, layer["attn_norm"], cfg.norm_eps)
     with jax.named_scope("attn"):
-        q = checkpoint_name(
-            jnp.einsum("btd,dhk->bthk", h, layer["attn"]["wq"]), "q_proj")
-        k = checkpoint_name(
-            jnp.einsum("btd,dhk->bthk", h, layer["attn"]["wk"]), "k_proj")
-        v = checkpoint_name(
-            jnp.einsum("btd,dhk->bthk", h, layer["attn"]["wv"]), "v_proj")
+        q, k, v = _qkv_ahead(mesh, h, jax.lax.stop_gradient(
+            {n: layer["attn"][n] for n in _QKV}), w_ahead)
+        q = checkpoint_name(q, "q_proj")
+        k = checkpoint_name(k, "k_proj")
+        v = checkpoint_name(v, "v_proj")
         q = apply_rope(q, cos, sin)
         k = apply_rope(k, cos, sin)
         attn = checkpoint_name(_attention(q, k, v, cfg, mesh), "attn")
@@ -428,9 +569,14 @@ def _remat(body, cfg: LlamaConfig):
         (The standard selective-checkpointing middle ground between "save
         all dots" and "save block outputs".)"""
     if cfg.remat_policy == "dots":
+        # q / k / v by name as well: their products sit inside _qkv_ahead's
+        # shard_map, where the policy does not look
         return jax.checkpoint(
             body,
-            policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
+            policy=jax.checkpoint_policies.save_from_both_policies(
+                jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
+                jax.checkpoint_policies.save_only_these_names(
+                    "q_proj", "k_proj", "v_proj")))
     if cfg.remat_policy == "hybrid":
         return jax.checkpoint(
             body,
@@ -460,12 +606,42 @@ def hidden_states(params, tokens, cfg: LlamaConfig, mesh=None):
         positions = jnp.broadcast_to(jnp.arange(t), (b, t))
         cos, sin = rope_freqs(cfg, positions)
 
-    def body(x, layer):
-        return _layer_fwd(x, layer, cos, sin, cfg, mesh), None
+    # The first thing a layer computes is its q / k / v projections and the
+    # last thing its backward produces is their weights' cotangent, and a
+    # scan iteration can neither ask for anything before it starts nor
+    # leave a sum running when it ends: gathered and reduced inside their
+    # own layer, these three weights are waited for twice a layer. So the
+    # carry holds them for the layer about to run, gathered while the layer
+    # before ran, each iteration asks for the next layer's, and the scan's
+    # transpose hands the unsummed cotangent on to where the gather was
+    # asked (_qkv_ahead). The index into the stacked shards transposes to
+    # an update in place.
+    stack = {n: params["layers"]["attn"][n] for n in _QKV}
+    gather = _per_shard(_gather_block, mesh, "fsdp", "fsdp")
+
+    def ahead(i):
+        # under the layer's scope: a trace counts moving these weights, and
+        # summing their cotangent, as the attention's
+        with jax.named_scope("attn"):
+            return gather(_pack_qkv(jax.tree.map(
+                lambda w: jax.lax.dynamic_index_in_dim(w, i, keepdims=False),
+                stack)))
+
+    def layer_fwd(x, layer, w_ahead):
+        return _layer_fwd(x, layer, w_ahead, cos, sin, cfg, mesh)
 
     if cfg.remat:
-        body = _remat(body, cfg)
-    x, _ = jax.lax.scan(body, x, params["layers"])
+        layer_fwd = _remat(layer_fwd, cfg)
+    last = cfg.n_layers - 1
+
+    def body(carry, xs):
+        (x, w_ahead), (layer, i) = carry, xs
+        # the last layer asks for its own again: one gather a step wasted
+        return (layer_fwd(x, layer, w_ahead),
+                ahead(jnp.minimum(i + 1, last))), None
+
+    (x, _), _ = jax.lax.scan(body, (x, ahead(0)),
+                             (params["layers"], jnp.arange(cfg.n_layers)))
     with jax.named_scope("norm"):
         return rms_norm(x, params["final_norm"], cfg.norm_eps)
 

@@ -187,6 +187,11 @@ def test_flash_attention_runs_per_shard_on_a_mesh(jax_cpu_mesh):
         fn = lambda p, cfg=cfg: llama.loss_fn(p, {"tokens": tokens}, cfg,
                                               mesh)
         losses[impl] = float(jax.jit(fn)(params))
-        assert ("shard_map" in str(jax.make_jaxpr(fn)(params))) \
+        # (traced at the attention itself: the q / k / v products have a
+        # shard_map of their own wherever "fsdp" splits the weights)
+        qkv = [jnp.zeros((4, 32, h, cfg.head_dim))
+               for h in (cfg.n_heads, cfg.n_kv_heads, cfg.n_kv_heads)]
+        attn = lambda q, k, v, cfg=cfg: llama._attention(q, k, v, cfg, mesh)
+        assert ("shard_map" in str(jax.make_jaxpr(attn)(*qkv))) \
             == (impl == "flash")
     assert abs(losses["flash"] - losses["dense"]) < 1e-4

@@ -223,14 +223,13 @@ def test_model_expands_where_kv_heads_do_not_split_over_tensor(jax_cpu_mesh):
 # ---- compiled for the chip, without the chip ------------------------------
 
 @pytest.fixture(scope="module")
-def one_chip():
-    """One device of a described v5e 2x2. Described here and never at
+def v5e_2x2():
+    """The four devices of a described v5e 2x2. Described here and never at
     import: only one process may load the TPU's library, and every xdist
     worker imports this file."""
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
-    from jax.sharding import SingleDeviceSharding
     try:
         topo = topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
@@ -241,9 +240,15 @@ def one_chip():
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo.devices
     jax.config.update("jax_enable_compilation_cache", was)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(v5e_2x2):
+    from jax.sharding import SingleDeviceSharding
+    return SingleDeviceSharding(v5e_2x2[0])
 
 
 @pytest.mark.parametrize("which", ["forward", "backward"])
@@ -258,3 +263,90 @@ def test_kernels_compile_for_v5e_at_the_train_cells_shapes(which, one_chip):
                                                   argnums=(0, 1, 2))
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert text.count("tpu_custom_call") >= (1 if which == "forward" else 2)
+
+
+def _loop_bodies(text):
+    """The scheduled while bodies of a compiled module, in the text's
+    order: each a list of (instruction line) strings."""
+    bodies, cur = [], None
+    for line in text.split("\n"):
+        if line.startswith("%") and line.rstrip().endswith("{"):
+            cur = [] if "region" in line.split("(")[0] else None
+        elif line.startswith("}"):
+            if cur:
+                bodies.append(cur)
+            cur = None
+        elif cur is not None:
+            cur.append(line)
+    return bodies
+
+
+def test_train_step_for_v5e_awaits_no_qkv_weight_inside_its_own_layer(
+        v5e_2x2, monkeypatch):
+    """mistral7b-train-fsdp4's step at depth 2, the cell's widths and
+    recipe, lowered on the described 2x2 with fsdp=4 and read as scheduled.
+    What models/llama.py claims: (1) in the forward loop's body no
+    collective stands under the q / k / v products (the parent's body sent
+    each of wq / wk / wv round a ring of permutes there, started at its top
+    with only the norm in front); the three weights arrive as ONE gather of
+    the packed [1024, 8, 6, 128] shard, started in the body and consumed by
+    the NEXT iteration (its result leaves through the loop's carry). (2) in
+    the backward loop's body the weights' cotangent is summed by three hops
+    of a [1024, 8, 6, 128] permute that read the carry, not by a ring under
+    the products that made it; the only collectives left under the products
+    are FSDP's second gather for dh. (3) no gathered [4096, 8, 6, 128] is
+    stacked a layer as a residual."""
+    import functools
+    import re
+
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from ray_tpu.parallel.mesh import MeshSpec, build_mesh
+    from ray_tpu.parallel.sharding import batch_sharding
+    from ray_tpu.train import spmd
+
+    monkeypatch.setattr(fa, "flash_attention", functools.partial(
+        fa.flash_attention, interpret=False))
+    cfg = llama.LlamaConfig(
+        vocab_size=32768, dim=4096, n_layers=2, n_heads=32, n_kv_heads=8,
+        ffn_dim=14336, max_seq_len=2048, rope_theta=1e6, dtype=jnp.bfloat16,
+        remat_policy="dots", ce_chunk=2048, ce_remat=False,
+        attn_impl="flash")
+    mesh = build_mesh(MeshSpec(fsdp=4), v5e_2x2)
+    opt = spmd.default_optimizer(name="adafactor")
+    make = lambda: spmd.TrainState.create(
+        llama.init_params(jax.random.PRNGKey(0), cfg), opt)
+    shapes = jax.eval_shape(make)
+    sh = spmd.state_shardings(llama.logical_axes(cfg), shapes.params, mesh,
+                              opt)
+    state = jax.tree.map(
+        lambda s, h: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=h),
+        shapes, sh)
+    batch = {"tokens": jax.ShapeDtypeStruct(
+        (8, 2049), jnp.int32, sharding=batch_sharding(mesh, extra_dims=1))}
+    step = spmd.make_train_step(
+        lambda p, b: llama.loss_fn(p, b, cfg, mesh), opt, mesh, sh)
+    text = step.lower(state, batch).compile().as_text()
+
+    assert "bf16[2,4096,8,6,128]" not in text          # (3)
+    collective = re.compile(
+        r" (collective-permute-start|all-gather|all-gather-start|"
+        r"reduce-scatter|all-reduce)\(|async-collective-start")
+    bodies = [b for b in _loop_bodies(text)
+              if any("flash_fwd" in line for line in b)]
+    backward = [b for b in bodies if any("flash_bwd" in line for line in b)]
+    forward = [b for b in bodies if b not in backward]
+    assert len(forward) == 1 and len(backward) == 1
+    qkv = "btd,dhk->bthk"
+    fwd = [line for line in forward[0] if collective.search(line)]
+    assert fwd and not [line for line in fwd if qkv in line], fwd  # (1)
+    gathers = [line for line in forward[0]
+               if "= bf16[4096,8,6,128]" in line.replace("(", " ")
+               and ("async-collective-done" in line or "all-gather" in line)]
+    assert len(gathers) == 1, gathers
+    bwd = [line for line in backward[0] if collective.search(line)]
+    ring = [line for line in bwd if "bf16[1024,8,6,128]" in line
+            and "collective-permute-start" in line and "ppermute" in line]
+    assert len(ring) == 3, ring                                     # (2)
+    under = [line for line in bwd if qkv in line]
+    assert under and all("transpose(jvp(" in line for line in under), under

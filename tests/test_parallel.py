@@ -431,3 +431,106 @@ def test_serve_and_train_share_rule_machinery():
     flat = jax.tree_util.tree_leaves(
         specs, is_leaf=lambda x: isinstance(x, jax.sharding.PartitionSpec))
     assert all(isinstance(s, jax.sharding.PartitionSpec) for s in flat)
+
+
+# ---- the layer scan's q / k / v weights, gathered one layer ahead -----------
+
+def _plain_loss(params, batch, cfg, mesh):
+    """llama.loss_fn with the layer scan in its plain form (the parent's
+    _layer_fwd): every layer projects with its own wq / wk / wv as the
+    parameters hold them, nothing rides in the carry, and autodiff alone
+    makes the backward. The reference for what models/llama.py does."""
+    from jax.ad_checkpoint import checkpoint_name
+
+    from ray_tpu.models import llama
+    tokens = batch["tokens"]
+    inputs, targets = tokens[:, :-1], tokens[:, 1:]
+    b, t = inputs.shape
+    x = params["embed"][inputs].astype(cfg.dtype)
+    cos, sin = llama.rope_freqs(cfg, jnp.broadcast_to(jnp.arange(t), (b, t)))
+
+    def body(x, layer):
+        h = llama.rms_norm(x, layer["attn_norm"], cfg.norm_eps)
+        q, k, v = (checkpoint_name(
+            jnp.einsum("btd,dhk->bthk", h, layer["attn"][w]), name)
+            for w, name in (("wq", "q_proj"), ("wk", "k_proj"),
+                            ("wv", "v_proj")))
+        q, k = llama.apply_rope(q, cos, sin), llama.apply_rope(k, cos, sin)
+        attn = checkpoint_name(llama._attention(q, k, v, cfg, mesh), "attn")
+        x = x + checkpoint_name(
+            jnp.einsum("bthk,hkd->btd", attn, layer["attn"]["wo"]),
+            "attn_out")
+        h = checkpoint_name(
+            llama.rms_norm(x, layer["mlp_norm"], cfg.norm_eps), "mlp_in")
+        gate = jax.nn.silu(h @ layer["mlp"]["w_gate"])
+        return x + checkpoint_name(
+            (gate * (h @ layer["mlp"]["w_up"])) @ layer["mlp"]["w_down"],
+            "mlp_out"), None
+
+    if cfg.remat:
+        body = llama._remat(body, cfg)
+    x, _ = jax.lax.scan(body, x, params["layers"])
+    hidden = llama.rms_norm(x, params["final_norm"], cfg.norm_eps)
+    return llama.chunked_cross_entropy(
+        params["lm_head"], hidden, targets, chunk=cfg.ce_chunk,
+        remat=cfg.ce_remat)
+
+
+AHEAD_MESHES = {"fsdp4": dict(fsdp=4, data=2), "fsdp1": dict(tensor=2, data=4),
+                "fsdp2_tensor2": dict(fsdp=2, tensor=2, data=2), "none": None}
+
+
+@pytest.mark.parametrize("policy", ["dots", "full", "hybrid", "outs", None])
+@pytest.mark.parametrize("mesh_name", list(AHEAD_MESHES))
+def test_weights_gathered_ahead_change_no_loss_and_no_gradient(
+        mesh_name, policy, jax_cpu_mesh):
+    """float32 on the 8-device mesh: loss and every gradient leaf of
+    llama.loss_fn equal the plain formulation's to 1e-6, where "fsdp"
+    splits the weights (the gathered copies ride in the carry, their
+    cotangent comes back through it and is summed by the ring), where it
+    does not (no collective is named) and without a mesh; under every
+    remat policy and without remat."""
+    from ray_tpu.models import llama
+
+    cfg = llama.llama_tiny(
+        n_layers=3, max_seq_len=32, attn_impl="flash",
+        remat=policy is not None, remat_policy=policy or "full")
+    params = llama.init_params(jax.random.PRNGKey(0), cfg)
+    mesh = None
+    if AHEAD_MESHES[mesh_name]:
+        mesh = build_mesh(MeshSpec(**AHEAD_MESHES[mesh_name]),
+                          jax_cpu_mesh[:8])
+        params = jax.device_put(params, logical_to_shardings(
+            llama.logical_axes(cfg), mesh))
+    batch = {"tokens": jnp.asarray(np.random.default_rng(0).integers(
+        0, cfg.vocab_size, (8, 33)), jnp.int32)}
+    loss, grads = jax.jit(jax.value_and_grad(
+        lambda p: llama.loss_fn(p, batch, cfg, mesh)))(params)
+    want, want_grads = jax.jit(jax.value_and_grad(
+        lambda p: _plain_loss(p, batch, cfg, mesh)))(params)
+    assert abs(float(loss) - float(want)) < 1e-6
+    for (path, g), w in zip(jax.tree_util.tree_leaves_with_path(grads),
+                            jax.tree.leaves(want_grads)):
+        assert g.shape == w.shape and float(jnp.max(jnp.abs(w))) > 0, path
+        np.testing.assert_allclose(g, w, atol=1e-6, rtol=0, err_msg=str(path))
+
+
+@pytest.mark.parametrize("policy", ["dots", "hybrid"])
+def test_gathered_weights_are_no_residual_of_the_layer_scan(policy):
+    """What the checkpointed scan keeps for the backward under the policies
+    that save q / k / v: no array of a gathered weight's size a layer (the
+    carry would be 50 MB x 24 at the train cell's widths). The residual is
+    the shard. (`full` and `outs` save no q / k / v, so their recompute
+    reads the gathered copy and it is kept: PERF.md section 7.)"""
+    from ray_tpu.models import llama
+
+    cfg = llama.llama_tiny(n_layers=3, max_seq_len=32, remat=True,
+                           remat_policy=policy)
+    params = llama.init_params(jax.random.PRNGKey(0), cfg)
+    batch = {"tokens": jnp.zeros((2, 33), jnp.int32)}
+    packed = (cfg.dim, cfg.n_kv_heads,
+              cfg.n_heads // cfg.n_kv_heads + 2, cfg.head_dim)
+    _, vjp = jax.vjp(lambda p: llama.loss_fn(p, batch, cfg, None), params)
+    saved = [x.shape for x in jax.tree.leaves(vjp) if hasattr(x, "shape")]
+    assert saved and not [s for s in saved if s[-4:] == packed and
+                          len(s) > 4 and cfg.n_layers in s[:-4]], saved

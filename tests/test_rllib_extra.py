@@ -81,7 +81,7 @@ def test_cql_learns_from_mixed_offline_data(tmp_path):
     assert ev["episode_return_mean"] == 1.0
 
 
-def test_offline_data_from_ray_dataset(tmp_path):
+def test_offline_data_from_ray_dataset(rt, tmp_path):
     """The offline path composes with ray_tpu.data (the reference routes
     offline episodes through Ray Data, rllib/offline/offline_data.py)."""
     from ray_tpu import data as rtd
