@@ -26,6 +26,22 @@ that can be served provides, at module level:
 
 ``cfg.head_dim``, ``cfg.dtype`` and ``cfg.max_seq_len`` are read off the
 configuration itself.
+
+A block that generates by diffusion over blocks says so in its cache spec
+(``block_length`` B above 1, ``mask_token``) and provides two more values on
+its configuration, ``denoise_passes`` S and (derived) ``B / S`` positions
+revealed a pass. For such a block positions are cut into blocks of B from
+0; key j is visible to query i iff ``j // B <= i // B`` (every paged
+program masks so); a prefill or a prefill chunk COMMITS the prompt's whole
+blocks (K / V of positions below ``true_len - true_len % B``), its logits
+mean nothing and it yields no token: the ``true_len % B`` tokens left over
+start the slot's pending block, the rest of which holds ``mask_token``.
+``kv_cache.paged_block_step`` then runs a pending block's B positions
+against the cache, S times without keeping K / V (the engine reveals B / S
+masked positions after each, by confidence) and once more, clean, to
+commit it. The logits AT a masked position are the distribution of the
+token that belongs there. ``serve_lm_head`` of such a block is only called
+on denoise passes.
 """
 
 from __future__ import annotations
@@ -43,7 +59,10 @@ class CacheSpec:
     ``state_shape`` a SEQUENCE (not a token): state that survives between
     decode steps, is carried from one prefill chunk to the next and cannot
     be rebuilt from the pages. ``routed_layers`` layers choose ``top_k`` of
-    ``n_experts`` experts a token, and the programs record the choice."""
+    ``n_experts`` experts a token, and the programs record the choice.
+    ``block_length``: 1 = a step yields a token a sequence; B above 1 = the
+    block generates by diffusion over blocks of B positions, of which a
+    not yet revealed one holds ``mask_token`` (never produced)."""
     paged_layers: int
     n_kv_heads: int
     head_dim: int
@@ -52,6 +71,8 @@ class CacheSpec:
     routed_layers: int = 0
     top_k: int = 0
     n_experts: int = 0
+    block_length: int = 1
+    mask_token: int = -1
 
 
 @dataclasses.dataclass(frozen=True)

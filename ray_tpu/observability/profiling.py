@@ -56,8 +56,8 @@ logger = logging.getLogger(__name__)
 # `loop_pass` (`patch_flush` inside a dispatch; `restore`, `kv_tier_flush`
 # only with the kv tier on).
 PHASES = ("queue_wait", "loop_pass", "admit", "restore", "prefill",
-          "chunk_prefill", "decode_dispatch", "verify_dispatch",
-          "patch_flush", "harvest", "emit", "kv_tier_flush", "loop_wait")
+          "chunk_prefill", "decode_dispatch", "block_dispatch",
+          "verify_dispatch", "patch_flush", "harvest", "emit", "kv_tier_flush", "loop_wait")
 # span names in the profiler's trace: "rt/<phase>"
 SPAN_PREFIX = "rt/"
 

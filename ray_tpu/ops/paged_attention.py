@@ -11,9 +11,14 @@ serving programs (serve/llm/kv_cache.py) can carry the pool through
 their loops in place and never slice a layer out of it.
 
 One core kernel covers the whole family — decode (T=1), multi-query
-speculative verify (T=k+1 causal within the span), and chunked prefill
-(B=1, extra ``true_len`` bound) are the same computation with different
-query spans and masks, dispatched through thin wrappers.
+speculative verify (T=k+1 causal within the span), chunked prefill
+(B=1, extra ``true_len`` bound) and the block pass of generation by
+diffusion over blocks (T = one block, every position of which sees the
+whole block) are the same computation with different query spans and
+masks, dispatched through thin wrappers. The mask is ``col < (pos //
+block_len + 1) * block_len``: key j is visible to query i iff j's block is
+not after i's; at ``block_len`` 1 (static) that is the causal ``col <=
+pos``.
 
 Identity contract: greedy TOKENS under the pallas backend must equal the
 gather backend exactly (hard-asserted in tests and the serve bench), so
@@ -125,7 +130,7 @@ def _row_tiling(r: int, max_len: int, dtype) -> tuple[int, int]:
 def _paged_attn_kernel(pt_ref, base_ref, limit_ref, layer_ref,  # prefetch
                        q_ref, k_ref, v_ref, o_ref, k_scr, v_scr, *,
                        sm_scale: float, page_size: int, num_pages: int,
-                       t_span: int, row_tile: int):
+                       t_span: int, row_tile: int, block_len: int = 1):
     """Grid (B, Hkv, num_pages); one (slot, kv-head) pair accumulates its
     pages into VMEM scratch and computes dense attention on the last page.
 
@@ -163,7 +168,11 @@ def _paged_attn_kernel(pt_ref, base_ref, limit_ref, layer_ref,  # prefetch
             col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
             row = r0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
             pos = base + row % t_span
-            valid = (col <= pos) & (col < limit)
+            if block_len == 1:
+                valid = (col <= pos) & (col < limit)
+            else:   # a position sees its whole block and every earlier one
+                valid = (col < (pos // block_len + 1) * block_len) \
+                    & (col < limit)
             s = jnp.where(valid, s, _NEG_INF)
             w = jax.nn.softmax(s, axis=-1).astype(q.dtype)
             o_ref[0, 0, pl.ds(r0, row_tile), :] = jax.lax.dot_general(
@@ -177,11 +186,14 @@ def _paged_attn_kernel(pt_ref, base_ref, limit_ref, layer_ref,  # prefetch
 def paged_attention(q, k_pages, v_pages, page_tables, base, limit=None,
                     layer=None, *, sm_scale: float | None = None,
                     interpret: bool | None = None,
-                    name: str = "paged_attention"):
+                    name: str = "paged_attention", block_len: int = 1):
     """Fused paged attention over the whole query span.
 
     q: [B, T, H, D] — query position of q[:, t] is ``base + t`` (causal
-    within the span, full attention over the paged cache below it).
+    within the span, full attention over the paged cache below it; with a
+    static ``block_len`` above 1, positions are cut into blocks of that
+    length from 0 and a query sees every key up to the end of its own
+    block).
     k_pages/v_pages: the whole pool [L, Hkv, P, page, D], of which the
     kernel reads layer ``layer`` (int32 scalar, traced or not): the index
     rides in as a scalar-prefetch operand and the K/V block index maps
@@ -203,7 +215,7 @@ def paged_attention(q, k_pages, v_pages, page_tables, base, limit=None,
         return _packed_heads(
             q, k_pages, v_pages, page_tables, base, limit, layer,
             sm_scale=d ** -0.5 if sm_scale is None else sm_scale,
-            interpret=interpret, name=name)
+            interpret=interpret, name=name, block_len=block_len)
     hkv = k_pages.shape[1]
     n_rep = h // hkv
     page_size = k_pages.shape[3]
@@ -234,7 +246,8 @@ def paged_attention(q, k_pages, v_pages, page_tables, base, limit=None,
             + 8 * row_tile * max_len * 4)
     kernel = functools.partial(
         _paged_attn_kernel, sm_scale=sm_scale, page_size=page_size,
-        num_pages=max_pages, t_span=t, row_tile=row_tile)
+        num_pages=max_pages, t_span=t, row_tile=row_tile,
+        block_len=block_len)
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -275,7 +288,7 @@ def paged_attention(q, k_pages, v_pages, page_tables, base, limit=None,
 
 
 def _packed_heads(q, k_pages, v_pages, page_tables, base, limit, layer, *,
-                  sm_scale, interpret, name):
+                  sm_scale, interpret, name, block_len=1):
     """Heads narrower than a pool row: the pool holds ``pack`` KV heads
     side by side in one row of lanes ([L, Hkv / pack, P, page, pack * D]:
     heads of 64 two to a 128-lane row, so HBM holds no padding and a page
@@ -296,7 +309,7 @@ def _packed_heads(q, k_pages, v_pages, page_tables, base, limit, layer, *,
         b, t, h, pack * d)
     out = paged_attention(spread, k_pages, v_pages, page_tables, base, limit,
                           layer, sm_scale=sm_scale, interpret=interpret,
-                          name=name)                        # [B, T, H, pack*D]
+                          name=name, block_len=block_len)   # [B, T, H, pack*D]
     out = out.reshape(b, t, rows, pack, n_rep, pack, d)
     return jnp.stack([out[:, :, :, j, :, j] for j in range(pack)],
                      axis=3).reshape(b, t, h, d)
@@ -328,16 +341,32 @@ def paged_verify_attention(q, k_pages, v_pages, page_tables, seq_lens,
                            name="paged_verify_attention")
 
 
+def paged_block_attention(q, k_pages, v_pages, page_tables, seq_lens,
+                          layer=None, *, block_len: int,
+                          sm_scale: float | None = None,
+                          interpret: bool | None = None):
+    """The block pass of generation by diffusion over blocks: q [B, T, H, D]
+    with T = ``block_len``, q[b, t] at position ``seq_lens[b] + t`` (a
+    block edge), every position seeing the slot's cached pages and the
+    whole block (whose k/v are pre-written). Returns [B, T, H, D]."""
+    return paged_attention(q, k_pages, v_pages, page_tables, seq_lens,
+                           layer=layer, sm_scale=sm_scale,
+                           interpret=interpret,
+                           name="paged_block_attention", block_len=block_len)
+
+
 def paged_chunk_attention(q, k_pages, v_pages, page_table, start, true_len,
                           layer=None, *, sm_scale: float | None = None,
-                          interpret: bool | None = None):
+                          interpret: bool | None = None,
+                          block_len: int = 1):
     """Chunked-prefill attention for ONE slot: q [1, C, H, D] chunk whose
     first token sits at position ``start``; keys are the slot's whole
     paged view (earlier chunks + this one, pre-written) bounded by
-    ``true_len``. Returns [1, C, H, D]."""
+    ``true_len``; causal, or by blocks of ``block_len``. Returns
+    [1, C, H, D]."""
     base = jnp.reshape(start, (1,)).astype(jnp.int32)
     limit = jnp.reshape(true_len, (1,)).astype(jnp.int32)
     return paged_attention(q, k_pages, v_pages, page_table[None], base,
                            limit, layer, sm_scale=sm_scale,
                            interpret=interpret,
-                           name="paged_chunk_attention")
+                           name="paged_chunk_attention", block_len=block_len)

@@ -159,6 +159,19 @@ def route_sigmoid_top_k(g, router, bias, top_k: int, *,
     return idx.astype(jnp.int32), w * scaling
 
 
+def route_softmax_top_k(g, router, top_k: int, *,
+                        norm_topk_prob: bool = True):
+    """Softmax over ALL experts in float32, the ``top_k`` most probable
+    chosen, combine weights their probabilities, renormalised over the
+    chosen where ``norm_topk_prob``. g: [N, D]; router: [D, E]. Returns
+    (idx [N, k] int32, w [N, k] float32)."""
+    logits = jnp.dot(g, router, preferred_element_type=jnp.float32)
+    w, idx = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
+    if norm_topk_prob:
+        w = w / jnp.sum(w, axis=-1, keepdims=True)
+    return idx.astype(jnp.int32), w
+
+
 def _tile(n: int, cap: int) -> int:
     """The whole dimension, or the largest ``n / 2**j`` under ``cap``."""
     while n > cap and n % 2 == 0:
@@ -206,8 +219,8 @@ def expert_share(g, idx, w, experts: dict, held: range):
     """The part of a routed layer's output that the experts ``held`` give.
 
     g [N, D] the normed tokens; idx / w [N, k] the routing over ALL
-    experts (:func:`route_sigmoid_top_k`: the router is counted once,
-    outside); experts: w_gate / w_up [len(held), D, F], w_down
+    experts (:func:`route_sigmoid_top_k` or :func:`route_softmax_top_k`:
+    the router is counted once, outside); experts: w_gate / w_up [len(held), D, F], w_down
     [len(held), F, D]: only the held experts' weights. Dropless: every
     (token, expert) pick with the expert in ``held`` is computed, however
     many land on one expert. Picks are sorted by expert, multiplied group

@@ -192,6 +192,23 @@ class LLMEngine:
         # only, so none of them happens for it; each is counted where it
         # would have (<x>_bypassed_stateful)
         self._stateful = kvc.has_slot_state(self.model_cfg)
+        # a block that generates by diffusion over blocks (block_length B
+        # above 1): a slot carries a pending block of B tokens across
+        # dispatches, a dispatch runs whole blocks (denoise passes, then
+        # the commit pass) and yields B tokens a slot and block, a prefill
+        # yields none. Speculation has no meaning for it; the kv tier and
+        # disaggregated hand-off move pages and ONE token, not a pending
+        # block: each is turned off or refused, and counted
+        # (<x>_bypassed_block, disagg_refused_block)
+        self._block_len = int(self._cache_spec.block_length)
+        if self._block_len > 1:
+            for name in ("page_size", "max_seq_len", "prefill_chunk"):
+                if getattr(cfg, name) % self._block_len:
+                    raise ValueError(
+                        f"{name}={getattr(cfg, name)} is no multiple of "
+                        f"the model's block length {self._block_len}: "
+                        f"page, chunk and sequence edges must be block "
+                        f"edges")
         self.tokenizer = get_tokenizer(cfg.tokenizer)
         self._jax = jax
         self._jnp = jnp
@@ -325,7 +342,21 @@ class LLMEngine:
                       "prefix_bypassed_stateful": 0,
                       "kv_tier_bypassed_stateful": 0,
                       "spec_bypassed_stateful": 0,
-                      "disagg_refused_stateful": 0}
+                      "disagg_refused_stateful": 0,
+                      # generation by diffusion over blocks (0 for a block
+                      # a step of which yields a token), over the block
+                      # dispatches harvested: passes run (``steps`` counts
+                      # them too), of them denoise and commit passes, live
+                      # slots summed over passes, blocks committed (slots
+                      # x blocks), tokens revealed and discarded past a
+                      # stop token or max_tokens; and what such a block is
+                      # kept out of
+                      "block_passes_total": 0, "denoise_passes_total": 0,
+                      "commit_passes_total": 0, "slot_passes_total": 0,
+                      "blocks_committed_total": 0, "tokens_cut_total": 0,
+                      "spec_bypassed_block": 0,
+                      "kv_tier_bypassed_block": 0,
+                      "disagg_refused_block": 0}
         # Tiered KV cache (kv_tier.py): evicted cached page chains spill
         # host-side into a shm/disk tier + cluster index instead of dying,
         # and _admit extends its longest-match search past the local index
@@ -333,7 +364,8 @@ class LLMEngine:
         # dispatches one device gather (stream-ordered before any reuse of
         # the pages); the host copy + object-store put happen later on the
         # loop, off the admission hot path (_kv_tier_flush).
-        self._kv_tier_on = bool(cfg.kv_tier_enabled) and self._prefix_cache_on
+        self._kv_tier_on = bool(cfg.kv_tier_enabled) \
+            and self._prefix_cache_on and self._block_len == 1
         self._kv_tier = None
         self._tier_pending: list = []  # [(dev_k, dev_v, [(page, dig, pos)])]
         # drain-time eager spill handshake (ISSUE 14): spill_inflight()
@@ -364,7 +396,8 @@ class LLMEngine:
         # below): host-side n-gram drafts verified k-at-a-time in one
         # fused dispatch. Greedy-only guarantee — non-greedy slots are
         # never drafted and ride the normal decode path.
-        self._spec_on = bool(cfg.spec_decode_enabled) and not self._stateful
+        self._spec_on = bool(cfg.spec_decode_enabled) \
+            and not self._stateful and self._block_len == 1
         # last decode-block k actually dispatched + live pipeline depth
         # (engine_stats gauges: the k=1/pressure/full tier transitions are
         # observable instead of inferred from throughput wiggles)
@@ -386,8 +419,12 @@ class LLMEngine:
         # the token every slot's next step consumes: [B+1] on device (row b
         # is the trash row, below). Only programs write it: a decode block
         # or verify round its last samples, a prefill (or last prefill
-        # chunk) its first token at the row of the slot it arms.
-        self._dev_tokens = jnp.zeros((b + 1,), jnp.int32)
+        # chunk) its first token at the row of the slot it arms. With a
+        # block length B above 1 a row is the slot's PENDING BLOCK [B]:
+        # known tokens, the mask token elsewhere.
+        self._dev_tokens = jnp.zeros(
+            (b + 1,) + ((self._block_len,) if self._block_len > 1 else ()),
+            jnp.int32)
         # slot -> a token the HOST knows and the device row does not hold:
         # a verify round's rollback, a disaggregated adoption. Host ints
         # only (a device value here would need an eager op to place).
@@ -427,9 +464,13 @@ class LLMEngine:
         # PACKED width, and scatters the carried state back — one program
         # per (bucket width, block length), so a lightly loaded engine pays
         # for the requests it has, not for max_batch_size.
+        # With a block length above 1 the same slot in the loop holds the
+        # block program (_block_impl: n whole blocks a dispatch).
+        decode_impl = self._block_impl if self._block_len > 1 \
+            else self._decode_impl
         self._decode = jax.jit(
             lambda params, kv, pt, sl, toks, rng, temp, idx, n:
-            self._decode_impl(params, kv, pt, sl, toks, rng, temp, idx, n),
+            decode_impl(params, kv, pt, sl, toks, rng, temp, idx, n),
             donate_argnums=(1, 3, 4), static_argnums=(8,))
         # verify-k (speculative decoding): same packed-width shape as
         # _decode, but the scan consumes the DRAFTED tokens instead of its
@@ -553,21 +594,126 @@ class LLMEngine:
             return all_toks, toks_full, kv, sl_full, rng, jnp.sum(touched)
         return all_toks, toks_full, kv, sl_full, rng
 
-    def _experts_touched(self, kv, idx):
-        """Distinct experts the live rows of the last decode step chose,
-        summed over the routed layers (int32 scalar); None for a block
-        without routed experts. ``idx``: the packed slot index, whose
-        padding lanes (the trash row) do not count."""
+    def _experts_touched(self, kv, idx, rows_a_slot: int = 1):
+        """Distinct experts the live rows of the last decode step (or
+        block pass: ``rows_a_slot`` rows a slot, slot-major) chose, summed
+        over the routed layers (int32 scalar); None for a block without
+        routed experts. ``idx``: the packed slot index, whose padding
+        lanes (the trash row) do not count."""
         spec = self._cache_spec
         if not spec.routed_layers:
             return None
         jnp = self._jnp
         with self._jax.named_scope("experts_touched"):
             live = idx != self.cfg.max_batch_size                    # [W]
-            chosen = kv["routing"][:, :idx.shape[0]]            # [L_r, W, k]
+            if rows_a_slot > 1:
+                live = jnp.repeat(live, rows_a_slot)
+            chosen = kv["routing"][:, :live.shape[0]]           # [L_r, W, k]
             hot = chosen[..., None] == jnp.arange(spec.n_experts)
             hit = jnp.any(hot & live[None, :, None, None], axis=(1, 2))
             return jnp.sum(hit, dtype=jnp.int32)
+
+    def _block_impl(self, params, kv, pt_full, sl_full, toks_full, rng,
+                    temps_full, idx, num_blocks: int = 1):
+        """The block program (generation by diffusion over blocks; the
+        model's block length B, ``denoise_passes`` S and ``reveal_per_pass``
+        n): ``num_blocks`` whole blocks in ONE program, at the packed
+        width W = ``len(idx)``, as _decode_impl is ``num_steps`` steps.
+
+        For each block: S denoise passes (kv_cache.paged_block_step without
+        commit, the head over the B positions, then ``unmask``: of the
+        positions that still hold the mask token, the n whose sampled
+        token is most probable take that token; greedy at temperature 0;
+        the mask token's own logit is left out, it is never produced) and
+        one commit pass of the now clean block, which keeps its K / V and
+        advances the slot by B; the next block starts all masked.
+        ``toks_full`` [B+1 rows, B]: every slot's pending block. Returns
+        the blocks' tokens as [num_blocks x B, W] (row j x B + i: position i
+        of block j, known tokens included: the host skips the ones a
+        prompt left), the carried state, and a routed block's count of
+        experts touched over all passes."""
+        jax = self._jax
+        jnp = self._jnp
+        mcfg = self.model_cfg
+        b, mask_id = self._block_len, self._cache_spec.mask_token
+        n_reveal = mcfg.reveal_per_pass
+
+        def block_pass(kv_c, lens, blk, commit):
+            return self._kvc.paged_block_step(
+                params, kv_c, pt, lens, blk, mcfg, self.cfg.page_size,
+                self._attn_backend, mesh=self._mesh, commit=commit)
+
+        def unmask(logits, blk, key):
+            """blk [W, B] with n more of its masked positions revealed."""
+            vocab = logits.shape[-1]
+            logits = jnp.where(jnp.arange(vocab) == mask_id, -jnp.inf,
+                               logits)
+            # (the sampler's noise over B x the vocabulary a slot is drawn
+            # only where a live slot asks for a temperature)
+            tok = jax.lax.cond(
+                jnp.any(temps > 0),
+                lambda: self._kvc.sample_tokens(
+                    logits.reshape(-1, vocab), key, jnp.repeat(temps, b),
+                    self.cfg.top_k).reshape(blk.shape),
+                lambda: jnp.argmax(logits, axis=-1)).astype(blk.dtype)
+            conf = jnp.exp(
+                jnp.take_along_axis(logits, tok[..., None], axis=-1)[..., 0]
+                - jax.nn.logsumexp(logits, axis=-1))              # [W, B]
+            masked = blk == mask_id
+            _, best = jax.lax.top_k(jnp.where(masked, conf, -1.0), n_reveal)
+            reveal = jnp.any(best[..., None] == jnp.arange(b), axis=1)
+            return jnp.where(reveal & masked, tok, blk)
+
+        with jax.named_scope("block_program"):
+            with jax.named_scope("gather_state"):
+                pt = pt_full[idx]
+                lens0 = sl_full[idx]
+                blk0 = toks_full[idx]
+                temps = temps_full[idx]
+
+            @jax.named_scope("block_step")
+            def one(carry, _):
+                kv_c, lens, blk, key = carry
+                touched = []
+                for _s in range(mcfg.denoise_passes):
+                    key, sub = jax.random.split(key)
+                    with jax.named_scope("denoise"):
+                        logits, kv_c, _ = block_pass(kv_c, lens, blk, False)
+                        with jax.named_scope("unmask"):
+                            blk = unmask(logits, blk, sub)
+                        touched.append(self._experts_touched(kv_c, idx, b))
+                with jax.named_scope("commit"):
+                    _, kv_c, lens = block_pass(kv_c, lens, blk, True)
+                    touched.append(self._experts_touched(kv_c, idx, b))
+                fresh = jnp.full_like(blk, mask_id)
+                return (kv_c, lens, fresh, key), (
+                    blk, None if touched[0] is None else sum(touched))
+
+            (kv, new_lens, last, rng), (blocks, touched) = jax.lax.scan(
+                one, (kv, lens0, blk0, rng), None, length=num_blocks)
+            all_toks = jnp.swapaxes(blocks, 1, 2).reshape(
+                num_blocks * b, -1)                           # [n x B, W]
+            trash = self.cfg.max_batch_size
+            with jax.named_scope("scatter_state"):
+                sl_full = sl_full.at[idx].set(
+                    jnp.where(idx == trash, 0, new_lens))
+                toks_full = toks_full.at[idx].set(last)
+        if touched is not None:
+            return all_toks, toks_full, kv, sl_full, rng, jnp.sum(touched)
+        return all_toks, toks_full, kv, sl_full, rng
+
+    def _pending_block(self, tokens, start, true_len):
+        """The block a prompt leaves pending (traced; a prefill or chunk
+        program's tail): the ``true_len % B`` tokens past its whole blocks,
+        read out of ``tokens`` [1, C] (which starts at position ``start``),
+        then the mask token."""
+        jax, jnp = self._jax, self._jnp
+        b = self._block_len
+        left = true_len % b
+        row = jax.lax.dynamic_slice_in_dim(
+            jnp.pad(tokens[0], (0, b)), true_len - left - start, b)
+        return jnp.where(jnp.arange(b) < left, row,
+                         self._cache_spec.mask_token).astype(jnp.int32)
 
     def _verify_impl(self, params, kv, pt_full, sl_full, toks_full, rng,
                      temps_full, idx, drafts):
@@ -583,11 +729,10 @@ class LLMEngine:
         per token, which is the speedup (decode is memory-bound).
 
         Rejected tail positions wrote junk KV past the accepted length;
-        the host rolls seq_lens back (dirty-slot patch), and because
-        decode positions are always >= the prompt length those writes land
-        in the slot's own suffix pages — never in shared prefix-cache
-        pages — and are overwritten before any later step can attend to
-        them. drafts: [W, k] int32 (-1 pads lanes/short drafts; -1 never
+        the host rolls seq_lens back (dirty-slot patch), and the junk is
+        harmless by kv_cache._span_step's junk-write rule (the block pass
+        of generation by diffusion over blocks relies on the same rule).
+        drafts: [W, k] int32 (-1 pads lanes/short drafts; -1 never
         equals a sampled token so padding can't be accepted, and junk
         from padded positions is causally invisible to earlier positions).
         Sampling uses one rng split for all positions — only greedy slots
@@ -632,7 +777,12 @@ class LLMEngine:
 
         top_k is the ENGINE's (static — per-request values would compile a
         new program per distinct k, each stalling the loop; decode already
-        uses the engine setting, see submit())."""
+        uses the engine setting, see submit()).
+
+        With a block length above 1 the program commits the prompt's whole
+        blocks, samples nothing (its logits mean nothing and are never
+        computed) and writes the slot's PENDING BLOCK (_pending_block) to
+        its row; the scalar it returns is a placeholder."""
         fn = self._prefill_cache.get(bucket)
         if fn is None:
             jax = self._jax
@@ -643,6 +793,9 @@ class LLMEngine:
                 logits, kv = self._kvc.paged_prefill(
                     params, kv, page_table, tokens, true_len,
                     self.model_cfg, self.cfg.page_size)
+                if self._block_len > 1:
+                    return true_len, toks_full.at[slot].set(
+                        self._pending_block(tokens, 0, true_len)), kv
                 tok = self._kvc.sample_tokens(
                     logits[None, :], rng, temp, top_k)
                 return tok[0], toks_full.at[slot].set(tok[0]), kv
@@ -671,6 +824,9 @@ class LLMEngine:
                     params, kv, page_table, tokens, start, true_len,
                     self.model_cfg, self.cfg.page_size,
                     self._attn_backend, mesh=self._mesh)
+                if self._block_len > 1:     # as _prefill_fn's
+                    return true_len, toks_full.at[slot].set(
+                        self._pending_block(tokens, start, true_len)), kv
                 tok = self._kvc.sample_tokens(
                     logits[None, :], rng, temp, top_k)
                 return tok[0], toks_full.at[slot].set(tok[0]), kv
@@ -701,9 +857,10 @@ class LLMEngine:
         # from the widths _step actually dispatches
         widths = sorted({self._bucket_width(n)
                          for n in range(1, self.cfg.max_batch_size + 1)})
-        tiers = {1, max(1, min(self.cfg.pressure_decode_block,
-                               self.cfg.decode_block)),
-                 self.cfg.decode_block}
+        tiers = {self._blocks_of(1),
+                 self._blocks_of(min(self.cfg.pressure_decode_block,
+                                     self.cfg.decode_block)),
+                 self._blocks_of(self.cfg.decode_block)}
         if self._spec_on:
             # the spec-capped idle tier (_select_block) dispatches too
             tiers.add(min(self.cfg.decode_block,
@@ -741,7 +898,8 @@ class LLMEngine:
             np.zeros((trash + 1,), np.int32),
             np.zeros((trash + 1,), np.float32))
         self._dev_tokens = self._patch_toks(
-            self._dev_tokens, didx, np.zeros((trash + 1,), np.int32))
+            self._dev_tokens, didx,
+            np.zeros(self._dev_tokens.shape, np.int32))
         # the key split of the first prefill (both halves dropped: the
         # loop's key is what it would be without this)
         self._split_key(self._rng)
@@ -1068,6 +1226,19 @@ class LLMEngine:
         return {"accepted": self._kv_tier.prefetch(list(digests), start)}
 
     def engine_stats(self) -> dict:
+        """Counters and gauges of this engine, one flat dict (README.md's
+        engine-telemetry table documents every key, and a test holds the
+        two together): ``self.stats`` (running totals: steps / passes,
+        prefills, tokens, prefix / tier / speculation / failover / disagg
+        counts, per-kernel dispatches, routed experts, what a block with
+        slot state or one that generates by diffusion over blocks is kept
+        out of: ``*_stateful`` / ``*_block``, and the latter's
+        ``block_passes_total``, ``denoise_passes_total``,
+        ``commit_passes_total``, ``slot_passes_total``,
+        ``blocks_committed_total``, ``tokens_cut_total``); occupancy
+        gauges; the profiler's ``phase_<p>_*``, compile and memory keys;
+        the attention backend, device and tensor-parallel surface; prefix
+        cache and tier gauges."""
         with self._lock:
             active = sum(1 for r in self.slot_req if r is not None)
             waiting = len(self._waiting)
@@ -1357,8 +1528,10 @@ class LLMEngine:
                 if self._prefix_cache_on:
                     matched = self.allocator.match_prefix(
                         req.prompt_tokens, self.cfg.page_size)
-                n_pages = -(-max(len(req.prompt_tokens) + req.max_tokens, 1)
-                            // self.cfg.page_size)
+                # (a block pass writes its whole block: to the block's edge)
+                reach = len(req.prompt_tokens) + req.max_tokens
+                reach += -reach % self._block_len
+                n_pages = -(-max(reach, 1) // self.cfg.page_size)
                 n_pages = min(n_pages, self.max_pages_per_seq)
                 pages = self.allocator.alloc(n_pages - len(matched))
                 if pages is None:
@@ -1382,6 +1555,15 @@ class LLMEngine:
                     self.stats["prefix_hit_tokens"] += req.cached_tokens
                 if self._stateful:
                     self._count_stateful_bypass(req)
+                if self._block_len > 1:
+                    # what the configuration asks for and a block that
+                    # carries a pending block does not take part in
+                    if self.cfg.spec_decode_enabled:
+                        self.stats["spec_bypassed_block"] += 1
+                    if self.cfg.kv_tier_enabled \
+                            and self.cfg.prefix_cache_enabled \
+                            and len(req.prompt_tokens) > self.cfg.page_size:
+                        self.stats["kv_tier_bypassed_block"] += 1
             # queue-wait phase sample (submit→admit), recorded OUTSIDE the
             # lock: the profiler observes a metrics histogram, which must
             # never run under the engine lock (graftlint lock-discipline)
@@ -1424,7 +1606,16 @@ class LLMEngine:
 
     def refuse_stateful(self, what: str) -> None:
         """Disaggregated handoff moves pages and a first token; a block
-        with slot state needs the state too, which no handoff carries."""
+        with slot state needs the state too, which no handoff carries, and
+        one that generates by diffusion over blocks a pending block and no
+        first token."""
+        if self._block_len > 1:
+            with self._lock:
+                self.stats["disagg_refused_block"] += 1
+            raise NotImplementedError(
+                f"{what}: the block generates by diffusion over blocks; a "
+                f"prefill leaves a pending block and no first token, which "
+                f"a handoff does not carry")
         if self._stateful:
             with self._lock:
                 self.stats["disagg_refused_stateful"] += 1
@@ -1893,16 +2084,23 @@ class LLMEngine:
         """Publish a freshly prefilled slot to the decode loop: host/device
         state patch and a harvest entry for the sampled first token
         ``tok_dev`` (which the prefill program has already written to the
-        slot's row of _dev_tokens: nothing to place here)."""
-        self._start_fetch(tok_dev)
+        slot's row of _dev_tokens: nothing to place here). With a block
+        length above 1 the prefill sampled nothing: the slot starts at the
+        prompt's whole blocks with its pending block in its row, and its
+        first tokens come with the first block dispatch."""
+        blocks = self._block_len > 1
+        if not blocks:
+            self._start_fetch(tok_dev)
+        kept = plen - plen % self._block_len
         with self._lock:
-            req.dispatched = 1
+            req.dispatched = 0 if blocks else 1
             self.page_tables[req.slot] = table
-            self.seq_lens[req.slot] = plen
+            self.seq_lens[req.slot] = kept
             self.slot_req[req.slot] = req
-            self._dirty_slots[req.slot] = (plen, req.temperature)
-            self._pending.append(
-                (tok_dev, [(0, req.slot, req)], 1, -1, None))
+            self._dirty_slots[req.slot] = (kept, req.temperature)
+            if not blocks:
+                self._pending.append(
+                    (tok_dev, [(0, req.slot, req)], 1, -1, None))
         if self._prefix_cache_on:
             # Index the prompt's FULL pages now (not at completion): the
             # writes are merely dispatched, but any matcher's reads are
@@ -2045,16 +2243,31 @@ class LLMEngine:
         (the head lands mid-block). Verify rounds are themselves k+1 fused
         steps, so speculation recovers the dispatch amortization the
         shorter blocks give up — and on non-repetitive traffic the cap is
-        the documented cost of leaving the flag on."""
+        the documented cost of leaving the flag on.
+
+        With a block length B above 1 the tiers count whole BLOCKS: the
+        configuration's token counts over B, at least one (_blocks_of)."""
         if self._admissions_blocked():
-            return 1
+            return self._blocks_of(1)
         if self._waiting:
-            return max(1, min(self.cfg.pressure_decode_block,
-                              self.cfg.decode_block))
-        k = self.cfg.decode_block
+            return self._blocks_of(min(self.cfg.pressure_decode_block,
+                                       self.cfg.decode_block))
+        k = self._blocks_of(self.cfg.decode_block)
         if self._spec_on:
             k = min(k, max(1, self.cfg.spec_draft_len))
         return k
+
+    def _blocks_of(self, tokens: int) -> int:
+        """A decode-block tier given in tokens as what a dispatch runs:
+        that many steps, or (block length B above 1) whole blocks."""
+        return max(1, tokens // self._block_len)
+
+    def _passes_of(self, k: int) -> int:
+        """Passes over the model that a dispatch of ``k`` runs: k steps, or
+        (block length above 1) k blocks of denoise passes and a commit."""
+        if self._block_len == 1:
+            return k
+        return k * (self.model_cfg.denoise_passes + 1)
 
     def _slot_index(self, slots, width: int):
         """``slots`` as the index vector of a fixed-shape program: int32
@@ -2140,7 +2353,14 @@ class LLMEngine:
         slot admissions patch them with one small jitted update). Block fusion
         brings the per-token dispatch cost to 1/decode_block of a
         dispatch; block size drops to 1 while admissions are pending so
-        new requests don't wait a whole block."""
+        new requests don't wait a whole block.
+
+        With a block length B above 1 the dispatch is ``k`` whole BLOCKS
+        (_block_impl): k x (denoise passes + 1) passes, k x B tokens a
+        slot, of which a slot's first block gives back the ``skip`` tokens
+        its prompt left in it (the harvest drops them); the span is
+        ``block_dispatch``."""
+        bl = self._block_len
         with self._lock:
             snapshot = [(i, i, req) for i, req in enumerate(self.slot_req)
                         if req is not None
@@ -2158,7 +2378,14 @@ class LLMEngine:
             # tokens in the cache of the block's slots as its first step
             # starts (what the device's seq_lens hold): the live context
             ctx_tokens = 0
+            skips = []
             for _col, _slot, req in snapshot:
+                if bl > 1:
+                    plen = len(req.prompt_tokens)
+                    ctx_tokens += (plen + req.dispatched) // bl * bl
+                    skips.append(0 if req.dispatched else plen % bl)
+                    req.dispatched += k * bl - skips[-1]
+                    continue
                 ctx_tokens += len(req.prompt_tokens) + req.dispatched - 1
                 req.dispatched += k
         # bucketed width: pack the active slots, pad with the trash row —
@@ -2174,14 +2401,17 @@ class LLMEngine:
         # the harvests the bound then forces (_step) — a trace says how
         # often, and how hard, the bound engages.
         inflight = len(self._pending)
-        with self._prof.span("decode_dispatch", seq=seq, k=k, w=w,
+        passes = self._passes_of(k)
+        how = {"blocks": k, "passes": passes} if bl > 1 else {"k": k}
+        with self._prof.span("block_dispatch" if bl > 1
+                             else "decode_dispatch", seq=seq, **how, w=w,
                              active=len(active_slots),
                              ctx_tokens=ctx_tokens, inflight=inflight,
                              trimmed=max(
                                  0, inflight + 1 - self.PIPELINE_DEPTH)):
             toks = self._flush_slot_patches(dirty, overrides)
             idx = self._slot_index(active_slots, w)
-            snapshot = [(col, slot, req)
+            snapshot = [(col, slot, req, *skips[col:col + 1])
                         for col, (_c, slot, req) in enumerate(snapshot)]
             with self._prof.compile_scope(
                     "decode", ("decode", w, k),
@@ -2195,7 +2425,7 @@ class LLMEngine:
             if dev_touched is not None:  # a routed block's count of experts
                 self._start_fetch(dev_touched)
             self._pending.append((all_toks, snapshot, k, seq, dev_touched))
-            self.stats["steps"] += k
+            self.stats["steps"] += passes
             self.stats["attn_decode_dispatches"] += 1
         return True
 
@@ -2390,6 +2620,10 @@ class LLMEngine:
         if isinstance(k, tuple):  # ("spec", draft_len) verify round
             self._apply_verify(dev_toks, snapshot, k[1], seq)
             return
+        # a block dispatch (rows of (col, slot, req, skip)): k blocks of B
+        # token rows each, run as ``passes`` passes
+        bl = self._block_len
+        passes = self._passes_of(k)
         # THE device sync: all device slowness (or a fetch that wasn't
         # prefetched) surfaces here, attributed as "harvest" instead of
         # smeared across the loop
@@ -2399,23 +2633,34 @@ class LLMEngine:
             # the same program as the tokens (no sync of its own)
             if dev_touched is not None:
                 touched = int(np.asarray(dev_touched))
-                layer_steps = k * self._cache_spec.routed_layers
+                layer_steps = passes * self._cache_spec.routed_layers
                 sp.set(experts_touched=touched)
                 self.stats["experts_touched_total"] += touched
                 self.stats["routed_layer_steps_total"] += layer_steps
                 self.stats["expert_rows_total"] += (
-                    layer_steps * len(snapshot) * self._cache_spec.top_k)
-        host_toks = host_toks.reshape(k, -1)
+                    layer_steps * len(snapshot) * bl
+                    * self._cache_spec.top_k)
+        if bl > 1:
+            self.stats["block_passes_total"] += passes
+            self.stats["commit_passes_total"] += k
+            self.stats["denoise_passes_total"] += passes - k
+            self.stats["slot_passes_total"] += passes * len(snapshot)
+            self.stats["blocks_committed_total"] += k * len(snapshot)
+        host_toks = host_toks.reshape(k * bl, -1)
         # emit: what follows the sync on the host — up to k x w
         # _record_token calls under the lock, then the completion tail
         with self._prof.span("emit", seq=seq) as sp:
             finished: list[_Request] = []
             tokens = 0
             with self._lock:
-                for step in range(k):
-                    for col, slot, req in snapshot:
+                for step in range(k * bl):
+                    for col, slot, req, *skip in snapshot:
+                        if skip and step < skip[0]:
+                            continue  # the prompt's own, left in its block
                         if req.done:
-                            continue  # stop/max lag: discard overshoot
+                            # stop/max lag: discard overshoot
+                            self.stats["tokens_cut_total"] += bl > 1
+                            continue
                         self._record_token(req, int(host_toks[step, col]))
                         tokens += 1
                         if req.done:

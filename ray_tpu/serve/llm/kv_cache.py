@@ -35,6 +35,11 @@ Design choices:
   dynamic shapes and `lax.cond`s;
 - full (non-chunked) prefill stays dense within the prompt: it runs at
   B=1 per admission with no cached prefix to read back;
+- a block that generates by diffusion over blocks (its cache spec states a
+  ``block_length`` above 1, models/block.py) runs the same programs under
+  the block mask (:func:`_visible`): prefill and the chunk program commit a
+  prompt's whole blocks, and :func:`paged_block_step` runs one pending
+  block's positions against the cache, committing or not;
 - tensor parallelism (ISSUE 20): every step function takes an optional
   ``mesh``. With a live "tensor" axis the pool is sharded per-KV-head
   (axis 1) and the q heads split into exactly the matching kv-head
@@ -644,6 +649,29 @@ def _ffn(x, kv, layer, cfg, ld):
     return x, kv
 
 
+def _block_len(cfg) -> int:
+    """1, or the block length of a block that generates by diffusion over
+    blocks (models/block.py)."""
+    return block_of(cfg).cache_spec(cfg).block_length
+
+
+def _visible(kpos, qpos, block_len: int):
+    """Whether the key at ``kpos`` is visible to the query at ``qpos``
+    (broadcast against each other): causal, or, with blocks of
+    ``block_len`` positions from 0, every key up to the end of the
+    query's own block."""
+    if block_len == 1:
+        return kpos <= qpos
+    return kpos < (qpos // block_len + 1) * block_len
+
+
+def _committed(true_len, block_len: int):
+    """How many of a prompt's ``true_len`` tokens a prefill keeps K / V
+    of: all of them, or its whole blocks (the rest start the pending
+    block, which the block pass commits once it is clean)."""
+    return true_len if block_len == 1 else true_len - true_len % block_len
+
+
 def _conv_mixer(x, kv, layer, cfg, ld, rows, fresh=None, n_real=None):
     """A mixer with slot state over x [B, T, D]. ``rows`` [B]: each
     sequence's state row (its first page). ``fresh``: true where the call
@@ -855,7 +883,8 @@ def paged_verify_step(params, kv, page_tables, seq_lens, tokens,
     single-query stock kernel deferred since PR 5). The gather backend
     materializes the [B, T, L] view — T times the decode fallback's
     traffic, bounded by small T (draft_len+1).
-    Returns (logits [B, T, vocab], new_kv, seq_lens + T).
+    Returns (logits [B, T, vocab], new_kv, seq_lens + T); what becomes of
+    the K / V of a rejected draft: :func:`_span_step`.
 
     A block with slot state has no verify program: a rejected draft's
     columns would have to be taken out of the state again, which pages and
@@ -865,6 +894,61 @@ def paged_verify_step(params, kv, page_tables, seq_lens, tokens,
         raise NotImplementedError(
             "speculative verify for a block with slot state: the state "
             "after a rejected draft cannot be rolled back")
+    x, kv = _span_step(params, kv, page_tables, seq_lens, tokens, cfg,
+                       page_size, attn_backend, mesh, block_len=1)
+    blk = block_of(cfg)
+    logits = blk.serve_lm_head(blk.serve_final_norm(x, params, cfg), params,
+                               cfg)                               # [B,T,V]
+    return logits, kv, seq_lens + tokens.shape[1]
+
+
+def paged_block_step(params, kv, page_tables, seq_lens, tokens,
+                     cfg, page_size: int,
+                     attn_backend: str = "gather", mesh=None, *,
+                     commit: bool):
+    """One pass over every slot's pending block (generation by diffusion
+    over blocks; ``block_length`` B of the block's cache spec).
+
+    tokens: [W, B], the block as it stands: known tokens, and the mask
+    token where none is revealed yet; seq_lens: [W] the positions
+    committed, a block edge. The B positions are computed together against
+    the cached blocks and the block itself (every position sees all B).
+    ``commit`` (static) false, a DENOISE pass: returns (logits [W, B,
+    vocab], new_kv, seq_lens); the logits at a masked position are the
+    distribution of the token that belongs there, and the K / V the pass
+    wrote is junk under :func:`_span_step`'s rule. True, the COMMIT pass
+    of a clean block: its K / V stay, no logits are computed, and it
+    returns (None, new_kv, seq_lens + B)."""
+    b = _block_len(cfg)
+    if tokens.shape[1] != b:
+        raise ValueError(f"a block pass takes blocks of {b} positions, got "
+                         f"{tokens.shape[1]}")
+    x, kv = _span_step(params, kv, page_tables, seq_lens, tokens, cfg,
+                       page_size, attn_backend, mesh, block_len=b)
+    if commit:
+        return None, kv, seq_lens + b
+    blk = block_of(cfg)
+    return blk.serve_lm_head(blk.serve_final_norm(x, params, cfg), params,
+                             cfg), kv, seq_lens
+
+
+def _span_step(params, kv, page_tables, seq_lens, tokens, cfg, page_size,
+               attn_backend, mesh, *, block_len: int):
+    """What speculative verify and the block pass share: T positions a
+    slot (tokens [B, T] at ``seq_lens[b] + t``) written to the slot's
+    pages and attended in one pass, causal inside the span
+    (``block_len`` 1) or every position seeing its whole block. Returns
+    (hidden states [B, T, D] before the final norm, new_kv).
+
+    THE JUNK-WRITE RULE. All T positions' K / V are written whether or not
+    the caller keeps them (a verify round's rejected drafts, a denoise
+    pass's masked positions): what is not kept lies at or past the length
+    the caller goes on with, in pages the slot owns alone (positions at or
+    past the prompt's: never a shared prefix page), and is overwritten
+    before anything can attend to it: by the next step that writes those
+    positions (the decode that follows a rollback, the commit pass that
+    follows the denoise passes), every one of which writes before it
+    reads."""
     blk = block_of(cfg)
     t = tokens.shape[1]
     max_len = page_tables.shape[1] * page_size
@@ -877,8 +961,9 @@ def paged_verify_step(params, kv, page_tables, seq_lens, tokens,
                                    axis=1)                        # [B,T]
     offset = pos % page_size
     kpos = jnp.arange(max_len)                                    # [L]
-    # position t sees cache + the span's tokens 0..t (its own write)
-    valid = kpos[None, None, :] <= pos[:, :, None]                # [B,T,L]
+    # position t sees cache + the span's tokens 0..t (its own write), or
+    # to the end of its block
+    valid = _visible(kpos[None, None, :], pos[:, :, None], block_len)
     sm = cfg.head_dim ** -0.5
 
     def step(x, kv, layer, ld, l):
@@ -891,8 +976,11 @@ def paged_verify_step(params, kv, page_tables, seq_lens, tokens,
         with jax.named_scope("attn"):
             if attn_backend == "pallas":
                 from ray_tpu.ops import paged_attention as paged_ops
+                kernel = paged_ops.paged_verify_attention if block_len == 1 \
+                    else functools.partial(paged_ops.paged_block_attention,
+                                           block_len=block_len)
                 attn = _paged_kernel(
-                    paged_ops.paged_verify_attention, q, k_pool, v_pool,
+                    kernel, q, k_pool, v_pool,
                     page_tables, seq_lens, l, sm_scale=sm, mesh=mesh)
             else:
                 attn = _dense_attention(
@@ -902,10 +990,7 @@ def paged_verify_step(params, kv, page_tables, seq_lens, tokens,
             x = x + blk.serve_attn_out(attn, layer)
         return _ffn(x, {**kv, "k": k_pool, "v": v_pool}, layer, cfg, ld)
 
-    x, kv = _over_layers(step, x, kv, params, cfg)
-    logits = blk.serve_lm_head(blk.serve_final_norm(x, params, cfg), params,
-                               cfg)                               # [B,T,V]
-    return logits, kv, seq_lens + t
+    return _over_layers(step, x, kv, params, cfg)
 
 
 @jax.named_scope("prefill")
@@ -918,19 +1003,25 @@ def paged_prefill(params, kv, page_table, tokens, true_len,
     [vocab], new_kv). Padding positions (>= true_len) write to the trash
     page via index clamping, so junk never lands in real pages. A layer
     with slot state starts from zeros and leaves the state as of position
-    ``true_len - 1`` in the sequence's row.
+    ``true_len - 1`` in the sequence's row. A block that generates by
+    diffusion over blocks attends under the block mask and keeps the K / V
+    of the prompt's whole blocks only (:func:`_committed`); its logits mean
+    nothing (models/block.py).
     """
     blk = block_of(cfg)
+    b = _block_len(cfg)
     t = tokens.shape[1]
     with jax.named_scope("embed"):
         x = blk.serve_embed(params, tokens, cfg)                  # [1,T,D]
         cos, sin = blk.rope_freqs(cfg, jnp.arange(t)[None, :])
     pos = jnp.arange(t)
-    in_range = pos < true_len
+    in_range = pos < _committed(true_len, b)
     page_idx = jnp.where(in_range, jnp.take(page_table, pos // page_size), 0)
     offset = pos % page_size
-    # causal mask for the in-prompt attention
-    causal = pos[:, None] >= pos[None, :]
+    # causal (or block) mask for the in-prompt attention (at 1 spelled as
+    # it was, so that the blocks served before lower to the same program)
+    causal = pos[:, None] >= pos[None, :] if b == 1 \
+        else _visible(pos[None, :], pos[:, None], b)
     sm = cfg.head_dim ** -0.5
 
     def step(x, kv, layer, ld, l):
@@ -980,8 +1071,12 @@ def paged_prefill_chunk(params, kv, page_table, tokens, start, true_len,
     with slot state reads the state its predecessor chunk left (zeros
     where ``start`` is 0) and leaves its own. Returns
     (last-token logits [vocab] — meaningful only on the final chunk, new_kv).
+    Under a block mask as :func:`paged_prefill`: ``start`` is a block edge,
+    and only the prompt's whole blocks are kept and attended to.
     """
     blk = block_of(cfg)
+    b = _block_len(cfg)
+    kept = _committed(true_len, b)
     c = tokens.shape[1]
     max_len = page_table.shape[0] * page_size
 
@@ -989,12 +1084,13 @@ def paged_prefill_chunk(params, kv, page_table, tokens, start, true_len,
     with jax.named_scope("embed"):
         x = blk.serve_embed(params, tokens, cfg)                  # [1,C,D]
         cos, sin = blk.rope_freqs(cfg, pos[None, :])
-    in_range = pos < true_len
+    in_range = pos < kept
     page_idx = jnp.where(in_range, jnp.take(page_table, pos // page_size), 0)
     offset = pos % page_size
     # keys: the whole paged view (earlier chunks + this one after write)
     kpos = jnp.arange(max_len)                                    # [L]
-    valid = (kpos[None, :] <= pos[:, None]) & (kpos[None, :] < true_len)
+    valid = _visible(kpos[None, :], pos[:, None], b) \
+        & (kpos[None, :] < kept)
     sm = cfg.head_dim ** -0.5
 
     def step(x, kv, layer, ld, l):
@@ -1017,8 +1113,9 @@ def paged_prefill_chunk(params, kv, page_table, tokens, start, true_len,
             if attn_backend == "pallas":
                 from ray_tpu.ops import paged_attention as paged_ops
                 attn = _paged_kernel(
-                    paged_ops.paged_chunk_attention, q, k_pool, v_pool,
-                    page_table, start, true_len, l, sm_scale=sm, mesh=mesh)
+                    functools.partial(paged_ops.paged_chunk_attention,
+                                      block_len=b), q, k_pool, v_pool,
+                    page_table, start, kept, l, sm_scale=sm, mesh=mesh)
             else:
                 attn = _dense_attention(
                     q, _gather_seq(k_pool, l, page_table, cfg.head_dim)[None],

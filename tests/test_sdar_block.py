@@ -1,0 +1,361 @@
+"""Generation by diffusion over blocks through the serving engine (ISSUE
+37: the SDAR-MoE block), on the CPU at the tiny preset in float32: the
+paged kernel's block mask (interpreted) against dense attention; every pass
+of the engine's programs against the plain float32 reference (whole
+prefill, chunked prefill, a denoise pass at every count of known tokens,
+the commit pass, through the cache); served streams equal to the
+reference's own generation token for token; the junk a denoise pass writes
+never readable; prefix reuse on and off; what such a block is kept out of,
+each with its counter. Nothing here is a device number.
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark import common
+from ray_tpu.models import sdar_moe
+from ray_tpu.ops import paged_attention as paged_ops
+from ray_tpu.parallel import expert
+from ray_tpu.serve.llm import LLMConfig, LLMEngine, disagg
+from ray_tpu.serve.llm import kv_cache as kvc
+
+CFG = sdar_moe.sdar_moe_tiny()
+B, MASK = CFG.block_length, CFG.mask_token_id
+REF = common.load_module("reference", "sdar_moe_f32")
+REF_KW = {"theta": CFG.rope_theta, "eps": CFG.norm_eps, "top_k": CFG.top_k,
+          "block": B, "mask": MASK, "denoise": CFG.denoise_passes}
+PAGE = 8
+ENGINE = dict(max_batch_size=4, page_size=PAGE, num_pages=64,
+              max_prompt_len=128, max_seq_len=192, prefill_chunk=32,
+              decode_block=8, pressure_decode_block=4, pipeline_depth=2,
+              attention_kernel="gather", warmup_compile=False)
+
+
+@pytest.fixture(scope="module")
+def params():
+    return sdar_moe.init_params(jax.random.PRNGKey(0), CFG)
+
+
+def _engine(cfg=CFG, **over):
+    eng = LLMEngine(LLMConfig(model_config=cfg, **{**ENGINE, **over}))
+    eng.start()
+    return eng
+
+
+def _prompt(seed: int, n: int) -> list[int]:
+    return [int(t) for t in np.random.RandomState(seed).randint(
+        0, 250, size=n)]
+
+
+def _serve(eng, prompts, max_tokens):
+    rids = [eng.submit(p, max_tokens=max_tokens, temperature=0.0)
+            for p in prompts]
+    outs = [eng.result(r, timeout=300.0) for r in rids]
+    assert all(o["error"] is None for o in outs), outs
+    return [[int(t) for t in o["tokens"]] for o in outs]
+
+
+# ---- the kernel's mask ---------------------------------------------------------
+
+@pytest.mark.parametrize("block_len", [1, 2, 4])
+def test_kernel_block_mask_against_dense_attention(block_len):
+    """The one Pallas kernel (interpreted) under ``block_len``: equal to
+    dense attention under the block mask; at 1 it IS today's causal kernel,
+    bit for bit."""
+    rs = np.random.RandomState(block_len)
+    slots, t, h, hkv, d, pages, mp = 3, 4, 4, 2, 16, 12, 3
+    q = jnp.asarray(rs.randn(slots, t, h, d), jnp.float32)
+    k_pool = jnp.asarray(rs.randn(1, hkv, pages, PAGE, d), jnp.float32)
+    v_pool = jnp.asarray(rs.randn(1, hkv, pages, PAGE, d), jnp.float32)
+    tables = jnp.asarray(1 + rs.permutation(pages - 1)[:slots * mp].reshape(
+        slots, mp), jnp.int32)
+    lens = jnp.asarray([4, 8, 12], jnp.int32)       # block edges for 1, 2, 4
+    got = paged_ops.paged_block_attention(
+        q, k_pool, v_pool, tables, lens, 0, block_len=block_len,
+        interpret=True)
+    pos = lens[:, None] + jnp.arange(t)[None, :]
+    kpos = jnp.arange(mp * PAGE)
+    valid = kvc._visible(kpos[None, None, :], pos[:, :, None], block_len)
+    want = kvc._dense_attention(
+        q, kvc._gather_seq(k_pool, 0, tables), kvc._gather_seq(v_pool, 0,
+                                                               tables),
+        valid[:, None], d ** -0.5)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-6)
+    if block_len == 1:
+        causal = paged_ops.paged_verify_attention(
+            q, k_pool, v_pool, tables, lens, 0, interpret=True)
+        assert np.array_equal(np.asarray(got), np.asarray(causal))
+    else:   # the last position of a block sees no more than the first does
+        assert not np.allclose(np.asarray(got), np.asarray(
+            paged_ops.paged_verify_attention(q, k_pool, v_pool, tables, lens,
+                                             0, interpret=True)))
+
+
+def test_pallas_and_gather_backends_give_one_block_pass(params):
+    kv = kvc.init_paged_cache(CFG, 16, PAGE)
+    tables = jnp.asarray([[1, 2, 3], [4, 5, 6]], jnp.int32)
+    lens = jnp.asarray([8, 12], jnp.int32)
+    blocks = jnp.asarray([[5, 6, MASK, MASK], [MASK] * 4], jnp.int32)
+    out = [kvc.paged_block_step(params, kv, tables, lens, blocks, CFG, PAGE,
+                                backend, commit=False)[0]
+           for backend in ("gather", "pallas")]
+    np.testing.assert_allclose(np.asarray(out[0]), np.asarray(out[1]),
+                               atol=1e-5)
+
+
+# ---- every pass against the reference --------------------------------------------
+
+def _reference_block_logits(params, clean, block, g):
+    """The reference's logits [B, V] for the noisy ``block`` standing for
+    block ``g`` after the clean tokens."""
+    _, xn, _, _ = REF._forward(params, np.asarray(clean, np.int32),
+                               np.asarray(block, np.int32)[None],
+                               np.asarray([g], np.int32), **REF_KW)
+    with jax.default_matmul_precision("highest"):
+        return np.asarray(REF._head(xn[0], params["final_norm"],
+                                    params["lm_head"], CFG.norm_eps))
+
+
+def _prefilled(params, prompt, chunked: bool):
+    """The cache after the engine's prompt programs, and the table."""
+    kv = kvc.init_paged_cache(CFG, 32, PAGE)
+    table = jnp.asarray(1 + np.arange(24), jnp.int32)
+    n = len(prompt)
+    if not chunked:
+        toks = np.zeros((1, 128), np.int32)
+        toks[0, :n] = prompt
+        return kvc.paged_prefill(params, kv, table, jnp.asarray(toks),
+                                 jnp.int32(n), CFG, PAGE)[1], table
+    for start in range(0, n, 32):
+        toks = np.zeros((1, 32), np.int32)
+        seg = prompt[start:start + 32]
+        toks[0, :len(seg)] = seg
+        kv = kvc.paged_prefill_chunk(
+            params, kv, table, jnp.asarray(toks), jnp.int32(start),
+            jnp.int32(n), CFG, PAGE)[1]
+    return kv, table
+
+
+@pytest.mark.parametrize("known", [0, 1, 2, 3])
+@pytest.mark.parametrize("chunked", [False, True],
+                         ids=["whole_prefill", "chunked_prefill"])
+def test_each_pass_is_the_references(params, known, chunked):
+    """A prompt that leaves ``known`` tokens in its pending block: the
+    denoise pass over that block, the commit pass once it is full, and a
+    denoise pass of the NEXT block (which reads what the commit wrote),
+    each against the plain reference at 1e-5."""
+    prompt = _prompt(known, 72 + known)
+    kept = len(prompt) - known
+    kv, table = _prefilled(params, prompt, chunked)
+    block = np.asarray(prompt[kept:] + [MASK] * (B - known), np.int32)
+    lens = jnp.asarray([kept], jnp.int32)
+    logits, kv, same = kvc.paged_block_step(
+        params, kv, table[None], lens, jnp.asarray(block)[None], CFG, PAGE,
+        commit=False)
+    assert int(same[0]) == kept
+    want = _reference_block_logits(params, prompt[:kept] + [0] * B, block,
+                                   kept // B)
+    np.testing.assert_allclose(np.asarray(logits[0]), want, atol=1e-5)
+    full = np.where(block == MASK, np.argmax(want, -1), block).astype(
+        np.int32)
+    none, kv, after = kvc.paged_block_step(
+        params, kv, table[None], lens, jnp.asarray(full)[None], CFG, PAGE,
+        commit=True)
+    assert none is None and int(after[0]) == kept + B
+    fresh = np.full((B,), MASK, np.int32)
+    logits, _, _ = kvc.paged_block_step(
+        params, kv, table[None], after, jnp.asarray(fresh)[None], CFG, PAGE,
+        commit=False)
+    want = _reference_block_logits(
+        params, prompt[:kept] + [int(t) for t in full] + [0] * B, fresh,
+        kept // B + 1)
+    np.testing.assert_allclose(np.asarray(logits[0]), want, atol=1e-5)
+
+
+def test_a_denoise_pass_leaves_nothing_readable(params):
+    """The K / V a denoise pass writes is junk (kv_cache._span_step's
+    rule): poisoned before the commit pass, it changes nothing after."""
+    prompt = _prompt(7, 40)
+    kv, table = _prefilled(params, prompt, False)
+    lens = jnp.asarray([40], jnp.int32)
+    masked = jnp.full((1, B), MASK, jnp.int32)
+    clean = jnp.asarray([[3, 1, 4, 1]], jnp.int32)
+
+    def rest(kv):
+        _, kv, after = kvc.paged_block_step(
+            params, kv, table[None], lens, clean, CFG, PAGE, commit=True)
+        return np.asarray(kvc.paged_block_step(
+            params, kv, table[None], after, masked, CFG, PAGE,
+            commit=False)[0])
+
+    _, kv, _ = kvc.paged_block_step(params, kv, table[None], lens, masked,
+                                    CFG, PAGE, commit=False)
+    page, off = int(table[40 // PAGE]), 40 % PAGE
+    written = np.asarray(kv["k"][:, :, page, off:off + B])
+    assert np.abs(written).max() > 0                 # the pass did write
+    poisoned = {**kv, "k": kv["k"].at[:, :, page, off:off + B].set(1e4),
+                "v": kv["v"].at[:, :, page, off:off + B].set(-1e4)}
+    assert np.array_equal(rest(kv), rest(poisoned))
+
+
+# ---- served streams against the reference's own generation ----------------------
+
+@pytest.mark.parametrize("left", [0, 1, 2, 3])
+def test_streams_are_the_references_for_every_prompt_remainder(params, left):
+    """Prompts that leave 0-3 tokens in their first block (one by a whole
+    prefill, one by chunks), side by side."""
+    prompts = [_prompt(10 + left, 20 + left), _prompt(20 + left, 72 + left)]
+    eng = _engine()
+    try:
+        got = _serve(eng, prompts, 12)
+    finally:
+        eng.shutdown()
+    for p, g in zip(prompts, got):
+        assert g == REF.generate(params, p, 12, **REF_KW)[0]
+
+
+@pytest.mark.parametrize("max_tokens", [9, 10, 11, 12])
+def test_streams_are_the_references_for_every_cut(params, max_tokens):
+    """``max_tokens`` of every remainder: the last block is revealed whole
+    and cut, and the cut tokens are counted."""
+    prompt = _prompt(max_tokens, 24)
+    eng = _engine()
+    try:
+        got = _serve(eng, [prompt], max_tokens)[0]
+        st = eng.engine_stats()
+    finally:
+        eng.shutdown()
+    assert got == REF.generate(params, prompt, max_tokens, **REF_KW)[0]
+    assert len(got) == max_tokens
+    assert st["tokens_cut_total"] >= -max_tokens % B
+    assert st["tokens_out"] == max_tokens
+
+
+def test_a_stop_token_inside_a_block_cuts_it(params):
+    prompt = _prompt(3, 21)
+    whole = REF.generate(params, prompt, 16, **REF_KW)[0]
+    # a token first served in the middle of a block (prompt leaves 1)
+    at = next(i for i, t in enumerate(whole)
+              if (21 + i) % B in (1, 2) and t not in whole[:i])
+    eng = _engine()
+    try:
+        eng.tokenizer.eos_token_id = whole[at]
+        got = _serve(eng, [prompt], 16)[0]
+        cut = eng.engine_stats()["tokens_cut_total"]
+    finally:
+        eng.shutdown()
+    assert got == whole[:at] and cut >= 1
+    assert got == REF.generate(params, prompt, 16, stop=whole[at],
+                               **REF_KW)[0]
+
+
+def test_prefix_reuse_on_and_off_give_the_same_tokens():
+    """Two prompts that share 256 tokens (32 pages): the second takes the
+    first's pages and prefills only its tail, by the chunk program, from a
+    block edge."""
+    cfg = sdar_moe.sdar_moe_tiny(max_seq_len=384)
+    shared = _prompt(1, 256)
+    prompts = [shared + _prompt(2, 9), shared + _prompt(3, 14)]
+    out = {}
+    for on in (True, False):
+        eng = _engine(cfg, max_prompt_len=320, max_seq_len=384, num_pages=128,
+                      prefix_cache_enabled=on)
+        try:
+            out[on] = [_serve(eng, [p], 10)[0] for p in prompts]
+            st = eng.engine_stats()
+        finally:
+            eng.shutdown()
+        assert (st["prefix_hits"] >= 1) == on
+        assert (st["prefix_hit_tokens"] >= 256) == on
+    assert out[True] == out[False]
+
+
+# ---- counters, and what the block is kept out of ---------------------------------
+
+def test_passes_and_blocks_are_counted(params):
+    """16 tokens after a prompt on a block edge: two dispatches of two
+    blocks, six passes each, nothing cut: 0.75 passes a token."""
+    eng = _engine()
+    try:
+        _serve(eng, [_prompt(5, 24)], 16)
+        st = eng.engine_stats()
+    finally:
+        eng.shutdown()
+    assert st["tokens_out"] == 16 and st["tokens_cut_total"] == 0
+    assert st["block_passes_total"] == st["steps"] == 12
+    assert (st["denoise_passes_total"], st["commit_passes_total"]) == (8, 4)
+    assert st["slot_passes_total"] / st["tokens_out"] == 0.75
+    assert st["blocks_committed_total"] == 4
+    layers = CFG.n_layers
+    assert st["routed_layer_steps_total"] == 12 * layers
+    assert st["expert_rows_total"] == 12 * layers * B * CFG.top_k
+    assert 0 < st["experts_touched_total"] <= 12 * layers * CFG.n_experts
+    assert st["prefills"] == 1 and st["phase_block_dispatch_n"] == 2
+    assert st["phase_decode_dispatch_n"] == 0
+
+
+def test_speculation_and_the_tier_are_bypassed_and_counted(params, tmp_path):
+    eng = _engine(spec_decode_enabled=True, kv_tier_enabled=True,
+                  kv_tier_disk_dir=str(tmp_path))
+    try:
+        prompt = _prompt(9, 30)
+        got = _serve(eng, [prompt], 8)[0]
+        st = eng.engine_stats()
+    finally:
+        eng.shutdown()
+    assert got == REF.generate(params, prompt, 8, **REF_KW)[0]
+    assert st["spec_bypassed_block"] == 1 and st["spec_rounds"] == 0
+    assert st["kv_tier_bypassed_block"] == 1 and st["spilled_pages"] == 0
+
+
+def test_disaggregated_handoff_is_refused_and_counted():
+    eng = LLMEngine(LLMConfig(model_config=CFG, **ENGINE))
+    with pytest.raises(NotImplementedError, match="pending block"):
+        disagg.prefill_only(eng, _prompt(40, 20))
+    assert eng.engine_stats()["disagg_refused_block"] == 1
+    dec = disagg.DecodeEngine(LLMConfig(model_config=CFG, **ENGINE))
+    with pytest.raises(NotImplementedError):
+        dec.submit_prefilled({})
+    assert dec.stats["disagg_refused_block"] == 1
+
+
+@pytest.mark.parametrize("field", ["page_size", "prefill_chunk",
+                                   "max_seq_len"])
+def test_edges_must_be_block_edges(field):
+    with pytest.raises(ValueError, match="block length"):
+        LLMEngine(LLMConfig(model_config=CFG, **{**ENGINE, field: 30}))
+
+
+def test_block_and_tensor_parallel_are_the_blocks_to_state():
+    spec = sdar_moe.cache_spec(CFG)
+    assert (spec.block_length, spec.mask_token) == (B, MASK)
+    assert spec.routed_layers == spec.paged_layers == CFG.n_layers
+    assert [ld.routed_layer for ld in sdar_moe.serve_layers(CFG)] \
+        == list(range(CFG.n_layers))                     # walked
+    assert CFG.reveal_per_pass == 2
+    with pytest.raises(ValueError, match="tensor-parallel"):
+        sdar_moe.check_tp_divides(CFG, 2)
+    with pytest.raises(NotImplementedError):
+        sdar_moe.load_params("x", CFG)
+    full = sdar_moe.SdarMoeConfig(n_layers=7)
+    assert sdar_moe.num_params(full) == 4_984_176_384    # 9.97 GB in bf16
+
+
+# ---- the softmax router ----------------------------------------------------------
+
+@pytest.mark.parametrize("norm", [True, False])
+def test_softmax_router_takes_the_most_probable_and_renormalises(norm):
+    rs = np.random.RandomState(0)
+    g = jnp.asarray(rs.randn(6, 16), jnp.float32)
+    router = jnp.asarray(rs.randn(16, 8), jnp.float32)
+    idx, w = expert.route_softmax_top_k(g, router, 3, norm_topk_prob=norm)
+    p = np.asarray(jax.nn.softmax(g @ router, axis=-1))
+    want = np.argsort(-p, axis=-1)[:, :3]
+    assert np.array_equal(np.asarray(idx), want)
+    taken = np.take_along_axis(p, want, axis=-1)
+    if norm:
+        taken = taken / taken.sum(-1, keepdims=True)
+        np.testing.assert_allclose(np.asarray(w).sum(-1), 1.0, atol=1e-6)
+    np.testing.assert_allclose(np.asarray(w), taken, atol=1e-6)
