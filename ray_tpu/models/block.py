@@ -39,7 +39,8 @@ start the slot's pending block, the rest of which holds ``mask_token``.
 ``kv_cache.paged_block_step`` then runs a pending block's B positions
 against the cache, S times without keeping K / V (the engine reveals B / S
 masked positions after each, by confidence) and once more, clean, to
-commit it. The logits AT a masked position are the distribution of the
+commit it; the engine runs that last pass and the next block's first as
+one (``kv_cache.paged_block_pair_step``). The logits AT a masked position are the distribution of the
 token that belongs there. ``serve_lm_head`` of such a block is only called
 on denoise passes.
 """

@@ -25,8 +25,10 @@ embedding). RMSNorm ``w * x / sqrt(mean(x^2) + eps)``.
   against the cached blocks and itself, keeps no K / V, and reveals the
   ``reveal_per_pass`` masked positions whose best token is most probable
   (greedy at temperature 0; the mask token's logit is left out: it is never
-  produced); after ``denoise_passes`` passes no mask is left and a commit
-  pass runs the clean block once more and keeps its K / V. The logits AT a
+  produced); after ``denoise_passes`` passes no mask is left, and the clean
+  block's K / V are kept by the pass that runs it once more: the first
+  denoise pass of the NEXT block, over both blocks at once (the deferred
+  commit; a stream's last block is never committed). The logits AT a
   masked position are the distribution of the token that belongs there.
 
 Parameters are a LIST of layers, each weight its own array, and the paged

@@ -346,9 +346,11 @@ def paged_block_attention(q, k_pages, v_pages, page_tables, seq_lens,
                           sm_scale: float | None = None,
                           interpret: bool | None = None):
     """The block pass of generation by diffusion over blocks: q [B, T, H, D]
-    with T = ``block_len``, q[b, t] at position ``seq_lens[b] + t`` (a
-    block edge), every position seeing the slot's cached pages and the
-    whole block (whose k/v are pre-written). Returns [B, T, H, D]."""
+    with T a whole number of blocks of ``block_len`` (one, or two for the
+    pass that keeps a block and denoises the next), q[b, t] at position
+    ``seq_lens[b] + t`` (a block edge), every position seeing the slot's
+    cached pages and the span up to the end of its own block (whose k/v
+    are pre-written). Returns [B, T, H, D]."""
     return paged_attention(q, k_pages, v_pages, page_tables, seq_lens,
                            layer=layer, sm_scale=sm_scale,
                            interpret=interpret,

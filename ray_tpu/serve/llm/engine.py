@@ -194,9 +194,11 @@ class LLMEngine:
         self._stateful = kvc.has_slot_state(self.model_cfg)
         # a block that generates by diffusion over blocks (block_length B
         # above 1): a slot carries a pending block of B tokens across
-        # dispatches, a dispatch runs whole blocks (denoise passes, then
-        # the commit pass) and yields B tokens a slot and block, a prefill
-        # yields none. Speculation has no meaning for it; the kv tier and
+        # dispatches, a dispatch runs whole blocks (the denoise passes,
+        # the first of which also keeps the block before: _block_impl) and
+        # yields B tokens a slot and block, a prefill yields none; the
+        # device's seq_lens lag a clean pending block behind the tokens
+        # given out. Speculation has no meaning for it; the kv tier and
         # disaggregated hand-off move pages and ONE token, not a pending
         # block: each is turned off or refused, and counted
         # (<x>_bypassed_block, disagg_refused_block)
@@ -232,12 +234,16 @@ class LLMEngine:
 
         b = cfg.max_batch_size
         self.max_pages_per_seq = -(-cfg.max_seq_len // cfg.page_size)
-        if self._cache_spec.routed_layers and b > self.model_cfg.max_seq_len:
+        # (rows a slot of the widest pass: a token, or two blocks)
+        rows = b * (2 * self._block_len if self._block_len > 1 else 1)
+        if self._cache_spec.routed_layers \
+                and rows > self.model_cfg.max_seq_len:
             # the routing record holds a call's rows, max_seq_len of them
             raise ValueError(
-                f"max_batch_size={b} exceeds the model's max_seq_len="
-                f"{self.model_cfg.max_seq_len}: a routed block records "
-                f"its choice of experts for at most max_seq_len rows a call")
+                f"max_batch_size={b} ({rows} rows a pass) exceeds the "
+                f"model's max_seq_len={self.model_cfg.max_seq_len}: a "
+                f"routed block records its choice of experts for at most "
+                f"max_seq_len rows a call")
         self.kv = kvc.init_paged_cache(
             self.model_cfg, cfg.num_pages, cfg.page_size, self._tp)
         # Tensor parallelism (ISSUE 20): one engine process drives a
@@ -346,12 +352,16 @@ class LLMEngine:
                       # generation by diffusion over blocks (0 for a block
                       # a step of which yields a token), over the block
                       # dispatches harvested: passes run (``steps`` counts
-                      # them too), of them denoise and commit passes, live
-                      # slots summed over passes, blocks committed (slots
-                      # x blocks), tokens revealed and discarded past a
-                      # stop token or max_tokens; and what such a block is
-                      # kept out of
+                      # them too; a pass of two blocks is ONE), of them
+                      # passes that denoise, passes that kept a block AND
+                      # denoised the next (counted on the device), commits
+                      # that ran alone (none since the commit is deferred),
+                      # live slots summed over passes, blocks committed
+                      # (slots x blocks, counted on the device), tokens
+                      # revealed and discarded past a stop token or
+                      # max_tokens; and what such a block is kept out of
                       "block_passes_total": 0, "denoise_passes_total": 0,
+                      "fused_passes_total": 0,
                       "commit_passes_total": 0, "slot_passes_total": 0,
                       "blocks_committed_total": 0, "tokens_cut_total": 0,
                       "spec_bypassed_block": 0,
@@ -413,7 +423,8 @@ class LLMEngine:
         # numbers decode and verify blocks (the same in a block's dispatch
         # and harvest spans), -1 for a prefill's first token; dev_touched:
         # the device count of experts a decode block's steps touched (a
-        # block with routed experts), None otherwise
+        # block with routed experts; a block program's counts, of which
+        # that is the first), None otherwise
         self._pending: list = []
         self._block_seq = 0
         # the token every slot's next step consumes: [B+1] on device (row b
@@ -465,7 +476,9 @@ class LLMEngine:
         # per (bucket width, block length), so a lightly loaded engine pays
         # for the requests it has, not for max_batch_size.
         # With a block length above 1 the same slot in the loop holds the
-        # block program (_block_impl: n whole blocks a dispatch).
+        # block program (_block_impl: n whole blocks a dispatch), whose
+        # last output is its counts (experts touched, fused passes, blocks
+        # kept) where a routed decode block's is its experts touched.
         decode_impl = self._block_impl if self._block_len > 1 \
             else self._decode_impl
         self._decode = jax.jit(
@@ -620,28 +633,50 @@ class LLMEngine:
         n): ``num_blocks`` whole blocks in ONE program, at the packed
         width W = ``len(idx)``, as _decode_impl is ``num_steps`` steps.
 
-        For each block: S denoise passes (kv_cache.paged_block_step without
-        commit, the head over the B positions, then ``unmask``: of the
-        positions that still hold the mask token, the n whose sampled
-        token is most probable take that token; greedy at temperature 0;
-        the mask token's own logit is left out, it is never produced) and
-        one commit pass of the now clean block, which keeps its K / V and
-        advances the slot by B; the next block starts all masked.
-        ``toks_full`` [B+1 rows, B]: every slot's pending block. Returns
-        the blocks' tokens as [num_blocks x B, W] (row j x B + i: position i
-        of block j, known tokens included: the host skips the ones a
-        prompt left), the carried state, and a routed block's count of
-        experts touched over all passes."""
+        A block costs S passes: THE COMMIT IS DEFERRED. A scan iteration
+        is one pass over 2B positions a slot (kv_cache.
+        paged_block_pair_step: the slot's pending block, then an
+        all-masked one) and S - 1 denoise passes of B
+        (kv_cache.paged_block_step without commit), and yields one CLEAN
+        block a slot, which stays the slot's pending block in
+        ``toks_full``, uncommitted, its tokens already on their way to the
+        host. What the pass of 2B is for a slot is read from the slot's
+        pending block, inside the program:
+
+        * clean (no position holds the mask token: every slot after its
+          first iteration): the pass keeps that block's K / V (the slot
+          advances by B) and is the first denoise pass of the next block,
+          which sees exactly the cache a commit pass would have left;
+        * not clean (what a prefill or chunk program left: the prompt's
+          ``true_len % B`` tokens, then masks): the pass denoises that
+          block as a pass of B would, keeps nothing, and its second half
+          is filler (its K / V junk, its hidden states dropped).
+
+        Every denoise pass ends in the head over B positions a slot and
+        ``unmask``: of the positions that still hold the mask token, the n
+        whose sampled token is most probable take that token; greedy at
+        temperature 0; the mask token's own logit is left out, it is never
+        produced. The last block of a stream is never committed: nothing
+        reads it. ``toks_full`` [B+1 rows, B]: every slot's pending block.
+        Returns the blocks' tokens as [num_blocks x B, W] (row j x B + i:
+        position i of block j, known tokens included: the host skips the
+        ones a prompt left), the carried state, and the program's counts,
+        int32 [3]: experts touched over all passes (0 without routed
+        experts), passes of 2B in which a live slot kept a block and
+        denoised the next, and blocks kept (live slots summed)."""
         jax = self._jax
         jnp = self._jnp
         mcfg = self.model_cfg
         b, mask_id = self._block_len, self._cache_spec.mask_token
         n_reveal = mcfg.reveal_per_pass
+        trash = self.cfg.max_batch_size
 
-        def block_pass(kv_c, lens, blk, commit):
-            return self._kvc.paged_block_step(
-                params, kv_c, pt, lens, blk, mcfg, self.cfg.page_size,
-                self._attn_backend, mesh=self._mesh, commit=commit)
+        how = dict(cfg=mcfg, page_size=self.cfg.page_size,
+                   attn_backend=self._attn_backend, mesh=self._mesh)
+
+        def touched(kv_c, rows_a_slot):
+            n = self._experts_touched(kv_c, idx, rows_a_slot)
+            return jnp.int32(0) if n is None else n
 
         def unmask(logits, blk, key):
             """blk [W, B] with n more of its masked positions revealed."""
@@ -670,37 +705,46 @@ class LLMEngine:
                 lens0 = sl_full[idx]
                 blk0 = toks_full[idx]
                 temps = temps_full[idx]
+            live = idx != trash
 
             @jax.named_scope("block_step")
             def one(carry, _):
                 kv_c, lens, blk, key = carry
-                touched = []
-                for _s in range(mcfg.denoise_passes):
+                fresh = jnp.full_like(blk, mask_id)
+                key, sub = jax.random.split(key)
+                with jax.named_scope("fused"):
+                    logits, kv_c, lens, kept = \
+                        self._kvc.paged_block_pair_step(
+                            params, kv_c, pt, lens,
+                            jnp.concatenate([blk, fresh], axis=1), **how)
+                    with jax.named_scope("unmask"):
+                        blk = unmask(
+                            logits, jnp.where(kept[:, None], fresh, blk),
+                            sub)
+                    n_touched = touched(kv_c, 2 * b)
+                for _s in range(mcfg.denoise_passes - 1):
                     key, sub = jax.random.split(key)
                     with jax.named_scope("denoise"):
-                        logits, kv_c, _ = block_pass(kv_c, lens, blk, False)
+                        logits, kv_c, _ = self._kvc.paged_block_step(
+                            params, kv_c, pt, lens, blk, **how,
+                            commit=False)
                         with jax.named_scope("unmask"):
                             blk = unmask(logits, blk, sub)
-                        touched.append(self._experts_touched(kv_c, idx, b))
-                with jax.named_scope("commit"):
-                    _, kv_c, lens = block_pass(kv_c, lens, blk, True)
-                    touched.append(self._experts_touched(kv_c, idx, b))
-                fresh = jnp.full_like(blk, mask_id)
-                return (kv_c, lens, fresh, key), (
-                    blk, None if touched[0] is None else sum(touched))
+                        n_touched += touched(kv_c, b)
+                kept = kept & live
+                return (kv_c, lens, blk, key), (blk, jnp.stack(
+                    [n_touched, jnp.any(kept).astype(jnp.int32),
+                     jnp.sum(kept, dtype=jnp.int32)]))
 
-            (kv, new_lens, last, rng), (blocks, touched) = jax.lax.scan(
+            (kv, new_lens, last, rng), (blocks, counts) = jax.lax.scan(
                 one, (kv, lens0, blk0, rng), None, length=num_blocks)
             all_toks = jnp.swapaxes(blocks, 1, 2).reshape(
                 num_blocks * b, -1)                           # [n x B, W]
-            trash = self.cfg.max_batch_size
             with jax.named_scope("scatter_state"):
                 sl_full = sl_full.at[idx].set(
-                    jnp.where(idx == trash, 0, new_lens))
+                    jnp.where(live, new_lens, 0))
                 toks_full = toks_full.at[idx].set(last)
-        if touched is not None:
-            return all_toks, toks_full, kv, sl_full, rng, jnp.sum(touched)
-        return all_toks, toks_full, kv, sl_full, rng
+        return all_toks, toks_full, kv, sl_full, rng, jnp.sum(counts, axis=0)
 
     def _pending_block(self, tokens, start, true_len):
         """The block a prompt leaves pending (traced; a prefill or chunk
@@ -1233,9 +1277,12 @@ class LLMEngine:
         counts, per-kernel dispatches, routed experts, what a block with
         slot state or one that generates by diffusion over blocks is kept
         out of: ``*_stateful`` / ``*_block``, and the latter's
-        ``block_passes_total``, ``denoise_passes_total``,
-        ``commit_passes_total``, ``slot_passes_total``,
-        ``blocks_committed_total``, ``tokens_cut_total``); occupancy
+        ``block_passes_total`` (a pass of two blocks counts once),
+        ``denoise_passes_total``, ``fused_passes_total`` (passes that kept
+        a block and denoised the next), ``commit_passes_total`` (commits
+        that ran alone: 0 since the commit is deferred),
+        ``slot_passes_total``, ``blocks_committed_total``,
+        ``tokens_cut_total``); occupancy
         gauges; the profiler's ``phase_<p>_*``, compile and memory keys;
         the attention backend, device and tensor-parallel surface; prefix
         cache and tier gauges."""
@@ -1528,7 +1575,11 @@ class LLMEngine:
                 if self._prefix_cache_on:
                     matched = self.allocator.match_prefix(
                         req.prompt_tokens, self.cfg.page_size)
-                # (a block pass writes its whole block: to the block's edge)
+                # (a block pass writes whole blocks: to the block's edge.
+                # The pass of two blocks reaches one block further, with
+                # K / V nothing ever reads: into the slot's last page, or
+                # the trash page, which table entries past the slot's
+                # pages and positions past the table's width name)
                 reach = len(req.prompt_tokens) + req.max_tokens
                 reach += -reach % self._block_len
                 n_pages = -(-max(reach, 1) // self.cfg.page_size)
@@ -2086,8 +2137,9 @@ class LLMEngine:
         ``tok_dev`` (which the prefill program has already written to the
         slot's row of _dev_tokens: nothing to place here). With a block
         length above 1 the prefill sampled nothing: the slot starts at the
-        prompt's whole blocks with its pending block in its row, and its
-        first tokens come with the first block dispatch."""
+        prompt's whole blocks with its pending block (not clean: it holds
+        a mask) in its row, and its first tokens come with the first block
+        dispatch."""
         blocks = self._block_len > 1
         if not blocks:
             self._start_fetch(tok_dev)
@@ -2264,10 +2316,13 @@ class LLMEngine:
 
     def _passes_of(self, k: int) -> int:
         """Passes over the model that a dispatch of ``k`` runs: k steps, or
-        (block length above 1) k blocks of denoise passes and a commit."""
+        (block length above 1) k blocks of ``denoise_passes`` passes each,
+        the first of them over two blocks (it keeps the block before):
+        ONE pass, one read of the weights, one call of each kernel a
+        layer."""
         if self._block_len == 1:
             return k
-        return k * (self.model_cfg.denoise_passes + 1)
+        return k * self.model_cfg.denoise_passes
 
     def _slot_index(self, slots, width: int):
         """``slots`` as the index vector of a fixed-shape program: int32
@@ -2356,10 +2411,15 @@ class LLMEngine:
         new requests don't wait a whole block.
 
         With a block length B above 1 the dispatch is ``k`` whole BLOCKS
-        (_block_impl): k x (denoise passes + 1) passes, k x B tokens a
+        (_block_impl): k x ``denoise_passes`` passes, k x B tokens a
         slot, of which a slot's first block gives back the ``skip`` tokens
         its prompt left in it (the harvest drops them); the span is
-        ``block_dispatch``."""
+        ``block_dispatch``. The device commits a block with the first
+        pass of the NEXT one, so its seq_lens stay one block behind the
+        tokens given out from a slot's second block on (``ctx_tokens``
+        says what the device holds; ``fused``: the passes of two blocks
+        that keep a block for some slot, all but a first iteration whose
+        slots are all new)."""
         bl = self._block_len
         with self._lock:
             snapshot = [(i, i, req) for i, req in enumerate(self.slot_req)
@@ -2379,10 +2439,14 @@ class LLMEngine:
             # starts (what the device's seq_lens hold): the live context
             ctx_tokens = 0
             skips = []
+            fused = k - 1
             for _col, _slot, req in snapshot:
                 if bl > 1:
                     plen = len(req.prompt_tokens)
                     ctx_tokens += (plen + req.dispatched) // bl * bl
+                    if req.dispatched:      # a clean block is pending
+                        ctx_tokens -= bl
+                        fused = k
                     skips.append(0 if req.dispatched else plen % bl)
                     req.dispatched += k * bl - skips[-1]
                     continue
@@ -2402,7 +2466,8 @@ class LLMEngine:
         # often, and how hard, the bound engages.
         inflight = len(self._pending)
         passes = self._passes_of(k)
-        how = {"blocks": k, "passes": passes} if bl > 1 else {"k": k}
+        how = {"blocks": k, "passes": passes, "fused": fused} if bl > 1 \
+            else {"k": k}
         with self._prof.span("block_dispatch" if bl > 1
                              else "decode_dispatch", seq=seq, **how, w=w,
                              active=len(active_slots),
@@ -2422,7 +2487,8 @@ class LLMEngine:
                         toks, self._rng, self._temps_dev, idx, k)
             self._start_fetch(all_toks)
             dev_touched = touched[0] if touched else None
-            if dev_touched is not None:  # a routed block's count of experts
+            # a routed block's count of experts (a block program's counts)
+            if dev_touched is not None:
                 self._start_fetch(dev_touched)
             self._pending.append((all_toks, snapshot, k, seq, dev_touched))
             self.stats["steps"] += passes
@@ -2621,7 +2687,8 @@ class LLMEngine:
             self._apply_verify(dev_toks, snapshot, k[1], seq)
             return
         # a block dispatch (rows of (col, slot, req, skip)): k blocks of B
-        # token rows each, run as ``passes`` passes
+        # token rows each, run as ``passes`` passes (the first of a block
+        # over two blocks: counted once)
         bl = self._block_len
         passes = self._passes_of(k)
         # THE device sync: all device slowness (or a fetch that wasn't
@@ -2632,20 +2699,24 @@ class LLMEngine:
             # a routed block: the experts its steps touched, an output of
             # the same program as the tokens (no sync of its own)
             if dev_touched is not None:
-                touched = int(np.asarray(dev_touched))
-                layer_steps = passes * self._cache_spec.routed_layers
-                sp.set(experts_touched=touched)
-                self.stats["experts_touched_total"] += touched
-                self.stats["routed_layer_steps_total"] += layer_steps
+                counts = [int(n)
+                          for n in np.atleast_1d(np.asarray(dev_touched))]
+                routed = self._cache_spec.routed_layers
+                # rows a slot: a token a step, or B positions a pass and B
+                # more for each block's pass of two blocks
+                rows = (passes + (k if bl > 1 else 0)) * bl
+                sp.set(experts_touched=counts[0])
+                self.stats["experts_touched_total"] += counts[0]
+                self.stats["routed_layer_steps_total"] += passes * routed
                 self.stats["expert_rows_total"] += (
-                    layer_steps * len(snapshot) * bl
-                    * self._cache_spec.top_k)
+                    routed * len(snapshot) * rows * self._cache_spec.top_k)
         if bl > 1:
+            _touched, fused, kept = counts
             self.stats["block_passes_total"] += passes
-            self.stats["commit_passes_total"] += k
-            self.stats["denoise_passes_total"] += passes - k
+            self.stats["denoise_passes_total"] += passes
+            self.stats["fused_passes_total"] += fused
             self.stats["slot_passes_total"] += passes * len(snapshot)
-            self.stats["blocks_committed_total"] += k * len(snapshot)
+            self.stats["blocks_committed_total"] += kept
         host_toks = host_toks.reshape(k * bl, -1)
         # emit: what follows the sync on the host — up to k x w
         # _record_token calls under the lock, then the completion tail

@@ -38,8 +38,10 @@ Design choices:
 - a block that generates by diffusion over blocks (its cache spec states a
   ``block_length`` above 1, models/block.py) runs the same programs under
   the block mask (:func:`_visible`): prefill and the chunk program commit a
-  prompt's whole blocks, and :func:`paged_block_step` runs one pending
-  block's positions against the cache, committing or not;
+  prompt's whole blocks, :func:`paged_block_step` runs one pending
+  block's positions against the cache, committing or not, and
+  :func:`paged_block_pair_step` a clean pending block and the one after
+  it in one pass (the engine's: the commit rides with the next denoise);
 - tensor parallelism (ISSUE 20): every step function takes an optional
   ``mesh``. With a live "tensor" axis the pool is sharded per-KV-head
   (axis 1) and the q heads split into exactly the matching kv-head
@@ -918,7 +920,13 @@ def paged_block_step(params, kv, page_tables, seq_lens, tokens,
     distribution of the token that belongs there, and the K / V the pass
     wrote is junk under :func:`_span_step`'s rule. True, the COMMIT pass
     of a clean block: its K / V stay, no logits are computed, and it
-    returns (None, new_kv, seq_lens + B)."""
+    returns (None, new_kv, seq_lens + B).
+
+    The engine's block program runs neither alone since it defers the
+    commit (:func:`paged_block_pair_step`: the pass that keeps block g is
+    the first denoise pass of block g + 1); the harness's check 1 drives
+    these two, one known token a call, and the CPU tests hold the pass of
+    two blocks to them position by position."""
     b = _block_len(cfg)
     if tokens.shape[1] != b:
         raise ValueError(f"a block pass takes blocks of {b} positions, got "
@@ -932,23 +940,67 @@ def paged_block_step(params, kv, page_tables, seq_lens, tokens,
                              cfg), kv, seq_lens
 
 
+def paged_block_pair_step(params, kv, page_tables, seq_lens, tokens,
+                          cfg, page_size: int,
+                          attn_backend: str = "gather", mesh=None):
+    """One pass over TWO consecutive blocks a slot: the deferred commit of
+    generation by diffusion over blocks.
+
+    tokens: [W, 2B] at ``seq_lens`` (a block edge): the slot's pending
+    block, then the block after it, all masked. The block mask cuts the
+    span into its two blocks (a position of the first sees the cache and
+    the first, one of the second sees both), so the pass is what a commit
+    pass of the first block FOLLOWED BY a denoise pass of the second would
+    compute, in one read of the weights. Whether it is that is read from
+    the data, per slot:
+
+    * the first block is CLEAN (no position holds the mask token): the
+      pass KEEPS it (its K / V stay: ``seq_lens + B``) and is the first
+      denoise pass of the second, whose K / V are junk under
+      :func:`_span_step`'s rule; the logits are the second block's.
+    * it is not (what a prompt left: known tokens, then masks): a denoise
+      pass of the first block, nothing kept; the second half is filler
+      that no position of the first can see, its K / V junk and its
+      hidden states dropped; the logits are the first block's.
+
+    The final norm and the head run over the B positions a slot that the
+    flag selects, so they cost what a pass of one block costs. Returns
+    (logits [W, B, vocab], new_kv, new seq_lens, kept [W] bool)."""
+    blk = block_of(cfg)
+    spec = blk.cache_spec(cfg)
+    b = spec.block_length
+    if tokens.shape[1] != 2 * b:
+        raise ValueError(f"a pass of two blocks takes {2 * b} positions, "
+                         f"got {tokens.shape[1]}")
+    x, kv = _span_step(params, kv, page_tables, seq_lens, tokens, cfg,
+                       page_size, attn_backend, mesh, block_len=b)
+    kept = jnp.all(tokens[:, :b] != spec.mask_token, axis=1)      # [W]
+    x = jnp.where(kept[:, None, None], x[:, b:], x[:, :b])        # [W,B,D]
+    return blk.serve_lm_head(blk.serve_final_norm(x, params, cfg), params,
+                             cfg), kv, seq_lens + b * kept, kept
+
+
 def _span_step(params, kv, page_tables, seq_lens, tokens, cfg, page_size,
                attn_backend, mesh, *, block_len: int):
-    """What speculative verify and the block pass share: T positions a
+    """What speculative verify and the block passes share: T positions a
     slot (tokens [B, T] at ``seq_lens[b] + t``) written to the slot's
     pages and attended in one pass, causal inside the span
-    (``block_len`` 1) or every position seeing its whole block. Returns
-    (hidden states [B, T, D] before the final norm, new_kv).
+    (``block_len`` 1) or every position seeing its whole block (T a whole
+    number of blocks from a block edge). Returns (hidden states [B, T, D]
+    before the final norm, new_kv).
 
     THE JUNK-WRITE RULE. All T positions' K / V are written whether or not
     the caller keeps them (a verify round's rejected drafts, a denoise
-    pass's masked positions): what is not kept lies at or past the length
-    the caller goes on with, in pages the slot owns alone (positions at or
-    past the prompt's: never a shared prefix page), and is overwritten
-    before anything can attend to it: by the next step that writes those
-    positions (the decode that follows a rollback, the commit pass that
-    follows the denoise passes), every one of which writes before it
-    reads."""
+    pass's masked positions, the second block of a pass of two): what is
+    not kept lies at or past the length the caller goes on with, in pages
+    the slot owns alone (positions at or past the prompt's: never a
+    shared prefix page) or in the trash page (table entries past the
+    slot's pages are 0; under a block mask so is every position past the
+    table's width, which the second block of a pass at the last block
+    edge reaches), and is overwritten before anything can attend to it:
+    by the next step that writes those positions (the decode that follows
+    a rollback, the pass that keeps the block), every one of which writes
+    before it reads."""
     blk = block_of(cfg)
     t = tokens.shape[1]
     max_len = page_tables.shape[1] * page_size
@@ -959,6 +1011,8 @@ def _span_step(params, kv, page_tables, seq_lens, tokens, cfg, page_size,
         cos, sin = blk.rope_freqs(cfg, pos)
     page_idx = jnp.take_along_axis(page_tables, pos // page_size,
                                    axis=1)                        # [B,T]
+    if block_len > 1:       # past the table's width: the trash page
+        page_idx = jnp.where(pos < max_len, page_idx, 0)
     offset = pos % page_size
     kpos = jnp.arange(max_len)                                    # [L]
     # position t sees cache + the span's tokens 0..t (its own write), or

@@ -3,10 +3,13 @@
 paged kernel's block mask (interpreted) against dense attention; every pass
 of the engine's programs against the plain float32 reference (whole
 prefill, chunked prefill, a denoise pass at every count of known tokens,
-the commit pass, through the cache); served streams equal to the
-reference's own generation token for token; the junk a denoise pass writes
-never readable; prefix reuse on and off; what such a block is kept out of,
-each with its counter. Nothing here is a device number.
+the commit pass, through the cache); the engine's pass of TWO blocks (ISSUE
+38: the commit of a block rides with the first denoise pass of the next)
+against commit-then-denoise position by position, and what its second half
+may touch; served streams equal to the reference's own generation token
+for token; the junk a denoise pass writes never readable; prefix reuse on
+and off; what such a block is kept out of, each with its counter. Nothing
+here is a device number.
 """
 
 import jax
@@ -200,6 +203,89 @@ def test_a_denoise_pass_leaves_nothing_readable(params):
     assert np.array_equal(rest(kv), rest(poisoned))
 
 
+# ---- the pass of two blocks (the deferred commit) --------------------------------
+
+@pytest.mark.parametrize("backend", ["gather", "pallas"])
+def test_the_pass_of_two_blocks_is_commit_then_denoise(params, backend):
+    """One batch, one pass of 2B: a slot whose pending block is CLEAN (the
+    pass keeps it and denoises the next: equal to the commit pass followed
+    by a denoise pass of an all-masked block) beside one whose pending
+    block is not (a denoise pass of that block, nothing kept). Logits
+    position by position, the lengths, and the K / V the kept block left."""
+    prompts = [_prompt(31, 40), _prompt(32, 24)]
+    kv = kvc.init_paged_cache(CFG, 32, PAGE)
+    tables = jnp.asarray([1 + np.arange(8), 9 + np.arange(8)], jnp.int32)
+    for table, prompt in zip(tables, prompts):
+        toks = np.zeros((1, 128), np.int32)
+        toks[0, :len(prompt)] = prompt
+        kv = kvc.paged_prefill(params, kv, table, jnp.asarray(toks),
+                               jnp.int32(len(prompt)), CFG, PAGE)[1]
+    lens = jnp.asarray([40, 24], jnp.int32)
+    fresh = np.full((B,), MASK, np.int32)
+    first = np.asarray([[3, 1, 4, 1], [5, 9, MASK, MASK]], np.int32)
+
+    def step(kv, lens, blocks, commit, tabs=tables):
+        return kvc.paged_block_step(params, kv, tabs, lens,
+                                    jnp.asarray(blocks), CFG, PAGE, backend,
+                                    commit=commit)
+
+    # what the parent's program ran: slot 1's denoise pass; slot 0's commit
+    # (slot 1 writing the trash page), then its denoise pass of a new block
+    want_1 = np.asarray(step(kv, lens, first, False)[0])[1]
+    _, committed, after = step(kv, lens, first, True,
+                               tables.at[1].set(0))
+    want_0 = np.asarray(step(committed, after, np.stack([fresh, fresh]),
+                             False)[0])[0]
+
+    logits, got, new_lens, kept = kvc.paged_block_pair_step(
+        params, kv, tables, lens,
+        jnp.asarray(np.concatenate([first, np.stack([fresh, fresh])], 1)),
+        CFG, PAGE, backend)
+    assert logits.shape == (2, B, CFG.vocab_size)
+    assert [bool(x) for x in kept] == [True, False]
+    assert [int(x) for x in new_lens] == [40 + B, 24]
+    np.testing.assert_allclose(np.asarray(logits[0]), want_0, atol=1e-5)
+    np.testing.assert_allclose(np.asarray(logits[1]), want_1, atol=1e-5)
+    page, off = int(tables[0, 40 // PAGE]), 40 % PAGE
+    for pool in ("k", "v"):
+        np.testing.assert_allclose(
+            np.asarray(got[pool][:, :, page, off:off + B]),
+            np.asarray(committed[pool][:, :, page, off:off + B]), atol=1e-6)
+    with pytest.raises(ValueError, match="two blocks"):
+        kvc.paged_block_pair_step(params, kv, tables, lens,
+                                  jnp.asarray(first), CFG, PAGE, backend)
+
+
+@pytest.mark.parametrize("clean", [True, False],
+                         ids=["kept_block", "prompt_block"])
+def test_the_second_half_touches_no_page_of_another_sequence(params, clean):
+    """A poisoned pool, two sequences in one pass of 2B: one at the LAST
+    block edge of a full table (its second half lies past the table's
+    width), one a block before the end of its only page (its second half
+    lies in a table entry it does not own). Every page but the trash page
+    keeps the poison outside the rows its owner's span names."""
+    mp = 3                                           # table width: 24 tokens
+    kv = kvc.init_paged_cache(CFG, 16, PAGE)
+    kv = {**kv, "k": jnp.full_like(kv["k"], 7e3),
+          "v": jnp.full_like(kv["v"], -7e3)}
+    tables = jnp.asarray([[4, 5, 6], [9, 0, 0]], jnp.int32)
+    lens = np.asarray([mp * PAGE - B, PAGE - B])
+    block = [3, 1, 4, 1] if clean else [3, 1, MASK, MASK]
+    tokens = jnp.asarray([block + [MASK] * B] * 2, jnp.int32)
+    logits, got, new_lens, kept = kvc.paged_block_pair_step(
+        params, kv, tables, jnp.asarray(lens, jnp.int32), tokens, CFG, PAGE)
+    assert [bool(x) for x in kept] == [clean, clean]
+    assert [int(x) for x in new_lens] == [int(n) + B * clean for n in lens]
+    own = {6: lens[0] % PAGE, 9: lens[1] % PAGE}     # page: first row written
+    for pool, poison in (("k", 7e3), ("v", -7e3)):
+        pages = np.asarray(got[pool])                # [L, Hkv, P, page, D]
+        for page in range(1, pages.shape[2]):
+            untouched = pages[:, :, page, :own.get(page, PAGE)]
+            assert np.all(untouched == poison), (pool, page)
+        for page, row in own.items():                # the first half, written
+            assert np.all(np.abs(pages[:, :, page, row:row + B]) < 1e3)
+
+
 # ---- served streams against the reference's own generation ----------------------
 
 @pytest.mark.parametrize("left", [0, 1, 2, 3])
@@ -251,6 +337,46 @@ def test_a_stop_token_inside_a_block_cuts_it(params):
                                **REF_KW)[0]
 
 
+def test_a_stream_to_max_seq_len_beside_a_cut_one_on_a_poisoned_pool(params):
+    """One stream ends at ``max_seq_len`` (its table is full: the last
+    pass of two blocks reaches past its width), its neighbour's last block
+    is cut. The pool starts poisoned: both are the reference's streams, so
+    nothing unwritten was read, and the pages no sequence was given still
+    hold the poison, so no overshoot left its sequence's pages."""
+    prompts = [_prompt(41, 120), _prompt(42, 21)]
+    budgets = [ENGINE["max_seq_len"] - 120, 10]
+    eng = LLMEngine(LLMConfig(model_config=CFG, **ENGINE))
+    eng.kv = {**eng.kv, "k": jnp.full_like(eng.kv["k"], 7e3),
+              "v": jnp.full_like(eng.kv["v"], -7e3)}
+    handed = set()
+    alloc = eng.allocator.alloc
+
+    def recording(n):
+        pages = alloc(n)
+        handed.update(pages or ())
+        return pages
+
+    eng.allocator.alloc = recording
+    eng.start()
+    try:
+        rids = [eng.submit(p, max_tokens=n, temperature=0.0)
+                for p, n in zip(prompts, budgets)]
+        outs = [eng.result(r, timeout=300.0) for r in rids]
+        st = eng.engine_stats()
+    finally:
+        eng.shutdown()
+    for p, n, o in zip(prompts, budgets, outs):
+        assert o["error"] is None
+        assert [int(t) for t in o["tokens"]] \
+            == REF.generate(params, p, n, **REF_KW)[0]
+    assert len(outs[0]["tokens"]) == budgets[0]      # to max_seq_len
+    assert st["tokens_cut_total"] >= 1
+    assert len(handed) == 24 + 4                     # a full table, and 32 / 8
+    rest = [p for p in range(1, ENGINE["num_pages"]) if p not in handed]
+    assert np.all(np.asarray(eng.kv["k"])[:, :, rest] == 7e3)
+    assert np.all(np.asarray(eng.kv["v"])[:, :, rest] == -7e3)
+
+
 def test_prefix_reuse_on_and_off_give_the_same_tokens():
     """Two prompts that share 256 tokens (32 pages): the second takes the
     first's pages and prefills only its tail, by the chunk program, from a
@@ -274,26 +400,46 @@ def test_prefix_reuse_on_and_off_give_the_same_tokens():
 
 # ---- counters, and what the block is kept out of ---------------------------------
 
-def test_passes_and_blocks_are_counted(params):
+@pytest.fixture(scope="module")
+def counted():
     """16 tokens after a prompt on a block edge: two dispatches of two
-    blocks, six passes each, nothing cut: 0.75 passes a token."""
+    blocks, S = 2 passes a block, the first of each over two blocks."""
     eng = _engine()
     try:
         _serve(eng, [_prompt(5, 24)], 16)
-        st = eng.engine_stats()
+        return eng.engine_stats()
     finally:
         eng.shutdown()
-    assert st["tokens_out"] == 16 and st["tokens_cut_total"] == 0
-    assert st["block_passes_total"] == st["steps"] == 12
-    assert (st["denoise_passes_total"], st["commit_passes_total"]) == (8, 4)
-    assert st["slot_passes_total"] / st["tokens_out"] == 0.75
-    assert st["blocks_committed_total"] == 4
-    layers = CFG.n_layers
-    assert st["routed_layer_steps_total"] == 12 * layers
-    assert st["expert_rows_total"] == 12 * layers * B * CFG.top_k
-    assert 0 < st["experts_touched_total"] <= 12 * layers * CFG.n_experts
-    assert st["prefills"] == 1 and st["phase_block_dispatch_n"] == 2
-    assert st["phase_decode_dispatch_n"] == 0
+
+
+LAYERS = CFG.n_layers
+COUNTS = {
+    "tokens_out": 16, "tokens_cut_total": 0,
+    # S passes a block (a pass of two blocks is ONE pass): 4 blocks x 2
+    "block_passes_total": 8, "steps": 8, "denoise_passes_total": 8,
+    # no commit runs alone; every pass of two blocks but the first (whose
+    # slot came from a prefill) kept a block and denoised the next
+    "commit_passes_total": 0, "fused_passes_total": 3,
+    "slot_passes_total": 8,                  # over tokens_out: S / B = 0.5
+    # the last block of a finished stream is never committed
+    "blocks_committed_total": 3,
+    "routed_layer_steps_total": 8 * LAYERS,
+    # a block: a pass of 2B rows and one of B, top_k experts a row
+    "expert_rows_total": 4 * 3 * B * LAYERS * CFG.top_k,
+    "prefills": 1, "phase_block_dispatch_n": 2, "phase_decode_dispatch_n": 0,
+}
+
+
+@pytest.mark.parametrize("counter", sorted(COUNTS))
+def test_passes_and_blocks_are_counted(counted, counter):
+    assert counted[counter] == COUNTS[counter]
+
+
+def test_passes_a_token_and_experts_touched(counted):
+    assert counted["slot_passes_total"] / counted["tokens_out"] \
+        == CFG.denoise_passes / B == 0.5
+    assert 0 < counted["experts_touched_total"] \
+        <= 8 * LAYERS * CFG.n_experts
 
 
 def test_speculation_and_the_tier_are_bypassed_and_counted(params, tmp_path):
