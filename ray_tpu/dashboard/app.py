@@ -954,9 +954,12 @@ def _render_profiling(apps: list[dict], artifacts: list[dict]) -> str:
     import time as _time
 
     phase_keys = ["queue_wait", "admit", "prefill", "chunk_prefill",
-                  "decode_dispatch", "verify_dispatch", "harvest"]
+                  "decode_dispatch", "verify_dispatch", "harvest", "fetch"]
     scalar_keys = ["itl_s", "compile_events", "mid_traffic_compiles",
-                   "compile_s", "kv_page_occupancy", "weights_bytes",
+                   "compile_s", "host_stall_n", "host_stall_s_total",
+                   "gc_pause_n", "gc_pause_s_total", "gc_pause_max_ms",
+                   "dry_dispatches_total", "dry_s_total",
+                   "kv_page_occupancy", "weights_bytes",
                    "kv_pool_bytes", "device_bytes_in_use"]
     sections = []
     for app in apps:

@@ -38,6 +38,7 @@ KINDS = (
     "cp_restart",          # control plane came up with a fresh epoch
     "chaos_fault",         # FaultSchedule injected a fault (ground truth)
     "mid_traffic_compile", # XLA compile after warmup, with its signature
+    "loop_stall",          # engine loop stood still: phase, seconds, gc share
     "restore_partial",     # KV restore degraded to a partial chain
     "disagg_fallback",     # disagg prefill leg failed; colocated instead
     "warm_start",          # replica promoted with a pre-warmed cache

@@ -12,10 +12,26 @@ runtime to find the next one):
   named `rt/<phase>` with the span's arguments, so host phases land in
   the trace's host plane on the clock the device ops are on. Dispatch
   phases measure host-side dispatch cost (the loop never blocks on the
-  device); `harvest` is where the device sync lives (`np.asarray` on the
-  oldest in-flight block), so device slowness shows up there,
-  attributed, instead of smeared across the loop; `loop_wait` is the
-  loop parked with nothing to do.
+  device) and say whether the device had run `dry` before them;
+  `harvest` is the WAIT for the device and nothing else
+  (`block_until_ready` on the oldest in-flight entry, the GIL released),
+  so device slowness shows up there, attributed, instead of smeared
+  across the loop; `fetch`, its sibling, is what the host does next with
+  the result (`np.asarray`, the routed counts); `loop_wait` is the loop
+  parked with nothing to do.
+- **Host stalls**: a span of host work (any but `loop_pass`, `loop_wait`,
+  `harvest`) whose OWN time, what no child span covers, reaches
+  `STALL_S` is a stall of the loop thread: `host_stall_s_total` /
+  `host_stall_n`, a warning and a `loop_stall` journal event with the
+  phase, its `seq`, the seconds and how many of them a full garbage
+  collection took.
+- **The garbage collector** (`watch_gc`): one process-wide `gc.callbacks`
+  watch. Seconds and counts by generation always (`gc_pause_*` for full
+  collections, `gc_young_*` for the rest); under a capture a full
+  collection is also an `rt/gc` span on the line of the thread it ran in
+  (any thread: a collection holds the GIL, so the loop stands still
+  wherever it is); one of `STALL_S` or more is a `loop_stall` event of
+  phase `gc`.
 - **Compile-event tracking** (`compile_scope`): every jit entry point's
   first dispatch per static signature (prefill bucket, chunk length,
   decode (width, block), verify width) is timed as a compile event.
@@ -39,8 +55,10 @@ from __future__ import annotations
 
 import collections
 import contextlib
+import gc
 import logging
 import os
+import sys
 import threading
 import time
 from typing import Optional
@@ -54,12 +72,20 @@ logger = logging.getLogger(__name__)
 # `queue_wait` is a per-request duration fed through `record`; the rest
 # are spans on the engine-loop thread, nested by containment under
 # `loop_pass` (`patch_flush` inside a dispatch; `restore`, `kv_tier_flush`
-# only with the kv tier on).
+# only with the kv tier on; `harvest`, `fetch`, `emit` siblings, in that
+# order, for one entry of the pipeline).
 PHASES = ("queue_wait", "loop_pass", "admit", "restore", "prefill",
           "chunk_prefill", "decode_dispatch", "block_dispatch",
-          "verify_dispatch", "patch_flush", "harvest", "emit", "kv_tier_flush", "loop_wait")
+          "verify_dispatch", "patch_flush", "harvest", "fetch", "emit",
+          "kv_tier_flush", "loop_wait")
 # span names in the profiler's trace: "rt/<phase>"
 SPAN_PREFIX = "rt/"
+# host work on the loop thread takes microseconds to a few ms: a span
+# whose own time reaches this, or a full collection that long on any
+# thread, is a stall (a constant on purpose: one definition fleet-wide)
+STALL_S = 0.050
+# spans that wait by design, or whose time is their children's
+_NOT_HOST_WORK = frozenset(("loop_pass", "loop_wait", "harvest"))
 
 _PHASE_BOUNDS = (0.0001, 0.0003, 0.001, 0.003, 0.01, 0.03,
                  0.1, 0.3, 1.0, 3.0, 10.0)
@@ -119,15 +145,100 @@ class _Noop:
 _NOOP = _Noop()
 
 
+class _GcWatch:
+    """The process's garbage collector, watched through `gc.callbacks`
+    (`watch_gc` installs the one instance, once). Both callbacks of a
+    collection run in the thread that tripped the threshold, holding the
+    GIL, and collections do not nest: the totals have one writer at a
+    time. They are not `EngineProfiler.record`'s (a collection runs in
+    whichever thread allocates: an HTTP handler as soon as the loop).
+
+    Nothing here logs or emits: a collection can start under any lock of
+    the process (the journal's own among them). A full collection of
+    `STALL_S` or more is left in `stall` for the engine loop's next span
+    to report (`EngineProfiler._close`)."""
+
+    __slots__ = ("pause_s", "pause_n", "young_s", "young_n", "pause_max_s",
+                 "stall", "_t0", "_ann")
+
+    def __init__(self):
+        self.pause_s = 0.0        # full (gen-2) collections
+        self.pause_n = 0
+        self.young_s = 0.0        # gen 0 and 1
+        self.young_n = 0
+        self.pause_max_s = 0.0
+        self.stall: Optional[tuple] = None    # (seconds, thread name)
+        self._t0 = 0.0
+        self._ann = None
+
+    def __call__(self, phase: str, info: dict) -> None:
+        if phase == "start":
+            if info["generation"] == 2 and _capture.active:
+                # on the collecting thread's line of the host plane, on
+                # the device ops' clock. Young collections get none:
+                # thousands a trace. No import statement here (a capture
+                # is jax's, so the module is loaded): a collection can
+                # start in a thread that holds the import lock
+                self._ann = sys.modules["jax"].profiler.TraceAnnotation(
+                    SPAN_PREFIX + "gc", generation=2)
+                self._ann.__enter__()
+            self._t0 = time.perf_counter()
+            return
+        dt = time.perf_counter() - self._t0
+        if info["generation"] < 2:
+            self.young_s += dt
+            self.young_n += 1
+            return
+        ann, self._ann = self._ann, None
+        if ann is not None:
+            # the thread by its Python name: the host plane's lines all
+            # carry the process's
+            ann.set_metadata(collected=info["collected"],
+                             uncollectable=info["uncollectable"],
+                             thread=threading.current_thread().name)
+            ann.__exit__(None, None, None)
+        self.pause_s += dt
+        self.pause_n += 1
+        self.pause_max_s = max(self.pause_max_s, dt)
+        if dt >= STALL_S:
+            self.stall = (dt, threading.current_thread().name)
+
+    def stats(self) -> dict:
+        """`gc_pause_*`: full collections (seconds, count, the longest
+        since start); `gc_young_*`: generations 0 and 1."""
+        return {"gc_pause_s_total": round(self.pause_s, 6),
+                "gc_pause_n": self.pause_n,
+                "gc_pause_max_ms": round(self.pause_max_s * 1e3, 3),
+                "gc_young_s_total": round(self.young_s, 6),
+                "gc_young_n": self.young_n}
+
+
+_gc = _GcWatch()
+_gc_install = threading.Lock()
+
+
+def watch_gc() -> _GcWatch:
+    """Install the process's collector watch (once, however many engines
+    a process builds) and return it."""
+    with _gc_install:
+        if _gc not in gc.callbacks:
+            gc.callbacks.append(_gc)
+    return _gc
+
+
 class _Span:
-    """One timed phase of the engine loop (`EngineProfiler.span`)."""
+    """One timed phase of the engine loop (`EngineProfiler.span`). Spans
+    of one profiler nest on one thread (the loop's): each knows its
+    parent, so its own time is what no child covers."""
 
-    __slots__ = ("_prof", "_name", "_ann", "_t0")
+    __slots__ = ("_prof", "_name", "_ann", "_args", "_t0", "_parent",
+                 "_child_s", "_gc0")
 
-    def __init__(self, prof: "EngineProfiler", name: str, ann):
+    def __init__(self, prof: "EngineProfiler", name: str, ann, args: dict):
         self._prof = prof
         self._name = name
         self._ann = ann
+        self._args = args
 
     def set(self, **args) -> None:
         """Arguments known only once the phase has run (how many were
@@ -139,6 +250,10 @@ class _Span:
     def __enter__(self):
         if self._ann is not None:
             self._ann.__enter__()
+        prof = self._prof
+        self._parent, prof._open = prof._open, self
+        self._child_s = 0.0
+        self._gc0 = _gc.pause_s
         self._t0 = time.perf_counter()
         return self
 
@@ -146,7 +261,10 @@ class _Span:
         dt = time.perf_counter() - self._t0
         if self._ann is not None:
             self._ann.__exit__(exc_type, exc, tb)
-        self._prof.record(self._name, dt)
+        self._prof._open = parent = self._parent
+        if parent is not None:
+            parent._child_s += dt
+        self._prof._close(self, dt)
         return False
 
 
@@ -200,9 +318,19 @@ class EngineProfiler:
         self._rings: dict[str, collections.deque] = {
             p: collections.deque(maxlen=ring_size) for p in PHASES}
         # running totals per phase: [seconds, count]. Written by the loop
-        # thread alone, read by engine_stats(); deltas over any interval
-        # give host time by phase.
+        # thread alone (`queue_wait` included: the admission pass records
+        # it), read by engine_stats(); deltas over any interval give host
+        # time by phase. The collector's totals are NOT here: a
+        # collection runs in any thread (`_GcWatch`).
         self._totals: dict[str, list] = {p: [0.0, 0] for p in PHASES}
+        # the innermost open span (the loop thread's), host stalls
+        # (spans of host work whose own time reached STALL_S) and when
+        # one was last reported
+        self._open: Optional[_Span] = None
+        self.host_stall_s = 0.0
+        self.host_stall_n = 0
+        self._stall_told = 0.0
+        self._gc = watch_gc()
         self._itl: collections.deque = collections.deque(maxlen=itl_ring_size)
         self._seen: set = set()
         self.compile_events = 0
@@ -214,6 +342,7 @@ class EngineProfiler:
 
     # ---- phase timers --------------------------------------------------
     def record(self, phase: str, dt: float) -> None:
+        """One sample of a phase. One writer: the loop thread."""
         if not self.enabled:
             return
         self._rings[phase].append(dt)
@@ -234,10 +363,55 @@ class EngineProfiler:
             import jax
 
             return _Span(self, name, jax.profiler.TraceAnnotation(
-                SPAN_PREFIX + name, **args))
+                SPAN_PREFIX + name, **args), args)
         if not self.enabled:
             return _NO_SPAN
-        return _Span(self, name, None)
+        return _Span(self, name, None, args)
+
+    def _close(self, span: _Span, dt: float) -> None:
+        """A span has ended: its sample, and the stall it may have been.
+        Own time = what no child span covers (a slow `prefill` is not
+        counted again as its parent `admit`)."""
+        if not self.enabled:
+            return
+        self.record(span._name, dt)
+        own = dt - span._child_s
+        if own >= STALL_S and span._name not in _NOT_HOST_WORK:
+            self.host_stall_s += own
+            self.host_stall_n += 1
+            self._tell_stall(span._name, own, span._args.get("seq"),
+                             self._gc.pause_s - span._gc0)
+        if self._gc.stall is not None:
+            # a full collection on ANY thread holds the GIL: the loop
+            # stood still wherever it was. Reported here, not from the
+            # collector's callback (see _GcWatch)
+            (seconds, thread), self._gc.stall = self._gc.stall, None
+            self._tell_stall("gc", seconds, None, seconds, thread=thread)
+
+    def _tell_stall(self, phase: str, seconds: float, seq, gc_s: float,
+                    **more) -> None:
+        """What an operator has when a replica's throughput dips: the
+        phase, the second, and whether it was the collector. At most one
+        warning and one `loop_stall` journal event a second."""
+        now = time.monotonic()
+        if now - self._stall_told < 1.0:
+            return
+        self._stall_told = now
+        logger.warning(
+            "engine loop stalled %.3fs in %s (seq=%s%s), %.3fs of it in full "
+            "garbage collections", seconds, phase, seq,
+            "".join(f", {k}={v}" for k, v in more.items()), gc_s)
+        from ray_tpu.observability import events as _fr
+        _fr.emit("loop_stall", "WARNING", reason=phase,
+                 attrs={"phase": phase, "seq": seq,
+                        "seconds": round(float(seconds), 4),
+                        "gc_s": round(float(gc_s), 4), **more})
+
+    def stall_stats(self) -> dict:
+        """`host_stall_*` of this engine's loop and the process's
+        `gc_*` totals."""
+        return {"host_stall_s_total": round(self.host_stall_s, 6),
+                "host_stall_n": self.host_stall_n, **self._gc.stats()}
 
     def record_itl(self, gap_s: float) -> None:
         if not self.enabled:
@@ -379,6 +553,21 @@ def device_memory_stats(devices=None) -> tuple[Optional[int], Optional[int]]:
 # on-demand XPlane capture (remote-drivable: worker RPC handlers call these)
 # ---------------------------------------------------------------------------
 
+def _profile_options():
+    """What every capture of this module is taken with. No Python frames:
+    the default capture hooks every Python call of every thread of the
+    process (the engine loop's and each request handler's) and so slows
+    the host it measures: a replica streaming 2,700 tokens/s left its
+    chip idle 12.7 % of a traced span for it, 0.03 % without (PERF.md,
+    PR 35). What reads a capture (benchmark/span_reduce.py) reads the
+    rt/ spans and the device planes."""
+    import jax
+
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    return options
+
+
 class CaptureController:
     """Process-wide start/stop around `jax.profiler` tracing. jax allows
     ONE active trace per process, so this serializes: a second start while
@@ -406,17 +595,8 @@ class CaptureController:
             # directory would overwrite each other's trace
             logdir = os.path.join(logdir, str(os.getpid()))
             os.makedirs(logdir, exist_ok=True)
-            # no Python frames: the default capture hooks every Python
-            # call of every thread of the process (the engine loop's and
-            # each request handler's) and so slows the host it measures:
-            # a replica streaming 2,700 tokens/s left its chip idle 12.7 %
-            # of a traced span for it, 0.03 % without (PERF.md, PR 35).
-            # What reads a capture (benchmark/span_reduce.py) reads the
-            # rt/ spans and the device planes
-            options = jax.profiler.ProfileOptions()
-            options.python_tracer_level = 0
             jax.profiler.start_trace(logdir, create_perfetto_link=False,
-                                     profiler_options=options)
+                                     profiler_options=_profile_options())
             self._logdir = logdir
             self._started_at = time.time()
             self.active = True
@@ -478,8 +658,9 @@ def save_device_memory_profile(path: Optional[str] = None) -> str:
 
 
 @contextlib.contextmanager
-def profile_trace(logdir: str, *, host_tracer_level: int = 2):
-    """Capture an XPlane trace of everything inside the block.
+def profile_trace(logdir: str):
+    """Capture an XPlane trace of everything inside the block: device
+    planes and `annotate` regions, no Python frames (`_profile_options`).
 
     Usage (inside a train fn)::
 
@@ -491,7 +672,8 @@ def profile_trace(logdir: str, *, host_tracer_level: int = 2):
     import jax
 
     os.makedirs(logdir, exist_ok=True)
-    jax.profiler.start_trace(logdir, create_perfetto_link=False)
+    jax.profiler.start_trace(logdir, create_perfetto_link=False,
+                             profiler_options=_profile_options())
     try:
         yield logdir
     finally:

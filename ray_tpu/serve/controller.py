@@ -451,7 +451,12 @@ class ServeController:
                         "itl_s", "compile_events", "mid_traffic_compiles",
                         "compile_s", "weights_bytes", "kv_pool_bytes",
                         "kv_page_occupancy", "device_bytes_in_use",
-                        "device_peak_bytes") + tuple(
+                        "device_peak_bytes",
+                        "host_stall_s_total", "host_stall_n",
+                        "gc_pause_s_total", "gc_pause_n", "gc_pause_max_ms",
+                        "gc_young_s_total", "gc_young_n",
+                        "dry_dispatches_total", "dry_s_total",
+                        "clock_s") + tuple(
                             f"phase_{p}_{q}" for p in PHASES
                             for q in ("p50_ms", "p95_ms", "s_total", "n"))
 

@@ -366,7 +366,12 @@ class LLMEngine:
                       "blocks_committed_total": 0, "tokens_cut_total": 0,
                       "spec_bypassed_block": 0,
                       "kv_tier_bypassed_block": 0,
-                      "disagg_refused_block": 0}
+                      "disagg_refused_block": 0,
+                      # dispatches that found the device with nothing
+                      # queued although slots were live, and the seconds
+                      # since the loop last knew it busy (_dry): an UPPER
+                      # bound of the idle they stand for
+                      "dry_dispatches_total": 0, "dry_s_total": 0.0}
         # Tiered KV cache (kv_tier.py): evicted cached page chains spill
         # host-side into a shm/disk tier + cluster index instead of dying,
         # and _admit extends its longest-match search past the local index
@@ -427,6 +432,13 @@ class LLMEngine:
         # that is the first), None otherwise
         self._pending: list = []
         self._block_seq = 0
+        # the run-dry watch (_dry): an output of the NEWEST program put on
+        # the device stream, of any kind; the last moment the loop saw it
+        # not ready or dispatched; and whether the loop has parked in
+        # loop_wait (or not run at all) since the last dispatch
+        self._newest = None
+        self._busy_seen = 0.0
+        self._parked = True
         # the token every slot's next step consumes: [B+1] on device (row b
         # is the trash row, below). Only programs write it: a decode block
         # or verify round its last samples, a prefill (or last prefill
@@ -1274,7 +1286,8 @@ class LLMEngine:
         engine-telemetry table documents every key, and a test holds the
         two together): ``self.stats`` (running totals: steps / passes,
         prefills, tokens, prefix / tier / speculation / failover / disagg
-        counts, per-kernel dispatches, routed experts, what a block with
+        counts, per-kernel dispatches, dry dispatches, routed experts,
+        what a block with
         slot state or one that generates by diffusion over blocks is kept
         out of: ``*_stateful`` / ``*_block``, and the latter's
         ``block_passes_total`` (a pass of two blocks counts once),
@@ -1283,7 +1296,8 @@ class LLMEngine:
         that ran alone: 0 since the commit is deferred),
         ``slot_passes_total``, ``blocks_committed_total``,
         ``tokens_cut_total``); occupancy
-        gauges; the profiler's ``phase_<p>_*``, compile and memory keys;
+        gauges; the profiler's ``phase_<p>_*``, ``host_stall_*``, ``gc_*``,
+        compile and memory keys; ``clock_s``;
         the attention backend, device and tensor-parallel surface; prefix
         cache and tier gauges."""
         with self._lock:
@@ -1315,6 +1329,13 @@ class LLMEngine:
         # compile_s is the profiler's measured total — the stats-dict slot
         # predates the tracker and is overridden here.
         out.update(self._prof.phase_stats())
+        # stalls of the loop's host: spans of host work whose own time
+        # reached profiling.STALL_S, and the process's garbage collector
+        out.update(self._prof.stall_stats())
+        out["dry_s_total"] = round(out["dry_s_total"], 6)
+        # this process's monotonic clock at the read: a reader divides a
+        # counter's delta by the delta of THIS between the same two reads
+        out["clock_s"] = round(time.monotonic(), 6)
         out["compile_events"] = self._prof.compile_events
         out["mid_traffic_compiles"] = self._prof.mid_traffic_compiles
         out["compile_s"] = round(self._prof.compile_s, 3)
@@ -1500,9 +1521,37 @@ class LLMEngine:
                 return
             # the one place the loop sleeps: what tells "the host had
             # nothing to do" from "the host was busy"
+            self._parked = True
             with prof.span("loop_wait"):
                 self._wake.wait(timeout=0.05)
             self._wake.clear()
+
+    def _dry(self) -> int:
+        """Called just before every dispatch (decode / block / verify,
+        prefill, prefill chunk): 1 when the device has nothing queued
+        (the newest program's output is ready) although the loop has not
+        parked in ``loop_wait`` since it last dispatched, i.e. slots
+        were live and the host did not keep the device fed. Counted in
+        ``dry_dispatches_total``; ``dry_s_total`` adds the time since
+        the loop last saw that output NOT ready or dispatched. When the
+        device finished is unknown to the host, so that is an UPPER
+        bound of the idle it stands for. One PJRT call, no sync."""
+        now = time.perf_counter()
+        dry = 0
+        if not self._parked and self._newest is not None \
+                and self._newest.is_ready():
+            dry = 1
+            self.stats["dry_dispatches_total"] += 1
+            self.stats["dry_s_total"] += now - self._busy_seen
+        self._busy_seen = now
+        self._parked = False
+        return dry
+
+    def _see_device(self) -> None:
+        """At every harvest's entry: is the device still running what was
+        dispatched last? Then that is the latest it is known busy."""
+        if self._newest is not None and not self._newest.is_ready():
+            self._busy_seen = time.perf_counter()
 
     @staticmethod
     def _start_fetch(dev_arr) -> None:
@@ -2121,7 +2170,8 @@ class LLMEngine:
         # waiting on it — warmup doesn't cover prompt buckets, so this is
         # always a mid-traffic compile when it fires
         with self._prof.span("prefill", rid=req.request_id, bucket=bucket,
-                             tokens=plen), self._prof.compile_scope(
+                             tokens=plen, dry=self._dry()), \
+                self._prof.compile_scope(
                 "prefill", ("prefill", bucket),
                 mid_traffic=self.stats["requests"] > 0):
             tok_dev, self._dev_tokens, self.kv = fn(
@@ -2129,6 +2179,7 @@ class LLMEngine:
                 np.int32(plen), sub,
                 np.full((1,), req.temperature, np.float32),
                 np.int32(req.slot))
+            self._newest = tok_dev
         self._arm_slot(req, table, tok_dev, plen)
 
     def _arm_slot(self, req: _Request, table, tok_dev, plen: int) -> None:
@@ -2204,7 +2255,8 @@ class LLMEngine:
             self._rng, sub = self._split_key(self._rng)
             with self._prof.span(
                     "chunk_prefill", rid=req.request_id, clen=clen,
-                    start=start, tokens=len(seg), last=int(final)), \
+                    start=start, tokens=len(seg), last=int(final),
+                    dry=self._dry()), \
                     self._prof.compile_scope(
                     "chunk", ("chunk", clen),
                     mid_traffic=self.stats["requests"] > 0):
@@ -2213,6 +2265,7 @@ class LLMEngine:
                     np.int32(start), np.int32(plen), sub,
                     np.full((1,), req.temperature, np.float32),
                     np.int32(req.slot if final else trash))
+                self._newest = tok_dev
             self.stats["attn_chunk_dispatches"] += 1
             req.prefill_pos = min(start + clen, plen)
             if req.prefill_pos >= plen:
@@ -2463,7 +2516,8 @@ class LLMEngine:
         # excluded — they're already sampled inside _harvest_one.
         # inflight: entries pending as this block is dispatched; trimmed:
         # the harvests the bound then forces (_step) — a trace says how
-        # often, and how hard, the bound engages.
+        # often, and how hard, the bound engages. dry: the device had
+        # nothing queued (_dry): the dispatch that ends an idle gap.
         inflight = len(self._pending)
         passes = self._passes_of(k)
         how = {"blocks": k, "passes": passes, "fused": fused} if bl > 1 \
@@ -2473,7 +2527,8 @@ class LLMEngine:
                              active=len(active_slots),
                              ctx_tokens=ctx_tokens, inflight=inflight,
                              trimmed=max(
-                                 0, inflight + 1 - self.PIPELINE_DEPTH)):
+                                 0, inflight + 1 - self.PIPELINE_DEPTH),
+                             dry=self._dry()):
             toks = self._flush_slot_patches(dirty, overrides)
             idx = self._slot_index(active_slots, w)
             snapshot = [(col, slot, req, *skips[col:col + 1])
@@ -2485,6 +2540,7 @@ class LLMEngine:
                     self._rng, *touched = self._decode(
                         self.params, self.kv, self._pt_dev, self._sl_dev,
                         toks, self._rng, self._temps_dev, idx, k)
+            self._newest = all_toks
             self._start_fetch(all_toks)
             dev_touched = touched[0] if touched else None
             # a routed block's count of experts (a block program's counts)
@@ -2527,7 +2583,8 @@ class LLMEngine:
         spec_slots = [slot for slot, _r, _d, _b in rows]
         w = self._bucket_width(len(spec_slots))
         self._block_seq = seq = self._block_seq + 1
-        with self._prof.span("verify_dispatch", seq=seq, k=k, w=w):
+        with self._prof.span("verify_dispatch", seq=seq, k=k, w=w,
+                             dry=self._dry()):
             toks = self._flush_slot_patches(dirty, overrides)
             idx = self._slot_index(spec_slots, w)
             draft_mat = np.full((w, k), -1, np.int32)
@@ -2542,6 +2599,7 @@ class LLMEngine:
                     self._rng = self._verify(
                         self.params, self.kv, self._pt_dev, self._sl_dev,
                         toks, self._rng, self._temps_dev, idx, draft_mat)
+            self._newest = all_toks
             self._start_fetch(all_toks)
             self._pending.append((all_toks, entry, ("spec", k), seq, None))
             self.stats["steps"] += k + 1
@@ -2615,9 +2673,11 @@ class LLMEngine:
         Slots whose fresh context drafts again chain straight into the
         next verify round (their just-harvested host state is exact — no
         pipeline drain needed); the rest drop back to decode blocks."""
+        self._see_device()
         with self._prof.span("harvest", seq=seq, k=k + 1):
-            host = np.asarray(dev_toks)  # device sync (oldest round)
-        host = host.reshape(k + 1, -1)
+            dev_toks.block_until_ready()  # device sync (oldest round)
+        with self._prof.span("fetch", seq=seq, k=k + 1):
+            host = np.asarray(dev_toks).reshape(k + 1, -1)
         with self._prof.span("emit", seq=seq) as sp:
             chain, tokens, finished = self._emit_verified(host, rows, k)
             sp.set(tokens=tokens, finished=finished)
@@ -2691,11 +2751,16 @@ class LLMEngine:
         # over two blocks: counted once)
         bl = self._block_len
         passes = self._passes_of(k)
-        # THE device sync: all device slowness (or a fetch that wasn't
-        # prefetched) surfaces here, attributed as "harvest" instead of
-        # smeared across the loop
-        with self._prof.span("harvest", seq=seq, k=k) as sp:
-            host_toks = np.asarray(dev_toks)  # sync point: oldest block only
+        # THE device sync: all device slowness surfaces here, attributed
+        # as "harvest" instead of smeared across the loop. The span holds
+        # the wait and nothing else (the GIL is released in it): what the
+        # host does with the result is its sibling, fetch
+        self._see_device()
+        with self._prof.span("harvest", seq=seq, k=k):
+            dev_toks.block_until_ready()    # sync point: oldest block only
+        with self._prof.span("fetch", seq=seq, k=k) as sp:
+            # the copy was started at dispatch (_start_fetch)
+            host_toks = np.asarray(dev_toks)
             # a routed block: the experts its steps touched, an output of
             # the same program as the tokens (no sync of its own)
             if dev_touched is not None:

@@ -69,7 +69,13 @@ _EXPORTED_STATS = (
     # None-valued entries (no samples yet / cpu backend) are skipped
     "compile_events", "mid_traffic_compiles", "compile_s",
     "weights_bytes", "kv_pool_bytes", "kv_page_occupancy",
-    "device_bytes_in_use", "device_peak_bytes", "itl_s")
+    "device_bytes_in_use", "device_peak_bytes", "itl_s",
+    # stalls of the loop's host (ISSUE 39): spans of host work past 50 ms,
+    # the process's garbage collector, dispatches that found the device
+    # with nothing queued (dry_s_total: an upper bound of the idle)
+    "host_stall_s_total", "host_stall_n", "gc_pause_s_total", "gc_pause_n",
+    "gc_pause_max_ms", "gc_young_s_total", "gc_young_n",
+    "dry_dispatches_total", "dry_s_total")
 
 
 def _export_engine_stats(model_id: str, stats: dict) -> None:

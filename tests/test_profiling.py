@@ -319,10 +319,11 @@ def test_capture_holds_engine_spans_with_args(tmp_path):
         prof.stop_capture()
     finally:
         eng.shutdown()
-    spans = _host_spans(info["logdir"])
+    # a full collection is a span too, on whichever thread ran it
+    spans = [sp for sp in _host_spans(info["logdir"]) if sp[0] != "rt/gc"]
     names = {n for n, *_ in spans}
     assert {"rt/loop_pass", "rt/admit", "rt/prefill", "rt/decode_dispatch",
-            "rt/patch_flush", "rt/harvest", "rt/emit",
+            "rt/patch_flush", "rt/harvest", "rt/fetch", "rt/emit",
             "rt/loop_wait"} <= names, names
     assert len({line for *_x, line in spans}) == 1   # the loop thread
     disp = [a for n, _s, _e, a, _l in spans if n == "rt/decode_dispatch"]
@@ -336,6 +337,27 @@ def test_capture_holds_engine_spans_with_args(tmp_path):
     assert matched and all(harvested[a["seq"]] == a["k"] for a in matched)
     pre = [a for n, _s, _e, a, _l in spans if n == "rt/prefill"]
     assert pre and all(a["tokens"] <= a["bucket"] and a["rid"] for a in pre)
+    # harvest is the wait alone; fetch follows it as a SIBLING (neither
+    # inside the other) with the same seq and k, before the entry's emit
+    order = sorted((s, e, n, a) for n, s, e, a, _l in spans
+                   if n in ("rt/harvest", "rt/fetch", "rt/emit"))
+    waits = [i for i, sp in enumerate(order) if sp[2] == "rt/harvest"]
+    assert waits
+    for i in waits[:-1]:
+        (hs, he, _n, ha), (fs, fe, fn, fa), (es, _ee, en, ea) = \
+            order[i:i + 3]
+        assert (fn, en) == ("rt/fetch", "rt/emit")
+        assert hs < he <= fs < fe <= es
+        assert fa["seq"] == ha["seq"] == ea["seq"] and fa["k"] == ha["k"]
+    # every dispatch says whether it found the device with nothing
+    # queued; the first one after the loop has parked never does
+    dispatches = sorted((s, n, a) for n, s, _e, a, _l in spans if n in (
+        "rt/prefill", "rt/chunk_prefill", "rt/decode_dispatch"))
+    assert all(a["dry"] in (0, 1) for _s, _n, a in dispatches)
+    parked = [e for n, _s, e, _a, _l in spans if n == "rt/loop_wait"]
+    for end in parked:
+        nxt = next((a for s, _n, a in dispatches if s >= end), None)
+        assert nxt is None or nxt["dry"] == 0
     emit = [a for n, _s, _e, a, _l in spans if n == "rt/emit"]
     assert sum(a["tokens"] for a in emit) >= 8
     # nesting by containment: every other span lies inside a loop_pass
@@ -378,10 +400,218 @@ def test_phase_totals_monotone_and_add_up_to_the_loops_wall():
     assert abs(delta["loop_pass"] - wall) <= 0.06 + 0.02 * wall, (delta, wall)
     direct = sum(delta[p] for p in (
         "admit", "restore", "chunk_prefill", "decode_dispatch",
-        "verify_dispatch", "harvest", "emit", "kv_tier_flush", "loop_wait"))
+        "verify_dispatch", "harvest", "fetch", "emit", "kv_tier_flush",
+        "loop_wait"))
     assert 0.9 * delta["loop_pass"] - 0.06 <= direct \
         <= delta["loop_pass"] + 0.06, (delta, wall)
     assert delta["loop_wait"] > 0.3 and b["phase_emit_n"] > a["phase_emit_n"]
+    # one fetch an entry harvested, and the replica's own clock beside
+    # the counters: the divisor of every delta
+    assert b["phase_fetch_n"] - a["phase_fetch_n"] \
+        == b["phase_harvest_n"] - a["phase_harvest_n"] > 0
+    assert abs((b["clock_s"] - a["clock_s"]) - wall) < 0.05
+
+
+# ---- stalls of the loop's host -----------------------------------------
+
+
+def test_gc_watch_counts_by_generation_and_installs_once():
+    import gc
+
+    from ray_tpu.observability import profiling as prof
+
+    first, second = prof.EngineProfiler(), prof.EngineProfiler()
+    assert first._gc is second._gc is prof.watch_gc()
+    assert gc.callbacks.count(prof.watch_gc()) == 1
+    a = first.stall_stats()
+    gc.collect()
+    b = first.stall_stats()
+    assert b["gc_pause_n"] == a["gc_pause_n"] + 1
+    assert b["gc_pause_s_total"] > a["gc_pause_s_total"]
+    assert b["gc_pause_max_ms"] >= a["gc_pause_max_ms"] > -1
+    young = b["gc_young_n"]
+    gc.collect(0)
+    gc.collect(1)
+    c = second.stall_stats()
+    assert c["gc_young_n"] == young + 2
+    assert c["gc_young_s_total"] > b["gc_young_s_total"]
+    assert c["gc_pause_n"] == b["gc_pause_n"]      # no full one since
+
+
+def test_two_engines_share_one_gc_watch_and_export_its_keys():
+    import gc
+
+    from ray_tpu.observability import profiling as prof
+
+    one = _mk_engine(warmup_compile=False)
+    two = _mk_engine(warmup_compile=False)
+    try:
+        assert gc.callbacks.count(prof.watch_gc()) == 1
+        gc.collect()
+        a, b = one.engine_stats(), two.engine_stats()
+        # the process's collector, not an engine's: both report it
+        assert a["gc_pause_n"] == b["gc_pause_n"] >= 1
+        for key in ("gc_pause_s_total", "gc_pause_max_ms", "gc_young_n",
+                    "gc_young_s_total", "host_stall_n", "host_stall_s_total",
+                    "dry_dispatches_total", "dry_s_total", "clock_s"):
+            assert key in a, key
+        assert two.engine_stats()["clock_s"] >= b["clock_s"] >= a["clock_s"]
+    finally:
+        one.shutdown()
+        two.shutdown()
+
+
+def test_capture_holds_a_collection_of_another_thread(tmp_path):
+    """A full collection run by a thread that is NOT the loop's is an
+    rt/gc span with generation 2 on THAT thread's line of the host plane,
+    and the benchmark's reader of every line finds it."""
+    import gc
+    import glob
+    import threading
+
+    from benchmark import stall_reduce
+    from ray_tpu.observability import profiling as prof
+
+    eng = _mk_engine()
+    try:
+        eng.generate("warm the programs up first", max_tokens=4)
+        info = prof.start_capture(str(tmp_path / "xprof"))
+        eng.generate("spans of the loop thread", max_tokens=6)
+        th = threading.Thread(target=gc.collect, name="not-the-loop")
+        th.start()
+        th.join()
+        gc.collect(0)                 # a young one leaves no span
+        eng.generate("and some more of them", max_tokens=6)
+        prof.stop_capture()
+    finally:
+        eng.shutdown()
+    spans = _host_spans(info["logdir"])
+    loop_lines = {line for n, *_x, line in spans if n == "rt/loop_pass"}
+    gcs = [(a, line) for n, _s, _e, a, line in spans if n == "rt/gc"]
+    assert len(loop_lines) == 1 and gcs
+    assert all(a["generation"] == 2 and a["collected"] >= 0
+               and a["uncollectable"] >= 0 for a, _l in gcs)
+    assert any(line not in loop_lines and a["thread"] == "not-the-loop"
+               for a, line in gcs)
+    (path,) = glob.glob(os.path.join(info["logdir"], "**", "*.xplane.pb"),
+                        recursive=True)
+    found = stall_reduce.gc_events(path)
+    assert len(found) == len(gcs)
+    assert all(e > s and st["generation"] == 2 for s, e, _t, st in found)
+    assert found == sorted(found)
+
+
+def test_planted_sleep_in_emit_is_one_host_stall_and_a_dry_dispatch():
+    """0.2 s of host work inside one emit: host_stall_n 1 with phase emit,
+    one loop_stall journal event that says so, and the dispatch after it
+    finds the device with nothing queued (dry_s_total covers the sleep)."""
+    import gc
+
+    from ray_tpu.observability import events
+    from ray_tpu.observability import profiling as prof
+
+    # no prefix reuse: the same prompt runs the same programs every time;
+    # a token a dispatch: dispatches are left when the first emit is over
+    eng = _mk_engine(prefix_cache_enabled=False, decode_block=1,
+                     pressure_decode_block=1)
+    prompt = "the request whose first emit sleeps"
+    cap = []
+    events.set_local_sink(cap.append)
+    try:
+        eng.generate(prompt, max_tokens=8)
+        real, planted = eng._finish_requests, []
+
+        def slow(finished):
+            if not planted:
+                planted.append(1)
+                time.sleep(0.2)
+            return real(finished)
+
+        gc.collect()                  # no full collection soon after
+        prof.watch_gc().stall = None
+        time.sleep(0.15)              # the loop parks
+        a = eng.engine_stats()
+        eng._prof._stall_told = 0.0   # the one-a-second limit starts anew
+        del cap[:]
+        eng._finish_requests = slow
+        eng.generate(prompt, max_tokens=8)
+        b = eng.engine_stats()
+    finally:
+        events.clear_local_sink()
+        eng.shutdown()
+    assert planted
+    assert b["host_stall_n"] - a["host_stall_n"] == 1
+    assert 0.2 <= b["host_stall_s_total"] - a["host_stall_s_total"] < 0.4
+    stalls = [e for e in cap if e["kind"] == "loop_stall"]
+    assert len(stalls) == 1 and stalls[0]["severity"] == "WARNING"
+    assert stalls[0]["reason"] == "emit"
+    attrs = stalls[0]["attrs"]
+    assert attrs["phase"] == "emit" and attrs["seconds"] >= 0.2
+    assert attrs["gc_s"] == 0.0 and "seq" in attrs
+    # the sleeping emit is followed by a dispatch (7 tokens to go) that
+    # finds what was in flight done: dry, for at least the sleep
+    assert b["dry_dispatches_total"] > a["dry_dispatches_total"]
+    assert b["dry_s_total"] - a["dry_s_total"] >= 0.2
+
+
+def test_a_stall_is_a_spans_own_time_and_the_gc_reports_through_the_loop():
+    """A slow child is not counted again as its parent; waits are never
+    stalls; a long full collection (any thread) is reported by the loop's
+    next span as phase gc, at most one event a second."""
+    from ray_tpu.observability import events
+    from ray_tpu.observability import profiling as prof
+
+    p = prof.EngineProfiler()
+    watch = prof.watch_gc()
+    watch.stall = None
+    cap = []
+    events.set_local_sink(cap.append)
+    try:
+        with p.span("loop_pass"):
+            with p.span("admit"):
+                with p.span("prefill", rid="r"):
+                    time.sleep(0.06)
+            with p.span("harvest", seq=3):
+                time.sleep(0.06)
+            with p.span("loop_wait"):
+                time.sleep(0.06)
+        st = p.stall_stats()
+        assert st["host_stall_n"] == 1 and 0.06 <= st["host_stall_s_total"] < 0.12
+        assert [e["reason"] for e in cap] == ["prefill"]
+        # within a second of that event another stall is counted, not told
+        with p.span("emit", seq=4):
+            time.sleep(0.06)
+        assert p.stall_stats()["host_stall_n"] == 2 and len(cap) == 1
+        p._stall_told = 0.0
+        watch.stall = (0.3, "http-handler")
+        with p.span("admit"):
+            pass
+        assert watch.stall is None
+        assert cap[-1]["reason"] == "gc" and cap[-1]["attrs"] == {
+            "phase": "gc", "seq": None, "seconds": 0.3, "gc_s": 0.3,
+            "thread": "http-handler"}
+        assert p.stall_stats()["host_stall_n"] == 2   # the collector's own
+    finally:
+        events.clear_local_sink()
+
+
+def test_stall_counters_ride_the_export_chain():
+    """engine_stats() -> llm_server _EXPORTED_STATS (gauges) -> controller
+    _ENGINE_KEYS (detailed_status, the dashboard); the controller's tuple
+    is function-local, so it is checked in source."""
+    import inspect
+
+    from ray_tpu.serve import controller
+    from ray_tpu.serve.llm import llm_server
+
+    keys = {"host_stall_s_total", "host_stall_n", "gc_pause_s_total",
+            "gc_pause_n", "gc_pause_max_ms", "gc_young_s_total",
+            "gc_young_n", "dry_dispatches_total", "dry_s_total"}
+    assert keys <= set(llm_server._EXPORTED_STATS)
+    engine_keys = inspect.getsource(controller).split(
+        "_ENGINE_KEYS = (", 1)[1]
+    for k in keys | {"clock_s"}:
+        assert f'"{k}"' in engine_keys, k
 
 
 # ---- names on device work ----------------------------------------------
