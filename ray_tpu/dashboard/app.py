@@ -959,6 +959,7 @@ def _render_profiling(apps: list[dict], artifacts: list[dict]) -> str:
                    "compile_s", "host_stall_n", "host_stall_s_total",
                    "gc_pause_n", "gc_pause_s_total", "gc_pause_max_ms",
                    "dry_dispatches_total", "dry_s_total",
+                   "idle_lead_k", "lead_climbs_total",
                    "kv_page_occupancy", "weights_bytes",
                    "kv_pool_bytes", "device_bytes_in_use"]
     sections = []

@@ -439,6 +439,10 @@ class ServeController:
                         "tier_prefetch_hit_pages",
                         "prefix_summary_version", "prefix_summary_pages",
                         "decode_block_effective", "pending_pipeline_depth",
+                        "dispatch_tier_admit_total",
+                        "dispatch_tier_pressure_total",
+                        "dispatch_tier_idle_total", "idle_lead_k",
+                        "lead_climbs_total", "lead_descents_total",
                         "spec_rounds", "spec_drafted_tokens",
                         "spec_accepted_tokens",
                         "attention_backend", "attn_backend_pallas",

@@ -59,22 +59,34 @@ class LLMConfig:
     # rule — see engine.kv_tier_namespace), so TP=1 and TP=2 stores
     # never exchange incompatible pages.
     tp_degree: int = 1
-    # decode steps fused into one dispatched program when the batch is
-    # steady (multi-step decode): per-token dispatch cost is one
-    # dispatch/decode_block. Streaming granularity and stop-token lag grow
-    # with it.
+    # The three values that size the engine loop's lead over the device.
+    # The device runs one ordered stream, so an arriving prompt's prefill
+    # runs behind whatever is in flight: at most pipeline_depth entries
+    # of k decode steps each, and the loop admits nothing while it waits
+    # for the oldest of them (engine.py _select_block, _step).
+    #
+    # decode_block: the CEILING of k, the decode steps fused into one
+    # dispatched program. With nothing queued the engine dispatches the
+    # smallest warmed tier that keeps the device fed (one step, then the
+    # pressure tier's k) and climbs to this value only while it sees the
+    # device run dry with less (lead.py; engine_stats idle_lead_k). Streaming
+    # granularity and stop-token lag grow with k. With a block length B
+    # above 1 (generation by diffusion over blocks) the tiers count
+    # whole blocks: this many tokens over B, at least one.
     decode_block: int = 8
-    # decode block while requests queue for slots (slot-starved): smaller
-    # blocks detect stop tokens (and free slots for the queue) sooner, at
-    # the cost of less dispatch amortization — the TTFT/throughput knob
-    # under saturation. 1-2 for latency-sensitive serving, decode_block to
-    # disable the tier.
+    # k while requests queue for slots (slot-starved): smaller blocks
+    # detect stop tokens (and free slots for the queue) sooner; also the
+    # idle tier's middle rung. A block pays its dispatch, its state
+    # gather / scatter and a re-layout of the attention weights once for
+    # k steps. 1-2 for latency-sensitive serving; decode_block makes the
+    # queue-pressure tier that size.
     pressure_decode_block: int = 2
-    # dispatched-but-unharvested decode blocks. TTFT under load is bounded
-    # below by pipeline_depth * decode_block * step_time (a fresh prefill
-    # executes behind the in-flight blocks), so latency-sensitive configs
-    # at large batch want SMALL blocks and a shallow pipeline; pure
-    # throughput wants them big/deep to amortize dispatch RTT.
+    # the ENTRY BOUND: dispatched-but-unharvested entries (decode blocks,
+    # verify rounds, prefills' first tokens). A first token waits behind
+    # at most pipeline_depth * k steps of device work, so the bound is as
+    # shallow as keeps the device fed while the host harvests, emits and
+    # dispatches (3 entries of ONE step of 13 ms do, on a v5e: the device
+    # idles 0.024 % of a trace; PERF.md section 6, PR 42).
     pipeline_depth: int = 3
 
     # compile all (bucket width, block) decode programs at start() instead

@@ -36,6 +36,7 @@ import numpy as np
 
 from ray_tpu.observability import events as _fr
 from ray_tpu.serve.llm.config import LLMConfig
+from ray_tpu.serve.llm.lead import IdleLead
 from ray_tpu.serve.llm.tokenizer import get_tokenizer
 
 logger = logging.getLogger(__name__)
@@ -371,7 +372,15 @@ class LLMEngine:
                       # queued although slots were live, and the seconds
                       # since the loop last knew it busy (_dry): an UPPER
                       # bound of the idle they stand for
-                      "dry_dispatches_total": 0, "dry_s_total": 0.0}
+                      "dry_dispatches_total": 0, "dry_s_total": 0.0,
+                      # decode / block dispatches by the tier that chose
+                      # their k (_select_block): admissions blocked (one
+                      # step), requests queue for slots (the pressure
+                      # tier), nothing waits (the idle tier, whose k is
+                      # ``idle_lead_k``: lead.py)
+                      "dispatch_tier_admit_total": 0,
+                      "dispatch_tier_pressure_total": 0,
+                      "dispatch_tier_idle_total": 0}
         # Tiered KV cache (kv_tier.py): evicted cached page chains spill
         # host-side into a shm/disk tier + cluster index instead of dying,
         # and _admit extends its longest-match search past the local index
@@ -414,9 +423,21 @@ class LLMEngine:
         self._spec_on = bool(cfg.spec_decode_enabled) \
             and not self._stateful and self._block_len == 1
         # last decode-block k actually dispatched + live pipeline depth
-        # (engine_stats gauges: the k=1/pressure/full tier transitions are
-        # observable instead of inferred from throughput wiggles)
+        # (engine_stats gauges: the k=1/pressure/idle tier transitions are
+        # observable instead of inferred from throughput wiggles), and
+        # the tier that chose it
         self._last_block = 0
+        self._last_tier = "idle"
+        # the idle tier's k (lead.py): the smallest of the three warmed
+        # tiers until the loop sees the device run dry with it, then the
+        # next; decode_block at most
+        self._collector = profiling_mod.watch_gc()
+        self._lead = IdleLead(
+            (self._blocks_of(1),
+             self._blocks_of(min(cfg.pressure_decode_block,
+                                 cfg.decode_block)),
+             self._blocks_of(cfg.decode_block)),
+            gc_n=self._collector.pause_n)
         # Pipelined decode (vLLM-style async token processing): each step's
         # input tokens are the previous step's on-device output, so steps
         # dispatch back-to-back without a host sync — the host harvests
@@ -1314,7 +1335,7 @@ class LLMEngine:
                "prefilling": prefilling, "restoring": restoring,
                "free_pages": free,
                # gauges: the decode-block tier actually dispatched last
-               # (1 / pressure_decode_block / decode_block — admission
+               # (1 / pressure_decode_block / the idle tier's — admission
                # pressure made visible) and the live dispatched-but-
                # unharvested block count (vs cfg.pipeline_depth)
                # sequences that hold a state row beside their pages (0
@@ -1322,7 +1343,11 @@ class LLMEngine:
                "state_slots_in_use": (active + prefilling + restoring
                                       if self._stateful else 0),
                "decode_block_effective": self._last_block,
-               "pending_pipeline_depth": len(self._pending)}
+               "pending_pipeline_depth": len(self._pending),
+               # the idle tier's k now, and how often the rule moved it
+               "idle_lead_k": self._lead.k,
+               "lead_climbs_total": self._lead.climbs,
+               "lead_descents_total": self._lead.descents}
         # introspection (observability/profiling.py): per-phase p50/p95 +
         # itl_s (None until sampled / while profiling_enabled=False),
         # compile-event counters (always live), device-memory gauges.
@@ -2332,32 +2357,44 @@ class LLMEngine:
 
     def _select_block(self) -> int:
         """Decode-block tier for the next dispatch (lock held). k is
-        STATIC to the jitted program: only three values ever occur (1
-        while admissions wait, pressure_decode_block while requests queue
-        for slots, decode_block otherwise), so at most three programs
-        compile per width. The slot-starved middle tier trades dispatch
-        amortization for TTFT: a finishing request's stop token is
-        detected (and its slot freed for the queue) within
-        ~pipeline_depth*k steps, so big blocks at saturation hold slots
-        long past completion.
+        STATIC to the jitted program, and every value returned here is
+        one start() warmed (_warmup_decode_programs), so nothing compiles
+        under traffic. Three tiers, by what the queue shows:
+
+        * admissions blocked (requests wait though slots are free, or a
+          chunked prefill is mid-flight): ONE step, so page reclamation
+          and the next chunk are not a block late;
+        * requests queue for slots: ``pressure_decode_block``. A
+          finishing request's stop token is seen (and its slot freed for
+          the queue) within ~pipeline_depth * k steps, so big blocks at
+          saturation hold slots long past completion;
+        * nothing waits (the idle tier): what keeps the device fed and no
+          more (lead.py). Whatever is in flight stands between an
+          arriving prompt's prefill and its first token, so the tier
+          starts at ONE step and climbs, through the pressure tier's k,
+          towards ``decode_block``, its CEILING, only while the loop sees
+          the device run dry with less (_decode_step reports every
+          idle-tier dispatch to the rule).
 
         With speculative decoding on, the idle tier is additionally capped
         at spec_draft_len: a draft can only continue the CURRENT head
         token, and the engine probes for drafts once per loop iteration,
         so long decode blocks would skip almost every draft opportunity
         (the head lands mid-block). Verify rounds are themselves k+1 fused
-        steps, so speculation recovers the dispatch amortization the
-        shorter blocks give up — and on non-repetitive traffic the cap is
-        the documented cost of leaving the flag on.
+        steps, and on non-repetitive traffic the cap is the documented
+        cost of leaving the flag on.
 
         With a block length B above 1 the tiers count whole BLOCKS: the
         configuration's token counts over B, at least one (_blocks_of)."""
         if self._admissions_blocked():
+            self._last_tier = "admit"
             return self._blocks_of(1)
         if self._waiting:
+            self._last_tier = "pressure"
             return self._blocks_of(min(self.cfg.pressure_decode_block,
                                        self.cfg.decode_block))
-        k = self._blocks_of(self.cfg.decode_block)
+        self._last_tier = "idle"
+        k = self._lead.k
         if self._spec_on:
             k = min(k, max(1, self.cfg.spec_draft_len))
         return k
@@ -2431,10 +2468,13 @@ class LLMEngine:
         round for slots with drafts (spec_decode_enabled), then one fused
         decode block for the rest; then hold the loop to its lead.
 
-        HOW FAR THE LOOP RUNS AHEAD OF THE DEVICE IS DECIDED HERE AND
-        NOWHERE ELSE: at most PIPELINE_DEPTH entries (decode blocks,
-        verify rounds, prefills' first tokens) stay in flight, and the
-        harvests below block until the device has retired the rest.
+        HOW MANY ENTRIES THE LOOP RUNS AHEAD OF THE DEVICE IS DECIDED
+        HERE AND NOWHERE ELSE: at most PIPELINE_DEPTH entries (decode
+        blocks, verify rounds, prefills' first tokens) stay in flight,
+        and the harvests below block until the device has retired the
+        rest. What an entry holds is _select_block's: the two together
+        are the device work an arriving prompt's prefill runs behind,
+        and the longest the loop sits in one harvest before it admits.
         Every admission queues an entry of its own beside the pass's
         block, so trimming one entry a dispatch let the backlog grow by
         one with each admission, and every stream's tokens reached its
@@ -2450,18 +2490,23 @@ class LLMEngine:
         return dispatched
 
     def _decode_step(self) -> bool:
-        """Dispatch one fused decode block (1..decode_block steps) without
-        waiting for its result; harvest PIPELINE_DEPTH blocks behind.
-        Device execution is a single ordered stream, so an in-flight block
-        that still references a freed slot's pages runs BEFORE any later
+        """Dispatch one fused decode block (_select_block's k steps: one
+        of the warmed tiers, decode_block at most) without waiting for
+        its result; _step harvests PIPELINE_DEPTH entries behind. Device
+        execution is a single ordered stream, so an in-flight block that
+        still references a freed slot's pages runs BEFORE any later
         prefill that reuses them.
 
         Steady-state decode is ONE jitted call with all-device arguments
         (page tables, seq lens, temps, last tokens, rng all live on device;
-        slot admissions patch them with one small jitted update). Block fusion
-        brings the per-token dispatch cost to 1/decode_block of a
-        dispatch; block size drops to 1 while admissions are pending so
-        new requests don't wait a whole block.
+        slot admissions patch them with one small jitted update). A block
+        of k steps pays a dispatch, the state gather / scatter and the
+        compiler's re-layout of wq / wk / wv once for k tokens a slot and
+        is k steps of lead over the device (on a v5e, Mistral-7B at depth
+        16 and widths 4-8, a step costs 12.49 ms in blocks of 8, 13.35 in
+        blocks of 2 and 13.08 alone: PERF.md section 6, PR 42). An
+        idle-tier dispatch tells the rule that sizes that tier whether
+        the device had run dry (lead.py).
 
         With a block length B above 1 the dispatch is ``k`` whole BLOCKS
         (_block_impl): k x ``denoise_passes`` passes, k x B tokens a
@@ -2486,6 +2531,8 @@ class LLMEngine:
             # page, and harvest discards them.
             k = self._select_block()
             self._last_block = k
+            tier = self._last_tier
+            self.stats[f"dispatch_tier_{tier}_total"] += 1
             dirty, self._dirty_slots = self._dirty_slots, {}
             overrides, self._overrides = self._overrides, {}
             # tokens in the cache of the block's slots as its first step
@@ -2516,19 +2563,28 @@ class LLMEngine:
         # excluded — they're already sampled inside _harvest_one.
         # inflight: entries pending as this block is dispatched; trimmed:
         # the harvests the bound then forces (_step) — a trace says how
-        # often, and how hard, the bound engages. dry: the device had
+        # often, and how hard, the bound engages. lead: the passes those
+        # entries hold (a prefill's first token: none), what this block
+        # and any prefill after it run behind. dry: the device had
         # nothing queued (_dry): the dispatch that ends an idle gap.
         inflight = len(self._pending)
+        lead = sum(e[2][1] + 1 if isinstance(e[2], tuple)
+                   else self._passes_of(e[2])
+                   for e in self._pending if e[3] >= 0)
         passes = self._passes_of(k)
         how = {"blocks": k, "passes": passes, "fused": fused} if bl > 1 \
             else {"k": k}
+        dry = self._dry()
+        if tier == "idle":
+            self._lead.observe(dry, self._collector.pause_n)
         with self._prof.span("block_dispatch" if bl > 1
                              else "decode_dispatch", seq=seq, **how, w=w,
                              active=len(active_slots),
                              ctx_tokens=ctx_tokens, inflight=inflight,
+                             lead=lead,
                              trimmed=max(
                                  0, inflight + 1 - self.PIPELINE_DEPTH),
-                             dry=self._dry()):
+                             dry=dry):
             toks = self._flush_slot_patches(dirty, overrides)
             idx = self._slot_index(active_slots, w)
             snapshot = [(col, slot, req, *skips[col:col + 1])

@@ -33,6 +33,11 @@ _EXPORTED_STATS = (
     "prefix_hit_pages", "prefix_cached_pages", "prefix_evictable_pages",
     "prefix_shared_pages", "prefix_evictions", "prefix_inserted_pages",
     "decode_block_effective", "pending_pipeline_depth",
+    # the tier that chose each decode block's k, and the idle tier's own
+    # (lead.py: how far the loop runs ahead of the device with no queue)
+    "dispatch_tier_admit_total", "dispatch_tier_pressure_total",
+    "dispatch_tier_idle_total", "idle_lead_k", "lead_climbs_total",
+    "lead_descents_total",
     # tiered KV cache (ISSUE 7): spill/restore economy + per-tier bytes
     "spilled_pages", "restored_pages", "tier_hit_tokens",
     "tier_bytes_shm", "tier_bytes_disk",

@@ -348,8 +348,11 @@ def test_pending_stays_within_pipeline_depth(depth, max_tokens):
 
 
 def test_decode_dispatch_span_says_how_hard_the_bound_engaged():
-    eng = LLMEngine(_cfg(max_batch_size=8, num_pages=128, pipeline_depth=2,
-                         max_tokens=5), rng_seed=0)
+    # one block of 8 ends every stream of 5 tokens, so each pass below
+    # starts from an empty engine
+    eng = _at_idle_tier(LLMEngine(
+        _cfg(max_batch_size=8, num_pages=128, pipeline_depth=2,
+             max_tokens=5), rng_seed=0), 8)
     spans = []
     span = eng._prof.span
 
@@ -379,9 +382,25 @@ def test_decode_dispatch_span_says_how_hard_the_bound_engaged():
     assert [d["trimmed"] for d in dispatches] == trims == [2] * 6
 
 
+def _at_idle_tier(eng, tier):
+    """Hold the idle tier (ISSUE 42: serve/llm/lead.py) at ``tier``: one
+    of the three k the engine warms. The parent ran it at decode_block."""
+    from ray_tpu.serve.llm.lead import IdleLead
+    eng._lead = IdleLead((tier,))
+    return eng
+
+
+IDLE_TIERS = pytest.mark.parametrize(
+    "tier", [1, 2, 8], ids=["k1", "pressure", "ceiling"])
+
+
+@IDLE_TIERS
 @pytest.mark.parametrize("chunk", [0, 16], ids=["whole", "chunked"])
-def test_greedy_tokens_of_a_fixed_seed_equal_the_parents(chunk):
-    eng = LLMEngine(_cfg(max_tokens=12, prefill_chunk=chunk), rng_seed=0)
+def test_greedy_tokens_of_a_fixed_seed_equal_the_parents(chunk, tier):
+    """... at every tier the idle lead can hold: greedy output does not
+    depend on how many steps a dispatch fuses."""
+    eng = _at_idle_tier(LLMEngine(_cfg(max_tokens=12, prefill_chunk=chunk),
+                                  rng_seed=0), tier)
     rids = [eng.submit(p, temperature=0.0) for p in PROMPTS]
     eng.start()
     try:
@@ -389,12 +408,16 @@ def test_greedy_tokens_of_a_fixed_seed_equal_the_parents(chunk):
     finally:
         eng.shutdown()
     assert got == PARENT_GREEDY
+    assert eng.stats["dispatch_tier_idle_total"] > 0
 
 
+@IDLE_TIERS
 @pytest.mark.parametrize("chunk", [0, 16], ids=["whole", "chunked"])
-def test_sampled_tokens_of_a_fixed_seed_equal_the_parents(chunk):
-    eng = LLMEngine(_cfg(max_tokens=24, top_k=0, prefill_chunk=chunk),
-                    rng_seed=7)
+def test_sampled_tokens_of_a_fixed_seed_equal_the_parents(chunk, tier):
+    """One stream alone: a key split a prefill or chunk and one a decode
+    step, in the same order however the steps are fused."""
+    eng = _at_idle_tier(LLMEngine(
+        _cfg(max_tokens=24, top_k=0, prefill_chunk=chunk), rng_seed=7), tier)
     rid = eng.submit(PROMPTS[2], temperature=1.0)
     eng.start()
     try:

@@ -402,8 +402,10 @@ def test_prefix_reuse_on_and_off_give_the_same_tokens():
 
 @pytest.fixture(scope="module")
 def counted():
-    """16 tokens after a prompt on a block edge: two dispatches of two
-    blocks, S = 2 passes a block, the first of each over two blocks."""
+    """16 tokens after a prompt on a block edge: four dispatches of one
+    block (nothing queues, so the idle tier runs the smallest warmed tier:
+    ISSUE 42; the parent ran two of two), S = 2 passes a block, the first
+    of each over two blocks."""
     eng = _engine()
     try:
         _serve(eng, [_prompt(5, 24)], 16)
@@ -426,7 +428,8 @@ COUNTS = {
     "routed_layer_steps_total": 8 * LAYERS,
     # a block: a pass of 2B rows and one of B, top_k experts a row
     "expert_rows_total": 4 * 3 * B * LAYERS * CFG.top_k,
-    "prefills": 1, "phase_block_dispatch_n": 2, "phase_decode_dispatch_n": 0,
+    "prefills": 1, "phase_block_dispatch_n": 4, "phase_decode_dispatch_n": 0,
+    "dispatch_tier_idle_total": 4, "idle_lead_k": 1,
 }
 
 

@@ -389,12 +389,20 @@ def test_engine_sheds_expired_waiting_request():
 def test_decode_block_tier_selection():
     """_select_block's three tiers: admissions blocked (waiting + free
     slots, or a chunked prefill mid-flight) -> 1; slot-starved (waiting,
-    no free slots) -> pressure_decode_block; idle -> decode_block."""
+    no free slots) -> pressure_decode_block; idle -> what keeps the
+    device fed: one step, then the pressure tier's k, and decode_block
+    (its ceiling) only once the loop has seen the device run dry with
+    less (ISSUE 42; tests/test_idle_lead.py holds the rule)."""
     from ray_tpu.serve.llm import LLMEngine
+    from ray_tpu.serve.llm.lead import CLIMB_DRY
+
+    def climb(eng):
+        for _ in range(CLIMB_DRY):
+            eng._lead.observe(1, eng._collector.pause_n)
 
     eng = LLMEngine(_tiny_cfg(decode_block=8, pressure_decode_block=2),
                     rng_seed=0)
-    assert eng._select_block() == 8          # idle: full block
+    assert eng._select_block() == 1          # idle: the smallest that feeds
     eng._waiting = [object()]
     assert eng._select_block() == 1          # waiting + free slots
     eng.free_slots = []
@@ -403,7 +411,13 @@ def test_decode_block_tier_selection():
     eng._prefilling = [object()]
     assert eng._select_block() == 1          # chunked prefill mid-flight
     eng._prefilling = []
-    assert eng._select_block() == 8          # back to idle
+    assert eng._select_block() == 1          # back to idle
+    climb(eng)
+    assert eng._select_block() == 2          # the host fell behind
+    climb(eng)
+    assert eng._select_block() == 8          # and again: the ceiling
+    eng._waiting = [object()]
+    assert eng._select_block() == 2          # the pressure tier keeps its k
 
     # pressure tier clamps to decode_block (a misconfigured larger value
     # must not out-dispatch the idle tier)
@@ -412,11 +426,17 @@ def test_decode_block_tier_selection():
     big._waiting = [object()]
     big.free_slots = []
     assert big._select_block() == 4
+    big._waiting = []
+    climb(big)
+    assert big._select_block() == 4
 
     # spec decode caps the idle tier at spec_draft_len (draft probing
     # happens between blocks; see _select_block docstring)
     spec = LLMEngine(_tiny_cfg(decode_block=8, spec_decode_enabled=True,
                                spec_draft_len=4), rng_seed=0)
+    assert spec._select_block() == 1
+    climb(spec)
+    climb(spec)
     assert spec._select_block() == 4
 
 

@@ -89,8 +89,14 @@ REPETITIVE = "abc abc abc abc abc"  # byte tokens; suffix n-grams recur
 
 def _run_engine(cfg, prompts, max_tokens, temperature=0.0):
     from ray_tpu.serve.llm import LLMEngine
+    from ray_tpu.serve.llm.lead import IdleLead
 
     eng = LLMEngine(cfg, rng_seed=0)
+    # the idle tier held at its ceiling, where these tests were written
+    # (blocks of spec_draft_len steps): on a random tiny model a draft
+    # fires or not by where the pipeline's drains land, and ISSUE 42's
+    # single steps move them (ROADMAP D11)
+    eng._lead = IdleLead(eng._lead.tiers[-1:])
     eng.start()
     try:
         rids = [eng.submit(p, max_tokens=max_tokens,

@@ -167,8 +167,13 @@ def test_tp2_spec_decode_identity_and_acceptance():
     """The verify-k program under TP: drafts accepted on a repetitive
     prompt, tokens still identical to the single-chip baseline."""
     want = _want_tokens(REPETITIVE, 32)
+    from ray_tpu.serve.llm.lead import IdleLead
+
     eng = LLMEngine(_tp_cfg(tp=2, spec_decode_enabled=True,
                             max_tokens=32), rng_seed=0)
+    # the idle tier at its ceiling, where this test was written: which
+    # drafts fire depends on where the pipeline's drains land (D11)
+    eng._lead = IdleLead(eng._lead.tiers[-1:])
     eng.start()
     try:
         out = eng.generate(REPETITIVE, max_tokens=32, temperature=0.0)
