@@ -16,6 +16,22 @@ that can be served provides, at module level:
     serve_embed(params, tokens, cfg) -> x
     serve_qkv(x, layer, cos, sin, cfg) -> q, k, v        (attention mixer)
     serve_attn_out(attn, layer) -> the mixer's output, before the residual
+    serve_latent(x, layer, cos, sin, cfg) -> q, entry    (latent mixer: its
+        cache spec states ``latent_dim``) ``entry`` [B, T, latent_dim] is
+        the ONE row a token leaves in the cache, and ``q`` [B, T, H,
+        latent_dim] the query with the per-head key expansion absorbed
+        into it, so that ``q . entry`` is the head's score against that
+        token and the first ``value_dim`` lanes of the rows, weighted by
+        the softmax, are what the head reads: multi-query attention with
+        one KV head whose key rows hold their own values
+    serve_latent_out(o, layer) -> the mixer's output, before the residual:
+        ``o`` [B, T, H, value_dim] the weighted rows, mapped back to the
+        heads' values and through the output projection
+    serve_latent_expanded(x, layer, cos, sin, cfg) -> q, k, v, entry
+        the same mixer with keys and values expanded per head (q and k
+        [B, T, H, head_dim], v [B, T, H, any width]) for a whole prompt
+        that attends to nothing cached; its output goes through
+        ``serve_attn_out``. The two forms give the same numbers.
     serve_conv(x, layer, prev, cfg) -> (x + mixer, ext)  (conv mixer only:
         ``prev`` [B, K-1, D] the state the sequence's earlier tokens left,
         ``ext`` [B, K-1+T, D] that state followed by this call's columns,
@@ -25,7 +41,8 @@ that can be served provides, at module level:
     serve_final_norm(x, params, cfg), serve_lm_head(x, params, cfg)
 
 ``cfg.head_dim``, ``cfg.dtype`` and ``cfg.max_seq_len`` are read off the
-configuration itself.
+configuration itself (``head_dim``: the width of a query and key head, so
+the softmax scale is ``head_dim ** -0.5`` for a latent mixer too).
 
 A block that generates by diffusion over blocks says so in its cache spec
 (``block_length`` B above 1, ``mask_token``) and provides two more values on
@@ -56,7 +73,10 @@ class CacheSpec:
     """What a block keeps between calls, a sequence.
 
     ``paged_layers`` layers write K and V of ``n_kv_heads`` x ``head_dim``
-    a token into pages. ``state_layers`` layers keep one array of
+    a token into pages; or, with ``latent_dim`` above 0, ONE row of
+    ``latent_dim`` lanes a token (``n_kv_heads`` 1), of which the first
+    ``value_dim`` are also the values: the pool is then one array, a
+    latent (compressed) cache. ``state_layers`` layers keep one array of
     ``state_shape`` a SEQUENCE (not a token): state that survives between
     decode steps, is carried from one prefill chunk to the next and cannot
     be rebuilt from the pages. ``routed_layers`` layers choose ``top_k`` of
@@ -74,6 +94,8 @@ class CacheSpec:
     n_experts: int = 0
     block_length: int = 1
     mask_token: int = -1
+    latent_dim: int = 0
+    value_dim: int = 0
 
 
 @dataclasses.dataclass(frozen=True)

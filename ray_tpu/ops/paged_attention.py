@@ -20,6 +20,14 @@ block_len + 1) * block_len``: key j is visible to query i iff j's block is
 not after i's; at ``block_len`` 1 (static) that is the causal ``col <=
 pos``.
 
+A LATENT pool (a cache spec with ``latent_dim``, models/block.py) is one
+array of one row a token, [L, 1, P, page, lanes], whose first
+``value_lanes`` lanes are also the row's values: every wrapper takes
+``value_lanes`` (and no value pool) and the kernel then reads each page
+ONCE, into one scratch of which the value operand is a static prefix of
+lanes. The rows are padded to whole 128-lane vectors with zeros, which
+meet whatever the query holds there and add nothing.
+
 Identity contract: greedy TOKENS under the pallas backend must equal the
 gather backend exactly (hard-asserted in tests and the serve bench), so
 the kernel computes the SAME dense-softmax numerics as the gather path —
@@ -62,6 +70,8 @@ _NEG_INF = -1e30
 # probability temporaries are each about this size again
 _SCORE_TILE_BYTES = 2 * 1024 * 1024
 _MAX_ROW_TILE = 256
+# query rows of one grid step of the chunk call on a latent pool
+_MAX_SPAN_ROWS = 2048
 
 
 def interpret_default() -> bool:
@@ -128,9 +138,10 @@ def _row_tiling(r: int, max_len: int, dtype) -> tuple[int, int]:
 
 
 def _paged_attn_kernel(pt_ref, base_ref, limit_ref, layer_ref,  # prefetch
-                       q_ref, k_ref, v_ref, o_ref, k_scr, v_scr, *,
+                       q_ref, k_ref, *rest,
                        sm_scale: float, page_size: int, num_pages: int,
-                       t_span: int, row_tile: int, block_len: int = 1):
+                       t_span: int, row_tile: int, block_len: int = 1,
+                       value_lanes: int = 0):
     """Grid (B, Hkv, num_pages); one (slot, kv-head) pair accumulates its
     pages into VMEM scratch and computes dense attention on the last page.
 
@@ -141,14 +152,21 @@ def _paged_attn_kernel(pt_ref, base_ref, limit_ref, layer_ref,  # prefetch
     pool page [page, D] (layer, kv-head and page block dimensions squeezed),
     selected by the block index map through the scalar-prefetched layer
     index and page table — the read IS the gather. ``layer_ref`` is read
-    by the index maps only.
+    by the index maps only. ``rest``: v_ref, o_ref, k_scr, v_scr; or, with
+    ``value_lanes`` (a latent pool), o_ref and k_scr alone: the values are
+    the first ``value_lanes`` lanes of the key rows in the one scratch.
     """
+    if value_lanes:
+        o_ref, k_scr = rest
+    else:
+        v_ref, o_ref, k_scr, v_scr = rest
     b = pl.program_id(0)
     p = pl.program_id(2)
 
     off = pl.multiple_of(p * page_size, page_size)
     k_scr[pl.ds(off, page_size)] = k_ref[...]
-    v_scr[pl.ds(off, page_size)] = v_ref[...]
+    if not value_lanes:
+        v_scr[pl.ds(off, page_size)] = v_ref[...]
 
     @pl.when(p == num_pages - 1)
     def _compute():
@@ -175,8 +193,9 @@ def _paged_attn_kernel(pt_ref, base_ref, limit_ref, layer_ref,  # prefetch
                     & (col < limit)
             s = jnp.where(valid, s, _NEG_INF)
             w = jax.nn.softmax(s, axis=-1).astype(q.dtype)
+            values = k_scr[:, :value_lanes] if value_lanes else v_scr[:]
             o_ref[0, 0, pl.ds(r0, row_tile), :] = jax.lax.dot_general(
-                w, v_scr[:], (((1,), (0,)), ((), ())),
+                w, values, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32).astype(o_ref.dtype)
             return carry
 
@@ -186,7 +205,8 @@ def _paged_attn_kernel(pt_ref, base_ref, limit_ref, layer_ref,  # prefetch
 def paged_attention(q, k_pages, v_pages, page_tables, base, limit=None,
                     layer=None, *, sm_scale: float | None = None,
                     interpret: bool | None = None,
-                    name: str = "paged_attention", block_len: int = 1):
+                    name: str = "paged_attention", block_len: int = 1,
+                    value_lanes: int = 0):
     """Fused paged attention over the whole query span.
 
     q: [B, T, H, D] — query position of q[:, t] is ``base + t`` (causal
@@ -206,8 +226,14 @@ def paged_attention(q, k_pages, v_pages, page_tables, base, limit=None,
     prefill passes ``true_len`` so padded tail pages stay masked. name:
     the kernel's name in the compiled program and in a profiler trace
     (each of the three callers below passes its own).
+    ``value_lanes`` (static) above 0: ``k_pages`` is a latent pool
+    [L, 1, P, page, lanes] and ``v_pages`` None; the H query heads all
+    read the one row a token, q [B, T, H, <= lanes] is padded with zeros
+    to the rows' lanes, and the result is [B, T, H, value_lanes].
     Returns [B, T, H, D] in q.dtype.
     """
+    if value_lanes:
+        q = jnp.pad(q, ((0, 0),) * 3 + ((0, k_pages.shape[4] - q.shape[3]),))
     b, t, h, d = q.shape
     if k_pages.ndim == 4:
         k_pages, v_pages, layer = k_pages[None], v_pages[None], 0
@@ -247,7 +273,12 @@ def paged_attention(q, k_pages, v_pages, page_tables, base, limit=None,
     kernel = functools.partial(
         _paged_attn_kernel, sm_scale=sm_scale, page_size=page_size,
         num_pages=max_pages, t_span=t, row_tile=row_tile,
-        block_len=block_len)
+        block_len=block_len, value_lanes=value_lanes)
+    d_out = value_lanes or d
+    # the paged read: block index (lyr[0], hi, pt[bi, pi]) picks the
+    # layer's pool page straight off the scalar-prefetched layer index
+    # and table; a latent pool is read once (no value pool, one scratch)
+    pools = (k_pages,) if value_lanes else (k_pages, v_pages)
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -257,24 +288,17 @@ def paged_attention(q, k_pages, v_pages, page_tables, base, limit=None,
                 pl.BlockSpec((1, 1, r_pad, d),
                              lambda bi, hi, pi, pt, bs, lim, lyr:
                              (bi, hi, 0, 0)),
-                # the paged read: block index (lyr[0], hi, pt[bi, pi])
-                # picks the layer's pool page straight off the
-                # scalar-prefetched layer index and table
+            ] + [
                 pl.BlockSpec((None, None, None, page_size, d),
                              lambda bi, hi, pi, pt, bs, lim, lyr:
-                             (lyr[0], hi, pt[bi, pi], 0, 0)),
-                pl.BlockSpec((None, None, None, page_size, d),
-                             lambda bi, hi, pi, pt, bs, lim, lyr:
-                             (lyr[0], hi, pt[bi, pi], 0, 0)),
-            ],
+                             (lyr[0], hi, pt[bi, pi], 0, 0))
+                for _ in pools],
             out_specs=pl.BlockSpec(
-                (1, 1, r_pad, d),
+                (1, 1, r_pad, d_out),
                 lambda bi, hi, pi, pt, bs, lim, lyr: (bi, hi, 0, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((max_len, d), k_pages.dtype),
-                pltpu.VMEM((max_len, d), v_pages.dtype),
-            ]),
-        out_shape=jax.ShapeDtypeStruct((b, hkv, r_pad, d), q.dtype),
+            scratch_shapes=[pltpu.VMEM((max_len, d), pool.dtype)
+                            for pool in pools]),
+        out_shape=jax.ShapeDtypeStruct((b, hkv, r_pad, d_out), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=max(32 * 1024 * 1024, 2 * vmem)),
@@ -282,9 +306,9 @@ def paged_attention(q, k_pages, v_pages, page_tables, base, limit=None,
         name=name,
     )(page_tables.astype(jnp.int32), base.astype(jnp.int32),
       limit.astype(jnp.int32), jnp.reshape(layer, (1,)).astype(jnp.int32),
-      qg, k_pages, v_pages)
-    return out[:, :, :r].reshape(b, hkv, n_rep, t, d).transpose(
-        0, 3, 1, 2, 4).reshape(b, t, h, d)
+      qg, *pools)
+    return out[:, :, :r].reshape(b, hkv, n_rep, t, d_out).transpose(
+        0, 3, 1, 2, 4).reshape(b, t, h, d_out)
 
 
 def _packed_heads(q, k_pages, v_pages, page_tables, base, limit, layer, *,
@@ -317,20 +341,23 @@ def _packed_heads(q, k_pages, v_pages, page_tables, base, limit, layer, *,
 
 def paged_decode_attention(q, k_pages, v_pages, page_tables, pos,
                            layer=None, *, sm_scale: float | None = None,
-                           interpret: bool | None = None):
+                           interpret: bool | None = None,
+                           value_lanes: int = 0):
     """Single-token decode attention: q [B, H, D], new token at position
     ``pos[b]`` (attends 0..pos inclusive — its own k/v is already written
     to the pool). Pool and ``layer`` as in :func:`paged_attention`.
     Returns [B, H, D]."""
     out = paged_attention(q[:, None], k_pages, v_pages, page_tables, pos,
                           layer=layer, sm_scale=sm_scale, interpret=interpret,
-                          name="paged_decode_attention")
+                          name="paged_decode_attention",
+                          value_lanes=value_lanes)
     return out[:, 0]
 
 
 def paged_verify_attention(q, k_pages, v_pages, page_tables, seq_lens,
                            layer=None, *, sm_scale: float | None = None,
-                           interpret: bool | None = None):
+                           interpret: bool | None = None,
+                           value_lanes: int = 0):
     """Multi-query speculative verify: q [B, T, H, D], T = k+1 draft span
     per slot, q[b, t] at position ``seq_lens[b] + t`` — causal within the
     span, full attention over the slot's cached pages (all T spans' k/v
@@ -338,7 +365,8 @@ def paged_verify_attention(q, k_pages, v_pages, page_tables, seq_lens,
     return paged_attention(q, k_pages, v_pages, page_tables, seq_lens,
                            layer=layer, sm_scale=sm_scale,
                            interpret=interpret,
-                           name="paged_verify_attention")
+                           name="paged_verify_attention",
+                           value_lanes=value_lanes)
 
 
 def paged_block_attention(q, k_pages, v_pages, page_tables, seq_lens,
@@ -360,15 +388,35 @@ def paged_block_attention(q, k_pages, v_pages, page_tables, seq_lens,
 def paged_chunk_attention(q, k_pages, v_pages, page_table, start, true_len,
                           layer=None, *, sm_scale: float | None = None,
                           interpret: bool | None = None,
-                          block_len: int = 1):
+                          block_len: int = 1, value_lanes: int = 0):
     """Chunked-prefill attention for ONE slot: q [1, C, H, D] chunk whose
     first token sits at position ``start``; keys are the slot's whole
     paged view (earlier chunks + this one, pre-written) bounded by
     ``true_len``; causal, or by blocks of ``block_len``. Returns
-    [1, C, H, D]."""
+    [1, C, H, D].
+
+    On a latent pool every head's rows lie on the ONE KV head, C x H of
+    them: more than a query block should hold in VMEM (512 x 32 rows of 640
+    lanes: 21 MB, twice for the pipeline). The chunk is then cut into spans
+    of positions, each a slot of its own on the same page table (its base
+    the span's first position), at most ``_MAX_SPAN_ROWS`` rows a span."""
     base = jnp.reshape(start, (1,)).astype(jnp.int32)
     limit = jnp.reshape(true_len, (1,)).astype(jnp.int32)
-    return paged_attention(q, k_pages, v_pages, page_table[None], base,
-                           limit, layer, sm_scale=sm_scale,
-                           interpret=interpret,
-                           name="paged_chunk_attention", block_len=block_len)
+    _, c, h, _ = q.shape
+    span = max(1, _MAX_SPAN_ROWS // h)
+    if not value_lanes or c <= span or c % span:
+        return paged_attention(
+            q, k_pages, v_pages, page_table[None], base, limit, layer,
+            sm_scale=sm_scale, interpret=interpret,
+            name="paged_chunk_attention", block_len=block_len,
+            value_lanes=value_lanes)
+    n = c // span
+    out = paged_attention(
+        q.reshape(n, span, h, q.shape[3]), k_pages, None,
+        jnp.broadcast_to(page_table[None], (n,) + page_table.shape),
+        base + span * jnp.arange(n, dtype=jnp.int32),
+        jnp.broadcast_to(limit, (n,)), layer, sm_scale=sm_scale,
+        interpret=interpret, name="paged_chunk_attention",
+        block_len=block_len, value_lanes=value_lanes)
+    return out.reshape(1, c, h, value_lanes)
+

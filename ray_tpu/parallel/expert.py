@@ -143,10 +143,12 @@ def moe_layer_tokens_sharded(x, gate_w, expert_fn: Callable, expert_params,
 # ---------------------------------------------------------------------------
 
 def route_sigmoid_top_k(g, router, bias, top_k: int, *,
-                        norm_topk_prob: bool = True, scaling: float = 1.0):
+                        norm_topk_prob: bool = True, scaling: float = 1.0,
+                        norm_eps: float = 1e-6):
     """Sigmoid scores over ALL experts, the ``top_k`` largest of score +
     bias chosen, combine weights from the scores alone (the bias selects
-    and never weighs), normalised over the chosen. g: [N, D]; router:
+    and never weighs), normalised over the chosen (their sum +
+    ``norm_eps``: the published codes differ in it). g: [N, D]; router:
     [D, E]; bias: [E] float32 or None. Returns (idx [N, k] int32,
     w [N, k] float32)."""
     logits = jnp.dot(g, router, preferred_element_type=jnp.float32)
@@ -155,7 +157,7 @@ def route_sigmoid_top_k(g, router, bias, top_k: int, *,
     _, idx = jax.lax.top_k(sel, top_k)
     w = jnp.take_along_axis(s, idx, axis=-1)
     if norm_topk_prob:
-        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-6)
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + norm_eps)
     return idx.astype(jnp.int32), w * scaling
 
 

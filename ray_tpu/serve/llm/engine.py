@@ -204,6 +204,13 @@ class LLMEngine:
         # block: each is turned off or refused, and counted
         # (<x>_bypassed_block, disagg_refused_block)
         self._block_len = int(self._cache_spec.block_length)
+        # a block with a latent cache (one row a token, a pool of ONE
+        # array): prefix reuse, speculation and everything else that is
+        # page bookkeeping work as they are; the kv tier's and the
+        # hand-off's host blobs are pairs of K and V pages, so each is
+        # turned off or refused, and counted (kv_tier_bypassed_latent,
+        # disagg_refused_latent)
+        self._latent = kvc.has_latent_cache(self.model_cfg)
         if self._block_len > 1:
             for name in ("page_size", "max_seq_len", "prefill_chunk"):
                 if getattr(cfg, name) % self._block_len:
@@ -368,6 +375,9 @@ class LLMEngine:
                       "spec_bypassed_block": 0,
                       "kv_tier_bypassed_block": 0,
                       "disagg_refused_block": 0,
+                      # what a block with a latent cache is kept out of
+                      "kv_tier_bypassed_latent": 0,
+                      "disagg_refused_latent": 0,
                       # dispatches that found the device with nothing
                       # queued although slots were live, and the seconds
                       # since the loop last knew it busy (_dry): an UPPER
@@ -389,7 +399,8 @@ class LLMEngine:
         # the pages); the host copy + object-store put happen later on the
         # loop, off the admission hot path (_kv_tier_flush).
         self._kv_tier_on = bool(cfg.kv_tier_enabled) \
-            and self._prefix_cache_on and self._block_len == 1
+            and self._prefix_cache_on and self._block_len == 1 \
+            and not self._latent
         self._kv_tier = None
         self._tier_pending: list = []  # [(dev_k, dev_v, [(page, dig, pos)])]
         # drain-time eager spill handshake (ISSUE 14): spill_inflight()
@@ -1393,6 +1404,10 @@ class LLMEngine:
             f"{a}={n}" for a, n in dict(self._mesh.shape).items()
             if n > 1))
         pool_bytes = self._kvc.pool_nbytes(self.kv)
+        # what a cached token costs the pool, all layers (padding lanes
+        # included: a latent cache of 576 numbers a layer in rows of 640)
+        out["kv_bytes_per_token"] = pool_bytes // (
+            self.cfg.num_pages * self.cfg.page_size)
         out["kv_shard_pool_bytes"] = pool_bytes // self._tp
         out["kv_shard_page_occupancy"] = (
             (self.cfg.num_pages - free) * pool_bytes
@@ -1689,6 +1704,10 @@ class LLMEngine:
                             and self.cfg.prefix_cache_enabled \
                             and len(req.prompt_tokens) > self.cfg.page_size:
                         self.stats["kv_tier_bypassed_block"] += 1
+                if self._latent and self.cfg.kv_tier_enabled \
+                        and self._prefix_cache_on \
+                        and len(req.prompt_tokens) > self.cfg.page_size:
+                    self.stats["kv_tier_bypassed_latent"] += 1
             # queue-wait phase sample (submit→admit), recorded OUTSIDE the
             # lock: the profiler observes a metrics histogram, which must
             # never run under the engine lock (graftlint lock-discipline)
@@ -1747,6 +1766,12 @@ class LLMEngine:
             raise NotImplementedError(
                 f"{what}: the block keeps per-sequence state beside its "
                 f"pages, which a handoff does not carry")
+        if self._latent:
+            with self._lock:
+                self.stats["disagg_refused_latent"] += 1
+            raise NotImplementedError(
+                f"{what}: the block keeps one latent row a token, and a "
+                f"handoff's wire format carries pairs of K and V pages")
 
     def _route_admitted(self, req: _Request) -> None:
         """Send an admitted request (prefix matched, tier restore — if

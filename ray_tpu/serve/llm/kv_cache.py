@@ -42,6 +42,12 @@ Design choices:
   block's positions against the cache, committing or not, and
   :func:`paged_block_pair_step` a clean pending block and the one after
   it in one pass (the engine's: the commit rides with the next denoise);
+- a block whose cache spec states ``latent_dim`` keeps ONE row a token and
+  layer (:func:`_latent_mixer`): the pool is one array, a whole prompt is
+  attended in the mixer's expanded form (per-head keys and values, nothing
+  read back), and every program that reads the cache runs the absorbed
+  form against the pool, all heads on the one row, the values a prefix of
+  the row's lanes;
 - tensor parallelism (ISSUE 20): every step function takes an optional
   ``mesh``. With a live "tensor" axis the pool is sharded per-KV-head
   (axis 1) and the q heads split into exactly the matching kv-head
@@ -55,7 +61,8 @@ Design choices:
 Page 0 is RESERVED as the trash page; the allocator never hands it out.
 
 This module alone knows the DEVICE pool's format (two arrays, pages on axis
-2, KV heads on axis 1; beside them whatever else the block's cache spec
+2, KV heads on axis 1, or ONE array of one row a token where the block's
+cache spec states a latent cache; beside them whatever else the spec
 asks for: ``init_paged_cache``): the engine, disaggregation and the tier
 path move pages through the page operations below ``init_paged_cache``.
 It names no architecture: the block comes with the model configuration
@@ -99,6 +106,12 @@ def init_paged_cache(cfg, num_pages: int, page_size: int, tp: int = 1):
     pool. The paged programs carry both arrays through their loops and only
     ever update them in place (see the module docstring).
 
+    A LATENT cache (the spec states ``latent_dim``): ``k`` alone, [paged_
+    layers, 1, num_pages, page_size, lanes], one row a token whose first
+    ``value_dim`` lanes are also its values; ``lanes`` is ``latent_dim``
+    rounded up to whole 128-lane vectors (:func:`latent_lanes`), the
+    padding zeros for ever. There is no ``v``.
+
     ``state`` (a block with slot state only): one array [num_pages,
     prod(state_shape)] a layer that keeps state (flat: a [2, D] row would
     be padded to whole sublane tiles). A sequence's row is its FIRST
@@ -114,9 +127,14 @@ def init_paged_cache(cfg, num_pages: int, page_size: int, tp: int = 1):
     max_seq_len, top_k], the experts the LAST program call chose for each
     of its token rows."""
     spec = block_of(cfg).cache_spec(cfg)
-    heads, lanes = pool_heads_lanes(spec.n_kv_heads, spec.head_dim, tp)
-    shape = (spec.paged_layers, heads, num_pages, page_size, lanes)
-    kv = {"k": jnp.zeros(shape, cfg.dtype), "v": jnp.zeros(shape, cfg.dtype)}
+    if spec.latent_dim:
+        kv = {"k": jnp.zeros((spec.paged_layers, 1, num_pages, page_size,
+                              latent_lanes(spec.latent_dim)), cfg.dtype)}
+    else:
+        heads, lanes = pool_heads_lanes(spec.n_kv_heads, spec.head_dim, tp)
+        shape = (spec.paged_layers, heads, num_pages, page_size, lanes)
+        kv = {"k": jnp.zeros(shape, cfg.dtype),
+              "v": jnp.zeros(shape, cfg.dtype)}
     if spec.state_layers:
         kv["state"] = tuple(
             jnp.zeros((num_pages, int(np.prod(spec.state_shape))), cfg.dtype)
@@ -142,6 +160,22 @@ def pool_heads_lanes(n_kv_heads: int, head_dim: int,
     return n_kv_heads, head_dim
 
 
+def latent_lanes(latent_dim: int) -> int:
+    """Lanes of a latent pool's row: ``latent_dim`` rounded up to whole
+    128-lane vectors (576 -> 640: a ninth of the pool is padding), so that
+    a page block is whole vectors (``paged_attention.can_tile``) and the
+    compiler has no reason to re-lay the pool out around a token write.
+    The padding is written as zeros and meets zeros in the query."""
+    return -(-latent_dim // 128) * 128
+
+
+def has_latent_cache(cfg) -> bool:
+    """Whether the block keeps ONE row a token (a pool of one array, no K
+    and V): the kv tier's and the hand-off's host blobs are pairs of K and
+    V pages, so the engine does neither for such a block (and counts)."""
+    return block_of(cfg).cache_spec(cfg).latent_dim > 0
+
+
 def has_slot_state(cfg) -> bool:
     """Whether the block keeps per-sequence state beside its pages: state
     that pages, ``seq_len`` and a page table do not restore. Prefix reuse,
@@ -156,13 +190,17 @@ def page_raw_nbytes(cfg, page_size: int) -> int:
     the pool spec (not a live array) so byte-budget callers (stream
     prefetch window, chunk sizing) can size before any page exists."""
     spec = block_of(cfg).cache_spec(cfg)
+    if spec.latent_dim:
+        per = spec.paged_layers * page_size * latent_lanes(spec.latent_dim)
+        return per * np.dtype(cfg.dtype).itemsize
     per = spec.paged_layers * spec.n_kv_heads * page_size * spec.head_dim
     return 2 * per * np.dtype(cfg.dtype).itemsize
 
 
 def pool_nbytes(kv) -> int:
-    """Bytes the whole pool holds on the device(s), k + v."""
-    return int(kv["k"].nbytes + kv["v"].nbytes)
+    """Bytes the whole pool holds on the device(s): k + v, or the one
+    array of a latent cache."""
+    return int(sum(kv[n].nbytes for n in ("k", "v") if n in kv))
 
 
 def pool_dtype(kv):
@@ -179,15 +217,19 @@ def pool_spec():
 
 def gather_pages(kv, pages):
     """Pool pages ``pages`` ([n] ints) as a device blob pair (bk, bv), each
-    [L, Hkv, n, page, D]."""
+    [L, Hkv, n, page, D]; of a latent pool (one array) ``bv`` is None, here
+    and in every page operation below."""
     pidx = np.asarray(pages, np.int32)
-    return jnp.take(kv["k"], pidx, axis=2), jnp.take(kv["v"], pidx, axis=2)
+    return tuple(jnp.take(kv[n], pidx, axis=2) if n in kv else None
+                 for n in ("k", "v"))
 
 
 def scatter_pages(kv, bk, bv, pages):
     """Write blob page i of (bk, bv) into pool page ``pages[i]``. The body of
     the engine's donated inject program: the pool is rewritten in place. A
     blob padded with zero pages targets the trash page with them."""
+    if bv is None:
+        return {**kv, "k": kv["k"].at[:, :, pages].set(bk)}
     return {**kv, "k": kv["k"].at[:, :, pages].set(bk),
             "v": kv["v"].at[:, :, pages].set(bv)}
 
@@ -195,13 +237,15 @@ def scatter_pages(kv, bk, bv, pages):
 def fetch_pages(bk, bv, n: int):
     """Host copy of the first ``n`` pages of a :func:`gather_pages` result
     (a gather at a fixed width is padded with the trash page past them)."""
-    return np.asarray(bk)[:, :, :n], np.asarray(bv)[:, :, :n]
+    return tuple(None if b is None else np.asarray(b)[:, :, :n]
+                 for b in (bk, bv))
 
 
 def zero_pages(kv, n: int):
     """A host blob pair of ``n`` zero pages in the pool's shape and dtype."""
     shape = kv["k"].shape[:2] + (n,) + kv["k"].shape[3:]
-    return np.zeros(shape, kv["k"].dtype), np.zeros(shape, kv["v"].dtype)
+    return tuple(np.zeros(shape, kv[m].dtype) if m in kv else None
+                 for m in ("k", "v"))
 
 
 def pack_pages(pairs, width: int):
@@ -211,11 +255,14 @@ def pack_pages(pairs, width: int):
     compiled at."""
     first = pairs[0][0]
     shape = first.shape[:2] + (width,) + first.shape[3:]
-    bk, bv = np.zeros(shape, first.dtype), np.zeros(shape, first.dtype)
+    bk = np.zeros(shape, first.dtype)
+    bv = None if pairs[0][1] is None else np.zeros(shape, first.dtype)
     at = 0
     for k, v in pairs:
         n = k.shape[2]
-        bk[:, :, at:at + n], bv[:, :, at:at + n] = k, v
+        bk[:, :, at:at + n] = k
+        if bv is not None:
+            bv[:, :, at:at + n] = v
         at += n
     return bk, bv
 
@@ -589,6 +636,43 @@ def _write_token_kv(k_pool, v_pool, layer, k_new, v_new, page_idx, offset):
             v_pool.at[idx].set(v_new.reshape(rows).astype(v_pool.dtype)))
 
 
+def _write_token_rows(pool, layer, new, page_idx, offset):
+    """:func:`_write_token_kv` for a latent pool [L, 1, P, page, lanes]:
+    ``new`` [..., latent_dim] at page_idx / offset [...], padded with zeros
+    to the rows' lanes. The same ONE scatter of single rows, the (one)
+    head among the scattered indices, and one array written."""
+    new = jnp.pad(new, ((0, 0),) * (new.ndim - 1)
+                  + ((0, pool.shape[4] - new.shape[-1]),))
+    idx = (layer, jnp.arange(1), page_idx[..., None], offset[..., None])
+    return pool.at[idx].set(new[..., None, :].astype(pool.dtype))
+
+
+def _latent_mixer(x, kv, layer, l, cos, sin, cfg, page_idx, offset, attend):
+    """A mixer that keeps one latent row a token, in its ABSORBED form
+    (models/block.py ``serve_latent``): the rows of x [B, T, D] are written
+    to the pool at page_idx / offset [B, T], then ``attend(q, pool, l)``
+    runs q [B, T, H, latent_dim] against layer ``l`` of the pool and
+    returns the weighted rows' first ``value_dim`` lanes [B, T, H,
+    value_dim]. Returns (x + mixer, kv)."""
+    blk = block_of(cfg)
+    q, entry = blk.serve_latent(x, layer, cos, sin, cfg)
+    with jax.named_scope("kv_latent"):
+        pool = _write_token_rows(kv["k"], l, entry, page_idx, offset)
+    with jax.named_scope("attn"):
+        o = attend(q, pool, l)
+    return x + blk.serve_latent_out(o, layer), {**kv, "k": pool}
+
+
+def _latent_gather_attention(q, pool, layer, page_tables, valid, sm,
+                             value_dim: int):
+    """The gather backend's absorbed attention: the slots' rows as one
+    sequence (padding lanes dropped), all heads on the one row, the values
+    its first ``value_dim`` lanes. q [B, T, H, latent_dim]; valid
+    broadcastable to [B, H, T, L]."""
+    rows = _gather_seq(pool, layer, page_tables)[..., :q.shape[-1]]
+    return _dense_attention(q, rows, rows[..., :value_dim], valid, sm)
+
+
 def _gather_seq(pool, layer, page_tables, head_dim: int | None = None):
     """The gather backend's read: layer ``layer`` of the slots' pages as
     one contiguous sequence. pool: [L, Hkv, P, page, D]; page_tables:
@@ -637,8 +721,14 @@ def _over_layers(step, x, kv, params, cfg):
     return x, kv
 
 
-def _attends(ld) -> bool:
-    return ld is None or ld.mixer == "attn"
+def _keeps_state(ld) -> bool:
+    """A mixer with slot state (models/block.py ``serve_conv``)."""
+    return ld is not None and ld.mixer == "conv"
+
+
+def _latent(ld) -> bool:
+    """A mixer that keeps one latent row a token (``serve_latent``)."""
+    return ld is not None and ld.mixer == "latent"
 
 
 def _ffn(x, kv, layer, cfg, ld):
@@ -709,6 +799,9 @@ def _use_pallas_decode(cfg=None, page_size: int = 0, tp: int = 1) -> bool:
     if cfg is None:
         return True
     from ray_tpu.ops.paged_attention import can_tile
+    latent = getattr(cfg, "latent_dim", 0)
+    if latent:        # the kernel reads the pool's padded rows
+        return can_tile(latent_lanes(latent), page_size, cfg.dtype)
     return can_tile(cfg.head_dim, page_size,
                     getattr(cfg, "dtype", jnp.bfloat16),
                     max(1, getattr(cfg, "n_kv_heads", 2) // tp))
@@ -756,8 +849,10 @@ def tp_degree(mesh) -> int:
 def _dense_attention(q, k, v, mask, sm):
     """Dense-softmax attention, the numerics every backend reproduces:
     float32 logits scaled by ``sm``, masked with -1e30, full-row float32
-    softmax, probabilities cast back to q.dtype. q: [B, T, H, D]; k/v:
-    [B, L, Hkv, D]; mask: broadcastable to [B, H, T, L]."""
+    softmax, probabilities cast back to q.dtype. q: [B, T, H, D]; k:
+    [B, L, Hkv, D]; v: [B, L, Hkv, Dv] (Dv need not be D: a latent
+    mixer's values are narrower than its keys in either form); mask:
+    broadcastable to [B, H, T, L]. Returns [B, T, H, Dv]."""
     n_rep = q.shape[2] // k.shape[2]
     k_full = gqa_expand(k, n_rep)
     v_full = gqa_expand(v, n_rep)
@@ -843,8 +938,27 @@ def paged_decode_step(params, kv, page_tables, seq_lens, tokens,
         page_tables, (pos // page_size)[:, None], axis=1)[:, 0]  # [B]
     offset = pos % page_size
 
+    sm = cfg.head_dim ** -0.5
+    value_dim = blk.cache_spec(cfg).value_dim
+
+    def latent_attend(q, pool, l):
+        if attn_backend == "pallas":
+            from ray_tpu.ops import paged_attention as paged_ops
+            return paged_ops.paged_decode_attention(
+                q[:, 0], pool, None, page_tables, pos, l, sm_scale=sm,
+                value_lanes=value_dim)[:, None]
+        valid = jnp.arange(page_tables.shape[1] * page_size)[None, :] \
+            <= pos[:, None]                                       # [B, L]
+        return _latent_gather_attention(
+            q, pool, l, page_tables, valid[:, None, None], sm, value_dim)
+
     def step(x, kv, layer, ld, l):
-        if not _attends(ld):
+        if _latent(ld):
+            x, kv = _latent_mixer(
+                x, kv, layer, l, cos, sin, cfg, page_idx[:, None],
+                offset[:, None], latent_attend)
+            return _ffn(x, kv, layer, cfg, ld)
+        if _keeps_state(ld):
             x, kv = _conv_mixer(x, kv, layer, cfg, ld, page_tables[:, 0])
             return _ffn(x, kv, layer, cfg, ld)
         q, k, v = blk.serve_qkv(x, layer, cos, sin, cfg)
@@ -1019,8 +1133,23 @@ def _span_step(params, kv, page_tables, seq_lens, tokens, cfg, page_size,
     # to the end of its block
     valid = _visible(kpos[None, None, :], pos[:, :, None], block_len)
     sm = cfg.head_dim ** -0.5
+    value_dim = blk.cache_spec(cfg).value_dim
+
+    def latent_attend(q, pool, l):
+        if attn_backend == "pallas":
+            from ray_tpu.ops import paged_attention as paged_ops
+            return paged_ops.paged_verify_attention(
+                q, pool, None, page_tables, seq_lens, l, sm_scale=sm,
+                value_lanes=value_dim)
+        return _latent_gather_attention(q, pool, l, page_tables,
+                                        valid[:, None], sm, value_dim)
 
     def step(x, kv, layer, ld, l):
+        if _latent(ld):
+            x, kv = _latent_mixer(
+                x, kv, layer, l, cos, sin, cfg, page_idx, offset,
+                latent_attend)
+            return _ffn(x, kv, layer, cfg, ld)
         q, k, v = blk.serve_qkv(x, layer, cos, sin, cfg)
         with jax.named_scope("kv_write"):
             # write all T tokens' k/v, then attend through the paged view —
@@ -1079,11 +1208,24 @@ def paged_prefill(params, kv, page_table, tokens, true_len,
     sm = cfg.head_dim ** -0.5
 
     def step(x, kv, layer, ld, l):
-        if not _attends(ld):
+        if _keeps_state(ld):
             x, kv = _conv_mixer(
                 x, kv, layer, cfg, ld, page_table[:1], fresh=True,
                 n_real=jnp.reshape(true_len, (1,)).astype(jnp.int32))
             return _ffn(x, kv, layer, cfg, ld)
+        if _latent(ld):
+            # the mixer's EXPANDED form: keys and values per head from the
+            # prompt's own rows, nothing read back; the rows go to the pool
+            q, k, v, entry = blk.serve_latent_expanded(x, layer, cos, sin,
+                                                       cfg)
+            with jax.named_scope("attn"):
+                attn = _dense_attention(q, k, v, causal[None, None], sm)
+                x = x + blk.serve_attn_out(attn, layer)
+            x, kv = _ffn(x, kv, layer, cfg, ld)
+            with jax.named_scope("kv_latent"):
+                pool = _write_token_rows(kv["k"], l, entry, page_idx[None],
+                                         offset[None])
+            return x, {**kv, "k": pool}
         q, k, v = blk.serve_qkv(x, layer, cos, sin, cfg)
         with jax.named_scope("attn"):
             # dense causal attention within the prompt (prefill is
@@ -1146,13 +1288,28 @@ def paged_prefill_chunk(params, kv, page_table, tokens, start, true_len,
     valid = _visible(kpos[None, :], pos[:, None], b) \
         & (kpos[None, :] < kept)
     sm = cfg.head_dim ** -0.5
+    value_dim = blk.cache_spec(cfg).value_dim
+
+    def latent_attend(q, pool, l):
+        if attn_backend == "pallas":
+            from ray_tpu.ops import paged_attention as paged_ops
+            return paged_ops.paged_chunk_attention(
+                q, pool, None, page_table, start, kept, l, sm_scale=sm,
+                value_lanes=value_dim)
+        return _latent_gather_attention(q, pool, l, page_table[None],
+                                        valid[None, None], sm, value_dim)
 
     def step(x, kv, layer, ld, l):
-        if not _attends(ld):
+        if _keeps_state(ld):
             x, kv = _conv_mixer(
                 x, kv, layer, cfg, ld, page_table[:1], fresh=start == 0,
                 n_real=jnp.reshape(jnp.clip(true_len - start, 0, c),
                                    (1,)).astype(jnp.int32))
+            return _ffn(x, kv, layer, cfg, ld)
+        if _latent(ld):
+            x, kv = _latent_mixer(
+                x, kv, layer, l, cos, sin, cfg, page_idx[None], offset[None],
+                latent_attend)
             return _ffn(x, kv, layer, cfg, ld)
         q, k, v = blk.serve_qkv(x, layer, cos, sin, cfg)
         with jax.named_scope("kv_write"):

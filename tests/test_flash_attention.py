@@ -350,3 +350,124 @@ def test_train_step_for_v5e_awaits_no_qkv_weight_inside_its_own_layer(
     assert len(ring) == 3, ring                                     # (2)
     under = [line for line in bwd if qkv in line]
     assert under and all("transpose(jvp(" in line for line in under), under
+
+
+# ---- the paged kernel on a latent pool (ISSUE 44) ---------------------------
+# One row a token, the first ``value_lanes`` lanes of which are its values,
+# all heads on the one row: against a dense masked softmax, interpreted.
+
+def _latent_case(seed, b, t, h, latent, value, pages, page=8, lanes=128,
+                 dtype=jnp.float32):
+    from ray_tpu.ops import paged_attention as paged_ops  # noqa: F401
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    n_pages = b * pages + 1
+    rows = jax.random.normal(ks[0], (1, 1, n_pages, page, latent), dtype)
+    pool = jnp.pad(rows, ((0, 0),) * 4 + ((0, lanes - latent),))
+    tables = 1 + jnp.arange(b * pages, dtype=jnp.int32).reshape(b, pages)
+    q = jax.random.normal(ks[1], (b, t, h, latent), dtype)
+    base = jax.random.randint(ks[2], (b,), 0, pages * page - t).astype(
+        jnp.int32)
+    return q, pool, tables, base
+
+
+def _latent_want(q, pool, tables, base, limit, latent, value, sm):
+    """Dense masked softmax over each slot's rows, values = the rows'
+    first ``value`` lanes, in float64 numpy."""
+    import numpy as np
+    q, pool = np.asarray(q, np.float64), np.asarray(pool, np.float64)
+    b, t, h, _ = q.shape
+    out = np.zeros((b, t, h, value))
+    for i in range(b):
+        rows = pool[0, 0, np.asarray(tables[i])].reshape(-1, pool.shape[-1])
+        for j in range(t):
+            pos = int(base[i]) + j
+            n = min(pos + 1, int(limit[i]))
+            s = q[i, j] @ rows[:n, :latent].T * sm            # [h, n]
+            p = np.exp(s - s.max(-1, keepdims=True))
+            out[i, j] = (p / p.sum(-1, keepdims=True)) @ rows[:n, :value]
+    return out
+
+
+@pytest.mark.parametrize("t,h", [(1, 4), (5, 4), (1, 32)],
+                         ids=["decode", "verify_k+1", "decode_32_rows"])
+def test_latent_pool_spans_match_a_dense_masked_softmax(t, h):
+    from ray_tpu.ops import paged_attention as paged_ops
+    latent, value, sm = 40, 32, 24 ** -0.5
+    q, pool, tables, base = _latent_case(t + h, 3, t, h, latent, value, 6)
+    got = paged_ops.paged_attention(q, pool, None, tables, base, None, 0,
+                                    sm_scale=sm, value_lanes=value)
+    assert got.shape == (3, t, h, value)
+    want = _latent_want(q, pool, tables, base, [48] * 3, latent, value, sm)
+    np.testing.assert_allclose(got, want, atol=2e-5)
+    if t == 1:
+        one = paged_ops.paged_decode_attention(
+            q[:, 0], pool, None, tables, base, 0, sm_scale=sm,
+            value_lanes=value)
+        np.testing.assert_array_equal(one, got[:, 0])
+
+
+@pytest.mark.parametrize("c,h", [(16, 4), (128, 32)],
+                         ids=["one_span", "cut_into_spans"])
+def test_latent_pool_chunk_matches_a_dense_masked_softmax(c, h):
+    """A chunk at ``start`` under ``true_len``; 128 positions x 32 heads is
+    4,096 rows on the one KV head, cut into two spans of 64 positions."""
+    from ray_tpu.ops import paged_attention as paged_ops
+    latent, value, sm = 40, 32, 24 ** -0.5
+    pages = (8 + c) // 8 + 1
+    q, pool, tables, _ = _latent_case(c, 1, c, h, latent, value, pages)
+    start, true_len = 8, 8 + c - 3
+    got = paged_ops.paged_chunk_attention(
+        q, pool, None, tables[0], jnp.int32(start), jnp.int32(true_len), 0,
+        sm_scale=sm, value_lanes=value)
+    want = _latent_want(q, pool, tables, [start], [true_len], latent, value,
+                        sm)
+    # (rows at or past true_len are padding: their output is not read)
+    np.testing.assert_allclose(got[0, :c - 3], want[0, :c - 3], atol=2e-5)
+
+
+def test_latent_pool_garbage_in_the_querys_padding_lanes_is_harmless():
+    """The pool's padding lanes are zeros for ever (written so, never
+    read as values: value_lanes <= latent), so whatever a query of the
+    pool's full width holds there adds nothing to a score."""
+    from ray_tpu.ops import paged_attention as paged_ops
+    latent, value, sm = 40, 32, 24 ** -0.5
+    q, pool, tables, base = _latent_case(11, 2, 2, 4, latent, value, 4)
+    clean = paged_ops.paged_attention(q, pool, None, tables, base, None, 0,
+                                      sm_scale=sm, value_lanes=value)
+    junk = 1e3 * jax.random.normal(jax.random.PRNGKey(5),
+                                   q.shape[:3] + (128 - latent,))
+    dirty = paged_ops.paged_attention(
+        jnp.concatenate([q, junk], axis=-1), pool, None, tables, base, None,
+        0, sm_scale=sm, value_lanes=value)
+    np.testing.assert_array_equal(clean, dirty)
+
+
+@pytest.mark.parametrize("which", ["decode", "verify", "chunk"])
+def test_latent_kernels_compile_for_v5e_at_the_cells_shapes(which, one_chip):
+    """joyai-llm-flash-serve-decode: a pool [5, 1, 2560, 128, 640] bf16 read
+    ONCE (one kernel, no second stream of pages), 128 slots x 32 query rows
+    of 576 lanes on one KV row, tables of 24 pages; a chunk of 512."""
+    from ray_tpu.ops import paged_attention as paged_ops
+
+    def s(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    pool = s((5, 1, 2560, 128, 640), jnp.bfloat16)
+    kw = dict(sm_scale=192 ** -0.5, interpret=False, value_lanes=512)
+    if which == "chunk":
+        fn = lambda q, pool, pt, a, n, l: paged_ops.paged_chunk_attention(
+            q, pool, None, pt, a, n, l, **kw)
+        args = (s((1, 512, 32, 576), jnp.bfloat16), pool, s((24,)), s(()),
+                s(()), s(()))
+        out = (1, 512, 32, 512)
+    else:
+        kernel = paged_ops.paged_decode_attention if which == "decode" \
+            else paged_ops.paged_verify_attention
+        fn = lambda q, pool, pt, pos, l: kernel(q, pool, None, pt, pos, l,
+                                                **kw)
+        span = () if which == "decode" else (5,)
+        args = (s((128,) + span + (32, 576), jnp.bfloat16), pool,
+                s((128, 24)), s((128,)), s(()))
+        out = (128,) + span + (32, 512)
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+    assert compiled.out_info.shape == out
