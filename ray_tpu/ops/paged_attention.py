@@ -3,42 +3,61 @@
 The serving engine's gather attention materializes each slot's full
 [max_len, Hkv, D] K/V view from the page pool every layer of every step.
 These kernels read the pool pages DIRECTLY via the layer index and the
-slot page table (scalar-prefetch block index maps, the canonical TPU
-paged-attention pattern): the per-slot view is assembled page by page in
-VMEM scratch, never in HBM. They take the WHOLE pool
-[L, Hkv, P, page, D] and pick the layer inside the index map, so the
+slot page table (scalar-prefetch block index maps, or copies the body
+issues itself: the canonical TPU paged-attention patterns): the per-slot
+view is assembled page by page in VMEM scratch, never in HBM. They take
+the WHOLE pool [L, Hkv, P, page, D] and pick the layer themselves, so the
 serving programs (serve/llm/kv_cache.py) can carry the pool through
 their loops in place and never slice a layer out of it.
 
-One core kernel covers the whole family — decode (T=1), multi-query
-speculative verify (T=k+1 causal within the span), chunked prefill
-(B=1, extra ``true_len`` bound) and the block pass of generation by
-diffusion over blocks (T = one block, every position of which sees the
-whole block) are the same computation with different query spans and
-masks, dispatched through thin wrappers. The mask is ``col < (pos //
-block_len + 1) * block_len``: key j is visible to query i iff j's block is
-not after i's; at ``block_len`` 1 (static) that is the causal ``col <=
-pos``.
+TWO bodies that share no logic, chosen by the pool's kind (what the
+cache spec states, never a model's name or an option):
 
-A LATENT pool (a cache spec with ``latent_dim``, models/block.py) is one
-array of one row a token, [L, 1, P, page, lanes], whose first
-``value_lanes`` lanes are also the row's values: every wrapper takes
-``value_lanes`` (and no value pool) and the kernel then reads each page
-ONCE, into one scratch of which the value operand is a static prefix of
-lanes. The rows are padded to whole 128-lane vectors with zeros, which
-meet whatever the query holds there and add nothing.
+- pools of K and V per head (:func:`paged_attention`,
+  ``_paged_attn_kernel``): one core kernel covers the family: decode
+  (T=1), multi-query speculative verify (T=k+1 causal within the span),
+  chunked prefill (B=1, extra ``true_len`` bound) and the block pass of
+  generation by diffusion over blocks (T = one block, every position of
+  which sees the whole block) are the same computation with different
+  query spans and masks, dispatched through thin wrappers. The mask is
+  ``col < (pos // block_len + 1) * block_len``: key j is visible to query
+  i iff j's block is not after i's; at ``block_len`` 1 (static) that is
+  the causal ``col <= pos``. Grid (slot, KV head, table page): every page
+  of a slot's table is read, one a grid step, and the scores run over
+  the table's whole span, ``max_len`` columns.
+
+- a LATENT pool (a cache spec with ``latent_dim``, models/block.py;
+  :func:`paged_latent_attention`, ``_latent_attn_kernel``): one array of
+  one row a token, [L, 1, P, page, lanes], whose first ``value_lanes``
+  lanes are also the row's values, read by every query head. The
+  wrappers take ``value_lanes`` (and no value pool) and run the latent
+  body under their own kernel names. Its work follows each slot's LIVE
+  length ``min(limit, base + T)``, which it reads off its scalar
+  operands: one grid step a slot, the slot's live pages copied from the
+  pool where it lies into one scratch (key and value at once), the next
+  slot's under this slot's products, and scores, sums and weighted
+  values over the live chunks of columns only. A page past the live
+  ones is never read, a slot with nothing live writes zeros. The rows
+  are padded to whole 128-lane vectors with zeros, which meet whatever
+  the query holds there and add nothing.
 
 Identity contract: greedy TOKENS under the pallas backend must equal the
 gather backend exactly (hard-asserted in tests and the serve bench), so
-the kernel computes the SAME dense-softmax numerics as the gather path —
-fp32 logits scaled by ``sm_scale``, masked with -1e30, full-row fp32
-softmax, probabilities cast back to q.dtype, same contractions — instead
-of a flash-style streaming softmax (whose rescaling visibly changes
-float results). Raw attention outputs agree with gather to the last ULPs
-(the fused [R, L] dot and the batched einsum may order partial sums
-differently); the win is memory traffic, not math: pages stream
-HBM->VMEM once per (slot, kv-head) with no materialized gather
-intermediate.
+both bodies compute the SAME dense-softmax numerics as the gather path —
+fp32 logits scaled by ``sm_scale``, masked with -1e30, fp32 softmax over
+the whole row (maximum, exponentials, their sum, one division),
+probabilities cast back to q.dtype, same contractions — instead of a
+flash-style streaming softmax (whose rescaling visibly changes float
+results). The latent body walks the row in chunks of columns and three
+times (scores and maximum; exponentials and sum; probabilities times
+values, summed in fp32), over the LIVE columns only: a column it leaves
+out is a masked one, whose exponential is an exact zero, so the row's
+maximum and probabilities are the full row's. Raw attention outputs
+agree with gather to the last ULPs (the fused [R, L] dot, the chunks'
+partial sums and the batched einsum may order partial sums differently);
+the win is memory traffic, not math: pages stream HBM->VMEM once per
+(slot, kv-head) with no materialized gather intermediate, and on a
+latent pool the live pages only.
 
 Off-TPU the kernels run in interpreter mode (pl.pallas_call
 (interpret=True)), which is how tier-1 gates them on CPU — same story as
@@ -48,11 +67,10 @@ Tensor parallelism (ISSUE 20): a pallas_call is opaque to GSPMD, so on a
 TP mesh the serving engine runs these kernels under ``shard_map`` with
 the canonical per-KV-head partitioning from :func:`tp_shard_specs` —
 pool axis 1 (Hkv of [L, Hkv, P, page, D]) and q's H axis split by the
-"tensor" mesh axis. The kv-major GQA head order above is what makes that
-split clean: each
-shard's kernel invocation is exactly a single-chip call over Hkv/tp
-kv heads with their n_rep q heads, no kernel-internal changes and no
-in-kernel collectives.
+"tensor" mesh axis. The kv-major GQA head order is what makes that
+split clean: each shard's kernel invocation is exactly a single-chip call
+over Hkv/tp kv heads with their n_rep q heads, no kernel-internal changes
+and no in-kernel collectives.
 """
 
 from __future__ import annotations
@@ -72,6 +90,9 @@ _SCORE_TILE_BYTES = 2 * 1024 * 1024
 _MAX_ROW_TILE = 256
 # query rows of one grid step of the chunk call on a latent pool
 _MAX_SPAN_ROWS = 2048
+# pages of one chunk of key columns of the latent body: its score tile's
+# width, and the pages one semaphore counts
+_LATENT_CHUNK_PAGES = 4
 
 
 def interpret_default() -> bool:
@@ -138,10 +159,9 @@ def _row_tiling(r: int, max_len: int, dtype) -> tuple[int, int]:
 
 
 def _paged_attn_kernel(pt_ref, base_ref, limit_ref, layer_ref,  # prefetch
-                       q_ref, k_ref, *rest,
+                       q_ref, k_ref, v_ref, o_ref, k_scr, v_scr, *,
                        sm_scale: float, page_size: int, num_pages: int,
-                       t_span: int, row_tile: int, block_len: int = 1,
-                       value_lanes: int = 0):
+                       t_span: int, row_tile: int, block_len: int = 1):
     """Grid (B, Hkv, num_pages); one (slot, kv-head) pair accumulates its
     pages into VMEM scratch and computes dense attention on the last page.
 
@@ -152,21 +172,14 @@ def _paged_attn_kernel(pt_ref, base_ref, limit_ref, layer_ref,  # prefetch
     pool page [page, D] (layer, kv-head and page block dimensions squeezed),
     selected by the block index map through the scalar-prefetched layer
     index and page table — the read IS the gather. ``layer_ref`` is read
-    by the index maps only. ``rest``: v_ref, o_ref, k_scr, v_scr; or, with
-    ``value_lanes`` (a latent pool), o_ref and k_scr alone: the values are
-    the first ``value_lanes`` lanes of the key rows in the one scratch.
+    by the index maps only.
     """
-    if value_lanes:
-        o_ref, k_scr = rest
-    else:
-        v_ref, o_ref, k_scr, v_scr = rest
     b = pl.program_id(0)
     p = pl.program_id(2)
 
     off = pl.multiple_of(p * page_size, page_size)
     k_scr[pl.ds(off, page_size)] = k_ref[...]
-    if not value_lanes:
-        v_scr[pl.ds(off, page_size)] = v_ref[...]
+    v_scr[pl.ds(off, page_size)] = v_ref[...]
 
     @pl.when(p == num_pages - 1)
     def _compute():
@@ -193,9 +206,8 @@ def _paged_attn_kernel(pt_ref, base_ref, limit_ref, layer_ref,  # prefetch
                     & (col < limit)
             s = jnp.where(valid, s, _NEG_INF)
             w = jax.nn.softmax(s, axis=-1).astype(q.dtype)
-            values = k_scr[:, :value_lanes] if value_lanes else v_scr[:]
             o_ref[0, 0, pl.ds(r0, row_tile), :] = jax.lax.dot_general(
-                w, values, (((1,), (0,)), ((), ())),
+                w, v_scr[:], (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32).astype(o_ref.dtype)
             return carry
 
@@ -205,8 +217,7 @@ def _paged_attn_kernel(pt_ref, base_ref, limit_ref, layer_ref,  # prefetch
 def paged_attention(q, k_pages, v_pages, page_tables, base, limit=None,
                     layer=None, *, sm_scale: float | None = None,
                     interpret: bool | None = None,
-                    name: str = "paged_attention", block_len: int = 1,
-                    value_lanes: int = 0):
+                    name: str = "paged_attention", block_len: int = 1):
     """Fused paged attention over the whole query span.
 
     q: [B, T, H, D] — query position of q[:, t] is ``base + t`` (causal
@@ -225,15 +236,9 @@ def paged_attention(q, k_pages, v_pages, page_tables, base, limit=None,
     int32 exclusive key bound (None = the whole table span) — chunked
     prefill passes ``true_len`` so padded tail pages stay masked. name:
     the kernel's name in the compiled program and in a profiler trace
-    (each of the three callers below passes its own).
-    ``value_lanes`` (static) above 0: ``k_pages`` is a latent pool
-    [L, 1, P, page, lanes] and ``v_pages`` None; the H query heads all
-    read the one row a token, q [B, T, H, <= lanes] is padded with zeros
-    to the rows' lanes, and the result is [B, T, H, value_lanes].
+    (each of the callers below passes its own).
     Returns [B, T, H, D] in q.dtype.
     """
-    if value_lanes:
-        q = jnp.pad(q, ((0, 0),) * 3 + ((0, k_pages.shape[4] - q.shape[3]),))
     b, t, h, d = q.shape
     if k_pages.ndim == 4:
         k_pages, v_pages, layer = k_pages[None], v_pages[None], 0
@@ -273,12 +278,11 @@ def paged_attention(q, k_pages, v_pages, page_tables, base, limit=None,
     kernel = functools.partial(
         _paged_attn_kernel, sm_scale=sm_scale, page_size=page_size,
         num_pages=max_pages, t_span=t, row_tile=row_tile,
-        block_len=block_len, value_lanes=value_lanes)
-    d_out = value_lanes or d
+        block_len=block_len)
     # the paged read: block index (lyr[0], hi, pt[bi, pi]) picks the
     # layer's pool page straight off the scalar-prefetched layer index
-    # and table; a latent pool is read once (no value pool, one scratch)
-    pools = (k_pages,) if value_lanes else (k_pages, v_pages)
+    # and table
+    pools = (k_pages, v_pages)
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -294,11 +298,11 @@ def paged_attention(q, k_pages, v_pages, page_tables, base, limit=None,
                              (lyr[0], hi, pt[bi, pi], 0, 0))
                 for _ in pools],
             out_specs=pl.BlockSpec(
-                (1, 1, r_pad, d_out),
+                (1, 1, r_pad, d),
                 lambda bi, hi, pi, pt, bs, lim, lyr: (bi, hi, 0, 0)),
             scratch_shapes=[pltpu.VMEM((max_len, d), pool.dtype)
                             for pool in pools]),
-        out_shape=jax.ShapeDtypeStruct((b, hkv, r_pad, d_out), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, hkv, r_pad, d), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=max(32 * 1024 * 1024, 2 * vmem)),
@@ -307,8 +311,239 @@ def paged_attention(q, k_pages, v_pages, page_tables, base, limit=None,
     )(page_tables.astype(jnp.int32), base.astype(jnp.int32),
       limit.astype(jnp.int32), jnp.reshape(layer, (1,)).astype(jnp.int32),
       qg, *pools)
-    return out[:, :, :r].reshape(b, hkv, n_rep, t, d_out).transpose(
-        0, 3, 1, 2, 4).reshape(b, t, h, d_out)
+    return out[:, :, :r].reshape(b, hkv, n_rep, t, d).transpose(
+        0, 3, 1, 2, 4).reshape(b, t, h, d)
+
+
+def _latent_attn_kernel(pt_ref, base_ref, limit_ref, layer_ref,  # prefetch
+                        q_ref, pool_ref, o_ref, rows_scr, s_scr, acc_scr,
+                        sems, *, sm_scale: float, page_size: int,
+                        max_pages: int, chunk_pages: int, t_span: int,
+                        row_tile: int, value_lanes: int):
+    """Grid (B,): one grid step a slot, whose work follows the slot's LIVE
+    length ``min(limit, base + t_span)`` (a decode call: ``pos + 1``).
+
+    pool_ref is the whole latent pool [L, 1, P, page, lanes], left where
+    it lies (no block of it is pipelined): the body walks the live entries
+    of a slot's table row and copies each page into its place in the
+    slot's half of rows_scr [2, chunks * chunk_pages * page, lanes], every
+    copy in flight at once, one semaphore a CHUNK of ``chunk_pages``
+    pages. Step b starts the copies of slot b + 1 into the other half
+    before it computes (step 0 its own too), so a slot's pages arrive
+    under the products of the slot before. A page past the live ones is
+    never read. The rows are key and value at once (the values their
+    first ``value_lanes`` lanes).
+
+    q_ref [R, lanes], row r = head * t_span + t. A tile of ``row_tile``
+    rows meets the live chunks only, in three walks: scores (the first
+    tile's walk waits for each chunk's pages as it reaches them), masked
+    and kept in s_scr with the running row maximum; exponentials and
+    their row sums; probabilities (cast to q's type) times the chunk's
+    values, summed in acc_scr. These are the dense softmax's float32
+    values: a column the walk leaves out is a masked one, whose
+    exponential is an exact zero. The dead pages of the last live chunk
+    are zeroed first (a weight of zero times whatever VMEM held could be
+    a NaN); a slot with nothing live walks nothing and writes zeros.
+    """
+    b = pl.program_id(0)
+    half = b % 2
+    base = base_ref[b]
+    limit = limit_ref[b]
+    chunk = chunk_pages * page_size
+
+    def pages_of(slot):
+        live = jnp.clip(jnp.minimum(limit_ref[slot],
+                                    base_ref[slot] + t_span),
+                        0, max_pages * page_size)
+        return (live + page_size - 1) // page_size
+
+    def page_rows(j):
+        return pl.ds(pl.multiple_of(j * page_size, page_size), page_size)
+
+    def page_copy(slot, j):
+        return pltpu.make_async_copy(
+            pool_ref.at[layer_ref[0], 0, pt_ref[slot, j]],
+            rows_scr.at[slot % 2, page_rows(j)],
+            sems.at[slot % 2, j // chunk_pages])
+
+    def fetch(slot):
+        def start(j, carry):
+            page_copy(slot, j).start()
+            return carry
+        jax.lax.fori_loop(0, pages_of(slot), start, None)
+
+    @pl.when(b == 0)
+    def _own():
+        fetch(b)
+
+    @pl.when(b + 1 < pl.num_programs(0))
+    def _ahead():
+        fetch(b + 1)
+
+    live_pages = pages_of(b)
+    live_chunks = (live_pages + chunk_pages - 1) // chunk_pages
+
+    def zero(j, carry):
+        rows_scr[half, page_rows(j), :] = jnp.zeros(
+            (page_size, rows_scr.shape[2]), rows_scr.dtype)
+        return carry
+
+    jax.lax.fori_loop(live_pages, live_chunks * chunk_pages, zero, None)
+
+    def rows(i, carry):
+        r0 = pl.multiple_of(i * row_tile, row_tile)
+        q = q_ref[pl.ds(r0, row_tile), :]                      # [TR, lanes]
+        row = r0 + jax.lax.broadcasted_iota(
+            jnp.int32, (row_tile, chunk), 0)
+        pos = base + row % t_span
+
+        def chunk_rows(c):
+            return pl.ds(pl.multiple_of(c * chunk, chunk), chunk)
+
+        def scores(c, m):
+            @pl.when(i == 0)
+            def _arrive():
+                for p in range(chunk_pages):
+                    j = c * chunk_pages + p
+
+                    @pl.when(j < live_pages)
+                    def _wait():
+                        page_copy(b, j).wait()
+
+            # fp32 MXU accumulation rounded to q.dtype, then the fp32
+            # scale: the gather path's einsum(...).astype(f32) * sm
+            s = jax.lax.dot_general(
+                q, rows_scr[half, chunk_rows(c), :],
+                (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32).astype(q.dtype)
+            s = s.astype(jnp.float32) * sm_scale               # [TR, chunk]
+            col = c * chunk + jax.lax.broadcasted_iota(
+                jnp.int32, s.shape, 1)
+            s = jnp.where((col <= pos) & (col < limit), s, _NEG_INF)
+            s_scr[c] = s
+            return jnp.maximum(m, s.max(axis=-1, keepdims=True))
+
+        m = jax.lax.fori_loop(
+            0, live_chunks, scores,
+            jnp.full((row_tile, 1), _NEG_INF, jnp.float32))
+
+        def sums(c, total):
+            e = jnp.exp(s_scr[c] - m)
+            s_scr[c] = e
+            return total + e.sum(axis=-1, keepdims=True)
+
+        total = jax.lax.fori_loop(
+            0, live_chunks, sums, jnp.zeros((row_tile, 1), jnp.float32))
+
+        acc_scr[...] = jnp.zeros(acc_scr.shape, acc_scr.dtype)
+
+        def weigh(c, carry):
+            w = (s_scr[c] / total).astype(q.dtype)
+            acc_scr[...] += jax.lax.dot_general(
+                w, rows_scr[half, chunk_rows(c), :value_lanes],
+                (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            return carry
+
+        jax.lax.fori_loop(0, live_chunks, weigh, None)
+        o_ref[pl.ds(r0, row_tile), :] = acc_scr[...].astype(o_ref.dtype)
+        return carry
+
+    jax.lax.fori_loop(0, q_ref.shape[0] // row_tile, rows, None)
+
+
+def paged_latent_attention(q, pool, page_tables, base, limit=None,
+                           layer=None, *, value_lanes: int,
+                           sm_scale: float | None = None,
+                           interpret: bool | None = None,
+                           name: str = "paged_latent_attention"):
+    """:func:`paged_attention` on a LATENT pool [L, 1, P, page, lanes] (or
+    [1, P, page, lanes] with ``layer`` None): one row a token, key and
+    value at once, read by every one of the H query heads. q
+    [B, T, H, <= lanes] is padded with zeros to the rows' lanes; base,
+    limit, page_tables, layer, name as there (causal: ``block_len`` 1).
+    The work of slot b follows ``min(limit[b], base[b] + T)``, its live
+    length (:func:`_latent_attn_kernel`). Returns [B, T, H, value_lanes]
+    in q.dtype: the weighted rows' first ``value_lanes`` (static) lanes.
+    """
+    if pool.ndim == 4:
+        pool, layer = pool[None], 0
+    if limit is None:
+        limit = jnp.full((q.shape[0],),
+                         page_tables.shape[1] * pool.shape[3], jnp.int32)
+    return _latent_call(
+        q, pool, page_tables.astype(jnp.int32), base.astype(jnp.int32),
+        limit.astype(jnp.int32), jnp.reshape(layer, (1,)).astype(jnp.int32),
+        value_lanes=value_lanes,
+        sm_scale=float(pool.shape[4] ** -0.5 if sm_scale is None
+                       else sm_scale),
+        interpret=interpret_default() if interpret is None else interpret,
+        name=name)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "value_lanes", "sm_scale", "interpret", "name"))
+def _latent_call(q, pool, page_tables, base, limit, layer, *, value_lanes,
+                 sm_scale, interpret, name):
+    """The latent body's call. Jitted with the layer an OPERAND: a program
+    that walks its layers calls it once a layer with the same shapes, and
+    the body (several times the other body's equations) is then traced
+    once a process and lowered once a program, not once a layer."""
+    lanes = pool.shape[4]
+    q = jnp.pad(q, ((0, 0),) * 3 + ((0, lanes - q.shape[3]),))
+    b, t, h, _ = q.shape
+    page_size = pool.shape[3]
+    max_pages = page_tables.shape[1]
+    chunk_pages = min(_LATENT_CHUNK_PAGES, max_pages)
+    n_chunks = -(-max_pages // chunk_pages)
+    chunk = chunk_pages * page_size
+    r = h * t
+    r_pad, row_tile = _row_tiling(r, n_chunks * chunk, q.dtype)
+    # [B, T, H, lanes] -> [B, H*T, lanes]: query positions innermost, so
+    # the kernel recovers t as row % t_span
+    qg = q.transpose(0, 2, 1, 3).reshape(b, r, lanes)
+    if r_pad != r:
+        qg = jnp.pad(qg, ((0, 0), (0, r_pad - r), (0, 0)))
+
+    isz = jnp.dtype(q.dtype).itemsize
+    # both halves of the rows' scratch + double-buffered q/o blocks + the
+    # row tile's scores, accumulator and their temporaries, with headroom
+    vmem = (2 * n_chunks * chunk * lanes * jnp.dtype(pool.dtype).itemsize
+            + 2 * r_pad * (lanes + value_lanes) * isz
+            + 4 * row_tile * n_chunks * chunk * 4
+            + 4 * row_tile * value_lanes * 4)
+    kernel = functools.partial(
+        _latent_attn_kernel, sm_scale=sm_scale, page_size=page_size,
+        max_pages=max_pages, chunk_pages=chunk_pages, t_span=t,
+        row_tile=row_tile, value_lanes=value_lanes)
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(b,),
+            in_specs=[
+                pl.BlockSpec((None, r_pad, lanes),
+                             lambda bi, pt, bs, lim, lyr: (bi, 0, 0)),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=pl.BlockSpec(
+                (None, r_pad, value_lanes),
+                lambda bi, pt, bs, lim, lyr: (bi, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((2, n_chunks * chunk, lanes), pool.dtype),
+                pltpu.VMEM((n_chunks, row_tile, chunk), jnp.float32),
+                pltpu.VMEM((row_tile, value_lanes), jnp.float32),
+                pltpu.SemaphoreType.DMA((2, n_chunks)),
+            ]),
+        out_shape=jax.ShapeDtypeStruct((b, r_pad, value_lanes), q.dtype),
+        # in order: a step starts the copies the next one waits for
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=max(32 * 1024 * 1024, 2 * vmem)),
+        interpret=interpret,
+        name=name,
+    )(page_tables, base, limit, layer, qg, pool)
+    return out[:, :r].reshape(b, h, t, value_lanes).transpose(0, 2, 1, 3)
 
 
 def _packed_heads(q, k_pages, v_pages, page_tables, base, limit, layer, *,
@@ -347,10 +582,14 @@ def paged_decode_attention(q, k_pages, v_pages, page_tables, pos,
     ``pos[b]`` (attends 0..pos inclusive — its own k/v is already written
     to the pool). Pool and ``layer`` as in :func:`paged_attention`.
     Returns [B, H, D]."""
+    if value_lanes:
+        return paged_latent_attention(
+            q[:, None], k_pages, page_tables, pos, layer=layer,
+            value_lanes=value_lanes, sm_scale=sm_scale, interpret=interpret,
+            name="paged_decode_attention")[:, 0]
     out = paged_attention(q[:, None], k_pages, v_pages, page_tables, pos,
                           layer=layer, sm_scale=sm_scale, interpret=interpret,
-                          name="paged_decode_attention",
-                          value_lanes=value_lanes)
+                          name="paged_decode_attention")
     return out[:, 0]
 
 
@@ -362,11 +601,15 @@ def paged_verify_attention(q, k_pages, v_pages, page_tables, seq_lens,
     per slot, q[b, t] at position ``seq_lens[b] + t`` — causal within the
     span, full attention over the slot's cached pages (all T spans' k/v
     are pre-written). Returns [B, T, H, D]."""
+    if value_lanes:
+        return paged_latent_attention(
+            q, k_pages, page_tables, seq_lens, layer=layer,
+            value_lanes=value_lanes, sm_scale=sm_scale, interpret=interpret,
+            name="paged_verify_attention")
     return paged_attention(q, k_pages, v_pages, page_tables, seq_lens,
                            layer=layer, sm_scale=sm_scale,
                            interpret=interpret,
-                           name="paged_verify_attention",
-                           value_lanes=value_lanes)
+                           name="paged_verify_attention")
 
 
 def paged_block_attention(q, k_pages, v_pages, page_tables, seq_lens,
@@ -399,24 +642,23 @@ def paged_chunk_attention(q, k_pages, v_pages, page_table, start, true_len,
     them: more than a query block should hold in VMEM (512 x 32 rows of 640
     lanes: 21 MB, twice for the pipeline). The chunk is then cut into spans
     of positions, each a slot of its own on the same page table (its base
-    the span's first position), at most ``_MAX_SPAN_ROWS`` rows a span."""
+    the span's first position, so it walks the pages up to its OWN end),
+    at most ``_MAX_SPAN_ROWS`` rows a span."""
     base = jnp.reshape(start, (1,)).astype(jnp.int32)
     limit = jnp.reshape(true_len, (1,)).astype(jnp.int32)
-    _, c, h, _ = q.shape
-    span = max(1, _MAX_SPAN_ROWS // h)
-    if not value_lanes or c <= span or c % span:
+    if not value_lanes:
         return paged_attention(
             q, k_pages, v_pages, page_table[None], base, limit, layer,
             sm_scale=sm_scale, interpret=interpret,
-            name="paged_chunk_attention", block_len=block_len,
-            value_lanes=value_lanes)
-    n = c // span
-    out = paged_attention(
-        q.reshape(n, span, h, q.shape[3]), k_pages, None,
+            name="paged_chunk_attention", block_len=block_len)
+    _, c, h, _ = q.shape
+    span = max(1, _MAX_SPAN_ROWS // h)
+    n = c // span if c > span and c % span == 0 else 1
+    out = paged_latent_attention(
+        q.reshape(n, c // n, h, q.shape[3]), k_pages,
         jnp.broadcast_to(page_table[None], (n,) + page_table.shape),
-        base + span * jnp.arange(n, dtype=jnp.int32),
-        jnp.broadcast_to(limit, (n,)), layer, sm_scale=sm_scale,
-        interpret=interpret, name="paged_chunk_attention",
-        block_len=block_len, value_lanes=value_lanes)
+        base + c // n * jnp.arange(n, dtype=jnp.int32),
+        jnp.broadcast_to(limit, (n,)), layer, value_lanes=value_lanes,
+        sm_scale=sm_scale, interpret=interpret,
+        name="paged_chunk_attention")
     return out.reshape(1, c, h, value_lanes)
-

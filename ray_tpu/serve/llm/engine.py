@@ -390,7 +390,13 @@ class LLMEngine:
                       # ``idle_lead_k``: lead.py)
                       "dispatch_tier_admit_total": 0,
                       "dispatch_tier_pressure_total": 0,
-                      "dispatch_tier_idle_total": 0}
+                      "dispatch_tier_idle_total": 0,
+                      # over the decode / block dispatches: the table
+                      # pages that hold keys of the dispatch's first step
+                      # (what a kernel that follows a slot's live length
+                      # walks), and active slots x the table's width
+                      "attn_live_pages_total": 0,
+                      "attn_table_pages_total": 0}
         # Tiered KV cache (kv_tier.py): evicted cached page chains spill
         # host-side into a shm/disk tier + cluster index instead of dying,
         # and _admit extends its longest-match search past the local index
@@ -2561,22 +2567,29 @@ class LLMEngine:
             dirty, self._dirty_slots = self._dirty_slots, {}
             overrides, self._overrides = self._overrides, {}
             # tokens in the cache of the block's slots as its first step
-            # starts (what the device's seq_lens hold): the live context
-            ctx_tokens = 0
+            # starts (what the device's seq_lens hold): the live context;
+            # live_pages: the pages that hold that step's keys (a slot's
+            # context and the step's own bl positions)
+            ctx_tokens = live_pages = 0
             skips = []
             fused = k - 1
             for _col, _slot, req in snapshot:
                 if bl > 1:
                     plen = len(req.prompt_tokens)
-                    ctx_tokens += (plen + req.dispatched) // bl * bl
+                    ctx = (plen + req.dispatched) // bl * bl
                     if req.dispatched:      # a clean block is pending
-                        ctx_tokens -= bl
+                        ctx -= bl
                         fused = k
                     skips.append(0 if req.dispatched else plen % bl)
                     req.dispatched += k * bl - skips[-1]
-                    continue
-                ctx_tokens += len(req.prompt_tokens) + req.dispatched - 1
-                req.dispatched += k
+                else:
+                    ctx = len(req.prompt_tokens) + req.dispatched - 1
+                    req.dispatched += k
+                ctx_tokens += ctx
+                live_pages += -(-(ctx + bl) // self.cfg.page_size)
+            table_pages = len(snapshot) * self.max_pages_per_seq
+            self.stats["attn_live_pages_total"] += live_pages
+            self.stats["attn_table_pages_total"] += table_pages
         # bucketed width: pack the active slots, pad with the trash row —
         # a lightly loaded engine runs a narrow program
         active_slots = [slot for _c, slot, _r in snapshot]
@@ -2605,7 +2618,8 @@ class LLMEngine:
         with self._prof.span("block_dispatch" if bl > 1
                              else "decode_dispatch", seq=seq, **how, w=w,
                              active=len(active_slots),
-                             ctx_tokens=ctx_tokens, inflight=inflight,
+                             ctx_tokens=ctx_tokens, live_pages=live_pages,
+                             table_pages=table_pages, inflight=inflight,
                              lead=lead,
                              trimmed=max(
                                  0, inflight + 1 - self.PIPELINE_DEPTH),

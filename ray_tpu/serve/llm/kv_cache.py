@@ -47,7 +47,8 @@ Design choices:
   attended in the mixer's expanded form (per-head keys and values, nothing
   read back), and every program that reads the cache runs the absorbed
   form against the pool, all heads on the one row, the values a prefix of
-  the row's lanes;
+  the row's lanes (the pallas backend: the latent body of ops/
+  paged_attention.py, whose work follows each slot's live length);
 - tensor parallelism (ISSUE 20): every step function takes an optional
   ``mesh``. With a live "tensor" axis the pool is sharded per-KV-head
   (axis 1) and the q heads split into exactly the matching kv-head

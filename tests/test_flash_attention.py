@@ -394,8 +394,8 @@ def test_latent_pool_spans_match_a_dense_masked_softmax(t, h):
     from ray_tpu.ops import paged_attention as paged_ops
     latent, value, sm = 40, 32, 24 ** -0.5
     q, pool, tables, base = _latent_case(t + h, 3, t, h, latent, value, 6)
-    got = paged_ops.paged_attention(q, pool, None, tables, base, None, 0,
-                                    sm_scale=sm, value_lanes=value)
+    got = paged_ops.paged_latent_attention(q, pool, tables, base, None, 0,
+                                           sm_scale=sm, value_lanes=value)
     assert got.shape == (3, t, h, value)
     want = _latent_want(q, pool, tables, base, [48] * 3, latent, value, sm)
     np.testing.assert_allclose(got, want, atol=2e-5)
@@ -432,13 +432,13 @@ def test_latent_pool_garbage_in_the_querys_padding_lanes_is_harmless():
     from ray_tpu.ops import paged_attention as paged_ops
     latent, value, sm = 40, 32, 24 ** -0.5
     q, pool, tables, base = _latent_case(11, 2, 2, 4, latent, value, 4)
-    clean = paged_ops.paged_attention(q, pool, None, tables, base, None, 0,
-                                      sm_scale=sm, value_lanes=value)
+    clean = paged_ops.paged_latent_attention(
+        q, pool, tables, base, None, 0, sm_scale=sm, value_lanes=value)
     junk = 1e3 * jax.random.normal(jax.random.PRNGKey(5),
                                    q.shape[:3] + (128 - latent,))
-    dirty = paged_ops.paged_attention(
-        jnp.concatenate([q, junk], axis=-1), pool, None, tables, base, None,
-        0, sm_scale=sm, value_lanes=value)
+    dirty = paged_ops.paged_latent_attention(
+        jnp.concatenate([q, junk], axis=-1), pool, tables, base, None, 0,
+        sm_scale=sm, value_lanes=value)
     np.testing.assert_array_equal(clean, dirty)
 
 

@@ -187,6 +187,92 @@ def test_kernel_matches_gather_under_jit():
 
 
 # ---------------------------------------------------------------------------
+# the latent body: its work follows each slot's live length (ISSUE 45)
+# ---------------------------------------------------------------------------
+
+_LAT = dict(page=128, pages=6, latent=40, value=32, sm=24 ** -0.5)
+
+
+def _latent_pools(key, slots):
+    """A latent pool [1, 1, P, 128, 128] (rows of 40 numbers, padded with
+    zeros) in which every slot owns ``pages`` pages of its own, and the
+    slots' tables."""
+    pages, page, latent = _LAT["pages"], _LAT["page"], _LAT["latent"]
+    rows = jax.random.normal(key, (1, 1, slots * pages + 1, page, latent))
+    pool = jnp.pad(rows, ((0, 0),) * 4 + ((0, 128 - latent),))
+    tables = 1 + jnp.arange(slots * pages, dtype=jnp.int32).reshape(
+        slots, pages)
+    return pool, tables
+
+
+def _nan_outside(pool, tables, live):
+    """``pool`` with NaN in every page that is not among the first
+    ceil(live / page) of its slot's table (the trash page 0 too)."""
+    keep = np.zeros(pool.shape[2], bool)
+    for row, n in zip(np.asarray(tables), live):
+        keep[row[:-(-int(n) // _LAT["page"])]] = True
+    return jnp.where(keep[None, None, :, None, None], pool, jnp.nan)
+
+
+def _latent_reference(q, pool, tables, base, limit):
+    col = jnp.arange(tables.shape[1] * _LAT["page"])
+    pos = base[:, None] + jnp.arange(q.shape[1])[None, :]         # [B, T]
+    valid = (col <= pos[..., None]) & (col < limit[:, None, None])
+    return kv_cache._latent_gather_attention(
+        q, pool, 0, tables, valid[:, None], _LAT["sm"], _LAT["value"])
+
+
+def _ragged_batch(t):
+    """Slots of 1, 127, 128, 129 tokens, a full table, nothing, and 300
+    in ONE batch, a span of ``t`` positions ending each."""
+    lens = np.array([1, 127, 128, 129, 6 * 128, 0, 300])
+    kq, kp = jax.random.split(jax.random.PRNGKey(45 + t))
+    pool, tables = _latent_pools(kp, len(lens))
+    q = jax.random.normal(kq, (len(lens), t, 4, _LAT["latent"]))
+    limit = jnp.asarray(lens, jnp.int32)
+    base = jnp.maximum(limit - t, 0)
+    run = lambda pool: paged_ops.paged_latent_attention(   # noqa: E731
+        q, pool, tables, base, limit, 0, sm_scale=_LAT["sm"],
+        value_lanes=_LAT["value"])
+    return run, q, pool, tables, base, limit, lens
+
+
+def _chunk_in_spans():
+    """The chunk call cut into two spans of 64 positions on one table: a
+    chunk of 128 at position 60 under a true length of 185 lives in two
+    pages; span 0 ends in page 0. (Rows at or past the true length are
+    padding: their output is not read.)"""
+    c, h, start, true_len = 128, 32, 60, 185
+    kq, kp = jax.random.split(jax.random.PRNGKey(7))
+    pool, tables = _latent_pools(kp, 1)
+    q = jax.random.normal(kq, (1, c, h, _LAT["latent"]))
+    run = lambda pool: paged_ops.paged_chunk_attention(    # noqa: E731
+        q, pool, None, tables[0], jnp.int32(start), jnp.int32(true_len), 0,
+        sm_scale=_LAT["sm"], value_lanes=_LAT["value"])[:, :true_len - start]
+    return (run, q[:, :true_len - start], pool, tables, jnp.array([start]),
+            jnp.array([true_len]), np.array([true_len]))
+
+
+@pytest.mark.parametrize("case", [
+    lambda: _ragged_batch(1), lambda: _ragged_batch(4), _chunk_in_spans],
+    ids=["decode", "verify", "chunk_in_spans"])
+def test_latent_body_reads_no_dead_page_and_multiplies_no_dead_row(case):
+    """On a pool whose pages outside every slot's live pages hold NaN the
+    outputs are finite and the gather path's (which is given the clean
+    pool: it reads every page), and a slot with nothing live writes
+    zeros. A table of 6 pages is walked in chunks of 4, so the last chunk
+    is cut short by the table too."""
+    run, q, pool, tables, base, limit, lens = case()
+    got = run(_nan_outside(pool, tables, lens))
+    assert got.shape == q.shape[:3] + (_LAT["value"],)
+    assert np.isfinite(np.asarray(got)).all()
+    want = _latent_reference(q, pool, tables, base, limit)
+    live = lens > 0
+    np.testing.assert_allclose(got[live], want[live], atol=2e-5)
+    assert not np.asarray(got[~live]).any()
+
+
+# ---------------------------------------------------------------------------
 # backend resolution
 # ---------------------------------------------------------------------------
 

@@ -302,6 +302,41 @@ def test_span_without_capture_makes_no_annotation(monkeypatch):
     assert off.phase_stats()["phase_harvest_n"] == 0
 
 
+def test_decode_dispatch_counts_live_and_table_pages(tmp_path):
+    """A decode dispatch span carries ``live_pages`` (the table pages that
+    hold keys of its first step, summed over its slots) and ``table_pages``
+    (active x the table's width), and ``engine_stats()`` totals both: one
+    stream of 60 tokens after a prompt, pages of 16, a table of 8."""
+    from ray_tpu.observability import profiling as prof
+
+    eng = _mk_engine(max_tokens=64)
+    try:
+        eng.generate("warm the programs up first", max_tokens=4)
+        before = eng.engine_stats()
+        info = prof.start_capture(str(tmp_path / "xprof"))
+        out = eng.generate("the quick brown fox jumps over", max_tokens=60)
+        prof.stop_capture()
+        after = eng.engine_stats()
+    finally:
+        eng.shutdown()
+    disp = [a for n, _s, _e, a, _l in _host_spans(info["logdir"])
+            if n == "rt/decode_dispatch"]
+    assert disp and all(a["active"] == 1 for a in disp)
+    # the first step of a block reads the context and its own token
+    assert all(a["live_pages"] == -(-(a["ctx_tokens"] + 1) // 16)
+               and a["table_pages"] == 128 // 16 for a in disp)
+    prompt = out["num_prompt_tokens"]
+    assert disp[0]["ctx_tokens"] == prompt
+    assert {a["live_pages"] for a in disp} == set(
+        range(-(-(prompt + 1) // 16), max(a["live_pages"] for a in disp) + 1))
+    assert max(a["live_pages"] for a in disp) >= -(-(prompt + 40) // 16)
+    for key, arg in (("attn_live_pages_total", "live_pages"),
+                     ("attn_table_pages_total", "table_pages")):
+        assert after[key] - before[key] == sum(a[arg] for a in disp)
+    assert 0 < after["attn_live_pages_total"] \
+        < after["attn_table_pages_total"]
+
+
 def test_capture_holds_engine_spans_with_args(tmp_path):
     """While a capture is active the loop's spans land in the profiler's
     host plane as rt/<phase> with their arguments, on one thread, nested
