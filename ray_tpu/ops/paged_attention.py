@@ -10,21 +10,41 @@ the WHOLE pool [L, Hkv, P, page, D] and pick the layer themselves, so the
 serving programs (serve/llm/kv_cache.py) can carry the pool through
 their loops in place and never slice a layer out of it.
 
-TWO bodies that share no logic, chosen by the pool's kind (what the
-cache spec states, never a model's name or an option):
+THREE bodies that share no logic. Which one a call runs is decided by
+the pool's kind (what the cache spec states) and the call's kind (the
+wrapper), from ONE table, :data:`WALKS_LIVE`; never by a model's name, an
+option or a rule on shapes:
 
-- pools of K and V per head (:func:`paged_attention`,
-  ``_paged_attn_kernel``): one core kernel covers the family: decode
-  (T=1), multi-query speculative verify (T=k+1 causal within the span),
-  chunked prefill (B=1, extra ``true_len`` bound) and the block pass of
-  generation by diffusion over blocks (T = one block, every position of
-  which sees the whole block) are the same computation with different
-  query spans and masks, dispatched through thin wrappers. The mask is
-  ``col < (pos // block_len + 1) * block_len``: key j is visible to query
-  i iff j's block is not after i's; at ``block_len`` 1 (static) that is
-  the causal ``col <= pos``. Grid (slot, KV head, table page): every page
-  of a slot's table is read, one a grid step, and the scores run over
-  the table's whole span, ``max_len`` columns.
+- pools of K and V per head, the GRID body (:func:`paged_attention`,
+  ``_paged_attn_kernel``): decode (T=1), multi-query speculative verify
+  (T=k+1 causal within the span), chunked prefill (B=1, extra ``true_len``
+  bound) and the block pass of generation by diffusion over blocks (T =
+  one block, every position of which sees the whole block) are the same
+  computation with different query spans and masks, dispatched through
+  thin wrappers. The mask is ``col < (pos // block_len + 1) * block_len``:
+  key j is visible to query i iff j's block is not after i's; at
+  ``block_len`` 1 (static) that is the causal ``col <= pos``. Grid (slot,
+  KV head, table page): every page of a slot's table is read, one a grid
+  step, and the scores run over the table's whole span, ``max_len``
+  columns: its work follows ``max_seq_len``, never the context. Called by
+  ``paged_decode_attention``, ``paged_verify_attention`` and
+  ``paged_chunk_attention``.
+
+- pools of K and V per head, the WALKING body (``paged_attention(...,
+  walk=True)``, ``_gqa_walk_kernel``; ISSUE 48): the same operands, mask
+  and values, but its work follows each slot's LIVE length, the end of
+  the block that holds the span's last position (bounded by ``limit`` and
+  the table), which it reads off its scalar operands: one grid step a
+  slot, the slot's live pages of K and of V copied from the pools where
+  they lie (one strided copy a page and pool carries every KV head), the
+  next slot's under this slot's products, and scores, sums and weighted
+  values over the live chunks of columns only, every KV head of the call
+  at once. Called by ``paged_block_attention`` ALONE for now: the block
+  program is the one caller whose gain the benchmark can judge today
+  (ROADMAP S3; PERF.md section 6, PR 48). It takes every call shape of
+  the family (tests/test_paged_kernels.py drives them), so the other
+  three wrappers move over by an entry in the table, after which the
+  grid body goes (ROADMAP D4).
 
 - a LATENT pool (a cache spec with ``latent_dim``, models/block.py;
   :func:`paged_latent_attention`, ``_latent_attn_kernel``): one array of
@@ -43,12 +63,12 @@ cache spec states, never a model's name or an option):
 
 Identity contract: greedy TOKENS under the pallas backend must equal the
 gather backend exactly (hard-asserted in tests and the serve bench), so
-both bodies compute the SAME dense-softmax numerics as the gather path —
+every body computes the SAME dense-softmax numerics as the gather path —
 fp32 logits scaled by ``sm_scale``, masked with -1e30, fp32 softmax over
 the whole row (maximum, exponentials, their sum, one division),
 probabilities cast back to q.dtype, same contractions — instead of a
 flash-style streaming softmax (whose rescaling visibly changes float
-results). The latent body walks the row in chunks of columns and three
+results). The two walking bodies walk the row in chunks of columns and three
 times (scores and maximum; exponentials and sum; probabilities times
 values, summed in fp32), over the LIVE columns only: a column it leaves
 out is a masked one, whose exponential is an exact zero, so the row's
@@ -56,8 +76,8 @@ maximum and probabilities are the full row's. Raw attention outputs
 agree with gather to the last ULPs (the fused [R, L] dot, the chunks'
 partial sums and the batched einsum may order partial sums differently);
 the win is memory traffic, not math: pages stream HBM->VMEM once per
-(slot, kv-head) with no materialized gather intermediate, and on a
-latent pool the live pages only.
+(slot, kv-head) with no materialized gather intermediate, and under a
+walking body the live pages only.
 
 Off-TPU the kernels run in interpreter mode (pl.pallas_call
 (interpret=True)), which is how tier-1 gates them on CPU — same story as
@@ -93,6 +113,25 @@ _MAX_SPAN_ROWS = 2048
 # pages of one chunk of key columns of the latent body: its score tile's
 # width, and the pages one semaphore counts
 _LATENT_CHUNK_PAGES = 4
+# the same of the walking body for pools of K and V per head
+_GQA_CHUNK_PAGES = 4
+# THE table of which call kinds run a body that walks a slot's live pages,
+# by the pool's kind: the wrappers route by it and the engine reports its
+# own programs' share of it (``attn_walks_live``). Every other call runs
+# the grid body, whose work follows the table's width.
+WALKS_LIVE = {"latent": ("decode", "verify", "chunk"), "heads": ("block",)}
+
+
+def walking_calls(latent: bool, block_len: int = 1) -> list[str]:
+    """The call kinds of ONE engine's programs whose body walks live pages
+    (the engine's ``attn_walks_live``): what its programs call, by the
+    cache spec's block length (a block program and the chunk program, or
+    decode, verify and chunk), that :data:`WALKS_LIVE` names for its
+    pool's kind."""
+    called = ("decode", "verify", "chunk") if block_len == 1 \
+        else ("block", "chunk")
+    return [kind for kind in called
+            if kind in WALKS_LIVE["latent" if latent else "heads"]]
 
 
 def interpret_default() -> bool:
@@ -217,7 +256,8 @@ def _paged_attn_kernel(pt_ref, base_ref, limit_ref, layer_ref,  # prefetch
 def paged_attention(q, k_pages, v_pages, page_tables, base, limit=None,
                     layer=None, *, sm_scale: float | None = None,
                     interpret: bool | None = None,
-                    name: str = "paged_attention", block_len: int = 1):
+                    name: str = "paged_attention", block_len: int = 1,
+                    walk: bool = False):
     """Fused paged attention over the whole query span.
 
     q: [B, T, H, D] — query position of q[:, t] is ``base + t`` (causal
@@ -236,7 +276,9 @@ def paged_attention(q, k_pages, v_pages, page_tables, base, limit=None,
     int32 exclusive key bound (None = the whole table span) — chunked
     prefill passes ``true_len`` so padded tail pages stay masked. name:
     the kernel's name in the compiled program and in a profiler trace
-    (each of the callers below passes its own).
+    (each of the callers below passes its own). walk (static): the body
+    that walks each slot's live pages (:func:`_gqa_walk_kernel`) instead of
+    the grid over the table's pages; the same values to the last ULPs.
     Returns [B, T, H, D] in q.dtype.
     """
     b, t, h, d = q.shape
@@ -246,7 +288,18 @@ def paged_attention(q, k_pages, v_pages, page_tables, base, limit=None,
         return _packed_heads(
             q, k_pages, v_pages, page_tables, base, limit, layer,
             sm_scale=d ** -0.5 if sm_scale is None else sm_scale,
-            interpret=interpret, name=name, block_len=block_len)
+            interpret=interpret, name=name, block_len=block_len, walk=walk)
+    if walk:
+        if limit is None:
+            limit = jnp.full((b,), page_tables.shape[1] * k_pages.shape[3],
+                             jnp.int32)
+        return _gqa_walk_call(
+            q, k_pages, v_pages, page_tables.astype(jnp.int32),
+            base.astype(jnp.int32), limit.astype(jnp.int32),
+            jnp.reshape(layer, (1,)).astype(jnp.int32),
+            sm_scale=float(d ** -0.5 if sm_scale is None else sm_scale),
+            interpret=interpret_default() if interpret is None
+            else interpret, name=name, block_len=block_len)
     hkv = k_pages.shape[1]
     n_rep = h // hkv
     page_size = k_pages.shape[3]
@@ -546,8 +599,233 @@ def _latent_call(q, pool, page_tables, base, limit, layer, *, value_lanes,
     return out[:, :r].reshape(b, h, t, value_lanes).transpose(0, 2, 1, 3)
 
 
+def _gqa_walk_kernel(pt_ref, base_ref, limit_ref, layer_ref,  # prefetch
+                     q_ref, k_pool, v_pool, o_ref, k_scr, v_scr, s_scr,
+                     acc_scr, sems, *, sm_scale: float, page_size: int,
+                     max_pages: int, chunk_pages: int, t_span: int,
+                     row_tile: int, block_len: int):
+    """Grid (B,): one grid step a slot, every KV head of the call walked
+    inside it, whose work follows the slot's LIVE length: the end of the
+    block that holds its last query position, ``min(limit, ((base + t_span
+    - 1) // block_len + 1) * block_len)`` (causal, ``block_len`` 1:
+    ``min(limit, base + t_span)``), clipped to the table's span.
+
+    k_pool / v_pool are the whole pools [L, Hkv, P, page, D], left where
+    they lie: the body walks the live entries of a slot's table row and
+    copies each page of EVERY KV head, one strided copy a pool, into its
+    place in the slot's half of k_scr / v_scr [2, Hkv, chunks *
+    chunk_pages * page, D], every copy in flight at once, one semaphore a
+    CHUNK of ``chunk_pages`` pages. Step b starts the copies of slot b + 1
+    into the other half before it computes (step 0 its own too), so a
+    slot's pages arrive under the products of the slot before. A page past
+    the live ones is never read.
+
+    q_ref [Hkv, R, D], row r = rep * t_span + t of its KV head (the grid
+    body's layout). A tile of ``row_tile`` rows of EVERY KV head at once
+    (one batched product: a head at a time, the chain of products and
+    reductions of one head waited for the head before's: 0.27 against 0.15
+    ms a call at the SDAR cell's shapes, chip runs of PR 48) meets the live chunks only, in
+    three walks: scores (the first tile's walk waits for each chunk's
+    pages as it reaches them), masked as the grid body masks and kept in
+    s_scr with the running row maximum;
+    exponentials and their row sums; probabilities (cast to q's type)
+    times the chunk's values, summed in acc_scr. These are the dense
+    softmax's float32 values: a column the walk leaves out is a masked
+    one, whose exponential is an exact zero. The dead value pages of the
+    last live chunk are zeroed first (a weight of zero times whatever VMEM
+    held could be a NaN; a masked score is replaced whatever it was); a
+    slot with nothing live walks nothing and writes zeros.
+    """
+    b = pl.program_id(0)
+    half = b % 2
+    base = base_ref[b]
+    limit = limit_ref[b]
+    chunk = chunk_pages * page_size
+    hkv, r_pad, d = q_ref.shape
+
+    def pages_of(slot):
+        end = base_ref[slot] + t_span
+        if block_len > 1:       # the end of the last position's block
+            end = ((end - 1) // block_len + 1) * block_len
+        live = jnp.clip(jnp.minimum(limit_ref[slot], end),
+                        0, max_pages * page_size)
+        return (live + page_size - 1) // page_size
+
+    def page_rows(j):
+        return pl.ds(pl.multiple_of(j * page_size, page_size), page_size)
+
+    def page_copies(slot, j):
+        """Page j of the slot's table, every KV head of it, K and V."""
+        return [pltpu.make_async_copy(
+            pool.at[layer_ref[0], :, pt_ref[slot, j]],
+            scr.at[slot % 2, :, page_rows(j)],
+            sems.at[slot % 2, j // chunk_pages])
+            for pool, scr in ((k_pool, k_scr), (v_pool, v_scr))]
+
+    def fetch(slot):
+        def start(j, carry):
+            for copy in page_copies(slot, j):
+                copy.start()
+            return carry
+        jax.lax.fori_loop(0, pages_of(slot), start, None)
+
+    @pl.when(b == 0)
+    def _own():
+        fetch(b)
+
+    @pl.when(b + 1 < pl.num_programs(0))
+    def _ahead():
+        fetch(b + 1)
+
+    live_pages = pages_of(b)
+    live_chunks = (live_pages + chunk_pages - 1) // chunk_pages
+
+    def zero(j, carry):
+        v_scr[half, :, page_rows(j), :] = jnp.zeros(
+            (hkv, page_size, d), v_scr.dtype)
+        return carry
+
+    jax.lax.fori_loop(live_pages, live_chunks * chunk_pages, zero, None)
+
+    def rows(i, carry):
+        r0 = pl.multiple_of(i * row_tile, row_tile)
+        q = q_ref[:, pl.ds(r0, row_tile), :]                   # [Hkv, TR, D]
+        row = r0 + jax.lax.broadcasted_iota(
+            jnp.int32, (row_tile, chunk), 0)
+        pos = base + row % t_span
+        # the last key a row sees: itself, or the end of its block
+        seen = pos + 1 if block_len == 1 \
+            else (pos // block_len + 1) * block_len
+
+        def chunk_rows(c):
+            return pl.ds(pl.multiple_of(c * chunk, chunk), chunk)
+
+        def scores(c, m):
+            @pl.when(i == 0)
+            def _arrive():
+                for p in range(chunk_pages):
+                    j = c * chunk_pages + p
+
+                    @pl.when(j < live_pages)
+                    def _wait():
+                        for copy in page_copies(b, j):
+                            copy.wait()
+
+            # fp32 MXU accumulation rounded to q.dtype, then the fp32
+            # scale: the gather path's einsum(...).astype(f32) * sm
+            s = jax.lax.dot_general(
+                q, k_scr[half, :, chunk_rows(c), :],
+                (((2,), (2,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32).astype(q.dtype)
+            s = s.astype(jnp.float32) * sm_scale          # [Hkv, TR, chunk]
+            col = c * chunk + jax.lax.broadcasted_iota(
+                jnp.int32, (row_tile, chunk), 1)
+            s = jnp.where(((col < seen) & (col < limit))[None], s, _NEG_INF)
+            s_scr[c] = s
+            return jnp.maximum(m, s.max(axis=-1, keepdims=True))
+
+        m = jax.lax.fori_loop(
+            0, live_chunks, scores,
+            jnp.full((hkv, row_tile, 1), _NEG_INF, jnp.float32))
+
+        def sums(c, total):
+            e = jnp.exp(s_scr[c] - m)
+            s_scr[c] = e
+            return total + e.sum(axis=-1, keepdims=True)
+
+        total = jax.lax.fori_loop(
+            0, live_chunks, sums,
+            jnp.zeros((hkv, row_tile, 1), jnp.float32))
+
+        acc_scr[...] = jnp.zeros(acc_scr.shape, acc_scr.dtype)
+
+        def weigh(c, carry):
+            w = (s_scr[c] / total).astype(q.dtype)
+            acc_scr[...] += jax.lax.dot_general(
+                w, v_scr[half, :, chunk_rows(c), :],
+                (((2,), (1,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32)
+            return carry
+
+        jax.lax.fori_loop(0, live_chunks, weigh, None)
+        o_ref[:, pl.ds(r0, row_tile), :] = acc_scr[...].astype(o_ref.dtype)
+        return carry
+
+    jax.lax.fori_loop(0, r_pad // row_tile, rows, None)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "sm_scale", "interpret", "name", "block_len"))
+def _gqa_walk_call(q, k_pages, v_pages, page_tables, base, limit, layer, *,
+                   sm_scale, interpret, name, block_len):
+    """The walking body's call on pools of K and V per head, every operand
+    as :func:`paged_attention` has prepared it. Jitted with the layer an
+    OPERAND, as the latent body's call is and for its reason: a block
+    program calls it twice a walked layer, and the body is then traced
+    once a process and lowered once a program."""
+    b, t, h, d = q.shape
+    hkv = k_pages.shape[1]
+    n_rep = h // hkv
+    page_size = k_pages.shape[3]
+    max_pages = page_tables.shape[1]
+    chunk_pages = min(_GQA_CHUNK_PAGES, max_pages)
+    n_chunks = -(-max_pages // chunk_pages)
+    chunk = chunk_pages * page_size
+    r = n_rep * t
+    # a score tile holds every KV head's rows
+    r_pad, row_tile = _row_tiling(r, hkv * n_chunks * chunk, q.dtype)
+    # [B, T, H, D] -> [B, Hkv, n_rep*T, D]: the grid body's layout
+    qg = q.reshape(b, t, hkv, n_rep, d).transpose(0, 2, 3, 1, 4).reshape(
+        b, hkv, r, d)
+    if r_pad != r:
+        qg = jnp.pad(qg, ((0, 0), (0, 0), (0, r_pad - r), (0, 0)))
+
+    isz = jnp.dtype(q.dtype).itemsize
+    # both halves of both scratches + double-buffered q/o blocks + the row
+    # tile's scores, accumulator and their temporaries, with headroom
+    vmem = (4 * hkv * n_chunks * chunk * d
+            * jnp.dtype(k_pages.dtype).itemsize
+            + 4 * hkv * r_pad * d * isz
+            + 4 * hkv * row_tile * n_chunks * chunk * 4
+            + 4 * hkv * row_tile * d * 4)
+    kernel = functools.partial(
+        _gqa_walk_kernel, sm_scale=sm_scale, page_size=page_size,
+        max_pages=max_pages, chunk_pages=chunk_pages, t_span=t,
+        row_tile=row_tile, block_len=block_len)
+    pools = (k_pages, v_pages)
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(b,),
+            in_specs=[
+                pl.BlockSpec((None, hkv, r_pad, d),
+                             lambda bi, pt, bs, lim, lyr: (bi, 0, 0, 0)),
+            ] + [pl.BlockSpec(memory_space=pl.ANY) for _ in pools],
+            out_specs=pl.BlockSpec(
+                (None, hkv, r_pad, d),
+                lambda bi, pt, bs, lim, lyr: (bi, 0, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((2, hkv, n_chunks * chunk, d), pool.dtype)
+                for pool in pools] + [
+                pltpu.VMEM((n_chunks, hkv, row_tile, chunk), jnp.float32),
+                pltpu.VMEM((hkv, row_tile, d), jnp.float32),
+                pltpu.SemaphoreType.DMA((2, n_chunks)),
+            ]),
+        out_shape=jax.ShapeDtypeStruct((b, hkv, r_pad, d), q.dtype),
+        # in order: a step starts the copies the next one waits for
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=max(32 * 1024 * 1024, 2 * vmem)),
+        interpret=interpret,
+        name=name,
+    )(page_tables, base, limit, layer, qg, *pools)
+    return out[:, :, :r].reshape(b, hkv, n_rep, t, d).transpose(
+        0, 3, 1, 2, 4).reshape(b, t, h, d)
+
+
 def _packed_heads(q, k_pages, v_pages, page_tables, base, limit, layer, *,
-                  sm_scale, interpret, name, block_len=1):
+                  sm_scale, interpret, name, block_len=1, walk=False):
     """Heads narrower than a pool row: the pool holds ``pack`` KV heads
     side by side in one row of lanes ([L, Hkv / pack, P, page, pack * D]:
     heads of 64 two to a 128-lane row, so HBM holds no padding and a page
@@ -568,7 +846,8 @@ def _packed_heads(q, k_pages, v_pages, page_tables, base, limit, layer, *,
         b, t, h, pack * d)
     out = paged_attention(spread, k_pages, v_pages, page_tables, base, limit,
                           layer, sm_scale=sm_scale, interpret=interpret,
-                          name=name, block_len=block_len)   # [B, T, H, pack*D]
+                          name=name, block_len=block_len,
+                          walk=walk)                        # [B, T, H, pack*D]
     out = out.reshape(b, t, rows, pack, n_rep, pack, d)
     return jnp.stack([out[:, :, :, j, :, j] for j in range(pack)],
                      axis=3).reshape(b, t, h, d)
@@ -589,7 +868,8 @@ def paged_decode_attention(q, k_pages, v_pages, page_tables, pos,
             name="paged_decode_attention")[:, 0]
     out = paged_attention(q[:, None], k_pages, v_pages, page_tables, pos,
                           layer=layer, sm_scale=sm_scale, interpret=interpret,
-                          name="paged_decode_attention")
+                          name="paged_decode_attention",
+                          walk="decode" in WALKS_LIVE["heads"])
     return out[:, 0]
 
 
@@ -609,7 +889,8 @@ def paged_verify_attention(q, k_pages, v_pages, page_tables, seq_lens,
     return paged_attention(q, k_pages, v_pages, page_tables, seq_lens,
                            layer=layer, sm_scale=sm_scale,
                            interpret=interpret,
-                           name="paged_verify_attention")
+                           name="paged_verify_attention",
+                           walk="verify" in WALKS_LIVE["heads"])
 
 
 def paged_block_attention(q, k_pages, v_pages, page_tables, seq_lens,
@@ -625,7 +906,8 @@ def paged_block_attention(q, k_pages, v_pages, page_tables, seq_lens,
     return paged_attention(q, k_pages, v_pages, page_tables, seq_lens,
                            layer=layer, sm_scale=sm_scale,
                            interpret=interpret,
-                           name="paged_block_attention", block_len=block_len)
+                           name="paged_block_attention", block_len=block_len,
+                           walk="block" in WALKS_LIVE["heads"])
 
 
 def paged_chunk_attention(q, k_pages, v_pages, page_table, start, true_len,
@@ -650,7 +932,8 @@ def paged_chunk_attention(q, k_pages, v_pages, page_table, start, true_len,
         return paged_attention(
             q, k_pages, v_pages, page_table[None], base, limit, layer,
             sm_scale=sm_scale, interpret=interpret,
-            name="paged_chunk_attention", block_len=block_len)
+            name="paged_chunk_attention", block_len=block_len,
+            walk="chunk" in WALKS_LIVE["heads"])
     _, c, h, _ = q.shape
     span = max(1, _MAX_SPAN_ROWS // h)
     n = c // span if c > span and c % span == 0 else 1

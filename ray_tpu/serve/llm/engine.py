@@ -274,6 +274,13 @@ class LLMEngine:
         from ray_tpu.ops import paged_attention as paged_ops
         self._attn_interpret = int(self._attn_backend == "pallas"
                                    and paged_ops.interpret_default())
+        # the call kinds of this engine's programs whose kernel body walks
+        # a slot's LIVE pages (ops/paged_attention.py WALKS_LIVE): where
+        # attn_live_pages_total / attn_table_pages_total is the share of
+        # a table a call reads
+        self._attn_walks_live = paged_ops.walking_calls(
+            bool(self._cache_spec.latent_dim), self._block_len) \
+            if self._attn_backend == "pallas" else []
         # performance introspection (observability/profiling.py): phase
         # timers + ITL ring gate on cfg.profiling_enabled; compile-event
         # tracking is always on (work only on first-dispatch-per-shape).
@@ -1394,6 +1401,7 @@ class LLMEngine:
         out["device_kind"] = self._devices[0].device_kind
         out["device_count"] = len(self._devices)
         out["attn_interpret"] = self._attn_interpret
+        out["attn_walks_live"] = list(self._attn_walks_live)
         out["attn_kernel_compiles"] = self._prof.compile_count(
             ("decode", "verify", "chunk"))
         # tensor-parallel surface (ISSUE 20), stable-key contract: degree

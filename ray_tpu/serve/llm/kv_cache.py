@@ -42,6 +42,8 @@ Design choices:
   block's positions against the cache, committing or not, and
   :func:`paged_block_pair_step` a clean pending block and the one after
   it in one pass (the engine's: the commit rides with the next denoise);
+  on the pallas backend their kernel call (``paged_block_attention``)
+  runs the body that walks each slot's live pages, ops/paged_attention.py;
 - a block whose cache spec states ``latent_dim`` keeps ONE row a token and
   layer (:func:`_latent_mixer`): the pool is one array, a whole prompt is
   attended in the mixer's expanded form (per-head keys and values, nothing

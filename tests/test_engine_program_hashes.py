@@ -1,5 +1,7 @@
-"""The engine's programs (the dense block, LFM2-MoE and, since ISSUE 42,
-SDAR-MoE's block program) lower to the text they lowered to when
+"""The engine's programs (the dense block, LFM2-MoE, since ISSUE 42
+SDAR-MoE's block program and since ISSUE 48 the latent block, JoyAI, whose
+entries the PARENT's tree wrote before that PR touched the kernels' file)
+lower to the text they lowered to when
 ``tests/data/engine_program_hashes.json`` was written: decode tiers,
 speculative verify, prefill and chunk buckets, on the gather and the pallas
 (interpreted) backends. A PR that works on another block's path (generation
@@ -30,7 +32,7 @@ if __name__ == "__main__":      # run as a script: the repo's root on the path
     sys.path.insert(0, os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
 
-from ray_tpu.models import lfm2_moe, llama, sdar_moe  # noqa: E402
+from ray_tpu.models import joyai, lfm2_moe, llama, sdar_moe  # noqa: E402
 from ray_tpu.serve.llm import LLMConfig, LLMEngine  # noqa: E402
 
 DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data",
@@ -41,7 +43,8 @@ ENGINE = dict(max_batch_size=4, page_size=8, num_pages=64, max_prompt_len=128,
               warmup_compile=False)
 BLOCKS = {"dense": lambda: llama.llama_tiny(vocab_size=512),
           "lfm2": lfm2_moe.lfm2_moe_tiny,
-          "sdar": sdar_moe.sdar_moe_tiny}
+          "sdar": sdar_moe.sdar_moe_tiny,
+          "joyai": joyai.joyai_tiny}
 BACKENDS = ("gather", "pallas")
 PROGRAMS = ("decode_1", "decode_4", "decode_8", "verify", "prefill_32",
             "chunk_16")

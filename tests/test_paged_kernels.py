@@ -44,10 +44,12 @@ def _rand_pool(key, hkv, pool_pages, page, d, dtype):
     return k_pages, v_pages
 
 
-def _ref_attention(q, k_pages, v_pages, page_tables, base, limit, sm):
+def _ref_attention(q, k_pages, v_pages, page_tables, base, limit, sm,
+                   block_len=1):
     """The gather path's exact op sequence (see kv_cache._decode_attention
     / paged_verify_step), generalized to the kernel's unified semantics:
-    row t of slot b attends keys ``col <= base[b] + t`` and
+    row t of slot b attends keys ``col <= base[b] + t`` (with positions cut
+    into blocks of ``block_len``: up to the end of its own block) and
     ``col < limit[b]``."""
     b, t, h, d = q.shape
     hkv = k_pages.shape[0]
@@ -62,7 +64,8 @@ def _ref_attention(q, k_pages, v_pages, page_tables, base, limit, sm):
     v_full = kv_cache.gqa_expand(v_seq, n_rep)
     col = jnp.arange(max_len)
     pos = base[:, None] + jnp.arange(t)[None, :]                  # [B,T]
-    valid = (col[None, None, :] <= pos[:, :, None]) \
+    seen = (pos // block_len + 1) * block_len
+    valid = (col[None, None, :] < seen[:, :, None]) \
         & (col[None, None, :] < limit[:, None, None])             # [B,T,L]
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k_full).astype(
         jnp.float32) * sm
@@ -205,13 +208,16 @@ def _latent_pools(key, slots):
     return pool, tables
 
 
-def _nan_outside(pool, tables, live):
-    """``pool`` with NaN in every page that is not among the first
-    ceil(live / page) of its slot's table (the trash page 0 too)."""
-    keep = np.zeros(pool.shape[2], bool)
+def _nan_outside(pool, tables, live, axis=2):
+    """``pool`` (its pages on ``axis``) with NaN in every page that is not
+    among the first ceil(live / page) of its slot's table (the trash page
+    0 too)."""
+    keep = np.zeros(pool.shape[axis], bool)
     for row, n in zip(np.asarray(tables), live):
         keep[row[:-(-int(n) // _LAT["page"])]] = True
-    return jnp.where(keep[None, None, :, None, None], pool, jnp.nan)
+    shape = [1] * pool.ndim
+    shape[axis] = -1
+    return jnp.where(keep.reshape(shape), pool, jnp.nan)
 
 
 def _latent_reference(q, pool, tables, base, limit):
@@ -270,6 +276,206 @@ def test_latent_body_reads_no_dead_page_and_multiplies_no_dead_row(case):
     live = lens > 0
     np.testing.assert_allclose(got[live], want[live], atol=2e-5)
     assert not np.asarray(got[~live]).any()
+
+
+# ---------------------------------------------------------------------------
+# the walking body for pools of K and V per head: its work follows each
+# slot's live length (ISSUE 48). Only paged_block_attention routes to it;
+# here it is driven at every call shape of the family.
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("block_len", [1, 4])
+@pytest.mark.parametrize("b,t", [(1, 1), (4, 1), (2, 2), (4, 4), (3, 5)])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_walking_body_matches_gather_across_width_and_span(b, t, dtype,
+                                                           block_len):
+    """The sweep of test_kernel_matches_gather_across_width_and_span under
+    the causal and the block mask: ragged positions (different live page
+    counts, spans that start inside a block) and a permuted page table. A
+    table of 6 pages is walked in chunks of 4, so a slot deep into its
+    table meets two chunks and the table cuts the last one short."""
+    hkv, n_rep, d, page, mp = 2, 2, 16, 8, 6
+    h = hkv * n_rep
+    key = jax.random.PRNGKey(b * 131 + t)
+    kq, kp, kt = jax.random.split(key, 3)
+    k_pages, v_pages = _rand_pool(kp, hkv, mp * b + 1, page, d, dtype)
+    q = jax.random.normal(kq, (b, t, h, d), dtype)
+    base = jnp.asarray([(page * ((2 * i + 1) % mp)) + (i * 3) % page
+                        for i in range(b)], jnp.int32)
+    page_tables = jax.random.permutation(
+        kt, mp * b) .reshape(b, mp).astype(jnp.int32) + 1
+    limit = jnp.full((b,), mp * page, jnp.int32)
+    sm = d ** -0.5
+
+    got = paged_ops.paged_attention(q, k_pages, v_pages, page_tables, base,
+                                    sm_scale=sm, block_len=block_len,
+                                    walk=True)
+    want = _ref_attention(q, k_pages, v_pages, page_tables, base, limit,
+                          sm, block_len)
+    _assert_matches(got, want)
+
+
+def test_walking_body_under_jit_with_a_traced_layer():
+    """How a block program calls it: inside jit, the layer a traced
+    operand, on a pool of several layers of other values."""
+    hkv, n_rep, d, page, mp, b, t = 2, 2, 16, 8, 4, 2, 4
+    h = hkv * n_rep
+    k_pages, v_pages = _rand_pool(jax.random.PRNGKey(5), hkv, mp * b + 1,
+                                  page, d, jnp.float32)
+    k_pool = jnp.stack([k_pages + 1.0, k_pages, k_pages - 1.0])
+    v_pool = jnp.stack([v_pages - 1.0, v_pages, v_pages + 1.0])
+    q = jax.random.normal(jax.random.PRNGKey(6), (b, t, h, d), jnp.float32)
+    page_tables = jnp.arange(1, mp * b + 1).reshape(b, mp).astype(jnp.int32)
+    base = jnp.asarray([4, 16], jnp.int32)
+    sm = d ** -0.5
+    got = jax.jit(lambda layer, *a: paged_ops.paged_block_attention(
+        *a, layer, block_len=4, sm_scale=sm))(
+        jnp.int32(1), q, k_pool, v_pool, page_tables, base)
+    want = _ref_attention(q, k_pages, v_pages, page_tables, base,
+                          jnp.full((b,), mp * page, jnp.int32), sm, 4)
+    _assert_matches(got, want)
+
+
+def test_walking_body_on_heads_of_64_packed_two_to_a_row():
+    """Heads of 64 lie two to a 128-lane pool row (_packed_heads): the
+    walking body runs on the rows as they are, against the gather math on
+    the same heads a row each."""
+    hkv, n_rep, d, page, mp, b, t = 4, 2, 64, 8, 6, 3, 4
+    h = hkv * n_rep
+    k_pages, v_pages = _rand_pool(jax.random.PRNGKey(8), hkv, mp * b + 1,
+                                  page, d, jnp.float32)
+    pack = lambda pool: pool.reshape(hkv // 2, 2, -1, page, d).transpose(  # noqa: E731
+        0, 2, 3, 1, 4).reshape(hkv // 2, -1, page, 2 * d)
+    q = jax.random.normal(jax.random.PRNGKey(9), (b, t, h, d), jnp.float32)
+    page_tables = jnp.arange(1, mp * b + 1).reshape(b, mp).astype(jnp.int32)
+    base = jnp.asarray([0, 20, 40], jnp.int32)
+    sm = d ** -0.5
+    got = paged_ops.paged_block_attention(
+        q, pack(k_pages), pack(v_pages), page_tables, base, block_len=4,
+        sm_scale=sm)
+    want = _ref_attention(q, k_pages, v_pages, page_tables, base,
+                          jnp.full((b,), mp * page, jnp.int32), sm, 4)
+    _assert_matches(got, want)
+
+
+def test_walking_body_on_per_kv_head_shards_of_a_tensor_mesh():
+    """Under ``shard_map`` with :func:`tp_shard_specs` (how a TP engine
+    runs every kernel of the family): each shard's call is a single-chip
+    call over its own KV heads, which the body reads off its operands'
+    shapes."""
+    import functools
+
+    from jax.sharding import Mesh
+
+    hkv, n_rep, d, page, mp, b, t = 4, 2, 16, 8, 6, 3, 4
+    k_pages, v_pages = _rand_pool(jax.random.PRNGKey(11), hkv, mp * b + 1,
+                                  page, d, jnp.float32)
+    q = jax.random.normal(jax.random.PRNGKey(12), (b, t, hkv * n_rep, d),
+                          jnp.float32)
+    page_tables = jnp.arange(1, mp * b + 1).reshape(b, mp).astype(jnp.int32)
+    base = jnp.asarray([0, 20, 44], jnp.int32)
+    mesh = Mesh(np.asarray(jax.devices()[:2]), ("tensor",))
+    got = kv_cache._paged_kernel(
+        functools.partial(paged_ops.paged_block_attention, block_len=4),
+        q, k_pages[None], v_pages[None], page_tables, base, jnp.int32(0),
+        sm_scale=d ** -0.5, mesh=mesh)
+    want = _ref_attention(q, k_pages, v_pages, page_tables, base,
+                          jnp.full((b,), mp * page, jnp.int32), d ** -0.5, 4)
+    _assert_matches(got, want)
+
+
+def test_walking_body_on_a_chunk_with_limit_below_the_table():
+    """A chunk-shaped call (one slot, ``limit`` = the prompt's length,
+    below the table's span): the padded rows' keys past the prompt stay
+    masked and the pages past the prompt are not walked."""
+    hkv, n_rep, d, page, mp = 2, 2, 16, 8, 6
+    h = hkv * n_rep
+    c, start, true_len = 16, 8, 19
+    k_pages, v_pages = _rand_pool(jax.random.PRNGKey(2), hkv, mp + 1,
+                                  page, d, jnp.float32)
+    q = jax.random.normal(jax.random.PRNGKey(3), (1, c, h, d), jnp.float32)
+    page_table = jnp.arange(1, mp + 1, dtype=jnp.int32)
+    base = jnp.asarray([start], jnp.int32)
+    limit = jnp.asarray([true_len], jnp.int32)
+    dead = jnp.arange(mp + 1) > -(-true_len // page)      # past page 3
+    for block_len in (1, 4):
+        got = paged_ops.paged_attention(
+            q, jnp.where(dead[None, :, None, None], jnp.nan, k_pages),
+            jnp.where(dead[None, :, None, None], jnp.nan, v_pages),
+            page_table[None], base, limit, sm_scale=d ** -0.5,
+            name="paged_chunk_attention", block_len=block_len, walk=True)
+        want = _ref_attention(q, k_pages, v_pages, page_table[None], base,
+                              limit, d ** -0.5, block_len)
+        _assert_matches(got, want)
+
+
+def _ragged_heads_batch(t, block_len):
+    """:func:`_ragged_batch` on pools of K and V per head (2 KV heads of
+    16, 2 query heads each, pages of 128, tables of 6)."""
+    lens = np.array([1, 127, 128, 129, 6 * 128, 0, 300])
+    page, mp, hkv, n_rep, d = _LAT["page"], 6, 2, 2, 16
+    kq, kp = jax.random.split(jax.random.PRNGKey(48 + t))
+    k_pages, v_pages = _rand_pool(kp, hkv, len(lens) * mp + 1, page, d,
+                                  jnp.float32)
+    tables = 1 + jnp.arange(len(lens) * mp, dtype=jnp.int32).reshape(
+        len(lens), mp)
+    q = jax.random.normal(kq, (len(lens), t, hkv * n_rep, d))
+    limit = jnp.asarray(lens, jnp.int32)
+    base = jnp.maximum(limit - t, 0)
+    got = paged_ops.paged_attention(
+        q, _nan_outside(k_pages, tables, lens, axis=1),
+        _nan_outside(v_pages, tables, lens, axis=1), tables, base, limit,
+        sm_scale=d ** -0.5, block_len=block_len, walk=True)
+    want = _ref_attention(q, k_pages, v_pages, tables, base, limit,
+                          d ** -0.5, block_len)
+    return got, want, lens
+
+
+@pytest.mark.parametrize("t,block_len", [(1, 1), (4, 4), (8, 4)],
+                         ids=["decode", "block", "two_blocks"])
+def test_walking_body_reads_no_dead_page_and_multiplies_no_dead_row(
+        t, block_len):
+    """The ragged batch of the latent body's test on pools of K and V
+    whose pages outside every slot's live pages hold NaN (the trash page
+    too): the outputs are finite and the gather path's (which is given the
+    clean pools: it reads every page), and a slot with nothing live
+    writes zeros."""
+    got, want, lens = _ragged_heads_batch(t, block_len)
+    assert np.isfinite(np.asarray(got)).all()
+    live = lens > 0
+    np.testing.assert_allclose(got[live], want[live], atol=2e-5)
+    assert not np.asarray(got[~live]).any()
+
+
+def test_only_the_block_wrapper_walks_on_pools_of_k_and_v():
+    """The adoption is by the call's kind, from ONE table: the block
+    wrapper's program holds the walking body's copies, the decode, verify
+    and chunk wrappers' programs do not; and what an engine reports as
+    ``attn_walks_live`` is that table read for its cache spec."""
+    hkv, d, page, mp, b = 2, 16, 8, 4, 2
+    k_pages, v_pages = _rand_pool(jax.random.PRNGKey(0), hkv, mp * b + 1,
+                                  page, d, jnp.float32)
+    q = jnp.zeros((b, 4, 2 * hkv, d), jnp.float32)
+    tables = jnp.arange(1, mp * b + 1).reshape(b, mp).astype(jnp.int32)
+    lens = jnp.asarray([4, 8], jnp.int32)
+
+    def walks(fn, *a, **kw):
+        return "dma_start" in str(jax.make_jaxpr(
+            lambda *a: fn(*a, **kw))(*a))
+
+    assert walks(paged_ops.paged_block_attention, q, k_pages, v_pages,
+                 tables, lens, block_len=4)
+    assert not walks(paged_ops.paged_verify_attention, q, k_pages, v_pages,
+                     tables, lens)
+    assert not walks(paged_ops.paged_decode_attention, q[:, 0], k_pages,
+                     v_pages, tables, lens)
+    assert not walks(paged_ops.paged_chunk_attention, q[:1], k_pages,
+                     v_pages, tables[0], lens[0], lens[1], block_len=4)
+    assert paged_ops.walking_calls(latent=False, block_len=4) == ["block"]
+    assert paged_ops.walking_calls(latent=False) == []
+    assert paged_ops.walking_calls(latent=True) == [
+        "decode", "verify", "chunk"]
 
 
 # ---------------------------------------------------------------------------

@@ -18,7 +18,7 @@ import numpy as np
 import pytest
 
 from benchmark import common
-from ray_tpu.models import sdar_moe
+from ray_tpu.models import llama, sdar_moe
 from ray_tpu.ops import paged_attention as paged_ops
 from ray_tpu.parallel import expert
 from ray_tpu.serve.llm import LLMConfig, LLMEngine, disagg
@@ -64,9 +64,13 @@ def _serve(eng, prompts, max_tokens):
 
 @pytest.mark.parametrize("block_len", [1, 2, 4])
 def test_kernel_block_mask_against_dense_attention(block_len):
-    """The one Pallas kernel (interpreted) under ``block_len``: equal to
-    dense attention under the block mask; at 1 it IS today's causal kernel,
-    bit for bit."""
+    """The block wrapper's kernel (interpreted) under ``block_len``: equal
+    to dense attention under the block mask; at 1 its mask is the causal
+    one, so it equals the verify wrapper's. Since ISSUE 48 the two run two
+    bodies (the block wrapper walks live pages in chunks of columns, the
+    verify wrapper multiplies the table's whole span at once) whose
+    partial sums may order differently: equal to the test's own
+    tolerance, where it was bit for bit."""
     rs = np.random.RandomState(block_len)
     slots, t, h, hkv, d, pages, mp = 3, 4, 4, 2, 16, 12, 3
     q = jnp.asarray(rs.randn(slots, t, h, d), jnp.float32)
@@ -89,11 +93,70 @@ def test_kernel_block_mask_against_dense_attention(block_len):
     if block_len == 1:
         causal = paged_ops.paged_verify_attention(
             q, k_pool, v_pool, tables, lens, 0, interpret=True)
-        assert np.array_equal(np.asarray(got), np.asarray(causal))
+        np.testing.assert_allclose(np.asarray(got), np.asarray(causal),
+                                   atol=2e-6)
     else:   # the last position of a block sees no more than the first does
         assert not np.allclose(np.asarray(got), np.asarray(
             paged_ops.paged_verify_attention(q, k_pool, v_pool, tables, lens,
                                              0, interpret=True)))
+
+
+@pytest.mark.parametrize("ends", ["inside_a_page", "on_a_page_edge",
+                                  "at_max_seq_len"])
+@pytest.mark.parametrize("blocks", [1, 2], ids=["one_block", "two_blocks"])
+def test_the_walking_kernel_reads_no_page_past_a_slots_live_ones(
+        params, blocks, ends):
+    """A block pass (and the pass of two blocks) through the pallas
+    backend on a POISONED pool, NaN in every page past each slot's live
+    ones (the trash page too), equals the gather backend's on the clean
+    pool: the kernel's work follows the context (ISSUE 48). The context
+    under test beside a short neighbour; at ``max_seq_len`` the second
+    block of a pass of two lies past the table's width (the trash page)
+    and the live length is the table's."""
+    mp = 6                                           # table width: 48 tokens
+    ctx = {"inside_a_page": 2 * PAGE + B, "on_a_page_edge": 2 * PAGE,
+           "at_max_seq_len": mp * PAGE - B}[ends]
+    lens = np.asarray([ctx, B])
+    t = blocks * B
+    rs = np.random.RandomState(48)
+    kv = kvc.init_paged_cache(CFG, 2 * mp + 1, PAGE)
+    kv = {**kv, **{pool: jnp.asarray(0.5 * rs.randn(*kv[pool].shape),
+                                     kv[pool].dtype) for pool in "kv"}}
+    tables = jnp.asarray(1 + rs.permutation(2 * mp).reshape(2, mp), jnp.int32)
+    live = np.zeros(2 * mp + 1, bool)
+    for row, n in zip(np.asarray(tables), lens):
+        live[row[:-(-min(int(n) + t, mp * PAGE) // PAGE)]] = True
+    poisoned = {**kv, **{pool: jnp.where(live[None, None, :, None, None],
+                                         kv[pool], jnp.nan) for pool in "kv"}}
+    tokens = jnp.asarray([[3, 1, 4, 1] + [MASK] * (t - B),
+                          [MASK] * t], jnp.int32)
+
+    def logits(kv, backend):
+        if blocks == 2:
+            return kvc.paged_block_pair_step(
+                params, kv, tables, jnp.asarray(lens, jnp.int32), tokens,
+                CFG, PAGE, backend)[0]
+        return kvc.paged_block_step(
+            params, kv, tables, jnp.asarray(lens, jnp.int32), tokens, CFG,
+            PAGE, backend, commit=False)[0]
+
+    got = np.asarray(logits(poisoned, "pallas"))
+    assert np.isfinite(got).all()
+    np.testing.assert_allclose(got, np.asarray(logits(kv, "gather")),
+                               atol=1e-5)
+
+
+@pytest.mark.parametrize("model,backend,want", [
+    (CFG, "pallas", ["block"]), (CFG, "gather", []),
+    (llama.llama_tiny(vocab_size=512), "pallas", [])],
+    ids=["sdar-pallas", "sdar-gather", "dense-pallas"])
+def test_attn_walks_live_names_the_calls_that_walk(model, backend, want):
+    """``attn_walks_live``: the call kinds of THIS engine's programs whose
+    kernel body walks live pages, so that ``attn_live_pages_total /
+    attn_table_pages_total`` is read only where it applies."""
+    eng = LLMEngine(LLMConfig(model_config=model,
+                              **{**ENGINE, "attention_kernel": backend}))
+    assert eng.engine_stats()["attn_walks_live"] == want
 
 
 def test_pallas_and_gather_backends_give_one_block_pass(params):
