@@ -276,6 +276,7 @@ def kernels_child(rehearsal: bool, chips: int) -> int:
 
     # the grouped expert product (rows sorted by expert, one group a held
     # expert) against its plain form: every row through its own expert
+    from ray_tpu.ops import grouped_matmul as grouped_ops
     from ray_tpu.parallel import expert as expert_mod
     rows, n_exp, dim, width = k["experts"]
     wk = jax.random.split(keys[4], 5)
@@ -301,10 +302,16 @@ def kernels_child(rehearsal: bool, chips: int) -> int:
         return jax.lax.scan(one, jnp.zeros((rows, dim), jnp.float32),
                             jnp.arange(n_exp))[0]
 
-    grouped = jax.jit(expert_mod.grouped_swiglu)
-    ok &= timed("grouped_experts",
-                lambda: grouped(xs, w_gate, w_up, w_down, group_sizes),
-                plain_experts)
+    @jax.jit
+    def grouped():
+        # the product takes and gives the ALIGNED layout: every expert's
+        # rows from a multiple of 16 on, padding between them
+        take, lie = grouped_ops.aligned_order(owner, group_sizes)
+        return expert_mod.grouped_swiglu(
+            xs[jnp.minimum(take, rows - 1)], w_gate, w_up, w_down,
+            group_sizes)[lie]
+
+    ok &= timed("grouped_experts", grouped, plain_experts)
 
     def ref_flash(q, k_, v):
         # float32 inputs AND float32 matmul passes (the TPU default for a

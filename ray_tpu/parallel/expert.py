@@ -10,12 +10,22 @@ buckets token-shard↔expert-shard over ICI.
 
 from __future__ import annotations
 
+import functools
 from typing import Callable
 
 import jax
 import jax.numpy as jnp
 from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
+
+
+def _grouped():
+    """``ray_tpu/ops/grouped_matmul.py``, at first use: it pulls Pallas in
+    (1.1 s of imports), and a process that imports a model to read its
+    configuration (a driver, a proxy, the benchmark's parent) multiplies
+    nothing."""
+    from ray_tpu.ops import grouped_matmul
+    return grouped_matmul
 
 
 def top_k_gating(gate_logits, k: int):
@@ -174,47 +184,99 @@ def route_softmax_top_k(g, router, top_k: int, *,
     return idx.astype(jnp.int32), w
 
 
-def _tile(n: int, cap: int) -> int:
-    """The whole dimension, or the largest ``n / 2**j`` under ``cap``."""
-    while n > cap and n % 2 == 0:
-        n //= 2
-    return n
+def _columns(k: int, n: int) -> int:
+    """Columns of one grid step of a product [rows, k] x [k, n]: all n, or
+    the largest ``n / 2**j`` that keeps the matrix's tile at 2,048 x 1,024
+    numbers (two of them are in flight) and is still whole lane tiles of
+    128. The kernel keeps the WHOLE of k in one tile, so a k that leaves
+    no such width (above 16,384, or an n that cannot be halved that far:
+    no family served here comes near) is refused and not run over the
+    VMEM's budget."""
+    tn = n
+    while k * tn > 2048 * 1024 and tn % 256 == 0:
+        tn //= 2
+    if k * tn > 2048 * 1024:
+        raise ValueError(
+            f"a grouped product of [{k}, {n}] matrices finds no column tile "
+            f"of whole 128s under {2048 * 1024} numbers: the kernel does "
+            "not tile k")
+    return tn
 
 
-def grouped_swiglu(xs, w_gate, w_up, w_down, sizes):
-    """SwiGLU of rows sorted by expert: rows ``sum(sizes[:e])`` ..
-    ``sum(sizes[:e + 1])`` of xs [M, D] go through expert e's weights
+def product_visits(sizes, m: int):
+    """The times ONE grouped product of a call of ``m`` rows passes an
+    expert's matrix through the MXU, from the rows each expert got: sizes
+    [..., E] (numpy or jax; leading dimensions are calls of m rows each
+    and are summed). A touched expert is one, and one more for each
+    further chunk of its rows (``grouped.chunk_rows``: 128 at any width a
+    cell runs)."""
+    grouped = _grouped()
+    c = grouped.chunk_rows(grouped.aligned_rows(m, sizes.shape[-1]))
+    return (-(-sizes // c)).sum()
+
+
+def grouped_swiglu(xs, w_gate, w_up, w_down, sizes, interpret=None):
+    """SwiGLU of rows grouped by expert: xs [A, D] in the ALIGNED layout
+    (``grouped.aligned_order``: expert e's ``sizes[e]`` rows from
+    ``sum(ceil(sizes[:e] / 16) * 16)`` on) go through expert e's weights
     (w_gate / w_up [E, D, F], w_down [E, F, D]).
 
-    Three calls of the Pallas grouped matmul ``gmm`` (jax.experimental.
-    pallas.ops.tpu.megablox), under the scope ``grouped_ffn``: it walks
-    (row tile, group) pairs, so an expert's weights are read once a row
-    tile that holds rows of it (once a call at decode widths) and an
-    expert with no row is never read. Chosen over ``jax.lax.ragged_dot``
-    by measurement on a v5e at the published widths (PERF.md section 6,
-    PR 35: 1.01 ms against 1.99 at 256 rows, 1.30 against 3.08 at 2,048).
-    Rows are padded to whole row tiles; rows past ``sum(sizes)`` (the
-    padding, picks of experts held elsewhere) are never visited and come
-    out as whatever memory held: the caller leaves them out. Off the TPU
-    the kernel runs interpreted. Returns [M, D] float32."""
-    from jax.experimental.pallas.ops.tpu.megablox import gmm
-
-    from ray_tpu.ops import paged_attention as paged_ops
-
-    m = xs.shape[0]
-    tm = min(128, -(-m // 16) * 16)
-    xs = jnp.pad(xs, ((0, -m % tm), (0, 0)))
-    interpret = paged_ops.interpret_default()   # one rule for every kernel
-
-    def product(a, w, dtype):
-        return gmm(a, w, sizes, preferred_element_type=dtype,
-                   tiling=(tm, _tile(w.shape[1], 2048),
-                           _tile(w.shape[2], 1024)), interpret=interpret)
-
+    Two calls of the Pallas grouped matmul of ``ray_tpu/ops/
+    grouped_matmul.py`` under the scope ``grouped_ffn``: gate, up and the
+    activation in the first (both matrices of an expert in one grid step,
+    its rows read once, the gated rows never in HBM as two halves), down
+    in the second. Each walks the experts that HAVE rows, one grid step an
+    expert, so an expert's weights are read once a call and pass through
+    the MXU once (once more for each further 128 rows of it), an expert
+    with no row is never read, and no row moves that is no expert's. The
+    megablox ``gmm`` it replaced (PR 35 chose that over
+    ``jax.lax.ragged_dot``: 1.01 ms against 1.99 at 256 rows) read the
+    weights once too, but pushed them through the MXU again, the memory
+    idle, wherever an expert's rows crossed one of its row tiles of 128:
+    nearly every tile's edge at SDAR's 32 and 16 rows an expert, one edge
+    in thirty at LFM2's and JoyAI's decode widths (PERF.md section 6, "PR
+    49 and PR 50", has every shape's reading on a v5e). Off the TPU the
+    kernel runs interpreted (``interpret``: None asks the one rule every
+    kernel asks). Returns [A, D] float32 in the same layout; rows of no
+    expert (the padding, picks of experts held elsewhere) are never
+    visited and come out as whatever memory held: the caller leaves them
+    out."""
+    if interpret is None:
+        from ray_tpu.ops import paged_attention as paged_ops
+        interpret = paged_ops.interpret_default()
+    grouped = _grouped()
+    d, f = w_gate.shape[1:]
+    how = dict(c=grouped.chunk_rows(xs.shape[0]), interpret=interpret)
     with jax.named_scope("grouped_ffn"):
-        gate = product(xs, w_gate, xs.dtype)
-        up = product(xs, w_up, xs.dtype)
-        return product(jax.nn.silu(gate) * up, w_down, jnp.float32)[:m]
+        walk, n = grouped.walk_of(sizes)
+        h = grouped.grouped_matmul(
+            xs, w_gate, walk, n, tn=_columns(d, f), out_dtype=xs.dtype,
+            rhs_up=w_up, **how)
+        return grouped.grouped_matmul(
+            h, w_down, walk, n, tn=_columns(f, d), out_dtype=jnp.float32,
+            **how)
+
+
+@functools.partial(jax.jit, static_argnames=("held", "interpret"))
+def _share(g, idx, w, w_gate, w_up, w_down, *, held, interpret):
+    """:func:`expert_share`'s body. Jitted with the layer's weights as
+    OPERANDS: a program that walks its layers calls it once a routed layer
+    and pass, and the whole share (sort, gathers, both kernels, the sum)
+    is then traced once a process and lowered once a program (PR 45's
+    lesson)."""
+    n, k = idx.shape
+    n_held = len(held)
+    local = idx - held.start
+    mine = (local >= 0) & (local < n_held)
+    key = jnp.where(mine, local, n_held).reshape(-1)              # [N*k]
+    sizes = jnp.sum(key[:, None] == jnp.arange(n_held)[None, :], axis=0,
+                    dtype=jnp.int32)
+    take, lie = _grouped().aligned_order(key, sizes)
+    ys = grouped_swiglu(g[jnp.minimum(take, n * k - 1) // k], w_gate, w_up,
+                        w_down, sizes, interpret)                 # [A, D]
+    picks = ys[lie].reshape(n, k, -1)
+    return jnp.sum(jnp.where(mine[..., None], picks * w[..., None], 0.0),
+                   axis=1)
 
 
 def expert_share(g, idx, w, experts: dict, held: range):
@@ -225,22 +287,17 @@ def expert_share(g, idx, w, experts: dict, held: range):
     the router is counted once, outside); experts: w_gate / w_up [len(held), D, F], w_down
     [len(held), F, D]: only the held experts' weights. Dropless: every
     (token, expert) pick with the expert in ``held`` is computed, however
-    many land on one expert. Picks are sorted by expert, multiplied group
-    by group, put back in token order and summed with their weights; picks
-    of experts held elsewhere sort last and are left out of the sum.
+    many land on one expert. ONE stable sort lays the picks out by expert,
+    every expert's on a multiple of 16 rows (the padding rides in the sort
+    as picks of its own: ``grouped.aligned_order``); they are multiplied
+    expert by expert (:func:`grouped_swiglu`: every expert that has a pick
+    is one grid step of each kernel, whatever the call's width), put back
+    in token order and summed with their weights; picks of experts held
+    elsewhere sort last, are never visited and are left out of the sum.
     The shares of a partition of the experts add up to the whole layer.
     Returns [N, D] float32."""
-    n, k = idx.shape
-    n_held = len(held)
-    local = idx - held.start
-    mine = (local >= 0) & (local < n_held)
-    key = jnp.where(mine, local, n_held).reshape(-1)              # [N*k]
-    order = jnp.argsort(key, stable=True)
-    sizes = jnp.sum(key[:, None] == jnp.arange(n_held)[None, :], axis=0,
-                    dtype=jnp.int32)
-    ys = grouped_swiglu(g[order // k], experts["w_gate"], experts["w_up"],
-                        experts["w_down"], sizes)                 # [N*k, D]
-    back = jnp.argsort(order)
-    picks = ys[back].reshape(n, k, -1)
-    return jnp.sum(jnp.where(mine[..., None], picks * w[..., None], 0.0),
-                   axis=1)
+    from ray_tpu.ops import paged_attention as paged_ops
+
+    return _share(g, idx, w, experts["w_gate"], experts["w_up"],
+                  experts["w_down"], held=held,
+                  interpret=paged_ops.interpret_default())  # one rule for every kernel

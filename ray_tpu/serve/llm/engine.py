@@ -353,8 +353,12 @@ class LLMEngine:
                       # routed experts (0 for a block without them): over
                       # the decode blocks harvested, distinct experts
                       # touched and (token, expert) rows multiplied, summed
-                      # over routed layers and steps, and those layer-steps
+                      # over routed layers and steps, the times one
+                      # grouped product passed an expert's matrix through
+                      # the MXU (1 an expert with rows, 1 more for each
+                      # further 128 rows of it), and those layer-steps
                       "experts_touched_total": 0, "expert_rows_total": 0,
+                      "expert_visits_total": 0,
                       "routed_layer_steps_total": 0,
                       # what a block with slot state does not take part
                       # in: admissions that skipped the prefix index / the
@@ -661,19 +665,26 @@ class LLMEngine:
                 toks_full = toks_full.at[idx].set(last)
         if touched is not None:
             # a routed block: one more output, harvested with the tokens
-            return all_toks, toks_full, kv, sl_full, rng, jnp.sum(touched)
+            return (all_toks, toks_full, kv, sl_full, rng,
+                    jnp.sum(touched, axis=0))
         return all_toks, toks_full, kv, sl_full, rng
 
     def _experts_touched(self, kv, idx, rows_a_slot: int = 1):
-        """Distinct experts the live rows of the last decode step (or
-        block pass: ``rows_a_slot`` rows a slot, slot-major) chose, summed
-        over the routed layers (int32 scalar); None for a block without
-        routed experts. ``idx``: the packed slot index, whose padding
-        lanes (the trash row) do not count."""
+        """What the routed layers of the last decode step (or block pass:
+        ``rows_a_slot`` rows a slot, slot-major) did, summed over them
+        (int32 [2]); None for a block without routed experts. [0]: the
+        distinct experts the LIVE rows chose (``idx``: the packed slot
+        index, whose padding lanes, the trash row, do not count). [1]: the
+        visits of one grouped product (parallel/expert.product_visits over
+        EVERY row of the call, as the kernel sees them): an expert with
+        rows is one, and one more for each further 128 rows of it. Both
+        are DERIVED here from the routing record; [1] is the kernel's rule
+        applied to it, not a count the kernel reports."""
         spec = self._cache_spec
         if not spec.routed_layers:
             return None
         jnp = self._jnp
+        from ray_tpu.parallel import expert
         with self._jax.named_scope("experts_touched"):
             live = idx != self.cfg.max_batch_size                    # [W]
             if rows_a_slot > 1:
@@ -681,7 +692,10 @@ class LLMEngine:
             chosen = kv["routing"][:, :live.shape[0]]           # [L_r, W, k]
             hot = chosen[..., None] == jnp.arange(spec.n_experts)
             hit = jnp.any(hot & live[None, :, None, None], axis=(1, 2))
-            return jnp.sum(hit, dtype=jnp.int32)
+            sizes = jnp.sum(hot, axis=(1, 2), dtype=jnp.int32)    # [L_r, E]
+            return jnp.stack([jnp.sum(hit, dtype=jnp.int32),
+                              expert.product_visits(
+                                  sizes, live.shape[0] * spec.top_k)])
 
     def _block_impl(self, params, kv, pt_full, sl_full, toks_full, rng,
                     temps_full, idx, num_blocks: int = 1):
@@ -718,9 +732,10 @@ class LLMEngine:
         Returns the blocks' tokens as [num_blocks x B, W] (row j x B + i:
         position i of block j, known tokens included: the host skips the
         ones a prompt left), the carried state, and the program's counts,
-        int32 [3]: experts touched over all passes (0 without routed
+        int32 [4]: experts touched over all passes (0 without routed
         experts), passes of 2B in which a live slot kept a block and
-        denoised the next, and blocks kept (live slots summed)."""
+        denoised the next, blocks kept (live slots summed), and the visits
+        of the routed layers' grouped products (_experts_touched)."""
         jax = self._jax
         jnp = self._jnp
         mcfg = self.model_cfg
@@ -733,7 +748,7 @@ class LLMEngine:
 
         def touched(kv_c, rows_a_slot):
             n = self._experts_touched(kv_c, idx, rows_a_slot)
-            return jnp.int32(0) if n is None else n
+            return jnp.zeros((2,), jnp.int32) if n is None else n
 
         def unmask(logits, blk, key):
             """blk [W, B] with n more of its masked positions revealed."""
@@ -790,8 +805,8 @@ class LLMEngine:
                         n_touched += touched(kv_c, b)
                 kept = kept & live
                 return (kv_c, lens, blk, key), (blk, jnp.stack(
-                    [n_touched, jnp.any(kept).astype(jnp.int32),
-                     jnp.sum(kept, dtype=jnp.int32)]))
+                    [n_touched[0], jnp.any(kept).astype(jnp.int32),
+                     jnp.sum(kept, dtype=jnp.int32), n_touched[1]]))
 
             (kv, new_lens, last, rng), (blocks, counts) = jax.lax.scan(
                 one, (kv, lens0, blk0, rng), None, length=num_blocks)
@@ -2873,13 +2888,14 @@ class LLMEngine:
                 # rows a slot: a token a step, or B positions a pass and B
                 # more for each block's pass of two blocks
                 rows = (passes + (k if bl > 1 else 0)) * bl
-                sp.set(experts_touched=counts[0])
+                sp.set(experts_touched=counts[0], expert_visits=counts[-1])
                 self.stats["experts_touched_total"] += counts[0]
+                self.stats["expert_visits_total"] += counts[-1]
                 self.stats["routed_layer_steps_total"] += passes * routed
                 self.stats["expert_rows_total"] += (
                     routed * len(snapshot) * rows * self._cache_spec.top_k)
         if bl > 1:
-            _touched, fused, kept = counts
+            _touched, fused, kept, _visits = counts
             self.stats["block_passes_total"] += passes
             self.stats["denoise_passes_total"] += passes
             self.stats["fused_passes_total"] += fused

@@ -10,7 +10,13 @@ dispatch and the harvest with these; "nothing moves in their cells" is held
 here, on the CPU, before any chip is asked. A PR that changes WHICH program
 the loop dispatches (ISSUE 42: the idle tier's k) changes no program: the
 SDAR entries were written by the parent commit's engine, and every k the
-loop can pick is one ``start()`` warmed.
+loop can pick is one ``start()`` warmed. ISSUE 50 (ISSUE 49 asked again)
+changed the grouped expert product: the ROUTED families' entries (lfm2,
+sdar, joyai: 30) were rewritten by that PR's tree; the dense block's
+twelve (decode tiers, verify, prefill, chunk, both backends) are commit
+c3e050f's, letter for letter, and ``PARENT_DENSE`` below holds them a
+second time so that a rewrite of the file cannot move them unseen: no
+Mistral cell runs a changed program.
 
 A PR that MEANS to change one of these programs rewrites the file and says
 so: ``python tests/test_engine_program_hashes.py`` (from the repo's root).
@@ -108,6 +114,67 @@ def test_program_lowers_to_the_recorded_text(recorded, block, backend,
         == recorded[f"{block}-{backend}-{program}"], (
         "the program's lowered text changed: if that was meant, rewrite "
         "tests/data/engine_program_hashes.json (this file, run as a script)")
+
+
+# the dense block's programs as commit c3e050f (PR 48) lowered them
+PARENT_DENSE = {
+    "dense-gather-chunk_16":
+        "34522c381ce66b8eacae53f6e9db1ba3d23aea1988454ce4043021282cefaabb",
+    "dense-gather-decode_1":
+        "68a2fb4b2fa91770adcdce54e4223207e9ac76bb4fa2c2549d90569cbbc0a6d1",
+    "dense-gather-decode_4":
+        "3538c58aaf3505d991f53ea776a2e3c1fb9361ea56c7963a7c10292b32f6d012",
+    "dense-gather-decode_8":
+        "ea99c6d11700e73d66dc0557dcd196ac0e7609504d388536135097987ae06d1b",
+    "dense-gather-prefill_32":
+        "1dd3bb5702898dca585b4ceb937338da2d441885b009b3cc11fee052474c9e32",
+    "dense-gather-verify":
+        "e719aa1b4a1d1276a982594dbf027e7819266c2136fb0b25ed4fabe05d7d5707",
+    "dense-pallas-chunk_16":
+        "8ff8ba71ba004989423c7eab1626690e4e999c49d1ddb70f81e169827cafa0a7",
+    "dense-pallas-decode_1":
+        "53b0c0a68c1199db22c7eebcf37489df9a57b5bfadfb7d2d16665d7365b272d1",
+    "dense-pallas-decode_4":
+        "76a987d42d0a1d7f6b50912c2e77f8fe562ed7b4ef20af28df7849e228d80aad",
+    "dense-pallas-decode_8":
+        "65c8a46f937628d1c7ee0a6ee7de79f34e377f79b0f69aa01dec95dd1aa125a4",
+    "dense-pallas-prefill_32":
+        "1dd3bb5702898dca585b4ceb937338da2d441885b009b3cc11fee052474c9e32",
+    "dense-pallas-verify":
+        "fdcff01d80ef193e2f548e4c0cdbfb6c2093c1f3d77d4f950430272f7f3adf62",
+}
+
+
+@pytest.mark.parametrize("name", sorted(PARENT_DENSE))
+def test_a_dense_program_is_recorded_as_the_parent_lowered_it(recorded, name):
+    """ISSUE 50 rewrote the routed families' entries; a dense entry that
+    moved with them would mean the Mistral cells run another program."""
+    assert recorded[name] == PARENT_DENSE[name]
+
+
+def test_expert_visits_follow_the_kernels_rule_on_a_hand_made_record():
+    """``_experts_touched`` [1]: the times one grouped product passes an
+    expert's matrix through the MXU, from the routing record by the
+    kernel's rule: 1 a touched expert, 1 more for each further 128 rows.
+    130 rows of one pick over 4 experts: expert 2 takes 129 (two visits),
+    expert 0 one (one visit), experts 1 and 3 none; the second layer puts
+    128 on expert 3 (one visit) and one each on 0 and 1."""
+    import types
+
+    import jax.numpy as jnp
+
+    first = np.full((130, 1), 2, np.int32)
+    first[7] = 0
+    second = np.full((130, 1), 3, np.int32)
+    second[0], second[129] = 0, 1
+    eng = types.SimpleNamespace(
+        _jax=jax, _jnp=jnp, cfg=types.SimpleNamespace(max_batch_size=130),
+        _cache_spec=types.SimpleNamespace(routed_layers=2, n_experts=4,
+                                          top_k=1))
+    idx = jnp.arange(130, dtype=jnp.int32)                  # every lane live
+    touched, visits = np.asarray(LLMEngine._experts_touched(
+        eng, {"routing": jnp.asarray(np.stack([first, second]))}, idx))
+    assert (touched, visits) == (2 + 3, (2 + 1) + (1 + 1 + 1))
 
 
 TIER_CASES = [("dense", {}), ("dense", {"spec_decode_enabled": True}),
