@@ -186,7 +186,7 @@ def kernels_child(rehearsal: bool, chips: int) -> int:
     hi = jax.lax.Precision.HIGHEST
 
     def ref_paged(q, page_tables, base, limit, kp=kp, vp=vp):
-        """The gather path's formula (kv_cache._decode_attention) in
+        """The gather path's formula (kv_cache._attend's decode read) in
         float32 throughout; kp / vp [Hkv, P, page, D]."""
         q, kf, vf = (x.astype(jnp.float32) for x in (q, kp, vp))
         b, t, h, d = q.shape

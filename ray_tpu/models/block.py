@@ -40,6 +40,25 @@ that can be served provides, at module level:
         ``choice`` int32 [rows, top_k]: the experts a routed layer took
     serve_final_norm(x, params, cfg), serve_lm_head(x, params, cfg)
 
+A MIXER KIND (``LayerDef.mixer``; every layer of a block with
+``serve_layers`` None is "attn") is ONE function in kv_cache.py's table
+``_MIXERS``, ``mixer(x, kv, layer, ld, l, geometry, cfg) -> (x + mixer,
+kv)``, which every program that reads the cache back runs through the one
+layer body (``kv_cache._layer``: the mixer, then ``serve_ffn``); a program
+states only its geometry (where this call's rows go, how it reads the cache
+back) and no mixer names a program. "attn" (``serve_qkv``,
+``serve_attn_out``) writes K and V a head, then reads the layer back;
+"latent" (``serve_latent``, ``serve_latent_out``) writes the one row and
+reads it back in the absorbed form; "conv" (``serve_conv``) keeps its state
+in the row of the sequence's first page: a call that starts a sequence
+reads zeros instead, and the row keeps the state as of the call's last real
+column. A whole prefill reads nothing back (the prompt's own rows, "latent"
+through ``serve_latent_expanded``, the write after the feed-forward) and
+keeps a layer of its own. A new kind provides its ``serve_*`` functions
+here, one function and one entry there, what it keeps in ``CacheSpec``
+and, only if it reads the cache in a new way, a wrapper in ops/
+paged_attention.py; it edits no program.
+
 ``cfg.head_dim``, ``cfg.dtype`` and ``cfg.max_seq_len`` are read off the
 configuration itself (``head_dim``: the width of a query and key head, so
 the softmax scale is ``head_dim ** -0.5`` for a latent mixer too).
@@ -101,8 +120,10 @@ class CacheSpec:
 @dataclasses.dataclass(frozen=True)
 class LayerDef:
     """One layer of a block whose layers differ: its mixer ("attn" |
-    "conv"), its feed-forward kind ("dense" | "routed"), and which row of
-    the pool, of the slot state and of the routing record is its own."""
+    "conv" | "latent": a key of kv_cache.py's ``_MIXERS``, the module
+    docstring's "a mixer kind"), its feed-forward kind ("dense" |
+    "routed"), and which row of the pool, of the slot state and of the
+    routing record is its own."""
     mixer: str
     ffn: str
     page_layer: int = -1

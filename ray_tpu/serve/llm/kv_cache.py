@@ -30,6 +30,10 @@ Design choices:
   index maps, the gather backend gathers ``pool[l, :, page_tables]`` — so
   no program slices a layer's pool out of the stack, restacks it, or
   copies the pool to update it (tests/test_pool_carry.py holds that);
+- the layer of a block is written ONCE: a program that reads the cache
+  back states its :class:`_Geometry` (where this call's rows go, how it
+  reads them back) and runs :func:`_layer`, the mixer of the layer's kind
+  (``_MIXERS``), then its feed-forward; a mixer names no program;
 - writes are scatters at (layer, head, page, offset) indices; inactive
   slots write to a reserved trash page (page 0), keeping the step free of
   dynamic shapes and `lax.cond`s;
@@ -76,6 +80,7 @@ with pages on axis 2) keep their own format, which those modules own.
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 import hashlib
 import logging
@@ -650,19 +655,20 @@ def _write_token_rows(pool, layer, new, page_idx, offset):
     return pool.at[idx].set(new[..., None, :].astype(pool.dtype))
 
 
-def _latent_mixer(x, kv, layer, l, cos, sin, cfg, page_idx, offset, attend):
+def _latent_mixer(x, kv, layer, ld, l, g):
     """A mixer that keeps one latent row a token, in its ABSORBED form
     (models/block.py ``serve_latent``): the rows of x [B, T, D] are written
-    to the pool at page_idx / offset [B, T], then ``attend(q, pool, l)``
-    runs q [B, T, H, latent_dim] against layer ``l`` of the pool and
-    returns the weighted rows' first ``value_dim`` lanes [B, T, H,
-    value_dim]. Returns (x + mixer, kv)."""
-    blk = block_of(cfg)
-    q, entry = blk.serve_latent(x, layer, cos, sin, cfg)
+    to layer ``l`` of the pool where the call's rows go, then q [B, T, H,
+    latent_dim] runs against that layer (:func:`_attend`: all heads on the
+    one row) and the weighted rows' first ``value_dim`` lanes [B, T, H,
+    value_dim] go through the output projection. Returns (x + mixer, kv)."""
+    blk = block_of(g.cfg)
+    page_idx, offset = _keep(g.page_idx, g.lone), _keep(g.offset, g.lone)
+    q, entry = blk.serve_latent(x, layer, g.cos, g.sin, g.cfg)
     with jax.named_scope("kv_latent"):
         pool = _write_token_rows(kv["k"], l, entry, page_idx, offset)
     with jax.named_scope("attn"):
-        o = attend(q, pool, l)
+        o = _attend(q, pool, None, l, g, g.value_dim)
     return x + blk.serve_latent_out(o, layer), {**kv, "k": pool}
 
 
@@ -690,48 +696,32 @@ def _gather_seq(pool, layer, page_tables, head_dim: int | None = None):
                                   hkv * d // head_dim, head_dim))
 
 
-def _scan_layers(body, x, kv, params):
-    """Run ``body(x, k_pool, v_pool, layer_params, l)`` -> (x, k_pool,
-    v_pool) over the layers with the pool as a CARRY: the only ``xs`` are
-    the layer parameters and the layer index, and there are no ``ys``.
-    Returns (x, new_kv)."""
-    n_layers = kv["k"].shape[0]
-
-    def step(carry, inputs):
-        return body(*carry, *inputs), None
-
-    (x, k_pool, v_pool), _ = jax.lax.scan(
-        step, (x, kv["k"], kv["v"]),
-        (params["layers"], jnp.arange(n_layers, dtype=jnp.int32)))
-    return x, {"k": k_pool, "v": v_pool}
-
-
-def _over_layers(step, x, kv, params, cfg):
-    """Run ``step(x, kv, layer_params, ld, l)`` -> (x, kv) over the
-    block's layers, the cache a carry that is only ever updated in place.
+def _over_layers(step, x, kv, params, cfg, *operands):
+    """Run ``step(x, kv, layer_params, ld, l, *operands)`` -> (x, kv) over
+    the block's layers, the cache a carry that is only ever updated in place.
     A block whose layers are all alike (``serve_layers`` None: stacked
-    parameters, ``ld`` None, ``l`` traced) is scanned; one whose layers
-    differ in kind is walked in order (``ld`` its :class:`LayerDef`, ``l``
-    its row of the pool), each layer's weights read where they lie."""
+    parameters, ``ld`` None, ``l`` traced) is scanned with the pool as a
+    CARRY: the only ``xs`` are the layer parameters and the layer index,
+    and there are no ``ys``. One whose layers differ in kind is walked in
+    order (``ld`` its :class:`LayerDef`, ``l`` its row of the pool), each
+    layer's weights read where they lie."""
     layers = block_of(cfg).serve_layers(cfg)
     if layers is None:
-        def body(x, k_pool, v_pool, layer, l):
-            x, out = step(x, {"k": k_pool, "v": v_pool}, layer, None, l)
-            return x, out["k"], out["v"]
-        return _scan_layers(body, x, kv, params)
+        def body(carry, inputs):
+            x, k_pool, v_pool = carry
+            layer, l = inputs
+            x, out = step(x, {"k": k_pool, "v": v_pool}, layer, None, l,
+                          *operands)
+            return (x, out["k"], out["v"]), None
+
+        (x, k_pool, v_pool), _ = jax.lax.scan(
+            body, (x, kv["k"], kv["v"]),
+            (params["layers"],
+             jnp.arange(kv["k"].shape[0], dtype=jnp.int32)))
+        return x, {"k": k_pool, "v": v_pool}
     for ld, layer in zip(layers, params["layers"]):
-        x, kv = step(x, kv, layer, ld, ld.page_layer)
+        x, kv = step(x, kv, layer, ld, ld.page_layer, *operands)
     return x, kv
-
-
-def _keeps_state(ld) -> bool:
-    """A mixer with slot state (models/block.py ``serve_conv``)."""
-    return ld is not None and ld.mixer == "conv"
-
-
-def _latent(ld) -> bool:
-    """A mixer that keeps one latent row a token (``serve_latent``)."""
-    return ld is not None and ld.mixer == "latent"
 
 
 def _ffn(x, kv, layer, cfg, ld):
@@ -866,58 +856,163 @@ def _dense_attention(q, k, v, mask, sm):
     return jnp.einsum("bhqk,bkhd->bqhd", p, v_full)
 
 
-def _paged_kernel(kernel, q, k_pool, v_pool, *rest, sm_scale, mesh=None):
-    """Call one of ops/paged_attention.py's wrappers on the whole pool:
-    ``kernel(q, k_pool, v_pool, *rest)`` where ``rest`` is the wrapper's
-    replicated operands (page tables, positions/lengths, the layer index
-    last).
+@dataclasses.dataclass(frozen=True)
+class _Geometry:
+    """What ONE call of a paged program fixes for every layer it runs:
+    where the call's rows go and how it reads the cache back. A program
+    builds it once a trace; the mixers read it and nothing of the program.
 
-    On a TP mesh the call runs under ``shard_map`` (GSPMD cannot partition
-    an opaque pallas_call): q's H axis splits into whole kv-head groups
-    (kv-major GQA order) and the pools per KV head, so each shard's kernel
-    sees a self-contained (Hkv/tp heads, n_rep q-heads each) problem — no
-    collective. check_vma=False: the kernel writes nothing replicated, and
-    rep inference can't see through pallas anyway."""
-    from ray_tpu.ops import paged_attention as paged_ops
+    ``page_idx`` / ``offset`` are in the form of the call's token grid: [B,
+    T], or without the axis the call has ONE of. ``lone`` names that axis:
+    1, one token a slot (decode: [B]; its per-head q, k, v and read have no
+    token axis either); 0, one slot (a chunk: [T]; its tables and mask have
+    no slot axis); None, a span. ``state``: () -> (rows, fresh, n_real),
+    :func:`_conv_mixer`'s operands, made at each layer that keeps state
+    (not once a trace: the recorded programs hold them a layer).
 
-    call = functools.partial(kernel, sm_scale=sm_scale)
-    if tp_degree(mesh) > 1:
-        in_specs, out_spec = paged_ops.tp_shard_specs(
-            q_rank=q.ndim, n_replicated=len(rest))
-        call = jax.shard_map(call, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_spec, check_vma=False)
-    return call(q, k_pool, v_pool, *rest)
+    The read, on the pallas backend: ``kernel``, the name of a wrapper of
+    ops/paged_attention.py, its ``static`` keywords, and ``operands``, its
+    replicated operands between the pools and the layer index ((tables,
+    pos), (tables, seq_lens) or (table, start, kept)). On the gather
+    backend: ``operands[0]``, the page tables, and ``valid``, the mask in
+    the grid's form with the keys last (decode has none: its read builds
+    the causal mask from ``operands[1]``). ``cfg``, ``value_dim`` (of its
+    cache spec), ``attn_backend`` and ``mesh`` are the engine's, the same
+    for every call. A call that reads nothing back (a whole prefill) has no
+    use for a geometry."""
+    cfg: object = None
+    cos: object = None
+    sin: object = None
+    page_idx: object = None
+    offset: object = None
+    lone: int | None = None
+    state: object = None
+    attn_backend: str = "gather"
+    kernel: str = ""
+    static: dict = dataclasses.field(default_factory=dict)
+    operands: tuple = ()
+    valid: object = None
+    value_dim: int = 0
+    mesh: object = None
 
 
-def _decode_attention(q, k_pool, v_pool, layer, page_tables, pos, cfg,
-                      page_size, attn_backend: str = "gather", mesh=None):
-    """Single-token attention over the paged KV for all slots.
+def _drop(a, lone):
+    """a [B, T, ...] without the axis its call has one of."""
+    return a if lone is None else a[(slice(None),) * lone + (0,)]
 
-    q: [B, H, D]; k_pool/v_pool: the whole pool [L, Hkv, P, page, D], read
-    at layer ``layer``; pos: [B] (the new token's position — attend over
-    0..pos inclusive). The pallas backend runs the fused paged kernel
-    (ops/paged_attention.py — reads each sequence's pages through the page
-    table, same dense-softmax numerics as the gather path); the gather
-    backend materializes the full [B, max_len] view."""
-    max_len = page_tables.shape[1] * page_size
-    sm = cfg.head_dim ** -0.5
-    if attn_backend == "pallas":
+
+def _keep(a, lone):
+    """:func:`_drop` undone: the axis back, of length 1."""
+    return a if lone is None else a[(slice(None),) * lone + (None,)]
+
+
+def _attend(q, k_pool, v_pool, l, g, value_lanes: int = 0):
+    """Layer ``l`` of the pool read back for q [B, T, H, D]: THE backend
+    switch (the module docstring's first design choice, and on a TP mesh
+    its last). ``v_pool`` None and ``value_lanes``: a latent pool, all
+    heads on the one row, the values its first ``value_lanes`` lanes.
+    Returns [B, T, H, Dv]; on per-head pools a call of one token a slot
+    (``g.lone`` 1) returns [B, H, D], the form its read works in: on the
+    gather backend three-axis einsums over the full [B, max_len] view (the
+    reference tests/test_paged_kernels.py holds the kernel to; one slot's
+    view, a chunk's, is small, a batch's is why the kernels exist)."""
+    head_dim = g.cfg.head_dim
+    sm = head_dim ** -0.5
+    one = g.lone == 1
+    if g.attn_backend == "pallas":
         from ray_tpu.ops import paged_attention as paged_ops
-        return _paged_kernel(
-            paged_ops.paged_decode_attention, q, k_pool, v_pool,
-            page_tables, pos, layer, sm_scale=sm, mesh=mesh)
-    # query heads a KV head (the pool's rows may hold two heads of 64)
-    n_rep = q.shape[1] * cfg.head_dim // (k_pool.shape[1] * k_pool.shape[4])
-    k_full = gqa_expand(
-        _gather_seq(k_pool, layer, page_tables, cfg.head_dim), n_rep)
-    v_full = gqa_expand(
-        _gather_seq(v_pool, layer, page_tables, cfg.head_dim), n_rep)
-    valid = jnp.arange(max_len)[None, :] <= pos[:, None]          # [B, L]
-    logits = jnp.einsum("bhd,bkhd->bhk", q, k_full).astype(
-        jnp.float32) * sm
-    logits = jnp.where(valid[:, None, :], logits, -1e30)
-    p = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
-    return jnp.einsum("bhk,bkhd->bhd", p, v_full)
+        static = {**g.static, "value_lanes": value_lanes} if value_lanes \
+            else g.static
+        call = functools.partial(getattr(paged_ops, g.kernel), sm_scale=sm,
+                                 **static)
+        q = q[:, 0] if one else q
+        if tp_degree(g.mesh) > 1:
+            # check_vma=False: the kernel writes nothing replicated, and
+            # rep inference can't see through pallas anyway
+            in_specs, out_spec = paged_ops.tp_shard_specs(
+                q_rank=q.ndim, n_replicated=len(g.operands) + 1)
+            call = jax.shard_map(call, mesh=g.mesh, in_specs=in_specs,
+                                 out_specs=out_spec, check_vma=False)
+        out = call(q, k_pool, v_pool, *g.operands, l)
+        return out[:, None] if one and value_lanes else out
+    tables = g.operands[0]
+    if one:
+        def causal():       # [B, L], made where the recorded programs have it
+            return jnp.arange(tables.shape[1] * k_pool.shape[3])[None, :] \
+                <= g.operands[1][:, None]
+
+        if value_lanes:
+            return _latent_gather_attention(
+                q, k_pool, l, tables, causal()[:, None, None], sm,
+                value_lanes)
+        q = q[:, 0]                                               # [B,H,D]
+        # query heads a KV head (the pool's rows may hold two heads of 64)
+        n_rep = q.shape[1] * head_dim // (k_pool.shape[1] * k_pool.shape[4])
+        k_full = gqa_expand(_gather_seq(k_pool, l, tables, head_dim), n_rep)
+        v_full = gqa_expand(_gather_seq(v_pool, l, tables, head_dim), n_rep)
+        valid = causal()
+        logits = jnp.einsum("bhd,bkhd->bhk", q, k_full).astype(
+            jnp.float32) * sm
+        logits = jnp.where(valid[:, None, :], logits, -1e30)
+        p = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
+        return jnp.einsum("bhk,bkhd->bhd", p, v_full)
+    heads = (None, None) if g.lone == 0 else (slice(None), None)
+    if value_lanes:
+        return _latent_gather_attention(
+            q, k_pool, l, _keep(tables, g.lone), g.valid[heads], sm,
+            value_lanes)
+    return _dense_attention(
+        q, _keep(_gather_seq(k_pool, l, tables, head_dim), g.lone),
+        _keep(_gather_seq(v_pool, l, tables, head_dim), g.lone),
+        g.valid[heads], sm)
+
+
+def _attn_mixer(x, kv, layer, ld, l, g):
+    """A mixer that keeps K and V a head (``serve_qkv``): the call's rows
+    written to layer ``l`` of the pool first, then read back with all that
+    is cached (write-then-read: a call sees earlier calls AND itself), then
+    the output projection. Returns (x + mixer, kv)."""
+    blk = block_of(g.cfg)
+    q, k, v = blk.serve_qkv(x, layer, g.cos, g.sin, g.cfg)
+    with jax.named_scope("kv_write"):
+        k_pool, v_pool = _write_token_kv(
+            kv["k"], kv["v"], l, _drop(k, g.lone), _drop(v, g.lone),
+            g.page_idx, g.offset)
+    with jax.named_scope("attn"):
+        out = blk.serve_attn_out(_attend(q, k_pool, v_pool, l, g), layer)
+        x = x + (out if out.ndim == x.ndim else out[:, None])
+    return x, {**kv, "k": k_pool, "v": v_pool}
+
+
+# A MIXER KIND (models/block.py ``LayerDef.mixer``) is one entry here:
+# ``mixer(x, kv, layer, ld, l, g) -> (x + mixer, kv)``, the cache updated
+# in place, the call's :class:`_Geometry` all it knows of the program that
+# runs it.
+_MIXERS = {
+    "attn": _attn_mixer,
+    "latent": _latent_mixer,
+    "conv": lambda x, kv, layer, ld, l, g: _conv_mixer(
+        x, kv, layer, g.cfg, ld, *g.state()),
+}
+
+
+def _mixer_kind(ld) -> str:
+    """The layer's mixer kind; the layers of a scanned block attend."""
+    return "attn" if ld is None else ld.mixer
+
+
+def _layer(x, kv, layer, ld, l, g):
+    """THE layer of every program that reads the cache back: its mixer,
+    then its feed-forward. Returns (x, kv)."""
+    x, kv = _MIXERS[_mixer_kind(ld)](x, kv, layer, ld, l, g)
+    return _ffn(x, kv, layer, g.cfg, ld)
+
+
+def _geometry(cfg, attn_backend, mesh, **call) -> _Geometry:
+    """A call's :class:`_Geometry`, what is the same for every call
+    filled in."""
+    return _Geometry(cfg=cfg, attn_backend=attn_backend, mesh=mesh,
+                     value_dim=block_of(cfg).cache_spec(cfg).value_dim, **call)
 
 
 def paged_decode_step(params, kv, page_tables, seq_lens, tokens,
@@ -941,41 +1036,11 @@ def paged_decode_step(params, kv, page_tables, seq_lens, tokens,
         page_tables, (pos // page_size)[:, None], axis=1)[:, 0]  # [B]
     offset = pos % page_size
 
-    sm = cfg.head_dim ** -0.5
-    value_dim = blk.cache_spec(cfg).value_dim
-
-    def latent_attend(q, pool, l):
-        if attn_backend == "pallas":
-            from ray_tpu.ops import paged_attention as paged_ops
-            return paged_ops.paged_decode_attention(
-                q[:, 0], pool, None, page_tables, pos, l, sm_scale=sm,
-                value_lanes=value_dim)[:, None]
-        valid = jnp.arange(page_tables.shape[1] * page_size)[None, :] \
-            <= pos[:, None]                                       # [B, L]
-        return _latent_gather_attention(
-            q, pool, l, page_tables, valid[:, None, None], sm, value_dim)
-
-    def step(x, kv, layer, ld, l):
-        if _latent(ld):
-            x, kv = _latent_mixer(
-                x, kv, layer, l, cos, sin, cfg, page_idx[:, None],
-                offset[:, None], latent_attend)
-            return _ffn(x, kv, layer, cfg, ld)
-        if _keeps_state(ld):
-            x, kv = _conv_mixer(x, kv, layer, cfg, ld, page_tables[:, 0])
-            return _ffn(x, kv, layer, cfg, ld)
-        q, k, v = blk.serve_qkv(x, layer, cos, sin, cfg)
-        with jax.named_scope("kv_write"):
-            k_pool, v_pool = _write_token_kv(
-                kv["k"], kv["v"], l, k[:, 0], v[:, 0], page_idx, offset)
-        with jax.named_scope("attn"):
-            attn = _decode_attention(
-                q[:, 0], k_pool, v_pool, l, page_tables, pos, cfg,
-                page_size, attn_backend, mesh)                    # [B,H,D]
-            x = x + blk.serve_attn_out(attn, layer)[:, None]
-        return _ffn(x, {**kv, "k": k_pool, "v": v_pool}, layer, cfg, ld)
-
-    x, kv = _over_layers(step, x, kv, params, cfg)
+    g = _geometry(cfg, attn_backend, mesh, cos=cos, sin=sin,
+                  page_idx=page_idx, offset=offset, lone=1,
+                  state=lambda: (page_tables[:, 0], None, None),
+                  kernel="paged_decode_attention", operands=(page_tables, pos))
+    x, kv = _over_layers(_layer, x, kv, params, cfg, g)
     x = blk.serve_final_norm(x, params, cfg)
     return blk.serve_lm_head(x[:, 0], params, cfg), kv, seq_lens + 1
 
@@ -1135,48 +1200,13 @@ def _span_step(params, kv, page_tables, seq_lens, tokens, cfg, page_size,
     # position t sees cache + the span's tokens 0..t (its own write), or
     # to the end of its block
     valid = _visible(kpos[None, None, :], pos[:, :, None], block_len)
-    sm = cfg.head_dim ** -0.5
-    value_dim = blk.cache_spec(cfg).value_dim
-
-    def latent_attend(q, pool, l):
-        if attn_backend == "pallas":
-            from ray_tpu.ops import paged_attention as paged_ops
-            return paged_ops.paged_verify_attention(
-                q, pool, None, page_tables, seq_lens, l, sm_scale=sm,
-                value_lanes=value_dim)
-        return _latent_gather_attention(q, pool, l, page_tables,
-                                        valid[:, None], sm, value_dim)
-
-    def step(x, kv, layer, ld, l):
-        if _latent(ld):
-            x, kv = _latent_mixer(
-                x, kv, layer, l, cos, sin, cfg, page_idx, offset,
-                latent_attend)
-            return _ffn(x, kv, layer, cfg, ld)
-        q, k, v = blk.serve_qkv(x, layer, cos, sin, cfg)
-        with jax.named_scope("kv_write"):
-            # write all T tokens' k/v, then attend through the paged view —
-            # same write-then-gather shape as paged_prefill_chunk, batched.
-            k_pool, v_pool = _write_token_kv(
-                kv["k"], kv["v"], l, k, v, page_idx, offset)
-        with jax.named_scope("attn"):
-            if attn_backend == "pallas":
-                from ray_tpu.ops import paged_attention as paged_ops
-                kernel = paged_ops.paged_verify_attention if block_len == 1 \
-                    else functools.partial(paged_ops.paged_block_attention,
-                                           block_len=block_len)
-                attn = _paged_kernel(
-                    kernel, q, k_pool, v_pool,
-                    page_tables, seq_lens, l, sm_scale=sm, mesh=mesh)
-            else:
-                attn = _dense_attention(
-                    q, _gather_seq(k_pool, l, page_tables, cfg.head_dim),
-                    _gather_seq(v_pool, l, page_tables, cfg.head_dim),
-                    valid[:, None], sm)
-            x = x + blk.serve_attn_out(attn, layer)
-        return _ffn(x, {**kv, "k": k_pool, "v": v_pool}, layer, cfg, ld)
-
-    return _over_layers(step, x, kv, params, cfg)
+    g = _geometry(
+        cfg, attn_backend, mesh, cos=cos, sin=sin, page_idx=page_idx,
+        offset=offset, operands=(page_tables, seq_lens), valid=valid,
+        kernel="paged_verify_attention" if block_len == 1
+        else "paged_block_attention",
+        static={} if block_len == 1 else {"block_len": block_len})
+    return _over_layers(_layer, x, kv, params, cfg, g)
 
 
 @jax.named_scope("prefill")
@@ -1187,12 +1217,15 @@ def paged_prefill(params, kv, page_table, tokens, true_len,
     tokens: [1, T] (bucket-padded); page_table: [max_pages] for this slot;
     true_len: scalar actual prompt length. Returns (last-token logits
     [vocab], new_kv). Padding positions (>= true_len) write to the trash
-    page via index clamping, so junk never lands in real pages. A layer
-    with slot state starts from zeros and leaves the state as of position
-    ``true_len - 1`` in the sequence's row. A block that generates by
-    diffusion over blocks attends under the block mask and keeps the K / V
-    of the prompt's whole blocks only (:func:`_committed`); its logits mean
-    nothing (models/block.py).
+    page via index clamping, so junk never lands in real pages. A block
+    that generates by diffusion over blocks attends under the block mask
+    and keeps the K / V of the prompt's whole blocks only
+    (:func:`_committed`); its logits mean nothing (models/block.py).
+
+    It reads nothing back, so it keeps a layer of its own and not
+    :func:`_layer`'s: the prompt's own rows are attended (a latent mixer in
+    its EXPANDED form) and written AFTER the feed-forward, for which a
+    layer that is "the mixer, then the feed-forward" has no place.
     """
     blk = block_of(cfg)
     b = _block_len(cfg)
@@ -1211,12 +1244,13 @@ def paged_prefill(params, kv, page_table, tokens, true_len,
     sm = cfg.head_dim ** -0.5
 
     def step(x, kv, layer, ld, l):
-        if _keeps_state(ld):
+        kind = _mixer_kind(ld)
+        if kind == "conv":
             x, kv = _conv_mixer(
                 x, kv, layer, cfg, ld, page_table[:1], fresh=True,
                 n_real=jnp.reshape(true_len, (1,)).astype(jnp.int32))
             return _ffn(x, kv, layer, cfg, ld)
-        if _latent(ld):
+        if kind == "latent":
             # the mixer's EXPANDED form: keys and values per head from the
             # prompt's own rows, nothing read back; the rows go to the pool
             q, k, v, entry = blk.serve_latent_expanded(x, layer, cos, sin,
@@ -1266,9 +1300,7 @@ def paged_prefill_chunk(params, kv, page_table, tokens, start, true_len,
     back through the page pool) plus causally within the chunk. Under the
     pallas backend the cached prefix is read page-by-page inside the fused
     chunk kernel instead of gathering the full paged view every chunk —
-    the long-prompt suffix-prefill-after-tier-restore hot path. A layer
-    with slot state reads the state its predecessor chunk left (zeros
-    where ``start`` is 0) and leaves its own. Returns
+    the long-prompt suffix-prefill-after-tier-restore hot path. Returns
     (last-token logits [vocab] — meaningful only on the final chunk, new_kv).
     Under a block mask as :func:`paged_prefill`: ``start`` is a block edge,
     and only the prompt's whole blocks are kept and attended to.
@@ -1290,55 +1322,13 @@ def paged_prefill_chunk(params, kv, page_table, tokens, start, true_len,
     kpos = jnp.arange(max_len)                                    # [L]
     valid = _visible(kpos[None, :], pos[:, None], b) \
         & (kpos[None, :] < kept)
-    sm = cfg.head_dim ** -0.5
-    value_dim = blk.cache_spec(cfg).value_dim
-
-    def latent_attend(q, pool, l):
-        if attn_backend == "pallas":
-            from ray_tpu.ops import paged_attention as paged_ops
-            return paged_ops.paged_chunk_attention(
-                q, pool, None, page_table, start, kept, l, sm_scale=sm,
-                value_lanes=value_dim)
-        return _latent_gather_attention(q, pool, l, page_table[None],
-                                        valid[None, None], sm, value_dim)
-
-    def step(x, kv, layer, ld, l):
-        if _keeps_state(ld):
-            x, kv = _conv_mixer(
-                x, kv, layer, cfg, ld, page_table[:1], fresh=start == 0,
-                n_real=jnp.reshape(jnp.clip(true_len - start, 0, c),
-                                   (1,)).astype(jnp.int32))
-            return _ffn(x, kv, layer, cfg, ld)
-        if _latent(ld):
-            x, kv = _latent_mixer(
-                x, kv, layer, l, cos, sin, cfg, page_idx[None], offset[None],
-                latent_attend)
-            return _ffn(x, kv, layer, cfg, ld)
-        q, k, v = blk.serve_qkv(x, layer, cos, sin, cfg)
-        with jax.named_scope("kv_write"):
-            # write the chunk's k/v first, then attend through the paged view —
-            # the same write-then-gather shape as the decode fallback, so the
-            # chunk sees earlier chunks AND itself causally. B=1 here, so the
-            # gathered view is small (unlike batched decode, where the
-            # materialized gather is why the Pallas kernel exists).
-            k_pool, v_pool = _write_token_kv(
-                kv["k"], kv["v"], l, k[0], v[0], page_idx, offset)
-        with jax.named_scope("attn"):
-            if attn_backend == "pallas":
-                from ray_tpu.ops import paged_attention as paged_ops
-                attn = _paged_kernel(
-                    functools.partial(paged_ops.paged_chunk_attention,
-                                      block_len=b), q, k_pool, v_pool,
-                    page_table, start, kept, l, sm_scale=sm, mesh=mesh)
-            else:
-                attn = _dense_attention(
-                    q, _gather_seq(k_pool, l, page_table, cfg.head_dim)[None],
-                    _gather_seq(v_pool, l, page_table, cfg.head_dim)[None],
-                    valid[None, None], sm)
-            x = x + blk.serve_attn_out(attn, layer)
-        return _ffn(x, {**kv, "k": k_pool, "v": v_pool}, layer, cfg, ld)
-
-    x, kv = _over_layers(step, x, kv, params, cfg)
+    g = _geometry(
+        cfg, attn_backend, mesh, cos=cos, sin=sin, page_idx=page_idx,
+        offset=offset, lone=0, operands=(page_table, start, kept),
+        valid=valid, kernel="paged_chunk_attention", static={"block_len": b},
+        state=lambda: (page_table[:1], start == 0, jnp.reshape(
+            jnp.clip(true_len - start, 0, c), (1,)).astype(jnp.int32)))
+    x, kv = _over_layers(_layer, x, kv, params, cfg, g)
     x = blk.serve_final_norm(x, params, cfg)
     # last REAL token's position relative to this chunk's start
     rel = jnp.clip(true_len - 1 - start, 0, c - 1)

@@ -17,7 +17,6 @@ import pytest
 from benchmark import checks, common
 from ray_tpu.models import joyai
 from ray_tpu.models.block import block_of
-from ray_tpu.ops import paged_attention as paged_ops
 from ray_tpu.serve.llm import LLMConfig, LLMEngine, disagg
 from ray_tpu.serve.llm import kv_cache as kvc
 
@@ -79,21 +78,12 @@ def test_absorbed_off_the_cache_equals_expanded_full_forward(params, backend):
     pool = kvc._write_token_rows(kv["k"], 0, entry[:, :n - 1],
                                  table[pos // page][None], (pos % page)[None])
     last = jnp.asarray([n - 1])
-    sm = CFG.head_dim ** -0.5
-
-    def attend(q, pool, _l):
-        if backend == "pallas":
-            return paged_ops.paged_decode_attention(
-                q[:, 0], pool, None, table[None], last, 0, sm_scale=sm,
-                value_lanes=CFG.kv_rank)[:, None]
-        valid = jnp.arange(4 * page) <= n - 1
-        return kvc._latent_gather_attention(
-            q, pool, 0, table[None], valid[None, None, None], sm,
-            CFG.kv_rank)
-
     got, new = kvc._latent_mixer(
-        x[:, n - 1:], {"k": pool}, layer, 0, cos[:, n - 1:], sin[:, n - 1:],
-        CFG, table[last // page][None], (last % page)[None], attend)
+        x[:, n - 1:], {"k": pool}, layer, None, 0, kvc._Geometry(
+            cfg=CFG, cos=cos[:, n - 1:], sin=sin[:, n - 1:],
+            page_idx=table[last // page], offset=last % page, lone=1,
+            attn_backend=backend, kernel="paged_decode_attention",
+            operands=(table[None], last), value_dim=CFG.kv_rank))
     np.testing.assert_allclose(got[0, 0] - x[0, n - 1], want, atol=2e-6)
     # the row the decode wrote is the expanded form's own entry, zero padded
     row = new["k"][0, 0, table[(n - 1) // page], (n - 1) % page]

@@ -22,6 +22,8 @@ Pins the PR's acceptance invariants:
   ``_ENGINE_KEYS``.
 """
 
+import types
+
 import numpy as np
 import pytest
 
@@ -46,7 +48,7 @@ def _rand_pool(key, hkv, pool_pages, page, d, dtype):
 
 def _ref_attention(q, k_pages, v_pages, page_tables, base, limit, sm,
                    block_len=1):
-    """The gather path's exact op sequence (see kv_cache._decode_attention
+    """The gather path's exact op sequence (see kv_cache._attend
     / paged_verify_step), generalized to the kernel's unified semantics:
     row t of slot b attends keys ``col <= base[b] + t`` (with positions cut
     into blocks of ``block_len``: up to the end of its own block) and
@@ -115,9 +117,7 @@ def test_kernel_matches_gather_across_width_and_span(b, t, dtype):
 
 def test_decode_wrapper_matches_decode_attention_integration():
     """The integration point the engine actually calls: gather vs pallas
-    through kv_cache._decode_attention must agree."""
-    import types
-
+    through kv_cache._attend, under decode's geometry, must agree."""
     hkv, h, d, page, mp, b = 2, 4, 16, 8, 4, 4
     key = jax.random.PRNGKey(0)
     k_pages, v_pages = _rand_pool(key, hkv, mp * b + 1, page, d,
@@ -126,16 +126,17 @@ def test_decode_wrapper_matches_decode_attention_integration():
     page_tables = jnp.arange(1, mp * b + 1).reshape(b, mp).astype(
         jnp.int32)
     pos = jnp.asarray([0, 7, 13, 30], jnp.int32)
-    cfg = types.SimpleNamespace(head_dim=d)
     # the integration point takes the whole layer-indexed pool: the layer
     # under test sits between two layers of other values
     k_pool = jnp.stack([k_pages + 1.0, k_pages, k_pages - 1.0])
     v_pool = jnp.stack([v_pages - 1.0, v_pages, v_pages + 1.0])
     layer = jnp.int32(1)
-    gather = kv_cache._decode_attention(q, k_pool, v_pool, layer,
-                                        page_tables, pos, cfg, page, "gather")
-    pallas = kv_cache._decode_attention(q, k_pool, v_pool, layer,
-                                        page_tables, pos, cfg, page, "pallas")
+    gather, pallas = (kv_cache._attend(
+        q[:, None], k_pool, v_pool, layer, kv_cache._Geometry(
+            lone=1, attn_backend=backend, kernel="paged_decode_attention",
+            operands=(page_tables, pos),
+            cfg=types.SimpleNamespace(head_dim=d)))
+        for backend in ("gather", "pallas"))
     _assert_matches(pallas, gather)
     _assert_matches(gather, _ref_attention(
         q[:, None], k_pages, v_pages, page_tables, pos,
@@ -364,8 +365,6 @@ def test_walking_body_on_per_kv_head_shards_of_a_tensor_mesh():
     runs every kernel of the family): each shard's call is a single-chip
     call over its own KV heads, which the body reads off its operands'
     shapes."""
-    import functools
-
     from jax.sharding import Mesh
 
     hkv, n_rep, d, page, mp, b, t = 4, 2, 16, 8, 6, 3, 4
@@ -376,10 +375,11 @@ def test_walking_body_on_per_kv_head_shards_of_a_tensor_mesh():
     page_tables = jnp.arange(1, mp * b + 1).reshape(b, mp).astype(jnp.int32)
     base = jnp.asarray([0, 20, 44], jnp.int32)
     mesh = Mesh(np.asarray(jax.devices()[:2]), ("tensor",))
-    got = kv_cache._paged_kernel(
-        functools.partial(paged_ops.paged_block_attention, block_len=4),
-        q, k_pages[None], v_pages[None], page_tables, base, jnp.int32(0),
-        sm_scale=d ** -0.5, mesh=mesh)
+    got = kv_cache._attend(
+        q, k_pages[None], v_pages[None], jnp.int32(0), kv_cache._Geometry(
+            attn_backend="pallas", kernel="paged_block_attention",
+            static={"block_len": 4}, operands=(page_tables, base),
+            cfg=types.SimpleNamespace(head_dim=d), mesh=mesh))
     want = _ref_attention(q, k_pages, v_pages, page_tables, base,
                           jnp.full((b,), mp * page, jnp.int32), d ** -0.5, 4)
     _assert_matches(got, want)
@@ -505,8 +505,6 @@ def test_resolve_on_tpu_shape_gate(monkeypatch):
     """On TPU, auto picks pallas only when the kernel tiling fits; an
     explicit pallas on shapes the kernel cannot tile raises — it is never
     served by gather under the kernel's name."""
-    import types
-
     monkeypatch.setattr(kv_cache.jax, "default_backend", lambda: "tpu")
     good = types.SimpleNamespace(head_dim=128)
     tiny = types.SimpleNamespace(head_dim=16)
