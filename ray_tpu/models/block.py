@@ -16,6 +16,13 @@ that can be served provides, at module level:
     serve_embed(params, tokens, cfg) -> x
     serve_qkv(x, layer, cos, sin, cfg) -> q, k, v        (attention mixer)
     serve_attn_out(attn, layer) -> the mixer's output, before the residual
+    serve_gated_qkv(x, layer, cos, sin, cfg, ld) -> q, k, v, gate
+        (gated mixer: attention whose output is multiplied lane by lane by
+        ``gate`` [B, T, H, D] before the output projection; it gets the
+        layer's definition, so a layer may rotate or not by its window)
+    serve_gated_out(attn, gate, layer, cfg) -> the mixer's output, before
+        the residual (``attn`` and ``gate`` with or without the token axis,
+        alike)
     serve_latent(x, layer, cos, sin, cfg) -> q, entry    (latent mixer: its
         cache spec states ``latent_dim``) ``entry`` [B, T, latent_dim] is
         the ONE row a token leaves in the cache, and ``q`` [B, T, H,
@@ -48,8 +55,9 @@ layer body (``kv_cache._layer``: the mixer, then ``serve_ffn``); a program
 states only its geometry (where this call's rows go, how it reads the cache
 back) and no mixer names a program. "attn" (``serve_qkv``,
 ``serve_attn_out``) writes K and V a head, then reads the layer back;
-"latent" (``serve_latent``, ``serve_latent_out``) writes the one row and
-reads it back in the absorbed form; "conv" (``serve_conv``) keeps its state
+"gated" (``serve_gated_qkv``, ``serve_gated_out``) is "attn" with a gate on
+what it read; "latent" (``serve_latent``, ``serve_latent_out``) writes the
+one row and reads it back in the absorbed form; "conv" (``serve_conv``) keeps its state
 in the row of the sequence's first page: a call that starts a sequence
 reads zeros instead, and the row keeps the state as of the call's last real
 column. A whole prefill reads nothing back (the prompt's own rows, "latent"
@@ -58,6 +66,21 @@ keeps a layer of its own. A new kind provides its ``serve_*`` functions
 here, one function and one entry there, what it keeps in ``CacheSpec``
 and, only if it reads the cache in a new way, a wrapper in ops/
 paged_attention.py; it edits no program.
+
+A WINDOW LAYER (``LayerDef.window`` W above 0, of a mixer that keeps K and V
+a head; the cache spec states ``window`` and how many ``window_layers``):
+query i sees key j iff ``0 <= i - j < W``. Such a layer's pages are a RING:
+the manager keeps ``kv_cache.ring_pages`` pages a slot for it whatever the
+context (the window, the widest call's span and a page), position p lies in
+entry ``(p // page) % ring`` of the slot's ring table, and a page whose last
+token has left every future query's window is written again. Window layers
+have a pool and a table of their own (``kw`` / ``vw``, the tail of the page
+table); full layers keep the growing table. Every paged read of a block
+that has window layers runs the walking body with a lower edge (ops/
+paged_attention.py), a full layer's edge 0. Prefix reuse, the kv tier,
+speculation and disaggregated hand-off all assume that a page, once
+written, holds its tokens for the sequence's life: the engine does none of
+them for such a block (and counts).
 
 ``cfg.head_dim``, ``cfg.dtype`` and ``cfg.max_seq_len`` are read off the
 configuration itself (``head_dim``: the width of a query and key head, so
@@ -99,7 +122,13 @@ class CacheSpec:
     ``state_shape`` a SEQUENCE (not a token): state that survives between
     decode steps, is carried from one prefill chunk to the next and cannot
     be rebuilt from the pages. ``routed_layers`` layers choose ``top_k`` of
-    ``n_experts`` experts a token, and the programs record the choice.
+    ``n_experts`` experts a token, and the programs record the choice
+    (``n_experts``: the experts whose rows THIS replica multiplies, the
+    first of the router's where it holds a share: what the engine's counts
+    of experts touched run over).
+    ``window_layers`` more layers write K and V of the same heads into a
+    ring of pages a slot and see the last ``window`` tokens only (the
+    module docstring's "a window layer").
     ``block_length``: 1 = a step yields a token a sequence; B above 1 = the
     block generates by diffusion over blocks of B positions, of which a
     not yet revealed one holds ``mask_token`` (never produced)."""
@@ -115,20 +144,25 @@ class CacheSpec:
     mask_token: int = -1
     latent_dim: int = 0
     value_dim: int = 0
+    window: int = 0
+    window_layers: int = 0
 
 
 @dataclasses.dataclass(frozen=True)
 class LayerDef:
     """One layer of a block whose layers differ: its mixer ("attn" |
-    "conv" | "latent": a key of kv_cache.py's ``_MIXERS``, the module
-    docstring's "a mixer kind"), its feed-forward kind ("dense" |
-    "routed"), and which row of the pool, of the slot state and of the
-    routing record is its own."""
+    "gated" | "conv" | "latent": a key of kv_cache.py's ``_MIXERS``, the
+    module docstring's "a mixer kind"), its feed-forward kind ("dense" |
+    "routed"), which row of the pool, of the slot state and of the
+    routing record is its own, and its window (0: a full layer, whose row
+    is the growing pool's; above 0: a window layer, whose row is the ring
+    pool's)."""
     mixer: str
     ffn: str
     page_layer: int = -1
     state_layer: int = -1
     routed_layer: int = -1
+    window: int = 0
 
 
 def gqa_expand(k, n_rep):

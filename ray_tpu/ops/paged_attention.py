@@ -39,12 +39,19 @@ option or a rule on shapes:
   they lie (one strided copy a page and pool carries every KV head), the
   next slot's under this slot's products, and scores, sums and weighted
   values over the live chunks of columns only, every KV head of the call
-  at once. Called by ``paged_block_attention`` ALONE for now: the block
-  program is the one caller whose gain the benchmark can judge today
-  (ROADMAP S3; PERF.md section 6, PR 48). It takes every call shape of
-  the family (tests/test_paged_kernels.py drives them), so the other
-  three wrappers move over by an entry in the table, after which the
-  grid body goes (ROADMAP D4).
+  at once. Called by ``paged_block_attention``, and by the decode and the
+  chunk call of a block that has WINDOW LAYERS (``window=``, models/
+  block.py), for it alone takes a LOWER EDGE: a window layer's walk starts
+  at the page of the first query's oldest visible key, reads the slot's
+  ring table (entry ``page % ring``) and masks ``col > pos - window``; a
+  full layer of such a block walks from 0. Tables of 16 k tokens do not
+  fit the scratch with every KV head, so those calls walk a group of KV
+  heads a grid step. For blocks without a window the block program is the
+  one caller whose gain the benchmark could judge (ROADMAP S3; PERF.md
+  section 6, PR 48); the body takes every call shape of the family
+  (tests/test_paged_kernels.py drives them), so the other three wrappers
+  move over by an entry in the table, after which the grid body goes
+  (ROADMAP D4).
 
 - a LATENT pool (a cache spec with ``latent_dim``, models/block.py;
   :func:`paged_latent_attention`, ``_latent_attn_kernel``): one array of
@@ -119,10 +126,21 @@ _GQA_CHUNK_PAGES = 4
 # by the pool's kind: the wrappers route by it and the engine reports its
 # own programs' share of it (``attn_walks_live``). Every other call runs
 # the grid body, whose work follows the table's width.
-WALKS_LIVE = {"latent": ("decode", "verify", "chunk"), "heads": ("block",)}
+# ("windowed": pools of K and V per head of a block that has window layers,
+# whose reads need the lower edge, which only a walking body takes.)
+WALKS_LIVE = {"latent": ("decode", "verify", "chunk"), "heads": ("block",),
+              "windowed": ("decode", "chunk")}
+# what the walking body's scratch may hold when it walks for a block with
+# window layers, whose tables run to 16 k tokens and more: both pools' pages
+# of one step's KV heads (both halves), and one row tile's scores. A call
+# whose KV heads do not fit walks them a GROUP a grid step.
+_WALK_KV_BYTES = 36 * 1024 * 1024
+_WALK_SCORE_BYTES = 16 * 1024 * 1024
+_WALK_VMEM_LIMIT = 100 * 1024 * 1024
 
 
-def walking_calls(latent: bool, block_len: int = 1) -> list[str]:
+def walking_calls(latent: bool, block_len: int = 1,
+                  windowed: bool = False) -> list[str]:
     """The call kinds of ONE engine's programs whose body walks live pages
     (the engine's ``attn_walks_live``): what its programs call, by the
     cache spec's block length (a block program and the chunk program, or
@@ -130,8 +148,8 @@ def walking_calls(latent: bool, block_len: int = 1) -> list[str]:
     pool's kind."""
     called = ("decode", "verify", "chunk") if block_len == 1 \
         else ("block", "chunk")
-    return [kind for kind in called
-            if kind in WALKS_LIVE["latent" if latent else "heads"]]
+    kind = "latent" if latent else "windowed" if windowed else "heads"
+    return [call for call in called if call in WALKS_LIVE[kind]]
 
 
 def interpret_default() -> bool:
@@ -183,13 +201,14 @@ def tp_shard_specs(q_rank: int, n_replicated: int, axis: str = "tensor"):
     return in_specs, q_spec
 
 
-def _row_tiling(r: int, max_len: int, dtype) -> tuple[int, int]:
+def _row_tiling(r: int, max_len: int, dtype,
+                tile_bytes: int = _SCORE_TILE_BYTES) -> tuple[int, int]:
     """(padded row count, row tile). Rows pad to whole sublane tiles —
     Mosaic rejects a 2-row bf16 matmul operand — and are processed in
     tiles small enough that one [tile, L] float32 score block plus its
     softmax temporaries fits scoped VMEM at any sequence limit."""
     sub = sublane_tile(dtype)
-    cap = min(_MAX_ROW_TILE, _SCORE_TILE_BYTES // (4 * max_len))
+    cap = min(_MAX_ROW_TILE, tile_bytes // (4 * max_len))
     cap = max(sub, cap // sub * sub)
     r_sub = -(-r // sub) * sub
     if r_sub <= cap:
@@ -257,7 +276,7 @@ def paged_attention(q, k_pages, v_pages, page_tables, base, limit=None,
                     layer=None, *, sm_scale: float | None = None,
                     interpret: bool | None = None,
                     name: str = "paged_attention", block_len: int = 1,
-                    walk: bool = False):
+                    walk: bool = False, window: int | None = None):
     """Fused paged attention over the whole query span.
 
     q: [B, T, H, D] — query position of q[:, t] is ``base + t`` (causal
@@ -279,6 +298,13 @@ def paged_attention(q, k_pages, v_pages, page_tables, base, limit=None,
     (each of the callers below passes its own). walk (static): the body
     that walks each slot's live pages (:func:`_gqa_walk_kernel`) instead of
     the grid over the table's pages; the same values to the last ULPs.
+    window (static; implies ``walk``): the LOWER EDGE, of a block that has
+    window layers. None: no such block. 0: one of its full layers, every
+    key from 0 on. W above 0: a window layer, query i sees key j iff ``0 <=
+    i - j < W``, and ``page_tables`` is the slots' RING tables [B, ring]:
+    position p lies in entry ``(p // page) % ring`` (models/block.py), the
+    walk starts at the page that holds the first query's oldest visible
+    key, and a page below it is never read.
     Returns [B, T, H, D] in q.dtype.
     """
     b, t, h, d = q.shape
@@ -288,10 +314,12 @@ def paged_attention(q, k_pages, v_pages, page_tables, base, limit=None,
         return _packed_heads(
             q, k_pages, v_pages, page_tables, base, limit, layer,
             sm_scale=d ** -0.5 if sm_scale is None else sm_scale,
-            interpret=interpret, name=name, block_len=block_len, walk=walk)
-    if walk:
-        if limit is None:
-            limit = jnp.full((b,), page_tables.shape[1] * k_pages.shape[3],
+            interpret=interpret, name=name, block_len=block_len, walk=walk,
+            window=window)
+    if walk or window is not None:
+        if limit is None:   # the table's span; a ring's positions pass it
+            limit = jnp.full((b,), 2 ** 30 if window else
+                             page_tables.shape[1] * k_pages.shape[3],
                              jnp.int32)
         return _gqa_walk_call(
             q, k_pages, v_pages, page_tables.astype(jnp.int32),
@@ -299,7 +327,7 @@ def paged_attention(q, k_pages, v_pages, page_tables, base, limit=None,
             jnp.reshape(layer, (1,)).astype(jnp.int32),
             sm_scale=float(d ** -0.5 if sm_scale is None else sm_scale),
             interpret=interpret_default() if interpret is None
-            else interpret, name=name, block_len=block_len)
+            else interpret, name=name, block_len=block_len, window=window)
     hkv = k_pages.shape[1]
     n_rep = h // hkv
     page_size = k_pages.shape[3]
@@ -603,7 +631,8 @@ def _gqa_walk_kernel(pt_ref, base_ref, limit_ref, layer_ref,  # prefetch
                      q_ref, k_pool, v_pool, o_ref, k_scr, v_scr, s_scr,
                      acc_scr, sems, *, sm_scale: float, page_size: int,
                      max_pages: int, chunk_pages: int, t_span: int,
-                     row_tile: int, block_len: int):
+                     row_tile: int, block_len: int, window: int,
+                     groups: int):
     """Grid (B,): one grid step a slot, every KV head of the call walked
     inside it, whose work follows the slot's LIVE length: the end of the
     block that holds its last query position, ``min(limit, ((base + t_span
@@ -635,49 +664,78 @@ def _gqa_walk_kernel(pt_ref, base_ref, limit_ref, layer_ref,  # prefetch
     last live chunk are zeroed first (a weight of zero times whatever VMEM
     held could be a NaN; a masked score is replaced whatever it was); a
     slot with nothing live walks nothing and writes zeros.
+
+    THE LOWER EDGE (``window`` W above 0, static; a window layer): a row
+    sees key j iff ``pos - W < j`` beside the rule above, the walk STARTS
+    at the page that holds the first query's oldest visible key, ``max(0,
+    base - W + 1) // page`` (a page below it is never copied), scratch page
+    0 is that page, and the table row is a RING of ``max_pages`` entries:
+    logical page j lies in entry ``j % max_pages``. ``groups`` G above 1
+    (static; tables too long for every KV head's pages to fit the
+    scratch): the grid is (B x G,), step s walks KV heads ``(s % G) * Hkv /
+    G ...`` of slot ``s // G``, and the copies it starts ahead are the next
+    STEP's.
     """
-    b = pl.program_id(0)
-    half = b % 2
+    step = pl.program_id(0)
+    half = step % 2
+    b = step if groups == 1 else step // groups
     base = base_ref[b]
     limit = limit_ref[b]
     chunk = chunk_pages * page_size
-    hkv, r_pad, d = q_ref.shape
+    hkv, r_pad, d = q_ref.shape        # the KV heads of one step
 
     def pages_of(slot):
         end = base_ref[slot] + t_span
         if block_len > 1:       # the end of the last position's block
             end = ((end - 1) // block_len + 1) * block_len
-        live = jnp.clip(jnp.minimum(limit_ref[slot], end),
-                        0, max_pages * page_size)
+        if window:      # a ring's positions run past its table's span
+            live = jnp.maximum(jnp.minimum(limit_ref[slot], end), 0)
+        else:
+            live = jnp.clip(jnp.minimum(limit_ref[slot], end),
+                            0, max_pages * page_size)
         return (live + page_size - 1) // page_size
+
+    def first_of(slot):
+        """The page of the first query's oldest visible key."""
+        return jnp.maximum(base_ref[slot] - (window - 1), 0) // page_size
 
     def page_rows(j):
         return pl.ds(pl.multiple_of(j * page_size, page_size), page_size)
 
-    def page_copies(slot, j):
-        """Page j of the slot's table, every KV head of it, K and V."""
+    def page_copies(s, j, at):
+        """Logical page j of step s's slot, the step's KV heads of it, K
+        and V, into scratch page ``at``."""
+        slot = s if groups == 1 else s // groups
+        heads = slice(None) if groups == 1 \
+            else pl.ds((s % groups) * hkv, hkv)
         return [pltpu.make_async_copy(
-            pool.at[layer_ref[0], :, pt_ref[slot, j]],
-            scr.at[slot % 2, :, page_rows(j)],
-            sems.at[slot % 2, j // chunk_pages])
+            pool.at[layer_ref[0], heads,
+                    pt_ref[slot, j % max_pages if window else j]],
+            scr.at[s % 2, :, page_rows(at)],
+            sems.at[s % 2, at // chunk_pages])
             for pool, scr in ((k_pool, k_scr), (v_pool, v_scr))]
 
-    def fetch(slot):
+    def fetch(s):
+        slot = s if groups == 1 else s // groups
+        first = first_of(slot) if window else 0
+
         def start(j, carry):
-            for copy in page_copies(slot, j):
+            for copy in page_copies(s, j, j - first if window else j):
                 copy.start()
             return carry
-        jax.lax.fori_loop(0, pages_of(slot), start, None)
+        jax.lax.fori_loop(first, pages_of(slot), start, None)
 
-    @pl.when(b == 0)
+    @pl.when(step == 0)
     def _own():
-        fetch(b)
+        fetch(step)
 
-    @pl.when(b + 1 < pl.num_programs(0))
+    @pl.when(step + 1 < pl.num_programs(0))
     def _ahead():
-        fetch(b + 1)
+        fetch(step + 1)
 
-    live_pages = pages_of(b)
+    first = first_of(b) if window else 0
+    live_pages = jnp.maximum(pages_of(b) - first, 0) if window \
+        else pages_of(b)
     live_chunks = (live_pages + chunk_pages - 1) // chunk_pages
 
     def zero(j, carry):
@@ -708,7 +766,8 @@ def _gqa_walk_kernel(pt_ref, base_ref, limit_ref, layer_ref,  # prefetch
 
                     @pl.when(j < live_pages)
                     def _wait():
-                        for copy in page_copies(b, j):
+                        for copy in page_copies(
+                                step, first + j if window else j, j):
                             copy.wait()
 
             # fp32 MXU accumulation rounded to q.dtype, then the fp32
@@ -720,7 +779,12 @@ def _gqa_walk_kernel(pt_ref, base_ref, limit_ref, layer_ref,  # prefetch
             s = s.astype(jnp.float32) * sm_scale          # [Hkv, TR, chunk]
             col = c * chunk + jax.lax.broadcasted_iota(
                 jnp.int32, (row_tile, chunk), 1)
-            s = jnp.where(((col < seen) & (col < limit))[None], s, _NEG_INF)
+            if window:      # scratch page 0 is the walk's first page
+                col = first * page_size + col
+            valid = (col < seen) & (col < limit)
+            if window:
+                valid = valid & (col > pos - window)
+            s = jnp.where(valid[None], s, _NEG_INF)
             s_scr[c] = s
             return jnp.maximum(m, s.max(axis=-1, keepdims=True))
 
@@ -755,68 +819,97 @@ def _gqa_walk_kernel(pt_ref, base_ref, limit_ref, layer_ref,  # prefetch
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "sm_scale", "interpret", "name", "block_len"))
+    "sm_scale", "interpret", "name", "block_len", "window"))
 def _gqa_walk_call(q, k_pages, v_pages, page_tables, base, limit, layer, *,
-                   sm_scale, interpret, name, block_len):
+                   sm_scale, interpret, name, block_len, window=None):
     """The walking body's call on pools of K and V per head, every operand
     as :func:`paged_attention` has prepared it. Jitted with the layer an
     OPERAND, as the latent body's call is and for its reason: a block
     program calls it twice a walked layer, and the body is then traced
-    once a process and lowered once a program."""
+    once a process and lowered once a program.
+
+    ``window`` None: the scratch holds a whole table of every KV head, one
+    grid step a slot. Not None (a block with window layers): the scratch
+    holds the widest walk, a window layer's window and span, and as many
+    KV heads a step as :data:`_WALK_KV_BYTES` allows."""
     b, t, h, d = q.shape
     hkv = k_pages.shape[1]
     n_rep = h // hkv
     page_size = k_pages.shape[3]
     max_pages = page_tables.shape[1]
-    chunk_pages = min(_GQA_CHUNK_PAGES, max_pages)
-    n_chunks = -(-max_pages // chunk_pages)
+    # the pages one walk can hold: the table, or a window, the span and
+    # the page the walk's first position lies in
+    span_pages = max_pages if not window \
+        else -(-(window + t - 1) // page_size) + 1
+    if span_pages > max_pages:
+        raise ValueError(
+            f"a span of {t} positions under a window of {window} walks "
+            f"{span_pages} pages; the ring table holds {max_pages}")
+    chunk_pages = min(_GQA_CHUNK_PAGES, span_pages)
+    n_chunks = -(-span_pages // chunk_pages)
     chunk = chunk_pages * page_size
     r = n_rep * t
-    # a score tile holds every KV head's rows
-    r_pad, row_tile = _row_tiling(r, hkv * n_chunks * chunk, q.dtype)
+    isz = jnp.dtype(q.dtype).itemsize
+    groups = 1
+    if window is None:
+        # a score tile holds every KV head's rows
+        r_pad, row_tile = _row_tiling(r, hkv * n_chunks * chunk, q.dtype)
+    else:
+        head_bytes = 4 * n_chunks * chunk * d \
+            * jnp.dtype(k_pages.dtype).itemsize
+        groups = next(g for g in range(1, hkv + 1) if hkv % g == 0
+                      and (hkv // g * head_bytes <= _WALK_KV_BYTES
+                           or g == hkv))
+        r_pad, row_tile = _row_tiling(
+            r, hkv // groups * n_chunks * chunk, q.dtype, _WALK_SCORE_BYTES)
+    hs = hkv // groups       # the KV heads of one grid step
     # [B, T, H, D] -> [B, Hkv, n_rep*T, D]: the grid body's layout
     qg = q.reshape(b, t, hkv, n_rep, d).transpose(0, 2, 3, 1, 4).reshape(
         b, hkv, r, d)
     if r_pad != r:
         qg = jnp.pad(qg, ((0, 0), (0, 0), (0, r_pad - r), (0, 0)))
 
-    isz = jnp.dtype(q.dtype).itemsize
     # both halves of both scratches + double-buffered q/o blocks + the row
     # tile's scores, accumulator and their temporaries, with headroom
-    vmem = (4 * hkv * n_chunks * chunk * d
+    vmem = (4 * hs * n_chunks * chunk * d
             * jnp.dtype(k_pages.dtype).itemsize
-            + 4 * hkv * r_pad * d * isz
-            + 4 * hkv * row_tile * n_chunks * chunk * 4
-            + 4 * hkv * row_tile * d * 4)
+            + 4 * hs * r_pad * d * isz
+            + 4 * hs * row_tile * n_chunks * chunk * 4
+            + 4 * hs * row_tile * d * 4)
     kernel = functools.partial(
         _gqa_walk_kernel, sm_scale=sm_scale, page_size=page_size,
         max_pages=max_pages, chunk_pages=chunk_pages, t_span=t,
-        row_tile=row_tile, block_len=block_len)
+        row_tile=row_tile, block_len=block_len, window=window or 0,
+        groups=groups)
     pools = (k_pages, v_pages)
+
+    def rows_of(bi, pt, bs, lim, lyr):
+        """A step's block of q and o: its slot's rows, its group's heads."""
+        return (bi, 0, 0, 0) if groups == 1 \
+            else (bi // groups, bi % groups, 0, 0)
+
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
-            grid=(b,),
+            grid=(b * groups,),
             in_specs=[
-                pl.BlockSpec((None, hkv, r_pad, d),
-                             lambda bi, pt, bs, lim, lyr: (bi, 0, 0, 0)),
+                pl.BlockSpec((None, hs, r_pad, d), rows_of),
             ] + [pl.BlockSpec(memory_space=pl.ANY) for _ in pools],
-            out_specs=pl.BlockSpec(
-                (None, hkv, r_pad, d),
-                lambda bi, pt, bs, lim, lyr: (bi, 0, 0, 0)),
+            out_specs=pl.BlockSpec((None, hs, r_pad, d), rows_of),
             scratch_shapes=[
-                pltpu.VMEM((2, hkv, n_chunks * chunk, d), pool.dtype)
+                pltpu.VMEM((2, hs, n_chunks * chunk, d), pool.dtype)
                 for pool in pools] + [
-                pltpu.VMEM((n_chunks, hkv, row_tile, chunk), jnp.float32),
-                pltpu.VMEM((hkv, row_tile, d), jnp.float32),
+                pltpu.VMEM((n_chunks, hs, row_tile, chunk), jnp.float32),
+                pltpu.VMEM((hs, row_tile, d), jnp.float32),
                 pltpu.SemaphoreType.DMA((2, n_chunks)),
             ]),
         out_shape=jax.ShapeDtypeStruct((b, hkv, r_pad, d), q.dtype),
         # in order: a step starts the copies the next one waits for
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
-            vmem_limit_bytes=max(32 * 1024 * 1024, 2 * vmem)),
+            vmem_limit_bytes=min(max(32 * 1024 * 1024, 2 * vmem),
+                                 _WALK_VMEM_LIMIT)),
         interpret=interpret,
         name=name,
     )(page_tables, base, limit, layer, qg, *pools)
@@ -825,7 +918,8 @@ def _gqa_walk_call(q, k_pages, v_pages, page_tables, base, limit, layer, *,
 
 
 def _packed_heads(q, k_pages, v_pages, page_tables, base, limit, layer, *,
-                  sm_scale, interpret, name, block_len=1, walk=False):
+                  sm_scale, interpret, name, block_len=1, walk=False,
+                  window=None):
     """Heads narrower than a pool row: the pool holds ``pack`` KV heads
     side by side in one row of lanes ([L, Hkv / pack, P, page, pack * D]:
     heads of 64 two to a 128-lane row, so HBM holds no padding and a page
@@ -846,8 +940,8 @@ def _packed_heads(q, k_pages, v_pages, page_tables, base, limit, layer, *,
         b, t, h, pack * d)
     out = paged_attention(spread, k_pages, v_pages, page_tables, base, limit,
                           layer, sm_scale=sm_scale, interpret=interpret,
-                          name=name, block_len=block_len,
-                          walk=walk)                        # [B, T, H, pack*D]
+                          name=name, block_len=block_len, walk=walk,
+                          window=window)                    # [B, T, H, pack*D]
     out = out.reshape(b, t, rows, pack, n_rep, pack, d)
     return jnp.stack([out[:, :, :, j, :, j] for j in range(pack)],
                      axis=3).reshape(b, t, h, d)
@@ -856,10 +950,11 @@ def _packed_heads(q, k_pages, v_pages, page_tables, base, limit, layer, *,
 def paged_decode_attention(q, k_pages, v_pages, page_tables, pos,
                            layer=None, *, sm_scale: float | None = None,
                            interpret: bool | None = None,
-                           value_lanes: int = 0):
+                           value_lanes: int = 0, window: int | None = None):
     """Single-token decode attention: q [B, H, D], new token at position
     ``pos[b]`` (attends 0..pos inclusive — its own k/v is already written
-    to the pool). Pool and ``layer`` as in :func:`paged_attention`.
+    to the pool). Pool, ``layer`` and ``window`` (the lower edge of a block
+    that has window layers: it walks) as in :func:`paged_attention`.
     Returns [B, H, D]."""
     if value_lanes:
         return paged_latent_attention(
@@ -869,7 +964,7 @@ def paged_decode_attention(q, k_pages, v_pages, page_tables, pos,
     out = paged_attention(q[:, None], k_pages, v_pages, page_tables, pos,
                           layer=layer, sm_scale=sm_scale, interpret=interpret,
                           name="paged_decode_attention",
-                          walk="decode" in WALKS_LIVE["heads"])
+                          walk="decode" in WALKS_LIVE["heads"], window=window)
     return out[:, 0]
 
 
@@ -913,12 +1008,14 @@ def paged_block_attention(q, k_pages, v_pages, page_tables, seq_lens,
 def paged_chunk_attention(q, k_pages, v_pages, page_table, start, true_len,
                           layer=None, *, sm_scale: float | None = None,
                           interpret: bool | None = None,
-                          block_len: int = 1, value_lanes: int = 0):
+                          block_len: int = 1, value_lanes: int = 0,
+                          window: int | None = None):
     """Chunked-prefill attention for ONE slot: q [1, C, H, D] chunk whose
     first token sits at position ``start``; keys are the slot's whole
     paged view (earlier chunks + this one, pre-written) bounded by
-    ``true_len``; causal, or by blocks of ``block_len``. Returns
-    [1, C, H, D].
+    ``true_len``; causal, or by blocks of ``block_len``; ``window``: the
+    lower edge of a block that has window layers (it walks, and under a
+    window ``page_table`` is the slot's ring). Returns [1, C, H, D].
 
     On a latent pool every head's rows lie on the ONE KV head, C x H of
     them: more than a query block should hold in VMEM (512 x 32 rows of 640
@@ -933,7 +1030,7 @@ def paged_chunk_attention(q, k_pages, v_pages, page_table, start, true_len,
             q, k_pages, v_pages, page_table[None], base, limit, layer,
             sm_scale=sm_scale, interpret=interpret,
             name="paged_chunk_attention", block_len=block_len,
-            walk="chunk" in WALKS_LIVE["heads"])
+            walk="chunk" in WALKS_LIVE["heads"], window=window)
     _, c, h, _ = q.shape
     span = max(1, _MAX_SPAN_ROWS // h)
     n = c // span if c > span and c % span == 0 else 1

@@ -90,6 +90,10 @@ class _Request:
     # state
     slot: int = -1
     pages: list[int] = field(default_factory=list)
+    # a block with window layers: the pages of the slot's ring (the window
+    # pool's), and how many ring entries its context has written again
+    window_pages: list[int] = field(default_factory=list)
+    ring_recycled: int = 0
     generated: list[int] = field(default_factory=list)
     dispatched: int = 0  # tokens whose computation has been dispatched
     prefill_pos: int = 0  # prompt tokens already prefilled (chunked prefill)
@@ -211,6 +215,32 @@ class LLMEngine:
         # turned off or refused, and counted (kv_tier_bypassed_latent,
         # disagg_refused_latent)
         self._latent = kvc.has_latent_cache(self.model_cfg)
+        # a block with window layers: a window layer's pages are a ring
+        # that is written again while the sequence lives, so a page of it
+        # never holds a prefix for another sequence, a spilled chain, a
+        # draft to roll back or a hand-off: prefix reuse, the kv tier,
+        # speculation and disaggregated hand-off are off or refused, and
+        # counted (<x>_bypassed_window, disagg_refused_window). Its slots
+        # hold pages of TWO kinds, each with its allocator: the growing
+        # table's and the ring's (_ring_pages entries, kv_cache.ring_pages
+        # of the window and the widest span a prompt pass writes)
+        self._windowed = kvc.has_window_layers(self.model_cfg)
+        self._ring_pages = 0
+        if self._windowed:
+            span = cfg.prefill_chunk if cfg.prefill_chunk > 0 \
+                else cfg.max_prompt_len
+            self._ring_pages = kvc.ring_pages(
+                self._cache_spec.window, cfg.page_size, span)
+            if -(-self.model_cfg.max_seq_len // cfg.page_size) \
+                    != -(-cfg.max_seq_len // cfg.page_size):
+                raise ValueError(
+                    f"max_seq_len={cfg.max_seq_len} and the model's "
+                    f"max_seq_len={self.model_cfg.max_seq_len} differ in "
+                    f"pages: a block with window layers reads the width of "
+                    f"the full layers' table off its configuration")
+            if self._block_len > 1:
+                raise ValueError("window layers under generation by "
+                                 "diffusion over blocks are not written")
         if self._block_len > 1:
             for name in ("page_size", "max_seq_len", "prefill_chunk"):
                 if getattr(cfg, name) % self._block_len:
@@ -252,8 +282,10 @@ class LLMEngine:
                 f"model's max_seq_len={self.model_cfg.max_seq_len}: a "
                 f"routed block records its choice of experts for at most "
                 f"max_seq_len rows a call")
+        # (the window pool: a ring a slot and the trash page)
         self.kv = kvc.init_paged_cache(
-            self.model_cfg, cfg.num_pages, cfg.page_size, self._tp)
+            self.model_cfg, cfg.num_pages, cfg.page_size, self._tp,
+            window_pages=b * self._ring_pages + 1 if self._windowed else 0)
         # Tensor parallelism (ISSUE 20): one engine process drives a
         # tp_degree-chip "tensor" mesh. Weights get Megatron-style
         # partition-rule shardings (parallel/sharding.py — the SAME
@@ -279,7 +311,8 @@ class LLMEngine:
         # attn_live_pages_total / attn_table_pages_total is the share of
         # a table a call reads
         self._attn_walks_live = paged_ops.walking_calls(
-            bool(self._cache_spec.latent_dim), self._block_len) \
+            bool(self._cache_spec.latent_dim), self._block_len,
+            self._windowed) \
             if self._attn_backend == "pallas" else []
         # performance introspection (observability/profiling.py): phase
         # timers + ITL ring gate on cfg.profiling_enabled; compile-event
@@ -297,13 +330,18 @@ class LLMEngine:
         # pages change WHICH pool pages a slot reads, never the compiled
         # programs or their shapes.
         self._prefix_cache_on = bool(cfg.prefix_cache_enabled) \
-            and not self._stateful
+            and not self._stateful and not self._windowed
         # one-shot log guard: ingress digests disagreeing with the local
         # recompute (tokenizer skew) warns once, not once per request
         self._ingress_skew_warned = False
         self.allocator = kvc.PageAllocator(
             cfg.num_pages, cache_pages=cfg.prefix_cache_max_pages)
-        self.page_tables = np.zeros((b, self.max_pages_per_seq), np.int32)
+        # the rings' pages (a block with window layers), and the width of
+        # a slot's page table: the growing table, then its ring table
+        self.window_allocator = kvc.PageAllocator(
+            b * self._ring_pages + 1) if self._windowed else None
+        self._table_width = self.max_pages_per_seq + self._ring_pages
+        self.page_tables = np.zeros((b, self._table_width), np.int32)
         self.seq_lens = np.zeros((b,), np.int32)
         self.slot_req: list[Optional[_Request]] = [None] * b
         self.free_slots = list(range(b))
@@ -389,6 +427,14 @@ class LLMEngine:
                       # what a block with a latent cache is kept out of
                       "kv_tier_bypassed_latent": 0,
                       "disagg_refused_latent": 0,
+                      # what a block with window layers is kept out of,
+                      # and the ring entries its slots have written again
+                      # (a page whose tokens no later query can see)
+                      "prefix_bypassed_window": 0,
+                      "kv_tier_bypassed_window": 0,
+                      "spec_bypassed_window": 0,
+                      "disagg_refused_window": 0,
+                      "window_pages_recycled_total": 0,
                       # dispatches that found the device with nothing
                       # queued although slots were live, and the seconds
                       # since the loop last knew it busy (_dry): an UPPER
@@ -449,7 +495,8 @@ class LLMEngine:
         # fused dispatch. Greedy-only guarantee — non-greedy slots are
         # never drafted and ride the normal decode path.
         self._spec_on = bool(cfg.spec_decode_enabled) \
-            and not self._stateful and self._block_len == 1
+            and not self._stateful and self._block_len == 1 \
+            and not self._windowed
         # last decode-block k actually dispatched + live pipeline depth
         # (engine_stats gauges: the k=1/pressure/idle tier transitions are
         # observable instead of inferred from throughput wiggles), and
@@ -507,7 +554,7 @@ class LLMEngine:
         # is a PERMANENT TRASH ROW: bucketed dispatch pads its packed slot
         # index vector with it, so padding lanes write into the trash page
         # (page-table row of zeros) instead of any live slot's KV.
-        self._pt_dev = jnp.zeros((b + 1, self.max_pages_per_seq), jnp.int32)
+        self._pt_dev = jnp.zeros((b + 1, self._table_width), jnp.int32)
         self._sl_dev = jnp.zeros((b + 1,), jnp.int32)
         self._temps_dev = jnp.zeros((b + 1,), jnp.float32)
         if self._mesh is not None:
@@ -1010,7 +1057,7 @@ class LLMEngine:
         didx = self._slot_index((), trash + 1)
         self._pt_dev, self._sl_dev, self._temps_dev = self._patch_state(
             self._pt_dev, self._sl_dev, self._temps_dev, didx,
-            np.zeros((trash + 1, self.max_pages_per_seq), np.int32),
+            np.zeros((trash + 1, self._table_width), np.int32),
             np.zeros((trash + 1,), np.int32),
             np.zeros((trash + 1,), np.float32))
         self._dev_tokens = self._patch_toks(
@@ -1381,6 +1428,14 @@ class LLMEngine:
                # for a block without slot state)
                "state_slots_in_use": (active + prefilling + restoring
                                       if self._stateful else 0),
+               # pages live requests hold of each kind (a block with
+               # window layers: its rings' beside the growing tables')
+               "full_pages_in_use": self.cfg.num_pages - 1 - free,
+               "window_pages_in_use": (
+                   self.window_allocator.num_pages - 1
+                   - self.window_allocator.available()
+                   if self._windowed else 0),
+               "ring_pages": self._ring_pages,
                "decode_block_effective": self._last_block,
                "pending_pipeline_depth": len(self._pending),
                # the idle tier's k now, and how often the rule moved it
@@ -1710,6 +1765,15 @@ class LLMEngine:
                     if matched:
                         self.allocator.free(matched)
                     return admitted
+                if self._windowed:
+                    # the slot's ring: every page of it the request can
+                    # reach, reserved now like the growing table's
+                    req.window_pages = self.window_allocator.alloc(
+                        min(n_pages, self._ring_pages))
+                    if req.window_pages is None:
+                        req.window_pages = []
+                        self.allocator.free(pages)
+                        return admitted
                 self._waiting.pop(0)
                 slot = self.free_slots.pop()
                 req.slot = slot
@@ -1723,7 +1787,7 @@ class LLMEngine:
                     self.stats[key] += 1
                     self.stats["prefix_hit_tokens"] += req.cached_tokens
                 if self._stateful:
-                    self._count_stateful_bypass(req)
+                    self._count_bypass(req, "stateful")
                 if self._block_len > 1:
                     # what the configuration asks for and a block that
                     # carries a pending block does not take part in
@@ -1737,6 +1801,8 @@ class LLMEngine:
                         and self._prefix_cache_on \
                         and len(req.prompt_tokens) > self.cfg.page_size:
                     self.stats["kv_tier_bypassed_latent"] += 1
+                if self._windowed:
+                    self._count_bypass(req, "window")
             # queue-wait phase sample (submit→admit), recorded OUTSIDE the
             # lock: the profiler observes a metrics histogram, which must
             # never run under the engine lock (graftlint lock-discipline)
@@ -1765,17 +1831,18 @@ class LLMEngine:
             self._route_admitted(req)
             admitted += 1
 
-    def _count_stateful_bypass(self, req: _Request) -> None:
+    def _count_bypass(self, req: _Request, why: str) -> None:
         """What this admission would have taken part in had the block no
-        slot state (lock held): the configuration asks for it, the engine
-        does not do it, and the count says so."""
+        slot state (``why`` "stateful") or no window layers ("window")
+        (lock held): the configuration asks for it, the engine does not do
+        it, and the count says so."""
         if self.cfg.prefix_cache_enabled \
                 and len(req.prompt_tokens) > self.cfg.page_size:
-            self.stats["prefix_bypassed_stateful"] += 1
+            self.stats[f"prefix_bypassed_{why}"] += 1
             if self.cfg.kv_tier_enabled:
-                self.stats["kv_tier_bypassed_stateful"] += 1
+                self.stats[f"kv_tier_bypassed_{why}"] += 1
         if self.cfg.spec_decode_enabled:
-            self.stats["spec_bypassed_stateful"] += 1
+            self.stats[f"spec_bypassed_{why}"] += 1
 
     def refuse_stateful(self, what: str) -> None:
         """Disaggregated handoff moves pages and a first token; a block
@@ -1801,6 +1868,13 @@ class LLMEngine:
             raise NotImplementedError(
                 f"{what}: the block keeps one latent row a token, and a "
                 f"handoff's wire format carries pairs of K and V pages")
+        if self._windowed:
+            with self._lock:
+                self.stats["disagg_refused_window"] += 1
+            raise NotImplementedError(
+                f"{what}: the block has window layers, whose pages are a "
+                f"ring of a second pool; a handoff carries pages of one "
+                f"pool, each holding its tokens for good")
 
     def _route_admitted(self, req: _Request) -> None:
         """Send an admitted request (prefix matched, tier restore — if
@@ -2241,8 +2315,7 @@ class LLMEngine:
         bucket = self._bucket(plen)
         toks = np.full((1, bucket), 0, np.int32)
         toks[0, :plen] = req.prompt_tokens
-        table = np.zeros((self.max_pages_per_seq,), np.int32)
-        table[: len(req.pages)] = req.pages
+        table = self._table_of(req)
         fn = self._prefill_fn(bucket)
         self._rng, sub = self._split_key(self._rng)
         # a first-use prefill bucket compiles HERE, with a live request
@@ -2260,6 +2333,34 @@ class LLMEngine:
                 np.int32(req.slot))
             self._newest = tok_dev
         self._arm_slot(req, table, tok_dev, plen)
+
+    def _table_of(self, req: _Request):
+        """The slot's page table as the programs take it: its pages of the
+        growing table and, of a block with window layers, its ring table
+        behind them (kv_cache.py's module docstring)."""
+        table = np.zeros((self._table_width,), np.int32)
+        table[: len(req.pages)] = req.pages
+        mp = self.max_pages_per_seq
+        table[mp: mp + len(req.window_pages)] = req.window_pages
+        return table
+
+    def _release_pages(self, req: _Request) -> None:
+        """Give back what the request holds of both kinds of pages."""
+        self.allocator.free(req.pages)
+        req.pages = []
+        if req.window_pages:
+            self.window_allocator.free(req.window_pages)
+            req.window_pages = []
+
+    def _count_recycled(self, req: _Request, ctx: int) -> None:
+        """A block with window layers: the ring entries of this slot that
+        a context of ``ctx`` tokens has written again (lock held or loop
+        thread: a counter)."""
+        if self._windowed:
+            n = max(0, -(-ctx // self.cfg.page_size) - self._ring_pages)
+            self.stats["window_pages_recycled_total"] += max(
+                0, n - req.ring_recycled)
+            req.ring_recycled = max(n, req.ring_recycled)
 
     def _arm_slot(self, req: _Request, table, tok_dev, plen: int) -> None:
         """Publish a freshly prefilled slot to the decode loop: host/device
@@ -2328,8 +2429,7 @@ class LLMEngine:
             toks = np.zeros((1, clen), np.int32)
             seg = req.prompt_tokens[start: start + clen]
             toks[0, : len(seg)] = seg
-            table = np.zeros((self.max_pages_per_seq,), np.int32)
-            table[: len(req.pages)] = req.pages
+            table = self._table_of(req)
             fn = self._chunk_fn(clen)
             self._rng, sub = self._split_key(self._rng)
             with self._prof.span(
@@ -2347,6 +2447,7 @@ class LLMEngine:
                 self._newest = tok_dev
             self.stats["attn_chunk_dispatches"] += 1
             req.prefill_pos = min(start + clen, plen)
+            self._count_recycled(req, req.prefill_pos)
             if req.prefill_pos >= plen:
                 with self._lock:
                     self._prefilling.remove(req)
@@ -2384,8 +2485,7 @@ class LLMEngine:
                 self.stats["shed_expired"] += 1
             else:
                 self._requests.pop(req.request_id, None)
-        self.allocator.free(req.pages)
-        req.pages = []
+        self._release_pages(req)
         req.done_event.set()
 
     def _record_token(self, req: _Request, tok: int) -> None:
@@ -2493,7 +2593,7 @@ class LLMEngine:
                 # (whose state is all-zeros by invariant), so ONE compiled
                 # scatter covers every dirty-count
                 order = sorted(dirty)
-                ptv = np.zeros((trash_row + 1, self.max_pages_per_seq),
+                ptv = np.zeros((trash_row + 1, self._table_width),
                                np.int32)
                 ptv[: len(order)] = self.page_tables[order]
                 slv = np.zeros((trash_row + 1,), np.int32)
@@ -2593,7 +2693,7 @@ class LLMEngine:
             # starts (what the device's seq_lens hold): the live context;
             # live_pages: the pages that hold that step's keys (a slot's
             # context and the step's own bl positions)
-            ctx_tokens = live_pages = 0
+            ctx_tokens = live_pages = window_tokens = 0
             skips = []
             fused = k - 1
             for _col, _slot, req in snapshot:
@@ -2610,6 +2710,10 @@ class LLMEngine:
                     req.dispatched += k
                 ctx_tokens += ctx
                 live_pages += -(-(ctx + bl) // self.cfg.page_size)
+                if self._windowed:
+                    # what a window layer's read of the slot walks
+                    window_tokens += min(ctx, self._cache_spec.window)
+                    self._count_recycled(req, ctx + k)
             table_pages = len(snapshot) * self.max_pages_per_seq
             self.stats["attn_live_pages_total"] += live_pages
             self.stats["attn_table_pages_total"] += table_pages
@@ -2642,6 +2746,8 @@ class LLMEngine:
                              else "decode_dispatch", seq=seq, **how, w=w,
                              active=len(active_slots),
                              ctx_tokens=ctx_tokens, live_pages=live_pages,
+                             **({"window_tokens": window_tokens}
+                                if self._windowed else {}),
                              table_pages=table_pages, inflight=inflight,
                              lead=lead,
                              trimmed=max(
@@ -2937,8 +3043,7 @@ class LLMEngine:
         """Completion tail shared by decode and verify harvests: free
         pages, release waiters, emit trace spans, reap abandoned."""
         for req in finished:
-            self.allocator.free(req.pages)
-            req.pages = []
+            self._release_pages(req)
         for req in finished:
             req.done_event.set()
             if req.trace_ctx:

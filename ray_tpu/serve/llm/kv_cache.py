@@ -55,6 +55,15 @@ Design choices:
   form against the pool, all heads on the one row, the values a prefix of
   the row's lanes (the pallas backend: the latent body of ops/
   paged_attention.py, whose work follows each slot's live length);
+- a block whose cache spec states a ``window`` has WINDOW LAYERS beside
+  its full ones (models/block.py): their K and V lie in a pool of their
+  own (``kw`` / ``vw``) as a RING of :func:`ring_pages` pages a slot,
+  position p in entry ``(p // page) % ring`` of the slot's ring table, which
+  is the TAIL of the page table the programs are handed (the full layers'
+  growing table first, ``ceil(cfg.max_seq_len / page)`` entries wide).
+  :func:`_layer_geometry` gives each layer its table, its place for the
+  call's rows and its lower edge; every paged read of such a block is the
+  walking body's (``window=``) or, on the gather backend, the ring's mask;
 - tensor parallelism (ISSUE 20): every step function takes an optional
   ``mesh``. With a live "tensor" axis the pool is sharded per-KV-head
   (axis 1) and the q heads split into exactly the matching kv-head
@@ -97,7 +106,18 @@ from ray_tpu.models.block import block_of, gqa_expand
 logger = logging.getLogger(__name__)
 
 
-def init_paged_cache(cfg, num_pages: int, page_size: int, tp: int = 1):
+def ring_pages(window: int, page_size: int, span: int) -> int:
+    """Pages a slot holds for ONE window layer, whatever its context: the
+    window, the widest span one call writes before it reads (a prefill
+    chunk) and one page more, since neither edge need lie on a page's. A
+    call at positions [s, s + span) overwrites the pages ``ring`` before
+    the ones it writes, whose last token lies at or below ``s - window``:
+    out of every query's sight from s on."""
+    return -(-(window + span) // page_size) + 1
+
+
+def init_paged_cache(cfg, num_pages: int, page_size: int, tp: int = 1,
+                     window_pages: int = 0):
     """What the block's cache spec (models/block.py) asks for, a pytree
     (``tp``: the chips the pool's heads will be split over).
 
@@ -119,6 +139,12 @@ def init_paged_cache(cfg, num_pages: int, page_size: int, tp: int = 1):
     ``value_dim`` lanes are also its values; ``lanes`` is ``latent_dim``
     rounded up to whole 128-lane vectors (:func:`latent_lanes`), the
     padding zeros for ever. There is no ``v``.
+
+    ``kw`` / ``vw`` (a block with window layers only): the same rows for
+    the layers that keep a window, [window_layers, n_kv_heads,
+    window_pages, page_size, head_dim]: a pool of its own, sized by the
+    rings (slots x :func:`ring_pages` and the trash page), not by
+    ``max_seq_len``; ``k`` / ``v`` then hold the full layers alone.
 
     ``state`` (a block with slot state only): one array [num_pages,
     prod(state_shape)] a layer that keeps state (flat: a [2, D] row would
@@ -143,6 +169,15 @@ def init_paged_cache(cfg, num_pages: int, page_size: int, tp: int = 1):
         shape = (spec.paged_layers, heads, num_pages, page_size, lanes)
         kv = {"k": jnp.zeros(shape, cfg.dtype),
               "v": jnp.zeros(shape, cfg.dtype)}
+        if spec.window_layers:
+            if window_pages < 2:
+                raise ValueError(
+                    f"the block has {spec.window_layers} window layers: "
+                    f"their pool needs window_pages (a trash page and a "
+                    f"ring a slot), got {window_pages}")
+            ring = (spec.window_layers, heads, window_pages, page_size, lanes)
+            kv["kw"] = jnp.zeros(ring, cfg.dtype)
+            kv["vw"] = jnp.zeros(ring, cfg.dtype)
     if spec.state_layers:
         kv["state"] = tuple(
             jnp.zeros((num_pages, int(np.prod(spec.state_shape))), cfg.dtype)
@@ -184,6 +219,16 @@ def has_latent_cache(cfg) -> bool:
     return block_of(cfg).cache_spec(cfg).latent_dim > 0
 
 
+def has_window_layers(cfg) -> bool:
+    """Whether some of the block's layers keep a window of their tokens in
+    a ring of pages: a page of theirs is written again while its sequence
+    lives, so prefix reuse (a shared page must hold its tokens), the kv
+    tier, speculative rollback and disaggregated handoff, which all move
+    or share pages of ONE kind, do not happen for such a block (the engine
+    counts each)."""
+    return block_of(cfg).cache_spec(cfg).window_layers > 0
+
+
 def has_slot_state(cfg) -> bool:
     """Whether the block keeps per-sequence state beside its pages: state
     that pages, ``seq_len`` and a page table do not restore. Prefix reuse,
@@ -206,9 +251,9 @@ def page_raw_nbytes(cfg, page_size: int) -> int:
 
 
 def pool_nbytes(kv) -> int:
-    """Bytes the whole pool holds on the device(s): k + v, or the one
-    array of a latent cache."""
-    return int(sum(kv[n].nbytes for n in ("k", "v") if n in kv))
+    """Bytes the whole pool holds on the device(s): k + v (window layers'
+    too), or the one array of a latent cache."""
+    return int(sum(kv[n].nbytes for n in ("k", "v", "kw", "vw") if n in kv))
 
 
 def pool_dtype(kv):
@@ -936,6 +981,11 @@ def _attend(q, k_pool, v_pool, l, g, value_lanes: int = 0):
         out = call(q, k_pool, v_pool, *g.operands, l)
         return out[:, None] if one and value_lanes else out
     tables = g.operands[0]
+    if one and g.valid is not None:     # a ring's mask, by its geometry
+        return _dense_attention(
+            q, _gather_seq(k_pool, l, tables, head_dim),
+            _gather_seq(v_pool, l, tables, head_dim),
+            g.valid[:, None, None], sm)[:, 0]
     if one:
         def causal():       # [B, L], made where the recorded programs have it
             return jnp.arange(tables.shape[1] * k_pool.shape[3])[None, :] \
@@ -967,21 +1017,114 @@ def _attend(q, k_pool, v_pool, l, g, value_lanes: int = 0):
         g.valid[heads], sm)
 
 
-def _attn_mixer(x, kv, layer, ld, l, g):
-    """A mixer that keeps K and V a head (``serve_qkv``): the call's rows
-    written to layer ``l`` of the pool first, then read back with all that
-    is cached (write-then-read: a call sees earlier calls AND itself), then
-    the output projection. Returns (x + mixer, kv)."""
-    blk = block_of(g.cfg)
-    q, k, v = blk.serve_qkv(x, layer, g.cos, g.sin, g.cfg)
+def _positions(g):
+    """The call's positions in the form of its token grid, from its
+    operands (the second is where the call's first row lies)."""
+    first = g.operands[1]
+    if g.lone == 1:
+        return first
+    return jnp.asarray(first)[..., None] + jnp.arange(g.offset.shape[-1])
+
+
+def _layer_geometry(g, ld, page_size: int):
+    """(the names of the layer's pools, its geometry, the scope of its
+    read). A block without window layers: the call's own, untouched. One
+    with them: the page table the call was handed is the full layers'
+    table and then the ring table (the module docstring); a full layer
+    reads the first with a lower edge of 0, a window layer gets the ring
+    table, the ring entries of the call's rows and, on the gather backend,
+    the ring's mask: entry ``c // page`` offset ``c % page`` holds the
+    LATEST position p <= hi with ``p % cap == c`` (hi: the last position
+    the call wrote; write-then-read), seen by query i iff ``0 <= i - p <
+    window``."""
+    if not has_window_layers(g.cfg):
+        return ("k", "v"), g, None
+    if g.lone is None or _block_len(g.cfg) > 1:
+        raise NotImplementedError(
+            "a span of positions a slot (speculative verify, a block pass) "
+            "for a block with window layers: a rejected position's row "
+            "would have overwritten a live one of the ring")
+    full_w = -(-g.cfg.max_seq_len // page_size)
+    tables = g.operands[0]
+    if tables.shape[-1] <= full_w:
+        raise ValueError(
+            f"the page table is {tables.shape[-1]} entries wide: a block "
+            f"with window layers takes the full table "
+            f"(ceil(cfg.max_seq_len / page) = {full_w}) followed by the "
+            f"ring table")
+    window = ld.window if ld is not None else 0
+    if not window:
+        valid = g.valid if g.valid is None \
+            else g.valid[..., :full_w * page_size]
+        return ("k", "v"), dataclasses.replace(
+            g, operands=(tables[..., :full_w], *g.operands[1:]),
+            static={**g.static, "window": 0}, valid=valid), "attn_full"
+    ring = tables[..., full_w:]
+    r = ring.shape[-1]
+    pos = _positions(g)
+    entry = (pos // page_size) % r
+    page_idx = jnp.where(g.page_idx != 0, jnp.take_along_axis(
+        ring, entry.reshape(ring.shape[:-1] + (-1,)), axis=-1).reshape(
+            entry.shape), 0)
+    valid = None
+    if g.attn_backend != "pallas":
+        cap = r * page_size
+        # the last position written: a decode's own, a chunk's last real
+        hi = pos if g.lone == 1 else jnp.minimum(
+            g.operands[1] + g.offset.shape[0], g.operands[2]) - 1
+        hi = jnp.asarray(hi)[..., None]
+        kpos = hi - (hi - jnp.arange(cap)) % cap         # [B, cap] | [cap]
+        valid = (kpos >= 0) if g.lone == 1 \
+            else (kpos >= 0) & (kpos < g.operands[2])
+        seen = (kpos <= pos[..., None]) & (kpos > pos[..., None] - window)
+        valid = seen & valid
+    return ("kw", "vw"), dataclasses.replace(
+        g, operands=(ring, *g.operands[1:]), page_idx=page_idx,
+        static={**g.static, "window": window}, valid=valid), "attn_window"
+
+
+def _write_read(x, kv, q, k, v, ld, l, g, project):
+    """What the mixers that keep K and V a head share: the call's rows
+    written to layer ``l`` of the layer's pool first, then read back with
+    all that is cached (write-then-read: a call sees earlier calls AND
+    itself; a window layer through its ring and no further back than its
+    window), then ``project``, the output projection. Returns (x + mixer,
+    kv)."""
+    (nk, nv), g, scope = _layer_geometry(g, ld, kv["k"].shape[3])
     with jax.named_scope("kv_write"):
         k_pool, v_pool = _write_token_kv(
-            kv["k"], kv["v"], l, _drop(k, g.lone), _drop(v, g.lone),
+            kv[nk], kv[nv], l, _drop(k, g.lone), _drop(v, g.lone),
             g.page_idx, g.offset)
     with jax.named_scope("attn"):
-        out = blk.serve_attn_out(_attend(q, k_pool, v_pool, l, g), layer)
+        if scope is None:
+            read = _attend(q, k_pool, v_pool, l, g)
+        else:       # a trace tells a window layer's read from a full one's
+            with jax.named_scope(scope):
+                read = _attend(q, k_pool, v_pool, l, g)
+        out = project(read)
         x = x + (out if out.ndim == x.ndim else out[:, None])
-    return x, {**kv, "k": k_pool, "v": v_pool}
+    return x, {**kv, nk: k_pool, nv: v_pool}
+
+
+def _attn_mixer(x, kv, layer, ld, l, g):
+    """A mixer that keeps K and V a head (``serve_qkv``,
+    :func:`_write_read`)."""
+    blk = block_of(g.cfg)
+    q, k, v = blk.serve_qkv(x, layer, g.cos, g.sin, g.cfg)
+    return _write_read(x, kv, q, k, v, ld, l, g,
+                       lambda read: blk.serve_attn_out(read, layer))
+
+
+def _gated_mixer(x, kv, layer, ld, l, g):
+    """The same under a gate (``serve_gated_qkv``): what was read is
+    multiplied by the gate of its own row, lane by lane, before the output
+    projection."""
+    blk = block_of(g.cfg)
+    q, k, v, gate = blk.serve_gated_qkv(x, layer, g.cos, g.sin, g.cfg, ld)
+    return _write_read(
+        x, kv, q, k, v, ld, l, g, lambda read: blk.serve_gated_out(
+            read, gate if read.ndim == gate.ndim else gate[:, 0], layer,
+            g.cfg))
 
 
 # A MIXER KIND (models/block.py ``LayerDef.mixer``) is one entry here:
@@ -990,6 +1133,7 @@ def _attn_mixer(x, kv, layer, ld, l, g):
 # runs it.
 _MIXERS = {
     "attn": _attn_mixer,
+    "gated": _gated_mixer,
     "latent": _latent_mixer,
     "conv": lambda x, kv, layer, ld, l, g: _conv_mixer(
         x, kv, layer, g.cfg, ld, *g.state()),
@@ -1078,6 +1222,10 @@ def paged_verify_step(params, kv, page_tables, seq_lens, tokens,
         raise NotImplementedError(
             "speculative verify for a block with slot state: the state "
             "after a rejected draft cannot be rolled back")
+    if has_window_layers(cfg):
+        raise NotImplementedError(
+            "speculative verify for a block with window layers: a "
+            "rejected draft's row has overwritten a live one of the ring")
     x, kv = _span_step(params, kv, page_tables, seq_lens, tokens, cfg,
                        page_size, attn_backend, mesh, block_len=1)
     blk = block_of(cfg)
@@ -1263,19 +1411,36 @@ def paged_prefill(params, kv, page_table, tokens, true_len,
                 pool = _write_token_rows(kv["k"], l, entry, page_idx[None],
                                          offset[None])
             return x, {**kv, "k": pool}
-        q, k, v = blk.serve_qkv(x, layer, cos, sin, cfg)
+        if kind == "gated":
+            q, k, v, gate = blk.serve_gated_qkv(x, layer, cos, sin, cfg, ld)
+        else:
+            q, k, v = blk.serve_qkv(x, layer, cos, sin, cfg)
+        window = ld.window if ld is not None else 0
+        (nk, nv), mask, rows = ("k", "v"), causal, page_idx
+        if window:
+            # a window layer: no further back than its window, and into
+            # its ring (of a prompt longer than the ring, the rows the
+            # ring still holds at its end: a row is written once)
+            ring = page_table[-(-cfg.max_seq_len // page_size):]
+            r = ring.shape[0]
+            (nk, nv) = ("kw", "vw")
+            mask = causal & (pos[:, None] - pos[None, :] < window)
+            rows = jnp.where(
+                in_range & (pos >= true_len - r * page_size),
+                jnp.take(ring, (pos // page_size) % r), 0)
         with jax.named_scope("attn"):
             # dense causal attention within the prompt (prefill is
             # compute-bound and contiguous — no need to read back through
             # pages)
-            attn = _dense_attention(q, k, v, causal[None, None], sm)
-            x = x + blk.serve_attn_out(attn, layer)
+            attn = _dense_attention(q, k, v, mask[None, None], sm)
+            x = x + (blk.serve_gated_out(attn, gate, layer, cfg)
+                     if kind == "gated" else blk.serve_attn_out(attn, layer))
         x, kv = _ffn(x, kv, layer, cfg, ld)
         with jax.named_scope("kv_write"):
             # scatter the prompt's k/v into this slot's pages
             k_pool, v_pool = _write_token_kv(
-                kv["k"], kv["v"], l, k[0], v[0], page_idx, offset)
-        return x, {**kv, "k": k_pool, "v": v_pool}
+                kv[nk], kv[nv], l, k[0], v[0], rows, offset)
+        return x, {**kv, nk: k_pool, nv: v_pool}
 
     x, kv = _over_layers(step, x, kv, params, cfg)
     x = blk.serve_final_norm(x, params, cfg)

@@ -16,7 +16,10 @@ sdar, joyai: 30) were rewritten by that PR's tree; the dense block's
 twelve (decode tiers, verify, prefill, chunk, both backends) are commit
 c3e050f's, letter for letter, and ``PARENT_DENSE`` below holds them a
 second time so that a rewrite of the file cannot move them unseen: no
-Mistral cell runs a changed program.
+Mistral cell runs a changed program. ISSUE 52 ADDS the block with window
+layers (``afmoe``: ten programs, no verify) and leaves the 42 others as
+they were recorded: that PR's edits of kv_cache.py and of the walking
+kernel lower every one of them to the parent's text.
 
 A PR that MEANS to change one of these programs rewrites the file and says
 so: ``python tests/test_engine_program_hashes.py`` (from the repo's root).
@@ -38,7 +41,7 @@ if __name__ == "__main__":      # run as a script: the repo's root on the path
     sys.path.insert(0, os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
 
-from ray_tpu.models import joyai, lfm2_moe, llama, sdar_moe  # noqa: E402
+from ray_tpu.models import afmoe, joyai, lfm2_moe, llama, sdar_moe  # noqa: E402
 from ray_tpu.serve.llm import LLMConfig, LLMEngine  # noqa: E402
 
 DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data",
@@ -50,7 +53,8 @@ ENGINE = dict(max_batch_size=4, page_size=8, num_pages=64, max_prompt_len=128,
 BLOCKS = {"dense": lambda: llama.llama_tiny(vocab_size=512),
           "lfm2": lfm2_moe.lfm2_moe_tiny,
           "sdar": sdar_moe.sdar_moe_tiny,
-          "joyai": joyai.joyai_tiny}
+          "joyai": joyai.joyai_tiny,
+          "afmoe": afmoe.afmoe_tiny}
 BACKENDS = ("gather", "pallas")
 PROGRAMS = ("decode_1", "decode_4", "decode_8", "verify", "prefill_32",
             "chunk_16")
@@ -59,7 +63,8 @@ PROGRAMS = ("decode_1", "decode_4", "decode_8", "verify", "prefill_32",
 SDAR_PROGRAMS = ("decode_1", "decode_2", "prefill_32", "chunk_16")
 CASES = [(blk, backend, prog) for blk in BLOCKS for backend in BACKENDS
          for prog in (SDAR_PROGRAMS if blk == "sdar" else PROGRAMS)
-         if not (blk == "lfm2" and prog == "verify")]     # slot state: none
+         if not (blk in ("lfm2", "afmoe") and prog == "verify")]
+# (no verify program: slot state, and window layers' rings)
 
 
 def _scope_rows(jaxpr, under: str = "") -> list:
@@ -109,7 +114,7 @@ def _traced(eng: LLMEngine, program: str):
     if kind == "verify":
         return eng._verify.trace(*state, np.full(
             (w, eng.cfg.spec_draft_len), -1, np.int32))
-    table = np.zeros((eng.max_pages_per_seq,), np.int32)
+    table = np.zeros((eng._table_width,), np.int32)   # with a ring, if any
     toks = np.zeros((1, int(n)), np.int32)
     tail = (eng._rng, np.zeros((1,), np.float32), np.int32(0))
     if kind == "prefill":

@@ -471,3 +471,39 @@ def test_latent_kernels_compile_for_v5e_at_the_cells_shapes(which, one_chip):
     compiled = jax.jit(fn).lower(*args).compile()
     assert compiled.as_text().count("tpu_custom_call") == 1
     assert compiled.out_info.shape == out
+
+
+@pytest.mark.parametrize("which", ["decode_window", "decode_full",
+                                   "chunk_window", "chunk_full"])
+def test_lower_edge_calls_compile_for_v5e_at_the_cells_shapes(which, one_chip):
+    """trinity-large-serve-longctx (ISSUE 52): the walking body with a lower
+    edge, 24 slots x 48 query heads on 8 KV heads of 128; a window layer on
+    the ring pool [4, 8, 889, 128, 128] through ring tables of 37 entries
+    (window 4,096), the full layer on [1, 8, 2881, 128, 128] through tables
+    of 128 pages, whose KV heads do not fit the scratch at once; a chunk of
+    512. What interpret mode cannot show: the scratch fits VMEM and every
+    slice is aligned to the tiling."""
+    from ray_tpu.ops import paged_attention as paged_ops
+
+    def s(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    ring = which.endswith("window")
+    pool = s((4, 8, 889, 128, 128) if ring else (1, 8, 2881, 128, 128),
+             jnp.bfloat16)
+    width = 37 if ring else 128
+    kw = dict(interpret=False, window=4096 if ring else 0)
+    if which.startswith("chunk"):
+        fn = lambda q, k, v, pt, a, n, l: paged_ops.paged_chunk_attention(
+            q, k, v, pt, a, n, l, **kw)
+        args = (s((1, 512, 48, 128), jnp.bfloat16), pool, pool, s((width,)),
+                s(()), s(()), s(()))
+        out = (1, 512, 48, 128)
+    else:
+        fn = lambda q, k, v, pt, pos, l: paged_ops.paged_decode_attention(
+            q, k, v, pt, pos, l, **kw)
+        args = (s((24, 48, 128), jnp.bfloat16), pool, pool, s((24, width)),
+                s((24,)), s(()))
+        out = (24, 48, 128)
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+    assert compiled.out_info.shape == out

@@ -479,6 +479,144 @@ def test_only_the_block_wrapper_walks_on_pools_of_k_and_v():
 
 
 # ---------------------------------------------------------------------------
+# the lower edge (ISSUE 52): a window layer's read walks its slot's RING
+# table from the page of the first query's oldest visible key
+# ---------------------------------------------------------------------------
+
+
+def _ring_case(base, t, limit, window, page, ring, seed=0):
+    """A ring pool laid out as the programs lay it (position p in entry
+    ``(p // page) % ring`` of its slot's table, a later position over an
+    earlier one), from a dense truth [B, L, Hkv, d]; ring entries whose
+    page lies wholly below the walk's first page hold NaN (the walk must
+    not copy them). Returns (q, k_pool, v_pool, tables, want): ``want`` the
+    band's softmax over the truth, in numpy."""
+    hkv, n_rep, d = 2, 2, 16
+    b, cap = len(base), ring * page
+    rng = np.random.default_rng(seed)
+    top = max(base) + t
+    truth = rng.normal(size=(2, b, top, hkv, d)).astype(np.float32)
+    q = rng.normal(size=(b, t, hkv * n_rep, d)).astype(np.float32)
+    pools = np.zeros((2, hkv, 1 + b * ring, page, d), np.float32)
+    tables = 1 + np.arange(b * ring, dtype=np.int32).reshape(b, ring)
+    want = np.zeros((b, t, hkv * n_rep, d), np.float32)
+    for s in range(b):
+        hi = min(base[s] + t, limit[s]) - 1          # the last row written
+        first = max(0, base[s] - window + 1) // page
+        for entry in range(ring):
+            # the newest page the entry holds: the largest j <= hi's page
+            # with j % ring == entry
+            j = hi // page - (hi // page - entry) % ring
+            if j < 0:
+                continue                    # never written: zeros
+            if j < first:
+                pools[:, :, tables[s, entry]] = np.nan
+                continue
+            for off in range(page):
+                at = j * page + off
+                if at > hi:                 # stale: the page a ring before
+                    at -= cap
+                if at >= 0:
+                    pools[:, :, tables[s, entry], off] = truth[:, s, at]
+        for i in range(t):
+            at = base[s] + i
+            keys = [j for j in range(max(0, at - window + 1), at + 1)
+                    if j < limit[s]]
+            if not keys:
+                continue
+            for head in range(hkv * n_rep):
+                sc = truth[0, s, keys, head // n_rep] @ q[s, i, head] \
+                    * d ** -0.5
+                w = np.exp(sc - sc.max())
+                want[s, i, head] = (w / w.sum()) \
+                    @ truth[1, s, keys, head // n_rep]
+    return (jnp.asarray(q), jnp.asarray(pools[0]), jnp.asarray(pools[1]),
+            jnp.asarray(tables), want)
+
+
+@pytest.mark.parametrize("groups", [False, True], ids=["heads_at_once",
+                                                       "a_head_a_step"])
+def test_decode_with_a_lower_edge_reads_the_band_off_the_ring(groups,
+                                                              monkeypatch):
+    """Window 16, pages of 8, rings of 5: positions inside the first
+    window (3), past it (37), past the ring's wrap (70, 200: entries
+    written again and again) and on a page's first offset (40). With the
+    scratch too small for both KV heads the same walk a head a grid step."""
+    if groups:
+        monkeypatch.setattr(paged_ops, "_WALK_KV_BYTES", 1)
+    pos = [3, 37, 40, 70, 200]
+    q, k_pool, v_pool, tables, want = _ring_case(
+        pos, 1, [10 ** 6] * len(pos), 16, 8, 5)
+    got = paged_ops.paged_decode_attention(
+        q[:, 0], k_pool, v_pool, tables, jnp.asarray(pos, jnp.int32),
+        window=16)
+    assert np.isfinite(np.asarray(got)).all()
+    np.testing.assert_allclose(got, want[:, 0], atol=2e-5)
+
+
+@pytest.mark.parametrize("start,true_len", [(0, 16), (24, 40), (120, 131),
+                                            (48, 64)])
+def test_chunk_with_a_lower_edge_reads_the_band_off_the_ring(start, true_len):
+    """A chunk of 16 rows under a window of 16 on a ring of 5 pages of 8
+    (window + chunk + a page): its first, one past the window, one past
+    the ring's wrap whose padded tail lies beyond ``true_len`` (rows there
+    see nothing of their own), one that starts on a ring's first entry."""
+    q, k_pool, v_pool, tables, want = _ring_case(
+        [start], 16, [true_len], 16, 8, 5, seed=start)
+    got = paged_ops.paged_chunk_attention(
+        q, k_pool, v_pool, tables[0], jnp.int32(start), jnp.int32(true_len),
+        window=16)
+    real = true_len - start
+    assert np.isfinite(np.asarray(got)).all()
+    np.testing.assert_allclose(got[0, :real], want[0, :real], atol=2e-5)
+
+
+@pytest.mark.parametrize("t", [1, 5])
+def test_a_full_layer_of_a_windowed_block_walks_from_zero(t):
+    """``window=0``: the same values as the walking body without an edge
+    and as the gather math, on the growing table."""
+    hkv, n_rep, d, page, mp, b = 2, 2, 16, 8, 6, 3
+    kq, kp = jax.random.split(jax.random.PRNGKey(t))
+    k_pages, v_pages = _rand_pool(kp, hkv, mp * b + 1, page, d, jnp.float32)
+    q = jax.random.normal(kq, (b, t, hkv * n_rep, d), jnp.float32)
+    base = jnp.asarray([2, 17, 40], jnp.int32)
+    tables = 1 + jnp.arange(mp * b, dtype=jnp.int32).reshape(b, mp)
+    limit = jnp.full((b,), mp * page, jnp.int32)
+    got = paged_ops.paged_attention(q, k_pages, v_pages, tables, base,
+                                    window=0)
+    _assert_matches(got, paged_ops.paged_attention(
+        q, k_pages, v_pages, tables, base, walk=True))
+    _assert_matches(got, _ref_attention(q, k_pages, v_pages, tables, base,
+                                        limit, d ** -0.5))
+
+
+def test_the_wrappers_of_a_windowed_block_walk_and_a_short_ring_is_refused():
+    hkv, d, page, b = 2, 16, 8, 2
+    k_pages, v_pages = _rand_pool(jax.random.PRNGKey(0), hkv, 5 * b + 1,
+                                  page, d, jnp.float32)
+    q = jnp.zeros((b, 16, 2 * hkv, d), jnp.float32)
+    tables = jnp.arange(1, 5 * b + 1).reshape(b, 5).astype(jnp.int32)
+    lens = jnp.asarray([4, 8], jnp.int32)
+
+    def walks(fn, *a, **kw):
+        return "dma_start" in str(jax.make_jaxpr(
+            lambda *a: fn(*a, **kw))(*a))
+
+    for window in (0, 16):
+        assert walks(paged_ops.paged_decode_attention, q[:, 0], k_pages,
+                     v_pages, tables, lens, window=window)
+        assert walks(paged_ops.paged_chunk_attention, q[:1], k_pages,
+                     v_pages, tables[0], lens[0], lens[1], window=window)
+    assert paged_ops.walking_calls(latent=False, windowed=True) == [
+        "decode", "chunk"]
+    # 16 rows under a window of 16 walk 5 pages of 8; a ring of 4 is short
+    with pytest.raises(ValueError, match="the ring table holds 4"):
+        paged_ops.paged_chunk_attention(
+            q[:1], k_pages, v_pages, tables[0, :4], lens[0], lens[1],
+            window=16)
+
+
+# ---------------------------------------------------------------------------
 # backend resolution
 # ---------------------------------------------------------------------------
 

@@ -185,24 +185,37 @@ def test_spec_stats_keys_and_off_by_default():
     assert on.engine_stats()["spec_accept_rate"] == 0.0
 
 
-def test_verify_program_compiles_once_per_width():
+def test_verify_program_compiles_once_per_width(monkeypatch):
     """The verify-k program must stay ONE compiled program per bucket
-    width (k and the draft matrix shape are static): compile-cache growth
-    here would mean mid-traffic stalls."""
+    width (k and the draft matrix shape are static): a second one here
+    would mean mid-traffic stalls. Counted on THIS engine's own program:
+    a miss of the jitted function's cache runs the Python body of
+    ``_verify_impl`` once, a hit never; what else the process compiled
+    before (an xdist worker runs other files' tests too) does not count."""
     from ray_tpu.serve.llm import LLMEngine
 
+    traced = []
+    real = LLMEngine._verify_impl
+
+    def counting(self, *operands):
+        traced.append(operands[-1].shape)       # the drafts: [width, k]
+        return real(self, *operands)
+
+    monkeypatch.setattr(LLMEngine, "_verify_impl", counting)
     cfg = _tiny_cfg(max_batch_size=4, spec_decode_enabled=True,
                     warmup_compile=True, max_tokens=24)
     eng = LLMEngine(cfg, rng_seed=0)
     eng.start()
     try:
-        assert eng._verify._cache_size() == 1  # warmup compiled it
+        assert len(traced) == 1, traced  # warmup compiled it
+        assert eng._prof.compile_count(("verify",)) == 1
         rids = [eng.submit(REPETITIVE, max_tokens=24, temperature=0.0)
                 for _ in range(3)]
         outs = [eng.result(r, timeout=120.0) for r in rids]
         assert all(o["error"] is None for o in outs)
         assert eng.engine_stats()["spec_rounds"] > 0
-        assert eng._verify._cache_size() == 1  # no recompilation
+        assert len(traced) == 1, traced  # no recompilation
+        assert eng._prof.compile_count(("verify",)) == 1
     finally:
         eng.shutdown()
 
