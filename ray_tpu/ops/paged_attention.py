@@ -46,7 +46,14 @@ option or a rule on shapes:
   ring table (entry ``page % ring``) and masks ``col > pos - window``; a
   full layer of such a block walks from 0. Tables of 16 k tokens do not
   fit the scratch with every KV head, so those calls walk a group of KV
-  heads a grid step. For blocks without a window the block program is the
+  heads a grid step. A call that walks also WRITES (``write=``, ISSUE 53;
+  :func:`writing_calls`): it holds the pages the call's own rows of K and
+  V go to, so the rows ride in, are laid over the scratch a tile of
+  positions at a time and copied back to their pages, and the row scatter
+  that every other call's program runs before its read
+  (serve/llm/kv_cache.py ``_write_token_kv``: one row of a packed tile an
+  index, 50 ns a row) is not run; the pools are then outputs aliased to
+  the inputs. For blocks without a window the block program is the
   one caller whose gain the benchmark could judge (ROADMAP S3; PERF.md
   section 6, PR 48); the body takes every call shape of the family
   (tests/test_paged_kernels.py drives them), so the other three wrappers
@@ -103,6 +110,7 @@ and no in-kernel collectives.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -152,6 +160,21 @@ def walking_calls(latent: bool, block_len: int = 1,
     return [call for call in called if call in WALKS_LIVE[kind]]
 
 
+def writing_calls(latent: bool, block_len: int = 1, windowed: bool = False,
+                  tp: int = 1) -> list[str]:
+    """The call kinds of ONE engine's programs whose kernel also WRITES the
+    call's own rows of K and V (the engine's ``attn_writes_in_kernel``;
+    serve/llm/kv_cache.py ``_geometry`` asks it whether to scatter): a
+    body that walks a slot's pages holds the pages the rows go to, so on
+    pools of K and V per head every call that walks writes (``write=``).
+    Every other call's rows are scattered before it reads: the grid body's,
+    a latent pool's, and any call on a tensor-parallel mesh, whose
+    ``shard_map`` returns no pool."""
+    if latent or tp > 1:
+        return []
+    return walking_calls(False, block_len, windowed)
+
+
 def interpret_default() -> bool:
     """Whether these kernels run interpreted in this process: true
     everywhere but on a TPU backend (the CPU tests' only way to run
@@ -163,6 +186,13 @@ def sublane_tile(dtype) -> int:
     """Rows of one native TPU tile for ``dtype``: (8, 128) at 4 bytes,
     (16, 128) at 2, (32, 128) at 1."""
     return 32 // jnp.dtype(dtype).itemsize
+
+
+def _write_tile(dtype, page_size: int) -> int:
+    """Rows of one write of the walking body: a native tile of the pool's
+    type (a page the TPU tiles is whole tiles, :func:`can_tile`; the
+    interpreter takes any page, and then a divisor of it)."""
+    return math.gcd(sublane_tile(dtype), page_size)
 
 
 def can_tile(head_dim: int, page_size: int, dtype,
@@ -276,7 +306,8 @@ def paged_attention(q, k_pages, v_pages, page_tables, base, limit=None,
                     layer=None, *, sm_scale: float | None = None,
                     interpret: bool | None = None,
                     name: str = "paged_attention", block_len: int = 1,
-                    walk: bool = False, window: int | None = None):
+                    walk: bool = False, window: int | None = None,
+                    write=None):
     """Fused paged attention over the whole query span.
 
     q: [B, T, H, D] — query position of q[:, t] is ``base + t`` (causal
@@ -305,17 +336,30 @@ def paged_attention(q, k_pages, v_pages, page_tables, base, limit=None,
     position p lies in entry ``(p // page) % ring`` (models/block.py), the
     walk starts at the page that holds the first query's oldest visible
     key, and a page below it is never read.
-    Returns [B, T, H, D] in q.dtype.
+    write (the walking body only; :func:`writing_calls`): (k_new, v_new
+    [B, T, Hkv, D], page_idx [B, T]), the span's own K and V and the page
+    each row goes to (its offset is its position's). The call then WRITES
+    the rows to layer ``layer`` of the pools where a scatter before it
+    would have (:func:`_gqa_walk_kernel`; a row whose page is not its
+    position's in the table, which the caller's rule sent to the trash
+    page, is dropped) and reads them back with the rest.
+    Returns [B, T, H, D] in q.dtype; with ``write``, (that, k_pages,
+    v_pages), the pools updated in place.
     """
     b, t, h, d = q.shape
     if k_pages.ndim == 4:
         k_pages, v_pages, layer = k_pages[None], v_pages[None], 0
+    if write is not None:       # the pool's own rows (heads of 64: two a row)
+        rows = (b, t, k_pages.shape[1], k_pages.shape[4])
+        write = (write[0].reshape(rows), write[1].reshape(rows), write[2])
     if k_pages.shape[4] != d:
         return _packed_heads(
             q, k_pages, v_pages, page_tables, base, limit, layer,
             sm_scale=d ** -0.5 if sm_scale is None else sm_scale,
             interpret=interpret, name=name, block_len=block_len, walk=walk,
-            window=window)
+            window=window, write=write)
+    if write is not None and not (walk or window is not None):
+        raise ValueError("only the walking body writes the call's rows")
     if walk or window is not None:
         if limit is None:   # the table's span; a ring's positions pass it
             limit = jnp.full((b,), 2 ** 30 if window else
@@ -324,7 +368,7 @@ def paged_attention(q, k_pages, v_pages, page_tables, base, limit=None,
         return _gqa_walk_call(
             q, k_pages, v_pages, page_tables.astype(jnp.int32),
             base.astype(jnp.int32), limit.astype(jnp.int32),
-            jnp.reshape(layer, (1,)).astype(jnp.int32),
+            jnp.reshape(layer, (1,)).astype(jnp.int32), write,
             sm_scale=float(d ** -0.5 if sm_scale is None else sm_scale),
             interpret=interpret_default() if interpret is None
             else interpret, name=name, block_len=block_len, window=window)
@@ -628,11 +672,10 @@ def _latent_call(q, pool, page_tables, base, limit, layer, *, value_lanes,
 
 
 def _gqa_walk_kernel(pt_ref, base_ref, limit_ref, layer_ref,  # prefetch
-                     q_ref, k_pool, v_pool, o_ref, k_scr, v_scr, s_scr,
-                     acc_scr, sems, *, sm_scale: float, page_size: int,
+                     *refs, sm_scale: float, page_size: int,
                      max_pages: int, chunk_pages: int, t_span: int,
                      row_tile: int, block_len: int, window: int,
-                     groups: int):
+                     groups: int, writes: bool = False):
     """Grid (B,): one grid step a slot, every KV head of the call walked
     inside it, whose work follows the slot's LIVE length: the end of the
     block that holds its last query position, ``min(limit, ((base + t_span
@@ -675,7 +718,37 @@ def _gqa_walk_kernel(pt_ref, base_ref, limit_ref, layer_ref,  # prefetch
     scratch): the grid is (B x G,), step s walks KV heads ``(s % G) * Hkv /
     G ...`` of slot ``s // G``, and the copies it starts ahead are the next
     STEP's.
+
+    THE CALL'S OWN ROWS (``writes``, static; ISSUE 53): the refs are then
+    ``pidx_ref`` (one more scalar operand: the page each row goes to, [B *
+    t_span], as the caller's junk-write rule decided), q_ref, ``kn_ref`` /
+    ``vn_ref`` (the step's new rows [Hkv, one tile of zeros + t_span rows +
+    zeros, D], in the pool's row form and type), the pools as they came in
+    (not touched: they ARE the outputs), o_ref, and the pools as OUTPUTS,
+    which every copy reads and writes. A row's place is its position's:
+    position p lies in scratch page ``p // page - first`` as it lies in the
+    table's page. The rows are handled a TILE of positions at a time (the
+    rows of one native tile of the pool's type: a copy that starts inside
+    one would split a packed word): when the first row tile's walk has
+    waited for a chunk's pages, the tiles of the span inside it are laid
+    over their columns of k_scr / v_scr (the new rows moved to their
+    sublanes by a roll in float32, which bf16 passes through unchanged;
+    the tile's other rows keep the bytes just read) and copied back to the
+    pool page they were read from, and every such copy is waited for
+    before the step ends. So the products see the bytes a scatter followed
+    by a read would have given them. A row whose ``pidx`` is not the page
+    its position has in the table was sent to the trash page by the
+    caller's rule (a chunk's padding, a position past the table): it is
+    neither laid nor written. The copies a step starts ahead are another
+    slot's pages or other KV heads' rows, so no read meets a write but on
+    the trash page, whose values are finite and whose readers' outputs
+    nobody uses.
     """
+    if writes:
+        (pidx_ref, q_ref, kn_ref, vn_ref, _, _, o_ref, k_pool, v_pool,
+         k_scr, v_scr, s_scr, acc_scr, sems, wsems) = refs
+    else:
+        q_ref, k_pool, v_pool, o_ref, k_scr, v_scr, s_scr, acc_scr, sems = refs
     step = pl.program_id(0)
     half = step % 2
     b = step if groups == 1 else step // groups
@@ -702,14 +775,17 @@ def _gqa_walk_kernel(pt_ref, base_ref, limit_ref, layer_ref,  # prefetch
     def page_rows(j):
         return pl.ds(pl.multiple_of(j * page_size, page_size), page_size)
 
+    def heads_of(s):
+        """The KV heads step s walks: every one, or its group's."""
+        return slice(None) if groups == 1 \
+            else pl.ds((s % groups) * hkv, hkv)
+
     def page_copies(s, j, at):
         """Logical page j of step s's slot, the step's KV heads of it, K
         and V, into scratch page ``at``."""
         slot = s if groups == 1 else s // groups
-        heads = slice(None) if groups == 1 \
-            else pl.ds((s % groups) * hkv, hkv)
         return [pltpu.make_async_copy(
-            pool.at[layer_ref[0], heads,
+            pool.at[layer_ref[0], heads_of(s),
                     pt_ref[slot, j % max_pages if window else j]],
             scr.at[s % 2, :, page_rows(at)],
             sems.at[s % 2, at // chunk_pages])
@@ -745,6 +821,71 @@ def _gqa_walk_kernel(pt_ref, base_ref, limit_ref, layer_ref,  # prefetch
 
     jax.lax.fori_loop(live_pages, live_chunks * chunk_pages, zero, None)
 
+    # the call's own rows, a tile of positions at a time
+    if writes:
+        sub = _write_tile(k_scr.dtype, page_size)
+        tile0 = base // sub                 # the tile of the span's first row
+        origin = first * page_size          # the position of scratch column 0
+
+        def tile_at(tile):
+            """(the tile's first position, the pool page the table has for
+            it, its columns of the scratch)."""
+            p0 = tile * sub
+            page = p0 // page_size
+            return (p0, pt_ref[b, page % max_pages if window else page],
+                    pl.ds(pl.multiple_of(p0 - origin, sub), sub))
+
+        def tile_copies(tile):
+            """Tile ``tile`` of positions, the step's KV heads of it, K and V,
+            from the scratch back to the page it was read from."""
+            p0, page, cols = tile_at(tile)
+            return [pltpu.make_async_copy(
+                scr.at[half, :, cols],
+                pool.at[layer_ref[0], heads_of(step), page,
+                        pl.ds(pl.multiple_of(p0 % page_size, sub), sub)],
+                wsems.at[n])
+                for n, (pool, scr) in enumerate(((k_pool, k_scr),
+                                                 (v_pool, v_scr)))]
+
+        def span_tiles(lo, hi):
+            """The tiles of the call's span that lie in scratch columns lo ..
+            hi of the live pages."""
+            hi = jnp.minimum(hi, live_pages * page_size)
+            return (jnp.maximum(tile0, (origin + lo) // sub),
+                    jnp.minimum((base + t_span - 1) // sub + 1,
+                                (origin + hi) // sub))
+
+        def lay(tile, carry):
+            p0, page, cols = tile_at(tile)
+            at = jax.lax.broadcasted_iota(jnp.int32, (sub, d), 0)
+            keep = jnp.zeros((sub, d), jnp.int32)
+            for r in range(sub):        # the rows the caller's rule kept here
+                t = p0 + r - base
+                kept = (t >= 0) & (t < t_span) & (
+                    pidx_ref[b * t_span + jnp.clip(t, 0, t_span - 1)] == page)
+                keep = jnp.where(at == r, kept.astype(jnp.int32), keep)
+            shift = base - tile0 * sub      # the sublane of the span's first row
+            m = tile - tile0
+
+            def rows_tile(ref, n):
+                return ref[:, pl.ds(pl.multiple_of((m + n) * sub, sub), sub),
+                           :].astype(jnp.float32)
+
+            for ref, scr in ((kn_ref, k_scr), (vn_ref, v_scr)):
+                # row r of the tile is row ``sub * m - shift + r`` of the call:
+                # sublane r - shift of the rows' tile m (which the leading
+                # tile of zeros makes tile m + 1), or, below ``shift``,
+                # sublane sub + r - shift of tile m - 1: one roll of both
+                new = pltpu.roll(jnp.where((at < sub - shift)[None],
+                                           rows_tile(ref, 1),
+                                           rows_tile(ref, 0)), shift, 1)
+                old = scr[half, :, cols, :].astype(jnp.float32)
+                scr[half, :, cols, :] = jnp.where(
+                    (keep != 0)[None], new, old).astype(scr.dtype)
+            for copy in tile_copies(tile):
+                copy.start()
+            return carry
+
     def rows(i, carry):
         r0 = pl.multiple_of(i * row_tile, row_tile)
         q = q_ref[:, pl.ds(r0, row_tile), :]                   # [Hkv, TR, D]
@@ -769,6 +910,10 @@ def _gqa_walk_kernel(pt_ref, base_ref, limit_ref, layer_ref,  # prefetch
                         for copy in page_copies(
                                 step, first + j if window else j, j):
                             copy.wait()
+
+                if writes:      # the span's tiles inside the chunk
+                    jax.lax.fori_loop(
+                        *span_tiles(c * chunk, (c + 1) * chunk), lay, None)
 
             # fp32 MXU accumulation rounded to q.dtype, then the fp32
             # scale: the gather path's einsum(...).astype(f32) * sm
@@ -817,11 +962,20 @@ def _gqa_walk_kernel(pt_ref, base_ref, limit_ref, layer_ref,  # prefetch
 
     jax.lax.fori_loop(0, r_pad // row_tile, rows, None)
 
+    if writes:
+        def written(tile, carry):
+            for copy in tile_copies(tile):
+                copy.wait()
+            return carry
+
+        jax.lax.fori_loop(*span_tiles(0, live_chunks * chunk), written, None)
+
 
 @functools.partial(jax.jit, static_argnames=(
     "sm_scale", "interpret", "name", "block_len", "window"))
-def _gqa_walk_call(q, k_pages, v_pages, page_tables, base, limit, layer, *,
-                   sm_scale, interpret, name, block_len, window=None):
+def _gqa_walk_call(q, k_pages, v_pages, page_tables, base, limit, layer,
+                   write=None, *, sm_scale, interpret, name, block_len,
+                   window=None):
     """The walking body's call on pools of K and V per head, every operand
     as :func:`paged_attention` has prepared it. Jitted with the layer an
     OPERAND, as the latent body's call is and for its reason: a block
@@ -831,7 +985,12 @@ def _gqa_walk_call(q, k_pages, v_pages, page_tables, base, limit, layer, *,
     ``window`` None: the scratch holds a whole table of every KV head, one
     grid step a slot. Not None (a block with window layers): the scratch
     holds the widest walk, a window layer's window and span, and as many
-    KV heads a step as :data:`_WALK_KV_BYTES` allows."""
+    KV heads a step as :data:`_WALK_KV_BYTES` allows.
+
+    ``write`` (k_new, v_new [B, T, Hkv, D] in the pool's row form, page_idx
+    [B, T]): the call's own rows ride in and the body writes them (its
+    docstring); the new operands join this ``jit``, the pools are outputs
+    aliased to the inputs, and the call returns (read, k_pages, v_pages)."""
     b, t, h, d = q.shape
     hkv = k_pages.shape[1]
     n_rep = h // hkv
@@ -880,31 +1039,52 @@ def _gqa_walk_call(q, k_pages, v_pages, page_tables, base, limit, layer, *,
         _gqa_walk_kernel, sm_scale=sm_scale, page_size=page_size,
         max_pages=max_pages, chunk_pages=chunk_pages, t_span=t,
         row_tile=row_tile, block_len=block_len, window=window or 0,
-        groups=groups)
+        groups=groups, writes=write is not None)
     pools = (k_pages, v_pages)
 
-    def rows_of(bi, pt, bs, lim, lyr):
-        """A step's block of q and o: its slot's rows, its group's heads."""
+    def rows_of(bi, *_):
+        """A step's block of q, o and the new rows: its slot's rows, its
+        group's heads."""
         return (bi, 0, 0, 0) if groups == 1 \
             else (bi // groups, bi % groups, 0, 0)
 
-    out = pl.pallas_call(
+    scalars, blocked = (page_tables, base, limit, layer), [(qg, r_pad)]
+    if write is not None:
+        k_new, v_new, page_idx = write
+        sub = _write_tile(k_pages.dtype, page_size)
+        # the tiles a span of t positions can touch, one tile of zeros
+        # before its rows (the body's roll reads a tile below) and zeros
+        # behind them up to whole tiles
+        t_pad = ((t + sub - 2) // sub + 2) * sub
+        scalars += (page_idx.reshape(-1).astype(jnp.int32),)
+        blocked += [(jnp.pad(new.astype(pool.dtype).transpose(0, 2, 1, 3),
+                             ((0, 0), (0, 0), (sub, t_pad - sub - t),
+                              (0, 0))), t_pad)
+                    for new, pool in ((k_new, k_pages), (v_new, v_pages))]
+        vmem += 4 * hs * t_pad * d * jnp.dtype(k_pages.dtype).itemsize
+    n_in = len(scalars) + len(blocked)
+    written = pools if write is not None else ()    # the outputs beside o
+    out, *written = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
+            num_scalar_prefetch=len(scalars),
             grid=(b * groups,),
-            in_specs=[
-                pl.BlockSpec((None, hs, r_pad, d), rows_of),
-            ] + [pl.BlockSpec(memory_space=pl.ANY) for _ in pools],
-            out_specs=pl.BlockSpec((None, hs, r_pad, d), rows_of),
+            in_specs=[pl.BlockSpec((None, hs, rows, d), rows_of)
+                      for _, rows in blocked]
+            + [pl.BlockSpec(memory_space=pl.ANY) for _ in pools],
+            out_specs=[pl.BlockSpec((None, hs, r_pad, d), rows_of)]
+            + [pl.BlockSpec(memory_space=pl.ANY) for _ in written],
             scratch_shapes=[
                 pltpu.VMEM((2, hs, n_chunks * chunk, d), pool.dtype)
                 for pool in pools] + [
                 pltpu.VMEM((n_chunks, hs, row_tile, chunk), jnp.float32),
                 pltpu.VMEM((hs, row_tile, d), jnp.float32),
                 pltpu.SemaphoreType.DMA((2, n_chunks)),
-            ]),
-        out_shape=jax.ShapeDtypeStruct((b, hkv, r_pad, d), q.dtype),
+            ] + ([pltpu.SemaphoreType.DMA((2,))] if written else [])),
+        out_shape=[jax.ShapeDtypeStruct((b, hkv, r_pad, d), q.dtype)]
+        + [jax.ShapeDtypeStruct(pool.shape, pool.dtype) for pool in written],
+        # the pools are written where they lie
+        input_output_aliases={n_in + n: 1 + n for n in range(len(written))},
         # in order: a step starts the copies the next one waits for
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
@@ -912,14 +1092,15 @@ def _gqa_walk_call(q, k_pages, v_pages, page_tables, base, limit, layer, *,
                                  _WALK_VMEM_LIMIT)),
         interpret=interpret,
         name=name,
-    )(page_tables, base, limit, layer, qg, *pools)
-    return out[:, :, :r].reshape(b, hkv, n_rep, t, d).transpose(
+    )(*scalars, *(a for a, _ in blocked), *pools)
+    out = out[:, :, :r].reshape(b, hkv, n_rep, t, d).transpose(
         0, 3, 1, 2, 4).reshape(b, t, h, d)
+    return (out, *written) if write is not None else out
 
 
 def _packed_heads(q, k_pages, v_pages, page_tables, base, limit, layer, *,
                   sm_scale, interpret, name, block_len=1, walk=False,
-                  window=None):
+                  window=None, write=None):
     """Heads narrower than a pool row: the pool holds ``pack`` KV heads
     side by side in one row of lanes ([L, Hkv / pack, P, page, pack * D]:
     heads of 64 two to a 128-lane row, so HBM holds no padding and a page
@@ -928,7 +1109,8 @@ def _packed_heads(q, k_pages, v_pages, page_tables, base, limit, layer, *,
     j * D .. (j + 1) * D of a zero row, so its scores are its own head's
     (the other head's lanes meet zeros, which add nothing: the same
     float32 sums), and of the output row, which is over both heads'
-    values, the same lanes are kept."""
+    values, the same lanes are kept. ``write``: the span's new rows are
+    pool rows already, and pass through."""
     b, t, h, d = q.shape
     rows = k_pages.shape[1]
     pack = k_pages.shape[4] // d
@@ -941,21 +1123,32 @@ def _packed_heads(q, k_pages, v_pages, page_tables, base, limit, layer, *,
     out = paged_attention(spread, k_pages, v_pages, page_tables, base, limit,
                           layer, sm_scale=sm_scale, interpret=interpret,
                           name=name, block_len=block_len, walk=walk,
-                          window=window)                    # [B, T, H, pack*D]
+                          window=window, write=write)       # [B, T, H, pack*D]
+    out, *written = out if write is not None else (out,)
     out = out.reshape(b, t, rows, pack, n_rep, pack, d)
-    return jnp.stack([out[:, :, :, j, :, j] for j in range(pack)],
-                     axis=3).reshape(b, t, h, d)
+    out = jnp.stack([out[:, :, :, j, :, j] for j in range(pack)],
+                    axis=3).reshape(b, t, h, d)
+    return (out, *written) if write is not None else out
+
+
+def _with_axis(write, axis: int):
+    """A call's new rows and their pages with the axis its grid has one of
+    (a decode's span, a chunk's slot) put back."""
+    return write and tuple(jnp.expand_dims(a, axis) for a in write)
 
 
 def paged_decode_attention(q, k_pages, v_pages, page_tables, pos,
                            layer=None, *, sm_scale: float | None = None,
                            interpret: bool | None = None,
-                           value_lanes: int = 0, window: int | None = None):
+                           value_lanes: int = 0, window: int | None = None,
+                           write=None):
     """Single-token decode attention: q [B, H, D], new token at position
     ``pos[b]`` (attends 0..pos inclusive — its own k/v is already written
-    to the pool). Pool, ``layer`` and ``window`` (the lower edge of a block
-    that has window layers: it walks) as in :func:`paged_attention`.
-    Returns [B, H, D]."""
+    to the pool, or rides in as ``write``: (k_new, v_new [B, Hkv, D],
+    page_idx [B])). Pool, ``layer``, ``window`` (the lower edge of a block
+    that has window layers: it walks) and ``write`` as in
+    :func:`paged_attention`. Returns [B, H, D]; with ``write``, (that,
+    k_pages, v_pages)."""
     if value_lanes:
         return paged_latent_attention(
             q[:, None], k_pages, page_tables, pos, layer=layer,
@@ -964,18 +1157,20 @@ def paged_decode_attention(q, k_pages, v_pages, page_tables, pos,
     out = paged_attention(q[:, None], k_pages, v_pages, page_tables, pos,
                           layer=layer, sm_scale=sm_scale, interpret=interpret,
                           name="paged_decode_attention",
-                          walk="decode" in WALKS_LIVE["heads"], window=window)
-    return out[:, 0]
+                          walk="decode" in WALKS_LIVE["heads"], window=window,
+                          write=_with_axis(write, 1))
+    return out[:, 0] if write is None else (out[0][:, 0], *out[1:])
 
 
 def paged_verify_attention(q, k_pages, v_pages, page_tables, seq_lens,
                            layer=None, *, sm_scale: float | None = None,
                            interpret: bool | None = None,
-                           value_lanes: int = 0):
+                           value_lanes: int = 0, write=None):
     """Multi-query speculative verify: q [B, T, H, D], T = k+1 draft span
     per slot, q[b, t] at position ``seq_lens[b] + t`` — causal within the
     span, full attention over the slot's cached pages (all T spans' k/v
-    are pre-written). Returns [B, T, H, D]."""
+    are pre-written, or ride in as ``write``, :func:`paged_attention`).
+    Returns [B, T, H, D]."""
     if value_lanes:
         return paged_latent_attention(
             q, k_pages, page_tables, seq_lens, layer=layer,
@@ -985,37 +1180,41 @@ def paged_verify_attention(q, k_pages, v_pages, page_tables, seq_lens,
                            layer=layer, sm_scale=sm_scale,
                            interpret=interpret,
                            name="paged_verify_attention",
-                           walk="verify" in WALKS_LIVE["heads"])
+                           walk="verify" in WALKS_LIVE["heads"], write=write)
 
 
 def paged_block_attention(q, k_pages, v_pages, page_tables, seq_lens,
                           layer=None, *, block_len: int,
                           sm_scale: float | None = None,
-                          interpret: bool | None = None):
+                          interpret: bool | None = None, write=None):
     """The block pass of generation by diffusion over blocks: q [B, T, H, D]
     with T a whole number of blocks of ``block_len`` (one, or two for the
     pass that keeps a block and denoises the next), q[b, t] at position
     ``seq_lens[b] + t`` (a block edge), every position seeing the slot's
     cached pages and the span up to the end of its own block (whose k/v
-    are pre-written). Returns [B, T, H, D]."""
+    are pre-written, or ride in as ``write``, :func:`paged_attention`).
+    Returns [B, T, H, D]; with ``write``, (that, k_pages, v_pages)."""
     return paged_attention(q, k_pages, v_pages, page_tables, seq_lens,
                            layer=layer, sm_scale=sm_scale,
                            interpret=interpret,
                            name="paged_block_attention", block_len=block_len,
-                           walk="block" in WALKS_LIVE["heads"])
+                           walk="block" in WALKS_LIVE["heads"], write=write)
 
 
 def paged_chunk_attention(q, k_pages, v_pages, page_table, start, true_len,
                           layer=None, *, sm_scale: float | None = None,
                           interpret: bool | None = None,
                           block_len: int = 1, value_lanes: int = 0,
-                          window: int | None = None):
+                          window: int | None = None, write=None):
     """Chunked-prefill attention for ONE slot: q [1, C, H, D] chunk whose
     first token sits at position ``start``; keys are the slot's whole
     paged view (earlier chunks + this one, pre-written) bounded by
     ``true_len``; causal, or by blocks of ``block_len``; ``window``: the
     lower edge of a block that has window layers (it walks, and under a
-    window ``page_table`` is the slot's ring). Returns [1, C, H, D].
+    window ``page_table`` is the slot's ring); ``write``: the chunk's own
+    rows ride in ((k_new, v_new [C, Hkv, D], page_idx [C]);
+    :func:`paged_attention`). Returns [1, C, H, D]; with ``write``, (that,
+    k_pages, v_pages).
 
     On a latent pool every head's rows lie on the ONE KV head, C x H of
     them: more than a query block should hold in VMEM (512 x 32 rows of 640
@@ -1030,7 +1229,8 @@ def paged_chunk_attention(q, k_pages, v_pages, page_table, start, true_len,
             q, k_pages, v_pages, page_table[None], base, limit, layer,
             sm_scale=sm_scale, interpret=interpret,
             name="paged_chunk_attention", block_len=block_len,
-            walk="chunk" in WALKS_LIVE["heads"], window=window)
+            walk="chunk" in WALKS_LIVE["heads"], window=window,
+            write=_with_axis(write, 0))
     _, c, h, _ = q.shape
     span = max(1, _MAX_SPAN_ROWS // h)
     n = c // span if c > span and c % span == 0 else 1

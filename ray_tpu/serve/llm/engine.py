@@ -314,6 +314,12 @@ class LLMEngine:
             bool(self._cache_spec.latent_dim), self._block_len,
             self._windowed) \
             if self._attn_backend == "pallas" else []
+        # and those whose kernel also WRITES the call's own rows of K and
+        # V, which nothing scatters before it (kv_cache._write_read)
+        self._attn_writes_in_kernel = paged_ops.writing_calls(
+            bool(self._cache_spec.latent_dim), self._block_len,
+            self._windowed, self._tp) \
+            if self._attn_backend == "pallas" else []
         # performance introspection (observability/profiling.py): phase
         # timers + ITL ring gate on cfg.profiling_enabled; compile-event
         # tracking is always on (work only on first-dispatch-per-shape).
@@ -1472,6 +1478,7 @@ class LLMEngine:
         out["device_count"] = len(self._devices)
         out["attn_interpret"] = self._attn_interpret
         out["attn_walks_live"] = list(self._attn_walks_live)
+        out["attn_writes_in_kernel"] = list(self._attn_writes_in_kernel)
         out["attn_kernel_compiles"] = self._prof.compile_count(
             ("decode", "verify", "chunk"))
         # tensor-parallel surface (ISSUE 20), stable-key contract: degree

@@ -36,7 +36,11 @@ Design choices:
   (``_MIXERS``), then its feed-forward; a mixer names no program;
 - writes are scatters at (layer, head, page, offset) indices; inactive
   slots write to a reserved trash page (page 0), keeping the step free of
-  dynamic shapes and `lax.cond`s;
+  dynamic shapes and `lax.cond`s. Where the kernel that reads a call's
+  pages back walks them (pools of K and V per head, one chip: ops/
+  paged_attention.py ``writing_calls``), the call's rows ride in that
+  kernel and it writes them (:func:`_write_read`): the same bytes in the
+  same rows, where the rows go decided here all the same;
 - full (non-chunked) prefill stays dense within the prompt: it runs at
   B=1 per admission with no cached prefix to read back;
 - a block that generates by diffusion over blocks (its cache spec states a
@@ -89,6 +93,7 @@ with pages on axis 2) keep their own format, which those modules own.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 import hashlib
@@ -918,7 +923,8 @@ class _Geometry:
     The read, on the pallas backend: ``kernel``, the name of a wrapper of
     ops/paged_attention.py, its ``static`` keywords, and ``operands``, its
     replicated operands between the pools and the layer index ((tables,
-    pos), (tables, seq_lens) or (table, start, kept)). On the gather
+    pos), (tables, seq_lens) or (table, start, kept)); ``writes``, whether
+    that kernel also writes the call's rows (:func:`_geometry`). On the gather
     backend: ``operands[0]``, the page tables, and ``valid``, the mask in
     the grid's form with the keys last (decode has none: its read builds
     the causal mask from ``operands[1]``). ``cfg``, ``value_dim`` (of its
@@ -934,6 +940,7 @@ class _Geometry:
     state: object = None
     attn_backend: str = "gather"
     kernel: str = ""
+    writes: bool = False
     static: dict = dataclasses.field(default_factory=dict)
     operands: tuple = ()
     valid: object = None
@@ -951,7 +958,7 @@ def _keep(a, lone):
     return a if lone is None else a[(slice(None),) * lone + (None,)]
 
 
-def _attend(q, k_pool, v_pool, l, g, value_lanes: int = 0):
+def _attend(q, k_pool, v_pool, l, g, value_lanes: int = 0, write=None):
     """Layer ``l`` of the pool read back for q [B, T, H, D]: THE backend
     switch (the module docstring's first design choice, and on a TP mesh
     its last). ``v_pool`` None and ``value_lanes``: a latent pool, all
@@ -960,7 +967,10 @@ def _attend(q, k_pool, v_pool, l, g, value_lanes: int = 0):
     (``g.lone`` 1) returns [B, H, D], the form its read works in: on the
     gather backend three-axis einsums over the full [B, max_len] view (the
     reference tests/test_paged_kernels.py holds the kernel to; one slot's
-    view, a chunk's, is small, a batch's is why the kernels exist)."""
+    view, a chunk's, is small, a batch's is why the kernels exist).
+    ``write`` (the pallas backend, a call whose ``g.writes`` is set):
+    the call's rows in its grid's form and the pages they go to, which the
+    kernel writes before it reads; returns (that, k_pool, v_pool)."""
     head_dim = g.cfg.head_dim
     sm = head_dim ** -0.5
     one = g.lone == 1
@@ -978,6 +988,8 @@ def _attend(q, k_pool, v_pool, l, g, value_lanes: int = 0):
                 q_rank=q.ndim, n_replicated=len(g.operands) + 1)
             call = jax.shard_map(call, mesh=g.mesh, in_specs=in_specs,
                                  out_specs=out_spec, check_vma=False)
+        if write is not None:
+            return call(q, k_pool, v_pool, *g.operands, l, write=write)
         out = call(q, k_pool, v_pool, *g.operands, l)
         return out[:, None] if one and value_lanes else out
     tables = g.operands[0]
@@ -1088,19 +1100,25 @@ def _write_read(x, kv, q, k, v, ld, l, g, project):
     written to layer ``l`` of the layer's pool first, then read back with
     all that is cached (write-then-read: a call sees earlier calls AND
     itself; a window layer through its ring and no further back than its
-    window), then ``project``, the output projection. Returns (x + mixer,
-    kv)."""
+    window), then ``project``, the output projection. The rows are
+    scattered by :func:`_write_token_kv`, or ride in the kernel that reads
+    them back (``g.writes``; the scope ``kv_write`` then holds no
+    operation). Returns (x + mixer, kv)."""
     (nk, nv), g, scope = _layer_geometry(g, ld, kv["k"].shape[3])
-    with jax.named_scope("kv_write"):
-        k_pool, v_pool = _write_token_kv(
-            kv[nk], kv[nv], l, _drop(k, g.lone), _drop(v, g.lone),
-            g.page_idx, g.offset)
+    k_pool, v_pool, write = kv[nk], kv[nv], None
+    if g.writes:
+        write = (_drop(k, g.lone), _drop(v, g.lone), g.page_idx)
+    else:
+        with jax.named_scope("kv_write"):
+            k_pool, v_pool = _write_token_kv(
+                k_pool, v_pool, l, _drop(k, g.lone), _drop(v, g.lone),
+                g.page_idx, g.offset)
     with jax.named_scope("attn"):
-        if scope is None:
-            read = _attend(q, k_pool, v_pool, l, g)
-        else:       # a trace tells a window layer's read from a full one's
-            with jax.named_scope(scope):
-                read = _attend(q, k_pool, v_pool, l, g)
+        # a trace tells a window layer's read from a full one's
+        with jax.named_scope(scope) if scope else contextlib.nullcontext():
+            read = _attend(q, k_pool, v_pool, l, g, write=write)
+        if write is not None:
+            read, k_pool, v_pool = read
         out = project(read)
         x = x + (out if out.ndim == x.ndim else out[:, None])
     return x, {**kv, nk: k_pool, nv: v_pool}
@@ -1152,11 +1170,24 @@ def _layer(x, kv, layer, ld, l, g):
     return _ffn(x, kv, layer, g.cfg, ld)
 
 
-def _geometry(cfg, attn_backend, mesh, **call) -> _Geometry:
-    """A call's :class:`_Geometry`, what is the same for every call
-    filled in."""
+def _geometry(cfg, attn_backend, mesh, kind: str, **call) -> _Geometry:
+    """The :class:`_Geometry` of a call of kind ``kind`` ("decode",
+    "verify", "block" or "chunk": the names ops/paged_attention.py's tables
+    of calls use), what is the same for every call filled in: the wrapper
+    that reads the call's pages back, and whether its kernel also WRITES
+    the call's rows (``writing_calls``: the calls whose body walks a slot's
+    pages, on pools of K and V per head, on one chip), so that nothing is
+    scattered before it."""
+    spec = block_of(cfg).cache_spec(cfg)
+    writes = False
+    if attn_backend == "pallas":
+        from ray_tpu.ops import paged_attention as paged_ops
+        writes = kind in paged_ops.writing_calls(
+            bool(spec.latent_dim), _block_len(cfg), has_window_layers(cfg),
+            tp_degree(mesh))
     return _Geometry(cfg=cfg, attn_backend=attn_backend, mesh=mesh,
-                     value_dim=block_of(cfg).cache_spec(cfg).value_dim, **call)
+                     value_dim=spec.value_dim,
+                     kernel=f"paged_{kind}_attention", writes=writes, **call)
 
 
 def paged_decode_step(params, kv, page_tables, seq_lens, tokens,
@@ -1183,7 +1214,7 @@ def paged_decode_step(params, kv, page_tables, seq_lens, tokens,
     g = _geometry(cfg, attn_backend, mesh, cos=cos, sin=sin,
                   page_idx=page_idx, offset=offset, lone=1,
                   state=lambda: (page_tables[:, 0], None, None),
-                  kernel="paged_decode_attention", operands=(page_tables, pos))
+                  kind="decode", operands=(page_tables, pos))
     x, kv = _over_layers(_layer, x, kv, params, cfg, g)
     x = blk.serve_final_norm(x, params, cfg)
     return blk.serve_lm_head(x[:, 0], params, cfg), kv, seq_lens + 1
@@ -1351,8 +1382,7 @@ def _span_step(params, kv, page_tables, seq_lens, tokens, cfg, page_size,
     g = _geometry(
         cfg, attn_backend, mesh, cos=cos, sin=sin, page_idx=page_idx,
         offset=offset, operands=(page_tables, seq_lens), valid=valid,
-        kernel="paged_verify_attention" if block_len == 1
-        else "paged_block_attention",
+        kind="verify" if block_len == 1 else "block",
         static={} if block_len == 1 else {"block_len": block_len})
     return _over_layers(_layer, x, kv, params, cfg, g)
 
@@ -1490,7 +1520,7 @@ def paged_prefill_chunk(params, kv, page_table, tokens, start, true_len,
     g = _geometry(
         cfg, attn_backend, mesh, cos=cos, sin=sin, page_idx=page_idx,
         offset=offset, lone=0, operands=(page_table, start, kept),
-        valid=valid, kernel="paged_chunk_attention", static={"block_len": b},
+        valid=valid, kind="chunk", static={"block_len": b},
         state=lambda: (page_table[:1], start == 0, jnp.reshape(
             jnp.clip(true_len - start, 0, c), (1,)).astype(jnp.int32)))
     x, kv = _over_layers(_layer, x, kv, params, cfg, g)

@@ -93,7 +93,7 @@ def test_engine_tokens_are_the_references(params, lengths, max_tokens,
         # asked for by default and not done: prompts longer than a page
         assert st["prefix_bypassed_window"] == sum(n > 8 for n in lengths)
         assert st["prefix_hits"] == 0
-        assert st["attn_walks_live"] == (
+        assert st["attn_walks_live"] == st["attn_writes_in_kernel"] == (
             ["decode", "chunk"] if backend == "pallas" else [])
     finally:
         eng.shutdown()

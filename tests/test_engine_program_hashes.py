@@ -19,7 +19,11 @@ second time so that a rewrite of the file cannot move them unseen: no
 Mistral cell runs a changed program. ISSUE 52 ADDS the block with window
 layers (``afmoe``: ten programs, no verify) and leaves the 42 others as
 they were recorded: that PR's edits of kv_cache.py and of the walking
-kernel lower every one of them to the parent's text.
+kernel lower every one of them to the parent's text. ISSUE 53 records
+again the SIX programs that call the walking body on pools of K and V
+(SDAR's two block programs, Trinity's three decode tiers and its chunk, on
+the pallas backend: their kernel writes the call's rows and nothing is
+scattered before it); ``PARENT_53`` holds the other 46 to the parent's.
 
 A PR that MEANS to change one of these programs rewrites the file and says
 so: ``python tests/test_engine_program_hashes.py`` (from the repo's root).
@@ -196,6 +200,36 @@ def test_a_dense_program_is_recorded_as_the_parent_lowered_it(recorded, name):
     """ISSUE 50 rewrote the routed families' entries; a dense entry that
     moved with them would mean the Mistral cells run another program."""
     assert recorded["programs"][name] == PARENT_DENSE[name]
+
+
+# ISSUE 53: the programs that call the walking body on pools of K and V,
+# whose kernel now writes the call's rows (kv_cache._write_read), were
+# recorded again by that PR's tree ...
+REWRITTEN_53 = {"sdar-pallas-decode_1", "sdar-pallas-decode_2",
+                "afmoe-pallas-decode_1", "afmoe-pallas-decode_4",
+                "afmoe-pallas-decode_8", "afmoe-pallas-chunk_16"}
+# ... and every other entry is commit f0bf47b's (PR 52), letter for letter:
+# a block's (programs, scopes) without those six, as sorted JSON, hashed
+PARENT_53 = {"dense": ("af3af578996588b6", "280cc153121c1f3f"),
+             "lfm2": ("9d40ee4fdcb69a1c", "f6677a7de16ef7bf"),
+             "joyai": ("3abdaec215c28aaa", "db96b92a84c5ddd7"),
+             "sdar": ("2e65f9bf509ffde7", "a7c2bbcd5235aa3d"),
+             "afmoe": ("6d36f42291a7f4f1", "ea6bba6fad1c7e0b")}
+
+
+@pytest.mark.parametrize("block", sorted(PARENT_53))
+def test_only_the_programs_whose_kernel_writes_were_recorded_again(recorded,
+                                                                   block):
+    """The dense, LFM2 and latent programs, SDAR's on the gather backend
+    and its prefill and chunk, Trinity's on the gather backend and its
+    whole prefill lower to the parent's text under the parent's scopes: no
+    Mistral, LFM2 or JoyAI cell runs a changed program, and a rewrite of
+    the file cannot move one unseen."""
+    for key, want in zip(("programs", "scopes"), PARENT_53[block]):
+        others = {k: v for k, v in recorded[key].items()
+                  if k.startswith(block + "-") and k not in REWRITTEN_53}
+        assert hashlib.sha256(json.dumps(others, sort_keys=True).encode()
+                              ).hexdigest()[:16] == want, (block, key)
 
 
 @pytest.mark.parametrize("block,backend,program", CASES,

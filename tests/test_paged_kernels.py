@@ -22,6 +22,7 @@ Pins the PR's acceptance invariants:
   ``_ENGINE_KEYS``.
 """
 
+import functools
 import types
 
 import numpy as np
@@ -614,6 +615,235 @@ def test_the_wrappers_of_a_windowed_block_walk_and_a_short_ring_is_refused():
         paged_ops.paged_chunk_attention(
             q[:1], k_pages, v_pages, tables[0, :4], lens[0], lens[1],
             window=16)
+
+
+# ---------------------------------------------------------------------------
+# the call's own rows ride in the walking body (ISSUE 53)
+# ---------------------------------------------------------------------------
+
+
+def _write_case(name, d=16, page=8, dtype=jnp.float32):
+    """One call whose rows ride in: (wrapper, q, pools [2 layers], the
+    wrapper's operands, its static keywords, k_new, v_new, page_idx,
+    offset, whether some row was sent to the trash page from a place of its
+    own). Every page of both layers of the pools holds values of its own;
+    the call is of layer 1. ``d`` / ``page`` / ``dtype``: the CPU's sizes,
+    or ones a TPU tiles (the builder's chip check)."""
+    rng = np.random.default_rng(sum(map(ord, name)))
+    hkv, n_rep, pack, window, static = 2, 2, 1, None, {}
+    call, lone, redirected = paged_ops.paged_block_attention, None, False
+    if name in ("block", "two_blocks", "packed"):
+        # blocks of 4: one ending its page, one in a table's last page,
+        # an inactive slot (a table of zeros), one deep in its table
+        mp, t = 6, 4 if name == "block" else 8
+        base = [page - 4, mp * page - 4, 0, 3 * page + 4]
+        static = {"block_len": 4}
+        if name == "packed":
+            hkv, pack, d = 4, 2, d // 2
+        # two_blocks: the second block lies in the next page (slot 0) and
+        # past the table (slot 1: the trash page, from a place of its own)
+        redirected = t == 8
+    elif name in ("decode", "decode_window", "decode_groups"):
+        call, lone, t = paged_ops.paged_decode_attention, 1, 1
+        window = 0 if name == "decode" else 2 * page
+        mp = 6 if name == "decode" else 5
+        base = [0, page - 1, 0, 4 * page + 3] if name == "decode" \
+            else [3, 4 * page + 5, 0, 25 * page]
+        static = {"window": window}
+    else:
+        # a chunk of two pages on a ring that wraps under it, ``true_len``
+        # inside the chunk (its padding: the trash page)
+        assert name in ("chunk_ring", "chunk_full"), name
+        call, lone, t = paged_ops.paged_chunk_attention, 0, 2 * page
+        window = 2 * page if name == "chunk_ring" else 0
+        mp = 5 if window else 16
+        base, redirected = [9 * page], True
+        static = {"window": window}
+    b = len(base)
+    tables = 1 + rng.permutation(b * mp).reshape(b, mp).astype(np.int32)
+    if b > 2:
+        tables[2] = 0
+    pools = [jnp.asarray(rng.normal(size=(
+        2, hkv // pack, 1 + b * mp, page, d * pack)), dtype) for _ in "kv"]
+    q = jnp.asarray(rng.normal(size=(b, t, hkv * n_rep, d)), dtype)
+    k_new, v_new = (jnp.asarray(rng.normal(size=(b, t, hkv, d)), dtype)
+                    for _ in "kv")
+    base = np.asarray(base, np.int32)
+    pos = base[:, None] + np.arange(t)[None]
+    entry = pos // page % mp if window else np.minimum(pos // page, mp - 1)
+    page_idx = np.take_along_axis(tables, entry, axis=1)
+    operands = (jnp.asarray(tables), jnp.asarray(base))
+    if lone == 0:
+        true_len = int(base[0]) + t - 3
+        page_idx = np.where(pos < true_len, page_idx, 0)
+        operands = (jnp.asarray(tables[0]), jnp.int32(base[0]),
+                    jnp.int32(true_len))
+    elif not window:
+        page_idx = np.where(pos < mp * page, page_idx, 0)
+    drop = (lambda a: a) if lone is None else (lambda a: a[0]) \
+        if lone == 0 else (lambda a: a[:, 0])
+    return (call, q if lone == 0 else drop(q), pools, operands, static,
+            drop(k_new), drop(v_new), drop(jnp.asarray(page_idx, jnp.int32)),
+            drop(jnp.asarray(pos % page, jnp.int32)), redirected)
+
+
+def _scatter_then_walk(case, layer=1):
+    """(what the call gives with its rows scattered first, with them riding
+    in): each (read, k_pool, v_pool)."""
+    call, q, (k_pool, v_pool), operands, static, k_new, v_new, page_idx, \
+        offset, _ = case
+    k_set, v_set = kv_cache._write_token_kv(k_pool, v_pool, layer, k_new,
+                                            v_new, page_idx, offset)
+    want = call(q, k_set, v_set, *operands, layer, **static)
+    got = call(q, k_pool, v_pool, *operands, layer, **static,
+               write=(k_new, v_new, page_idx))
+    return (want, k_set, v_set), got
+
+
+def _assert_same_bytes(case, want, got):
+    """The read and both pools, every layer: EXACTLY. Only where the
+    caller's rule sent a row to the trash page from a place of its own
+    (which the kernel drops) is the trash page left out."""
+    redirected = case[-1]
+    for name, a, w in zip(("read", "k_pool", "v_pool"), got, want):
+        a, w = np.asarray(a, np.float32), np.asarray(w, np.float32)
+        assert a.shape == w.shape, name
+        if name != "read" and redirected:
+            a, w = a[:, :, 1:], w[:, :, 1:]
+        assert (a == w).all(), (name, np.argwhere(a != w)[:4])
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("name", ["block", "two_blocks", "packed", "decode",
+                                  "decode_window", "chunk_ring",
+                                  "chunk_full"])
+def test_rows_that_ride_in_are_the_scatters_bytes(name, dtype):
+    """"Walk with the write inside" is "``_write_token_kv``, then walk",
+    byte for byte, on the read and on every layer of both pools: a block
+    call of B and of 2B whose second block lies in the next page, heads of
+    64 two to a row, a decode call with and without a lower edge, a chunk
+    on a ring that wraps (and on a growing table) with ``true_len`` inside
+    it, an inactive slot beside live ones. bf16 pools take tiles of 16
+    rows, so their pages are 16."""
+    case = _write_case(name, page=16 if dtype == jnp.bfloat16 else 8,
+                       dtype=dtype)
+    _assert_same_bytes(case, *_scatter_then_walk(case))
+
+
+@pytest.mark.parametrize("name", ["decode_groups", "chunk_ring"])
+def test_rows_ride_in_a_group_of_kv_heads_a_step(name, monkeypatch):
+    """A scratch too small for every KV head (``groups`` 2): each step
+    lays and writes its own heads' rows."""
+    monkeypatch.setattr(paged_ops, "_WALK_KV_BYTES", 1)
+    case = _write_case(name)
+    _assert_same_bytes(case, *_scatter_then_walk(case))
+
+
+def test_rows_ride_in_under_jit_with_a_traced_layer_and_donated_pools():
+    """How a program calls it: inside jit, the layer a traced operand, the
+    pools donated and handed back updated, twice in a row (the second
+    call's rows over the first's)."""
+    case = _write_case("two_blocks")
+    call, q, pools, operands, static, k_new, v_new, page_idx, offset, _ = case
+
+    @functools.partial(jax.jit, donate_argnums=(1, 2))
+    def step(layer, k_pool, v_pool, k_new, v_new):
+        read, k_pool, v_pool = call(q, k_pool, v_pool, *operands, layer,
+                                    **static, write=(k_new, v_new, page_idx))
+        return call(q, k_pool, v_pool, *operands, layer, **static,
+                    write=(v_new, k_new, page_idx)), read
+
+    k_set, v_set = kv_cache._write_token_kv(*pools, 1, k_new, v_new,
+                                            page_idx, offset)
+    first = call(q, k_set, v_set, *operands, 1, **static)
+    k_set, v_set = kv_cache._write_token_kv(k_set, v_set, 1, v_new, k_new,
+                                            page_idx, offset)
+    want = (call(q, k_set, v_set, *operands, 1, **static), k_set, v_set)
+    got, read = step(jnp.int32(1), jnp.copy(pools[0]), jnp.copy(pools[1]),
+                     k_new, v_new)
+    assert (np.asarray(read) == np.asarray(first)).all()
+    _assert_same_bytes(case, want, got)
+
+
+@pytest.mark.parametrize("name", ["block", "decode_window", "chunk_ring"])
+def test_a_page_the_call_does_not_write_keeps_its_bytes(name):
+    """Outside the pages the rows go to (and the trash page), both pools
+    are what they were: every other page of the call's layer, every page
+    of the other layer."""
+    case = _write_case(name)
+    pools, page_idx = case[2], np.asarray(case[7])
+    _, (_, k_pool, v_pool) = _scatter_then_walk(case)
+    other = np.setdiff1d(np.arange(pools[0].shape[2]),
+                         np.append(page_idx.ravel(), 0))
+    assert other.size
+    for was, now in zip(pools, (k_pool, v_pool)):
+        was, now = np.asarray(was), np.asarray(now)
+        assert (was[0] == now[0]).all()
+        assert (was[1][:, other] == now[1][:, other]).all()
+        assert (was[1] != now[1]).any()
+
+
+@pytest.mark.parametrize("name", ["two_blocks", "chunk_ring", "chunk_full"])
+def test_the_trash_page_after_dropped_rows_is_its_old_bytes_or_an_idle_slots(
+        name):
+    """THE CONTRACT of a row the caller's rule sent to the trash page from
+    a place of its own (a block past the table, a chunk's padding): the
+    kernel drops it, where the scatter wrote it to page 0. So page 0 is the
+    one place the pools may differ from the parent's, and what it holds is
+    finite: a row of it is the bytes it had, or the row an INACTIVE slot (a
+    table of zeros, whose rows go there from their own place) wrote at its
+    position's offset. Nothing but an inactive slot reads it."""
+    case = _write_case(name)
+    _, _, pools, operands, _, k_new, v_new, _, offset, redirected = case
+    assert redirected
+    _, got = _scatter_then_walk(case)
+    tables = np.asarray(operands[0])
+    tables = tables.reshape(-1, tables.shape[-1])
+    offset = np.asarray(offset).reshape(tables.shape[0], -1)
+    for was, now, new in zip(pools, got[1:], (k_new, v_new)):
+        was, now = np.asarray(was), np.asarray(now)
+        assert np.isfinite(now[:, :, 0]).all()
+        assert (was[0, :, 0] == now[0, :, 0]).all()     # the other layer
+        old = (was[1, :, 0] == now[1, :, 0]).all(-1)    # [heads, offsets]
+        # the rows in the pool's form: [slots, T, heads, lanes]
+        new = np.asarray(new).reshape(*offset.shape, *was.shape[1::3])
+        for slot in np.flatnonzero(~tables.any(axis=1)):
+            for t, at in enumerate(offset[slot]):
+                old[:, at] |= (now[1, :, 0, at] == new[slot, t]).all(-1)
+        assert old.all(), np.argwhere(~old)[:4]
+
+
+@pytest.mark.parametrize("module", [paged_ops, kv_cache],
+                         ids=lambda m: m.__name__.rsplit(".", 1)[-1])
+def test_no_top_level_name_is_defined_twice(module):
+    """A later ``def`` of a name silently replaces the earlier one: a
+    spliced-in second copy of a kernel body would run in place of the one
+    the text describes (PR 53's review)."""
+    import ast
+    import collections
+    import inspect
+    names = collections.Counter(
+        node.name for node in ast.parse(inspect.getsource(module)).body
+        if isinstance(node, (ast.FunctionDef, ast.ClassDef)))
+    assert [name for name, n in names.items() if n > 1] == []
+
+
+def test_the_calls_that_walk_on_pools_of_k_and_v_write_their_rows():
+    """``writing_calls``: what ``_geometry`` asks for ``_write_read`` and
+    what an engine reports as ``attn_writes_in_kernel``. The grid body, a
+    latent pool and a tensor-parallel mesh keep the scatter; the grid body
+    refuses rows."""
+    assert paged_ops.writing_calls(False, block_len=4) == ["block"]
+    assert paged_ops.writing_calls(False, windowed=True) == [
+        "decode", "chunk"]
+    assert paged_ops.writing_calls(False) == []
+    assert paged_ops.writing_calls(True) == []
+    assert paged_ops.writing_calls(False, block_len=4, tp=2) == []
+    case = _write_case("decode")
+    call, q, pools, operands, _, k_new, v_new, page_idx, _, _ = case
+    with pytest.raises(ValueError, match="only the walking body"):
+        call(q, *pools, *operands, 1, write=(k_new, v_new, page_idx))
 
 
 # ---------------------------------------------------------------------------

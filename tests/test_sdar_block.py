@@ -18,7 +18,7 @@ import numpy as np
 import pytest
 
 from benchmark import common
-from ray_tpu.models import llama, sdar_moe
+from ray_tpu.models import joyai, llama, sdar_moe
 from ray_tpu.ops import paged_attention as paged_ops
 from ray_tpu.parallel import expert
 from ray_tpu.serve.llm import LLMConfig, LLMEngine, disagg
@@ -146,17 +146,23 @@ def test_the_walking_kernel_reads_no_page_past_a_slots_live_ones(
                                atol=1e-5)
 
 
-@pytest.mark.parametrize("model,backend,want", [
-    (CFG, "pallas", ["block"]), (CFG, "gather", []),
-    (llama.llama_tiny(vocab_size=512), "pallas", [])],
-    ids=["sdar-pallas", "sdar-gather", "dense-pallas"])
-def test_attn_walks_live_names_the_calls_that_walk(model, backend, want):
+@pytest.mark.parametrize("model,backend,want,writes", [
+    (CFG, "pallas", ["block"], ["block"]), (CFG, "gather", [], []),
+    (llama.llama_tiny(vocab_size=512), "pallas", [], []),
+    (joyai.joyai_tiny(), "pallas", ["decode", "verify", "chunk"], [])],
+    ids=["sdar-pallas", "sdar-gather", "dense-pallas", "latent-pallas"])
+def test_attn_walks_live_names_the_calls_that_walk(model, backend, want,
+                                                   writes):
     """``attn_walks_live``: the call kinds of THIS engine's programs whose
     kernel body walks live pages, so that ``attn_live_pages_total /
-    attn_table_pages_total`` is read only where it applies."""
+    attn_table_pages_total`` is read only where it applies; and
+    ``attn_writes_in_kernel``, those whose kernel also writes the call's
+    own rows of K and V (ISSUE 53: the calls that walk on pools of K and V
+    per head; a dense and a latent engine scatter every call's)."""
     eng = LLMEngine(LLMConfig(model_config=model,
                               **{**ENGINE, "attention_kernel": backend}))
     assert eng.engine_stats()["attn_walks_live"] == want
+    assert eng.engine_stats()["attn_writes_in_kernel"] == writes
 
 
 def test_pallas_and_gather_backends_give_one_block_pass(params):
