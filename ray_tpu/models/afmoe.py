@@ -41,7 +41,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models.block import CacheSpec, LayerDef
+from ray_tpu.models.block import CacheSpec, LayerDef, head_major
 from ray_tpu.models.joyai import _normal
 from ray_tpu.models.llama import apply_rope, rms_norm
 from ray_tpu.parallel import expert as expert_mod
@@ -212,6 +212,14 @@ def rope_freqs(cfg: AfmoeConfig, positions):
     return jnp.cos(ang), jnp.sin(ang)
 
 
+def serve_params(params, cfg: AfmoeConfig):
+    """wq, wk, wv and wg of every layer head-major, [H, D, hd] (``wq_hm``
+    ...; models/block.py ``head_major``)."""
+    return {**params, "layers": [
+        {**lp, "attn": head_major(lp["attn"], ("wq", "wk", "wv", "wg"))}
+        for lp in params["layers"]]}
+
+
 def serve_embed(params, tokens, cfg: AfmoeConfig):
     x = params["embed"][tokens]
     if cfg.mup:
@@ -226,16 +234,16 @@ def serve_gated_qkv(x, layer, cos, sin, cfg: AfmoeConfig, ld: LayerDef):
     with jax.named_scope("norm"):
         h = rms_norm(x, layer["attn_norm"], cfg.norm_eps)
     with jax.named_scope("attn"):
-        q = jnp.einsum("btd,dhk->bthk", h, a["wq"])
-        k = jnp.einsum("btd,dhk->bthk", h, a["wk"])
-        v = jnp.einsum("btd,dhk->bthk", h, a["wv"])
+        q = jnp.einsum("btd,hdk->bthk", h, a["wq_hm"])
+        k = jnp.einsum("btd,hdk->bthk", h, a["wk_hm"])
+        v = jnp.einsum("btd,hdk->bthk", h, a["wv_hm"])
         q = rms_norm(q, a["q_norm"], cfg.norm_eps)
         k = rms_norm(k, a["k_norm"], cfg.norm_eps)
         if ld.window:
             q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
     with jax.named_scope("gate"):
         gate = jax.nn.sigmoid(jnp.einsum(
-            "btd,dhk->bthk", h, a["wg"],
+            "btd,hdk->bthk", h, a["wg_hm"],
             preferred_element_type=jnp.float32)).astype(x.dtype)
     return q, k, v, gate
 

@@ -7,6 +7,18 @@ that can be served provides, at module level:
 
     init_params(key, cfg), load_params(path, cfg)
     check_tp_divides(cfg, tp), serve_partition_rules()
+    serve_params(params, cfg) -> params in the block's SERVED FORM
+        ``init_params`` / ``load_params`` give the tree as a checkpoint
+        lays it; the ``serve_*`` functions below read this form of it.
+        Idempotent (served in, served out), the same leaves under names
+        that tell the two forms apart, so that the engine applies it once
+        as it takes its weights and every paged program of kv_cache.py
+        once more on entry: nothing for the engine's tree, the conversion
+        inside the program for a caller that hands it a checkpoint's.
+        A block that keeps K and V a head of a whole lane tile holds each
+        ``[in, H, hd]`` projection of its mixer HEAD-MAJOR
+        (:func:`head_major`); one that has nothing to re-lay returns its
+        tree.
     cache_spec(cfg) -> CacheSpec     what the cache manager must hold
     serve_layers(cfg) -> None | tuple[LayerDef, ...]
         None: every layer is the same, ``params["layers"]`` is one stacked
@@ -163,6 +175,37 @@ class LayerDef:
     state_layer: int = -1
     routed_layer: int = -1
     window: int = 0
+
+
+HEAD_MAJOR = "_hm"
+
+
+def head_major(attn: dict, names) -> dict:
+    """``attn`` with each projection of ``names`` (``[.., in, H, hd]``,
+    stacked or not) held head-major, ``[.., H, in, hd]``, under its name +
+    ``HEAD_MAJOR``: what ``einsum("btd,hdk->bthk")`` reads in place. On a
+    v5e the compiler reads ``[in, H, hd]`` under ``"btd,dhk->bthk"``
+    through a copy with the heads outermost, every execution, or every
+    layer of every step of a scanned stack (PERF.md section 6, PR 54). A
+    name that is not there is already served."""
+    import jax.numpy as jnp
+    out = dict(attn)
+    for name in names:
+        if name in out:
+            out[name + HEAD_MAJOR] = jnp.swapaxes(out.pop(name), -3, -2)
+    return out
+
+
+def head_major_nbytes(params) -> dict:
+    """{leaf name: bytes over the layers} of the leaves that
+    :func:`head_major` made (the engine's ``weights_head_major``)."""
+    import jax
+    out = {}
+    for path, leaf in jax.tree_util.tree_leaves_with_path(params):
+        name = str(getattr(path[-1], "key", ""))
+        if name.endswith(HEAD_MAJOR):
+            out[name] = out.get(name, 0) + int(leaf.nbytes)
+    return out
 
 
 def gqa_expand(k, n_rep):

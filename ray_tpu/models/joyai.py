@@ -216,6 +216,12 @@ def cache_spec(cfg: JoyaiConfig) -> CacheSpec:
         value_dim=cfg.kv_rank)
 
 
+def serve_params(params, cfg: JoyaiConfig):
+    """As the checkpoint lays it: the latent mixer's projections (3 % of
+    a step, PERF.md ``latent_proj_share.joyai``) keep their layout."""
+    return params
+
+
 def serve_layers(cfg: JoyaiConfig) -> tuple:
     """Every layer keeps a latent row; layer i owns row i of the pool, a
     routed one row i - n_dense of the routing record."""

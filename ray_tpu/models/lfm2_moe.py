@@ -182,6 +182,12 @@ def cache_spec(cfg: Lfm2MoeConfig) -> CacheSpec:
         n_experts=cfg.n_experts)
 
 
+def serve_params(params, cfg: Lfm2MoeConfig):
+    """As the checkpoint lays it: a head-major row of this block's heads
+    of 64 would fill half a lane tile (models/block.py ``head_major``)."""
+    return params
+
+
 def serve_layers(cfg: Lfm2MoeConfig) -> tuple:
     out, pages, states, routed = [], 0, 0, 0
     for i, kind in enumerate(cfg.layer_types):

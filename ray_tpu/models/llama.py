@@ -25,7 +25,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ray_tpu.models.block import CacheSpec, gqa_expand
+from ray_tpu.models.block import CacheSpec, gqa_expand, head_major
 
 
 @dataclasses.dataclass(frozen=True)
@@ -175,6 +175,7 @@ def serve_partition_rules():
     groups, so per-head attention math never crosses a shard."""
     from jax.sharding import PartitionSpec as P
     return (
+        (r"layers/attn/w[qkv]_hm$", P(None, "tensor", None, None)),
         (r"layers/attn/w[qkv]$", P(None, None, "tensor", None)),
         (r"layers/attn/wo$", P(None, "tensor", None, None)),
         (r"layers/mlp/w_(gate|up)$", P(None, None, "tensor")),
@@ -242,18 +243,27 @@ def serve_layers(cfg: LlamaConfig):
     return None
 
 
+def serve_params(params, cfg: LlamaConfig):
+    """wq, wk and wv of the stacked layers head-major, [L, H, D, hd]
+    (``wq_hm`` ...; models/block.py ``head_major``)."""
+    layers = params["layers"]
+    return {**params, "layers": {
+        **layers, "attn": head_major(layers["attn"], _QKV)}}
+
+
 def serve_embed(params, tokens, cfg: LlamaConfig):
     return params["embed"][tokens].astype(cfg.dtype)
 
 
 def serve_qkv(x, layer, cos, sin, cfg: LlamaConfig):
     """Pre-attention norm, the q/k/v projections and RoPE. x: [B,T,D]."""
+    a = layer["attn"]
     with jax.named_scope("norm"):
         h = rms_norm(x, layer["attn_norm"], cfg.norm_eps)
     with jax.named_scope("attn"):
-        q = jnp.einsum("btd,dhk->bthk", h, layer["attn"]["wq"])
-        k = jnp.einsum("btd,dhk->bthk", h, layer["attn"]["wk"])
-        v = jnp.einsum("btd,dhk->bthk", h, layer["attn"]["wv"])
+        q = jnp.einsum("btd,hdk->bthk", h, a["wq_hm"])
+        k = jnp.einsum("btd,hdk->bthk", h, a["wk_hm"])
+        v = jnp.einsum("btd,hdk->bthk", h, a["wv_hm"])
         return apply_rope(q, cos, sin), apply_rope(k, cos, sin), v
 
 

@@ -50,7 +50,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models.block import CacheSpec, LayerDef
+from ray_tpu.models.block import CacheSpec, LayerDef, head_major
 from ray_tpu.models.llama import apply_rope, rms_norm, rope_freqs  # noqa: F401
 from ray_tpu.parallel import expert as expert_mod
 
@@ -178,6 +178,14 @@ def serve_layers(cfg: SdarMoeConfig) -> tuple:
                           routed_layer=i) for i in range(cfg.n_layers))
 
 
+def serve_params(params, cfg: SdarMoeConfig):
+    """wq, wk and wv of every layer head-major, [H, D, hd] (``wq_hm`` ...;
+    models/block.py ``head_major``)."""
+    return {**params, "layers": [
+        {**lp, "attn": head_major(lp["attn"], ("wq", "wk", "wv"))}
+        for lp in params["layers"]]}
+
+
 def serve_embed(params, tokens, cfg: SdarMoeConfig):
     return params["embed"][tokens].astype(cfg.dtype)
 
@@ -188,9 +196,9 @@ def serve_qkv(x, layer, cos, sin, cfg: SdarMoeConfig):
     with jax.named_scope("norm"):
         h = rms_norm(x, layer["attn_norm"], cfg.norm_eps)
     with jax.named_scope("attn"):
-        q = jnp.einsum("btd,dhk->bthk", h, a["wq"])
-        k = jnp.einsum("btd,dhk->bthk", h, a["wk"])
-        v = jnp.einsum("btd,dhk->bthk", h, a["wv"])
+        q = jnp.einsum("btd,hdk->bthk", h, a["wq_hm"])
+        k = jnp.einsum("btd,hdk->bthk", h, a["wk_hm"])
+        v = jnp.einsum("btd,hdk->bthk", h, a["wv_hm"])
         q = rms_norm(q, a["q_norm"], cfg.norm_eps)
         k = rms_norm(k, a["k_norm"], cfg.norm_eps)
         return apply_rope(q, cos, sin), apply_rope(k, cos, sin), v

@@ -182,7 +182,7 @@ class LLMEngine:
         import jax
         import jax.numpy as jnp
 
-        from ray_tpu.models.block import block_of
+        from ray_tpu.models.block import block_of, head_major_nbytes
         from ray_tpu.serve.llm import kv_cache as kvc
 
         self.cfg = cfg
@@ -268,7 +268,12 @@ class LLMEngine:
             else:
                 params = self._block.init_params(
                     jax.random.PRNGKey(rng_seed), self.model_cfg)
-        self.params = params
+        # the block's served form (models/block.py ``serve_params``), made
+        # once, a leaf at a time, and the checkpoint's form let go before
+        # the pool is allocated: no program re-lays a weight again
+        self.params = self._block.serve_params(params, self.model_cfg)
+        del params
+        self._weights_head_major = head_major_nbytes(self.params)
 
         b = cfg.max_batch_size
         self.max_pages_per_seq = -(-cfg.max_seq_len // cfg.page_size)
@@ -1479,6 +1484,9 @@ class LLMEngine:
         out["attn_interpret"] = self._attn_interpret
         out["attn_walks_live"] = list(self._attn_walks_live)
         out["attn_writes_in_kernel"] = list(self._attn_writes_in_kernel)
+        # the projections held head-major ({leaf: bytes over the layers};
+        # empty: the block serves its weights as the checkpoint lays them)
+        out["weights_head_major"] = dict(self._weights_head_major)
         out["attn_kernel_compiles"] = self._prof.compile_count(
             ("decode", "verify", "chunk"))
         # tensor-parallel surface (ISSUE 20), stable-key contract: degree
@@ -2661,11 +2669,14 @@ class LLMEngine:
         Steady-state decode is ONE jitted call with all-device arguments
         (page tables, seq lens, temps, last tokens, rng all live on device;
         slot admissions patch them with one small jitted update). A block
-        of k steps pays a dispatch, the state gather / scatter and the
-        compiler's re-layout of wq / wk / wv once for k tokens a slot and
-        is k steps of lead over the device (on a v5e, Mistral-7B at depth
-        16 and widths 4-8, a step costs 12.49 ms in blocks of 8, 13.35 in
-        blocks of 2 and 13.08 alone: PERF.md section 6, PR 42). An
+        of k steps pays a dispatch and the state gather / scatter once for
+        k tokens a slot and is k steps of lead over the device. No
+        program re-lays a projection out any more (the served form,
+        models/block.py ``serve_params``: before ISSUE 54 a block of k
+        also spread the compiler's copy of wq / wk / wv over its k steps,
+        and on a v5e, Mistral-7B at depth 16 and widths 4-8, a step cost
+        12.49 ms in blocks of 8, 13.35 in blocks of 2 and 13.08 alone:
+        PERF.md section 6, PR 42 and PR 54). An
         idle-tier dispatch tells the rule that sizes that tier whether
         the device had run dry (lead.py).
 

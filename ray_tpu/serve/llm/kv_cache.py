@@ -754,8 +754,13 @@ def _over_layers(step, x, kv, params, cfg, *operands):
     CARRY: the only ``xs`` are the layer parameters and the layer index,
     and there are no ``ys``. One whose layers differ in kind is walked in
     order (``ld`` its :class:`LayerDef`, ``l`` its row of the pool), each
-    layer's weights read where they lie."""
-    layers = block_of(cfg).serve_layers(cfg)
+    layer's weights read where they lie. ``params`` as a checkpoint lays
+    them or in the block's served form (``serve_params``, idempotent: the
+    engine's tree is served already and nothing is made here; a caller
+    that hands a checkpoint's pays the conversion inside its program)."""
+    blk = block_of(cfg)
+    params = blk.serve_params(params, cfg)
+    layers = blk.serve_layers(cfg)
     if layers is None:
         def body(carry, inputs):
             x, k_pool, v_pool = carry

@@ -370,7 +370,7 @@ def test_a_window_layer_rotates_and_a_full_layer_does_not(params):
     far = afmoe.rope_freqs(CFG, jnp.arange(100, 105)[None])
     near = afmoe.rope_freqs(CFG, jnp.arange(5)[None])
     for ld, moves in ((lds[0], True), (lds[3], False)):
-        layer = params["layers"][lds.index(ld)]
+        layer = afmoe.serve_params(params, CFG)["layers"][lds.index(ld)]
         q0, k0, v0, g0 = afmoe.serve_gated_qkv(x, layer, *near, CFG, ld)
         q1, k1, v1, g1 = afmoe.serve_gated_qkv(x, layer, *far, CFG, ld)
         assert (float(jnp.abs(q0 - q1).max()) > 1e-3) == moves

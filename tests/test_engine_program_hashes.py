@@ -24,6 +24,11 @@ again the SIX programs that call the walking body on pools of K and V
 (SDAR's two block programs, Trinity's three decode tiers and its chunk, on
 the pallas backend: their kernel writes the call's rows and nothing is
 scattered before it); ``PARENT_53`` holds the other 46 to the parent's.
+ISSUE 54 records again the THIRTY programs of the dense block, afmoe and
+SDAR (their q / k / v / gate projections are held head-major and read by
+``"btd,hdk->bthk"``: the text's parameter shapes and that einsum's name among
+the scopes move, no equation is added or taken away) and leaves the
+twenty-two of LFM2 and JoyAI to the parent's text and scopes.
 
 A PR that MEANS to change one of these programs rewrites the file and says
 so: ``python tests/test_engine_program_hashes.py`` (from the repo's root).
@@ -166,39 +171,43 @@ def test_program_lowers_to_the_recorded_text(recorded, block, backend,
         "tests/data/engine_program_hashes.json (this file, run as a script)")
 
 
-# the dense block's programs as commit c3e050f (PR 48) lowered them
+# the dense block's programs as ISSUE 54's tree lowered them (until then
+# commit c3e050f's, PR 48): ``wq_hm`` / ``wk_hm`` / ``wv_hm`` [L, H, D, hd]
+# under ``"btd,hdk->bthk"``, nothing else of the text moved
 PARENT_DENSE = {
     "dense-gather-chunk_16":
-        "34522c381ce66b8eacae53f6e9db1ba3d23aea1988454ce4043021282cefaabb",
+        "2b2f6ba7595da43054ae09b247b64e792a2ed5367d52f39cd5d63cf38bb86e57",
     "dense-gather-decode_1":
-        "68a2fb4b2fa91770adcdce54e4223207e9ac76bb4fa2c2549d90569cbbc0a6d1",
+        "ef76820ef20329918e4a99e7cac28a26299d611961e6fc3f8fa49edc7a077b39",
     "dense-gather-decode_4":
-        "3538c58aaf3505d991f53ea776a2e3c1fb9361ea56c7963a7c10292b32f6d012",
+        "35ce70162fac9f4c2e1434243dfe3a1d42bf00291cfb2ff6d00dd73049879fac",
     "dense-gather-decode_8":
-        "ea99c6d11700e73d66dc0557dcd196ac0e7609504d388536135097987ae06d1b",
+        "df74eabae488f13b777d3cf040d1528deaa0a61cac10d843cfbc543abf058134",
     "dense-gather-prefill_32":
-        "1dd3bb5702898dca585b4ceb937338da2d441885b009b3cc11fee052474c9e32",
+        "4f2c18d4e7663d6562abfd793e412cbb6cc4e635114ae0ffcfe422e2d795124d",
     "dense-gather-verify":
-        "e719aa1b4a1d1276a982594dbf027e7819266c2136fb0b25ed4fabe05d7d5707",
+        "c43f030ed3315c8bf7e011c33b21b9a0c1e18c8a76654a7bcf016605b20bc4ef",
     "dense-pallas-chunk_16":
-        "8ff8ba71ba004989423c7eab1626690e4e999c49d1ddb70f81e169827cafa0a7",
+        "d1566d6d905ff94019524b3cdee83e3f9fa137ff24a37be36c135ceda05edb90",
     "dense-pallas-decode_1":
-        "53b0c0a68c1199db22c7eebcf37489df9a57b5bfadfb7d2d16665d7365b272d1",
+        "16ac56ffd7a76c580e6916e59b372c633da1f48810c8f38a0c88a2026c7b8e5b",
     "dense-pallas-decode_4":
-        "76a987d42d0a1d7f6b50912c2e77f8fe562ed7b4ef20af28df7849e228d80aad",
+        "418ead806944b131cfb5ad2790ebf3d230b8f28fbeb6cad6f5afc2335a08eace",
     "dense-pallas-decode_8":
-        "65c8a46f937628d1c7ee0a6ee7de79f34e377f79b0f69aa01dec95dd1aa125a4",
+        "d5e10eb75cbad1f7d569dd3497f2e82e00bf4664864ab10a7b665326879f980e",
     "dense-pallas-prefill_32":
-        "1dd3bb5702898dca585b4ceb937338da2d441885b009b3cc11fee052474c9e32",
+        "4f2c18d4e7663d6562abfd793e412cbb6cc4e635114ae0ffcfe422e2d795124d",
     "dense-pallas-verify":
-        "fdcff01d80ef193e2f548e4c0cdbfb6c2093c1f3d77d4f950430272f7f3adf62",
+        "e7a06a0d750b8038a59b75330fe6e388b1af658ccbef3e05e8f3600ddb5b6c34",
 }
 
 
 @pytest.mark.parametrize("name", sorted(PARENT_DENSE))
 def test_a_dense_program_is_recorded_as_the_parent_lowered_it(recorded, name):
     """ISSUE 50 rewrote the routed families' entries; a dense entry that
-    moved with them would mean the Mistral cells run another program."""
+    moved with them would mean the Mistral cells run another program.
+    ISSUE 54 MEANT to move them (the served projections head-major) and
+    pinned what its tree lowered."""
     assert recorded["programs"][name] == PARENT_DENSE[name]
 
 
@@ -210,11 +219,16 @@ REWRITTEN_53 = {"sdar-pallas-decode_1", "sdar-pallas-decode_2",
                 "afmoe-pallas-decode_8", "afmoe-pallas-chunk_16"}
 # ... and every other entry is commit f0bf47b's (PR 52), letter for letter:
 # a block's (programs, scopes) without those six, as sorted JSON, hashed
-PARENT_53 = {"dense": ("af3af578996588b6", "280cc153121c1f3f"),
+# ISSUE 54 (the attention projections of the dense block, afmoe and SDAR
+# head-major) recorded those three blocks' programs again, every one: their
+# rows are that PR's tree's, the einsum's own name (``btd,hdk->bthk``) the
+# only scope that moved; the LFM2 and JoyAI rows are STILL f0bf47b's, which
+# is the test that those two cells bypass the change
+PARENT_53 = {"dense": ("7915703ca84ad9bf", "1d580735eeb2efa6"),
              "lfm2": ("9d40ee4fdcb69a1c", "f6677a7de16ef7bf"),
              "joyai": ("3abdaec215c28aaa", "db96b92a84c5ddd7"),
-             "sdar": ("2e65f9bf509ffde7", "a7c2bbcd5235aa3d"),
-             "afmoe": ("6d36f42291a7f4f1", "ea6bba6fad1c7e0b")}
+             "sdar": ("a276d9c95613b27d", "664acd450d35de00"),
+             "afmoe": ("fb165ad26588ac5e", "e7005cf1a4e73aa1")}
 
 
 @pytest.mark.parametrize("block", sorted(PARENT_53))

@@ -88,7 +88,10 @@ def test_engine_greedy_matches_reference_loop():
     from ray_tpu.serve.llm import LLMEngine
 
     cfg = _tiny_cfg(max_tokens=6)
-    eng = LLMEngine(cfg, rng_seed=0)
+    # the weights as a checkpoint lays them: what ``forward`` reads (the
+    # engine keeps its own tree in the block's served form)
+    params = llama.init_params(jax.random.PRNGKey(0), cfg.model())
+    eng = LLMEngine(cfg, params=params)
     eng.start()
     try:
         out = eng.generate("abc")
@@ -100,7 +103,7 @@ def test_engine_greedy_matches_reference_loop():
         expect = []
         for _ in range(len(toks)):
             logits = llama.forward(
-                eng.params, jnp.asarray([seq], jnp.int32), mcfg)
+                params, jnp.asarray([seq], jnp.int32), mcfg)
             nxt = int(np.argmax(np.asarray(logits[0, -1])))
             expect.append(nxt)
             seq.append(nxt)

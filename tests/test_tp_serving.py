@@ -91,10 +91,11 @@ def test_tp_engine_state_is_sharded():
         k = eng.kv["k"]
         assert k.sharding.shard_shape(k.shape)[1] == k.shape[1] // 2
         assert k.sharding.shard_shape(k.shape)[2] == k.shape[2]
-        # Megatron weight split: wq [L, D, H, hd] column-parallel on H,
-        # wo [L, H, hd, D] row-parallel, norms replicated
-        wq = eng.params["layers"]["attn"]["wq"]
-        assert wq.sharding.shard_shape(wq.shape)[2] == wq.shape[2] // 2
+        # Megatron weight split: wq, served head-major [L, H, D, hd],
+        # column-parallel on H, wo [L, H, hd, D] row-parallel, norms
+        # replicated
+        wq = eng.params["layers"]["attn"]["wq_hm"]
+        assert wq.sharding.shard_shape(wq.shape)[1] == wq.shape[1] // 2
         wo = eng.params["layers"]["attn"]["wo"]
         assert wo.sharding.shard_shape(wo.shape)[1] == wo.shape[1] // 2
         fn = eng.params["final_norm"]
@@ -112,6 +113,40 @@ def test_tp_engine_state_is_sharded():
         assert st["free_pages"] == eng.allocator.available()
     finally:
         eng.shutdown()
+
+
+@pytest.mark.parametrize("leaf,form,axis", [
+    ("wq", "served", 1), ("wk", "served", 1), ("wv", "served", 1),
+    ("wq", "checkpoint", 2), ("wk", "checkpoint", 2),
+    ("wv", "checkpoint", 2), ("wo", "served", 1)])
+def test_tp_rules_shard_a_projection_on_its_head_axis(leaf, form, axis):
+    """``serve_partition_rules`` follow the served leaves (ISSUE 54):
+    ``wq_hm`` / ``wk_hm`` / ``wv_hm`` [L, H, D, hd] split on H, axis 1; a
+    tree as a checkpoint lays it ([L, D, H, hd], what a caller of the paged
+    programs may still hand them) keeps its split on axis 2; ``wo`` [L, H,
+    hd, D] is the same leaf in both forms."""
+    import jax
+
+    from ray_tpu.models.block import HEAD_MAJOR
+    from ray_tpu.parallel import sharding as shd
+    from ray_tpu.parallel.mesh import MeshSpec, build_mesh
+
+    cfg = llama.llama_tiny(vocab_size=512)
+    params = jax.eval_shape(
+        lambda: llama.init_params(jax.random.PRNGKey(0), cfg))
+    if form == "served":
+        params = jax.eval_shape(lambda p: llama.serve_params(p, cfg), params)
+        leaf += HEAD_MAJOR if leaf != "wo" else ""
+    mesh = build_mesh(MeshSpec(tensor=2), jax.devices()[:2])
+    sh = shd.rule_shardings(llama.serve_partition_rules(), params, mesh)
+    want = [None] * 4
+    want[axis] = "tensor"
+    spec = tuple(sh["layers"]["attn"][leaf].spec)   # (trailing Nones cut)
+    assert spec + (None,) * (4 - len(spec)) == tuple(want)
+    shape = params["layers"]["attn"][leaf].shape
+    assert shape[axis] in (cfg.n_heads, cfg.n_kv_heads)
+    assert sh["layers"]["attn"][leaf].shard_shape(shape)[axis] \
+        == shape[axis] // 2
 
 
 def test_tp1_builds_no_mesh_and_default_namespace():
