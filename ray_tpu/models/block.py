@@ -35,6 +35,14 @@ that can be served provides, at module level:
     serve_gated_out(attn, gate, layer, cfg) -> the mixer's output, before
         the residual (``attn`` and ``gate`` with or without the token axis,
         alike)
+    serve_sink_qkv(x, layer, cos, sin, cfg, ld) -> q, k, v, sink
+        (sink mixer: attention whose projections read the layer's
+        definition, so that a layer kind may rotate by its own angles
+        (``cos`` / ``sin`` are whatever ``rope_freqs`` gave) and keep its own
+        number of KV heads, and whose softmax may hold a learned SINK:
+        ``sink`` float32 [H], one logit a query head that joins the
+        denominator and weighs no value, or None for a layer without; its
+        output goes through ``serve_attn_out``)
     serve_latent(x, layer, cos, sin, cfg) -> q, entry    (latent mixer: its
         cache spec states ``latent_dim``) ``entry`` [B, T, latent_dim] is
         the ONE row a token leaves in the cache, and ``q`` [B, T, H,
@@ -68,7 +76,9 @@ states only its geometry (where this call's rows go, how it reads the cache
 back) and no mixer names a program. "attn" (``serve_qkv``,
 ``serve_attn_out``) writes K and V a head, then reads the layer back;
 "gated" (``serve_gated_qkv``, ``serve_gated_out``) is "attn" with a gate on
-what it read; "latent" (``serve_latent``, ``serve_latent_out``) writes the
+what it read; "sink" (``serve_sink_qkv``, ``serve_attn_out``) is "attn"
+that is told its layer's definition and may hand the read a learned sink,
+one more column of the softmax that weighs no value; "latent" (``serve_latent``, ``serve_latent_out``) writes the
 one row and reads it back in the absorbed form; "conv" (``serve_conv``) keeps its state
 in the row of the sequence's first page: a call that starts a sequence
 reads zeros instead, and the row keeps the state as of the call's last real
@@ -87,7 +97,8 @@ context (the window, the widest call's span and a page), position p lies in
 entry ``(p // page) % ring`` of the slot's ring table, and a page whose last
 token has left every future query's window is written again. Window layers
 have a pool and a table of their own (``kw`` / ``vw``, the tail of the page
-table); full layers keep the growing table. Every paged read of a block
+table), which may hold another number of KV heads than the full layers'
+(``CacheSpec.window_kv_heads``); full layers keep the growing table. Every paged read of a block
 that has window layers runs the walking body with a lower edge (ops/
 paged_attention.py), a full layer's edge 0. Prefix reuse, the kv tier,
 speculation and disaggregated hand-off all assume that a page, once
@@ -127,7 +138,10 @@ class CacheSpec:
     """What a block keeps between calls, a sequence.
 
     ``paged_layers`` layers write K and V of ``n_kv_heads`` x ``head_dim``
-    a token into pages; or, with ``latent_dim`` above 0, ONE row of
+    a token into pages (with ``value_dim`` above 0 and no ``latent_dim``
+    the VALUE rows are ``value_dim`` wide, not ``head_dim``: two pools of
+    two widths, the key rows stored on whole 128-lane vectors); or, with
+    ``latent_dim`` above 0, ONE row of
     ``latent_dim`` lanes a token (``n_kv_heads`` 1), of which the first
     ``value_dim`` are also the values: the pool is then one array, a
     latent (compressed) cache. ``state_layers`` layers keep one array of
@@ -138,9 +152,10 @@ class CacheSpec:
     (``n_experts``: the experts whose rows THIS replica multiplies, the
     first of the router's where it holds a share: what the engine's counts
     of experts touched run over).
-    ``window_layers`` more layers write K and V of the same heads into a
+    ``window_layers`` more layers write K and V of the same widths into a
     ring of pages a slot and see the last ``window`` tokens only (the
-    module docstring's "a window layer").
+    module docstring's "a window layer"), of ``window_kv_heads`` KV heads
+    (0: ``n_kv_heads``, as the full layers).
     ``block_length``: 1 = a step yields a token a sequence; B above 1 = the
     block generates by diffusion over blocks of B positions, of which a
     not yet revealed one holds ``mask_token`` (never produced)."""
@@ -158,23 +173,26 @@ class CacheSpec:
     value_dim: int = 0
     window: int = 0
     window_layers: int = 0
+    window_kv_heads: int = 0
 
 
 @dataclasses.dataclass(frozen=True)
 class LayerDef:
     """One layer of a block whose layers differ: its mixer ("attn" |
-    "gated" | "conv" | "latent": a key of kv_cache.py's ``_MIXERS``, the
-    module docstring's "a mixer kind"), its feed-forward kind ("dense" |
-    "routed"), which row of the pool, of the slot state and of the
-    routing record is its own, and its window (0: a full layer, whose row
-    is the growing pool's; above 0: a window layer, whose row is the ring
-    pool's)."""
+    "gated" | "sink" | "conv" | "latent": a key of kv_cache.py's
+    ``_MIXERS``, the module docstring's "a mixer kind"), its feed-forward
+    kind ("dense" | "routed"), which row of the pool, of the slot state and
+    of the routing record is its own, its window (0: a full layer, whose
+    row is the growing pool's; above 0: a window layer, whose row is the
+    ring pool's), and whether its softmax holds a learned sink (a "sink"
+    mixer's ``serve_sink_qkv`` then returns one)."""
     mixer: str
     ffn: str
     page_layer: int = -1
     state_layer: int = -1
     routed_layer: int = -1
     window: int = 0
+    sink: bool = False
 
 
 HEAD_MAJOR = "_hm"

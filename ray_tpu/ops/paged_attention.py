@@ -53,7 +53,14 @@ option or a rule on shapes:
   that every other call's program runs before its read
   (serve/llm/kv_cache.py ``_write_token_kv``: one row of a packed tile an
   index, 50 ns a row) is not run; the pools are then outputs aliased to
-  the inputs. For blocks without a window the block program is the
+  the inputs. The pools of such a block need not be of one width nor
+  its layers of one KV-head count (ISSUE 55): the key pool's rows may be
+  wider than the value pool's (keys of 192 lanes stored on 256, values of
+  128: the body's shapes follow each pool's own), a layer's KV heads are
+  its pool's, and a layer's softmax may hold a learned SINK a query head
+  (``sink=``: the running maximum starts at it, the row sum at ``exp(sink
+  - maximum)``, and its column weighs no value). For blocks without a
+  window the block program is the
   one caller whose gain the benchmark could judge (ROADMAP S3; PERF.md
   section 6, PR 48); the body takes every call shape of the family
   (tests/test_paged_kernels.py drives them), so the other three wrappers
@@ -196,17 +203,23 @@ def _write_tile(dtype, page_size: int) -> int:
 
 
 def can_tile(head_dim: int, page_size: int, dtype,
-             n_kv_heads: int = 2) -> bool:
-    """Shapes Mosaic compiles these kernels for: head_dim fills whole
-    128-lane vectors, or is the half vector of 64 lanes with an even
-    number of KV heads (``n_kv_heads``: a chip's share of them), which the
-    pool then stores two to a 128-lane row, so that a page block's last
-    dimension is whole vectors and HBM holds no padding (an odd count
+             n_kv_heads: int = 2, value_dim: int = 0) -> bool:
+    """Shapes Mosaic compiles these kernels for, of the rows THE POOL
+    STORES: ``head_dim`` lanes fill whole 128-lane vectors (a key row of
+    192 lanes is stored on 256, the padding zeros: serve/llm/kv_cache.py
+    ``key_lanes``, and is asked about at 256), or are the half vector of
+    64 lanes with an even number of KV heads (``n_kv_heads``: a chip's
+    share of them), which the pool then stores two to a 128-lane row, so
+    that a page block's last dimension is whole vectors (an odd count
     cannot be packed, and a 64-lane pool is re-laid out by the compiler
-    around every token write); and a page is whole sublane tiles, so the
-    page copy into scratch and both matmuls stay tile-aligned."""
+    around every token write); a value pool of its own width
+    (``value_dim`` above 0) whole vectors too; and a page is whole sublane
+    tiles, so the page copy into scratch and both matmuls stay
+    tile-aligned."""
     return (head_dim % 128 == 0
-            or (head_dim == 64 and n_kv_heads % 2 == 0)) \
+            or (head_dim == 64 and n_kv_heads % 2 == 0
+                and not value_dim)) \
+        and value_dim % 128 == 0 \
         and page_size % sublane_tile(dtype) == 0
 
 
@@ -307,7 +320,7 @@ def paged_attention(q, k_pages, v_pages, page_tables, base, limit=None,
                     interpret: bool | None = None,
                     name: str = "paged_attention", block_len: int = 1,
                     walk: bool = False, window: int | None = None,
-                    write=None):
+                    write=None, sink=None):
     """Fused paged attention over the whole query span.
 
     q: [B, T, H, D] — query position of q[:, t] is ``base + t`` (causal
@@ -343,23 +356,35 @@ def paged_attention(q, k_pages, v_pages, page_tables, base, limit=None,
     would have (:func:`_gqa_walk_kernel`; a row whose page is not its
     position's in the table, which the caller's rule sent to the trash
     page, is dropped) and reads them back with the rest.
-    Returns [B, T, H, D] in q.dtype; with ``write``, (that, k_pages,
+    A VALUE POOL NARROWER THAN THE KEY POOL (the walking body only; keys of
+    192 lanes stored on 256 beside values of 128, models/block.py
+    ``CacheSpec.value_dim``): q and ``k_new`` come with the key pool's
+    lanes (the padding zeros, which meet zeros), ``v_new`` with the value
+    pool's, and the result has the value pool's lanes.
+    sink (the walking body only): float32 [H], a learned logit a query head
+    that joins the softmax's denominator and weighs no value
+    (:func:`_gqa_walk_kernel`); None: the plain softmax.
+    Returns [B, T, H, Dv] in q.dtype; with ``write``, (that, k_pages,
     v_pages), the pools updated in place.
     """
     b, t, h, d = q.shape
     if k_pages.ndim == 4:
         k_pages, v_pages, layer = k_pages[None], v_pages[None], 0
     if write is not None:       # the pool's own rows (heads of 64: two a row)
-        rows = (b, t, k_pages.shape[1], k_pages.shape[4])
-        write = (write[0].reshape(rows), write[1].reshape(rows), write[2])
+        write = tuple(new.reshape(b, t, pool.shape[1], pool.shape[4])
+                      for new, pool in zip(write, (k_pages, v_pages))) \
+            + (write[2],)
     if k_pages.shape[4] != d:
         return _packed_heads(
             q, k_pages, v_pages, page_tables, base, limit, layer,
             sm_scale=d ** -0.5 if sm_scale is None else sm_scale,
             interpret=interpret, name=name, block_len=block_len, walk=walk,
-            window=window, write=write)
-    if write is not None and not (walk or window is not None):
-        raise ValueError("only the walking body writes the call's rows")
+            window=window, write=write, sink=sink)
+    if (write is not None or sink is not None
+            or v_pages.shape[4] != k_pages.shape[4]) \
+            and not (walk or window is not None):
+        raise ValueError("only the walking body writes the call's rows, "
+                         "takes a sink or reads pools of two widths")
     if walk or window is not None:
         if limit is None:   # the table's span; a ring's positions pass it
             limit = jnp.full((b,), 2 ** 30 if window else
@@ -368,7 +393,7 @@ def paged_attention(q, k_pages, v_pages, page_tables, base, limit=None,
         return _gqa_walk_call(
             q, k_pages, v_pages, page_tables.astype(jnp.int32),
             base.astype(jnp.int32), limit.astype(jnp.int32),
-            jnp.reshape(layer, (1,)).astype(jnp.int32), write,
+            jnp.reshape(layer, (1,)).astype(jnp.int32), write, sink,
             sm_scale=float(d ** -0.5 if sm_scale is None else sm_scale),
             interpret=interpret_default() if interpret is None
             else interpret, name=name, block_len=block_len, window=window)
@@ -675,7 +700,7 @@ def _gqa_walk_kernel(pt_ref, base_ref, limit_ref, layer_ref,  # prefetch
                      *refs, sm_scale: float, page_size: int,
                      max_pages: int, chunk_pages: int, t_span: int,
                      row_tile: int, block_len: int, window: int,
-                     groups: int, writes: bool = False):
+                     groups: int, writes: bool = False, sink: int = 0):
     """Grid (B,): one grid step a slot, every KV head of the call walked
     inside it, whose work follows the slot's LIVE length: the end of the
     block that holds its last query position, ``min(limit, ((base + t_span
@@ -743,12 +768,29 @@ def _gqa_walk_kernel(pt_ref, base_ref, limit_ref, layer_ref,  # prefetch
     slot's pages or other KV heads' rows, so no read meets a write but on
     the trash page, whose values are finite and whose readers' outputs
     nobody uses.
+
+    KEY ROWS WIDER THAN VALUE ROWS (the pools' own lanes: q_ref, k_scr and
+    ``kn_ref`` have the key pool's, v_scr, ``vn_ref``, acc_scr and o_ref
+    the value pool's): nothing but the shapes differs.
+
+    A SINK (``sink`` R above 0, static: the query heads a KV head; one more
+    ref behind the call's rows, ``sink_ref`` [Hkv, >= R, 128] float32, row
+    rep of KV head g the learned logit of query head ``g * R + rep`` on
+    every lane): the softmax's denominator holds ``exp(sink)`` beside the
+    visible keys' exponentials, and the sink's column weighs no value. The
+    running maximum starts at the sink and the row sum at ``exp(sink -
+    maximum)``; the weights of a row then sum to less than 1.
     """
-    if writes:
-        (pidx_ref, q_ref, kn_ref, vn_ref, _, _, o_ref, k_pool, v_pool,
-         k_scr, v_scr, s_scr, acc_scr, sems, wsems) = refs
+    refs = list(refs)
+    pidx_ref = refs.pop(0) if writes else None
+    q_ref = refs.pop(0)
+    kn_ref, vn_ref = (refs.pop(0), refs.pop(0)) if writes else (None, None)
+    sink_ref = refs.pop(0) if sink else None
+    if writes:      # the pools as they came in: they ARE the outputs
+        del refs[:2]
+        o_ref, k_pool, v_pool, k_scr, v_scr, s_scr, acc_scr, sems, wsems = refs
     else:
-        q_ref, k_pool, v_pool, o_ref, k_scr, v_scr, s_scr, acc_scr, sems = refs
+        k_pool, v_pool, o_ref, k_scr, v_scr, s_scr, acc_scr, sems = refs
     step = pl.program_id(0)
     half = step % 2
     b = step if groups == 1 else step // groups
@@ -756,6 +798,7 @@ def _gqa_walk_kernel(pt_ref, base_ref, limit_ref, layer_ref,  # prefetch
     limit = limit_ref[b]
     chunk = chunk_pages * page_size
     hkv, r_pad, d = q_ref.shape        # the KV heads of one step
+    dv = v_scr.shape[-1]               # a value row's lanes (a key row's: d)
 
     def pages_of(slot):
         end = base_ref[slot] + t_span
@@ -816,7 +859,7 @@ def _gqa_walk_kernel(pt_ref, base_ref, limit_ref, layer_ref,  # prefetch
 
     def zero(j, carry):
         v_scr[half, :, page_rows(j), :] = jnp.zeros(
-            (hkv, page_size, d), v_scr.dtype)
+            (hkv, page_size, dv), v_scr.dtype)
         return carry
 
     jax.lax.fori_loop(live_pages, live_chunks * chunk_pages, zero, None)
@@ -857,13 +900,22 @@ def _gqa_walk_kernel(pt_ref, base_ref, limit_ref, layer_ref,  # prefetch
 
         def lay(tile, carry):
             p0, page, cols = tile_at(tile)
-            at = jax.lax.broadcasted_iota(jnp.int32, (sub, d), 0)
-            keep = jnp.zeros((sub, d), jnp.int32)
-            for r in range(sub):        # the rows the caller's rule kept here
-                t = p0 + r - base
-                kept = (t >= 0) & (t < t_span) & (
-                    pidx_ref[b * t_span + jnp.clip(t, 0, t_span - 1)] == page)
-                keep = jnp.where(at == r, kept.astype(jnp.int32), keep)
+
+            def kept_rows(lanes):
+                """(the sublane of each element of a [sub, lanes] tile, 1
+                where the caller's rule kept that row here)."""
+                at = jax.lax.broadcasted_iota(jnp.int32, (sub, lanes), 0)
+                keep = jnp.zeros((sub, lanes), jnp.int32)
+                for r in range(sub):
+                    t = p0 + r - base
+                    kept = (t >= 0) & (t < t_span) & (pidx_ref[
+                        b * t_span + jnp.clip(t, 0, t_span - 1)] == page)
+                    keep = jnp.where(at == r, kept.astype(jnp.int32), keep)
+                return at, keep
+
+            masks = {d: kept_rows(d)}
+            if dv != d:
+                masks[dv] = kept_rows(dv)
             shift = base - tile0 * sub      # the sublane of the span's first row
             m = tile - tile0
 
@@ -872,6 +924,7 @@ def _gqa_walk_kernel(pt_ref, base_ref, limit_ref, layer_ref,  # prefetch
                            :].astype(jnp.float32)
 
             for ref, scr in ((kn_ref, k_scr), (vn_ref, v_scr)):
+                at, keep = masks[scr.shape[-1]]
                 # row r of the tile is row ``sub * m - shift + r`` of the call:
                 # sublane r - shift of the rows' tile m (which the leading
                 # tile of zeros makes tile m + 1), or, below ``shift``,
@@ -933,9 +986,14 @@ def _gqa_walk_kernel(pt_ref, base_ref, limit_ref, layer_ref,  # prefetch
             s_scr[c] = s
             return jnp.maximum(m, s.max(axis=-1, keepdims=True))
 
-        m = jax.lax.fori_loop(
-            0, live_chunks, scores,
-            jnp.full((hkv, row_tile, 1), _NEG_INF, jnp.float32))
+        lowest = jnp.full((hkv, row_tile, 1), _NEG_INF, jnp.float32)
+        if sink:    # each row's own head's sink: row r = rep * t_span + t
+            rep = (r0 + jax.lax.broadcasted_iota(
+                jnp.int32, (row_tile, 1), 0)) // t_span
+            for j in range(sink):
+                lowest = jnp.where((rep == j)[None],
+                                   sink_ref[:, j:j + 1, :][:, :, :1], lowest)
+        m = jax.lax.fori_loop(0, live_chunks, scores, lowest)
 
         def sums(c, total):
             e = jnp.exp(s_scr[c] - m)
@@ -944,7 +1002,8 @@ def _gqa_walk_kernel(pt_ref, base_ref, limit_ref, layer_ref,  # prefetch
 
         total = jax.lax.fori_loop(
             0, live_chunks, sums,
-            jnp.zeros((hkv, row_tile, 1), jnp.float32))
+            jnp.exp(lowest - m) if sink
+            else jnp.zeros((hkv, row_tile, 1), jnp.float32))
 
         acc_scr[...] = jnp.zeros(acc_scr.shape, acc_scr.dtype)
 
@@ -974,8 +1033,8 @@ def _gqa_walk_kernel(pt_ref, base_ref, limit_ref, layer_ref,  # prefetch
 @functools.partial(jax.jit, static_argnames=(
     "sm_scale", "interpret", "name", "block_len", "window"))
 def _gqa_walk_call(q, k_pages, v_pages, page_tables, base, limit, layer,
-                   write=None, *, sm_scale, interpret, name, block_len,
-                   window=None):
+                   write=None, sink=None, *, sm_scale, interpret, name,
+                   block_len, window=None):
     """The walking body's call on pools of K and V per head, every operand
     as :func:`paged_attention` has prepared it. Jitted with the layer an
     OPERAND, as the latent body's call is and for its reason: a block
@@ -990,8 +1049,13 @@ def _gqa_walk_call(q, k_pages, v_pages, page_tables, base, limit, layer,
     ``write`` (k_new, v_new [B, T, Hkv, D] in the pool's row form, page_idx
     [B, T]): the call's own rows ride in and the body writes them (its
     docstring); the new operands join this ``jit``, the pools are outputs
-    aliased to the inputs, and the call returns (read, k_pages, v_pages)."""
+    aliased to the inputs, and the call returns (read, k_pages, v_pages).
+
+    The pools' rows need not be of one width: q and ``k_new`` have the key
+    pool's lanes, ``v_new`` and the read the value pool's. ``sink`` (float32
+    [H], a learned logit a query head): the body's "a sink"."""
     b, t, h, d = q.shape
+    dv = v_pages.shape[4]
     hkv = k_pages.shape[1]
     n_rep = h // hkv
     page_size = k_pages.shape[3]
@@ -1014,7 +1078,7 @@ def _gqa_walk_call(q, k_pages, v_pages, page_tables, base, limit, layer,
         # a score tile holds every KV head's rows
         r_pad, row_tile = _row_tiling(r, hkv * n_chunks * chunk, q.dtype)
     else:
-        head_bytes = 4 * n_chunks * chunk * d \
+        head_bytes = 2 * n_chunks * chunk * (d + dv) \
             * jnp.dtype(k_pages.dtype).itemsize
         groups = next(g for g in range(1, hkv + 1) if hkv % g == 0
                       and (hkv // g * head_bytes <= _WALK_KV_BYTES
@@ -1030,16 +1094,17 @@ def _gqa_walk_call(q, k_pages, v_pages, page_tables, base, limit, layer,
 
     # both halves of both scratches + double-buffered q/o blocks + the row
     # tile's scores, accumulator and their temporaries, with headroom
-    vmem = (4 * hs * n_chunks * chunk * d
+    vmem = (2 * hs * n_chunks * chunk * (d + dv)
             * jnp.dtype(k_pages.dtype).itemsize
-            + 4 * hs * r_pad * d * isz
+            + 2 * hs * r_pad * (d + dv) * isz
             + 4 * hs * row_tile * n_chunks * chunk * 4
-            + 4 * hs * row_tile * d * 4)
+            + 4 * hs * row_tile * dv * 4)
     kernel = functools.partial(
         _gqa_walk_kernel, sm_scale=sm_scale, page_size=page_size,
         max_pages=max_pages, chunk_pages=chunk_pages, t_span=t,
         row_tile=row_tile, block_len=block_len, window=window or 0,
-        groups=groups, writes=write is not None)
+        groups=groups, writes=write is not None,
+        sink=0 if sink is None else n_rep)
     pools = (k_pages, v_pages)
 
     def rows_of(bi, *_):
@@ -1048,7 +1113,7 @@ def _gqa_walk_call(q, k_pages, v_pages, page_tables, base, limit, layer,
         return (bi, 0, 0, 0) if groups == 1 \
             else (bi // groups, bi % groups, 0, 0)
 
-    scalars, blocked = (page_tables, base, limit, layer), [(qg, r_pad)]
+    scalars, blocked = (page_tables, base, limit, layer), [qg]
     if write is not None:
         k_new, v_new, page_idx = write
         sub = _write_tile(k_pages.dtype, page_size)
@@ -1057,11 +1122,23 @@ def _gqa_walk_call(q, k_pages, v_pages, page_tables, base, limit, layer,
         # behind them up to whole tiles
         t_pad = ((t + sub - 2) // sub + 2) * sub
         scalars += (page_idx.reshape(-1).astype(jnp.int32),)
-        blocked += [(jnp.pad(new.astype(pool.dtype).transpose(0, 2, 1, 3),
-                             ((0, 0), (0, 0), (sub, t_pad - sub - t),
-                              (0, 0))), t_pad)
+        blocked += [jnp.pad(new.astype(pool.dtype).transpose(0, 2, 1, 3),
+                            ((0, 0), (0, 0), (sub, t_pad - sub - t), (0, 0)))
                     for new, pool in ((k_new, k_pages), (v_new, v_pages))]
-        vmem += 4 * hs * t_pad * d * jnp.dtype(k_pages.dtype).itemsize
+        vmem += 2 * hs * t_pad * (d + dv) * jnp.dtype(k_pages.dtype).itemsize
+    in_specs = [pl.BlockSpec((None, hs) + a.shape[2:], rows_of)
+                for a in blocked]
+    if sink is not None:
+        # [H] -> [1, Hkv, query heads a KV head (whole sublane tiles), 128]:
+        # the same for every slot, a step's group of KV heads of it
+        rows = -(-n_rep // 8) * 8
+        blocked.append(jnp.broadcast_to(jnp.pad(
+            sink.astype(jnp.float32).reshape(hkv, n_rep),
+            ((0, 0), (0, rows - n_rep)))[None, :, :, None],
+            (1, hkv, rows, 128)))
+        in_specs.append(pl.BlockSpec(
+            (None, hs, rows, 128),
+            lambda bi, *_: (0, 0 if groups == 1 else bi % groups, 0, 0)))
     n_in = len(scalars) + len(blocked)
     written = pools if write is not None else ()    # the outputs beside o
     out, *written = pl.pallas_call(
@@ -1069,19 +1146,19 @@ def _gqa_walk_call(q, k_pages, v_pages, page_tables, base, limit, layer,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(scalars),
             grid=(b * groups,),
-            in_specs=[pl.BlockSpec((None, hs, rows, d), rows_of)
-                      for _, rows in blocked]
+            in_specs=in_specs
             + [pl.BlockSpec(memory_space=pl.ANY) for _ in pools],
-            out_specs=[pl.BlockSpec((None, hs, r_pad, d), rows_of)]
+            out_specs=[pl.BlockSpec((None, hs, r_pad, dv), rows_of)]
             + [pl.BlockSpec(memory_space=pl.ANY) for _ in written],
             scratch_shapes=[
-                pltpu.VMEM((2, hs, n_chunks * chunk, d), pool.dtype)
+                pltpu.VMEM((2, hs, n_chunks * chunk, pool.shape[4]),
+                           pool.dtype)
                 for pool in pools] + [
                 pltpu.VMEM((n_chunks, hs, row_tile, chunk), jnp.float32),
-                pltpu.VMEM((hs, row_tile, d), jnp.float32),
+                pltpu.VMEM((hs, row_tile, dv), jnp.float32),
                 pltpu.SemaphoreType.DMA((2, n_chunks)),
             ] + ([pltpu.SemaphoreType.DMA((2,))] if written else [])),
-        out_shape=[jax.ShapeDtypeStruct((b, hkv, r_pad, d), q.dtype)]
+        out_shape=[jax.ShapeDtypeStruct((b, hkv, r_pad, dv), q.dtype)]
         + [jax.ShapeDtypeStruct(pool.shape, pool.dtype) for pool in written],
         # the pools are written where they lie
         input_output_aliases={n_in + n: 1 + n for n in range(len(written))},
@@ -1092,15 +1169,15 @@ def _gqa_walk_call(q, k_pages, v_pages, page_tables, base, limit, layer,
                                  _WALK_VMEM_LIMIT)),
         interpret=interpret,
         name=name,
-    )(*scalars, *(a for a, _ in blocked), *pools)
-    out = out[:, :, :r].reshape(b, hkv, n_rep, t, d).transpose(
-        0, 3, 1, 2, 4).reshape(b, t, h, d)
+    )(*scalars, *blocked, *pools)
+    out = out[:, :, :r].reshape(b, hkv, n_rep, t, dv).transpose(
+        0, 3, 1, 2, 4).reshape(b, t, h, dv)
     return (out, *written) if write is not None else out
 
 
 def _packed_heads(q, k_pages, v_pages, page_tables, base, limit, layer, *,
                   sm_scale, interpret, name, block_len=1, walk=False,
-                  window=None, write=None):
+                  window=None, write=None, sink=None):
     """Heads narrower than a pool row: the pool holds ``pack`` KV heads
     side by side in one row of lanes ([L, Hkv / pack, P, page, pack * D]:
     heads of 64 two to a 128-lane row, so HBM holds no padding and a page
@@ -1110,7 +1187,8 @@ def _packed_heads(q, k_pages, v_pages, page_tables, base, limit, layer, *,
     (the other head's lanes meet zeros, which add nothing: the same
     float32 sums), and of the output row, which is over both heads'
     values, the same lanes are kept. ``write``: the span's new rows are
-    pool rows already, and pass through."""
+    pool rows already, and pass through; so does ``sink``, a number a
+    query head in the same order."""
     b, t, h, d = q.shape
     rows = k_pages.shape[1]
     pack = k_pages.shape[4] // d
@@ -1123,7 +1201,8 @@ def _packed_heads(q, k_pages, v_pages, page_tables, base, limit, layer, *,
     out = paged_attention(spread, k_pages, v_pages, page_tables, base, limit,
                           layer, sm_scale=sm_scale, interpret=interpret,
                           name=name, block_len=block_len, walk=walk,
-                          window=window, write=write)       # [B, T, H, pack*D]
+                          window=window, write=write,       # [B, T, H, pack*D]
+                          sink=sink)
     out, *written = out if write is not None else (out,)
     out = out.reshape(b, t, rows, pack, n_rep, pack, d)
     out = jnp.stack([out[:, :, :, j, :, j] for j in range(pack)],
@@ -1141,13 +1220,13 @@ def paged_decode_attention(q, k_pages, v_pages, page_tables, pos,
                            layer=None, *, sm_scale: float | None = None,
                            interpret: bool | None = None,
                            value_lanes: int = 0, window: int | None = None,
-                           write=None):
+                           write=None, sink=None):
     """Single-token decode attention: q [B, H, D], new token at position
     ``pos[b]`` (attends 0..pos inclusive — its own k/v is already written
     to the pool, or rides in as ``write``: (k_new, v_new [B, Hkv, D],
     page_idx [B])). Pool, ``layer``, ``window`` (the lower edge of a block
-    that has window layers: it walks) and ``write`` as in
-    :func:`paged_attention`. Returns [B, H, D]; with ``write``, (that,
+    that has window layers: it walks), ``write`` and ``sink`` as in
+    :func:`paged_attention`. Returns [B, H, Dv]; with ``write``, (that,
     k_pages, v_pages)."""
     if value_lanes:
         return paged_latent_attention(
@@ -1158,7 +1237,7 @@ def paged_decode_attention(q, k_pages, v_pages, page_tables, pos,
                           layer=layer, sm_scale=sm_scale, interpret=interpret,
                           name="paged_decode_attention",
                           walk="decode" in WALKS_LIVE["heads"], window=window,
-                          write=_with_axis(write, 1))
+                          write=_with_axis(write, 1), sink=sink)
     return out[:, 0] if write is None else (out[0][:, 0], *out[1:])
 
 
@@ -1205,7 +1284,7 @@ def paged_chunk_attention(q, k_pages, v_pages, page_table, start, true_len,
                           layer=None, *, sm_scale: float | None = None,
                           interpret: bool | None = None,
                           block_len: int = 1, value_lanes: int = 0,
-                          window: int | None = None, write=None):
+                          window: int | None = None, write=None, sink=None):
     """Chunked-prefill attention for ONE slot: q [1, C, H, D] chunk whose
     first token sits at position ``start``; keys are the slot's whole
     paged view (earlier chunks + this one, pre-written) bounded by
@@ -1213,8 +1292,8 @@ def paged_chunk_attention(q, k_pages, v_pages, page_table, start, true_len,
     lower edge of a block that has window layers (it walks, and under a
     window ``page_table`` is the slot's ring); ``write``: the chunk's own
     rows ride in ((k_new, v_new [C, Hkv, D], page_idx [C]);
-    :func:`paged_attention`). Returns [1, C, H, D]; with ``write``, (that,
-    k_pages, v_pages).
+    :func:`paged_attention`, as ``sink``). Returns [1, C, H, Dv]; with
+    ``write``, (that, k_pages, v_pages).
 
     On a latent pool every head's rows lie on the ONE KV head, C x H of
     them: more than a query block should hold in VMEM (512 x 32 rows of 640
@@ -1230,7 +1309,7 @@ def paged_chunk_attention(q, k_pages, v_pages, page_table, start, true_len,
             sm_scale=sm_scale, interpret=interpret,
             name="paged_chunk_attention", block_len=block_len,
             walk="chunk" in WALKS_LIVE["heads"], window=window,
-            write=_with_axis(write, 0))
+            write=_with_axis(write, 0), sink=sink)
     _, c, h, _ = q.shape
     span = max(1, _MAX_SPAN_ROWS // h)
     n = c // span if c > span and c % span == 0 else 1

@@ -325,6 +325,11 @@ class LLMEngine:
             bool(self._cache_spec.latent_dim), self._block_len,
             self._windowed, self._tp) \
             if self._attn_backend == "pallas" else []
+        # and those whose kernel takes a learned SINK of some layer of the
+        # block (models/block.py ``LayerDef.sink``): the calls that walk
+        self._attn_sink_calls = list(self._attn_walks_live) if any(
+            ld.sink for ld in self._block.serve_layers(self.model_cfg) or ()
+        ) else []
         # performance introspection (observability/profiling.py): phase
         # timers + ITL ring gate on cfg.profiling_enabled; compile-event
         # tracking is always on (work only on first-dispatch-per-shape).
@@ -1484,6 +1489,9 @@ class LLMEngine:
         out["attn_interpret"] = self._attn_interpret
         out["attn_walks_live"] = list(self._attn_walks_live)
         out["attn_writes_in_kernel"] = list(self._attn_writes_in_kernel)
+        out["attn_sink_calls"] = list(self._attn_sink_calls)
+        # {pool: [a head's lanes, the lanes stored for it]}: the padding
+        out["pool_lanes"] = self._kvc.pool_lanes(self.model_cfg, self.kv)
         # the projections held head-major ({leaf: bytes over the layers};
         # empty: the block serves its weights as the checkpoint lays them)
         out["weights_head_major"] = dict(self._weights_head_major)
@@ -1503,10 +1511,11 @@ class LLMEngine:
             f"{a}={n}" for a, n in dict(self._mesh.shape).items()
             if n > 1))
         pool_bytes = self._kvc.pool_nbytes(self.kv)
-        # what a cached token costs the pool, all layers (padding lanes
-        # included: a latent cache of 576 numbers a layer in rows of 640)
-        out["kv_bytes_per_token"] = pool_bytes // (
-            self.cfg.num_pages * self.cfg.page_size)
+        # what a cached token costs the pool, all layers, each pool at its
+        # own heads and stored lanes (padding lanes included: a latent
+        # cache of 576 numbers a layer in rows of 640; a window layer's
+        # row counts like a full layer's)
+        out["kv_bytes_per_token"] = self._kvc.token_nbytes(self.kv)
         out["kv_shard_pool_bytes"] = pool_bytes // self._tp
         out["kv_shard_page_occupancy"] = (
             (self.cfg.num_pages - free) * pool_bytes

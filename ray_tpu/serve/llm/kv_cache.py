@@ -68,6 +68,12 @@ Design choices:
   :func:`_layer_geometry` gives each layer its table, its place for the
   call's rows and its lower edge; every paged read of such a block is the
   walking body's (``window=``) or, on the gather backend, the ring's mask;
+  the window layers may keep another number of KV heads than the full
+  ones, the value rows may be narrower than the key rows (which are then
+  stored on whole 128-lane vectors, :func:`key_lanes`), and a layer's
+  softmax may hold a learned sink (the mixer kind "sink"): the pools'
+  shapes, :func:`_write_read`'s padding and :func:`_dense_attention`'s
+  extra column carry these, no program does;
 - tensor parallelism (ISSUE 20): every step function takes an optional
   ``mesh``. With a live "tensor" axis the pool is sharded per-KV-head
   (axis 1) and the q heads split into exactly the matching kv-head
@@ -149,7 +155,16 @@ def init_paged_cache(cfg, num_pages: int, page_size: int, tp: int = 1,
     the layers that keep a window, [window_layers, n_kv_heads,
     window_pages, page_size, head_dim]: a pool of its own, sized by the
     rings (slots x :func:`ring_pages` and the trash page), not by
-    ``max_seq_len``; ``k`` / ``v`` then hold the full layers alone.
+    ``max_seq_len``; ``k`` / ``v`` then hold the full layers alone. The
+    window layers may keep another number of KV heads than the full ones
+    (the spec's ``window_kv_heads``).
+
+    VALUE ROWS NARROWER THAN KEY ROWS (the spec states ``value_dim`` and no
+    ``latent_dim``): the key pools' rows are ``head_dim`` lanes rounded up
+    to whole 128-lane vectors (:func:`key_lanes`: 192 -> 256, the padding
+    zeros for ever, as a latent pool's), the value pools' ``value_dim``:
+    ``k`` [paged_layers, n_kv_heads, num_pages, page_size, key lanes], ``v``
+    [.., value_dim], ``kw`` / ``vw`` alike at their own heads.
 
     ``state`` (a block with slot state only): one array [num_pages,
     prod(state_shape)] a layer that keeps state (flat: a [2, D] row would
@@ -170,19 +185,20 @@ def init_paged_cache(cfg, num_pages: int, page_size: int, tp: int = 1,
         kv = {"k": jnp.zeros((spec.paged_layers, 1, num_pages, page_size,
                               latent_lanes(spec.latent_dim)), cfg.dtype)}
     else:
-        heads, lanes = pool_heads_lanes(spec.n_kv_heads, spec.head_dim, tp)
-        shape = (spec.paged_layers, heads, num_pages, page_size, lanes)
-        kv = {"k": jnp.zeros(shape, cfg.dtype),
-              "v": jnp.zeros(shape, cfg.dtype)}
+        heads, *lanes = pool_rows(spec, spec.n_kv_heads, tp)
+        kv = {n: jnp.zeros((spec.paged_layers, heads, num_pages, page_size,
+                            w), cfg.dtype) for n, w in zip("kv", lanes)}
         if spec.window_layers:
             if window_pages < 2:
                 raise ValueError(
                     f"the block has {spec.window_layers} window layers: "
                     f"their pool needs window_pages (a trash page and a "
                     f"ring a slot), got {window_pages}")
-            ring = (spec.window_layers, heads, window_pages, page_size, lanes)
-            kv["kw"] = jnp.zeros(ring, cfg.dtype)
-            kv["vw"] = jnp.zeros(ring, cfg.dtype)
+            heads, *lanes = pool_rows(
+                spec, spec.window_kv_heads or spec.n_kv_heads, tp)
+            for n, w in zip(("kw", "vw"), lanes):
+                kv[n] = jnp.zeros((spec.window_layers, heads, window_pages,
+                                   page_size, w), cfg.dtype)
     if spec.state_layers:
         kv["state"] = tuple(
             jnp.zeros((num_pages, int(np.prod(spec.state_shape))), cfg.dtype)
@@ -206,6 +222,27 @@ def pool_heads_lanes(n_kv_heads: int, head_dim: int,
     if head_dim == 64 and n_kv_heads % (2 * tp) == 0:
         return n_kv_heads // 2, 128
     return n_kv_heads, head_dim
+
+
+def pool_rows(spec, n_kv_heads: int, tp: int = 1) -> tuple[int, int, int]:
+    """(rows of heads, lanes a key row, lanes a value row) of the pools of
+    K and V a head that hold ``n_kv_heads`` heads a layer: one width and
+    :func:`pool_heads_lanes`' packing, or, where the cache spec states a
+    ``value_dim`` of its own, a head a row, the keys on whole vectors
+    (:func:`key_lanes`) and the values as wide as they are."""
+    if spec.value_dim and not spec.latent_dim:
+        return n_kv_heads, key_lanes(spec.head_dim), spec.value_dim
+    heads, lanes = pool_heads_lanes(n_kv_heads, spec.head_dim, tp)
+    return heads, lanes, lanes
+
+
+def key_lanes(head_dim: int) -> int:
+    """Lanes of a key row beside a value row of another width: ``head_dim``
+    rounded up to whole 128-lane vectors (192 -> 256: a quarter of the key
+    pool, a sixth of both pools, is padding), for :func:`latent_lanes`'
+    reasons. The padding is written as zeros and meets the zeros the query
+    is padded with."""
+    return latent_lanes(head_dim)
 
 
 def latent_lanes(latent_dim: int) -> int:
@@ -259,6 +296,26 @@ def pool_nbytes(kv) -> int:
     """Bytes the whole pool holds on the device(s): k + v (window layers'
     too), or the one array of a latent cache."""
     return int(sum(kv[n].nbytes for n in ("k", "v", "kw", "vw") if n in kv))
+
+
+def token_nbytes(kv) -> int:
+    """Bytes ONE cached token holds in the pool, all layers, each pool at
+    its own heads and stored lanes (padding included; a window layer's row
+    counts like a full layer's, whatever its ring recycles)."""
+    return int(sum(kv[n].nbytes // (kv[n].shape[2] * kv[n].shape[3])
+                   for n in ("k", "v", "kw", "vw") if n in kv))
+
+
+def pool_lanes(cfg, kv) -> dict:
+    """{"k": [lanes a head's key row has, lanes the pool stores for it],
+    "v": ...}: where the two differ the pool holds padding (a latent row of
+    576 on 640, a key row of 192 on 256) and every page read moves it."""
+    spec = block_of(cfg).cache_spec(cfg)
+    has = {"k": spec.latent_dim or spec.head_dim,
+           "v": spec.value_dim or spec.head_dim}
+    return {n: [has[n], kv[n].shape[1] * kv[n].shape[4]
+                // (1 if spec.latent_dim else spec.n_kv_heads)]
+            for n in ("k", "v") if n in kv}
 
 
 def pool_dtype(kv):
@@ -668,6 +725,15 @@ class PageAllocator:
 # page-pool and slot-state updates only); the scopes are compile-time
 # metadata and change no executable.
 
+def _pad_lanes(a, lanes: int):
+    """``a`` with zeros behind its last axis up to ``lanes`` (a key row, or
+    the query that meets it, on the whole vectors the pool stores:
+    :func:`key_lanes`); ``a`` itself where it is that wide."""
+    if a.shape[-1] == lanes:
+        return a
+    return jnp.pad(a, ((0, 0),) * (a.ndim - 1) + ((0, lanes - a.shape[-1]),))
+
+
 def _write_token_kv(k_pool, v_pool, layer, k_new, v_new, page_idx, offset):
     """Scatter new tokens' k/v into layer ``layer`` of the page pool.
 
@@ -688,10 +754,18 @@ def _write_token_kv(k_pool, v_pool, layer, k_new, v_new, page_idx, offset):
     """
     heads = jnp.arange(k_pool.shape[1])
     idx = (layer, heads, page_idx[..., None], offset[..., None])
-    # the pool's own rows (heads of 64: two heads a row, same bytes)
-    rows = k_new.shape[:-2] + (k_pool.shape[1], k_pool.shape[4])
-    return (k_pool.at[idx].set(k_new.reshape(rows).astype(k_pool.dtype)),
-            v_pool.at[idx].set(v_new.reshape(rows).astype(v_pool.dtype)))
+
+    def rows(new, pool):
+        """The pool's own rows (heads of 64: two heads a row, same bytes;
+        key rows beside narrower value rows: zeros up to whole vectors,
+        :func:`key_lanes`)."""
+        if new.shape[-2] == pool.shape[1]:      # a head a row
+            new = _pad_lanes(new, pool.shape[4])
+        return new.reshape(new.shape[:-2] + (pool.shape[1], pool.shape[4])
+                           ).astype(pool.dtype)
+
+    return (k_pool.at[idx].set(rows(k_new, k_pool)),
+            v_pool.at[idx].set(rows(v_new, v_pool)))
 
 
 def _write_token_rows(pool, layer, new, page_idx, offset):
@@ -850,6 +924,10 @@ def _use_pallas_decode(cfg=None, page_size: int = 0, tp: int = 1) -> bool:
     latent = getattr(cfg, "latent_dim", 0)
     if latent:        # the kernel reads the pool's padded rows
         return can_tile(latent_lanes(latent), page_size, cfg.dtype)
+    value = getattr(cfg, "value_dim", 0)
+    if value:         # value rows of their own width: the keys padded too
+        return can_tile(key_lanes(cfg.head_dim), page_size, cfg.dtype,
+                        value_dim=value)
     return can_tile(cfg.head_dim, page_size,
                     getattr(cfg, "dtype", jnp.bfloat16),
                     max(1, getattr(cfg, "n_kv_heads", 2) // tp))
@@ -894,20 +972,28 @@ def tp_degree(mesh) -> int:
     return int(mesh.shape["tensor"])
 
 
-def _dense_attention(q, k, v, mask, sm):
+def _dense_attention(q, k, v, mask, sm, sink=None):
     """Dense-softmax attention, the numerics every backend reproduces:
     float32 logits scaled by ``sm``, masked with -1e30, full-row float32
     softmax, probabilities cast back to q.dtype. q: [B, T, H, D]; k:
     [B, L, Hkv, D]; v: [B, L, Hkv, Dv] (Dv need not be D: a latent
     mixer's values are narrower than its keys in either form); mask:
-    broadcastable to [B, H, T, L]. Returns [B, T, H, Dv]."""
+    broadcastable to [B, H, T, L]; ``sink`` float32 [H]: a learned logit
+    a head, one more column of the softmax that weighs no value (a row's
+    weights then sum to less than 1). Returns [B, T, H, Dv]."""
     n_rep = q.shape[2] // k.shape[2]
     k_full = gqa_expand(k, n_rep)
     v_full = gqa_expand(v, n_rep)
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k_full).astype(
         jnp.float32) * sm
     logits = jnp.where(mask, logits, -1e30)
+    if sink is not None:
+        logits = jnp.concatenate([logits, jnp.broadcast_to(
+            sink.astype(jnp.float32)[None, :, None, None],
+            logits.shape[:3] + (1,))], axis=-1)
     p = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
+    if sink is not None:
+        p = p[..., :-1]
     return jnp.einsum("bhqk,bkhd->bqhd", p, v_full)
 
 
@@ -963,7 +1049,8 @@ def _keep(a, lone):
     return a if lone is None else a[(slice(None),) * lone + (None,)]
 
 
-def _attend(q, k_pool, v_pool, l, g, value_lanes: int = 0, write=None):
+def _attend(q, k_pool, v_pool, l, g, value_lanes: int = 0, write=None,
+            sink=None):
     """Layer ``l`` of the pool read back for q [B, T, H, D]: THE backend
     switch (the module docstring's first design choice, and on a TP mesh
     its last). ``v_pool`` None and ``value_lanes``: a latent pool, all
@@ -975,10 +1062,20 @@ def _attend(q, k_pool, v_pool, l, g, value_lanes: int = 0, write=None):
     view, a chunk's, is small, a batch's is why the kernels exist).
     ``write`` (the pallas backend, a call whose ``g.writes`` is set):
     the call's rows in its grid's form and the pages they go to, which the
-    kernel writes before it reads; returns (that, k_pool, v_pool)."""
+    kernel writes before it reads; returns (that, k_pool, v_pool).
+    ``sink``: float32 [H], a learned logit a head in the softmax's
+    denominator (:func:`_dense_attention`; the walking body's ``sink=``);
+    the gather backend's read of one token takes it under a ring's mask
+    alone (no block has a sink in a layer that has no window)."""
     head_dim = g.cfg.head_dim
     sm = head_dim ** -0.5
     one = g.lone == 1
+    # a head's lanes in a row of each pool: the model's (a row may hold two
+    # heads of 64), or the pools' own where value rows are narrower
+    kd = vd = head_dim
+    if v_pool is not None and v_pool.shape[4] != k_pool.shape[4]:
+        kd, vd = k_pool.shape[4], v_pool.shape[4]
+    extra = {} if sink is None else {"sink": sink}
     if g.attn_backend == "pallas":
         from ray_tpu.ops import paged_attention as paged_ops
         static = {**g.static, "value_lanes": value_lanes} if value_lanes \
@@ -994,15 +1091,16 @@ def _attend(q, k_pool, v_pool, l, g, value_lanes: int = 0, write=None):
             call = jax.shard_map(call, mesh=g.mesh, in_specs=in_specs,
                                  out_specs=out_spec, check_vma=False)
         if write is not None:
-            return call(q, k_pool, v_pool, *g.operands, l, write=write)
-        out = call(q, k_pool, v_pool, *g.operands, l)
+            return call(q, k_pool, v_pool, *g.operands, l, write=write,
+                        **extra)
+        out = call(q, k_pool, v_pool, *g.operands, l, **extra)
         return out[:, None] if one and value_lanes else out
     tables = g.operands[0]
     if one and g.valid is not None:     # a ring's mask, by its geometry
         return _dense_attention(
-            q, _gather_seq(k_pool, l, tables, head_dim),
-            _gather_seq(v_pool, l, tables, head_dim),
-            g.valid[:, None, None], sm)[:, 0]
+            q, _gather_seq(k_pool, l, tables, kd),
+            _gather_seq(v_pool, l, tables, vd),
+            g.valid[:, None, None], sm, sink)[:, 0]
     if one:
         def causal():       # [B, L], made where the recorded programs have it
             return jnp.arange(tables.shape[1] * k_pool.shape[3])[None, :] \
@@ -1014,9 +1112,9 @@ def _attend(q, k_pool, v_pool, l, g, value_lanes: int = 0, write=None):
                 value_lanes)
         q = q[:, 0]                                               # [B,H,D]
         # query heads a KV head (the pool's rows may hold two heads of 64)
-        n_rep = q.shape[1] * head_dim // (k_pool.shape[1] * k_pool.shape[4])
-        k_full = gqa_expand(_gather_seq(k_pool, l, tables, head_dim), n_rep)
-        v_full = gqa_expand(_gather_seq(v_pool, l, tables, head_dim), n_rep)
+        n_rep = q.shape[1] * kd // (k_pool.shape[1] * k_pool.shape[4])
+        k_full = gqa_expand(_gather_seq(k_pool, l, tables, kd), n_rep)
+        v_full = gqa_expand(_gather_seq(v_pool, l, tables, vd), n_rep)
         valid = causal()
         logits = jnp.einsum("bhd,bkhd->bhk", q, k_full).astype(
             jnp.float32) * sm
@@ -1029,9 +1127,9 @@ def _attend(q, k_pool, v_pool, l, g, value_lanes: int = 0, write=None):
             q, k_pool, l, _keep(tables, g.lone), g.valid[heads], sm,
             value_lanes)
     return _dense_attention(
-        q, _keep(_gather_seq(k_pool, l, tables, head_dim), g.lone),
-        _keep(_gather_seq(v_pool, l, tables, head_dim), g.lone),
-        g.valid[heads], sm)
+        q, _keep(_gather_seq(k_pool, l, tables, kd), g.lone),
+        _keep(_gather_seq(v_pool, l, tables, vd), g.lone),
+        g.valid[heads], sm, sink)
 
 
 def _positions(g):
@@ -1100,7 +1198,7 @@ def _layer_geometry(g, ld, page_size: int):
         static={**g.static, "window": window}, valid=valid), "attn_window"
 
 
-def _write_read(x, kv, q, k, v, ld, l, g, project):
+def _write_read(x, kv, q, k, v, ld, l, g, project, sink=None):
     """What the mixers that keep K and V a head share: the call's rows
     written to layer ``l`` of the layer's pool first, then read back with
     all that is cached (write-then-read: a call sees earlier calls AND
@@ -1108,9 +1206,14 @@ def _write_read(x, kv, q, k, v, ld, l, g, project):
     window), then ``project``, the output projection. The rows are
     scattered by :func:`_write_token_kv`, or ride in the kernel that reads
     them back (``g.writes``; the scope ``kv_write`` then holds no
-    operation). Returns (x + mixer, kv)."""
+    operation). Key rows that the pool stores on more lanes than the
+    values' (:func:`key_lanes`) are padded with zeros here, q with them;
+    ``sink``: the layer's learned logits in the softmax's denominator
+    (:func:`_attend`). Returns (x + mixer, kv)."""
     (nk, nv), g, scope = _layer_geometry(g, ld, kv["k"].shape[3])
     k_pool, v_pool, write = kv[nk], kv[nv], None
+    if v_pool.shape[4] != k_pool.shape[4]:
+        q, k = _pad_lanes(q, k_pool.shape[4]), _pad_lanes(k, k_pool.shape[4])
     if g.writes:
         write = (_drop(k, g.lone), _drop(v, g.lone), g.page_idx)
     else:
@@ -1121,7 +1224,7 @@ def _write_read(x, kv, q, k, v, ld, l, g, project):
     with jax.named_scope("attn"):
         # a trace tells a window layer's read from a full one's
         with jax.named_scope(scope) if scope else contextlib.nullcontext():
-            read = _attend(q, k_pool, v_pool, l, g, write=write)
+            read = _attend(q, k_pool, v_pool, l, g, write=write, sink=sink)
         if write is not None:
             read, k_pool, v_pool = read
         out = project(read)
@@ -1150,6 +1253,17 @@ def _gated_mixer(x, kv, layer, ld, l, g):
             g.cfg))
 
 
+def _sink_mixer(x, kv, layer, ld, l, g):
+    """"attn" whose projections read the layer's definition (a rotation and
+    a KV-head count a layer kind) and whose softmax may hold a learned sink
+    (``serve_sink_qkv``: the sink float32 [H], or None for a layer that
+    has none)."""
+    blk = block_of(g.cfg)
+    q, k, v, sink = blk.serve_sink_qkv(x, layer, g.cos, g.sin, g.cfg, ld)
+    return _write_read(x, kv, q, k, v, ld, l, g,
+                       lambda read: blk.serve_attn_out(read, layer), sink)
+
+
 # A MIXER KIND (models/block.py ``LayerDef.mixer``) is one entry here:
 # ``mixer(x, kv, layer, ld, l, g) -> (x + mixer, kv)``, the cache updated
 # in place, the call's :class:`_Geometry` all it knows of the program that
@@ -1157,6 +1271,7 @@ def _gated_mixer(x, kv, layer, ld, l, g):
 _MIXERS = {
     "attn": _attn_mixer,
     "gated": _gated_mixer,
+    "sink": _sink_mixer,
     "latent": _latent_mixer,
     "conv": lambda x, kv, layer, ld, l, g: _conv_mixer(
         x, kv, layer, g.cfg, ld, *g.state()),
@@ -1446,8 +1561,11 @@ def paged_prefill(params, kv, page_table, tokens, true_len,
                 pool = _write_token_rows(kv["k"], l, entry, page_idx[None],
                                          offset[None])
             return x, {**kv, "k": pool}
+        sink = None
         if kind == "gated":
             q, k, v, gate = blk.serve_gated_qkv(x, layer, cos, sin, cfg, ld)
+        elif kind == "sink":
+            q, k, v, sink = blk.serve_sink_qkv(x, layer, cos, sin, cfg, ld)
         else:
             q, k, v = blk.serve_qkv(x, layer, cos, sin, cfg)
         window = ld.window if ld is not None else 0
@@ -1467,7 +1585,7 @@ def paged_prefill(params, kv, page_table, tokens, true_len,
             # dense causal attention within the prompt (prefill is
             # compute-bound and contiguous — no need to read back through
             # pages)
-            attn = _dense_attention(q, k, v, mask[None, None], sm)
+            attn = _dense_attention(q, k, v, mask[None, None], sm, sink)
             x = x + (blk.serve_gated_out(attn, gate, layer, cfg)
                      if kind == "gated" else blk.serve_attn_out(attn, layer))
         x, kv = _ffn(x, kv, layer, cfg, ld)

@@ -28,7 +28,12 @@ ISSUE 54 records again the THIRTY programs of the dense block, afmoe and
 SDAR (their q / k / v / gate projections are held head-major and read by
 ``"btd,hdk->bthk"``: the text's parameter shapes and that einsum's name among
 the scopes move, no equation is added or taken away) and leaves the
-twenty-two of LFM2 and JoyAI to the parent's text and scopes.
+twenty-two of LFM2 and JoyAI to the parent's text and scopes. ISSUE 55 ADDS
+the block whose key and value rows differ in width and whose layer kinds
+differ in KV heads and in a sink (``mimo``: ten programs, no verify) and
+leaves the 52 others as they were recorded (``PARENT_54``): that PR's edits
+of kv_cache.py and of the walking kernel (a value width, ``sink=``) lower
+every one of them to the parent's text.
 
 A PR that MEANS to change one of these programs rewrites the file and says
 so: ``python tests/test_engine_program_hashes.py`` (from the repo's root).
@@ -50,7 +55,8 @@ if __name__ == "__main__":      # run as a script: the repo's root on the path
     sys.path.insert(0, os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
 
-from ray_tpu.models import afmoe, joyai, lfm2_moe, llama, sdar_moe  # noqa: E402
+from ray_tpu.models import (afmoe, joyai, lfm2_moe, llama, mimo,  # noqa: E402
+                            sdar_moe)
 from ray_tpu.serve.llm import LLMConfig, LLMEngine  # noqa: E402
 
 DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data",
@@ -63,7 +69,8 @@ BLOCKS = {"dense": lambda: llama.llama_tiny(vocab_size=512),
           "lfm2": lfm2_moe.lfm2_moe_tiny,
           "sdar": sdar_moe.sdar_moe_tiny,
           "joyai": joyai.joyai_tiny,
-          "afmoe": afmoe.afmoe_tiny}
+          "afmoe": afmoe.afmoe_tiny,
+          "mimo": mimo.mimo_tiny}
 BACKENDS = ("gather", "pallas")
 PROGRAMS = ("decode_1", "decode_4", "decode_8", "verify", "prefill_32",
             "chunk_16")
@@ -72,7 +79,7 @@ PROGRAMS = ("decode_1", "decode_4", "decode_8", "verify", "prefill_32",
 SDAR_PROGRAMS = ("decode_1", "decode_2", "prefill_32", "chunk_16")
 CASES = [(blk, backend, prog) for blk in BLOCKS for backend in BACKENDS
          for prog in (SDAR_PROGRAMS if blk == "sdar" else PROGRAMS)
-         if not (blk in ("lfm2", "afmoe") and prog == "verify")]
+         if not (blk in ("lfm2", "afmoe", "mimo") and prog == "verify")]
 # (no verify program: slot state, and window layers' rings)
 
 
@@ -243,6 +250,28 @@ def test_only_the_programs_whose_kernel_writes_were_recorded_again(recorded,
         others = {k: v for k, v in recorded[key].items()
                   if k.startswith(block + "-") and k not in REWRITTEN_53}
         assert hashlib.sha256(json.dumps(others, sort_keys=True).encode()
+                              ).hexdigest()[:16] == want, (block, key)
+
+
+# ISSUE 55 added the block "mimo" and recorded nothing else again: a block's
+# (programs, scopes), EVERY entry, as sorted JSON, hashed, are commit
+# 6dba7c7's (PR 54)
+PARENT_54 = {"dense": ("7915703ca84ad9bf", "1d580735eeb2efa6"),
+             "lfm2": ("9d40ee4fdcb69a1c", "f6677a7de16ef7bf"),
+             "joyai": ("3abdaec215c28aaa", "db96b92a84c5ddd7"),
+             "sdar": ("2421318f7c41876d", "1d14140e23f85b6e"),
+             "afmoe": ("07c619f41e8f0c75", "d6bbdc8add88db0d")}
+
+
+@pytest.mark.parametrize("block", sorted(PARENT_54))
+def test_a_new_block_records_no_accepted_program_again(recorded, block):
+    """Every program of the blocks the accepted cells run lowers to the
+    parent's text under the parent's scopes: a rewrite of the file that
+    adds a block cannot move one of them unseen."""
+    for key, want in zip(("programs", "scopes"), PARENT_54[block]):
+        mine = {k: v for k, v in recorded[key].items()
+                if k.startswith(block + "-")}
+        assert hashlib.sha256(json.dumps(mine, sort_keys=True).encode()
                               ).hexdigest()[:16] == want, (block, key)
 
 

@@ -485,22 +485,31 @@ def test_only_the_block_wrapper_walks_on_pools_of_k_and_v():
 # ---------------------------------------------------------------------------
 
 
-def _ring_case(base, t, limit, window, page, ring, seed=0):
+def _ring_case(base, t, limit, window, page, ring, seed=0, hkv=2, n_rep=2,
+               d=16, dv=None, k_lanes=None, sink=False):
     """A ring pool laid out as the programs lay it (position p in entry
     ``(p // page) % ring`` of its slot's table, a later position over an
     earlier one), from a dense truth [B, L, Hkv, d]; ring entries whose
     page lies wholly below the walk's first page hold NaN (the walk must
     not copy them). Returns (q, k_pool, v_pool, tables, want): ``want`` the
-    band's softmax over the truth, in numpy."""
-    hkv, n_rep, d = 2, 2, 16
+    band's softmax over the truth, in numpy. ``dv`` / ``k_lanes`` (ISSUE
+    55): value rows of ``dv`` lanes, key rows of ``d`` numbers stored on
+    ``k_lanes`` (zeros behind them, as q's); ``sink``: a logit a query
+    head in the softmax's denominator, returned last."""
+    dv, k_lanes = dv or d, k_lanes or d
     b, cap = len(base), ring * page
     rng = np.random.default_rng(seed)
     top = max(base) + t
-    truth = rng.normal(size=(2, b, top, hkv, d)).astype(np.float32)
-    q = rng.normal(size=(b, t, hkv * n_rep, d)).astype(np.float32)
-    pools = np.zeros((2, hkv, 1 + b * ring, page, d), np.float32)
+    truth = [np.pad(rng.normal(size=(b, top, hkv, w)).astype(np.float32),
+                    ((0, 0),) * 3 + ((0, lanes - w),))
+             for w, lanes in ((d, k_lanes), (dv, dv))]
+    q = np.pad(rng.normal(size=(b, t, hkv * n_rep, d)).astype(np.float32),
+               ((0, 0),) * 3 + ((0, k_lanes - d),))
+    sinks = rng.normal(size=(hkv * n_rep,)).astype(np.float32) + 1.0
+    pools = [np.zeros((hkv, 1 + b * ring, page, w), np.float32)
+             for w in (k_lanes, dv)]
     tables = 1 + np.arange(b * ring, dtype=np.int32).reshape(b, ring)
-    want = np.zeros((b, t, hkv * n_rep, d), np.float32)
+    want = np.zeros((b, t, hkv * n_rep, dv), np.float32)
     for s in range(b):
         hi = min(base[s] + t, limit[s]) - 1          # the last row written
         first = max(0, base[s] - window + 1) // page
@@ -511,14 +520,16 @@ def _ring_case(base, t, limit, window, page, ring, seed=0):
             if j < 0:
                 continue                    # never written: zeros
             if j < first:
-                pools[:, :, tables[s, entry]] = np.nan
+                for pool in pools:
+                    pool[:, tables[s, entry]] = np.nan
                 continue
             for off in range(page):
                 at = j * page + off
                 if at > hi:                 # stale: the page a ring before
                     at -= cap
                 if at >= 0:
-                    pools[:, :, tables[s, entry], off] = truth[:, s, at]
+                    for pool, rows in zip(pools, truth):
+                        pool[:, tables[s, entry], off] = rows[s, at]
         for i in range(t):
             at = base[s] + i
             keys = [j for j in range(max(0, at - window + 1), at + 1)
@@ -526,13 +537,17 @@ def _ring_case(base, t, limit, window, page, ring, seed=0):
             if not keys:
                 continue
             for head in range(hkv * n_rep):
-                sc = truth[0, s, keys, head // n_rep] @ q[s, i, head] \
+                sc = truth[0][s, keys, head // n_rep] @ q[s, i, head] \
                     * d ** -0.5
-                w = np.exp(sc - sc.max())
-                want[s, i, head] = (w / w.sum()) \
-                    @ truth[1, s, keys, head // n_rep]
-    return (jnp.asarray(q), jnp.asarray(pools[0]), jnp.asarray(pools[1]),
-            jnp.asarray(tables), want)
+                top_sc = max(sc.max(), sinks[head]) if sink else sc.max()
+                w = np.exp(sc - top_sc)
+                total = w.sum() + (np.exp(sinks[head] - top_sc) if sink
+                                   else 0.0)
+                want[s, i, head] = (w / total) \
+                    @ truth[1][s, keys, head // n_rep]
+    out = (jnp.asarray(q), jnp.asarray(pools[0]), jnp.asarray(pools[1]),
+           jnp.asarray(tables), want)
+    return out + (jnp.asarray(sinks),) if sink else out
 
 
 @pytest.mark.parametrize("groups", [False, True], ids=["heads_at_once",
@@ -618,6 +633,126 @@ def test_the_wrappers_of_a_windowed_block_walk_and_a_short_ring_is_refused():
 
 
 # ---------------------------------------------------------------------------
+# key rows wider than value rows, a KV-head count a layer kind, a sink, a
+# window of ONE page (ISSUE 55)
+# ---------------------------------------------------------------------------
+
+# (KV heads, query rows a KV head): a full layer's 4 x 16, a window layer's
+# 8 x 8; keys of 24 numbers on rows of 32 lanes beside values of 16
+_MIXED = dict(d=24, k_lanes=32, dv=16)
+
+
+@pytest.mark.parametrize("sink", [False, True], ids=["plain", "sink"])
+@pytest.mark.parametrize("hkv,n_rep,window", [(4, 16, 0), (8, 8, 8)],
+                         ids=["full_16_rows", "one_page_window_8_rows"])
+def test_decode_on_pools_of_two_widths(hkv, n_rep, window, sink):
+    """One token a slot on key rows of 32 lanes (24 numbers, zeros behind
+    them) and value rows of 16: a full layer's read from 0 (a "ring" long
+    enough never to wrap) and a window layer's, whose window is exactly one
+    page of 8 (a ring of 3: two pages walked a slot), at positions inside
+    the first window, on a page's first and last offset and past many
+    wraps; with and without a sink a query head."""
+    pos = [3, 8, 15, 37, 200] if window else [3, 8, 15, 37, 60]
+    ring = 3 if window else 8
+    q, k_pool, v_pool, tables, want, *sinks = _ring_case(
+        pos, 1, [10 ** 6] * len(pos), window or 10 ** 6, 8, ring, seed=5,
+        hkv=hkv, n_rep=n_rep, sink=sink, **_MIXED)
+    got = paged_ops.paged_decode_attention(
+        q[:, 0], k_pool, v_pool, tables, jnp.asarray(pos, jnp.int32),
+        window=window, sm_scale=24 ** -0.5,
+        **({"sink": sinks[0]} if sink else {}))
+    assert got.shape == (len(pos), hkv * n_rep, 16)
+    assert np.isfinite(np.asarray(got)).all()
+    np.testing.assert_allclose(got, want[:, 0], atol=2e-5)
+
+
+@pytest.mark.parametrize("sink", [False, True], ids=["plain", "sink"])
+@pytest.mark.parametrize("start,true_len,window", [
+    (0, 16, 8), (24, 40, 8), (120, 131, 8), (32, 48, 0)])
+def test_chunk_on_pools_of_two_widths(start, true_len, window, sink):
+    """A chunk of 16 rows on the same pools: under a window of one page on
+    a ring of 4 (its first, one past the window, one past many wraps with
+    a padded tail), and a full layer's from 0."""
+    ring = 4 if window else 8
+    q, k_pool, v_pool, tables, want, *sinks = _ring_case(
+        [start], 16, [true_len], window or 10 ** 6, 8, ring, seed=start,
+        hkv=8 if window else 4, n_rep=8 if window else 16, sink=sink,
+        **_MIXED)
+    got = paged_ops.paged_chunk_attention(
+        q, k_pool, v_pool, tables[0], jnp.int32(start), jnp.int32(true_len),
+        window=window, sm_scale=24 ** -0.5,
+        **({"sink": sinks[0]} if sink else {}))
+    real = true_len - start
+    assert got.shape == (1, 16, 64, 16)
+    assert np.isfinite(np.asarray(got)).all()
+    np.testing.assert_allclose(got[0, :real], want[0, :real], atol=2e-5)
+
+
+@pytest.mark.parametrize("groups", [False, True], ids=["heads_at_once",
+                                                       "a_head_a_step"])
+def test_a_sink_follows_its_kv_heads_group(groups, monkeypatch):
+    """With the scratch too small for every KV head a step walks one, and
+    reads ITS rows of the sinks."""
+    if groups:
+        monkeypatch.setattr(paged_ops, "_WALK_KV_BYTES", 1)
+    pos = [5, 21, 90]
+    q, k_pool, v_pool, tables, want, sinks = _ring_case(
+        pos, 1, [10 ** 6] * 3, 8, 8, 3, seed=9, hkv=4, n_rep=4, sink=True,
+        **_MIXED)
+    got = paged_ops.paged_decode_attention(
+        q[:, 0], k_pool, v_pool, tables, jnp.asarray(pos, jnp.int32),
+        window=8, sm_scale=24 ** -0.5, sink=sinks)
+    np.testing.assert_allclose(got, want[:, 0], atol=2e-5)
+
+
+@pytest.mark.parametrize("lone", [1, 0], ids=["decode", "chunk"])
+def test_the_kernels_sink_is_the_dense_softmaxs_extra_column(lone):
+    """The walking body against kv_cache's ``_dense_attention`` (the gather
+    backend's numerics: one more column in the softmax, dropped before the
+    values) on the same pool, a full layer with a sink; and a sink of
+    -1e30 is no sink."""
+    hkv, n_rep, page, mp, b = 4, 4, 8, 6, 3 if lone else 1
+    rng = np.random.default_rng(3)
+    k_pages = jnp.asarray(rng.normal(size=(hkv, mp * b + 1, page, 32)),
+                          jnp.float32)
+    v_pages = jnp.asarray(rng.normal(size=(hkv, mp * b + 1, page, 16)),
+                          jnp.float32)
+    t = 1 if lone else 16
+    q = jnp.asarray(rng.normal(size=(b, t, hkv * n_rep, 32)), jnp.float32)
+    sinks = jnp.asarray(rng.normal(size=(hkv * n_rep,)) + 2.0, jnp.float32)
+    base = jnp.asarray([2, 17, 40][:b], jnp.int32)
+    tables = 1 + jnp.arange(mp * b, dtype=jnp.int32).reshape(b, mp)
+    kpos = jnp.arange(mp * page)
+    valid = kpos[None, None, :] <= (base[:, None] + jnp.arange(t))[:, :, None]
+    keys = kv_cache._gather_seq(k_pages[None], 0, tables)
+    vals = kv_cache._gather_seq(v_pages[None], 0, tables)
+    for sink in (sinks, jnp.full_like(sinks, -1e30), None):
+        got = paged_ops.paged_attention(q, k_pages, v_pages, tables, base,
+                                        window=0, sm_scale=0.2, sink=sink)
+        want = kv_cache._dense_attention(
+            q, keys, vals, valid[:, None], 0.2,
+            None if sink is None else sink)
+        np.testing.assert_allclose(got, want, atol=2e-5)
+    plain = kv_cache._dense_attention(q, keys, vals, valid[:, None], 0.2)
+    assert float(jnp.abs(plain - want).max()) < 1e-6        # -1e30 == none
+    with_sink = kv_cache._dense_attention(q, keys, vals, valid[:, None], 0.2,
+                                          sinks)
+    assert float(jnp.abs(plain - with_sink).max()) > 1e-2
+
+
+def test_only_the_walking_body_takes_a_sink_or_two_widths():
+    k_pages = jnp.zeros((2, 5, 8, 32))
+    q = jnp.zeros((1, 1, 4, 32))
+    tables, base = jnp.ones((1, 4), jnp.int32), jnp.zeros((1,), jnp.int32)
+    with pytest.raises(ValueError, match="only the walking body"):
+        paged_ops.paged_attention(q, k_pages, jnp.zeros((2, 5, 8, 16)),
+                                  tables, base)
+    with pytest.raises(ValueError, match="only the walking body"):
+        paged_ops.paged_attention(q, k_pages, k_pages, tables, base,
+                                  sink=jnp.zeros((4,)))
+
+
+# ---------------------------------------------------------------------------
 # the call's own rows ride in the walking body (ISSUE 53)
 # ---------------------------------------------------------------------------
 
@@ -631,6 +766,11 @@ def _write_case(name, d=16, page=8, dtype=jnp.float32):
     or ones a TPU tiles (the builder's chip check)."""
     rng = np.random.default_rng(sum(map(ord, name)))
     hkv, n_rep, pack, window, static = 2, 2, 1, None, {}
+    dv, mixed = None, name.endswith("_mixed")
+    if mixed:
+        # ISSUE 55: value rows half as wide as key rows, 8 query rows a KV
+        # head, a window of ONE page, a sink a query head
+        name, hkv, n_rep, dv = name[:-len("_mixed")], 4, 8, d // 2
     call, lone, redirected = paged_ops.paged_block_attention, None, False
     if name in ("block", "two_blocks", "packed"):
         # blocks of 4: one ending its page, one in a table's last page,
@@ -645,7 +785,7 @@ def _write_case(name, d=16, page=8, dtype=jnp.float32):
         redirected = t == 8
     elif name in ("decode", "decode_window", "decode_groups"):
         call, lone, t = paged_ops.paged_decode_attention, 1, 1
-        window = 0 if name == "decode" else 2 * page
+        window = 0 if name == "decode" else page if mixed else 2 * page
         mp = 6 if name == "decode" else 5
         base = [0, page - 1, 0, 4 * page + 3] if name == "decode" \
             else [3, 4 * page + 5, 0, 25 * page]
@@ -655,19 +795,24 @@ def _write_case(name, d=16, page=8, dtype=jnp.float32):
         # inside the chunk (its padding: the trash page)
         assert name in ("chunk_ring", "chunk_full"), name
         call, lone, t = paged_ops.paged_chunk_attention, 0, 2 * page
-        window = 2 * page if name == "chunk_ring" else 0
+        window = (page if mixed else 2 * page) if name == "chunk_ring" \
+            else 0
         mp = 5 if window else 16
         base, redirected = [9 * page], True
         static = {"window": window}
-    b = len(base)
+    b, dv = len(base), dv or d
     tables = 1 + rng.permutation(b * mp).reshape(b, mp).astype(np.int32)
     if b > 2:
         tables[2] = 0
     pools = [jnp.asarray(rng.normal(size=(
-        2, hkv // pack, 1 + b * mp, page, d * pack)), dtype) for _ in "kv"]
+        2, hkv // pack, 1 + b * mp, page, w * pack)), dtype)
+        for w in (d, dv)]
     q = jnp.asarray(rng.normal(size=(b, t, hkv * n_rep, d)), dtype)
-    k_new, v_new = (jnp.asarray(rng.normal(size=(b, t, hkv, d)), dtype)
-                    for _ in "kv")
+    k_new, v_new = (jnp.asarray(rng.normal(size=(b, t, hkv, w)), dtype)
+                    for w in (d, dv))
+    if mixed:
+        static["sink"] = jnp.asarray(
+            rng.normal(size=(hkv * n_rep,)) + 1.0, jnp.float32)
     base = np.asarray(base, np.int32)
     pos = base[:, None] + np.arange(t)[None]
     entry = pos // page % mp if window else np.minimum(pos // page, mp - 1)
@@ -717,14 +862,18 @@ def _assert_same_bytes(case, want, got):
                          ids=["f32", "bf16"])
 @pytest.mark.parametrize("name", ["block", "two_blocks", "packed", "decode",
                                   "decode_window", "chunk_ring",
-                                  "chunk_full"])
+                                  "chunk_full", "decode_mixed",
+                                  "decode_window_mixed", "chunk_ring_mixed",
+                                  "chunk_full_mixed"])
 def test_rows_that_ride_in_are_the_scatters_bytes(name, dtype):
     """"Walk with the write inside" is "``_write_token_kv``, then walk",
     byte for byte, on the read and on every layer of both pools: a block
     call of B and of 2B whose second block lies in the next page, heads of
     64 two to a row, a decode call with and without a lower edge, a chunk
     on a ring that wraps (and on a growing table) with ``true_len`` inside
-    it, an inactive slot beside live ones. bf16 pools take tiles of 16
+    it, an inactive slot beside live ones; ``_mixed`` (ISSUE 55): the same
+    calls on key rows twice as wide as the value rows, 8 query rows a KV
+    head, a window of one page and a sink. bf16 pools take tiles of 16
     rows, so their pages are 16."""
     case = _write_case(name, page=16 if dtype == jnp.bfloat16 else 8,
                        dtype=dtype)

@@ -30,7 +30,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from ray_tpu.models import afmoe, llama, sdar_moe
+from ray_tpu.models import afmoe, llama, mimo, sdar_moe
 from ray_tpu.models.block import HEAD_MAJOR, block_of
 from ray_tpu.ops import paged_attention as paged_ops
 from ray_tpu.serve.llm import kv_cache as kvc
@@ -50,13 +50,22 @@ TRINITY = dict(
 SDAR = dict(
     name="sdar", model=lambda: sdar_moe.SdarMoeConfig(n_layers=2),
     batch=64, pages=256, seq=2048, chunk=512)
+# ISSUE 55: a full layer and two window layers (4 and 8 KV heads, q / k of
+# 192 beside v of 128: ``wq_hm`` [64, 4096, 192], ``wv_hm`` [Hkv, 4096, 128])
+MIMO = dict(
+    name="mimo", model=lambda: mimo.MimoConfig(
+        n_layers=3, pattern=(0, 1, 1), moe_freq=(0, 1, 1), experts_held=8,
+        max_seq_len=9216),
+    batch=48, pages=512, seq=9216, chunk=512)
 # (cell, program, width, k): k steps a dispatch, 1 and the largest the
 # engine warms (decode_block 8); SDAR's block program, one block
 CASES = [(MISTRAL, "decode", 4, 1), (MISTRAL, "decode", 4, 8),
          (MISTRAL, "decode", 32, 8),
          (TRINITY, "decode", 24, 1), (TRINITY, "decode", 24, 8),
          (TRINITY, "chunk", 512, 0),
-         (SDAR, "decode", 64, 1)]
+         (SDAR, "decode", 64, 1),
+         (MIMO, "decode", 48, 1), (MIMO, "decode", 48, 8),
+         (MIMO, "chunk", 512, 0)]
 IDS = [f"{cell['name']}-{prog}-w{w}-k{k}" for cell, prog, w, k in CASES]
 
 
@@ -209,7 +218,8 @@ def test_the_listing_finds_the_copy_of_checkpoint_layout_leaves(one_chip):
 # ---- on the CPU: the two forms give the same numbers ------------------------
 
 TINY = {"llama": lambda: llama.llama_tiny(vocab_size=512),
-        "afmoe": afmoe.afmoe_tiny, "sdar": sdar_moe.sdar_moe_tiny}
+        "afmoe": afmoe.afmoe_tiny, "sdar": sdar_moe.sdar_moe_tiny,
+        "mimo": mimo.mimo_tiny}
 PAGE_T, CHUNK_T = 8, 32
 
 
@@ -318,6 +328,7 @@ def test_serve_params_is_idempotent_and_names_the_forms_apart(block):
     ("llama", {"wq_hm", "wk_hm", "wv_hm"}),
     ("afmoe", {"wq_hm", "wk_hm", "wv_hm", "wg_hm"}),
     ("sdar", {"wq_hm", "wk_hm", "wv_hm"}),
+    ("mimo", {"wq_hm", "wk_hm", "wv_hm"}),
     ("lfm2", set()), ("joyai", set())])
 def test_the_engine_says_which_projections_it_holds_head_major(block,
                                                                leaves):
@@ -341,8 +352,9 @@ def test_the_engine_says_which_projections_it_holds_head_major(block,
             itemsize = jnp.dtype(cfg.dtype).itemsize
             assert got["wq_hm"] == cfg.n_layers * cfg.dim * cfg.n_heads \
                 * cfg.head_dim * itemsize
-            assert got["wk_hm"] == got["wv_hm"] == cfg.n_layers * cfg.dim \
-                * cfg.n_kv_heads * cfg.head_dim * itemsize
+            if block != "mimo":     # (its K and V differ by layer kind)
+                assert got["wk_hm"] == got["wv_hm"] == cfg.n_layers \
+                    * cfg.dim * cfg.n_kv_heads * cfg.head_dim * itemsize
         names = {str(path[-1].key) for path, _ in
                  jax.tree_util.tree_leaves_with_path(eng.params)
                  if hasattr(path[-1], "key")}
