@@ -448,6 +448,7 @@ class ServeController:
                         "attention_backend", "attn_backend_pallas",
                         "attn_kernel_compiles", "attn_decode_dispatches",
                         "attn_verify_dispatches", "attn_chunk_dispatches",
+                        "chunk_heads_skipped", "greedy_dispatches",
                         "device_platform", "device_kind", "device_count",
                         "attn_interpret",
                         "tp_degree", "mesh_shape", "kv_shard_pool_bytes",

@@ -404,6 +404,14 @@ class LLMEngine:
                       "attn_decode_dispatches": 0,
                       "attn_verify_dispatches": 0,
                       "attn_chunk_dispatches": 0,
+                      # a program's tail does only what is used (ISSUE
+                      # 56): chunk dispatches that armed no slot and so
+                      # ran no head (of attn_chunk_dispatches), and decode
+                      # / block dispatches none of whose rows asked for a
+                      # temperature, so nothing was drawn (of
+                      # attn_decode_dispatches; a verify round only ever
+                      # holds greedy slots)
+                      "chunk_heads_skipped": 0, "greedy_dispatches": 0,
                       # routed experts (0 for a block without them): over
                       # the decode blocks harvested, distinct experts
                       # touched and (token, expert) rows multiplied, summed
@@ -818,14 +826,11 @@ class LLMEngine:
             vocab = logits.shape[-1]
             logits = jnp.where(jnp.arange(vocab) == mask_id, -jnp.inf,
                                logits)
-            # (the sampler's noise over B x the vocabulary a slot is drawn
-            # only where a live slot asks for a temperature)
-            tok = jax.lax.cond(
-                jnp.any(temps > 0),
-                lambda: self._kvc.sample_tokens(
-                    logits.reshape(-1, vocab), key, jnp.repeat(temps, b),
-                    self.cfg.top_k).reshape(blk.shape),
-                lambda: jnp.argmax(logits, axis=-1)).astype(blk.dtype)
+            # (the sampler draws its noise over B x the vocabulary a slot
+            # only where a slot of the dispatch asks for a temperature)
+            tok = self._kvc.sample_tokens(
+                logits, key, jnp.broadcast_to(temps[:, None], blk.shape),
+                self.cfg.top_k).astype(blk.dtype)
             conf = jnp.exp(
                 jnp.take_along_axis(logits, tok[..., None], axis=-1)[..., 0]
                 - jax.nn.logsumexp(logits, axis=-1))              # [W, B]
@@ -986,11 +991,18 @@ class LLMEngine:
     def _chunk_fn(self, clen: int):
         """Chunked-prefill program for a chunk of ``clen`` tokens: write the
         chunk's KV through the page pool, attend over everything cached so
-        far, and sample a (candidate) next token on device — only the final
-        chunk's sample is used: it is written to row ``slot`` of the donated
-        token vector as in _prefill_fn, and every other chunk passes the
-        trash row. One program per chunk bucket (full chunks
-        share one shape; the padded tail adds at most log2(prefill_chunk))."""
+        far and, where ``final`` (a traced flag beside ``slot``: the host
+        knows which chunk is a prompt's last), sample the next token on
+        device and write it to row ``slot`` of the donated token vector as
+        in _prefill_fn. The program's tail (the last row's final norm, the
+        head over the vocabulary, the sampler) stands under a ``cond`` on
+        ``final``: every other chunk runs none of it and returns the
+        placeholder 0, which it writes to the row it was handed (the trash
+        row). ``slot`` is a write address and nothing else, as in
+        _prefill_fn: a final chunk that arms no slot passes the trash row
+        and still reads the token returned. One program per chunk bucket
+        (full chunks share one shape; the padded tail adds at most
+        log2(prefill_chunk))."""
         key = ("chunk", clen)
         fn = self._prefill_cache.get(key)
         if fn is None:
@@ -998,17 +1010,23 @@ class LLMEngine:
             top_k = self.cfg.top_k
 
             def impl(params, kv, toks_full, page_table, tokens, start,
-                     true_len, rng, temp, slot):
-                logits, kv = self._kvc.paged_prefill_chunk(
+                     true_len, rng, temp, slot, final):
+                x, kv = self._kvc.paged_chunk_walk(
                     params, kv, page_table, tokens, start, true_len,
                     self.model_cfg, self.cfg.page_size,
                     self._attn_backend, mesh=self._mesh)
                 if self._block_len > 1:     # as _prefill_fn's
                     return true_len, toks_full.at[slot].set(
                         self._pending_block(tokens, start, true_len)), kv
-                tok = self._kvc.sample_tokens(
-                    logits[None, :], rng, temp, top_k)
-                return tok[0], toks_full.at[slot].set(tok[0]), kv
+
+                def head():
+                    logits = self._kvc.chunk_head(
+                        params, x, start, true_len, self.model_cfg)
+                    return self._kvc.sample_tokens(
+                        logits[None, :], rng, temp, top_k)[0]
+
+                tok = jax.lax.cond(final, head, lambda: self._jnp.int32(0))
+                return tok, toks_full.at[slot].set(tok), kv
 
             fn = jax.jit(impl, donate_argnums=(1, 2))
             self._prefill_cache[key] = fn
@@ -2427,10 +2445,11 @@ class LLMEngine:
         """Dispatch ONE prefill chunk per in-progress chunked admission
         (loop thread). The final chunk's on-device sampled token arms the
         slot exactly like _prefill's; intermediate chunks only extend the
-        cached KV (their sample goes to the trash row). Chunks are
-        dispatched async — the decode block that follows in this loop
-        iteration queues behind them on the device stream, which is the
-        interleaving."""
+        cached KV (they pass the trash row and ``final`` false, on which
+        the program skips its head and sampler: ``chunk_heads_skipped``).
+        Chunks are dispatched async — the decode block that follows in this
+        loop iteration queues behind them on the device stream, which is
+        the interleaving."""
         trash = self.cfg.max_batch_size
         with self._lock:
             active = list(self._prefilling)
@@ -2459,7 +2478,7 @@ class LLMEngine:
             with self._prof.span(
                     "chunk_prefill", rid=req.request_id, clen=clen,
                     start=start, tokens=len(seg), last=int(final),
-                    dry=self._dry()), \
+                    head=int(final), dry=self._dry()), \
                     self._prof.compile_scope(
                     "chunk", ("chunk", clen),
                     mid_traffic=self.stats["requests"] > 0):
@@ -2467,9 +2486,10 @@ class LLMEngine:
                     self.params, self.kv, self._dev_tokens, table, toks,
                     np.int32(start), np.int32(plen), sub,
                     np.full((1,), req.temperature, np.float32),
-                    np.int32(req.slot if final else trash))
+                    np.int32(req.slot if final else trash), np.bool_(final))
                 self._newest = tok_dev
             self.stats["attn_chunk_dispatches"] += 1
+            self.stats["chunk_heads_skipped"] += not final
             req.prefill_pos = min(start + clen, plen)
             self._count_recycled(req, req.prefill_pos)
             if req.prefill_pos >= plen:
@@ -2766,6 +2786,10 @@ class LLMEngine:
         passes = self._passes_of(k)
         how = {"blocks": k, "passes": passes, "fused": fused} if bl > 1 \
             else {"k": k}
+        # draws: a row of the dispatch asks for a temperature, so the
+        # program's sampler draws (kv_cache.sample_tokens reads the same
+        # in the device's temperatures, which hold each request's)
+        draws = any(req.temperature > 0 for _c, _s, req in snapshot)
         dry = self._dry()
         if tier == "idle":
             self._lead.observe(dry, self._collector.pause_n)
@@ -2779,7 +2803,7 @@ class LLMEngine:
                              lead=lead,
                              trimmed=max(
                                  0, inflight + 1 - self.PIPELINE_DEPTH),
-                             dry=dry):
+                             draws=int(draws), dry=dry):
             toks = self._flush_slot_patches(dirty, overrides)
             idx = self._slot_index(active_slots, w)
             snapshot = [(col, slot, req, *skips[col:col + 1])
@@ -2800,6 +2824,7 @@ class LLMEngine:
             self._pending.append((all_toks, snapshot, k, seq, dev_touched))
             self.stats["steps"] += passes
             self.stats["attn_decode_dispatches"] += 1
+            self.stats["greedy_dispatches"] += not draws
         return True
 
     # ---- speculative decoding ------------------------------------------

@@ -1603,14 +1603,14 @@ def paged_prefill(params, kv, page_table, tokens, true_len,
 
 
 @jax.named_scope("prefill_chunk")
-def paged_prefill_chunk(params, kv, page_table, tokens, start, true_len,
-                        cfg, page_size: int,
-                        attn_backend: str = "gather", mesh=None):
-    """One CHUNK of a long prompt's prefill (chunked prefill: the engine
-    interleaves prompt chunks with decode blocks so a long admission never
-    stalls active generations for the whole prompt pass — the scheduling
-    intent the reference delegates to vLLM's chunked-prefill/priority
-    scheduler, vllm_engine.py:101).
+def paged_chunk_walk(params, kv, page_table, tokens, start, true_len,
+                     cfg, page_size: int,
+                     attn_backend: str = "gather", mesh=None):
+    """The layers of one CHUNK of a long prompt's prefill (chunked prefill:
+    the engine interleaves prompt chunks with decode blocks so a long
+    admission never stalls active generations for the whole prompt pass —
+    the scheduling intent the reference delegates to vLLM's
+    chunked-prefill/priority scheduler, vllm_engine.py:101).
 
     tokens: [1, C] the chunk (bucket-padded); start: scalar position of the
     chunk's first token; true_len: scalar total prompt length. The chunk's
@@ -1619,7 +1619,7 @@ def paged_prefill_chunk(params, kv, page_table, tokens, start, true_len,
     pallas backend the cached prefix is read page-by-page inside the fused
     chunk kernel instead of gathering the full paged view every chunk —
     the long-prompt suffix-prefill-after-tier-restore hot path. Returns
-    (last-token logits [vocab] — meaningful only on the final chunk, new_kv).
+    (the chunk's hidden states [1, C, D] before the final norm, new_kv).
     Under a block mask as :func:`paged_prefill`: ``start`` is a block edge,
     and only the prompt's whole blocks are kept and attended to.
     """
@@ -1646,25 +1646,59 @@ def paged_prefill_chunk(params, kv, page_table, tokens, start, true_len,
         valid=valid, kind="chunk", static={"block_len": b},
         state=lambda: (page_table[:1], start == 0, jnp.reshape(
             jnp.clip(true_len - start, 0, c), (1,)).astype(jnp.int32)))
-    x, kv = _over_layers(_layer, x, kv, params, cfg, g)
+    return _over_layers(_layer, x, kv, params, cfg, g)
+
+
+@jax.named_scope("prefill_chunk")
+def chunk_head(params, x, start, true_len, cfg):
+    """The tail of a chunk: logits [vocab] of the prompt's last REAL token,
+    picked out of ``x`` [1, C, D] (paged_chunk_walk's) by its position
+    relative to the chunk's ``start``."""
+    blk = block_of(cfg)
     x = blk.serve_final_norm(x, params, cfg)
-    # last REAL token's position relative to this chunk's start
-    rel = jnp.clip(true_len - 1 - start, 0, c - 1)
+    rel = jnp.clip(true_len - 1 - start, 0, x.shape[1] - 1)
     last = jnp.take_along_axis(x, rel[None, None, None], axis=1)[:, 0]
-    return blk.serve_lm_head(last, params, cfg)[0], kv
+    return blk.serve_lm_head(last, params, cfg)[0]
+
+
+def paged_prefill_chunk(params, kv, page_table, tokens, start, true_len,
+                        cfg, page_size: int,
+                        attn_backend: str = "gather", mesh=None):
+    """One CHUNK of a long prompt's prefill: :func:`paged_chunk_walk`, then
+    :func:`chunk_head` whatever the chunk (the engine's chunk program puts
+    the head under a ``cond``: only a prompt's last chunk needs it).
+    Returns (last-token logits [vocab] — meaningful only on the final
+    chunk, new_kv)."""
+    x, kv = paged_chunk_walk(params, kv, page_table, tokens, start,
+                             true_len, cfg, page_size, attn_backend, mesh)
+    return chunk_head(params, x, start, true_len, cfg), kv
 
 
 @jax.named_scope("sample")
 def sample_tokens(logits, rng, temperature, top_k: int = 0):
-    """Greedy/temperature/top-k sampling on device. logits: [B, V];
-    temperature: [B] (0 → greedy)."""
+    """Greedy/temperature/top-k sampling on device. logits: [..., V];
+    temperature: [...], a row's (0 → greedy). The draw (random bits over
+    every row's logits, or its top k) runs under a ``cond`` on whether any
+    row samples: with no such row the token is the argmax alone, over the
+    logits as they lie (rows flattened only inside the draw: a block
+    program's [W, B, V] lies B-major on the chip, and flattening it is a
+    copy of the whole array); with one, the same expression on the same
+    key as without the ``cond``."""
     greedy = jnp.argmax(logits, axis=-1)
-    if top_k and top_k > 0:
-        vals, idx = jax.lax.top_k(logits, top_k)
-        scaled = vals / jnp.maximum(temperature[:, None], 1e-6)
-        choice = jax.random.categorical(rng, scaled, axis=-1)
-        sampled = jnp.take_along_axis(idx, choice[:, None], axis=-1)[:, 0]
-    else:
-        scaled = logits / jnp.maximum(temperature[:, None], 1e-6)
-        sampled = jax.random.categorical(rng, scaled, axis=-1)
-    return jnp.where(temperature > 0, sampled, greedy)
+
+    def drawn():
+        rows = logits.reshape(-1, logits.shape[-1])
+        temp = temperature.reshape(-1)
+        if top_k and top_k > 0:
+            vals, idx = jax.lax.top_k(rows, top_k)
+            scaled = vals / jnp.maximum(temp[:, None], 1e-6)
+            choice = jax.random.categorical(rng, scaled, axis=-1)
+            sampled = jnp.take_along_axis(
+                idx, choice[:, None], axis=-1)[:, 0]
+        else:
+            scaled = rows / jnp.maximum(temp[:, None], 1e-6)
+            sampled = jax.random.categorical(rng, scaled, axis=-1)
+        return jnp.where(temperature > 0, sampled.reshape(greedy.shape),
+                         greedy)
+
+    return jax.lax.cond(jnp.any(temperature > 0), drawn, lambda: greedy)

@@ -61,6 +61,9 @@ _EXPORTED_STATS = (
     "attention_backend", "attn_backend_pallas", "attn_kernel_compiles",
     "attn_decode_dispatches", "attn_verify_dispatches",
     "attn_chunk_dispatches",
+    # a program's tail does only what is used (ISSUE 56): chunks that ran
+    # no head, decode dispatches that drew nothing
+    "chunk_heads_skipped", "greedy_dispatches",
     # the device the engine ran on, as jax reports it (strings one-hot
     # like attention_backend), and whether the pallas kernels are being
     # interpreted rather than compiled

@@ -14,7 +14,7 @@ loop can pick is one ``start()`` warmed. ISSUE 50 (ISSUE 49 asked again)
 changed the grouped expert product: the ROUTED families' entries (lfm2,
 sdar, joyai: 30) were rewritten by that PR's tree; the dense block's
 twelve (decode tiers, verify, prefill, chunk, both backends) are commit
-c3e050f's, letter for letter, and ``PARENT_DENSE`` below holds them a
+c3e050f's, letter for letter, and a pin (``PARENT_DENSE``) held them a
 second time so that a rewrite of the file cannot move them unseen: no
 Mistral cell runs a changed program. ISSUE 52 ADDS the block with window
 layers (``afmoe``: ten programs, no verify) and leaves the 42 others as
@@ -23,7 +23,7 @@ kernel lower every one of them to the parent's text. ISSUE 53 records
 again the SIX programs that call the walking body on pools of K and V
 (SDAR's two block programs, Trinity's three decode tiers and its chunk, on
 the pallas backend: their kernel writes the call's rows and nothing is
-scattered before it); ``PARENT_53`` holds the other 46 to the parent's.
+scattered before it); a pin (``PARENT_53``) held the other 46 to the parent's.
 ISSUE 54 records again the THIRTY programs of the dense block, afmoe and
 SDAR (their q / k / v / gate projections are held head-major and read by
 ``"btd,hdk->bthk"``: the text's parameter shapes and that einsum's name among
@@ -31,9 +31,24 @@ the scopes move, no equation is added or taken away) and leaves the
 twenty-two of LFM2 and JoyAI to the parent's text and scopes. ISSUE 55 ADDS
 the block whose key and value rows differ in width and whose layer kinds
 differ in KV heads and in a sink (``mimo``: ten programs, no verify) and
-leaves the 52 others as they were recorded (``PARENT_54``): that PR's edits
+leaves the 52 others as they were recorded (then ``PARENT_54``): that PR's edits
 of kv_cache.py and of the walking kernel (a value width, ``sink=``) lower
-every one of them to the parent's text.
+every one of them to the parent's text. ISSUE 56 records again EVERY program
+that ends in a head or a sampler, which is all but four: ``sample_tokens``
+draws under a ``cond`` on "a row of the dispatch samples" (the decode tiers,
+verify, the whole prefill and the chunk of every block; SDAR's block
+programs, whose own ``cond`` around the sampler folded into it), and the
+chunk program's tail (final norm, row pick, head, sampler) stands under a
+``cond`` on ``final``, one more operand beside ``slot`` (every ``chunk_16``
+but SDAR's: text and scopes). SDAR's ``prefill_32`` and ``chunk_16`` compute
+neither logits nor a sample and lower to the parent's text (an operand that
+nothing reads is not in it); the chunk's SCOPES moved all the same (the
+head's equations, which the parent traced and the lowering dropped, are no
+longer traced: ``kv_cache.paged_chunk_walk``). ``PINNED`` below holds what
+that PR's tree lowered, a (block, backend), so that a later rewrite of the
+file cannot move a program unseen; it took the place of three pins
+(``PARENT_DENSE``, ``PARENT_53``, ``PARENT_55``) that had come to hold the
+same tree.
 
 A PR that MEANS to change one of these programs rewrites the file and says
 so: ``python tests/test_engine_program_hashes.py`` (from the repo's root).
@@ -139,7 +154,7 @@ def _traced(eng: LLMEngine, program: str):
             *tail)
     return eng._chunk_fn(int(n)).trace(
         eng.params, eng.kv, eng._dev_tokens, table, toks, np.int32(0),
-        np.int32(5), *tail)
+        np.int32(5), *tail, np.bool_(True))
 
 
 @functools.cache
@@ -178,101 +193,40 @@ def test_program_lowers_to_the_recorded_text(recorded, block, backend,
         "tests/data/engine_program_hashes.json (this file, run as a script)")
 
 
-# the dense block's programs as ISSUE 54's tree lowered them (until then
-# commit c3e050f's, PR 48): ``wq_hm`` / ``wk_hm`` / ``wv_hm`` [L, H, D, hd]
-# under ``"btd,hdk->bthk"``, nothing else of the text moved
-PARENT_DENSE = {
-    "dense-gather-chunk_16":
-        "2b2f6ba7595da43054ae09b247b64e792a2ed5367d52f39cd5d63cf38bb86e57",
-    "dense-gather-decode_1":
-        "ef76820ef20329918e4a99e7cac28a26299d611961e6fc3f8fa49edc7a077b39",
-    "dense-gather-decode_4":
-        "35ce70162fac9f4c2e1434243dfe3a1d42bf00291cfb2ff6d00dd73049879fac",
-    "dense-gather-decode_8":
-        "df74eabae488f13b777d3cf040d1528deaa0a61cac10d843cfbc543abf058134",
-    "dense-gather-prefill_32":
-        "4f2c18d4e7663d6562abfd793e412cbb6cc4e635114ae0ffcfe422e2d795124d",
-    "dense-gather-verify":
-        "c43f030ed3315c8bf7e011c33b21b9a0c1e18c8a76654a7bcf016605b20bc4ef",
-    "dense-pallas-chunk_16":
-        "d1566d6d905ff94019524b3cdee83e3f9fa137ff24a37be36c135ceda05edb90",
-    "dense-pallas-decode_1":
-        "16ac56ffd7a76c580e6916e59b372c633da1f48810c8f38a0c88a2026c7b8e5b",
-    "dense-pallas-decode_4":
-        "418ead806944b131cfb5ad2790ebf3d230b8f28fbeb6cad6f5afc2335a08eace",
-    "dense-pallas-decode_8":
-        "d5e10eb75cbad1f7d569dd3497f2e82e00bf4664864ab10a7b665326879f980e",
-    "dense-pallas-prefill_32":
-        "4f2c18d4e7663d6562abfd793e412cbb6cc4e635114ae0ffcfe422e2d795124d",
-    "dense-pallas-verify":
-        "e7a06a0d750b8038a59b75330fe6e388b1af658ccbef3e05e8f3600ddb5b6c34",
+# What the last PR that MEANT to move a program recorded, a (block, backend):
+# its (programs, scopes), every entry, as sorted JSON, hashed. ISSUE 56's
+# tree wrote every value (each program ends in a sampler or a head, SDAR's
+# prefill and chunk apart); until then three pins stood here, one for the
+# dense block's twelve programs (ISSUE 50, 54), one for all but the six
+# programs whose kernel writes the call's rows (ISSUE 53) and one a block
+# (ISSUE 55), which after ISSUE 56 all pinned the same tree.
+PINNED = {
+    ("afmoe", "gather"): ("1544a414d8e6742a", "6177d2e6031680d3"),
+    ("afmoe", "pallas"): ("2f58010608951db5", "007b490c9725bfe6"),
+    ("dense", "gather"): ("aa52df80876c8bb4", "c0f09ae5f7cc0595"),
+    ("dense", "pallas"): ("6404c648146edd0e", "5fb6e753f3f6df57"),
+    ("joyai", "gather"): ("89ece47f8b6fb55a", "c1bf1e32aad2f305"),
+    ("joyai", "pallas"): ("568c74a9c0c860a6", "fc40df3fb8a0c7b0"),
+    ("lfm2", "gather"): ("b7eb04a908183266", "975dbc9f84da2a0c"),
+    ("lfm2", "pallas"): ("e8485db8ac199b0a", "e9f2694add31644a"),
+    ("mimo", "gather"): ("5cdd87e0cc9f6318", "09e384b219266a31"),
+    ("mimo", "pallas"): ("1c16c6903021953c", "5d76f4b3adab3bc1"),
+    ("sdar", "gather"): ("f9415abf6a4a2a8e", "761664c839cfab88"),
+    ("sdar", "pallas"): ("9ac4149600c0cd8f", "356d33a4e647d20e"),
 }
 
 
-@pytest.mark.parametrize("name", sorted(PARENT_DENSE))
-def test_a_dense_program_is_recorded_as_the_parent_lowered_it(recorded, name):
-    """ISSUE 50 rewrote the routed families' entries; a dense entry that
-    moved with them would mean the Mistral cells run another program.
-    ISSUE 54 MEANT to move them (the served projections head-major) and
-    pinned what its tree lowered."""
-    assert recorded["programs"][name] == PARENT_DENSE[name]
-
-
-# ISSUE 53: the programs that call the walking body on pools of K and V,
-# whose kernel now writes the call's rows (kv_cache._write_read), were
-# recorded again by that PR's tree ...
-REWRITTEN_53 = {"sdar-pallas-decode_1", "sdar-pallas-decode_2",
-                "afmoe-pallas-decode_1", "afmoe-pallas-decode_4",
-                "afmoe-pallas-decode_8", "afmoe-pallas-chunk_16"}
-# ... and every other entry is commit f0bf47b's (PR 52), letter for letter:
-# a block's (programs, scopes) without those six, as sorted JSON, hashed
-# ISSUE 54 (the attention projections of the dense block, afmoe and SDAR
-# head-major) recorded those three blocks' programs again, every one: their
-# rows are that PR's tree's, the einsum's own name (``btd,hdk->bthk``) the
-# only scope that moved; the LFM2 and JoyAI rows are STILL f0bf47b's, which
-# is the test that those two cells bypass the change
-PARENT_53 = {"dense": ("7915703ca84ad9bf", "1d580735eeb2efa6"),
-             "lfm2": ("9d40ee4fdcb69a1c", "f6677a7de16ef7bf"),
-             "joyai": ("3abdaec215c28aaa", "db96b92a84c5ddd7"),
-             "sdar": ("a276d9c95613b27d", "664acd450d35de00"),
-             "afmoe": ("fb165ad26588ac5e", "e7005cf1a4e73aa1")}
-
-
-@pytest.mark.parametrize("block", sorted(PARENT_53))
-def test_only_the_programs_whose_kernel_writes_were_recorded_again(recorded,
-                                                                   block):
-    """The dense, LFM2 and latent programs, SDAR's on the gather backend
-    and its prefill and chunk, Trinity's on the gather backend and its
-    whole prefill lower to the parent's text under the parent's scopes: no
-    Mistral, LFM2 or JoyAI cell runs a changed program, and a rewrite of
-    the file cannot move one unseen."""
-    for key, want in zip(("programs", "scopes"), PARENT_53[block]):
-        others = {k: v for k, v in recorded[key].items()
-                  if k.startswith(block + "-") and k not in REWRITTEN_53}
-        assert hashlib.sha256(json.dumps(others, sort_keys=True).encode()
-                              ).hexdigest()[:16] == want, (block, key)
-
-
-# ISSUE 55 added the block "mimo" and recorded nothing else again: a block's
-# (programs, scopes), EVERY entry, as sorted JSON, hashed, are commit
-# 6dba7c7's (PR 54)
-PARENT_54 = {"dense": ("7915703ca84ad9bf", "1d580735eeb2efa6"),
-             "lfm2": ("9d40ee4fdcb69a1c", "f6677a7de16ef7bf"),
-             "joyai": ("3abdaec215c28aaa", "db96b92a84c5ddd7"),
-             "sdar": ("2421318f7c41876d", "1d14140e23f85b6e"),
-             "afmoe": ("07c619f41e8f0c75", "d6bbdc8add88db0d")}
-
-
-@pytest.mark.parametrize("block", sorted(PARENT_54))
-def test_a_new_block_records_no_accepted_program_again(recorded, block):
-    """Every program of the blocks the accepted cells run lowers to the
-    parent's text under the parent's scopes: a rewrite of the file that
-    adds a block cannot move one of them unseen."""
-    for key, want in zip(("programs", "scopes"), PARENT_54[block]):
+@pytest.mark.parametrize("block,backend", sorted(PINNED),
+                         ids=["-".join(k) for k in sorted(PINNED)])
+def test_no_program_is_recorded_again_unseen(recorded, block, backend):
+    """A rewrite of the record (a block added, a program MEANT to move)
+    cannot move another block's or backend's programs unseen: a PR that
+    means to move these re-pins them here and says so in the header."""
+    for key, want in zip(("programs", "scopes"), PINNED[block, backend]):
         mine = {k: v for k, v in recorded[key].items()
-                if k.startswith(block + "-")}
+                if k.startswith(f"{block}-{backend}-")}
         assert hashlib.sha256(json.dumps(mine, sort_keys=True).encode()
-                              ).hexdigest()[:16] == want, (block, key)
+                              ).hexdigest()[:16] == want, (block, backend, key)
 
 
 @pytest.mark.parametrize("block,backend,program", CASES,

@@ -337,6 +337,47 @@ def test_decode_dispatch_counts_live_and_table_pages(tmp_path):
         < after["attn_table_pages_total"]
 
 
+@pytest.mark.parametrize("temperature", [0.0, 0.8], ids=["greedy", "sampled"])
+def test_counters_and_spans_say_how_often_a_programs_tail_ran(tmp_path,
+                                                              temperature):
+    """ISSUE 56: ``chunk_heads_skipped`` beside ``attn_chunk_dispatches``
+    (every chunk of a prompt but its last) and ``greedy_dispatches`` beside
+    ``attn_decode_dispatches`` (no row of the dispatch asked for a
+    temperature); ``head`` on every ``rt/chunk_prefill`` span and ``draws``
+    on every ``rt/decode_dispatch`` span say the same, dispatch by
+    dispatch. A prompt of 40 tokens in chunks of 16, 16 and 8."""
+    from ray_tpu.observability import profiling as prof
+    from ray_tpu.serve.llm import llm_server
+
+    eng = _mk_engine(prefill_chunk=16, prefix_cache_enabled=False)
+    try:
+        eng.generate(list(range(1, 20)), max_tokens=4)       # the programs
+        before = eng.engine_stats()
+        info = prof.start_capture(str(tmp_path / "xprof"))
+        eng.generate(list(range(100, 140)), max_tokens=10,
+                     temperature=temperature)
+        prof.stop_capture()
+        after = eng.engine_stats()
+    finally:
+        eng.shutdown()
+    moved = {k: after[k] - before[k] for k in (
+        "attn_chunk_dispatches", "chunk_heads_skipped",
+        "attn_decode_dispatches", "greedy_dispatches")}
+    assert moved["attn_chunk_dispatches"] == 3
+    assert moved["chunk_heads_skipped"] == 2
+    assert moved["attn_decode_dispatches"] >= 3
+    assert moved["greedy_dispatches"] == (
+        0 if temperature else moved["attn_decode_dispatches"])
+    spans = _host_spans(info["logdir"])
+    chunks = [a for n, _s, _e, a, _l in spans if n == "rt/chunk_prefill"]
+    assert [(a["last"], a["head"]) for a in chunks] == [(0, 0), (0, 0), (1, 1)]
+    disp = [a for n, _s, _e, a, _l in spans if n == "rt/decode_dispatch"]
+    assert len(disp) == moved["attn_decode_dispatches"]
+    assert {a["draws"] for a in disp} == {int(temperature > 0)}
+    assert {"chunk_heads_skipped", "greedy_dispatches"} <= set(
+        llm_server._EXPORTED_STATS)
+
+
 def test_capture_holds_engine_spans_with_args(tmp_path):
     """While a capture is active the loop's spans land in the profiler's
     host plane as rt/<phase> with their arguments, on one thread, nested

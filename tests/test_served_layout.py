@@ -101,12 +101,19 @@ def _stand_in(cell):
         cfg=types.SimpleNamespace(page_size=PAGE, top_k=0,
                                   max_batch_size=cell["batch"]))
     eng._experts_touched = functools.partial(LLMEngine._experts_touched, eng)
+    eng._pending_block = functools.partial(LLMEngine._pending_block, eng)
+    eng._prefill_cache = {}
     return eng
 
 
 def _compiled_text(cell, program, width, k, one_chip, served=True) -> tuple:
     """(the compiled module's text, the shapes of the projections); the
-    Pallas kernels lowered as on the chip, not for the interpreter."""
+    Pallas kernels lowered as on the chip, not for the interpreter.
+    ``program``: the engine's ``decode`` (``_decode_impl`` / ``_block_impl``),
+    ``verify``, ``prefill`` and ``engine_chunk`` (``_prefill_fn`` /
+    ``_chunk_fn`` of ``width`` tokens), or ``chunk``: the layers and the
+    head as ``kv_cache.paged_prefill_chunk`` composes them (the
+    benchmark's adapters' program: the head whatever the chunk)."""
     eng = _stand_in(cell)
     cfg, blk = eng.model_cfg, block_of(eng.model_cfg)
 
@@ -130,15 +137,27 @@ def _compiled_text(cell, program, width, k, one_chip, served=True) -> tuple:
         cfg, cell["pages"], PAGE, window_pages=b * ring + 1 if ring else 0)))
     toks = arg(b + 1, eng._block_len) if eng._block_len > 1 else arg(b + 1)
     key = shaped(jax.eval_shape(lambda: jax.random.PRNGKey(0)))
+    state = (params, kv, arg(b + 1, table), arg(b + 1), toks, key,
+             arg(b + 1, dtype=jnp.float32), arg(width))
+    tail = (key, arg(1, dtype=jnp.float32), arg())      # rng, temp, slot
     with mock.patch.object(paged_ops, "interpret_default", lambda: False):
         if program == "decode":
             impl = LLMEngine._block_impl if eng._block_len > 1 \
                 else LLMEngine._decode_impl
             fn = jax.jit(functools.partial(impl, eng),
                          donate_argnums=(1, 3, 4), static_argnums=(8,))
-            lowered = fn.lower(
-                params, kv, arg(b + 1, table), arg(b + 1), toks, key,
-                arg(b + 1, dtype=jnp.float32), arg(width), k)
+            lowered = fn.lower(*state, k)
+        elif program == "verify":
+            fn = jax.jit(functools.partial(LLMEngine._verify_impl, eng),
+                         donate_argnums=(1, 3, 4))
+            lowered = fn.lower(*state, arg(width, k))
+        elif program == "prefill":
+            lowered = LLMEngine._prefill_fn(eng, width).lower(
+                params, kv, toks, arg(table), arg(1, width), arg(), *tail)
+        elif program == "engine_chunk":
+            lowered = LLMEngine._chunk_fn(eng, width).lower(
+                params, kv, toks, arg(table), arg(1, width), arg(), arg(),
+                *tail, arg(dtype=jnp.bool_))            # ..., final
         else:
             fn = jax.jit(lambda p, kv, t, x, s, n: kvc.paged_prefill_chunk(
                 p, kv, t, x, s, n, cfg, PAGE, "pallas"), donate_argnums=(1,))
@@ -213,6 +232,82 @@ def test_the_listing_finds_the_copy_of_checkpoint_layout_leaves(one_chip):
                                        served=False)
     found = weight_shaped_writes(text, projections)
     assert any("bf16[2,32,4096,128]" in line for line in found), found
+
+
+# ---- a program's tail stands under a ``conditional`` (ISSUE 56) -------------
+
+_CALLS = re.compile(r"(?:calls|to_apply|body|condition)=(%[\w.\-]+)")
+
+
+def outside_conditionals(text: str) -> list:
+    """The instructions of a compiled module that run whatever a
+    ``conditional`` picks: ENTRY's and those of every computation reached
+    from it (a fusion's, a loop's body, a call's) other than as a branch
+    of a ``conditional``."""
+    comps, name, entry = {}, None, None
+    for line in text.splitlines():
+        if line.startswith(("%", "ENTRY")) and line.rstrip().endswith("{"):
+            name = line.split()[1 if line.startswith("ENTRY") else 0]
+            comps[name] = []
+            if line.startswith("ENTRY"):
+                entry = name
+        elif name is not None:
+            comps[name].append(line)
+    todo, seen, out = [entry], set(), []
+    while todo:
+        name = todo.pop()
+        if name in seen:
+            continue
+        seen.add(name)
+        for line in comps[name]:
+            out.append(line)
+            if " conditional(" not in line:
+                todo += _CALLS.findall(line)
+    return out
+
+
+def _holds(lines, dtype: str, vocab: int) -> list:
+    """The instructions that write ``dtype`` over the vocabulary."""
+    shape = re.compile(rf"= \(?{dtype}\[(?:\d+,)*{vocab}[\],]")
+    return [line.strip()[:160] for line in lines if shape.search(line)]
+
+
+# (cell, program, width, k): one case a program kind; a dispatch that draws
+# holds the sampler's random bits as u32 over rows x the vocabulary
+TAIL_CASES = [(MISTRAL, "decode", 4, 1), (MISTRAL, "decode", 4, 8),
+              (MISTRAL, "verify", 4, 3), (MISTRAL, "prefill", 512, 0),
+              (TRINITY, "engine_chunk", 512, 0), (SDAR, "decode", 64, 1)]
+
+
+@pytest.mark.parametrize("cell,program,width,k", TAIL_CASES, ids=[
+    f"{cell['name']}-{prog}-w{w}-k{k}" for cell, prog, w, k in TAIL_CASES])
+def test_a_programs_tail_stands_under_a_conditional(cell, program, width, k,
+                                                    one_chip):
+    """The sampler's random bits, in every program that samples, and in the
+    engine's chunk program the head's product (float32 over the
+    vocabulary) stand ONLY inside a ``conditional``: a dispatch none of
+    whose rows samples draws nothing, a chunk that arms no slot reads no
+    head. The control is the layers and the head as
+    ``kv_cache.paged_prefill_chunk`` composes them, whose head stands
+    outside."""
+    text, _ = _compiled_text(cell, program, width, k, one_chip)
+    vocab = cell["model"]().vocab_size
+    assert _holds(text.splitlines(), "u32", vocab)      # drawn somewhere
+    outside = outside_conditionals(text)
+    assert " conditional(" in "\n".join(outside)
+    assert _holds(outside, "u32", vocab) == []
+    if program == "decode":
+        # nor does a decode or block program lay its logits out again for
+        # the sampler's sake whatever the branch: SDAR's [W, B, V] lies
+        # B-major on the chip, and flattened before the ``cond`` it cost a
+        # copy of the whole array an unmask
+        assert [line for line in _holds(outside, "f32", vocab)
+                if " copy(" in line or " reshape(" in line] == []
+    if program == "engine_chunk":
+        assert _holds(text.splitlines(), "f32", vocab)
+        assert _holds(outside, "f32", vocab) == []
+        always, _ = _compiled_text(cell, "chunk", width, k, one_chip)
+        assert _holds(outside_conditionals(always), "f32", vocab)
 
 
 # ---- on the CPU: the two forms give the same numbers ------------------------
