@@ -12,6 +12,7 @@ import logging
 import os
 import signal
 import threading
+import time
 
 
 def _parse_addr(s: str) -> tuple[str, int]:
@@ -47,6 +48,11 @@ def main():
         "worker_ready",
         {"worker_id": worker_id, "addr": rt.addr, "pid": os.getpid()},
         timeout=30.0)
+    # the process's start-up ledger: from the process's creation, as the
+    # kernel has it, to here (interpreter, imports, the runtime)
+    from ray_tpu.observability import profiling
+    startup = profiling.startup()
+    startup.stamp("worker_boot", startup.created, time.monotonic())
 
     stop = threading.Event()
     signal.signal(signal.SIGTERM, lambda *_: stop.set())

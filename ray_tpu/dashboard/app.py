@@ -953,10 +953,13 @@ def _render_profiling(apps: list[dict], artifacts: list[dict]) -> str:
     import html as _html
     import time as _time
 
+    from ray_tpu.observability.profiling import STARTUP_TOTALS
+
     phase_keys = ["queue_wait", "admit", "prefill", "chunk_prefill",
                   "decode_dispatch", "verify_dispatch", "harvest", "fetch"]
     scalar_keys = ["itl_s", "compile_events", "mid_traffic_compiles",
-                   "compile_s", "host_stall_n", "host_stall_s_total",
+                   "compile_s", *STARTUP_TOTALS,
+                   "host_stall_n", "host_stall_s_total",
                    "gc_pause_n", "gc_pause_s_total", "gc_pause_max_ms",
                    "dry_dispatches_total", "dry_s_total",
                    "idle_lead_k", "lead_climbs_total",

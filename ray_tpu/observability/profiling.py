@@ -34,11 +34,19 @@ runtime to find the next one):
   phase `gc`.
 - **Compile-event tracking** (`compile_scope`): every jit entry point's
   first dispatch per static signature (prefill bucket, chunk length,
-  decode (width, block), verify width) is timed as a compile event.
+  decode (width, block), verify width) is timed as a compile event and
+  split into tracing, lowering and the backend's compile or, on a hit of
+  the persistent cache, load (`_Parts`: jax's own monitoring events,
+  attributed to the scope open on the thread that fired them).
   Compiles while traffic is in flight are the documented loop-stall
   failure class (engine.py `_warmup_decode_programs`): they're flagged
   `mid_traffic`, logged as warnings, and counted — a regression here is
   a serving-latency regression.
+- **The start-up ledger** (`startup`): one a process. The stages from
+  the process's creation to the replica ready (`STARTUP_STAGES`, each
+  stamped where its work happens), every first dispatch's record, what
+  compiled under no scope, and the thread that built the engine: "why
+  did this replica take 140 s to come up", in `/v1/stats` `startup`.
 - **Device-memory accounting**: weights / KV-pool byte gauges computed
   from array layouts, KV page occupancy, and the backend allocator's
   live/peak bytes when the platform reports them (`device.memory_stats()`
@@ -78,6 +86,22 @@ PHASES = ("queue_wait", "loop_pass", "admit", "restore", "prefill",
           "chunk_prefill", "decode_dispatch", "block_dispatch",
           "verify_dispatch", "patch_flush", "harvest", "fetch", "emit",
           "kv_tier_flush", "loop_wait")
+# the stages of a replica's start, in order (README's table and PERF.md's
+# layer row key off this tuple): the worker PROCESS's creation to its
+# registration with the node agent (core/worker_main.py), that to the
+# deployment's constructor (serve/llm/llm_server.py), then inside
+# `LLMEngine.__init__` the first `import jax` + `jax.devices()`, the
+# weights, their served form, the page pool and the device state beside
+# it, `_warmup_decode_programs`, and the mark `LLMServer.__init__`
+# returned. An engine built outside a worker has no first two.
+STARTUP_STAGES = ("worker_boot", "actor_wait", "backend", "weights",
+                  "serve_form", "pool", "warm_decode", "ready")
+# the ledger's flat totals in `engine_stats()`, for exporters and the
+# controller's and dashboard's kept keys (the nested `startup` is not)
+STARTUP_TOTALS = ("startup_s", "startup_programs", "startup_cache_hits",
+                  "startup_cache_misses", "startup_trace_s",
+                  "startup_lower_s", "startup_load_s",
+                  "startup_backend_compile_s")
 # span names in the profiler's trace: "rt/<phase>"
 SPAN_PREFIX = "rt/"
 # host work on the loop thread takes microseconds to a few ms: a span
@@ -282,7 +306,294 @@ class _NoSpan(_Noop):
 _NO_SPAN = _NoSpan()
 
 
+# jax's monitoring events a first dispatch is made of. Each duration
+# event fires on the thread that did the work, as the work ends
+_PART_OF = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace_s",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower_s",
+    "/jax/core/compile/backend_compile_duration": "backend",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "retrieve_s"}
+_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+PARTS = ("trace_s", "lower_s", "compile_s", "load_s", "retrieve_s")
+
+
+class _Parts:
+    """What the compiles of one scope (or, under no scope, of one thread)
+    were made of. One writer: the thread it belongs to.
+
+    The parts are kept DISJOINT. An event is the interval that ends now
+    and lasted its duration; events arrive innermost first, so those an
+    event contains are the tail of `_stack`, and its own time is its
+    duration less theirs. A nested jit's trace (jax reports each) thus
+    adds up to the outermost trace alone, a function traced while
+    another is lowered counts once, and the parts never exceed the wall
+    time around them. `retrieve_s`, the cache's own read, lies inside
+    `load_s` and is kept beside the sum."""
+
+    __slots__ = ("trace_s", "lower_s", "compile_s", "load_s", "retrieve_s",
+                 "hits", "misses", "names", "_stack", "_hit")
+
+    def __init__(self, named: bool = False):
+        self.trace_s = self.lower_s = self.compile_s = self.load_s = 0.0
+        self.retrieve_s = 0.0
+        self.hits = self.misses = 0
+        # {function: backend compiles or loads} of the programs nobody
+        # listed (the `unscoped` record alone keeps names)
+        self.names: Optional[dict] = {} if named else None
+        self._stack: list = []         # [(start, duration)]
+        self._hit = False
+
+    def add(self, part: str, duration: float, fun_name) -> None:
+        if part == "retrieve_s":
+            self.retrieve_s += duration
+            return
+        start = time.monotonic() - duration
+        stack, inner = self._stack, 0.0
+        while stack and stack[-1][0] >= start:
+            inner += stack.pop()[1]
+        stack.append((start, duration))
+        own = max(0.0, duration - inner)
+        if part == "backend":
+            # the persistent cache says "hit" inside the interval this
+            # event closes; a compile without a cache says nothing
+            hit, self._hit = self._hit, False
+            part = "load_s" if hit else "compile_s"
+            if hit:
+                self.hits += 1
+            else:
+                self.misses += 1
+            names = self.names
+            if names is not None and (fun_name in names or len(names) < 64):
+                names[fun_name] = names.get(fun_name, 0) + 1
+        setattr(self, part, getattr(self, part) + own)
+
+    def as_dict(self) -> dict:
+        return {p: round(getattr(self, p), 6) for p in PARTS}
+
+
+_tls = threading.local()        # .scope: the _Parts of the open scope
+
+
+def _on_duration(event: str, duration: float, **kw) -> None:
+    """jax calls this from inside a trace, a lowering or a compile, on
+    the thread that does it: whatever goes wrong here stays here."""
+    part = _PART_OF.get(event)
+    if part is not None:
+        try:
+            (getattr(_tls, "scope", None) or _startup.loose()).add(
+                part, float(duration), kw.get("fun_name"))
+        except Exception:  # noqa: BLE001 - never fail a compile
+            pass
+
+
+def _on_event(event: str, **kw) -> None:
+    if event == _CACHE_HIT:
+        try:
+            (getattr(_tls, "scope", None) or _startup.loose())._hit = True
+        except Exception:  # noqa: BLE001 - never fail a compile
+            pass
+
+
+def _process_created() -> float:
+    """This process's creation on `time.monotonic()`, as the kernel has
+    it (/proc/self/stat `starttime`, ticks of the boot clock; to 10 ms).
+    Now, where /proc does not say."""
+    now = time.monotonic()
+    try:
+        with open("/proc/self/stat") as f:
+            ticks = int(f.read().rsplit(") ", 1)[1].split()[19])
+        age = time.clock_gettime(time.CLOCK_BOOTTIME) \
+            - ticks / os.sysconf("SC_CLK_TCK")
+        return now - age if age >= 0.0 else now
+    except (OSError, ValueError, IndexError, AttributeError):
+        return now
+
+
+class _Stage:
+    """One stage of the start, timed where it is entered and left."""
+
+    __slots__ = ("_ledger", "_name", "_t0")
+
+    def __init__(self, ledger: "_StartupLedger", name: str):
+        self._ledger = ledger
+        self._name = name
+
+    def __enter__(self):
+        self._t0 = time.monotonic()
+        return None
+
+    def __exit__(self, exc_type, exc, tb):
+        if exc_type is None:
+            self._ledger.stamp(self._name, self._t0, time.monotonic())
+        return False
+
+
+class _StartupLedger:
+    """A process's start-up (`startup` returns the one instance, made with
+    the module like `_GcWatch`): stages, first dispatches, the compiles
+    nobody listed, the constructing thread. Everything is on
+    `time.monotonic()`, the machine's clock (`clock_s` is on it too), so a
+    reader in another process lays the ledger on its own line.
+
+    `view` is what `/v1/stats` carries as `startup`: built once, its lists
+    appended to in place, so a poll copies a reference. The ledger closes
+    to STAGES at the first `ready` (a second engine of the process is not
+    a start); first dispatches are recorded for as long as the process
+    lives, at most `MAX_PROGRAMS` of them."""
+
+    MAX_PROGRAMS = 1024
+
+    def __init__(self):
+        self.created = _process_created()
+        self.ready: Optional[float] = None
+        self._lock = threading.Lock()
+        self._listening = False
+        self._loose: list[_Parts] = []      # one a thread, under no scope
+        self.stages: list = []              # [name, start, seconds]
+        self.programs: list = []
+        # totals over the scoped first dispatches
+        self._scoped = _Parts()
+        self.view = {"created": round(self.created, 6), "ready": None,
+                     "built_on": None, "stages": self.stages,
+                     "programs": self.programs, "unscoped": {}}
+
+    # ---- jax's events ---------------------------------------------------
+    def listen(self) -> None:
+        """Register the process's pair of `jax.monitoring` listeners, once
+        (the engine calls this after its `import jax`; a worker that
+        builds no engine never imports jax for it). jax fires the events
+        only while something traces, lowers or compiles: a call of a
+        compiled program fires none."""
+        with self._lock:
+            if self._listening:
+                return
+            from jax import monitoring
+            monitoring.register_event_duration_secs_listener(_on_duration)
+            monitoring.register_event_listener(_on_event)
+            self._listening = True
+
+    def loose(self) -> _Parts:
+        """The calling thread's record of what it compiles under no
+        scope."""
+        parts = getattr(_tls, "loose", None)
+        if parts is None:
+            parts = _tls.loose = _Parts(named=True)
+            with self._lock:
+                self._loose.append(parts)
+        return parts
+
+    # ---- stages ---------------------------------------------------------
+    def stage(self, name: str) -> _Stage:
+        return _Stage(self, name)
+
+    def stamp(self, name: str, start: float, end: float) -> None:
+        with self._lock:
+            if self.ready is None:
+                self.stages.append(
+                    [name, round(start, 6), round(end - start, 6)])
+
+    def stamp_since_last(self, name: str, after: str) -> None:
+        """The stage from the end of stage `after` to now, where `after`
+        is the last stage stamped (`actor_wait`: only a worker waits)."""
+        with self._lock:
+            last = self.stages[-1] if self.stages else None
+        if last is not None and last[0] == after:
+            self.stamp(name, last[1] + last[2], time.monotonic())
+
+    def built_on(self) -> None:
+        """The calling thread builds the engine."""
+        th = threading.current_thread()
+        self.view["built_on"] = [th.name, th is threading.main_thread()]
+
+    def mark_ready(self) -> None:
+        """The replica is ready: the mark that ends the stages, and the
+        operator's line."""
+        now = time.monotonic()
+        with self._lock:
+            if self.ready is not None:
+                return
+            self.stages.append(["ready", round(now, 6), 0.0])
+            self.ready = now
+            self.view["ready"] = round(now, 6)
+        top = sorted(self.programs, key=lambda p: -p["wall_s"])[:3]
+        tot = self.totals()
+        logger.info(
+            "replica ready %.1fs after its process began (built on %s): %s; "
+            "%d first dispatches, executables %d loaded from the cache and "
+            "%d compiled; largest %s",
+            now - self.created, self.view["built_on"],
+            ", ".join(f"{n} {s:.1f}s" for n, _t, s in self.stages[:-1]),
+            tot["startup_programs"], tot["startup_cache_hits"],
+            tot["startup_cache_misses"],
+            ", ".join(f"{p['sig']} {p['wall_s']:.1f}s" for p in top))
+
+    # ---- first dispatches -----------------------------------------------
+    def record(self, rec: dict, parts: _Parts) -> None:
+        with self._lock:
+            if len(self.programs) < self.MAX_PROGRAMS:
+                self.programs.append(rec)
+            tot = self._scoped
+            tot.hits += parts.hits
+            tot.misses += parts.misses
+            for p in PARTS:
+                setattr(tot, p, getattr(tot, p) + getattr(parts, p))
+
+    def unscoped(self) -> dict:
+        """What compiled under no scope, over every thread: the parts,
+        executables loaded and compiled, and which functions they were."""
+        with self._lock:
+            loose = list(self._loose)
+        out = {p: round(sum(getattr(x, p) for x in loose), 6) for p in PARTS}
+        out["hits"] = sum(x.hits for x in loose)
+        out["misses"] = sum(x.misses for x in loose)
+        names: dict = {}
+        for x in loose:
+            for k, v in list(x.names.items()):
+                names[k] = names.get(k, 0) + v
+        out["n"] = out["hits"] + out["misses"]
+        out["names"] = names         # (at most 64 functions a thread)
+        return out
+
+    def totals(self, unscoped: Optional[dict] = None) -> dict:
+        """The flat `startup_*` keys: scoped and unscoped together, since
+        the process began (first dispatches after `ready` included)."""
+        un = unscoped or self.unscoped()
+        tot = self._scoped
+        return {
+            "startup_s": (round(self.ready - self.created, 3)
+                          if self.ready is not None else None),
+            "startup_programs": len(self.programs),
+            "startup_cache_hits": tot.hits + un["hits"],
+            "startup_cache_misses": tot.misses + un["misses"],
+            "startup_trace_s": round(tot.trace_s + un["trace_s"], 3),
+            "startup_lower_s": round(tot.lower_s + un["lower_s"], 3),
+            "startup_load_s": round(tot.load_s + un["load_s"], 3),
+            "startup_backend_compile_s": round(
+                tot.compile_s + un["compile_s"], 3)}
+
+    def stats(self) -> dict:
+        """`startup` and the flat totals, for `engine_stats()`."""
+        un = self.view["unscoped"] = self.unscoped()
+        return {"startup": self.view, **self.totals(un)}
+
+
+_startup = _StartupLedger()
+
+
+def startup() -> _StartupLedger:
+    """The process's start-up ledger."""
+    return _startup
+
+
 class _CompileScope:
+    """A signature's first dispatch: its wall time, and what jax says it
+    was made of (the events that fire on this thread while the scope is
+    open). Under a capture also the span `rt/compile` on this thread's
+    line, so an idle gap a first use caused reads `compile` in a trace."""
+
+    __slots__ = ("_prof", "_kind", "_sig", "_mid", "_parts", "_outer",
+                 "_ann", "_t", "_t0")
+
     def __init__(self, prof: "EngineProfiler", kind: str, sig,
                  mid_traffic: bool):
         self._prof = prof
@@ -291,14 +602,31 @@ class _CompileScope:
         self._mid = mid_traffic
 
     def __enter__(self):
+        self._ann = None
+        if _capture.active:
+            self._ann = sys.modules["jax"].profiler.TraceAnnotation(
+                SPAN_PREFIX + "compile", kind=self._kind, sig=str(self._sig))
+            self._ann.__enter__()
+        self._parts = _Parts()
+        self._outer = getattr(_tls, "scope", None)
+        _tls.scope = self._parts
+        self._t = time.monotonic()
         self._t0 = time.perf_counter()
         return None
 
     def __exit__(self, exc_type, exc, tb):
+        dt = time.perf_counter() - self._t0
+        _tls.scope = self._outer
+        rec = None
         if exc_type is None:
-            self._prof._record_compile(
-                self._kind, self._sig, time.perf_counter() - self._t0,
-                self._mid)
+            rec = self._prof._record_compile(
+                self._kind, self._sig, dt, self._mid, self._t, self._parts)
+        if self._ann is not None:
+            if rec is not None:
+                self._ann.set_metadata(**{
+                    k: rec[k] for k in ("hit", "trace_s", "lower_s")},
+                    **{k: rec[k] for k in ("compile_s", "load_s") if rec[k]})
+            self._ann.__exit__(exc_type, exc, tb)
         return False
 
 
@@ -459,11 +787,15 @@ class EngineProfiler:
             return sum(1 for s in self._seen
                        if isinstance(s, tuple) and s and s[0] in kinds)
 
-    def _record_compile(self, kind: str, sig, dt: float,
-                        mid_traffic: bool) -> None:
+    def _record_compile(self, kind: str, sig, dt: float, mid_traffic: bool,
+                        t: Optional[float] = None,
+                        parts: Optional[_Parts] = None) -> Optional[dict]:
+        """A first dispatch has ended: the counters, the two metric
+        families, and its record in the process's start-up ledger (None
+        where another thread recorded the signature first)."""
         with self._lock:
             if sig in self._seen:
-                return
+                return None
             self._seen.add(sig)
             self.compile_events += 1
             self.compile_s += dt
@@ -472,22 +804,38 @@ class EngineProfiler:
         COMPILE_EVENTS.inc(1, {"kind": kind,
                                "mid_traffic": str(bool(mid_traffic)).lower()})
         COMPILE_SECONDS.observe(dt, {"kind": kind})
+        parts = parts or _Parts()
+        split = parts.as_dict()
+        rec = {"kind": kind,
+               "sig": list(sig) if isinstance(sig, (tuple, list))
+               else [str(sig)],
+               "t": round(time.monotonic() - dt if t is None else t, 6),
+               "wall_s": round(dt, 6), **split,
+               # what is left of the wall: the dispatch (the execution is
+               # asynchronous and is not waited for)
+               "rest_s": round(max(0.0, dt - sum(
+                   split[p] for p in PARTS if p != "retrieve_s")), 6),
+               # every executable of the scope came out of the cache
+               "hit": int(parts.hits > 0 and parts.misses == 0),
+               "thread": threading.current_thread().name,
+               "mid_traffic": int(bool(mid_traffic))}
+        _startup.record(rec, parts)
         if mid_traffic:
             logger.warning(
                 "mid-traffic compile: kind=%s sig=%s took %.2fs — every "
                 "active generation stalled for it (warm this program at "
                 "startup, see engine warmup_compile)", kind, sig, dt)
             # off-box visibility (ISSUE 19): a WARNING journal event
-            # carrying the compile signature. Warmup compiles
-            # (mid_traffic=False) emit nothing — the regression test
-            # holds that line.
+            # carrying the compile signature and its parts. Warmup
+            # compiles (mid_traffic=False) emit nothing — the regression
+            # test holds that line.
             from ray_tpu.observability import events as _fr
             _fr.emit("mid_traffic_compile", "WARNING",
                      reason=kind,
-                     attrs={"kind": kind,
-                            "sig": list(sig) if isinstance(
-                                sig, (tuple, list)) else [str(sig)],
-                            "seconds": round(float(dt), 4)})
+                     attrs={"kind": kind, "sig": rec["sig"],
+                            "seconds": round(float(dt), 4),
+                            "hit": rec["hit"], **split})
+        return rec
 
     # ---- memory accounting ---------------------------------------------
     def set_memory_layout(self, weights_bytes: int,
@@ -616,11 +964,6 @@ class CaptureController:
         return {"logdir": logdir, "duration_s": round(dur, 3),
                 "pid": os.getpid()}
 
-    def status(self) -> dict:
-        with self._lock:
-            return {"active": self._logdir is not None,
-                    "logdir": self._logdir, "pid": os.getpid()}
-
 
 _capture = CaptureController()
 
@@ -631,10 +974,6 @@ def start_capture(logdir: Optional[str] = None) -> dict:
 
 def stop_capture() -> dict:
     return _capture.stop()
-
-
-def capture_status() -> dict:
-    return _capture.status()
 
 
 def save_device_memory_profile(path: Optional[str] = None) -> str:

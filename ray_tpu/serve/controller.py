@@ -417,7 +417,7 @@ class ServeController:
         # counters per replica — plus the ISSUE-6 introspection surface
         # (per-phase p50/p95, ITL, compile events, device memory) that the
         # dashboard /profiling panel renders; anything else probes to None
-        from ray_tpu.observability.profiling import PHASES
+        from ray_tpu.observability.profiling import PHASES, STARTUP_TOTALS
         _ENGINE_KEYS = ("steps", "prefills", "tokens_out", "requests",
                         "shed_expired",
                         "active_slots", "waiting", "free_pages",
@@ -455,6 +455,7 @@ class ServeController:
                         "kv_shard_page_occupancy",
                         "itl_s", "compile_events", "mid_traffic_compiles",
                         "compile_s", "weights_bytes", "kv_pool_bytes",
+                        *STARTUP_TOTALS,
                         "kv_page_occupancy", "device_bytes_in_use",
                         "device_peak_bytes",
                         "host_stall_s_total", "host_stall_n",

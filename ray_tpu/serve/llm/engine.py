@@ -179,8 +179,18 @@ class _Request:
 
 class LLMEngine:
     def __init__(self, cfg: LLMConfig, params=None, rng_seed: int = 0):
-        import jax
-        import jax.numpy as jnp
+        # the process's start-up ledger (observability/profiling.py): the
+        # stages below are stamped where their work happens, and jax's
+        # compile events are listened to from the first program on
+        from ray_tpu.observability import profiling as profiling_mod
+        self._startup = startup = profiling_mod.startup()
+        startup.built_on()
+        with startup.stage("backend"):
+            import jax
+            import jax.numpy as jnp
+
+            startup.listen()
+            jax.devices()
 
         from ray_tpu.models.block import block_of, head_major_nbytes
         from ray_tpu.serve.llm import kv_cache as kvc
@@ -261,18 +271,25 @@ class LLMEngine:
         self._attn_backend = kvc.resolve_attention_backend(
             cfg.attention_kernel, self.model_cfg, cfg.page_size, self._tp)
 
-        if params is None:
-            if cfg.checkpoint_path:
-                params = self._block.load_params(cfg.checkpoint_path,
-                                                 self.model_cfg)
-            else:
-                params = self._block.init_params(
-                    jax.random.PRNGKey(rng_seed), self.model_cfg)
+        # (each of the three stages waits for its arrays: the device runs
+        # them in the order it was given them either way, and the host
+        # work that follows each is far longer than what it waits for)
+        with startup.stage("weights"):
+            if params is None:
+                if cfg.checkpoint_path:
+                    params = self._block.load_params(cfg.checkpoint_path,
+                                                     self.model_cfg)
+                else:
+                    params = self._block.init_params(
+                        jax.random.PRNGKey(rng_seed), self.model_cfg)
+            jax.block_until_ready(params)
         # the block's served form (models/block.py ``serve_params``), made
         # once, a leaf at a time, and the checkpoint's form let go before
         # the pool is allocated: no program re-lays a weight again
-        self.params = self._block.serve_params(params, self.model_cfg)
-        del params
+        with startup.stage("serve_form"):
+            self.params = self._block.serve_params(params, self.model_cfg)
+            del params
+            jax.block_until_ready(self.params)
         self._weights_head_major = head_major_nbytes(self.params)
 
         b = cfg.max_batch_size
@@ -288,6 +305,7 @@ class LLMEngine:
                 f"routed block records its choice of experts for at most "
                 f"max_seq_len rows a call")
         # (the window pool: a ring a slot and the trash page)
+        pool_t0 = time.monotonic()
         self.kv = kvc.init_paged_cache(
             self.model_cfg, cfg.num_pages, cfg.page_size, self._tp,
             window_pages=b * self._ring_pages + 1 if self._windowed else 0)
@@ -303,6 +321,8 @@ class LLMEngine:
         self._mesh = None
         if self._tp > 1:
             self._mesh = self._setup_tp_mesh()
+        jax.block_until_ready(self.kv)
+        startup.stamp("pool", pool_t0, time.monotonic())
         # the devices this engine's programs run on: the TP mesh's, or the
         # process default device. attn_interpret: the pallas kernels run in
         # the Pallas interpreter (any backend but TPU) — never a timing
@@ -335,7 +355,6 @@ class LLMEngine:
         # tracking is always on (work only on first-dispatch-per-shape).
         # Weights/KV-pool byte accounting is shape*dtype math — the KV
         # pool is donated every step but its layout never changes.
-        from ray_tpu.observability import profiling as profiling_mod
         self._prof = profiling_mod.EngineProfiler(
             enabled=bool(cfg.profiling_enabled))
         self._prof.set_memory_layout(
@@ -384,7 +403,7 @@ class LLMEngine:
         # ones, and LLMServer.check_health reports the replica unhealthy
         self.loop_error: Optional[str] = None
         self.stats = {"steps": 0, "prefills": 0, "tokens_out": 0,
-                      "requests": 0, "shed_expired": 0, "compile_s": 0.0,
+                      "requests": 0, "shed_expired": 0,
                       "prefix_hits": 0, "prefix_misses": 0,
                       "prefix_hit_tokens": 0,
                       "spilled_pages": 0, "restored_pages": 0,
@@ -565,6 +584,7 @@ class LLMEngine:
         # chunk) its first token at the row of the slot it arms. With a
         # block length B above 1 a row is the slot's PENDING BLOCK [B]:
         # known tokens, the mask token elsewhere.
+        state_t0 = time.monotonic()
         self._dev_tokens = jnp.zeros(
             (b + 1,) + ((self._block_len,) if self._block_len > 1 else ()),
             jnp.int32)
@@ -592,6 +612,8 @@ class LLMEngine:
             self._pt_dev, self._sl_dev, self._temps_dev, self._dev_tokens = \
                 jax.device_put((self._pt_dev, self._sl_dev, self._temps_dev,
                                 self._dev_tokens), rep)
+        # (the device state beside the pool: the stage's second stamp)
+        startup.stamp("pool", state_t0, time.monotonic())
         self._dirty_slots: dict[int, tuple] = {}  # slot -> (seq_len, temp)
 
         # jitted programs. The KV pool is DONATED, and every paged program
@@ -1036,7 +1058,8 @@ class LLMEngine:
     def start(self):
         if self._loop_thread is None:
             if self.cfg.warmup_compile:
-                self._warmup_decode_programs()
+                with self._startup.stage("warm_decode"):
+                    self._warmup_decode_programs()
             self._loop_thread = threading.Thread(
                 target=self._loop, name="llm-engine", daemon=True)
             self._loop_thread.start()
@@ -1089,17 +1112,20 @@ class LLMEngine:
                             self._temps_dev, idx, drafts)
         # the fixed-shape slot patches (all-trash write of zeros is a no-op)
         didx = self._slot_index((), trash + 1)
-        self._pt_dev, self._sl_dev, self._temps_dev = self._patch_state(
-            self._pt_dev, self._sl_dev, self._temps_dev, didx,
-            np.zeros((trash + 1, self._table_width), np.int32),
-            np.zeros((trash + 1,), np.int32),
-            np.zeros((trash + 1,), np.float32))
-        self._dev_tokens = self._patch_toks(
-            self._dev_tokens, didx,
-            np.zeros(self._dev_tokens.shape, np.int32))
+        with self._prof.compile_scope("patch", ("patch", "state")):
+            self._pt_dev, self._sl_dev, self._temps_dev = self._patch_state(
+                self._pt_dev, self._sl_dev, self._temps_dev, didx,
+                np.zeros((trash + 1, self._table_width), np.int32),
+                np.zeros((trash + 1,), np.int32),
+                np.zeros((trash + 1,), np.float32))
+        with self._prof.compile_scope("patch", ("patch", "toks")):
+            self._dev_tokens = self._patch_toks(
+                self._dev_tokens, didx,
+                np.zeros(self._dev_tokens.shape, np.int32))
         # the key split of the first prefill (both halves dropped: the
         # loop's key is what it would be without this)
-        self._split_key(self._rng)
+        with self._prof.compile_scope("split_key", ("split_key",)):
+            self._split_key(self._rng)
         if self._kv_tier_on:
             # the tier-restore scatter too: its one fixed shape would
             # otherwise compile on the first tier hit, mid-traffic (an
@@ -1479,8 +1505,6 @@ class LLMEngine:
         # introspection (observability/profiling.py): per-phase p50/p95 +
         # itl_s (None until sampled / while profiling_enabled=False),
         # compile-event counters (always live), device-memory gauges.
-        # compile_s is the profiler's measured total — the stats-dict slot
-        # predates the tracker and is overridden here.
         out.update(self._prof.phase_stats())
         # stalls of the loop's host: spans of host work whose own time
         # reached profiling.STALL_S, and the process's garbage collector
@@ -1492,6 +1516,10 @@ class LLMEngine:
         out["compile_events"] = self._prof.compile_events
         out["mid_traffic_compiles"] = self._prof.mid_traffic_compiles
         out["compile_s"] = round(self._prof.compile_s, 3)
+        # the process's start-up ledger: ``startup`` (stages, every first
+        # dispatch's record, what compiled under no scope; its lists are
+        # the ledger's own, not copied) and the flat ``startup_*`` totals
+        out.update(self._startup.stats())
         # paged-attention backend surface (ISSUE 18): which kernel family
         # this replica compiled in (string + a numeric twin exporters can
         # gauge), plus how many attention-bearing programs — decode /

@@ -13,6 +13,7 @@ import time
 import uuid
 from typing import Any, Iterator, Optional
 
+from ray_tpu.observability import profiling
 from ray_tpu.serve.llm.config import LLMConfig
 from ray_tpu.serve.llm.engine import LLMEngine
 
@@ -76,6 +77,8 @@ _EXPORTED_STATS = (
     # introspection scalars (ISSUE 6): compile tracker + memory gauges;
     # None-valued entries (no samples yet / cpu backend) are skipped
     "compile_events", "mid_traffic_compiles", "compile_s",
+    # the start-up ledger's flat totals (never the nested ``startup``)
+    *profiling.STARTUP_TOTALS,
     "weights_bytes", "kv_pool_bytes", "kv_page_occupancy",
     "device_bytes_in_use", "device_peak_bytes", "itl_s",
     # stalls of the loop's host (ISSUE 39): spans of host work past 50 ms,
@@ -155,6 +158,10 @@ class LLMServer:
     SURVEY.md §7 hard-part 7)."""
 
     def __init__(self, llm_config: LLMConfig | dict):
+        # the process's start-up ledger: a worker has waited for this
+        # constructor since it registered (core/worker_main.py)
+        startup = profiling.startup()
+        startup.stamp_since_last("actor_wait", after="worker_boot")
         if isinstance(llm_config, dict):
             llm_config = LLMConfig(**llm_config)
         self.cfg = llm_config
@@ -180,6 +187,7 @@ class LLMServer:
             signal.signal(signal.SIGTERM, _on_term)
         except (ValueError, OSError, RuntimeError):
             pass
+        startup.mark_ready()
 
     # ---- OpenAI-shaped endpoints --------------------------------------
     def completions(self, payload: dict) -> Any:

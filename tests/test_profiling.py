@@ -162,7 +162,7 @@ def test_capture_round_trip_local(tmp_path):
     # each process writes under a subdirectory of its own
     logdir = os.path.join(given, str(os.getpid()))
     assert info["logdir"] == logdir
-    assert prof.capture_status()["active"]
+    assert prof._capture.active
     # double-start is refused while a capture is live
     with pytest.raises(RuntimeError):
         prof.start_capture(str(tmp_path / "other"))
@@ -170,7 +170,7 @@ def test_capture_round_trip_local(tmp_path):
     out = prof.stop_capture()
     assert out["logdir"] == logdir
     assert out["duration_s"] > 0.0
-    assert not prof.capture_status()["active"]
+    assert not prof._capture.active
     # the profiler writes <logdir>/plugins/profile/<run>/...
     plugin_dir = os.path.join(logdir, "plugins", "profile")
     assert os.path.isdir(plugin_dir)
@@ -283,7 +283,7 @@ def test_span_without_capture_makes_no_annotation(monkeypatch):
         raise AssertionError("TraceAnnotation created with no capture")
 
     monkeypatch.setattr(jax.profiler, "TraceAnnotation", boom)
-    assert not prof.capture_status()["active"]
+    assert not prof._capture.active
     p = prof.EngineProfiler(enabled=True)
     for i in range(3):
         with p.span("decode_dispatch", seq=i, k=8) as sp:

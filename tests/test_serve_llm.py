@@ -246,6 +246,43 @@ def test_openai_http_completions(llm_app):
     assert models["data"][0]["id"] == "llama-tiny"
 
 
+def test_replica_reports_its_start_up_ledger(llm_app):
+    """Through serve.run: the replica's worker stamps ``worker_boot`` from
+    its process's creation and ``actor_wait`` up to the deployment's
+    constructor, then the engine's stages; ``/v1/stats`` carries them."""
+    from ray_tpu.observability import profiling
+
+    with urllib.request.urlopen(f"{llm_app}/v1/stats", timeout=60) as r:
+        stats = json.loads(r.read())
+    su = stats["startup"]
+    stages = su["stages"]
+    names = [n for n, _s, _d in stages]
+    assert names[:2] == ["worker_boot", "actor_wait"]
+    assert names[-1] == "ready" and set(names) == set(profiling.STARTUP_STAGES)
+    # created precedes every stamp; the first stage starts AT it
+    assert stages[0][1] == su["created"] and stages[0][2] > 0
+    end = su["created"]
+    for _n, start, seconds in stages:
+        assert start >= end - 1e-6 and seconds >= 0
+        end = start + seconds
+    assert su["ready"] == stages[-1][1]
+    assert stats["startup_s"] == pytest.approx(su["ready"] - su["created"],
+                                               abs=2e-3)
+    # the replica's clock and this process's are the machine's: ready lies
+    # behind us, the worker's creation after this test process's
+    assert profiling.startup().created < su["created"] < su["ready"] \
+        < time.monotonic()
+    name, is_main = su["built_on"]
+    assert isinstance(name, str) and is_main is False
+    assert {tuple(p["sig"]) for p in su["programs"]} >= {
+        ("split_key",), ("patch", "state"), ("patch", "toks")}
+    # warmed on the thread that built the engine, never on the loop's
+    assert all(p["thread"] != "llm-engine" for p in su["programs"]
+               if not p["mid_traffic"])
+    for key in profiling.STARTUP_TOTALS:
+        assert key in stats
+
+
 def test_openai_http_streaming(llm_app):
     status, body = _post(
         f"{llm_app}/v1/completions",
