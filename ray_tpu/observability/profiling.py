@@ -34,7 +34,7 @@ runtime to find the next one):
   phase `gc`.
 - **Compile-event tracking** (`compile_scope`): every jit entry point's
   first dispatch per static signature (prefill bucket, chunk length,
-  decode (width, block), verify width) is timed as a compile event and
+  decode width, verify width) is timed as a compile event and
   split into tracing, lowering and the backend's compile or, on a hit of
   the persistent cache, load (`_Parts`: jax's own monitoring events,
   attributed to the scope open on the thread that fired them).
@@ -778,10 +778,10 @@ class EngineProfiler:
 
     def compile_count(self, kinds) -> int:
         """Compiled-program count for the given scope kinds (each sig's
-        first element is its kind — e.g. ("decode", w, k)). Feeds the
+        first element is its kind — e.g. ("decode", w)). Feeds the
         per-kernel compile counters in engine_stats(): with warmup on,
         this number is reached before traffic and must then stay flat
-        (the compile-once contract per (width, k) tier)."""
+        (the compile-once contract of a width's program)."""
         kinds = tuple(kinds)
         with self._lock:
             return sum(1 for s in self._seen

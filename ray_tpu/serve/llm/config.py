@@ -66,8 +66,9 @@ class LLMConfig:
     # for the oldest of them (engine.py _select_block, _step).
     #
     # decode_block: the CEILING of k, the decode steps fused into one
-    # dispatched program. With nothing queued the engine dispatches the
-    # smallest warmed tier that keeps the device fed (one step, then the
+    # dispatched program (and the rows of that program's token buffer:
+    # k is its operand). With nothing queued the engine dispatches the
+    # smallest tier that keeps the device fed (one step, then the
     # pressure tier's k) and climbs to this value only while it sees the
     # device run dry with less (lead.py; engine_stats idle_lead_k). Streaming
     # granularity and stop-token lag grow with k. With a block length B
@@ -89,8 +90,8 @@ class LLMConfig:
     # idles 0.024 % of a trace; PERF.md section 6, PR 42).
     pipeline_depth: int = 3
 
-    # compile all (bucket width, block) decode programs at start() instead
-    # of on first use mid-traffic (a compile stalls every active request)
+    # compile every bucket width's decode program at start() instead of
+    # on first use mid-traffic (a compile stalls every active request)
     warmup_compile: bool = True
 
     # Engine performance introspection (observability/profiling.py):

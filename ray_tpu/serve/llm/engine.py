@@ -546,7 +546,7 @@ class LLMEngine:
         # the tier that chose it
         self._last_block = 0
         self._last_tier = "idle"
-        # the idle tier's k (lead.py): the smallest of the three warmed
+        # the idle tier's k (lead.py): the smallest of the three
         # tiers until the loop sees the device run dry with it, then the
         # next; decode_block at most
         self._collector = profiling_mod.watch_gc()
@@ -619,7 +619,7 @@ class LLMEngine:
         # jitted programs. The KV pool is DONATED, and every paged program
         # (kv_cache.paged_*) carries it through its loops as a carry that
         # is only ever scattered into — the scan over layers, and around it
-        # here the scan over a block's steps — so the donated argument, the
+        # here the loop over a block's steps — so the donated argument, the
         # loop carries and the returned pool are ONE buffer: no program
         # copies, slices or restacks the pool, and a program's temporaries
         # are megabytes beside the pool's gigabytes (tests/test_pool_carry.py
@@ -627,8 +627,9 @@ class LLMEngine:
         # materialize a second full pool. The decode program gathers the
         # packed active rows by index on device, runs the fused block at the
         # PACKED width, and scatters the carried state back — one program
-        # per (bucket width, block length), so a lightly loaded engine pays
-        # for the requests it has, not for max_batch_size.
+        # a bucket width, so a lightly loaded engine pays for the requests
+        # it has, not for max_batch_size. The number of steps (``n``) is an
+        # operand: the tiers of k are dispatches of the width's one program.
         # With a block length above 1 the same slot in the loop holds the
         # block program (_block_impl: n whole blocks a dispatch), whose
         # last output is its counts (experts touched, fused passes, blocks
@@ -638,7 +639,7 @@ class LLMEngine:
         self._decode = jax.jit(
             lambda params, kv, pt, sl, toks, rng, temp, idx, n:
             decode_impl(params, kv, pt, sl, toks, rng, temp, idx, n),
-            donate_argnums=(1, 3, 4), static_argnums=(8,))
+            donate_argnums=(1, 3, 4))
         # verify-k (speculative decoding): same packed-width shape as
         # _decode, but the scan consumes the DRAFTED tokens instead of its
         # own samples; the draft length is static via drafts.shape — one
@@ -711,17 +712,71 @@ class LLMEngine:
         return mesh
 
     # ---- compiled impls ------------------------------------------------
+    def _run_steps(self, one, num, carry, counts):
+        """``num`` iterations of ``one`` in one ``lax.while_loop``. ``num``
+        is an OPERAND of the program (a traced int32 scalar), not a static
+        argument: the tiers of k (_select_block) are dispatches of ONE
+        program a width, whose loop body is traced, lowered and compiled
+        once. ``one(carry)`` gives (carry, the iteration's tokens, its
+        counts): the tokens go to row i of a buffer of the CEILING tier's
+        rows (_blocks_of(decode_block); the rows past ``num`` stay zeros
+        the host never reads), the counts (int32 like ``counts``, or None)
+        are summed. The carry holds the donated pool as a scan's would, so
+        it still aliases (tests/test_pool_carry.py). Returns what a
+        ``lax.scan(one, carry, length=num)`` returns, bit for bit: the
+        carry, the tokens [rows, ...] and the summed counts."""
+        jax, jnp = self._jax, self._jnp
+        rows = self._blocks_of(self.cfg.decode_block)
+
+        def body(state):
+            i, carry, buf, total = state
+            carry, toks, n = one(carry)
+            return (i + 1, carry,
+                    jax.lax.dynamic_update_index_in_dim(buf, toks, i, 0),
+                    jax.tree.map(jnp.add, total, n))
+
+        toks0 = carry[2]            # a step's tokens: a row of the buffer
+        _, carry, buf, counts = jax.lax.while_loop(
+            lambda state: state[0] < num, body,
+            (jnp.int32(0), carry,
+             jnp.zeros((rows,) + toks0.shape, toks0.dtype), counts))
+        return carry, buf, counts
+
+    def _decode_one(self, params, pt, temps, idx):
+        """One decode step at the packed width as a function of the carry
+        (pool, lengths, tokens, key): the body of the decode program's
+        loop. Gives the carry after the step, the step's tokens [W] and,
+        for a routed block, what it touched (_experts_touched; else
+        None)."""
+        jax = self._jax
+
+        @jax.named_scope("decode_step")
+        def one(carry):
+            kv_c, lens, toks, key = carry
+            key, sub = jax.random.split(key)
+            logits, kv_c, lens = self._kvc.paged_decode_step(
+                params, kv_c, pt, lens, toks, self.model_cfg,
+                self.cfg.page_size, self._attn_backend, mesh=self._mesh)
+            toks = self._kvc.sample_tokens(
+                logits, sub, temps, self.cfg.top_k)
+            return (kv_c, lens, toks, key), toks, \
+                self._experts_touched(kv_c, idx)
+
+        return one
+
     def _decode_impl(self, params, kv, pt_full, sl_full, toks_full, rng,
-                     temps_full, idx, num_steps: int = 1):
-        """num_steps fused decode iterations in ONE program (lax.scan), at
-        the PACKED width ``len(idx)``.
+                     temps_full, idx, num_steps):
+        """``num_steps`` fused decode iterations in ONE program (_run_steps:
+        the count is an operand), at the PACKED width ``len(idx)``.
 
         Every host->device dispatch has a fixed cost; fusing K steps
         amortizes it to 1/K per token (the standard TPU serving shape —
         cf. multi-step decode in TPU LLM stacks). ``idx``
         selects the active slots (padded with the trash row); the gather /
-        scatter of the [W]-sized state stays on device. Returns all K
-        sampled tokens [K, W] plus the full-size carried state."""
+        scatter of the [W]-sized state stays on device. Returns the
+        sampled tokens [_blocks_of(decode_block), W], of which rows
+        [:num_steps] are the dispatch's, plus the full-size carried
+        state."""
         jax = self._jax
         jnp = self._jnp
         # named scopes (compile-time metadata): a trace names the
@@ -733,21 +788,12 @@ class LLMEngine:
                 lens0 = sl_full[idx]
                 toks0 = toks_full[idx]
                 temps = temps_full[idx]
-
-            @jax.named_scope("decode_step")
-            def one(carry, _):
-                kv_c, lens, toks, key = carry
-                key, sub = jax.random.split(key)
-                logits, kv_c, lens = self._kvc.paged_decode_step(
-                    params, kv_c, pt, lens, toks, self.model_cfg,
-                    self.cfg.page_size, self._attn_backend, mesh=self._mesh)
-                toks = self._kvc.sample_tokens(
-                    logits, sub, temps, self.cfg.top_k)
-                return (kv_c, lens, toks, key), (
-                    toks, self._experts_touched(kv_c, idx))
-
-            (kv, new_lens, last, rng), (all_toks, touched) = jax.lax.scan(
-                one, (kv, lens0, toks0, rng), None, length=num_steps)
+            (kv, new_lens, last, rng), all_toks, touched = self._run_steps(
+                self._decode_one(params, pt, temps, idx), num_steps,
+                (kv, lens0, toks0, rng),
+                # (what a routed block's steps touched: _experts_touched)
+                jnp.zeros((2,), jnp.int32)
+                if self._cache_spec.routed_layers else None)
             # padding lanes must not accumulate garbage into the trash row
             # (its seq_len would creep toward int32 overflow on a
             # long-lived engine): pin it back to zero on scatter
@@ -758,8 +804,7 @@ class LLMEngine:
                 toks_full = toks_full.at[idx].set(last)
         if touched is not None:
             # a routed block: one more output, harvested with the tokens
-            return (all_toks, toks_full, kv, sl_full, rng,
-                    jnp.sum(touched, axis=0))
+            return all_toks, toks_full, kv, sl_full, rng, touched
         return all_toks, toks_full, kv, sl_full, rng
 
     def _experts_touched(self, kv, idx, rows_a_slot: int = 1):
@@ -790,14 +835,13 @@ class LLMEngine:
                               expert.product_visits(
                                   sizes, live.shape[0] * spec.top_k)])
 
-    def _block_impl(self, params, kv, pt_full, sl_full, toks_full, rng,
-                    temps_full, idx, num_blocks: int = 1):
-        """The block program (generation by diffusion over blocks; the
-        model's block length B, ``denoise_passes`` S and ``reveal_per_pass``
-        n): ``num_blocks`` whole blocks in ONE program, at the packed
-        width W = ``len(idx)``, as _decode_impl is ``num_steps`` steps.
+    def _block_one(self, params, pt, temps, idx):
+        """One whole block a slot at the packed width as a function of the
+        carry (pool, lengths, pending blocks, key): the body of the block
+        program's loop (generation by diffusion over blocks; the model's
+        block length B, ``denoise_passes`` S and ``reveal_per_pass`` n).
 
-        A block costs S passes: THE COMMIT IS DEFERRED. A scan iteration
+        A block costs S passes: THE COMMIT IS DEFERRED. An iteration
         is one pass over 2B positions a slot (kv_cache.
         paged_block_pair_step: the slot's pending block, then an
         all-masked one) and S - 1 denoise passes of B
@@ -821,20 +865,18 @@ class LLMEngine:
         whose sampled token is most probable take that token; greedy at
         temperature 0; the mask token's own logit is left out, it is never
         produced. The last block of a stream is never committed: nothing
-        reads it. ``toks_full`` [B+1 rows, B]: every slot's pending block.
-        Returns the blocks' tokens as [num_blocks x B, W] (row j x B + i:
-        position i of block j, known tokens included: the host skips the
-        ones a prompt left), the carried state, and the program's counts,
-        int32 [4]: experts touched over all passes (0 without routed
-        experts), passes of 2B in which a live slot kept a block and
-        denoised the next, blocks kept (live slots summed), and the visits
-        of the routed layers' grouped products (_experts_touched)."""
+        reads it. Gives the carry after the block, the clean blocks [W, B]
+        and the iteration's counts, int32 [4]: experts touched over its
+        passes (0 without routed experts), whether its pass of 2B kept a
+        block for a live slot and denoised the next, blocks kept (live
+        slots summed), and the visits of the routed layers' grouped
+        products (_experts_touched)."""
         jax = self._jax
         jnp = self._jnp
         mcfg = self.model_cfg
         b, mask_id = self._block_len, self._cache_spec.mask_token
         n_reveal = mcfg.reveal_per_pass
-        trash = self.cfg.max_batch_size
+        live = idx != self.cfg.max_batch_size
 
         how = dict(cfg=mcfg, page_size=self.cfg.page_size,
                    attn_backend=self._attn_backend, mesh=self._mesh)
@@ -861,52 +903,66 @@ class LLMEngine:
             reveal = jnp.any(best[..., None] == jnp.arange(b), axis=1)
             return jnp.where(reveal & masked, tok, blk)
 
+        @jax.named_scope("block_step")
+        def one(carry):
+            kv_c, lens, blk, key = carry
+            fresh = jnp.full_like(blk, mask_id)
+            key, sub = jax.random.split(key)
+            with jax.named_scope("fused"):
+                logits, kv_c, lens, kept = \
+                    self._kvc.paged_block_pair_step(
+                        params, kv_c, pt, lens,
+                        jnp.concatenate([blk, fresh], axis=1), **how)
+                with jax.named_scope("unmask"):
+                    blk = unmask(
+                        logits, jnp.where(kept[:, None], fresh, blk),
+                        sub)
+                n_touched = touched(kv_c, 2 * b)
+            for _s in range(mcfg.denoise_passes - 1):
+                key, sub = jax.random.split(key)
+                with jax.named_scope("denoise"):
+                    logits, kv_c, _ = self._kvc.paged_block_step(
+                        params, kv_c, pt, lens, blk, **how,
+                        commit=False)
+                    with jax.named_scope("unmask"):
+                        blk = unmask(logits, blk, sub)
+                    n_touched += touched(kv_c, b)
+            kept = kept & live
+            return (kv_c, lens, blk, key), blk, jnp.stack(
+                [n_touched[0], jnp.any(kept).astype(jnp.int32),
+                 jnp.sum(kept, dtype=jnp.int32), n_touched[1]])
+
+        return one
+
+    def _block_impl(self, params, kv, pt_full, sl_full, toks_full, rng,
+                    temps_full, idx, num_blocks):
+        """The block program: ``num_blocks`` whole blocks (_block_one) in
+        ONE program, at the packed width W = ``len(idx)``, as _decode_impl
+        is ``num_steps`` steps (_run_steps: the count is an operand).
+        ``toks_full`` [B+1 rows, B]: every slot's pending block. Returns
+        the blocks' tokens as [rows x B, W], rows the ceiling tier's blocks
+        (row j x B + i: position i of block j, known tokens included: the
+        host skips the ones a prompt left, and reads no row past
+        ``num_blocks`` x B), the carried state, and the program's counts
+        (_block_one's, summed over the blocks)."""
+        jax = self._jax
+        jnp = self._jnp
         with jax.named_scope("block_program"):
             with jax.named_scope("gather_state"):
                 pt = pt_full[idx]
                 lens0 = sl_full[idx]
                 blk0 = toks_full[idx]
                 temps = temps_full[idx]
-            live = idx != trash
-
-            @jax.named_scope("block_step")
-            def one(carry, _):
-                kv_c, lens, blk, key = carry
-                fresh = jnp.full_like(blk, mask_id)
-                key, sub = jax.random.split(key)
-                with jax.named_scope("fused"):
-                    logits, kv_c, lens, kept = \
-                        self._kvc.paged_block_pair_step(
-                            params, kv_c, pt, lens,
-                            jnp.concatenate([blk, fresh], axis=1), **how)
-                    with jax.named_scope("unmask"):
-                        blk = unmask(
-                            logits, jnp.where(kept[:, None], fresh, blk),
-                            sub)
-                    n_touched = touched(kv_c, 2 * b)
-                for _s in range(mcfg.denoise_passes - 1):
-                    key, sub = jax.random.split(key)
-                    with jax.named_scope("denoise"):
-                        logits, kv_c, _ = self._kvc.paged_block_step(
-                            params, kv_c, pt, lens, blk, **how,
-                            commit=False)
-                        with jax.named_scope("unmask"):
-                            blk = unmask(logits, blk, sub)
-                        n_touched += touched(kv_c, b)
-                kept = kept & live
-                return (kv_c, lens, blk, key), (blk, jnp.stack(
-                    [n_touched[0], jnp.any(kept).astype(jnp.int32),
-                     jnp.sum(kept, dtype=jnp.int32), n_touched[1]]))
-
-            (kv, new_lens, last, rng), (blocks, counts) = jax.lax.scan(
-                one, (kv, lens0, blk0, rng), None, length=num_blocks)
+            (kv, new_lens, last, rng), blocks, counts = self._run_steps(
+                self._block_one(params, pt, temps, idx), num_blocks,
+                (kv, lens0, blk0, rng), jnp.zeros((4,), jnp.int32))
             all_toks = jnp.swapaxes(blocks, 1, 2).reshape(
-                num_blocks * b, -1)                           # [n x B, W]
+                blocks.shape[0] * self._block_len, -1)     # [rows x B, W]
             with jax.named_scope("scatter_state"):
                 sl_full = sl_full.at[idx].set(
-                    jnp.where(live, new_lens, 0))
+                    jnp.where(idx != self.cfg.max_batch_size, new_lens, 0))
                 toks_full = toks_full.at[idx].set(last)
-        return all_toks, toks_full, kv, sl_full, rng, jnp.sum(counts, axis=0)
+        return all_toks, toks_full, kv, sl_full, rng, counts
 
     def _pending_block(self, tokens, start, true_len):
         """The block a prompt leaves pending (traced; a prefill or chunk
@@ -1065,38 +1121,36 @@ class LLMEngine:
             self._loop_thread.start()
 
     def _warmup_decode_programs(self):
-        """Compile every (bucket width, block length) decode program before
+        """Compile the decode program of every bucket width before
         serving: a first-use compile mid-traffic stalls ALL active
         generations for the whole XLA compile (seconds to tens of
-        seconds) and wrecks tail latency. All-trash index vectors
-        make the warmup dispatches write only into the trash page. The
-        operands are built as the loop builds them (numpy, or what a
-        program returned), so traffic finds these very cache entries."""
+        seconds) and wrecks tail latency. ONE program a width: the number
+        of steps is its operand (_decode_impl), so every k _select_block
+        can return is a dispatch of it; it is run here at the ceiling
+        tier's k, which fills every row of its token buffer. All-trash
+        index vectors make the warmup dispatches write only into the
+        trash page. The operands are built as the loop builds them (numpy,
+        or what a program returned), so traffic finds these very cache
+        entries."""
         trash = self.cfg.max_batch_size
         # derive from _bucket_width so the warmed set can never diverge
         # from the widths _step actually dispatches
         widths = sorted({self._bucket_width(n)
                          for n in range(1, self.cfg.max_batch_size + 1)})
-        tiers = {self._blocks_of(1),
-                 self._blocks_of(min(self.cfg.pressure_decode_block,
-                                     self.cfg.decode_block)),
-                 self._blocks_of(self.cfg.decode_block)}
-        if self._spec_on:
-            # the spec-capped idle tier (_select_block) dispatches too
-            tiers.add(min(self.cfg.decode_block,
-                          max(1, self.cfg.spec_draft_len)))
+        k = np.int32(self._blocks_of(self.cfg.decode_block))
         for w in widths:
             idx = self._slot_index((), w)
-            for k in tiers:
-                # compile_scope registers each (width, block) signature so
-                # the traffic-path scopes see it as already compiled; a
-                # warmup compile is by definition not mid-traffic
-                with self._prof.compile_scope("decode", ("decode", w, k)):
-                    _all, self._dev_tokens, self.kv, self._sl_dev, \
-                        self._rng, *_touched = self._decode(
-                            self.params, self.kv, self._pt_dev,
-                            self._sl_dev, self._dev_tokens, self._rng,
-                            self._temps_dev, idx, k)
+            # compile_scope registers the width's signature so the
+            # traffic-path scopes see it as already compiled; a warmup
+            # compile is by definition not mid-traffic. The key that comes
+            # back is dropped (it is not donated): the loop's key, and so
+            # a seed's sampled streams, do not depend on what was warmed
+            with self._prof.compile_scope("decode", ("decode", w)):
+                _all, self._dev_tokens, self.kv, self._sl_dev, \
+                    _key, *_touched = self._decode(
+                        self.params, self.kv, self._pt_dev,
+                        self._sl_dev, self._dev_tokens, self._rng,
+                        self._temps_dev, idx, k)
             if self._spec_on:
                 # the verify-k program per width too: an uncompiled verify
                 # stalls the first speculative round mid-traffic exactly
@@ -1106,7 +1160,7 @@ class LLMEngine:
                 with self._prof.compile_scope(
                         "verify", ("verify", w, self.cfg.spec_draft_len)):
                     _all, self._dev_tokens, self.kv, self._sl_dev, \
-                        self._rng = self._verify(
+                        _key = self._verify(
                             self.params, self.kv, self._pt_dev,
                             self._sl_dev, self._dev_tokens, self._rng,
                             self._temps_dev, idx, drafts)
@@ -1534,6 +1588,9 @@ class LLMEngine:
         out["device_count"] = len(self._devices)
         out["attn_interpret"] = self._attn_interpret
         out["attn_walks_live"] = list(self._attn_walks_live)
+        # decode programs this engine has dispatched: one a bucket width
+        # once start() has warmed them, whatever k traffic then runs
+        out["decode_programs"] = self._prof.compile_count(("decode",))
         out["attn_writes_in_kernel"] = list(self._attn_writes_in_kernel)
         out["attn_sink_calls"] = list(self._attn_sink_calls)
         # {pool: [a head's lanes, the lanes stored for it]}: the padding
@@ -2582,10 +2639,12 @@ class LLMEngine:
             req.finished_at = time.monotonic()
 
     def _select_block(self) -> int:
-        """Decode-block tier for the next dispatch (lock held). k is
-        STATIC to the jitted program, and every value returned here is
-        one start() warmed (_warmup_decode_programs), so nothing compiles
-        under traffic. Three tiers, by what the queue shows:
+        """Decode-block tier for the next dispatch (lock held). k is an
+        OPERAND of the width's one decode program, whose token buffer has
+        the ceiling tier's rows: every value returned here is at most
+        that, so nothing compiles under traffic (start() warmed the
+        program: _warmup_decode_programs). Three tiers, by what the queue
+        shows:
 
         * admissions blocked (requests wait though slots are free, or a
           chunked prefill is mid-flight): ONE step, so page reclamation
@@ -2717,7 +2776,7 @@ class LLMEngine:
 
     def _decode_step(self) -> bool:
         """Dispatch one fused decode block (_select_block's k steps: one
-        of the warmed tiers, decode_block at most) without waiting for
+        of the tiers, decode_block at most) without waiting for
         its result; _step harvests PIPELINE_DEPTH entries behind. Device
         execution is a single ordered stream, so an in-flight block that
         still references a freed slot's pages runs BEFORE any later
@@ -2837,12 +2896,12 @@ class LLMEngine:
             snapshot = [(col, slot, req, *skips[col:col + 1])
                         for col, (_c, slot, req) in enumerate(snapshot)]
             with self._prof.compile_scope(
-                    "decode", ("decode", w, k),
+                    "decode", ("decode", w),
                     mid_traffic=self.stats["requests"] > 0):
                 all_toks, self._dev_tokens, self.kv, self._sl_dev, \
                     self._rng, *touched = self._decode(
                         self.params, self.kv, self._pt_dev, self._sl_dev,
-                        toks, self._rng, self._temps_dev, idx, k)
+                        toks, self._rng, self._temps_dev, idx, np.int32(k))
             self._newest = all_toks
             self._start_fetch(all_toks)
             dev_touched = touched[0] if touched else None
@@ -3037,9 +3096,10 @@ class LLMEngine:
     def _harvest_one(self) -> None:
         """Block on the OLDEST in-flight block's tokens and record them.
 
-        Entries are decode blocks (tokens [k, W] at the PACKED bucket
-        width — the column is the request's position in that block's
-        packed index vector, NOT its slot id), prefill first-tokens
+        Entries are decode blocks (tokens at the PACKED bucket width, of
+        which rows [:k] are the dispatch's: the program's buffer has the
+        ceiling tier's rows — the column is the request's position in that
+        block's packed index vector, NOT its slot id), prefill first-tokens
         (scalar, column 0) with snapshot rows (token_column, slot,
         request), or verify-k rounds (meta ("spec", k), handled by
         _apply_verify)."""
@@ -3087,7 +3147,9 @@ class LLMEngine:
             self.stats["fused_passes_total"] += fused
             self.stats["slot_passes_total"] += passes * len(snapshot)
             self.stats["blocks_committed_total"] += kept
-        host_toks = host_toks.reshape(k * bl, -1)
+        # (a prefill's first token is a scalar; a block's rows past its k
+        # are the buffer's, never the dispatch's)
+        host_toks = np.atleast_2d(host_toks)[: k * bl]
         # emit: what follows the sync on the host — up to k x w
         # _record_token calls under the lock, then the completion tail
         with self._prof.span("emit", seq=seq) as sp:

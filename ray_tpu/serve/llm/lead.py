@@ -7,7 +7,7 @@ With callers waiting the engine picks k by the queue's state
 (``_select_block``); with none waiting there is capacity to spare, and the
 only reason to dispatch more than the smallest tier is a host that cannot
 keep the device fed with it. This module holds that one decision: the idle
-tier's k is the SMALLEST of the tiers the engine warmed (one step, the
+tier's k is the SMALLEST of the engine's tiers (one step, the
 pressure tier's, ``decode_block``), and climbs towards ``decode_block`` only
 when the loop sees the device run dry (engine.py ``_dry``) through its own
 fault, again and again.
@@ -45,8 +45,8 @@ HOLD_MAX = 64
 
 
 class IdleLead:
-    """The idle tier's k. ``tiers``: the k of the decode programs the
-    engine warmed (one step, its pressure tier, its ``decode_block``);
+    """The idle tier's k. ``tiers``: the k the engine dispatches its
+    decode program at (one step, its pressure tier, its ``decode_block``);
     ``k`` is always one of them, the smallest at first. One writer: the
     loop thread."""
 

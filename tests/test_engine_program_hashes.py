@@ -48,7 +48,15 @@ longer traced: ``kv_cache.paged_chunk_walk``). ``PINNED`` below holds what
 that PR's tree lowered, a (block, backend), so that a later rewrite of the
 file cannot move a program unseen; it took the place of three pins
 (``PARENT_DENSE``, ``PARENT_53``, ``PARENT_55``) that had come to hold the
-same tree.
+same tree. ISSUE 58 makes the number of steps (or whole blocks) of a
+dispatch an OPERAND of the decode program: the 34 recorded decode programs
+(``decode_1/4/8``, SDAR's ``decode_1/2``) are 12, one a (block, backend),
+recorded under ``decode`` and pinned again; what the 34 were is held in
+their place by ``test_the_operand_program_at_k_is_a_scan_of_k_steps``: the
+one program run at k against a ``lax.scan`` of k of the same step, on
+operands a live engine dispatched, bit for bit. Every OTHER program
+(verify, prefill, chunk: 28) lowers to the parent's text under the parent's
+scopes, and ``PARENT_57`` holds that apart from ``PINNED``.
 
 A PR that MEANS to change one of these programs rewrites the file and says
 so: ``python tests/test_engine_program_hashes.py`` (from the repo's root).
@@ -87,15 +95,18 @@ BLOCKS = {"dense": lambda: llama.llama_tiny(vocab_size=512),
           "afmoe": afmoe.afmoe_tiny,
           "mimo": mimo.mimo_tiny}
 BACKENDS = ("gather", "pallas")
-PROGRAMS = ("decode_1", "decode_4", "decode_8", "verify", "prefill_32",
-            "chunk_16")
-# a block length of 4: the tiers of 1, 4 and 8 tokens are one and two
-# whole blocks (decode_<blocks>); no speculation beside a pending block
-SDAR_PROGRAMS = ("decode_1", "decode_2", "prefill_32", "chunk_16")
+PROGRAMS = ("decode", "verify", "prefill_32", "chunk_16")
+# (no speculation beside a pending block)
+SDAR_PROGRAMS = ("decode", "prefill_32", "chunk_16")
 CASES = [(blk, backend, prog) for blk in BLOCKS for backend in BACKENDS
          for prog in (SDAR_PROGRAMS if blk == "sdar" else PROGRAMS)
          if not (blk in ("lfm2", "afmoe", "mimo") and prog == "verify")]
 # (no verify program: slot state, and window layers' rings)
+# every k a dispatch can run (the tiers of 1, 4 and 8 tokens: steps, or with
+# SDAR's block length of 4 one and two whole blocks): the parent held a
+# program for each
+STEP_CASES = [(blk, backend, k) for blk in BLOCKS for backend in BACKENDS
+              for k in ((1, 2) if blk == "sdar" else (1, 4, 8))]
 
 
 def _scope_rows(jaxpr, under: str = "") -> list:
@@ -141,7 +152,7 @@ def _traced(eng: LLMEngine, program: str):
     state = (eng.params, eng.kv, eng._pt_dev, eng._sl_dev, eng._dev_tokens,
              eng._rng, eng._temps_dev, idx)
     if kind == "decode":
-        return eng._decode.trace(*state, int(n))
+        return eng._decode.trace(*state, np.int32(1))
     if kind == "verify":
         return eng._verify.trace(*state, np.full(
             (w, eng.cfg.spec_draft_len), -1, np.int32))
@@ -158,11 +169,17 @@ def _traced(eng: LLMEngine, program: str):
 
 
 @functools.cache
+def _engine(block: str, backend: str) -> LLMEngine:
+    """One engine a (block, backend), built once a process."""
+    return LLMEngine(LLMConfig(model_config=BLOCKS[block](),
+                               attention_kernel=backend, **ENGINE))
+
+
+@functools.cache
 def _hashes(block: str, backend: str) -> dict:
-    """One engine a (block, backend), built once a process; each of its
-    programs traced once: (hash of the lowered text, hash of the scopes)."""
-    eng = LLMEngine(LLMConfig(model_config=BLOCKS[block](),
-                              attention_kernel=backend, **ENGINE))
+    """Each program of the (block, backend)'s engine traced once: (hash of
+    the lowered text, hash of the scopes)."""
+    eng = _engine(block, backend)
     out = {}
     for b, k, prog in CASES:
         if (b, k) == (block, backend):
@@ -194,26 +211,36 @@ def test_program_lowers_to_the_recorded_text(recorded, block, backend,
 
 
 # What the last PR that MEANT to move a program recorded, a (block, backend):
-# its (programs, scopes), every entry, as sorted JSON, hashed. ISSUE 56's
-# tree wrote every value (each program ends in a sampler or a head, SDAR's
-# prefill and chunk apart); until then three pins stood here, one for the
-# dense block's twelve programs (ISSUE 50, 54), one for all but the six
-# programs whose kernel writes the call's rows (ISSUE 53) and one a block
-# (ISSUE 55), which after ISSUE 56 all pinned the same tree.
+# its (programs, scopes), every entry, as sorted JSON, hashed. ISSUE 58's
+# tree wrote the decode entry of each (the steps of a dispatch became an
+# operand); every other entry is ISSUE 56's tree's (each program ends in a
+# sampler or a head, SDAR's prefill and chunk apart), which ``PARENT_57``
+# below holds on its own.
 PINNED = {
-    ("afmoe", "gather"): ("1544a414d8e6742a", "6177d2e6031680d3"),
-    ("afmoe", "pallas"): ("2f58010608951db5", "007b490c9725bfe6"),
-    ("dense", "gather"): ("aa52df80876c8bb4", "c0f09ae5f7cc0595"),
-    ("dense", "pallas"): ("6404c648146edd0e", "5fb6e753f3f6df57"),
-    ("joyai", "gather"): ("89ece47f8b6fb55a", "c1bf1e32aad2f305"),
-    ("joyai", "pallas"): ("568c74a9c0c860a6", "fc40df3fb8a0c7b0"),
-    ("lfm2", "gather"): ("b7eb04a908183266", "975dbc9f84da2a0c"),
-    ("lfm2", "pallas"): ("e8485db8ac199b0a", "e9f2694add31644a"),
-    ("mimo", "gather"): ("5cdd87e0cc9f6318", "09e384b219266a31"),
-    ("mimo", "pallas"): ("1c16c6903021953c", "5d76f4b3adab3bc1"),
-    ("sdar", "gather"): ("f9415abf6a4a2a8e", "761664c839cfab88"),
-    ("sdar", "pallas"): ("9ac4149600c0cd8f", "356d33a4e647d20e"),
+    ("afmoe", "gather"): ("7335753175bc5025", "718e71920d0fbe71"),
+    ("afmoe", "pallas"): ("64396d8b5de1485f", "6dd79f73b6792200"),
+    ("dense", "gather"): ("9d39e962040c8f7c", "bc2127eb6ec59536"),
+    ("dense", "pallas"): ("e305a2b1b7bab861", "116838f808d11b14"),
+    ("joyai", "gather"): ("bc56b071827794c3", "d1e09a603e6d8b2c"),
+    ("joyai", "pallas"): ("312034029fb07bda", "063d50367ea3e23a"),
+    ("lfm2", "gather"): ("e0aeff7770d874dc", "c7818c674526ef2d"),
+    ("lfm2", "pallas"): ("0067266e91a7d9b3", "49d87ddf2555df16"),
+    ("mimo", "gather"): ("2e94649e0b0c148c", "60d19042b4136686"),
+    ("mimo", "pallas"): ("7b490b141093dc5e", "0b3dc3fe9c38e833"),
+    ("sdar", "gather"): ("1cfca4cb8846a3e2", "0f8308efca9ee145"),
+    ("sdar", "pallas"): ("22e786ab705593fe", "8ee2d629a1866894"),
 }
+
+
+def _pin(data: dict, block: str, backend: str, decode: bool = True) -> tuple:
+    """(programs, scopes) of a (block, backend) in a record, each as sorted
+    JSON, hashed; without ``decode``, every program but the decode one."""
+    return tuple(hashlib.sha256(json.dumps(
+        {k: v for k, v in data[key].items()
+         if k.startswith(f"{block}-{backend}-")
+         and (decode or not k.endswith("-decode"))},
+        sort_keys=True).encode()).hexdigest()[:16]
+        for key in ("programs", "scopes"))
 
 
 @pytest.mark.parametrize("block,backend", sorted(PINNED),
@@ -222,11 +249,34 @@ def test_no_program_is_recorded_again_unseen(recorded, block, backend):
     """A rewrite of the record (a block added, a program MEANT to move)
     cannot move another block's or backend's programs unseen: a PR that
     means to move these re-pins them here and says so in the header."""
-    for key, want in zip(("programs", "scopes"), PINNED[block, backend]):
-        mine = {k: v for k, v in recorded[key].items()
-                if k.startswith(f"{block}-{backend}-")}
-        assert hashlib.sha256(json.dumps(mine, sort_keys=True).encode()
-                              ).hexdigest()[:16] == want, (block, backend, key)
+    assert _pin(recorded, block, backend) == PINNED[block, backend]
+
+
+# What commit d784841's record (ISSUE 56's tree wrote it) holds for every
+# program BUT the decode ones, a (block, backend): ISSUE 58 recorded the
+# decode programs again (the steps became an operand) and no other.
+PARENT_57 = {
+    ("afmoe", "gather"): ("0318071c036f5855", "3d593530f9433595"),
+    ("afmoe", "pallas"): ("4406906dc0b647e2", "f77aaf89487ddfb9"),
+    ("dense", "gather"): ("8cc48a604031adb5", "9985945bacdc9d7c"),
+    ("dense", "pallas"): ("9056607e1dd6f26b", "e435867eff4aedcd"),
+    ("joyai", "gather"): ("4f178b70acd4a9ec", "9b8c3dc9658ba592"),
+    ("joyai", "pallas"): ("00ad5719e83a4852", "d36039e595c6f124"),
+    ("lfm2", "gather"): ("30e49160fb2f0a39", "01e934d82a65d88d"),
+    ("lfm2", "pallas"): ("4be70bf6cf86ac2d", "fe815c648e186b72"),
+    ("mimo", "gather"): ("391eeec10946fb37", "9db93c3d19a79156"),
+    ("mimo", "pallas"): ("15caa6926c7e58cb", "12b564c9ab2ec9a9"),
+    ("sdar", "gather"): ("c5917877db65c058", "2a97e54a9534c8db"),
+    ("sdar", "pallas"): ("c8e7a767c83aef5b", "51cd9b00ecef8e66"),
+}
+
+
+@pytest.mark.parametrize("block,backend", sorted(PINNED),
+                         ids=["-".join(k) for k in sorted(PINNED)])
+def test_verify_prefill_and_chunk_lower_to_the_parents_text(recorded, block,
+                                                            backend):
+    assert _pin(recorded, block, backend, decode=False) \
+        == PARENT_57[block, backend]
 
 
 @pytest.mark.parametrize("block,backend,program", CASES,
@@ -270,34 +320,174 @@ def test_expert_visits_follow_the_kernels_rule_on_a_hand_made_record():
     assert (touched, visits) == (2 + 3, (2 + 1) + (1 + 1 + 1))
 
 
+def _equal(got, want, what: str):
+    """Two trees of arrays, bit for bit (a key by its raw words)."""
+    got, want = jax.tree.leaves(got), jax.tree.leaves(want)
+    assert len(got) == len(want), what
+    for a, b in zip(got, want):
+        a, b = np.asarray(a), np.asarray(b)
+        assert a.shape == b.shape and a.dtype == b.dtype, what
+        assert a.tobytes() == b.tobytes(), what
+
+
+PROMPTS = ((5, 0.0), (19, 1.0), (12, 0.7))      # (tokens, temperature)
+
+
+def _submit(eng: LLMEngine, max_tokens: int, greedy: bool = False) -> list:
+    """PROMPTS handed to ``eng``; the requests' ids."""
+    rs = np.random.RandomState(58)
+    return [eng.submit(rs.randint(1, 500, size=n).tolist(),
+                       temperature=0.0 if greedy else temp,
+                       max_tokens=max_tokens) for n, temp in PROMPTS]
+
+
+@functools.cache
+def _live(block: str, backend: str):
+    """(engine, the operands of a decode dispatch of its loop as host
+    arrays): three streams, one greedy and two that sample, prefilled and
+    a few dispatches into their decode, the loop driven on this thread.
+    What the loop hands ``_decode`` is copied before the program takes it
+    (the pool, lengths and tokens are donated)."""
+    eng = _engine(block, backend)
+    _submit(eng, max_tokens=64)
+    real, seen = eng._decode, []
+
+    def spy(*operands):
+        seen.append(jax.tree.map(np.asarray, operands[1:8]))
+        return real(*operands)
+
+    eng._decode = spy
+    for _ in range(60):
+        eng._loop_pass()
+        if len(seen) >= 4 and (seen[-1][-1] != eng.cfg.max_batch_size
+                               ).sum() == len(PROMPTS):
+            break
+    else:
+        raise AssertionError("the loop never decoded the three streams")
+    eng._decode = real
+    return eng, seen[-1]
+
+
+def _scan_of(eng: LLMEngine, k: int):
+    """The program the parent compiled for a static k: the state gathered,
+    ``lax.scan`` of k of the engine's own step, the state scattered."""
+    import jax.numpy as jnp
+
+    blocks = eng._block_len > 1
+    trash = eng.cfg.max_batch_size
+
+    def program(params, kv, pt_full, sl_full, toks_full, rng, temps_full,
+                idx):
+        one = (eng._block_one if blocks else eng._decode_one)(
+            params, pt_full[idx], temps_full[idx], idx)
+
+        def step(carry, _):
+            carry, toks, counts = one(carry)
+            return carry, (toks, counts)
+
+        (kv, lens, last, rng), (toks, counts) = jax.lax.scan(
+            step, (kv, sl_full[idx], toks_full[idx], rng), None, length=k)
+        if blocks:                                          # [k x B, W]
+            toks = jnp.swapaxes(toks, 1, 2).reshape(k * eng._block_len, -1)
+        out = (toks, toks_full.at[idx].set(last), kv,
+               sl_full.at[idx].set(jnp.where(idx == trash, 0, lens)), rng)
+        return out if counts is None else out + (jnp.sum(counts, axis=0),)
+
+    return jax.jit(program)
+
+
+@pytest.mark.parametrize("block,backend,k", STEP_CASES,
+                         ids=[f"{b}-{be}-k{k}" for b, be, k in STEP_CASES])
+def test_the_operand_program_at_k_is_a_scan_of_k_steps(block, backend, k):
+    """ISSUE 58: the width's ONE decode program, handed k as an operand,
+    returns the tokens (rows [:k] of its buffer; the rest are never read),
+    the carried tokens, the pool, the lengths, the key and a routed
+    block's counts that the parent's program of a static k (a scan of k
+    steps) returns from the same operands, bit for bit."""
+    eng, operands = _live(block, backend)
+    got = eng._decode(eng.params, *operands, np.int32(k))
+    want = _scan_of(eng, k)(eng.params, *operands)
+    rows = k * eng._block_len
+    assert got[0].shape[0] == eng._blocks_of(ENGINE["decode_block"]) \
+        * eng._block_len >= rows
+    _equal(got[0][:rows], want[0], "tokens")
+    for g, w, what in zip(got[1:], want[1:], (
+            "carried tokens", "pool", "lengths", "key", "counts")):
+        _equal(g, w, what)
+    assert len(got) == len(want)
+    # (the dispatch sampled: a live row asked for a temperature)
+    assert (operands[5][operands[6]] > 0).any()
+
+
+@pytest.mark.parametrize("block", ["dense", "lfm2", "sdar"])
+def test_harvest_reads_only_the_rows_of_its_k(block):
+    """The program's buffer has the ceiling tier's rows whatever k a
+    dispatch ran: with every row past k poisoned before the harvest sees
+    the buffer, the streams are what they were."""
+    streams = []
+    for poison in (False, True):
+        eng = LLMEngine(LLMConfig(model_config=BLOCKS[block](),
+                                  attention_kernel="gather", **ENGINE))
+        rids = _submit(eng, max_tokens=12, greedy=True)
+        real, short = eng._decode, []
+
+        def spoiled(*operands, real=real, eng=eng, short=short):
+            toks, *rest = real(*operands)
+            rows = int(operands[8]) * eng._block_len
+            short.append(rows < toks.shape[0])
+            return (toks.at[rows:].set(-1), *rest)
+
+        if poison:
+            eng._decode = spoiled
+        for _ in range(200):
+            eng._loop_pass()
+            if all(eng._requests[r].done for r in rids):
+                break
+        streams.append([eng.result(r, timeout=1)["tokens"] for r in rids])
+        assert not poison or any(short)
+    assert streams[0] == streams[1]
+    assert all(s and min(s) >= 0 for s in streams[1])
+
+
 TIER_CASES = [("dense", {}), ("dense", {"spec_decode_enabled": True}),
-              ("lfm2", {}), ("sdar", {})]
+              ("lfm2", {}), ("sdar", {}), ("joyai", {}), ("afmoe", {}),
+              ("mimo", {})]
 
 
-@pytest.mark.parametrize("block,over", TIER_CASES,
-                         ids=["dense", "dense-spec", "lfm2", "sdar"])
+@pytest.mark.parametrize("block,over", TIER_CASES, ids=[
+    "dense", "dense-spec", "lfm2", "sdar", "joyai", "afmoe", "mimo"])
 def test_every_k_the_loop_can_pick_is_one_start_warmed(block, over):
-    """``_select_block`` in every state of the queue and at every tier of
-    the idle lead (ISSUE 42) against the k of the decode programs that
-    ``_warmup_decode_programs`` runs: no new program, so no compile under
-    traffic. Without speculation (which caps the idle tier) the loop can
-    reach every warmed tier too."""
+    """``_warmup_decode_programs`` dispatches ONE decode program a bucket
+    width (ISSUE 58: k is its operand), at the ceiling tier's k, and
+    leaves the loop's key as it was. Every k ``_select_block`` can return,
+    in every state of the queue and at every tier of the idle lead (ISSUE
+    42), is at most the rows of that program's buffer, and a dispatch of
+    the warmed program at each such k enters no compile. Without
+    speculation (which caps the idle tier) the loop reaches every tier."""
     from ray_tpu.serve.llm import lead as lead_mod
 
     eng = LLMEngine(LLMConfig(model_config=BLOCKS[block](),
                               attention_kernel="gather",
                               **{**ENGINE, **over}))
-    warmed = set()
+    real, warmed = eng._decode, []
 
     def decode(*operands):          # k is the program's last operand
-        warmed.add(operands[-1])
-        out = (None, eng._dev_tokens, eng.kv, eng._sl_dev, eng._rng)
-        return out + ((None,) if eng._cache_spec.routed_layers else ())
+        warmed.append((operands[7].shape[0], int(operands[8])))
+        return real(*operands)
 
     eng._decode = decode
-    eng._verify = lambda *operands: decode(*operands, None)[:5]
+    key = np.asarray(eng._rng).copy()
     eng._warmup_decode_programs()
-    warmed.discard(None)
+    eng._decode = real
+    np.testing.assert_array_equal(np.asarray(eng._rng), key)
+    rows = eng._blocks_of(ENGINE["decode_block"])
+    widths = sorted({eng._bucket_width(n)
+                     for n in range(1, ENGINE["max_batch_size"] + 1)})
+    assert warmed == [(w, rows) for w in widths]
+    assert real._cache_size() == len(widths)
+    stats = eng.engine_stats()
+    assert stats["decode_programs"] == len(widths)
+    assert stats["compile_events"] > 0
     picked = set()
     for _tier in eng._lead.tiers:
         for waiting, free, prefilling in (([], [0], []), ([object()], [0], []),
@@ -306,12 +496,36 @@ def test_every_k_the_loop_can_pick_is_one_start_warmed(block, over):
             eng._waiting, eng.free_slots = waiting, free
             eng._prefilling = prefilling
             picked.add(eng._select_block())
-        for _ in range(lead_mod.CLIMB_DRY):
+        # (a dry dispatch under a full collection does not count)
+        for _ in range(8 * lead_mod.CLIMB_DRY):
+            if eng._lead.k != _tier:
+                break
             eng._lead.observe(1, eng._collector.pause_n)
-    assert eng._lead.k == eng._blocks_of(ENGINE["decode_block"])
-    assert picked <= warmed, (picked, warmed)
+    eng._waiting, eng._prefilling = [], []
+    assert eng._lead.k == rows
+    tiers = {eng._blocks_of(1), eng._blocks_of(4), rows}
+    if over:        # speculation caps the idle tier at a draft's length
+        tiers.add(ENGINE["spec_draft_len"])
+    assert picked <= tiers and max(picked) <= rows, (picked, tiers)
     if not over:
-        assert picked == warmed
+        assert picked == tiers
+    # a dispatch at every such k, as the loop builds it, is the warmed
+    # program: nothing is traced or compiled again
+    events = stats["compile_events"]
+    for w in widths:
+        for k in sorted(picked):
+            with eng._prof.compile_scope("decode", ("decode", w),
+                                         mid_traffic=True):
+                toks, eng._dev_tokens, eng.kv, eng._sl_dev, _key, *_n = \
+                    eng._decode(eng.params, eng.kv, eng._pt_dev, eng._sl_dev,
+                                eng._dev_tokens, eng._rng, eng._temps_dev,
+                                eng._slot_index((), w), np.int32(k))
+            assert toks.shape[0] == rows * eng._block_len
+    assert real._cache_size() == len(widths)
+    stats = eng.engine_stats()
+    assert stats["mid_traffic_compiles"] == 0
+    assert stats["compile_events"] == events
+    assert stats["decode_programs"] == len(widths)
 
 
 if __name__ == "__main__":
@@ -334,3 +548,8 @@ if __name__ == "__main__":
         json.dump(out, f, indent=1, sort_keys=True)
         f.write("\n")
     print(f"{len(out['programs'])} programs -> {DATA}")
+    for name, decode in (("PINNED", True), ("every program but decode", False)):
+        print(name, "= {")
+        for blk, kernel in sorted(PINNED):
+            print(f"    {(blk, kernel)!r}: {_pin(out, blk, kernel, decode)!r},")
+        print("}")

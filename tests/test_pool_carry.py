@@ -170,8 +170,9 @@ def test_pool_is_only_a_loop_carry(params, name, backend):
 
 
 def test_pool_is_only_a_loop_carry_in_the_engine_decode_block():
-    """The engine's fused block: the scan over steps around the scan over
-    layers carries the same pool (so the donated argument can alias)."""
+    """The engine's fused block: the loop over steps (a ``while`` whose
+    bound is the program's operand, ISSUE 58) around the scan over layers
+    carries the same pool (so the donated argument can alias)."""
     from ray_tpu.serve.llm import LLMConfig, LLMEngine
 
     cfg = LLMConfig(model_config=CFG, max_batch_size=2, page_size=PAGE,
@@ -183,7 +184,8 @@ def test_pool_is_only_a_loop_carry_in_the_engine_decode_block():
             return eng._decode_impl(
                 eng.params, kv, eng._pt_dev, eng._sl_dev,
                 jnp.zeros((3,), jnp.int32), jax.random.PRNGKey(0),
-                eng._temps_dev, jnp.arange(2, dtype=jnp.int32), 4)
+                eng._temps_dev, jnp.arange(2, dtype=jnp.int32),
+                jnp.int32(4))
 
         _assert_pool_is_carry_only(jax.make_jaxpr(block)(eng.kv).jaxpr, 2)
     finally:

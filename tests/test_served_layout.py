@@ -57,14 +57,16 @@ MIMO = dict(
         n_layers=3, pattern=(0, 1, 1), moe_freq=(0, 1, 1), experts_held=8,
         max_seq_len=9216),
     batch=48, pages=512, seq=9216, chunk=512)
-# (cell, program, width, k): k steps a dispatch, 1 and the largest the
-# engine warms (decode_block 8); SDAR's block program, one block
-CASES = [(MISTRAL, "decode", 4, 1), (MISTRAL, "decode", 4, 8),
-         (MISTRAL, "decode", 32, 8),
-         (TRINITY, "decode", 24, 1), (TRINITY, "decode", 24, 8),
+# (cell, program, width, k): k the drafts of a verify round, 0 elsewhere.
+# The steps (or whole blocks) of a dispatch are an operand of the decode
+# program since ISSUE 58, one program a width: where the parent's k of 1
+# and 8 stood, two of the cell's bucket widths do
+CASES = [(MISTRAL, "decode", 4, 0), (MISTRAL, "decode", 16, 0),
+         (MISTRAL, "decode", 32, 0),
+         (TRINITY, "decode", 24, 0), (TRINITY, "decode", 8, 0),
          (TRINITY, "chunk", 512, 0),
-         (SDAR, "decode", 64, 1),
-         (MIMO, "decode", 48, 1), (MIMO, "decode", 48, 8),
+         (SDAR, "decode", 64, 0),
+         (MIMO, "decode", 48, 0), (MIMO, "decode", 16, 0),
          (MIMO, "chunk", 512, 0)]
 IDS = [f"{cell['name']}-{prog}-w{w}-k{k}" for cell, prog, w, k in CASES]
 
@@ -98,9 +100,11 @@ def _stand_in(cell):
         _jax=jax, _jnp=jnp, _kvc=kvc, model_cfg=cfg, _mesh=None,
         _attn_backend="pallas", _cache_spec=spec,
         _block_len=spec.block_length,
-        cfg=types.SimpleNamespace(page_size=PAGE, top_k=0,
+        cfg=types.SimpleNamespace(page_size=PAGE, top_k=0, decode_block=8,
                                   max_batch_size=cell["batch"]))
-    eng._experts_touched = functools.partial(LLMEngine._experts_touched, eng)
+    for name in ("_experts_touched", "_blocks_of", "_run_steps",
+                 "_decode_one", "_block_one"):
+        setattr(eng, name, functools.partial(getattr(LLMEngine, name), eng))
     eng._pending_block = functools.partial(LLMEngine._pending_block, eng)
     eng._prefill_cache = {}
     return eng
@@ -145,8 +149,8 @@ def _compiled_text(cell, program, width, k, one_chip, served=True) -> tuple:
             impl = LLMEngine._block_impl if eng._block_len > 1 \
                 else LLMEngine._decode_impl
             fn = jax.jit(functools.partial(impl, eng),
-                         donate_argnums=(1, 3, 4), static_argnums=(8,))
-            lowered = fn.lower(*state, k)
+                         donate_argnums=(1, 3, 4))
+            lowered = fn.lower(*state, arg())           # ..., the steps
         elif program == "verify":
             fn = jax.jit(functools.partial(LLMEngine._verify_impl, eng),
                          donate_argnums=(1, 3, 4))
@@ -227,11 +231,14 @@ def test_the_listing_finds_the_copy_of_checkpoint_layout_leaves(one_chip):
     ``wv`` as a checkpoint lays them ([L, D, H, hd]) converts them on entry
     (``serve_params`` inside the program), and the listing says so; on the
     parent (read in place by ``"btd,dhk->bthk"``) it found the compiler's
-    own copies under the same shapes."""
-    text, projections = _compiled_text(MISTRAL, "decode", 4, 1, one_chip,
+    own copies under the same shapes. Under the loop of steps (ISSUE 58:
+    every decode program has one, whatever its k) the conversion is a copy
+    of the whole stack at entry, to the layout with the heads outermost."""
+    text, projections = _compiled_text(MISTRAL, "decode", 4, 0, one_chip,
                                        served=False)
     found = weight_shaped_writes(text, projections)
-    assert any("bf16[2,32,4096,128]" in line for line in found), found
+    assert any("bf16[2,4096,32,128]{3,1,2,0" in line and " copy(" in line
+               for line in found), found
 
 
 # ---- a program's tail stands under a ``conditional`` (ISSUE 56) -------------
@@ -274,9 +281,9 @@ def _holds(lines, dtype: str, vocab: int) -> list:
 
 # (cell, program, width, k): one case a program kind; a dispatch that draws
 # holds the sampler's random bits as u32 over rows x the vocabulary
-TAIL_CASES = [(MISTRAL, "decode", 4, 1), (MISTRAL, "decode", 4, 8),
+TAIL_CASES = [(MISTRAL, "decode", 4, 0), (MISTRAL, "decode", 32, 0),
               (MISTRAL, "verify", 4, 3), (MISTRAL, "prefill", 512, 0),
-              (TRINITY, "engine_chunk", 512, 0), (SDAR, "decode", 64, 1)]
+              (TRINITY, "engine_chunk", 512, 0), (SDAR, "decode", 64, 0)]
 
 
 @pytest.mark.parametrize("cell,program,width,k", TAIL_CASES, ids=[

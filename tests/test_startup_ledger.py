@@ -36,7 +36,7 @@ import ray_tpu.ops.paged_attention, ray_tpu.serve.llm.kv_cache
 import ray_tpu.serve.llm.kv_tier, ray_tpu.parallel.expert
 
 cfg = LLMConfig(model_config=llama.llama_tiny(vocab_size=512),
-                max_batch_size=4, page_size=16, num_pages=64,
+                max_batch_size=8, page_size=16, num_pages=64,
                 max_prompt_len=64, max_seq_len=128, max_tokens=8)
 box = {}
 th = threading.Thread(target=lambda: box.update(srv=LLMServer(cfg)),
@@ -47,7 +47,8 @@ srv.engine.generate("hello there", max_tokens=3)
 stats = srv.engine.engine_stats()
 srv.engine.shutdown()
 print("LEDGER " + json.dumps(
-    {k: v for k, v in stats.items() if k.startswith("startup")}))
+    {k: v for k, v in stats.items()
+     if k.startswith("startup") or k == "decode_programs"}))
 """
 
 
@@ -96,6 +97,18 @@ def test_warm_start_loads_every_program(starts):
     assert warm["startup_backend_compile_s"] == 0
     un = warm["startup"]["unscoped"]
     assert un["misses"] == 0 and un["hits"] == un["n"] > 0
+
+
+@pytest.mark.parametrize("which", ["cold", "warm"])
+def test_the_ledger_lists_one_decode_program_a_width(starts, which):
+    """ISSUE 58: the steps of a dispatch are an operand of the decode
+    program, so a start warms one a bucket width (4 and 8 for eight slots),
+    and the request's decode (k = 1, then what the lead picks) adds none."""
+    st = starts[which]
+    decode = [p for p in st["startup"]["programs"] if p["kind"] == "decode"]
+    assert [p["sig"] for p in decode] == [["decode", 4], ["decode", 8]]
+    assert all(p["mid_traffic"] == 0 for p in decode)
+    assert st["decode_programs"] == 2
 
 
 @pytest.mark.parametrize("which", ["cold", "warm"])
