@@ -637,15 +637,20 @@ def name_idle_gaps(trace: dict, top: int = 10) -> list[list]:
     return out
 
 
-def engine_loop_busy_share(before: dict, after: dict,
-                           seconds: float) -> float | None:
-    """1 - (time the loop spent parked or blocked on a result) / window,
-    from the engine's running totals at the window's edges."""
+def engine_loop_busy_share(before: dict, after: dict) -> float | None:
+    """1 - (time the loop spent parked or blocked on a result) / the time
+    between the two reads of /v1/stats by the replica's own ``clock_s``: a
+    traced run's closing read comes seconds after the window's end, so the
+    seconds the harness asked for are not the interval. None where a read
+    lacks a total or the clock."""
     keys = [f"phase_{p}_s_total" for p in WAITING]
-    if any(k not in before or k not in after for k in keys) or not seconds:
+    if any(k not in d for d in (before, after) for k in (*keys, "clock_s")):
+        return None
+    took = after["clock_s"] - before["clock_s"]
+    if took <= 0:
         return None
     waited = sum(after[k] - before[k] for k in keys)
-    return 100.0 * (1.0 - waited / seconds)
+    return 100.0 * (1.0 - waited / took)
 
 
 def report(trace: dict, n_layers: int, is_main, model_scopes: tuple) -> dict:

@@ -6,7 +6,9 @@ opens, as set-up) and cooldown_s (and this long after it closes), so that
 a request in flight at either edge of the window starts and finishes under
 the window's own load; pool (requests in the schedule the clients draw
 from, in order); schedule_seed / prompt_tokens / output_tokens as in
-open_loop.
+open_loop; deal_block (optional: lengths dealt in blocks of that many
+requests, each holding the stratified quantiles once, so that a loop that
+draws a part of its pool draws the same mix from any starting point).
 
 The window's tokens are those streamed inside it (metrics/
 serve_tokens_per_s.py); the loop around it only keeps the load steady.

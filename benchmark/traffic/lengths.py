@@ -48,23 +48,47 @@ def prompt_text(rng: random.Random, n_tokens: int) -> str:
                    for _ in range(max(1, n_tokens - 1)))
 
 
+def rotated(values: list, seed: int) -> list:
+    """``values`` from the seed's starting point in their cycle."""
+    k = random.Random(seed).randrange(len(values))
+    return values[k:] + values[:k]
+
+
 def ordered(values: list, params: dict, seed: int, salt: int) -> list:
     """``values`` in the file's fixed order (``schedule_seed``; ``salt``
     tells lengths from gaps), rotated to the seed's starting point."""
     values = list(values)
     random.Random(int(params["schedule_seed"]) * 7919 + salt).shuffle(values)
-    k = random.Random(seed).randrange(len(values))
-    return values[k:] + values[:k]
+    return rotated(values, seed)
+
+
+def dealt_lengths(spec: dict, n: int, params: dict, salt: int) -> list[int]:
+    """n lengths in the file's fixed order, before the seed's rotation.
+    With ``deal_block`` B in the file they are dealt in blocks: every run
+    of B requests from a multiple of B on holds the B stratified quantiles
+    once, in an order of its own, so any stretch of the cycle far longer
+    than B holds nearly the same lengths wherever the seed enters it.
+    Without it: ONE shuffle of the n quantiles (``ordered``'s, to the
+    letter), and a loop that draws a part of its pool reads by which part
+    (the cell file's ``deal_why`` has the readings)."""
+    block = int(params.get("deal_block") or n)
+    if block < 1:
+        raise ValueError(f"deal_block must be a positive count, not {block}")
+    rng = random.Random(int(params["schedule_seed"]) * 7919 + salt)
+    out: list[int] = []
+    while len(out) < n:
+        part = lognormal_lengths(spec, min(block, n - len(out)))
+        rng.shuffle(part)
+        out += part
+    return out
 
 
 def requests_for(params: dict, seed: int, n: int) -> list[dict]:
     """n requests: stratified prompt and output lengths in the schedule's
     order from the seed's starting point, prompt text from the seed."""
     rng = random.Random(seed)
-    plens = ordered(lognormal_lengths(params["prompt_tokens"], n), params,
-                    seed, 1)
-    olens = ordered(lognormal_lengths(params["output_tokens"], n), params,
-                    seed, 2)
+    plens = rotated(dealt_lengths(params["prompt_tokens"], n, params, 1), seed)
+    olens = rotated(dealt_lengths(params["output_tokens"], n, params, 2), seed)
     return [{"index": i, "prompt_tokens": p, "max_tokens": o,
              "prompt": prompt_text(rng, p)}
             for i, (p, o) in enumerate(zip(plens, olens))]

@@ -211,10 +211,10 @@ def _reader(name):
     return common.load_module("metrics", name).reduce
 
 
-NEW_READERS = ("paged_window_roofline_traced.trinity",
-               "paged_window_chunk_roofline.trinity",
-               "window_attn_share.trinity", "full_attn_share.trinity",
-               "ring_pages_share.trinity")
+NEW_READERS = ("paged_window_roofline_traced",
+               "paged_window_chunk_roofline",
+               "window_attn_share", "full_attn_share",
+               "ring_pages_share")
 
 
 @pytest.mark.parametrize("name", NEW_READERS)
@@ -288,26 +288,26 @@ def test_trace_readers_find_the_walking_calls_by_their_scopes():
                (1.5, {"window_pages_in_use": 12 * 37,
                       "full_pages_in_use": 12 * 74})]}
     total = 2 * (4 * 1 + 2 + 5 + 5)
-    assert _reader("window_attn_share.trinity")(run) \
+    assert _reader("window_attn_share")(run) \
         == pytest.approx(100 * 2 * 4 / total)
-    assert _reader("full_attn_share.trinity")(run) \
+    assert _reader("full_attn_share")(run) \
         == pytest.approx(100 * 2 * 2 / total)
-    assert _reader("decode_step_traced_ms.trinity")(run) \
+    assert _reader("decode_step_traced_ms")(run) \
         == pytest.approx(20.0)
-    assert _reader("ring_pages_share.trinity")(run) == pytest.approx(50.0)
+    assert _reader("ring_pages_share")(run) == pytest.approx(50.0)
     peak = common.peaks("TPU v5 lite")
     shape = (8, 128, 48)
     need = sum(
         costs_window.paged_read_bytes(240000 + 24 * (s + 1), 24, *shape)
         + 4 * costs_window.paged_read_bytes(24 * 4096, 24, *shape)
         for s in range(2)) / peak["hbm_bytes_per_s"]
-    roof = _reader("paged_window_roofline_traced.trinity")(run)
+    roof = _reader("paged_window_roofline_traced")(run)
     assert roof == pytest.approx(100 * need / 12e-3)
     assert 0 < roof < 100
     need = 4 * costs_window.paged_chunk_flops(512 * 4096, 48, 128) \
         / peak["bf16_flops_per_s"] + costs_window.paged_chunk_flops(
             costs_window.chunk_pairs(8192, 512, 0), 48, 128) \
         / peak["bf16_flops_per_s"]
-    roof = _reader("paged_window_chunk_roofline.trinity")(run)
+    roof = _reader("paged_window_chunk_roofline")(run)
     assert roof == pytest.approx(100 * need / 10e-3)
     assert 0 < roof < 100

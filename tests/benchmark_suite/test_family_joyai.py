@@ -177,8 +177,8 @@ def _reader(name):
     return common.load_module("metrics", name).reduce
 
 
-NEW_READERS = ("paged_latent_roofline_traced.joyai", "latent_attn_share.joyai",
-               "latent_proj_share.joyai", "shared_expert_share.joyai")
+NEW_READERS = ("paged_latent_roofline_traced", "latent_attn_share",
+               "latent_proj_share", "shared_expert_share")
 
 
 @pytest.mark.parametrize("name", NEW_READERS)
@@ -231,17 +231,17 @@ def test_trace_readers_find_the_latent_kernel_and_the_new_scopes():
            "kind": "serve", "device": {"kind": "TPU v5 lite"},
            "stats_before": {}, "stats_after": {}}
     total = 10 * 4.5
-    assert _reader("latent_attn_share.joyai")(run) \
+    assert _reader("latent_attn_share")(run) \
         == pytest.approx(100 * 10 / total)
-    assert _reader("latent_proj_share.joyai")(run) \
+    assert _reader("latent_proj_share")(run) \
         == pytest.approx(100 * 10 * 0.4 / total)
-    assert _reader("shared_expert_share.joyai")(run) \
+    assert _reader("shared_expert_share")(run) \
         == pytest.approx(100 * 10 * 0.1 / total)
-    assert _reader("decode_step_traced_ms.joyai")(run) == pytest.approx(30.0)
+    assert _reader("decode_step_traced_ms")(run) == pytest.approx(30.0)
     peak = common.peaks("TPU v5 lite")
     need = sum(5 * costs_latent.paged_latent_bytes(
         134400 + 128 * (s + 1), 128, 32, 576, 512) for s in range(2)) \
         / peak["hbm_bytes_per_s"]
-    roof = _reader("paged_latent_roofline_traced.joyai")(run)
+    roof = _reader("paged_latent_roofline_traced")(run)
     assert roof == pytest.approx(100 * need / 10e-3)
     assert 0 < roof < 100

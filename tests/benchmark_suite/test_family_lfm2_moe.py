@@ -133,15 +133,15 @@ def test_counter_readers_read_the_engines_counters_and_nothing_of_a_parent():
              "expert_rows_total": 500 + 14 * 8 * 256}
     run = {"stats_before": before, "stats_after": after,
            "sizes": FAM.sizes(CONFIG, False), "trace_dir": None}
-    assert _reader("experts_touched_share.lfm2")(run) \
+    assert _reader("experts_touched_share")(run) \
         == pytest.approx(100 * 30 / 32)
-    assert _reader("expert_ffn_roofline.lfm2")(run) is None   # no trace
-    assert _reader("routed_ffn_share.lfm2")(run) is None
+    assert _reader("expert_ffn_roofline")(run) is None   # no trace
+    assert _reader("routed_ffn_share")(run) is None
     # a program without the counters (the parent): nothing, and no raise
     old = {"stats_before": {"steps": 1}, "stats_after": {"steps": 9},
            "sizes": {"n_layers": 16, "dim": 4096}, "trace_dir": None}
-    for name in ("experts_touched_share.lfm2", "expert_ffn_roofline.lfm2",
-                 "routed_ffn_share.lfm2"):
+    for name in ("experts_touched_share", "expert_ffn_roofline",
+                 "routed_ffn_share"):
         assert _reader(name)(old) is None
 
 
@@ -174,9 +174,9 @@ def test_trace_readers_find_the_grouped_product_and_the_routed_scopes():
            "family": FAM, "kind": "serve",
            "device": {"kind": "TPU v5 lite"},
            "stats_before": {k: 0 for k in counters}, "stats_after": counters}
-    share = _reader("routed_ffn_share.lfm2")(run)
+    share = _reader("routed_ffn_share")(run)
     assert share == pytest.approx(100 * 28 / (28 + 4))
-    roof = _reader("expert_ffn_roofline.lfm2")(run)
+    roof = _reader("expert_ffn_roofline")(run)
     peak = common.peaks("TPU v5 lite")
     need = costs_routed.grouped_ffn_bytes(256, 32, 2048, 1792) \
         / peak["hbm_bytes_per_s"]

@@ -260,9 +260,9 @@ def _reader(name):
     return common.load_module("metrics", name).reduce
 
 
-NEW_READERS = ("paged_full_roofline_traced.mimo",
-               "paged_ring_roofline_traced.mimo",
-               "paged_mixed_chunk_roofline.mimo")
+NEW_READERS = ("paged_full_roofline_traced",
+               "paged_ring_roofline_traced",
+               "paged_mixed_chunk_roofline")
 
 
 @pytest.mark.parametrize("name", NEW_READERS)
@@ -337,21 +337,21 @@ def test_trace_readers_find_the_calls_of_each_kind_by_their_scopes():
                (0.5, {"window_pages_in_use": 48 * 6,
                       "full_pages_in_use": 48 * 40})]}
     total = 2 * (5 * 1 + 2 * 2 + 7)
-    assert _reader("window_attn_share.mimo")(run) \
+    assert _reader("window_attn_share")(run) \
         == pytest.approx(100 * 2 * 5 / total)
-    assert _reader("full_attn_share.mimo")(run) \
+    assert _reader("full_attn_share")(run) \
         == pytest.approx(100 * 2 * 4 / total)
-    assert _reader("ring_pages_share.mimo")(run) == pytest.approx(15.0)
+    assert _reader("ring_pages_share")(run) == pytest.approx(15.0)
     peak = common.peaks("TPU v5 lite")
     need = sum(2 * costs_mixed.paged_read_bytes(
         240000 + 48 * (s + 1), 48, 4, 192, 128, 64)
         for s in range(2)) / peak["hbm_bytes_per_s"]
-    roof = _reader("paged_full_roofline_traced.mimo")(run)
+    roof = _reader("paged_full_roofline_traced")(run)
     assert roof == pytest.approx(100 * need / 8e-3)
     assert 0 < roof < 100
     need = 2 * 5 * costs_mixed.paged_read_bytes(
         48 * 128, 48, 8, 192, 128, 64) / peak["hbm_bytes_per_s"]
-    roof = _reader("paged_ring_roofline_traced.mimo")(run)
+    roof = _reader("paged_ring_roofline_traced")(run)
     assert roof == pytest.approx(100 * need / 10e-3)
     assert 0 < roof < 100
     need = sum(costs.roofline_s(
@@ -360,6 +360,6 @@ def test_trace_readers_find_the_calls_of_each_kind_by_their_scopes():
         costs_mixed.paged_read_bytes(
             costs_window.chunk_keys(4096, 512, w), 512, hkv, 192, 128, 64),
         peak)[0] for w, hkv in [(0, 4)] * 2 + [(128, 8)] * 5)
-    roof = _reader("paged_mixed_chunk_roofline.mimo")(run)
+    roof = _reader("paged_mixed_chunk_roofline")(run)
     assert roof == pytest.approx(100 * need / 14e-3)
     assert 0 < roof < 100

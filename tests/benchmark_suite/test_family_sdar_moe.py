@@ -162,9 +162,9 @@ def _reader(name):
     return common.load_module("metrics", name).reduce
 
 
-NEW_READERS = ("block_pass_traced_ms.sdar", "passes_per_token.sdar",
-               "unmask_share.sdar", "expert_ffn_block_roofline.sdar",
-               "paged_block_roofline_traced.sdar")
+NEW_READERS = ("block_pass_traced_ms", "passes_per_token",
+               "unmask_share", "expert_ffn_block_roofline",
+               "paged_block_roofline_traced")
 
 
 def test_counter_readers_read_the_engines_counters_and_nothing_of_a_parent():
@@ -172,7 +172,7 @@ def test_counter_readers_read_the_engines_counters_and_nothing_of_a_parent():
            "stats_after": {"slot_passes_total": 10 + 6 * 64 * 50,
                            "tokens_out": 100 + 8 * 64 * 50 - 600},
            "sizes": FAM.sizes(CONFIG, False), "trace_dir": None}
-    assert _reader("passes_per_token.sdar")(run) \
+    assert _reader("passes_per_token")(run) \
         == pytest.approx(19_200 / 25_000)
     # a program without the counters, the spans or the kernel (the parent),
     # and a run without a trace: nothing, and no raise
@@ -239,22 +239,22 @@ def test_trace_readers_count_passes_and_find_the_scopes():
            "kind": "serve", "device": {"kind": "TPU v5 lite"},
            "stats_before": {k: 0 for k in counters}, "stats_after": counters}
     # an execution: 6 passes x 7 layers x 2 ms + 4 denoise passes x 2 ms
-    assert _reader("block_pass_traced_ms.sdar")(run) \
+    assert _reader("block_pass_traced_ms")(run) \
         == pytest.approx((6 * 7 * 2 + 4 * 2) / 6)
     per = 6 * 7 * (0.5 + 0.1 + 1.0) + 4 * 2.0
-    assert _reader("unmask_share.sdar")(run) == pytest.approx(100 * 8 / per)
-    assert _reader("routed_ffn_share.sdar")(run) \
+    assert _reader("unmask_share")(run) == pytest.approx(100 * 8 / per)
+    assert _reader("routed_ffn_share")(run) \
         == pytest.approx(100 * 6 * 7 * 1.1 / per)
     peak = common.peaks("TPU v5 lite")
     need = costs_routed.grouped_ffn_bytes(2048, 128, 2048, 768) \
         / peak["hbm_bytes_per_s"]
-    assert _reader("expert_ffn_block_roofline.sdar")(run) \
+    assert _reader("expert_ffn_block_roofline")(run) \
         == pytest.approx(100 * need / 1e-3)
     block = sum(costs_block.paged_block_bytes(
         25_600 + done * 256, 64, 4, 4, 128, 32) for done in range(2)) * 3 * 7
-    assert _reader("paged_block_roofline_traced.sdar")(run) \
+    assert _reader("paged_block_roofline_traced")(run) \
         == pytest.approx(100 * block / peak["hbm_bytes_per_s"]
                          / (6 * 7 * 0.5e-3))
     # the accepted readers see decode executions of 0 steps: no number
-    assert _reader("decode_step_traced_ms.lfm2")(run) is None
-    assert _reader("paged_decode_roofline_traced.lfm2")(run) is None
+    assert _reader("decode_step_traced_ms")(run) is None
+    assert _reader("paged_decode_roofline_traced")(run) is None

@@ -155,15 +155,245 @@ def test_a_count_of_experts_or_heads_may_be_the_chips_share():
         "num_experts: 16 is not the published 64"]
 
 
-@pytest.mark.parametrize("metric", [m["name"] for m in MAN["per_layer"]])
+@pytest.mark.parametrize("metric,cell", [
+    (m["name"], cell) for m in MAN["per_layer"] for cell in _cells_of(m)])
 def test_per_layer_metric_moves_an_end_to_end_metric_of_each_of_its_cells(
-        metric):
+        metric, cell):
     m = next(x for x in MAN["per_layer"] if x["name"] == metric)
     assert m["moves"] in E2E
-    for cell in _cells_of(m):
-        assert cell in _cells_of(E2E[m["moves"]]), (metric, cell)
+    assert cell in _cells_of(E2E[m["moves"]]), (metric, cell)
     assert m["source"] in ("device_trace", "program_span", "program_counter",
                            "host_clock")
+
+
+# ---- one entry a (reader, moves); a cell joins a reader by its list --------
+
+CELLS = {"chat": "mistral7b-serve-chat", "peak": "mistral7b-serve-peak",
+         "train": "mistral7b-train-fsdp4", "lfm2": "lfm2-8b-a1b-serve-decode",
+         "sdar": "sdar-30b-a3b-serve-decode",
+         "joyai": "joyai-llm-flash-serve-decode",
+         "trinity": "trinity-large-serve-longctx",
+         "mimo": "mimo-v2-flash-serve-reasoning"}
+
+# (entry, unit, better, source, layer, moves, cells) as PR 59 left the
+# section: the 146 (reader file, cell) pairs of the 128 suffixed entries
+# before it, each with the fields it had there, the 14 of the two
+# start-up readers listed since, and chat's first_token_p90_ms. A later PR
+# appends a cell to a list or an entry to the section; one that drops or
+# changes a pair fails here. ONE field differs from the parent's: chat's
+# five readers that moved ttft_p90_ms move ttft_p50_ms, because the check
+# of PR 59 found no admissible bound for the 90th percentile end to end
+# (PERF.md section 2); it is read per layer as first_token_p90_ms.
+READ_SINCE_PR59 = [
+    ("stream_gap_p95_ms", "ms", "lower", "host_clock",
+     "client, ingress", "tpot_p90_ms", "chat"),
+    ("queue_wait_p95_ms", "ms", "lower", "program_span",
+     "engine loop", "ttft_p50_ms", "chat"),
+    ("compiles_in_window.chat", "count", "lower", "program_counter",
+     "engine loop", "ttft_p50_ms", "chat"),
+    ("compiles_in_window", "count", "lower", "program_counter",
+     "engine loop", "serve_tokens_per_s", "peak lfm2 sdar joyai trinity mimo"),
+    ("engine_tokens_per_step", "tokens/step", "higher", "program_counter",
+     "engine loop", "serve_tokens_per_s", "peak lfm2 sdar joyai trinity mimo"),
+    ("kv_pages_in_use_share", "%", "higher", "program_counter",
+     "KV manager", "serve_tokens_per_s", "peak joyai"),
+    ("flash_roofline", "%", "higher", "device_trace",
+     "kernels", "train_tokens_per_s_per_chip", "train"),
+    ("train_step_ms", "ms", "lower", "host_clock",
+     "train step", "train_tokens_per_s_per_chip", "train"),
+    ("train_mfu", "%", "higher", "host_clock",
+     "train step", "train_tokens_per_s_per_chip", "train"),
+    ("collective_exposed_share", "%", "lower", "device_trace",
+     "collectives", "train_tokens_per_s_per_chip", "train"),
+    ("device_idle_share.chat", "%", "lower", "device_trace",
+     "device", "tpot_p90_ms", "chat"),
+    ("device_idle_share", "%", "lower", "device_trace",
+     "device", "serve_tokens_per_s", "peak lfm2 sdar joyai trinity mimo"),
+    ("device_idle_share.train", "%", "lower", "device_trace",
+     "device", "train_tokens_per_s_per_chip", "train"),
+    ("decode_step_traced_ms.chat", "ms", "lower", "device_trace",
+     "model step", "tpot_p90_ms", "chat"),
+    ("decode_step_traced_ms", "ms", "lower", "device_trace",
+     "model step", "serve_tokens_per_s", "peak lfm2 joyai trinity mimo"),
+    ("prefill_traced_ms_per_ktok", "ms/ktok", "lower", "device_trace",
+     "model step", "ttft_p50_ms", "chat"),
+    ("model_op_share.chat", "%", "higher", "device_trace",
+     "model step", "tpot_p90_ms", "chat"),
+    ("model_op_share", "%", "higher", "device_trace",
+     "model step", "serve_tokens_per_s", "peak lfm2 sdar joyai trinity mimo"),
+    ("model_op_share.train", "%", "higher", "device_trace",
+     "train step", "train_tokens_per_s_per_chip", "train"),
+    ("idle_host_busy_share.chat", "%", "lower", "device_trace",
+     "engine loop", "tpot_p90_ms", "chat"),
+    ("idle_host_busy_share", "%", "lower", "device_trace",
+     "engine loop", "serve_tokens_per_s", "peak lfm2 sdar joyai trinity mimo"),
+    ("engine_loop_busy_share.chat", "%", "lower", "program_counter",
+     "engine loop", "ttft_p50_ms", "chat"),
+    ("engine_loop_busy_share", "%", "lower", "program_counter",
+     "engine loop", "serve_tokens_per_s", "peak"),
+    ("paged_decode_roofline_traced", "%", "higher", "device_trace",
+     "kernels", "serve_tokens_per_s", "peak lfm2"),
+    ("prefill_program_share.chat", "%", "lower", "device_trace",
+     "model step", "ttft_p50_ms", "chat"),
+    ("prefill_program_share", "%", "lower", "device_trace",
+     "model step", "serve_tokens_per_s", "peak lfm2 sdar joyai trinity mimo"),
+    ("expert_ffn_roofline", "%", "higher", "device_trace",
+     "kernels", "serve_tokens_per_s", "lfm2 joyai trinity mimo"),
+    ("routed_ffn_share", "%", "higher", "device_trace",
+     "model step", "serve_tokens_per_s", "lfm2 sdar joyai trinity mimo"),
+    ("experts_touched_share", "%", "higher", "program_counter",
+     "engine loop", "serve_tokens_per_s", "lfm2 sdar joyai trinity mimo"),
+    ("block_pass_traced_ms", "ms", "lower", "device_trace",
+     "model step", "serve_tokens_per_s", "sdar"),
+    ("passes_per_token", "passes/token", "lower", "program_counter",
+     "engine loop", "serve_tokens_per_s", "sdar"),
+    ("unmask_share", "%", "lower", "device_trace",
+     "model step", "serve_tokens_per_s", "sdar"),
+    ("expert_ffn_block_roofline", "%", "higher", "device_trace",
+     "kernels", "serve_tokens_per_s", "sdar"),
+    ("paged_block_roofline_traced", "%", "higher", "device_trace",
+     "kernels", "serve_tokens_per_s", "sdar"),
+    ("gc_pause_share.chat", "%", "lower", "program_counter",
+     "engine loop", "tpot_p90_ms", "chat"),
+    ("gc_pause_share", "%", "lower", "program_counter",
+     "engine loop", "serve_tokens_per_s", "peak lfm2 sdar joyai trinity mimo"),
+    ("pipeline_dry_share.chat", "%", "lower", "program_counter",
+     "engine loop", "tpot_p90_ms", "chat"),
+    ("pipeline_dry_share", "%", "lower", "program_counter",
+     "engine loop", "serve_tokens_per_s", "peak lfm2 sdar joyai trinity mimo"),
+    ("host_stall_share.chat", "%", "lower", "program_counter",
+     "engine loop", "tpot_p90_ms", "chat"),
+    ("host_stall_share", "%", "lower", "program_counter",
+     "engine loop", "serve_tokens_per_s", "peak lfm2 sdar joyai trinity mimo"),
+    ("idle_gc_share.chat", "%", "lower", "device_trace",
+     "engine loop", "tpot_p90_ms", "chat"),
+    ("idle_gc_share", "%", "lower", "device_trace",
+     "engine loop", "serve_tokens_per_s", "peak lfm2 sdar joyai trinity mimo"),
+    ("paged_latent_roofline_traced", "%", "higher", "device_trace",
+     "kernels", "serve_tokens_per_s", "joyai"),
+    ("latent_attn_share", "%", "lower", "device_trace",
+     "model step", "serve_tokens_per_s", "joyai"),
+    ("latent_proj_share", "%", "lower", "device_trace",
+     "model step", "serve_tokens_per_s", "joyai"),
+    ("shared_expert_share", "%", "lower", "device_trace",
+     "model step", "serve_tokens_per_s", "joyai trinity"),
+    ("paged_window_roofline_traced", "%", "higher", "device_trace",
+     "kernels", "serve_tokens_per_s", "trinity"),
+    ("paged_window_chunk_roofline", "%", "higher", "device_trace",
+     "kernels", "serve_tokens_per_s", "trinity"),
+    ("window_attn_share", "%", "lower", "device_trace",
+     "model step", "serve_tokens_per_s", "trinity mimo"),
+    ("full_attn_share", "%", "lower", "device_trace",
+     "model step", "serve_tokens_per_s", "trinity mimo"),
+    ("ring_pages_share", "%", "lower", "program_counter",
+     "KV manager", "serve_tokens_per_s", "trinity mimo"),
+    ("paged_full_roofline_traced", "%", "higher", "device_trace",
+     "kernels", "serve_tokens_per_s", "mimo"),
+    ("paged_ring_roofline_traced", "%", "higher", "device_trace",
+     "kernels", "serve_tokens_per_s", "mimo"),
+    ("paged_mixed_chunk_roofline", "%", "higher", "device_trace",
+     "kernels", "serve_tokens_per_s", "mimo"),
+    ("setup_programs_share", "%", "lower", "program_span",
+     "engine start-up", "setup_s", "chat peak lfm2 sdar joyai trinity mimo"),
+    ("setup_cache_misses", "count", "lower", "program_counter",
+     "engine start-up", "setup_s", "chat peak lfm2 sdar joyai trinity mimo"),
+    ("setup_untraced_share", "%", "lower", "program_span",
+     "engine start-up", "setup_s", "chat peak lfm2 sdar joyai trinity mimo"),
+    ("setup_load_ms_per_program", "ms", "lower", "program_span",
+     "engine start-up", "setup_s", "chat peak lfm2 sdar joyai trinity mimo"),
+    ("setup_lower_ms_per_program", "ms", "lower", "program_span",
+     "engine start-up", "setup_s", "chat peak lfm2 sdar joyai trinity mimo"),
+    ("first_token_p90_ms", "ms", "lower", "host_clock",
+     "client, ingress", "ttft_p50_ms", "chat"),
+]
+
+
+def _reader_file(name: str) -> str:
+    """The file under benchmark/metrics/ that ``common.load_module`` loads
+    for an entry: its own, or for ``a.b`` without one the reader ``a``."""
+    own = os.path.join(common.bench_dir(), "metrics", f"{name}.py")
+    return name if os.path.exists(own) else name.split(".")[0]
+
+
+def _pairs(per_layer: list) -> dict:
+    """{(reader file, cell): [(unit, better, source, layer, moves), ...]}"""
+    out: dict = {}
+    for m in per_layer:
+        for cell in m["workloads"]:
+            out.setdefault((_reader_file(m["name"]), cell), []).append(
+                (m["unit"], m["better"], m["source"], m["layer"], m["moves"]))
+    return out
+
+
+READ = _pairs(MAN["per_layer"])
+
+
+def test_the_written_list_holds_the_parents_146_pairs_and_the_15_new():
+    pairs = [(_reader_file(row[0]), cell) for row in READ_SINCE_PR59
+             for cell in row[6].split()]
+    assert len(pairs) == len(set(pairs)) == 146 + 14 + 1
+    assert len(READ_SINCE_PR59) == 57 + 2 + 1
+
+
+def _run_of(first_token_ms: list, t1: float = 51.0) -> dict:
+    """A run's records as http_client leaves them: due at 1.0, the first
+    chunk ``ms`` later (None: the stream never began)."""
+    return {"window": {"t1": t1}, "records": [
+        {"due": 1.0, "first": None if ms is None else 1.0 + ms / 1e3}
+        for ms in first_token_ms]}
+
+
+@pytest.mark.parametrize("reader,rank", [("ttft_p50_ms", 51),
+                                         ("first_token_p90_ms", 92)])
+def test_first_token_readers_take_the_nearest_rank_of_every_request(
+        reader, rank):
+    """Chat's window holds 102 requests: the median is the 51st smallest
+    first-token time and the 90th percentile the 92nd, one request's time
+    each and no mean; a request cut by the closed loop's cool-down is not
+    the window's, one that never began counts as given up at t1 + 60 s."""
+    reduce = common.load_module("metrics", reader).reduce
+    times = [float(ms) for ms in range(102, 0, -1)]       # 102 ... 1 ms
+    assert reduce(_run_of(times)) == pytest.approx(rank)
+    run = _run_of(times[:-1] + [None])                    # the quickest lost
+    assert reduce(run) == pytest.approx(rank + 1)
+    assert reduce(_run_of([None])) == pytest.approx((51.0 + 60.0 - 1.0) * 1e3)
+    run = _run_of(times)
+    run["records"].append({"due": 1.0, "first": 9.0, "abandoned": True})
+    assert reduce(run) == pytest.approx(rank)
+    assert reduce({"window": {"t1": 51.0}, "records": []}) is None
+
+
+def test_chat_is_judged_by_the_median_first_token_and_reads_the_tail():
+    """ttft_p90_ms is no end-to-end metric since PR 59's check (its runs
+    spread by half of the widest bound the contract has): chat reports the
+    median end to end and the 90th percentile per layer, moving it."""
+    assert "ttft_p90_ms" not in E2E
+    assert E2E["ttft_p50_ms"]["workloads"] == [CELLS["chat"]]
+    assert E2E["ttft_p50_ms"]["better"] == "lower"
+    tail = next(m for m in MAN["per_layer"] if m["name"] == "first_token_p90_ms")
+    assert (tail["moves"], tail["workloads"]) == ("ttft_p50_ms", [CELLS["chat"]])
+
+
+@pytest.mark.parametrize("row", READ_SINCE_PR59, ids=lambda row: row[0])
+def test_every_pair_read_since_pr59_is_still_read_with_its_fields(row):
+    """No cell loses a reader, and no reader's unit, direction, source,
+    layer or end-to-end metric changes under a cell that reads it."""
+    entry, *fields, cells = row
+    for cell in cells.split():
+        assert READ.get((_reader_file(entry), CELLS[cell])) == [
+            tuple(fields)], (entry, cell)
+
+
+def test_no_two_entries_share_a_reader_and_the_metric_it_moves():
+    """One entry a (reader file, ``moves``): a further cell joins the
+    entry's ``workloads`` list, which costs none of the 128 places. Every
+    entry has such a list (a metric without one is read in every cell
+    that reports what it moves, those of later PRs too)."""
+    keys = [(_reader_file(m["name"]), m["moves"]) for m in MAN["per_layer"]]
+    assert len(keys) == len(set(keys))
+    assert all(m.get("workloads") for m in MAN["per_layer"])
+    # and no cell reads one reader under two entries
+    assert all(len(v) == 1 for v in READ.values())
 
 
 def test_every_file_under_paths_is_named_from_allowed_characters():

@@ -155,9 +155,34 @@ def test_hand_counts_of_every_metric():
         HAND, SIZES, {"hbm_bytes_per_s": 1e9}) == pytest.approx(
             100 * need / 1e9 / 600e-6)
     assert sr.engine_loop_busy_share(
-        {"phase_loop_wait_s_total": 1.0, "phase_harvest_s_total": 2.0},
-        {"phase_loop_wait_s_total": 11.0, "phase_harvest_s_total": 22.0},
-        50.0) == pytest.approx(40.0)
+        {"phase_loop_wait_s_total": 1.0, "phase_harvest_s_total": 2.0,
+         "clock_s": 500.0},
+        {"phase_loop_wait_s_total": 11.0, "phase_harvest_s_total": 22.0,
+         "clock_s": 550.0}) == pytest.approx(40.0)
+
+
+def test_engine_loop_busy_share_divides_by_the_replicas_clock():
+    """A traced run's closing read comes when the capture has been written:
+    64 s after the opening one, not the 51 the harness asked for. The loop
+    waited 48 of those 64 s: busy 25 % (over 51 it would read 5.9, and a
+    loop that waited 56 s would read negative)."""
+    before = {"phase_loop_wait_s_total": 2.0, "phase_harvest_s_total": 3.0,
+              "clock_s": 500.0}
+    after = {"phase_loop_wait_s_total": 10.0, "phase_harvest_s_total": 43.0,
+             "clock_s": 564.0}
+    assert sr.engine_loop_busy_share(before, after) == pytest.approx(25.0)
+    run = _run(None)
+    run.update(stats_before=before, stats_after=after)
+    assert run["window"]["seconds"] == 50.0      # not what it divides by
+    assert common.load_module("metrics", "engine_loop_busy_share").reduce(
+        run) == pytest.approx(25.0)
+    # a read without the clock (a program before PR 39), or a clock that
+    # did not move: nothing, never a share of the seconds asked for
+    bare = {k: v for k, v in after.items() if k != "clock_s"}
+    assert sr.engine_loop_busy_share(before, bare) is None
+    assert sr.engine_loop_busy_share(bare, after) is None
+    assert sr.engine_loop_busy_share(before, dict(after, clock_s=500.0)) \
+        is None
 
 
 def test_numerator_and_denominator_that_disagree_give_none():
@@ -416,21 +441,23 @@ def test_xplane_file_is_read_without_any_proto_library(tmp_path):
 
 # ---- the metric files, as run.py calls them --------------------------------
 
-NEW = ["decode_step_traced_ms.chat", "decode_step_traced_ms.peak",
+# entries of BENCHMARK.json: since PR 59 a reader's bare name is its entry
+# that moves serve_tokens_per_s (peak among its cells)
+NEW = ["decode_step_traced_ms.chat", "decode_step_traced_ms",
        "prefill_traced_ms_per_ktok", "model_op_share.chat",
-       "model_op_share.peak", "model_op_share.train",
-       "idle_host_busy_share.chat", "idle_host_busy_share.peak",
-       "engine_loop_busy_share.chat", "engine_loop_busy_share.peak",
+       "model_op_share", "model_op_share.train",
+       "idle_host_busy_share.chat", "idle_host_busy_share",
+       "engine_loop_busy_share.chat", "engine_loop_busy_share",
        "paged_decode_roofline_traced", "prefill_program_share.chat",
-       "prefill_program_share.peak"]
+       "prefill_program_share"]
 
 
 def _run(trace, kind="serve"):
     return {"kind": kind, "span_trace": trace, "sizes": SIZES, "family": FAM,
             "device": {"kind": "TPU v5 lite"}, "window": {"seconds": 50.0},
-            "stats_before": {"phase_loop_wait_s_total": 1.0,
+            "stats_before": {"phase_loop_wait_s_total": 1.0, "clock_s": 7.0,
                              "phase_harvest_s_total": 2.0, "steps": 1},
-            "stats_after": {"phase_loop_wait_s_total": 11.0,
+            "stats_after": {"phase_loop_wait_s_total": 11.0, "clock_s": 57.0,
                             "phase_harvest_s_total": 22.0, "steps": 9},
             "trace": {"breakdown": {"device_ops": [], "idle_gaps": [
                 ["in or before jit__lambda", 200e-6]]}}}

@@ -10,7 +10,10 @@ import pytest
 from benchmark import common, span_reduce as sr, stall_reduce
 
 US = 1000
-SUFFIXES = ("chat", "peak", "lfm2", "sdar")
+CELLS = ("mistral7b-serve-chat", "mistral7b-serve-peak",
+         "lfm2-8b-a1b-serve-decode", "sdar-30b-a3b-serve-decode")
+READERS = ("gc_pause_share", "pipeline_dry_share", "host_stall_share",
+           "idle_gc_share")
 
 
 def _stats(clock, **counters):
@@ -28,29 +31,38 @@ def _run(before, after, samples=(), marks=None, seconds=51.0):
                 "t_start", "t_stop", "stats_start", "stats_stop")}}
 
 
-def _metric(name):
-    return common.load_module("metrics", name)
+def _entry(reader, cell):
+    """The manifest's one entry of ``reader`` that lists ``cell``."""
+    [m] = [m for m in common.cell_metrics(common.manifest(), cell,
+                                          "per_layer")
+           if m["name"].split(".")[0] == reader]
+    return m
+
+
+def _metric(reader, cell):
+    """The reader as run.py loads it in a run of ``cell``."""
+    return common.load_module("metrics", _entry(reader, cell)["name"])
 
 
 # ---- counters over the replica's own clock ----------------------------------
 
-@pytest.mark.parametrize("suffix", SUFFIXES)
-def test_gc_pause_share_divides_by_the_replicas_clock(suffix):
+@pytest.mark.parametrize("cell", CELLS)
+def test_gc_pause_share_divides_by_the_replicas_clock(cell):
     """A traced run's closing read comes when the capture has been written:
     64 s after the opening one, not the 51 the harness asked for. Two
     collections of 0.32 s: 1 % of 64 s (1.25 % of 51 would be wrong)."""
     run = _run(_stats(500.0, gc_pause_s_total=1.00),
                _stats(564.0, gc_pause_s_total=1.64))
-    assert _metric(f"gc_pause_share.{suffix}").reduce(run) \
+    assert _metric("gc_pause_share", cell).reduce(run) \
         == pytest.approx(1.0)
 
 
 @pytest.mark.parametrize("name,key", [
     ("pipeline_dry_share", "dry_s_total"),
     ("host_stall_share", "host_stall_s_total")])
-@pytest.mark.parametrize("suffix", SUFFIXES)
+@pytest.mark.parametrize("cell", CELLS)
 def test_untraced_shares_stop_at_the_last_read_before_the_capture(
-        name, key, suffix):
+        name, key, cell):
     """Once-a-second reads; the capture starts 20.4 s in. The read stamped
     20.0 s was made AFTER profiling_start (the watcher stamps, starts the
     capture, then reads): the replica's clock gives it away. So the
@@ -67,7 +79,7 @@ def test_untraced_shares_stop_at_the_last_read_before_the_capture(
     run = _run(_stats(500.0, **{key: 0.0}), _stats(564.0, **{key: 40.0}),
                samples, marks)
     assert stall_reduce.last_untraced(run) is samples[19][1]
-    assert _metric(f"{name}.{suffix}").reduce(run) \
+    assert _metric(name, cell).reduce(run) \
         == pytest.approx(100.0 * 0.19 / 19.05)
 
 
@@ -85,16 +97,14 @@ def test_last_untraced_by_the_harness_clock_alone_and_with_no_capture():
     assert stall_reduce.last_untraced(_run({}, {})) is None
 
 
-@pytest.mark.parametrize("name", [
-    f"{m}.{s}" for m in ("gc_pause_share", "pipeline_dry_share",
-                         "host_stall_share", "idle_gc_share")
-    for s in SUFFIXES])
-def test_a_program_without_the_counters_reports_nothing(name):
+@pytest.mark.parametrize("cell", CELLS)
+@pytest.mark.parametrize("name", READERS)
+def test_a_program_without_the_counters_reports_nothing(name, cell):
     """The parent's /v1/stats: no clock, no counter. None, and no raise;
     so with a clock that did not move, and with no reads at all."""
     old = {"tokens_out": 7, "phase_harvest_s_total": 1.0}
     samples = [(float(i), dict(old)) for i in range(5)]
-    reduce = _metric(name).reduce
+    reduce = _metric(name, cell).reduce
     assert reduce(_run(dict(old), dict(old), samples)) is None
     assert reduce(_run(None, None)) is None
     still = _stats(500.0, gc_pause_s_total=1.0, dry_s_total=1.0,
@@ -104,23 +114,23 @@ def test_a_program_without_the_counters_reports_nothing(name):
 
 
 def test_every_new_metric_is_listed_for_its_cell_and_resolves():
-    man = common.manifest()
-    cells = {"chat": "mistral7b-serve-chat", "peak": "mistral7b-serve-peak",
-             "lfm2": "lfm2-8b-a1b-serve-decode",
-             "sdar": "sdar-30b-a3b-serve-decode"}
-    by_name = {m["name"]: m for m in man["per_layer"]}
+    """Since PR 59 one entry a (reader, ``moves``): chat's under the
+    suffixed name (it moves ``tpot_p90_ms``), the closed loops' under the
+    reader's bare name, each cell in its ``workloads`` list."""
     for base, source in (("gc_pause_share", "program_counter"),
                          ("pipeline_dry_share", "program_counter"),
                          ("host_stall_share", "program_counter"),
                          ("idle_gc_share", "device_trace")):
-        for suffix, cell in cells.items():
-            m = by_name[f"{base}.{suffix}"]
-            assert m["workloads"] == [cell] and m["source"] == source
+        for cell in CELLS:
+            m = _entry(base, cell)
+            chat = cell == "mistral7b-serve-chat"
+            assert m["name"] == (f"{base}.chat" if chat else base)
+            assert cell in m["workloads"] and m["source"] == source
             assert (m["layer"], m["better"], m["unit"]) \
                 == ("engine loop", "lower", "%")
-            assert m["moves"] == ("tpot_p90_ms" if suffix == "chat"
+            assert m["moves"] == ("tpot_p90_ms" if chat
                                   else "serve_tokens_per_s")
-            assert callable(_metric(m["name"]).reduce)
+            assert callable(_metric(base, cell).reduce)
 
 
 # ---- the collector's spans against the device's idle gaps -------------------
@@ -192,7 +202,7 @@ def test_idle_gc_share_renames_the_breakdowns_gaps_and_spares_the_parents():
     run = _run(_stats(1.0), _stats(2.0, gc_pause_n=3))
     run.update(span_trace=trace, gc_events=gcs,
                trace={"breakdown": {"idle_gaps": [["x", 1.0]]}})
-    reduce = _metric("idle_gc_share.sdar").reduce
+    reduce = _metric("idle_gc_share", "sdar-30b-a3b-serve-decode").reduce
     assert reduce(run) == pytest.approx(14.0)
     assert run["trace"]["breakdown"]["idle_gaps"][0][0] \
         == "gc_in_harvest before jit_split_key"

@@ -14,6 +14,9 @@ READERS = ("setup_programs_share", "setup_load_ms_per_program",
            "setup_untraced_share")
 SERVE_CELLS = [w["name"] for w in common.manifest()["workloads"]
                if w["name"] != "mistral7b-train-fsdp4"]
+UNITS = {"setup_programs_share": "%", "setup_cache_misses": "count",
+         "setup_untraced_share": "%", "setup_load_ms_per_program": "ms",
+         "setup_lower_ms_per_program": "ms"}
 T0 = 1000.0
 
 
@@ -149,20 +152,22 @@ def test_each_reader_has_a_file_and_says_where_its_number_comes_from(name):
 
 @pytest.mark.parametrize("cell", SERVE_CELLS)
 def test_every_serve_cell_lists_the_ledgers_readers(cell):
-    """The manifest's entries (three: BENCHMARK.json may hold 128 per-layer
-    metrics and held 125): each moves ``setup_s``, under the layer
-    "engine start-up", read in every serve cell and not in the train
-    cell, whose start is the benchmark's own code."""
+    """The manifest's entries (all five since PR 59, which made room: one
+    entry a reader): each moves ``setup_s``, under the layer "engine
+    start-up", read in every serve cell and not in the train cell, whose
+    start is the benchmark's own code."""
     man = common.manifest()
     mine = [m for m in common.cell_metrics(man, cell, "per_layer")
             if m["moves"] == "setup_s"]
     assert [m["name"] for m in mine] == [
-        "setup_programs_share", "setup_cache_misses", "setup_untraced_share"]
+        "setup_programs_share", "setup_cache_misses", "setup_untraced_share",
+        "setup_load_ms_per_program", "setup_lower_ms_per_program"]
     for m in mine:
         assert m["layer"] == "engine start-up" and m["better"] == "lower"
         assert m["workloads"] == SERVE_CELLS
         assert m["source"] == ("program_counter" if m["name"]
                                == "setup_cache_misses" else "program_span")
+        assert m["unit"] == UNITS[m["name"]]
     assert len(man["per_layer"]) <= 128
     assert not [m for m in common.cell_metrics(
         man, "mistral7b-train-fsdp4", "per_layer") if m["moves"] == "setup_s"]

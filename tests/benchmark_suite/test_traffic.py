@@ -3,12 +3,14 @@ the file's clamps; the same work for every seed in another order; the open
 loop times from the due time and reports lateness."""
 
 import json
+import random
+import statistics
 import time
 
 import pytest
 
 from benchmark import common
-from benchmark.traffic import closed_loop, open_loop, train_batches
+from benchmark.traffic import closed_loop, lengths, open_loop, train_batches
 
 CELLS = ["mistral7b-serve-chat", "mistral7b-serve-peak"]
 
@@ -49,6 +51,98 @@ def test_lengths_stay_inside_the_clamps(cell, seed):
         assert o["min"] <= r["max_tokens"] <= o["max"]
         # BOS + one id per character
         assert len(common.byte_encode(r["prompt"])) == r["prompt_tokens"]
+
+
+def _one_shuffle(values, params, seed, salt):
+    """The order of a cell's lengths as ``lengths.requests_for`` made it
+    before ``deal_block`` (PR 59), kept here as the reference: ONE shuffle
+    of the n quantiles by the file's ``schedule_seed``, then the seed's
+    rotation."""
+    values = list(values)
+    random.Random(int(params["schedule_seed"]) * 7919 + salt).shuffle(values)
+    k = random.Random(seed).randrange(len(values))
+    return values[k:] + values[:k]
+
+
+@pytest.mark.parametrize("cell", [w["name"] for w in
+                                  common.manifest()["workloads"]])
+def test_a_file_without_deal_block_keeps_its_schedule_to_the_letter(cell):
+    """Dealing in blocks is a parameter of a cell's file; a file without
+    it (every cell's but peak's, and every rehearsal) gets the lengths it
+    got before the parameter existed, in the same order."""
+    whole = common.load_json(common.bench_dir(), "workloads", f"{cell}.json")
+    for part in (whole, whole["rehearsal"]):
+        params = part["traffic"]
+        if "prompt_tokens" not in params:
+            continue                              # the train cell's steps
+        plain = {k: v for k, v in params.items() if k != "deal_block"}
+        n = int(params.get("pool", 102))
+        for seed in (3, 2**31 + 41):
+            for key, salt in (("prompt_tokens", 1), ("output_tokens", 2)):
+                assert lengths.rotated(lengths.dealt_lengths(
+                    plain[key], n, plain, salt), seed) == _one_shuffle(
+                        lengths.lognormal_lengths(plain[key], n), plain,
+                        seed, salt)
+
+
+def test_requests_are_the_dealt_lengths_with_text_from_the_seed():
+    params = _traffic("mistral7b-serve-chat")
+    reqs = lengths.requests_for(params, 3, 40)
+    for key, name, salt in (("prompt_tokens", "prompt_tokens", 1),
+                            ("output_tokens", "max_tokens", 2)):
+        assert [r[name] for r in reqs] == _one_shuffle(
+            lengths.lognormal_lengths(params[key], 40), params, 3, salt)
+    assert [r["index"] for r in reqs] == list(range(40))
+
+
+def test_dealt_in_blocks_every_stretch_of_the_cycle_holds_the_same_mix():
+    """Peak's file: 2,048 requests in 16 blocks, each the 128 stratified
+    quantiles once in its own order. A window sees about 485 requests from
+    wherever the seed enters the cycle: over all 2,048 entry points the
+    quartiles of their prompt tokens lie 1.8 % apart and of their output
+    tokens 1.0 %, where one shuffle of 2,048 quantiles gives 4.1 % and
+    3.4 % (this test's own numbers)."""
+    params = _traffic("mistral7b-serve-peak")
+    n, block = params["pool"], params["deal_block"]
+    assert n % block == 0 and n // block == 16
+    for key, salt in (("prompt_tokens", 1), ("output_tokens", 2)):
+        cycle = lengths.dealt_lengths(params[key], n, params, salt)
+        quantiles = lengths.lognormal_lengths(params[key], block)
+        blocks = [cycle[i:i + block] for i in range(0, n, block)]
+        assert all(sorted(b) == quantiles for b in blocks)
+        assert len({tuple(b) for b in blocks}) == len(blocks)
+        plain = lengths.dealt_lengths(
+            params[key], n, {"schedule_seed": params["schedule_seed"]}, salt)
+        assert sorted(plain) == lengths.lognormal_lengths(params[key], n)
+
+        def swing(values, width=485):
+            twice = values + values
+            sums = [sum(twice[k:k + width]) for k in range(len(values))]
+            q1, med, q3 = statistics.quantiles(sums, n=4)
+            return (q3 - q1) / med
+        assert swing(cycle) < 0.5 * swing(plain)
+        assert swing(cycle) < 0.02 < 0.03 < swing(plain)
+    # the seed still chooses where the window enters the cycle, and the text
+    a, b = _plan("mistral7b-serve-peak", 11), _plan("mistral7b-serve-peak", 12)
+    assert [r["max_tokens"] for r in a["requests"]] \
+        != [r["max_tokens"] for r in b["requests"]]
+    with pytest.raises(ValueError, match="deal_block"):
+        lengths.dealt_lengths(params["prompt_tokens"], 8,
+                              dict(params, deal_block=-1), 1)
+
+
+def test_peaks_pool_outlasts_the_loop_at_a_faster_engine_than_todays():
+    """The loop runs ramp + window + cool-down = 87 s. At 1,200 tokens/s
+    (a fifth over what the cell reads) and the mix's 113.7 output tokens a
+    request it completes 918 requests: the pool holds at least twice that,
+    so no engine in reach empties it inside the window (a pool of 512 was
+    spent by any engine past 924 tokens/s, and a faster one read LOWER)."""
+    params = _traffic("mistral7b-serve-peak")
+    plan = _plan("mistral7b-serve-peak", 5)
+    out = sum(r["max_tokens"] for r in plan["requests"]) / params["pool"]
+    assert out == pytest.approx(113.66, abs=0.05)
+    loop_s = params["ramp_s"] + 51 + params["cooldown_s"]
+    assert params["pool"] >= 2 * 1200 * loop_s / out
 
 
 def test_open_loop_schedule_fills_the_window_at_the_rate():
