@@ -63,6 +63,16 @@ that can be served provides, at module level:
         ``prev`` [B, K-1, D] the state the sequence's earlier tokens left,
         ``ext`` [B, K-1+T, D] that state followed by this call's columns,
         of which the manager keeps the last K-1 real ones)
+    serve_hybrid_in(x, layer, cos, sin, cfg) -> (q, k, v), (z, xbc, dt)
+        (hybrid mixer only: the layer's one norm and both mixers' inputs,
+        ``xbc`` [B, T, C] BEFORE the convolution, whose last K-1 real
+        columns the manager keeps; then ``serve_ssm_conv(ext, layer, cfg)
+        -> x [B, T, H, P], b, c [B, T, G, N]`` over the kept columns and
+        the call's, ``serve_ssm_step(dt, layer) -> dt, a`` (after bias and
+        softplus, float32; the heads' negative rates), the manager's scan
+        or update of the state (ops/ssm.py), ``serve_ssm_out(y, x, z,
+        layer, cfg)`` and ``serve_attn_out(attn, layer, cfg)``: the two
+        branches as the residual takes them)
     serve_ffn(x, layer, cfg, ld) -> (x + ffn, choice | None)
         ``choice`` int32 [rows, top_k]: the experts a routed layer took
     serve_final_norm(x, params, cfg), serve_lm_head(x, params, cfg)
@@ -82,7 +92,14 @@ one more column of the softmax that weighs no value; "latent" (``serve_latent``,
 one row and reads it back in the absorbed form; "conv" (``serve_conv``) keeps its state
 in the row of the sequence's first page: a call that starts a sequence
 reads zeros instead, and the row keeps the state as of the call's last real
-column. A whole prefill reads nothing back (the prompt's own rows, "latent"
+column; "hybrid" (``serve_hybrid_in``, ``serve_attn_out`` with the
+configuration, ``serve_ssm_conv``, ``serve_ssm_step``, ``serve_ssm_out``)
+runs TWO mixers off one norm and adds both to the residual: the "attn"
+write and read, and a state-space mixer whose row holds the convolution's
+last columns and a recurrent state of a matrix a head (ops/ssm.py: a
+chunked scan over a call's columns from the carried state, an update in
+place for a call of one column a slot; columns past the real ones leave
+the state as it was). A whole prefill reads nothing back (the prompt's own rows, "latent"
 through ``serve_latent_expanded``, the write after the feed-forward) and
 keeps a layer of its own. A new kind provides its ``serve_*`` functions
 here, one function and one entry there, what it keeps in ``CacheSpec``
@@ -144,10 +161,20 @@ class CacheSpec:
     ``latent_dim`` above 0, ONE row of
     ``latent_dim`` lanes a token (``n_kv_heads`` 1), of which the first
     ``value_dim`` are also the values: the pool is then one array, a
-    latent (compressed) cache. ``state_layers`` layers keep one array of
-    ``state_shape`` a SEQUENCE (not a token): state that survives between
-    decode steps, is carried from one prefill chunk to the next and cannot
-    be rebuilt from the pages. ``routed_layers`` layers choose ``top_k`` of
+    latent (compressed) cache. ``state_layers`` layers keep state a
+    SEQUENCE (not a token): state that survives between decode steps, is
+    carried from one prefill chunk to the next and cannot be rebuilt from
+    the pages. Either ONE array of ``state_shape`` in the activations'
+    dtype, or each array that ``state_arrays`` states, ``((shape, dtype),
+    ...)`` with "" for the activations' dtype (a convolution's last columns
+    beside a recurrent state in float32: each its own array a layer, so a
+    kernel may update one where it lies). ``state_per_slot``: false, the
+    state pool has a row a PAGE and a sequence's row is its first page,
+    whichever page that is; true, a row a SLOT: first pages come from a
+    reserved range as long as there are slots (``PageAllocator``'s
+    ``first_pages``), the pool holds that many rows and the trash row, and
+    a sequence's row is still its first page (a state of megabytes a row
+    cannot be held a page). ``routed_layers`` layers choose ``top_k`` of
     ``n_experts`` experts a token, and the programs record the choice
     (``n_experts``: the experts whose rows THIS replica multiplies, the
     first of the router's where it holds a share: what the engine's counts
@@ -164,6 +191,8 @@ class CacheSpec:
     head_dim: int
     state_layers: int = 0
     state_shape: tuple = ()
+    state_arrays: tuple = ()
+    state_per_slot: bool = False
     routed_layers: int = 0
     top_k: int = 0
     n_experts: int = 0
@@ -179,7 +208,7 @@ class CacheSpec:
 @dataclasses.dataclass(frozen=True)
 class LayerDef:
     """One layer of a block whose layers differ: its mixer ("attn" |
-    "gated" | "sink" | "conv" | "latent": a key of kv_cache.py's
+    "gated" | "sink" | "conv" | "latent" | "hybrid": a key of kv_cache.py's
     ``_MIXERS``, the module docstring's "a mixer kind"), its feed-forward
     kind ("dense" | "routed"), which row of the pool, of the slot state and
     of the routing record is its own, its window (0: a full layer, whose

@@ -94,6 +94,7 @@ class _Request:
     # pool's), and how many ring entries its context has written again
     window_pages: list[int] = field(default_factory=list)
     ring_recycled: int = 0
+    waited_state_row: bool = False   # refused once for want of a state row
     generated: list[int] = field(default_factory=list)
     dispatched: int = 0  # tokens whose computation has been dispatched
     prefill_pos: int = 0  # prompt tokens already prefilled (chunked prefill)
@@ -306,9 +307,18 @@ class LLMEngine:
                 f"max_seq_len rows a call")
         # (the window pool: a ring a slot and the trash page)
         pool_t0 = time.monotonic()
+        # (a state pool a SLOT, models/block.py ``state_per_slot``: a row a
+        # slot and the trash row; a sequence's row is its first page, which
+        # the allocator hands from the reserved range 1..b)
+        self._state_rows = b if self._cache_spec.state_per_slot else 0
+        if self._state_rows and cfg.num_pages <= b:
+            raise ValueError(
+                f"num_pages={cfg.num_pages}: a block whose state pool has "
+                f"a row a slot reserves max_batch_size={b} first pages")
         self.kv = kvc.init_paged_cache(
             self.model_cfg, cfg.num_pages, cfg.page_size, self._tp,
-            window_pages=b * self._ring_pages + 1 if self._windowed else 0)
+            window_pages=b * self._ring_pages + 1 if self._windowed else 0,
+            state_rows=self._state_rows + 1 if self._state_rows else 0)
         # Tensor parallelism (ISSUE 20): one engine process drives a
         # tp_degree-chip "tensor" mesh. Weights get Megatron-style
         # partition-rule shardings (parallel/sharding.py — the SAME
@@ -360,6 +370,7 @@ class LLMEngine:
         self._prof.set_memory_layout(
             profiling_mod.tree_bytes(self.params),
             profiling_mod.tree_bytes(self.kv))
+        self._state = kvc.state_nbytes(self.kv)
         # Prefix caching (see kv_cache.PageAllocator): all bookkeeping is
         # host-side between steps — the page table indirection means shared
         # pages change WHICH pool pages a slot reads, never the compiled
@@ -370,7 +381,8 @@ class LLMEngine:
         # recompute (tokenizer skew) warns once, not once per request
         self._ingress_skew_warned = False
         self.allocator = kvc.PageAllocator(
-            cfg.num_pages, cache_pages=cfg.prefix_cache_max_pages)
+            cfg.num_pages, cache_pages=cfg.prefix_cache_max_pages,
+            first_pages=self._state_rows)
         # the rings' pages (a block with window layers), and the width of
         # a slot's page table: the growing table, then its ring table
         self.window_allocator = kvc.PageAllocator(
@@ -449,6 +461,7 @@ class LLMEngine:
                       "kv_tier_bypassed_stateful": 0,
                       "spec_bypassed_stateful": 0,
                       "disagg_refused_stateful": 0,
+                      "admission_waited_state_row": 0,
                       # generation by diffusion over blocks (0 for a block
                       # a step of which yields a token), over the block
                       # dispatches harvested: passes run (``steps`` counts
@@ -1542,6 +1555,15 @@ class LLMEngine:
                # for a block without slot state)
                "state_slots_in_use": (active + prefilling + restoring
                                       if self._stateful else 0),
+               # the state pool: its rows (a row a page, or slots + 1),
+               # its bytes by kind (``taps`` in the activations' dtype,
+               # ``recurrent`` float32), what ONE sequence holds of it
+               # beside kv_bytes_per_token a token, and the reserved first
+               # pages (state rows) no sequence holds
+               "state_rows": self._state["rows"],
+               "state_pool_bytes": dict(self._state["pool_bytes"]),
+               "state_bytes_per_slot": self._state["bytes_per_slot"],
+               "first_pages_free": self.allocator.first_pages_free(),
                # pages live requests hold of each kind (a block with
                # window layers: its rings' beside the growing tables')
                "full_pages_in_use": self.cfg.num_pages - 1 - free,
@@ -1885,6 +1907,12 @@ class LLMEngine:
                 n_pages = -(-max(reach, 1) // self.cfg.page_size)
                 n_pages = min(n_pages, self.max_pages_per_seq)
                 pages = self.allocator.alloc(n_pages - len(matched))
+                if pages is None and self._state_rows \
+                        and not req.waited_state_row \
+                        and not self.allocator.first_pages_free():
+                    # it had a slot and no state row (counted once)
+                    req.waited_state_row = True
+                    self.stats["admission_waited_state_row"] += 1
                 if pages is None:
                     # page pool exhausted; drop the match refs (pages park
                     # back in the cached LRU, still matchable) + retry next

@@ -128,7 +128,7 @@ def ring_pages(window: int, page_size: int, span: int) -> int:
 
 
 def init_paged_cache(cfg, num_pages: int, page_size: int, tp: int = 1,
-                     window_pages: int = 0):
+                     window_pages: int = 0, state_rows: int = 0):
     """What the block's cache spec (models/block.py) asks for, a pytree
     (``tp``: the chips the pool's heads will be split over).
 
@@ -166,16 +166,24 @@ def init_paged_cache(cfg, num_pages: int, page_size: int, tp: int = 1,
     ``k`` [paged_layers, n_kv_heads, num_pages, page_size, key lanes], ``v``
     [.., value_dim], ``kw`` / ``vw`` alike at their own heads.
 
-    ``state`` (a block with slot state only): one array [num_pages,
-    prod(state_shape)] a layer that keeps state (flat: a [2, D] row would
-    be padded to whole sublane tiles). A sequence's row is its FIRST
-    PAGE, so the state rides the page table: the allocator that hands a
-    sequence its pages has handed it its state row, the programs need no
-    slot argument, and inactive lanes (a page table of zeros) meet in the
-    trash page's row. Nothing zeroes a row: a program that starts a
-    sequence (a whole prefill, a chunk with ``start`` 0) reads zeros
-    instead of the row. Two sequences must never share a first page, so a
-    block with slot state takes no prefix reuse (:func:`has_slot_state`).
+    ``state`` (a block with slot state only), an entry a layer that keeps
+    state: one array [rows, prod(state_shape)] (flat: a [2, D] row would
+    be padded to whole sublane tiles), or, where the spec states
+    ``state_arrays``, a tuple of arrays [rows, *shape], each at its own
+    dtype (a convolution's columns flat in the activations' dtype beside
+    a recurrent state [rows, H, N, P] in float32). A sequence's row is its
+    FIRST PAGE, so the state rides the page table: the allocator that
+    hands a sequence its pages has handed it its state row, the programs
+    need no slot argument, and inactive lanes (a page table of zeros) meet
+    in the trash page's row. ``rows`` is ``num_pages``, or ``state_rows``
+    where the caller says so: a block whose spec states ``state_per_slot``
+    takes its first pages from a reserved range (:class:`PageAllocator`
+    ``first_pages``), so the engine holds slots + 1 rows and not a row a
+    page (4 MB a row and layer would not fit a row a page). Nothing zeroes
+    a row: a program that starts a sequence (a whole prefill, a chunk with
+    ``start`` 0) reads zeros instead of the row. Two sequences must never
+    share a first page, so a block with slot state takes no prefix reuse
+    (:func:`has_slot_state`).
 
     ``routing`` (a block with routed experts only): int32 [routed_layers,
     max_seq_len, top_k], the experts the LAST program call chose for each
@@ -200,9 +208,16 @@ def init_paged_cache(cfg, num_pages: int, page_size: int, tp: int = 1,
                 kv[n] = jnp.zeros((spec.window_layers, heads, window_pages,
                                    page_size, w), cfg.dtype)
     if spec.state_layers:
-        kv["state"] = tuple(
-            jnp.zeros((num_pages, int(np.prod(spec.state_shape))), cfg.dtype)
-            for _ in range(spec.state_layers))
+        rows = state_rows or num_pages
+        if spec.state_arrays:
+            kv["state"] = tuple(
+                tuple(jnp.zeros((rows, *shape), dtype or cfg.dtype)
+                      for shape, dtype in spec.state_arrays)
+                for _ in range(spec.state_layers))
+        else:
+            kv["state"] = tuple(
+                jnp.zeros((rows, int(np.prod(spec.state_shape))), cfg.dtype)
+                for _ in range(spec.state_layers))
     if spec.routed_layers:
         kv["routing"] = jnp.zeros(
             (spec.routed_layers, cfg.max_seq_len, spec.top_k), jnp.int32)
@@ -272,8 +287,11 @@ def has_window_layers(cfg) -> bool:
 
 
 def has_slot_state(cfg) -> bool:
-    """Whether the block keeps per-sequence state beside its pages: state
-    that pages, ``seq_len`` and a page table do not restore. Prefix reuse,
+    """Whether the block keeps per-sequence state beside its pages (a
+    convolution's last columns, a recurrent state a head: whatever its
+    cache spec's ``state_layers`` keep): state that pages, ``seq_len`` and
+    a page table do not restore, and that a page could not carry where it
+    is megabytes a layer. Prefix reuse,
     the kv tier, speculative rollback and disaggregated handoff move pages
     only, so the engine does none of them for such a block (and counts)."""
     return block_of(cfg).cache_spec(cfg).state_layers > 0
@@ -304,6 +322,24 @@ def token_nbytes(kv) -> int:
     counts like a full layer's, whatever its ring recycles)."""
     return int(sum(kv[n].nbytes // (kv[n].shape[2] * kv[n].shape[3])
                    for n in ("k", "v", "kw", "vw") if n in kv))
+
+
+def state_nbytes(kv) -> dict:
+    """{"rows", "pool_bytes": {kind: bytes over the layers}, "bytes_per_
+    slot"} of the slot state the cache holds (zeros and an empty dict for
+    a block without): ``taps`` the arrays in the activations' dtype (a
+    convolution's last columns), ``recurrent`` the float32 ones."""
+    arrays = [a for entry in kv.get("state", ())
+              for a in (entry if isinstance(entry, tuple) else (entry,))]
+    kinds = {}
+    for a in arrays:
+        kind = "recurrent" if a.dtype == jnp.float32 and a.ndim > 2 \
+            else "taps"
+        kinds[kind] = kinds.get(kind, 0) + int(a.nbytes)
+    return {"rows": int(arrays[0].shape[0]) if arrays else 0,
+            "pool_bytes": kinds,
+            "bytes_per_slot": int(sum(a.nbytes // a.shape[0]
+                                      for a in arrays))}
 
 
 def pool_lanes(cfg, kv) -> dict:
@@ -423,6 +459,15 @@ class PageAllocator:
     ``cache_pages`` caps how many refcount-zero cached pages are retained
     (0 = bounded only by the pool itself).
 
+    ``first_pages`` (a block whose state pool has a row a SLOT, models/
+    block.py ``CacheSpec.state_per_slot``): pages 1..first_pages are handed
+    out as a sequence's FIRST page and as nothing else, every other page as
+    a later page only, so a first page names one of ``first_pages`` state
+    rows. ``alloc(n)`` then takes one page of the range and n - 1 others,
+    the first page first in its answer, and refuses when either kind has
+    run out (``first_pages_free`` tells a caller which). Such a block takes
+    no prefix reuse, so a reserved page is never indexed.
+
     Spilling (serve/llm/kv_tier.py): ``spill_hook``, when set, receives
     every ``(page, digest, chain_pos)`` evicted during one ``alloc()`` /
     ``free()`` call — after the allocator lock is released but BEFORE
@@ -433,8 +478,15 @@ class PageAllocator:
     free — no page leaks, no deadlock, just no spill.
     """
 
-    def __init__(self, num_pages: int, cache_pages: int = 0):
-        self._free = list(range(num_pages - 1, 0, -1))  # stack; never page 0
+    def __init__(self, num_pages: int, cache_pages: int = 0,
+                 first_pages: int = 0):
+        if not 0 <= first_pages < num_pages:
+            raise ValueError(f"first_pages={first_pages} of {num_pages} "
+                             f"pages (page 0 is the trash page)")
+        # stacks; never page 0
+        self._free = list(range(num_pages - 1, first_pages, -1))
+        self._free_first = list(range(first_pages, 0, -1))
+        self.first_pages = first_pages
         self._lock = threading.Lock()
         self.num_pages = num_pages
         self._cache_cap = int(cache_pages)
@@ -488,11 +540,16 @@ class PageAllocator:
         under pressure; None when free + evictable can't cover n."""
         spilled: list = []
         with self._lock:
-            if len(self._free) + len(self._lru) < n:
+            first = bool(self.first_pages and n > 0)
+            if first and not self._free_first:
+                return None     # no state row left, whatever else is free
+            rest = n - first
+            if len(self._free) + len(self._lru) < rest:
                 return None  # can't be satisfied — don't evict for nothing
-            while len(self._free) < n:
+            while len(self._free) < rest:
                 self._evict_one_locked(spilled)
-            out = [self._free.pop() for _ in range(n)]
+            out = ([self._free_first.pop()] if first else []) \
+                + [self._free.pop() for _ in range(rest)]
             for p in out:
                 self._ref[p] = 1
         self._fire_spill_hook(spilled)
@@ -523,9 +580,17 @@ class PageAllocator:
                     while self._cache_cap > 0 \
                             and len(self._lru) > self._cache_cap:
                         self._evict_one_locked(spilled)
+                elif p <= self.first_pages:
+                    self._free_first.append(p)
                 else:
                     self._free.append(p)
         self._fire_spill_hook(spilled)
+
+    def first_pages_free(self) -> int:
+        """Reserved first pages (state rows) no sequence holds; 0 without
+        a reserved range."""
+        with self._lock:
+            return len(self._free_first)
 
     def incref(self, pages: list[int]) -> None:
         with self._lock:
@@ -541,7 +606,7 @@ class PageAllocator:
         three-way occupancy breakdown. Whole-replica logical pages
         (shard-count-independent; see the class docstring)."""
         with self._lock:
-            return len(self._free) + len(self._lru)
+            return len(self._free) + len(self._lru) + len(self._free_first)
 
     def refcount(self, page: int) -> int:
         """Current refcount of one page (0 = free or parked in the cached
@@ -722,8 +787,10 @@ class PageAllocator:
 # Each piece sits under a jax.named_scope so that a profiler trace says
 # which layer an op belongs to (`norm`, `attn`, `conv`, `mlp`, `router`,
 # `experts`, `embed`, `lm_head`, `sample`; `kv_write` / `state_write` = the
-# page-pool and slot-state updates only); the scopes are compile-time
-# metadata and change no executable.
+# page-pool and slot-state updates only; `ssm` a state-space mixer, inside
+# it `ssm_in`, `ssm_conv`, `ssm_scan` | `ssm_update`, `state_write`,
+# `ssm_out`); the scopes are compile-time metadata and change no
+# executable.
 
 def _pad_lanes(a, lanes: int):
     """``a`` with zeros behind its last axis up to ``lanes`` (a key row, or
@@ -899,16 +966,88 @@ def _conv_mixer(x, kv, layer, cfg, ld, rows, fresh=None, n_real=None):
         prev = jnp.where(fresh, jnp.zeros_like(prev), prev)
     x, ext = block_of(cfg).serve_conv(x, layer, prev, cfg)
     with jax.named_scope("state_write"):
-        keep = prev.shape[1]
-        if n_real is None:
-            new = ext[:, ext.shape[1] - keep:]
-        else:
-            new = jax.vmap(lambda e, n: jax.lax.dynamic_slice_in_dim(
-                e, n, keep, axis=0))(ext, n_real)
+        new = _last_real(ext, prev.shape[1], n_real)
         state = state.at[rows].set(
             new.reshape(new.shape[0], -1).astype(state.dtype))
     return x, {**kv, "state": kv["state"][:i] + (state,)
                + kv["state"][i + 1:]}
+
+
+def _last_real(ext, keep: int, n_real):
+    """The ``keep`` columns of ext [B, keep + T, D] that end at each
+    sequence's last REAL column of the call's T (``n_real`` [B]; None =
+    all T are real): what a convolution's state keeps."""
+    if n_real is None:
+        return ext[:, ext.shape[1] - keep:]
+    return jax.vmap(lambda e, n: jax.lax.dynamic_slice_in_dim(
+        e, n, keep, axis=0))(ext, n_real)
+
+
+def _ssm_half(kv, layer, cfg, ld, z, xbc, dt, rows, fresh, n_real,
+              kernel: bool):
+    """The state-space half of a "hybrid" mixer over a call's columns (z,
+    xbc, dt: ``serve_hybrid_in``'s) on the layer's two state arrays, the
+    convolution's last K - 1 columns and the recurrent state a head (ops/
+    ssm.py). ``rows`` / ``fresh`` / ``n_real`` as :func:`_conv_mixer`'s;
+    columns past ``n_real`` leave both as they were. A call of one column a
+    slot with nothing to mask (a decode step) updates the state IN PLACE
+    (``kernel``: the Pallas kernel; else its ``jax.numpy`` form); any other
+    scans its columns from the carried state. Returns (the branch as the
+    residual takes it [B, T, D], kv)."""
+    from ray_tpu.ops import ssm as ssm_ops
+    blk = block_of(cfg)
+    i = ld.state_layer
+    taps, pool = kv["state"][i]
+    nb, t = xbc.shape[:2]
+    with jax.named_scope("ssm"):
+        with jax.named_scope("ssm_conv"):
+            prev = taps[rows].reshape(nb, -1, xbc.shape[-1])
+            if fresh is not None:
+                prev = jnp.where(fresh, jnp.zeros_like(prev), prev)
+            ext = jnp.concatenate([prev.astype(xbc.dtype), xbc], axis=1)
+            xs, b, c = blk.serve_ssm_conv(ext, layer, cfg)
+            dt, a = blk.serve_ssm_step(dt, layer)
+        if t == 1 and fresh is None and n_real is None:
+            with jax.named_scope("ssm_update"):
+                update = ssm_ops.decode_update if kernel \
+                    else ssm_ops.decode_update_xla
+                y, pool = update(pool, rows, xs[:, 0], dt[:, 0], a, b[:, 0],
+                                 c[:, 0])
+                y = y[:, None]
+        else:
+            with jax.named_scope("ssm_scan"):
+                if n_real is not None:
+                    dt = jnp.where(jnp.arange(t)[None, :, None]
+                                   < n_real[:, None, None], dt, 0.0)
+                state = pool[rows]
+                if fresh is not None:
+                    state = jnp.where(fresh, jnp.zeros_like(state), state)
+                y, state = jax.vmap(
+                    lambda *o: ssm_ops.chunk_scan(*o, cfg.ssm_chunk),
+                    in_axes=(0, 0, None, 0, 0, 0))(xs, dt, a, b, c, state)
+                pool = pool.at[rows].set(state)
+        with jax.named_scope("state_write"):
+            taps = taps.at[rows].set(_last_real(
+                ext, prev.shape[1], n_real).reshape(nb, -1).astype(
+                    taps.dtype))
+        with jax.named_scope("ssm_out"):
+            out = blk.serve_ssm_out(y, xs, z, layer, cfg)
+    return out, {**kv, "state": kv["state"][:i] + ((taps, pool),)
+                 + kv["state"][i + 1:]}
+
+
+def _hybrid_mixer(x, kv, layer, ld, l, g):
+    """TWO mixers off one norm (``serve_hybrid_in``), both added to the
+    residual: the "attn" write and read (:func:`_write_read`) and the
+    state-space half (:func:`_ssm_half`)."""
+    blk = block_of(g.cfg)
+    (q, k, v), (z, xbc, dt) = blk.serve_hybrid_in(x, layer, g.cos, g.sin,
+                                                 g.cfg)
+    out, kv = _ssm_half(kv, layer, g.cfg, ld, z, xbc, dt, *g.state(),
+                        kernel=g.attn_backend == "pallas")
+    x, kv = _write_read(x, kv, q, k, v, ld, l, g,
+                        lambda read: blk.serve_attn_out(read, layer, g.cfg))
+    return x + out, kv
 
 
 def _use_pallas_decode(cfg=None, page_size: int = 0, tp: int = 1) -> bool:
@@ -1275,6 +1414,7 @@ _MIXERS = {
     "latent": _latent_mixer,
     "conv": lambda x, kv, layer, ld, l, g: _conv_mixer(
         x, kv, layer, g.cfg, ld, *g.state()),
+    "hybrid": _hybrid_mixer,
 }
 
 
@@ -1561,8 +1701,13 @@ def paged_prefill(params, kv, page_table, tokens, true_len,
                 pool = _write_token_rows(kv["k"], l, entry, page_idx[None],
                                          offset[None])
             return x, {**kv, "k": pool}
-        sink = None
-        if kind == "gated":
+        sink = ssm = None
+        if kind == "hybrid":
+            (q, k, v), parts = blk.serve_hybrid_in(x, layer, cos, sin, cfg)
+            ssm, kv = _ssm_half(
+                kv, layer, cfg, ld, *parts, page_table[:1], True,
+                jnp.reshape(true_len, (1,)).astype(jnp.int32), kernel=False)
+        elif kind == "gated":
             q, k, v, gate = blk.serve_gated_qkv(x, layer, cos, sin, cfg, ld)
         elif kind == "sink":
             q, k, v, sink = blk.serve_sink_qkv(x, layer, cos, sin, cfg, ld)
@@ -1586,8 +1731,12 @@ def paged_prefill(params, kv, page_table, tokens, true_len,
             # compute-bound and contiguous — no need to read back through
             # pages)
             attn = _dense_attention(q, k, v, mask[None, None], sm, sink)
-            x = x + (blk.serve_gated_out(attn, gate, layer, cfg)
-                     if kind == "gated" else blk.serve_attn_out(attn, layer))
+            if kind == "hybrid":
+                x = x + blk.serve_attn_out(attn, layer, cfg) + ssm
+            else:
+                x = x + (blk.serve_gated_out(attn, gate, layer, cfg)
+                         if kind == "gated"
+                         else blk.serve_attn_out(attn, layer))
         x, kv = _ffn(x, kv, layer, cfg, ld)
         with jax.named_scope("kv_write"):
             # scatter the prompt's k/v into this slot's pages

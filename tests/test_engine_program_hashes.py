@@ -56,7 +56,13 @@ their place by ``test_the_operand_program_at_k_is_a_scan_of_k_steps``: the
 one program run at k against a ``lax.scan`` of k of the same step, on
 operands a live engine dispatched, bit for bit. Every OTHER program
 (verify, prefill, chunk: 28) lowers to the parent's text under the parent's
-scopes, and ``PARENT_57`` holds that apart from ``PINNED``.
+scopes, and ``PARENT_57`` holds that apart from ``PINNED``. ISSUE 60 ADDS
+the block that runs a state-space mixer beside attention in every layer
+(``falcon``: six programs, no verify; its state arrays a layer, its decode
+programs with the in-place update, on the pallas backend the kernel) and
+leaves the 40 others as they were recorded: that PR's edits of kv_cache.py
+(the state pool's rows, a mixer kind, the whole prefill's branch) lower
+every one of them to the parent's text under the parent's scopes.
 
 A PR that MEANS to change one of these programs rewrites the file and says
 so: ``python tests/test_engine_program_hashes.py`` (from the repo's root).
@@ -78,8 +84,8 @@ if __name__ == "__main__":      # run as a script: the repo's root on the path
     sys.path.insert(0, os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
 
-from ray_tpu.models import (afmoe, joyai, lfm2_moe, llama, mimo,  # noqa: E402
-                            sdar_moe)
+from ray_tpu.models import (afmoe, falcon_h1, joyai, lfm2_moe,  # noqa: E402
+                            llama, mimo, sdar_moe)
 from ray_tpu.serve.llm import LLMConfig, LLMEngine  # noqa: E402
 
 DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data",
@@ -93,14 +99,16 @@ BLOCKS = {"dense": lambda: llama.llama_tiny(vocab_size=512),
           "sdar": sdar_moe.sdar_moe_tiny,
           "joyai": joyai.joyai_tiny,
           "afmoe": afmoe.afmoe_tiny,
-          "mimo": mimo.mimo_tiny}
+          "mimo": mimo.mimo_tiny,
+          "falcon": falcon_h1.falcon_h1_tiny}
 BACKENDS = ("gather", "pallas")
 PROGRAMS = ("decode", "verify", "prefill_32", "chunk_16")
 # (no speculation beside a pending block)
 SDAR_PROGRAMS = ("decode", "prefill_32", "chunk_16")
 CASES = [(blk, backend, prog) for blk in BLOCKS for backend in BACKENDS
          for prog in (SDAR_PROGRAMS if blk == "sdar" else PROGRAMS)
-         if not (blk in ("lfm2", "afmoe", "mimo") and prog == "verify")]
+         if not (blk in ("lfm2", "afmoe", "mimo", "falcon")
+                 and prog == "verify")]
 # (no verify program: slot state, and window layers' rings)
 # every k a dispatch can run (the tiers of 1, 4 and 8 tokens: steps, or with
 # SDAR's block length of 4 one and two whole blocks): the parent held a
@@ -230,6 +238,12 @@ PINNED = {
     ("sdar", "gather"): ("1cfca4cb8846a3e2", "0f8308efca9ee145"),
     ("sdar", "pallas"): ("22e786ab705593fe", "8ee2d629a1866894"),
 }
+# ISSUE 60's tree wrote the new block's entries; it is no part of the
+# parent's record (``PARENT_57`` runs over ``PINNED``'s keys)
+ADDED_60 = {
+    ("falcon", "gather"): ("e32d807d32d177aa", "ff990a5d03fd0166"),
+    ("falcon", "pallas"): ("455581214e9bbe8b", "57ce864f140aaa52"),
+}
 
 
 def _pin(data: dict, block: str, backend: str, decode: bool = True) -> tuple:
@@ -250,6 +264,12 @@ def test_no_program_is_recorded_again_unseen(recorded, block, backend):
     cannot move another block's or backend's programs unseen: a PR that
     means to move these re-pins them here and says so in the header."""
     assert _pin(recorded, block, backend) == PINNED[block, backend]
+
+
+@pytest.mark.parametrize("block,backend", sorted(ADDED_60),
+                         ids=["-".join(k) for k in sorted(ADDED_60)])
+def test_the_block_added_since_is_pinned_too(recorded, block, backend):
+    assert _pin(recorded, block, backend) == ADDED_60[block, backend]
 
 
 # What commit d784841's record (ISSUE 56's tree wrote it) holds for every
@@ -451,11 +471,12 @@ def test_harvest_reads_only_the_rows_of_its_k(block):
 
 TIER_CASES = [("dense", {}), ("dense", {"spec_decode_enabled": True}),
               ("lfm2", {}), ("sdar", {}), ("joyai", {}), ("afmoe", {}),
-              ("mimo", {})]
+              ("mimo", {}), ("falcon", {})]
 
 
 @pytest.mark.parametrize("block,over", TIER_CASES, ids=[
-    "dense", "dense-spec", "lfm2", "sdar", "joyai", "afmoe", "mimo"])
+    "dense", "dense-spec", "lfm2", "sdar", "joyai", "afmoe", "mimo",
+    "falcon"])
 def test_every_k_the_loop_can_pick_is_one_start_warmed(block, over):
     """``_warmup_decode_programs`` dispatches ONE decode program a bucket
     width (ISSUE 58: k is its operand), at the ceiling tier's k, and
@@ -534,10 +555,23 @@ if __name__ == "__main__":
     # to another text" leaves that key to the tree that wrote it)
     out = {"jax": jax.__version__, "programs": {}, "scopes": {}}
     for blk in BLOCKS:
+        if "--only" in sys.argv[1:] \
+                and blk != sys.argv[sys.argv.index("--only") + 1]:
+            continue
         for kernel in BACKENDS:
             for name, (text, scopes) in _hashes(blk, kernel).items():
                 out["programs"][f"{blk}-{kernel}-{name}"] = text
                 out["scopes"][f"{blk}-{kernel}-{name}"] = scopes
+    if "--only" in sys.argv[1:]:
+        # ``--only <block>``: that block's entries alone are written; every
+        # other entry stays the tree's that wrote it, letter for letter
+        only = sys.argv[sys.argv.index("--only") + 1]
+        with open(DATA) as f:
+            was = json.load(f)
+        assert was["jax"] == out["jax"], (was["jax"], out["jax"])
+        for key in ("programs", "scopes"):
+            out[key] = {**was[key], **{k: v for k, v in out[key].items()
+                                       if k.startswith(f"{only}-")}}
     if "--scopes" in sys.argv[1:]:
         with open(DATA) as f:
             was = json.load(f)
@@ -550,6 +584,6 @@ if __name__ == "__main__":
     print(f"{len(out['programs'])} programs -> {DATA}")
     for name, decode in (("PINNED", True), ("every program but decode", False)):
         print(name, "= {")
-        for blk, kernel in sorted(PINNED):
+        for blk, kernel in sorted({**PINNED, **ADDED_60}):
             print(f"    {(blk, kernel)!r}: {_pin(out, blk, kernel, decode)!r},")
         print("}")

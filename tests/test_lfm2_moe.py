@@ -93,6 +93,37 @@ def test_a_slot_and_its_state_row_reused_after_another_sequence(params):
         eng.shutdown()
 
 
+def test_the_state_still_rides_the_page_table_a_row_a_page(params):
+    """ISSUE 60 gave the allocator a reserved range of first pages for a
+    block whose state pool has a row a SLOT; this block's pool keeps a row
+    a PAGE, any free page may be a sequence's first, and a sequence's state
+    row is that page."""
+    spec = lfm2_moe.cache_spec(CFG)
+    assert not spec.state_per_slot and not spec.state_arrays
+    eng = _engine()
+    try:
+        assert eng.allocator.first_pages == 0
+        assert all(a.shape == (ENGINE["num_pages"], 2 * CFG.dim)
+                   for a in eng.kv["state"])
+        st = eng.engine_stats()
+        assert st["state_rows"] == ENGINE["num_pages"]
+        assert st["first_pages_free"] == 0
+        assert set(st["state_pool_bytes"]) == {"taps"}
+        assert st["state_bytes_per_slot"] == 3 * 2 * CFG.dim * 4
+        # a first page far up the pool: the sequence's state lands in its row
+        held = eng.allocator.alloc(40)
+        prompt = _prompt(40, 21)
+        out = eng.result(eng.submit(prompt, max_tokens=6, temperature=0.0),
+                         timeout=120.0)
+        _reference_agrees(params, [prompt], [out], 6)
+        rows = [int(r) for r in np.flatnonzero(
+            np.abs(np.asarray(eng.kv["state"][0])).sum(axis=1))]
+        assert set(rows) - {0} == {41}      # (idle lanes: the trash row)
+        eng.allocator.free(held)
+    finally:
+        eng.shutdown()
+
+
 # ---- the expert layer ------------------------------------------------------
 
 def _plain_routed(g, moe, idx, w):

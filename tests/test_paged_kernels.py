@@ -144,6 +144,39 @@ def test_decode_wrapper_matches_decode_attention_integration():
         jnp.full((b,), mp * page, jnp.int32), d ** -0.5)[:, 0])
 
 
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("call", ["decode", "chunk"])
+def test_five_query_heads_a_kv_head_through_the_wrappers(call, dtype):
+    """ISSUE 60: 20 query heads of 128 on 4 KV heads, the first group that
+    is no power of two (the wrappers pad a KV head's query rows to whole
+    sublane tiles): decode and a chunk against the gather math."""
+    hkv, n_rep, d, page, mp, b = 4, 5, 128, 8, 4, 3
+    h = hkv * n_rep
+    k_pages, v_pages = _rand_pool(jax.random.PRNGKey(60), hkv, mp * b + 1,
+                                  page, d, dtype)
+    sm = d ** -0.5
+    if call == "decode":
+        q = jax.random.normal(jax.random.PRNGKey(61), (b, h, d), dtype)
+        tables = jnp.arange(1, mp * b + 1).reshape(b, mp).astype(jnp.int32)
+        pos = jnp.asarray([0, 13, 30], jnp.int32)
+        got = paged_ops.paged_decode_attention(q, k_pages, v_pages, tables,
+                                               pos, sm_scale=sm)[:, None]
+        want = _ref_attention(q[:, None], k_pages, v_pages, tables, pos,
+                              jnp.full((b,), mp * page, jnp.int32), sm)
+    else:
+        q = jax.random.normal(jax.random.PRNGKey(62), (1, 16, h, d), dtype)
+        table = jnp.arange(1, mp + 1, dtype=jnp.int32)
+        start, true_len = 8, 21
+        got = paged_ops.paged_chunk_attention(
+            q, k_pages, v_pages, table, jnp.int32(start),
+            jnp.int32(true_len), sm_scale=sm)
+        want = _ref_attention(
+            q, k_pages, v_pages, table[None],
+            jnp.asarray([start], jnp.int32),
+            jnp.asarray([true_len], jnp.int32), sm)
+    _assert_matches(got, want)
+
+
 def test_chunk_kernel_masks_padded_tail():
     """Chunked prefill: limit=true_len must hide the padded tail pages —
     same result as the gather reference with the same bound, and NOT the

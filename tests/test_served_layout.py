@@ -30,7 +30,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from ray_tpu.models import afmoe, llama, mimo, sdar_moe
+from ray_tpu.models import afmoe, falcon_h1, llama, mimo, sdar_moe
 from ray_tpu.models.block import HEAD_MAJOR, block_of
 from ray_tpu.ops import paged_attention as paged_ops
 from ray_tpu.serve.llm import kv_cache as kvc
@@ -57,6 +57,12 @@ MIMO = dict(
         n_layers=3, pattern=(0, 1, 1), moe_freq=(0, 1, 1), experts_held=8,
         max_seq_len=9216),
     batch=48, pages=512, seq=9216, chunk=512)
+# ISSUE 60: a state-space mixer beside attention in every layer (20 query
+# heads on 4 KV heads of 128; a state pool of a row a slot: 97 rows of
+# [32, 256, 128] float32 a layer)
+FALCON = dict(
+    name="falcon", model=lambda: falcon_h1.FalconH1Config(n_layers=2),
+    batch=96, pages=1153, seq=2048, chunk=512)
 # (cell, program, width, k): k the drafts of a verify round, 0 elsewhere.
 # The steps (or whole blocks) of a dispatch are an operand of the decode
 # program since ISSUE 58, one program a width: where the parent's k of 1
@@ -67,7 +73,8 @@ CASES = [(MISTRAL, "decode", 4, 0), (MISTRAL, "decode", 16, 0),
          (TRINITY, "chunk", 512, 0),
          (SDAR, "decode", 64, 0),
          (MIMO, "decode", 48, 0), (MIMO, "decode", 16, 0),
-         (MIMO, "chunk", 512, 0)]
+         (MIMO, "chunk", 512, 0),
+         (FALCON, "decode", 96, 0), (FALCON, "chunk", 512, 0)]
 IDS = [f"{cell['name']}-{prog}-w{w}-k{k}" for cell, prog, w, k in CASES]
 
 
@@ -138,7 +145,8 @@ def _compiled_text(cell, program, width, k, one_chip, served=True) -> tuple:
     table = cell["seq"] // PAGE + ring
     b = cell["batch"]
     kv = shaped(jax.eval_shape(lambda: kvc.init_paged_cache(
-        cfg, cell["pages"], PAGE, window_pages=b * ring + 1 if ring else 0)))
+        cfg, cell["pages"], PAGE, window_pages=b * ring + 1 if ring else 0,
+        state_rows=b + 1 if eng._cache_spec.state_per_slot else 0)))
     toks = arg(b + 1, eng._block_len) if eng._block_len > 1 else arg(b + 1)
     key = shaped(jax.eval_shape(lambda: jax.random.PRNGKey(0)))
     state = (params, kv, arg(b + 1, table), arg(b + 1), toks, key,
@@ -211,6 +219,23 @@ def test_no_program_lays_a_projection_out_again(cell, program, width, k,
     text, projections = _compiled_text(cell, program, width, k, one_chip)
     assert projections and "tpu_custom_call" in text
     assert weight_shaped_writes(text, projections) == []
+
+
+@pytest.mark.parametrize("width", [96, 16])
+def test_a_decode_step_moves_the_recurrent_state_in_its_kernel_alone(
+        width, one_chip):
+    """ISSUE 60: the decode program updates a slot's 4 MB of float32 state
+    a layer IN PLACE: one kernel call a layer beside the paged attention
+    kernel's, the pool aliased input to output, and no ``copy`` or fusion
+    of the pool's shape (a gather, an update and a scatter would write
+    [width, 32, 256, 128] twice; a pool that is not donated through, the
+    whole [97, 32, 256, 128])."""
+    text, _ = _compiled_text(FALCON, "decode", width, 0, one_chip)
+    assert text.count("tpu_custom_call") == 2 * 2      # two a layer
+    state = [line.strip()[:160] for line in text.splitlines()
+             if re.search(r"= \(?f32\[\d+,32,256,128\]\S* (copy|fusion)\(",
+                          line)]
+    assert state == []
 
 
 def test_the_dense_chunk_copies_no_more_than_it_did(one_chip):
