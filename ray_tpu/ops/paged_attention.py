@@ -26,9 +26,12 @@ option or a rule on shapes:
   ``block_len`` 1 (static) that is the causal ``col <= pos``. Grid (slot,
   KV head, table page): every page of a slot's table is read, one a grid
   step, and the scores run over the table's whole span, ``max_len``
-  columns: its work follows ``max_seq_len``, never the context. Called by
-  ``paged_decode_attention``, ``paged_verify_attention`` and
-  ``paged_chunk_attention``.
+  columns: its work follows ``max_seq_len``, never the context. It has
+  ONE caller kind left (ISSUE 61): ``paged_chunk_attention`` for a block
+  WITHOUT window layers, the one entry :data:`WALKS_LIVE` ``["heads"]``
+  lacks. When that call moves too (PERF.md section 7 has both bodies
+  timed at the cells' chunk shapes), this body, the ``walk`` flag and the
+  table go (ROADMAP D4).
 
 - pools of K and V per head, the WALKING body (``paged_attention(...,
   walk=True)``, ``_gqa_walk_kernel``; ISSUE 48): the same operands, mask
@@ -39,14 +42,22 @@ option or a rule on shapes:
   they lie (one strided copy a page and pool carries every KV head), the
   next slot's under this slot's products, and scores, sums and weighted
   values over the live chunks of columns only, every KV head of the call
-  at once. Called by ``paged_block_attention``, and by the decode and the
-  chunk call of a block that has WINDOW LAYERS (``window=``, models/
+  at once. Called by ``paged_block_attention``, by
+  ``paged_decode_attention`` and ``paged_verify_attention`` on every such
+  pool (ISSUE 61: a decode step read every page of a 2,048-token table
+  for slots that held 400, at a twelfth of the bytes' time), and by the
+  chunk call too of a block that has WINDOW LAYERS (``window=``, models/
   block.py), for it alone takes a LOWER EDGE: a window layer's walk starts
   at the page of the first query's oldest visible key, reads the slot's
   ring table (entry ``page % ring``) and masks ``col > pos - window``; a
-  full layer of such a block walks from 0. Tables of 16 k tokens do not
-  fit the scratch with every KV head, so those calls walk a group of KV
-  heads a grid step. A call that walks also WRITES (``write=``, ISSUE 53;
+  full layer of such a block walks from 0. The scratch holds a whole walk
+  (a table, or a window and the span) of the KV heads of one grid step, so
+  a call walks as many KV heads a step as :data:`_WALK_KV_BYTES` holds of,
+  by the table's width it sees: every head at a table of 2,048 positions,
+  two of eight at 16 k, one at 32 k; a walk whose ONE head passes the
+  body's VMEM (some 96 k positions of 128 lanes in bf16) is refused when the
+  program is traced, by a ValueError that says so. A call that walks also
+  WRITES (``write=``, ISSUE 53;
   :func:`writing_calls`): it holds the pages the call's own rows of K and
   V go to, so the rows ride in, are laid over the scratch a tile of
   positions at a time and copied back to their pages, and the row scatter
@@ -59,13 +70,11 @@ option or a rule on shapes:
   128: the body's shapes follow each pool's own), a layer's KV heads are
   its pool's, and a layer's softmax may hold a learned SINK a query head
   (``sink=``: the running maximum starts at it, the row sum at ``exp(sink
-  - maximum)``, and its column weighs no value). For blocks without a
-  window the block program is the
-  one caller whose gain the benchmark could judge (ROADMAP S3; PERF.md
-  section 6, PR 48); the body takes every call shape of the family
-  (tests/test_paged_kernels.py drives them), so the other three wrappers
-  move over by an entry in the table, after which the grid body goes
-  (ROADMAP D4).
+  - maximum)``, and its column weighs no value). The body takes every
+  call shape of the family (tests/test_paged_kernels.py drives them): a
+  wrapper moves over by an entry in the table, as the block call did
+  (ISSUE 48) and the decode and verify calls (ISSUE 61); the one left is
+  the chunk call of a block without window layers.
 
 - a LATENT pool (a cache spec with ``latent_dim``, models/block.py;
   :func:`paged_latent_attention`, ``_latent_attn_kernel``): one array of
@@ -139,16 +148,19 @@ _LATENT_CHUNK_PAGES = 4
 _GQA_CHUNK_PAGES = 4
 # THE table of which call kinds run a body that walks a slot's live pages,
 # by the pool's kind: the wrappers route by it and the engine reports its
-# own programs' share of it (``attn_walks_live``). Every other call runs
-# the grid body, whose work follows the table's width.
+# own programs' share of it (``attn_walks_live``). Every other call, which
+# is the chunk call on "heads" and no other, runs the grid body, whose work
+# follows the table's width.
 # ("windowed": pools of K and V per head of a block that has window layers,
 # whose reads need the lower edge, which only a walking body takes.)
-WALKS_LIVE = {"latent": ("decode", "verify", "chunk"), "heads": ("block",),
+WALKS_LIVE = {"latent": ("decode", "verify", "chunk"),
+              "heads": ("decode", "verify", "block"),
               "windowed": ("decode", "chunk")}
-# what the walking body's scratch may hold when it walks for a block with
-# window layers, whose tables run to 16 k tokens and more: both pools' pages
-# of one step's KV heads (both halves), and one row tile's scores. A call
-# whose KV heads do not fit walks them a GROUP a grid step.
+# what the walking body's scratch may hold: both pools' pages of one step's
+# KV heads (both halves), and one row tile's scores of a block with window
+# layers (every other call's: _SCORE_TILE_BYTES). A call whose KV heads do
+# not fit, by its table's width or its window's, walks them a GROUP a grid
+# step; one whose single head passes the limit is refused.
 _WALK_KV_BYTES = 36 * 1024 * 1024
 _WALK_SCORE_BYTES = 16 * 1024 * 1024
 _WALK_VMEM_LIMIT = 100 * 1024 * 1024
@@ -696,6 +708,21 @@ def _latent_call(q, pool, page_tables, base, limit, layer, *, value_lanes,
     return out[:, :r].reshape(b, h, t, value_lanes).transpose(0, 2, 1, 3)
 
 
+def _div(a, b: int):
+    """``a // b`` for an index that is NEVER NEGATIVE, in a kernel body: one
+    equation. (``//`` and ``%`` of a traced integer also correct a negative
+    operand's quotient, a dozen equations and two nested calls each; a
+    walking body has some forty of them, all on positions, pages and grid
+    steps, and they were half of what a program paid to trace and lower
+    it.)"""
+    return a // b if isinstance(a, int) else jax.lax.div(a, b)
+
+
+def _rem(a, b: int):
+    """``a % b`` for an index that is never negative (:func:`_div`)."""
+    return a % b if isinstance(a, int) else jax.lax.rem(a, b)
+
+
 def _gqa_walk_kernel(pt_ref, base_ref, limit_ref, layer_ref,  # prefetch
                      *refs, sm_scale: float, page_size: int,
                      max_pages: int, chunk_pages: int, t_span: int,
@@ -792,8 +819,8 @@ def _gqa_walk_kernel(pt_ref, base_ref, limit_ref, layer_ref,  # prefetch
     else:
         k_pool, v_pool, o_ref, k_scr, v_scr, s_scr, acc_scr, sems = refs
     step = pl.program_id(0)
-    half = step % 2
-    b = step if groups == 1 else step // groups
+    half = _rem(step, 2)
+    b = step if groups == 1 else _div(step, groups)
     base = base_ref[b]
     limit = limit_ref[b]
     chunk = chunk_pages * page_size
@@ -803,17 +830,17 @@ def _gqa_walk_kernel(pt_ref, base_ref, limit_ref, layer_ref,  # prefetch
     def pages_of(slot):
         end = base_ref[slot] + t_span
         if block_len > 1:       # the end of the last position's block
-            end = ((end - 1) // block_len + 1) * block_len
+            end = (_div(end - 1, block_len) + 1) * block_len
         if window:      # a ring's positions run past its table's span
             live = jnp.maximum(jnp.minimum(limit_ref[slot], end), 0)
         else:
             live = jnp.clip(jnp.minimum(limit_ref[slot], end),
                             0, max_pages * page_size)
-        return (live + page_size - 1) // page_size
+        return _div(live + page_size - 1, page_size)
 
     def first_of(slot):
         """The page of the first query's oldest visible key."""
-        return jnp.maximum(base_ref[slot] - (window - 1), 0) // page_size
+        return _div(jnp.maximum(base_ref[slot] - (window - 1), 0), page_size)
 
     def page_rows(j):
         return pl.ds(pl.multiple_of(j * page_size, page_size), page_size)
@@ -821,21 +848,21 @@ def _gqa_walk_kernel(pt_ref, base_ref, limit_ref, layer_ref,  # prefetch
     def heads_of(s):
         """The KV heads step s walks: every one, or its group's."""
         return slice(None) if groups == 1 \
-            else pl.ds((s % groups) * hkv, hkv)
+            else pl.ds(_rem(s, groups) * hkv, hkv)
 
     def page_copies(s, j, at):
         """Logical page j of step s's slot, the step's KV heads of it, K
         and V, into scratch page ``at``."""
-        slot = s if groups == 1 else s // groups
+        slot = s if groups == 1 else _div(s, groups)
         return [pltpu.make_async_copy(
             pool.at[layer_ref[0], heads_of(s),
-                    pt_ref[slot, j % max_pages if window else j]],
-            scr.at[s % 2, :, page_rows(at)],
-            sems.at[s % 2, at // chunk_pages])
+                    pt_ref[slot, _rem(j, max_pages) if window else j]],
+            scr.at[_rem(s, 2), :, page_rows(at)],
+            sems.at[_rem(s, 2), _div(at, chunk_pages)])
             for pool, scr in ((k_pool, k_scr), (v_pool, v_scr))]
 
     def fetch(s):
-        slot = s if groups == 1 else s // groups
+        slot = s if groups == 1 else _div(s, groups)
         first = first_of(slot) if window else 0
 
         def start(j, carry):
@@ -855,7 +882,7 @@ def _gqa_walk_kernel(pt_ref, base_ref, limit_ref, layer_ref,  # prefetch
     first = first_of(b) if window else 0
     live_pages = jnp.maximum(pages_of(b) - first, 0) if window \
         else pages_of(b)
-    live_chunks = (live_pages + chunk_pages - 1) // chunk_pages
+    live_chunks = _div(live_pages + chunk_pages - 1, chunk_pages)
 
     def zero(j, carry):
         v_scr[half, :, page_rows(j), :] = jnp.zeros(
@@ -867,15 +894,15 @@ def _gqa_walk_kernel(pt_ref, base_ref, limit_ref, layer_ref,  # prefetch
     # the call's own rows, a tile of positions at a time
     if writes:
         sub = _write_tile(k_scr.dtype, page_size)
-        tile0 = base // sub                 # the tile of the span's first row
+        tile0 = _div(base, sub)             # the tile of the span's first row
         origin = first * page_size          # the position of scratch column 0
 
         def tile_at(tile):
             """(the tile's first position, the pool page the table has for
             it, its columns of the scratch)."""
             p0 = tile * sub
-            page = p0 // page_size
-            return (p0, pt_ref[b, page % max_pages if window else page],
+            page = _div(p0, page_size)
+            return (p0, pt_ref[b, _rem(page, max_pages) if window else page],
                     pl.ds(pl.multiple_of(p0 - origin, sub), sub))
 
         def tile_copies(tile):
@@ -885,7 +912,7 @@ def _gqa_walk_kernel(pt_ref, base_ref, limit_ref, layer_ref,  # prefetch
             return [pltpu.make_async_copy(
                 scr.at[half, :, cols],
                 pool.at[layer_ref[0], heads_of(step), page,
-                        pl.ds(pl.multiple_of(p0 % page_size, sub), sub)],
+                        pl.ds(pl.multiple_of(_rem(p0, page_size), sub), sub)],
                 wsems.at[n])
                 for n, (pool, scr) in enumerate(((k_pool, k_scr),
                                                  (v_pool, v_scr)))]
@@ -894,22 +921,26 @@ def _gqa_walk_kernel(pt_ref, base_ref, limit_ref, layer_ref,  # prefetch
             """The tiles of the call's span that lie in scratch columns lo ..
             hi of the live pages."""
             hi = jnp.minimum(hi, live_pages * page_size)
-            return (jnp.maximum(tile0, (origin + lo) // sub),
-                    jnp.minimum((base + t_span - 1) // sub + 1,
-                                (origin + hi) // sub))
+            return (jnp.maximum(tile0, _div(origin + lo, sub)),
+                    jnp.minimum(_div(base + t_span - 1, sub) + 1,
+                                _div(origin + hi, sub)))
 
         def lay(tile, carry):
             p0, page, cols = tile_at(tile)
 
             def kept_rows(lanes):
                 """(the sublane of each element of a [sub, lanes] tile, 1
-                where the caller's rule kept that row here)."""
+                where the caller's rule kept that row here). The tile holds
+                at most ``min(t_span, sub)`` rows of the call, from row
+                ``max(p0 - base, 0)`` on: a decode call's one."""
                 at = jax.lax.broadcasted_iota(jnp.int32, (sub, lanes), 0)
                 keep = jnp.zeros((sub, lanes), jnp.int32)
-                for r in range(sub):
-                    t = p0 + r - base
-                    kept = (t >= 0) & (t < t_span) & (pidx_ref[
-                        b * t_span + jnp.clip(t, 0, t_span - 1)] == page)
+                t0 = jnp.maximum(p0 - base, 0)
+                for j in range(min(t_span, sub)):
+                    t = t0 + j              # a row of the call, which lies at
+                    r = base + t - p0       # sublane r (past the tile: none)
+                    kept = (t < t_span) & (pidx_ref[
+                        b * t_span + jnp.minimum(t, t_span - 1)] == page)
                     keep = jnp.where(at == r, kept.astype(jnp.int32), keep)
                 return at, keep
 
@@ -944,10 +975,10 @@ def _gqa_walk_kernel(pt_ref, base_ref, limit_ref, layer_ref,  # prefetch
         q = q_ref[:, pl.ds(r0, row_tile), :]                   # [Hkv, TR, D]
         row = r0 + jax.lax.broadcasted_iota(
             jnp.int32, (row_tile, chunk), 0)
-        pos = base + row % t_span
+        pos = base + _rem(row, t_span)
         # the last key a row sees: itself, or the end of its block
         seen = pos + 1 if block_len == 1 \
-            else (pos // block_len + 1) * block_len
+            else (_div(pos, block_len) + 1) * block_len
 
         def chunk_rows(c):
             return pl.ds(pl.multiple_of(c * chunk, chunk), chunk)
@@ -988,8 +1019,8 @@ def _gqa_walk_kernel(pt_ref, base_ref, limit_ref, layer_ref,  # prefetch
 
         lowest = jnp.full((hkv, row_tile, 1), _NEG_INF, jnp.float32)
         if sink:    # each row's own head's sink: row r = rep * t_span + t
-            rep = (r0 + jax.lax.broadcasted_iota(
-                jnp.int32, (row_tile, 1), 0)) // t_span
+            rep = _div(r0 + jax.lax.broadcasted_iota(
+                jnp.int32, (row_tile, 1), 0), t_span)
             for j in range(sink):
                 lowest = jnp.where((rep == j)[None],
                                    sink_ref[:, j:j + 1, :][:, :, :1], lowest)
@@ -1041,10 +1072,11 @@ def _gqa_walk_call(q, k_pages, v_pages, page_tables, base, limit, layer,
     program calls it twice a walked layer, and the body is then traced
     once a process and lowered once a program.
 
-    ``window`` None: the scratch holds a whole table of every KV head, one
-    grid step a slot. Not None (a block with window layers): the scratch
-    holds the widest walk, a window layer's window and span, and as many
-    KV heads a step as :data:`_WALK_KV_BYTES` allows.
+    The scratch holds the widest walk (``window`` None or 0: a whole
+    table; a window layer: its window and span) of as many KV heads a step
+    as :data:`_WALK_KV_BYTES` allows: every head and one grid step a slot
+    at the tables the cells serve, fewer under a wider table, one at least;
+    if that one does not fit :data:`_WALK_VMEM_LIMIT` the call raises.
 
     ``write`` (k_new, v_new [B, T, Hkv, D] in the pool's row form, page_idx
     [B, T]): the call's own rows ride in and the body writes them (its
@@ -1073,18 +1105,18 @@ def _gqa_walk_call(q, k_pages, v_pages, page_tables, base, limit, layer,
     chunk = chunk_pages * page_size
     r = n_rep * t
     isz = jnp.dtype(q.dtype).itemsize
-    groups = 1
-    if window is None:
-        # a score tile holds every KV head's rows
-        r_pad, row_tile = _row_tiling(r, hkv * n_chunks * chunk, q.dtype)
-    else:
-        head_bytes = 2 * n_chunks * chunk * (d + dv) \
-            * jnp.dtype(k_pages.dtype).itemsize
-        groups = next(g for g in range(1, hkv + 1) if hkv % g == 0
-                      and (hkv // g * head_bytes <= _WALK_KV_BYTES
-                           or g == hkv))
-        r_pad, row_tile = _row_tiling(
-            r, hkv // groups * n_chunks * chunk, q.dtype, _WALK_SCORE_BYTES)
+    # the KV heads a grid step walks: as many as the scratch may hold of
+    # (both halves of both pools' pages of one walk), by what the call can
+    # see, the table's width or the window's: every head at the cells'
+    # tables of 2,048 positions, two at 16 k, one at 32 k and beyond
+    head_bytes = 2 * n_chunks * chunk * (d + dv) \
+        * jnp.dtype(k_pages.dtype).itemsize
+    groups = next(g for g in range(1, hkv + 1) if hkv % g == 0
+                  and (hkv // g * head_bytes <= _WALK_KV_BYTES or g == hkv))
+    # a score tile holds the rows of every KV head of a step
+    r_pad, row_tile = _row_tiling(
+        r, hkv // groups * n_chunks * chunk, q.dtype,
+        _SCORE_TILE_BYTES if window is None else _WALK_SCORE_BYTES)
     hs = hkv // groups       # the KV heads of one grid step
     # [B, T, H, D] -> [B, Hkv, n_rep*T, D]: the grid body's layout
     qg = q.reshape(b, t, hkv, n_rep, d).transpose(0, 2, 3, 1, 4).reshape(
@@ -1092,13 +1124,19 @@ def _gqa_walk_call(q, k_pages, v_pages, page_tables, base, limit, layer,
     if r_pad != r:
         qg = jnp.pad(qg, ((0, 0), (0, 0), (0, r_pad - r), (0, 0)))
 
-    # both halves of both scratches + double-buffered q/o blocks + the row
-    # tile's scores, accumulator and their temporaries, with headroom
-    vmem = (2 * hs * n_chunks * chunk * (d + dv)
-            * jnp.dtype(k_pages.dtype).itemsize
-            + 2 * hs * r_pad * (d + dv) * isz
-            + 4 * hs * row_tile * n_chunks * chunk * 4
-            + 4 * hs * row_tile * dv * 4)
+    # what the call declares: both halves of both scratches, the
+    # double-buffered q/o blocks, the row tile's scores and accumulator;
+    # and, with headroom, their temporaries beside them
+    held = (hs * head_bytes + 2 * hs * r_pad * (d + dv) * isz
+            + hs * row_tile * (n_chunks * chunk + dv) * 4)
+    if held > _WALK_VMEM_LIMIT and not interpret:
+        raise ValueError(
+            f"a walk of {n_chunks * chunk} positions holds {held >> 20} MB "
+            f"in VMEM a grid step of {hs} KV head(s) ({hs * head_bytes >> 20} "
+            f"MB of it the pages of K and V, both halves) and the body may "
+            f"take {_WALK_VMEM_LIMIT >> 20} MB: use a shorter max_seq_len "
+            f"or attention_kernel='gather'")
+    vmem = held + 3 * hs * row_tile * (n_chunks * chunk + dv) * 4
     kernel = functools.partial(
         _gqa_walk_kernel, sm_scale=sm_scale, page_size=page_size,
         max_pages=max_pages, chunk_pages=chunk_pages, t_span=t,

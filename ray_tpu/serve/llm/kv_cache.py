@@ -20,7 +20,11 @@ Design choices:
   + dense softmax. Auto resolution picks pallas on TPU (when the
   kernel's tiling accepts the shapes) and gather elsewhere; an explicit
   "pallas" the kernel cannot tile raises; tests force the pallas backend
-  in interpreter mode on CPU;
+  in interpreter mode on CPU. Which kernel BODY a call runs (one that
+  walks a slot's live pages, or the grid over its whole table) is that
+  file's table ``WALKS_LIVE``, by the pool's kind and the call's kind:
+  every call walks but the chunk call on pools of K and V per head of a
+  block without window layers;
 - the pool is LAYER-INDEXED AND CARRIED IN PLACE: inside every paged
   program the whole pool [n_layers, Hkv, P, page, D] is a carry of the
   scan over layers (and, in the engine's decode block, of the scan over
@@ -38,9 +42,13 @@ Design choices:
   slots write to a reserved trash page (page 0), keeping the step free of
   dynamic shapes and `lax.cond`s. Where the kernel that reads a call's
   pages back walks them (pools of K and V per head, one chip: ops/
-  paged_attention.py ``writing_calls``), the call's rows ride in that
-  kernel and it writes them (:func:`_write_read`): the same bytes in the
-  same rows, where the rows go decided here all the same;
+  paged_attention.py ``writing_calls``: the decode and verify calls of
+  every such block, a block pass, and the chunk call too of a block with
+  window layers; NOT the chunk call of a block without them, a latent
+  pool's calls, the gather backend or a tensor-parallel mesh), the call's
+  rows ride in that kernel and it writes them (:func:`_write_read`): the
+  same bytes in the same rows, where the rows go decided here all the
+  same;
 - full (non-chunked) prefill stays dense within the prompt: it runs at
   B=1 per admission with no cached prefix to read back;
 - a block that generates by diffusion over blocks (its cache spec states a

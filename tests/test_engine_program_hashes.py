@@ -62,7 +62,22 @@ the block that runs a state-space mixer beside attention in every layer
 programs with the in-place update, on the pallas backend the kernel) and
 leaves the 40 others as they were recorded: that PR's edits of kv_cache.py
 (the state pool's rows, a mixer kind, the whole prefill's branch) lower
-every one of them to the parent's text under the parent's scopes.
+every one of them to the parent's text under the parent's scopes. ISSUE 61
+moves the decode and verify calls of a block WITHOUT window layers to the
+walking body (one entry of ``paged_attention.WALKS_LIVE``), whose kernel
+writes the call's rows, and cuts that body's index arithmetic (``_div`` /
+``_rem``: one equation a division of an index that is never negative,
+where ``//`` and ``%`` lowered a dozen; the rows a tile keeps found in
+``min(t_span, 16)`` steps, not 16): NINE programs of the pallas backend
+were recorded again, text and scopes, every one that calls the walking
+body (``dense-decode``, ``dense-verify``, ``lfm2-decode``, ``falcon-decode``:
+the kernel under ``jit(_gqa_walk_call)``, no row scatter under
+``kv_write``; ``sdar-decode``, ``afmoe-decode``, ``afmoe-chunk_16``,
+``mimo-decode``, ``mimo-chunk_16``: the same calls, the shorter body), their
+six pins with them and three entries of ``PARENT_57`` (the dense block's
+verify program, afmoe's and mimo's chunk programs); the 37 others (every
+program of the gather backend, every prefill, the latent block, the chunk
+programs that run the grid body) are the parent's, letter for letter.
 
 A PR that MEANS to change one of these programs rewrites the file and says
 so: ``python tests/test_engine_program_hashes.py`` (from the repo's root).
@@ -223,26 +238,30 @@ def test_program_lowers_to_the_recorded_text(recorded, block, backend,
 # tree wrote the decode entry of each (the steps of a dispatch became an
 # operand); every other entry is ISSUE 56's tree's (each program ends in a
 # sampler or a head, SDAR's prefill and chunk apart), which ``PARENT_57``
-# below holds on its own.
+# below holds on its own. ISSUE 61's tree wrote the pallas backend's entries
+# that call the walking body again (its header paragraph): ``dense`` and
+# ``lfm2`` walk and write in their decode (and verify) programs, and the
+# body's index arithmetic is shorter for ``sdar``, ``afmoe`` and ``mimo`` too.
 PINNED = {
     ("afmoe", "gather"): ("7335753175bc5025", "718e71920d0fbe71"),
-    ("afmoe", "pallas"): ("64396d8b5de1485f", "6dd79f73b6792200"),
+    ("afmoe", "pallas"): ("e7a589dc891935d7", "9daa650e8f5cd520"),
     ("dense", "gather"): ("9d39e962040c8f7c", "bc2127eb6ec59536"),
-    ("dense", "pallas"): ("e305a2b1b7bab861", "116838f808d11b14"),
+    ("dense", "pallas"): ("ded2fe8c05def00e", "726fda2220990466"),
     ("joyai", "gather"): ("bc56b071827794c3", "d1e09a603e6d8b2c"),
     ("joyai", "pallas"): ("312034029fb07bda", "063d50367ea3e23a"),
     ("lfm2", "gather"): ("e0aeff7770d874dc", "c7818c674526ef2d"),
-    ("lfm2", "pallas"): ("0067266e91a7d9b3", "49d87ddf2555df16"),
+    ("lfm2", "pallas"): ("afc016ca4a413020", "8b2c509b37a5836f"),
     ("mimo", "gather"): ("2e94649e0b0c148c", "60d19042b4136686"),
-    ("mimo", "pallas"): ("7b490b141093dc5e", "0b3dc3fe9c38e833"),
+    ("mimo", "pallas"): ("67ef1f3a7e05a3a2", "487df88c0654797e"),
     ("sdar", "gather"): ("1cfca4cb8846a3e2", "0f8308efca9ee145"),
-    ("sdar", "pallas"): ("22e786ab705593fe", "8ee2d629a1866894"),
+    ("sdar", "pallas"): ("3b396836bcf5318e", "429b3e47041217fc"),
 }
-# ISSUE 60's tree wrote the new block's entries; it is no part of the
-# parent's record (``PARENT_57`` runs over ``PINNED``'s keys)
+# ISSUE 60's tree wrote the new block's entries (ISSUE 61's its decode
+# program on the pallas backend again); it is no part of the parent's
+# record (``PARENT_57`` runs over ``PINNED``'s keys)
 ADDED_60 = {
     ("falcon", "gather"): ("e32d807d32d177aa", "ff990a5d03fd0166"),
-    ("falcon", "pallas"): ("455581214e9bbe8b", "57ce864f140aaa52"),
+    ("falcon", "pallas"): ("ffd057cea1734dcd", "e5e43c32ceca854b"),
 }
 
 
@@ -274,18 +293,21 @@ def test_the_block_added_since_is_pinned_too(recorded, block, backend):
 
 # What commit d784841's record (ISSUE 56's tree wrote it) holds for every
 # program BUT the decode ones, a (block, backend): ISSUE 58 recorded the
-# decode programs again (the steps became an operand) and no other.
+# decode programs again (the steps became an operand) and no other;
+# ISSUE 61 the dense block's verify program on the pallas backend (its
+# call walks and writes) and afmoe's and mimo's chunk programs (the walking
+# body's shorter index arithmetic), so those three entries are its tree's.
 PARENT_57 = {
     ("afmoe", "gather"): ("0318071c036f5855", "3d593530f9433595"),
-    ("afmoe", "pallas"): ("4406906dc0b647e2", "f77aaf89487ddfb9"),
+    ("afmoe", "pallas"): ("bb8a1d45dd918a04", "1187ad2afae06848"),
     ("dense", "gather"): ("8cc48a604031adb5", "9985945bacdc9d7c"),
-    ("dense", "pallas"): ("9056607e1dd6f26b", "e435867eff4aedcd"),
+    ("dense", "pallas"): ("1e203700384c43f3", "06d7f3b4a6bf48bd"),
     ("joyai", "gather"): ("4f178b70acd4a9ec", "9b8c3dc9658ba592"),
     ("joyai", "pallas"): ("00ad5719e83a4852", "d36039e595c6f124"),
     ("lfm2", "gather"): ("30e49160fb2f0a39", "01e934d82a65d88d"),
     ("lfm2", "pallas"): ("4be70bf6cf86ac2d", "fe815c648e186b72"),
     ("mimo", "gather"): ("391eeec10946fb37", "9db93c3d19a79156"),
-    ("mimo", "pallas"): ("15caa6926c7e58cb", "12b564c9ab2ec9a9"),
+    ("mimo", "pallas"): ("80b8feaade6ced89", "811463c329833ffd"),
     ("sdar", "gather"): ("c5917877db65c058", "2a97e54a9534c8db"),
     ("sdar", "pallas"): ("c8e7a767c83aef5b", "51cd9b00ecef8e66"),
 }

@@ -77,6 +77,25 @@ def _ref_attention(q, k_pages, v_pages, page_tables, base, limit, sm,
     return jnp.einsum("bhqk,bkhd->bqhd", p, v_full)
 
 
+@pytest.fixture
+def a_head_a_step(monkeypatch):
+    """A scratch budget no KV head fits: every walking call then walks ONE
+    KV head (or pool row) a grid step, the least the rule gives. The call's
+    ``jit`` does not see the budget, so what it traced under the other
+    budget is dropped, before and after."""
+    monkeypatch.setattr(paged_ops, "_WALK_KV_BYTES", 1)
+    paged_ops._gqa_walk_call.clear_cache()
+    yield
+    paged_ops._gqa_walk_call.clear_cache()
+
+
+def _grid_steps(fn, *args) -> list:
+    """The grid of every kernel in ``fn``'s program."""
+    import re
+    return [int(n) for n in re.findall(r"grid=\((\d+),?\)",
+                                       str(jax.make_jaxpr(fn)(*args)))]
+
+
 def _assert_matches(got, want):
     """Same dtype, same values to the last ULPs. Contraction accumulation
     order is the only permitted difference (fused [R, L] dot vs batched
@@ -482,10 +501,11 @@ def test_walking_body_reads_no_dead_page_and_multiplies_no_dead_row(
     assert not np.asarray(got[~live]).any()
 
 
-def test_only_the_block_wrapper_walks_on_pools_of_k_and_v():
-    """The adoption is by the call's kind, from ONE table: the block
-    wrapper's program holds the walking body's copies, the decode, verify
-    and chunk wrappers' programs do not; and what an engine reports as
+def test_the_decode_verify_and_block_wrappers_walk_on_pools_of_k_and_v():
+    """The adoption is by the call's kind, from ONE table (ISSUE 61: the
+    decode and verify calls beside the block call): their wrappers'
+    programs hold the walking body's copies, the chunk wrapper's, the grid
+    body's ONE caller left, does not; and what an engine reports as
     ``attn_walks_live`` is that table read for its cache spec."""
     hkv, d, page, mp, b = 2, 16, 8, 4, 2
     k_pages, v_pages = _rand_pool(jax.random.PRNGKey(0), hkv, mp * b + 1,
@@ -500,16 +520,162 @@ def test_only_the_block_wrapper_walks_on_pools_of_k_and_v():
 
     assert walks(paged_ops.paged_block_attention, q, k_pages, v_pages,
                  tables, lens, block_len=4)
-    assert not walks(paged_ops.paged_verify_attention, q, k_pages, v_pages,
-                     tables, lens)
-    assert not walks(paged_ops.paged_decode_attention, q[:, 0], k_pages,
-                     v_pages, tables, lens)
+    assert walks(paged_ops.paged_verify_attention, q, k_pages, v_pages,
+                 tables, lens)
+    assert walks(paged_ops.paged_decode_attention, q[:, 0], k_pages,
+                 v_pages, tables, lens)
     assert not walks(paged_ops.paged_chunk_attention, q[:1], k_pages,
                      v_pages, tables[0], lens[0], lens[1], block_len=4)
+    assert paged_ops.WALKS_LIVE["heads"] == ("decode", "verify", "block")
     assert paged_ops.walking_calls(latent=False, block_len=4) == ["block"]
-    assert paged_ops.walking_calls(latent=False) == []
+    assert paged_ops.walking_calls(latent=False) == ["decode", "verify"]
     assert paged_ops.walking_calls(latent=True) == [
         "decode", "verify", "chunk"]
+
+
+# ---------------------------------------------------------------------------
+# the decode and verify calls of a block without window layers walk and
+# write (ISSUE 61): the dense block's, LFM2's heads of 64, Falcon-H1's five
+# query heads a KV head
+# ---------------------------------------------------------------------------
+
+
+def _serving_call(kind, n_rep, packed=False, dtype=jnp.float32):
+    """A decode call (one row a slot) or a verify call (three) as the
+    serving programs make it, on pools [2 layers] of 2 KV heads (``packed``:
+    4 heads of half the lanes, two to a pool row) with ``n_rep`` query
+    heads each, tables of 6 pages (two chunks of the walk): a slot in its
+    first page, one whose span ends a page (decode) or crosses into the
+    next (verify), an IDLE slot (a table of zeros at length 0: its rows go
+    to the trash page from their own place and it reads nothing else) and
+    one in the walk's second chunk. Every page of both layers that is not
+    live for its slot, nor the trash page, holds NaN.
+    Returns (call(layer, k_pool, v_pool, rides) -> (read, k_pool, v_pool),
+    the poisoned pools, the clean ones, what the reference needs)."""
+    page = 16 if dtype == jnp.bfloat16 else 8
+    t = 1 if kind == "decode" else 3
+    hkv, pack, d, mp = (4, 2, 8, 6) if packed else (2, 1, 16, 6)
+    rng = np.random.default_rng(61 + n_rep + t)
+    base = np.asarray([5, 2 * page - 1 if t == 1 else 2 * page - 2, 0,
+                       4 * page + 2], np.int32)
+    b = len(base)
+    tables = 1 + rng.permutation(b * mp).reshape(b, mp).astype(np.int32)
+    tables[2] = 0
+    pools = [jnp.asarray(rng.normal(size=(
+        2, hkv // pack, 1 + b * mp, page, d * pack)), dtype)
+        for _ in "kv"]
+    live = np.zeros(1 + b * mp, bool)
+    live[0] = True
+    for row, n in zip(tables, base + t):
+        live[row[:-(-int(n) // page)]] = True
+    poisoned = [jnp.where(live[None, None, :, None, None], pool, jnp.nan)
+                for pool in pools]
+    q = jnp.asarray(rng.normal(size=(b, t, hkv * n_rep, d)), dtype)
+    k_new, v_new = (jnp.asarray(rng.normal(size=(b, t, hkv, d)), dtype)
+                    for _ in "kv")
+    pos = base[:, None] + np.arange(t)[None]
+    page_idx = jnp.asarray(np.take_along_axis(tables, pos // page, axis=1))
+    offset = jnp.asarray(pos % page)
+    wrapper = paged_ops.paged_decode_attention if t == 1 \
+        else paged_ops.paged_verify_attention
+    drop = (lambda a: a[:, 0]) if t == 1 else (lambda a: a)
+
+    @functools.partial(jax.jit, static_argnames="rides")
+    def call(layer, k_pool, v_pool, rides=True):
+        """The rows riding in, or scattered first (the parent's order)."""
+        if rides:
+            return wrapper(drop(q), k_pool, v_pool, jnp.asarray(tables),
+                           jnp.asarray(base), layer, sm_scale=d ** -0.5,
+                           write=(drop(k_new), drop(v_new), drop(page_idx)))
+        k_pool, v_pool = kv_cache._write_token_kv(
+            k_pool, v_pool, layer, drop(k_new), drop(v_new), drop(page_idx),
+            drop(offset))
+        return k_pool, v_pool
+
+    def reference(k_set, v_set, layer=1):
+        """The gather path on the layer's pools, a head a row."""
+        heads = [jnp.moveaxis(pool[layer].reshape(
+            hkv // pack, 1 + b * mp, page, pack, d), 3, 1).reshape(
+                hkv, 1 + b * mp, page, d) for pool in (k_set, v_set)]
+        return _ref_attention(q, *heads, jnp.asarray(tables),
+                              jnp.asarray(base),
+                              jnp.full((b,), mp * page, jnp.int32),
+                              d ** -0.5)
+
+    return call, poisoned, pools, (reference, drop, base > 0)
+
+
+@pytest.mark.parametrize("grouped", [False, True],
+                         ids=["heads_at_once", "a_head_a_step"])
+@pytest.mark.parametrize("packed", [False, True], ids=["heads", "packed"])
+@pytest.mark.parametrize("n_rep", [4, 5, 8])
+@pytest.mark.parametrize("kind", ["decode", "verify"])
+def test_decode_and_verify_calls_walk_live_pages_and_write_their_rows(
+        kind, n_rep, packed, grouped, request):
+    """The wrappers the serving programs call, under jit with a TRACED
+    layer, at 4 (the dense block's, LFM2's), 5 (Falcon-H1's) and 8 query
+    heads a KV head, on heads a row and on heads of half a row packed two
+    to a row: the read is the gather path's on the scattered pools (which
+    is given the clean ones: it reads every page) and finite, so no dead
+    page was read and no dead row multiplied; the rows that rode in are the
+    scatter's bytes on every page of both layers but the trash page, which
+    stays finite. ``a_head_a_step``: a table too wide for every KV head's
+    pages to fit the scratch (here: a budget no head fits; on the chip a
+    dense block's 8 heads of 128 past 4,096 positions): a block WITHOUT
+    window layers then walks a group of KV heads a grid step too, by the
+    rule of one with them: two grid steps a slot, the same read and bytes."""
+    if grouped:
+        request.getfixturevalue("a_head_a_step")
+    call, poisoned, pools, (reference, drop, live) = _serving_call(
+        kind, n_rep, packed)
+    assert _grid_steps(lambda *pools: call(jnp.int32(1), *pools),
+                       *poisoned) == [(1 + grouped) * len(live)]
+    read, k_pool, v_pool = call(jnp.int32(1), *poisoned)
+    want = drop(reference(*call(jnp.int32(1), *pools, rides=False)))
+    assert np.isfinite(np.asarray(read)[live]).all()
+    np.testing.assert_allclose(np.asarray(read)[live],
+                               np.asarray(want)[live], atol=2e-5)
+    for got, scattered in zip((k_pool, v_pool), call(
+            jnp.int32(1), *poisoned, rides=False)):
+        got, scattered = np.asarray(got), np.asarray(scattered)
+        assert np.array_equal(got[:, :, 1:], scattered[:, :, 1:],
+                              equal_nan=True)
+        assert np.isfinite(got[:, :, 0]).all()
+
+
+def test_a_walk_one_kv_head_of_which_passes_the_scratch_is_refused():
+    """Not the interpreter's concern, which has no VMEM: the call as the
+    chip's compiler would get it (``interpret=False``, traced and never
+    lowered). A dense block's decode call at a table of 32,768 positions
+    walks one head a step; at 131,072 one head's pages alone pass the limit
+    and the call says so, where the compiler would have run out of VMEM."""
+    def steps(table):
+        q = jax.ShapeDtypeStruct((4, 32, 128), jnp.bfloat16)
+        pool = jax.ShapeDtypeStruct((2, 8, 9, 128, 128), jnp.bfloat16)
+        return _grid_steps(
+            lambda q, k, v: paged_ops.paged_decode_attention(
+                q, k, v, jnp.zeros((4, table // 128), jnp.int32),
+                jnp.zeros((4,), jnp.int32), 1, interpret=False),
+            q, pool, pool)
+
+    assert steps(2048) == [4] and steps(4096) == [4]
+    assert steps(16384) == [4 * 4] and steps(32768) == [4 * 8]
+    with pytest.raises(ValueError, match="VMEM.*max_seq_len"):
+        steps(131072)
+
+
+@pytest.mark.parametrize("kind", ["decode", "verify"])
+def test_decode_and_verify_calls_write_tiles_of_a_packed_type(kind):
+    """bf16 pools, whose native tile is 16 rows (pages of 16): the same
+    bytes as the scatter's, read and pools."""
+    call, _, pools, (reference, drop, live) = _serving_call(
+        kind, 4, dtype=jnp.bfloat16)
+    read, k_pool, v_pool = call(jnp.int32(1), *pools)
+    k_set, v_set = call(jnp.int32(1), *pools, rides=False)
+    _assert_matches(read[live], drop(reference(k_set, v_set))[live])
+    for got, scattered in ((k_pool, k_set), (v_pool, v_set)):
+        assert (np.asarray(got, np.float32)[:, :, 1:]
+                == np.asarray(scattered, np.float32)[:, :, 1:]).all()
 
 
 # ---------------------------------------------------------------------------
@@ -586,13 +752,13 @@ def _ring_case(base, t, limit, window, page, ring, seed=0, hkv=2, n_rep=2,
 @pytest.mark.parametrize("groups", [False, True], ids=["heads_at_once",
                                                        "a_head_a_step"])
 def test_decode_with_a_lower_edge_reads_the_band_off_the_ring(groups,
-                                                              monkeypatch):
+                                                              request):
     """Window 16, pages of 8, rings of 5: positions inside the first
     window (3), past it (37), past the ring's wrap (70, 200: entries
     written again and again) and on a page's first offset (40). With the
     scratch too small for both KV heads the same walk a head a grid step."""
     if groups:
-        monkeypatch.setattr(paged_ops, "_WALK_KV_BYTES", 1)
+        request.getfixturevalue("a_head_a_step")
     pos = [3, 37, 40, 70, 200]
     q, k_pool, v_pool, tables, want = _ring_case(
         pos, 1, [10 ** 6] * len(pos), 16, 8, 5)
@@ -723,11 +889,11 @@ def test_chunk_on_pools_of_two_widths(start, true_len, window, sink):
 
 @pytest.mark.parametrize("groups", [False, True], ids=["heads_at_once",
                                                        "a_head_a_step"])
-def test_a_sink_follows_its_kv_heads_group(groups, monkeypatch):
+def test_a_sink_follows_its_kv_heads_group(groups, request):
     """With the scratch too small for every KV head a step walks one, and
     reads ITS rows of the sinks."""
     if groups:
-        monkeypatch.setattr(paged_ops, "_WALK_KV_BYTES", 1)
+        request.getfixturevalue("a_head_a_step")
     pos = [5, 21, 90]
     q, k_pool, v_pool, tables, want, sinks = _ring_case(
         pos, 1, [10 ** 6] * 3, 8, 8, 3, seed=9, hkv=4, n_rep=4, sink=True,
@@ -914,10 +1080,9 @@ def test_rows_that_ride_in_are_the_scatters_bytes(name, dtype):
 
 
 @pytest.mark.parametrize("name", ["decode_groups", "chunk_ring"])
-def test_rows_ride_in_a_group_of_kv_heads_a_step(name, monkeypatch):
+def test_rows_ride_in_a_group_of_kv_heads_a_step(name, a_head_a_step):
     """A scratch too small for every KV head (``groups`` 2): each step
     lays and writes its own heads' rows."""
-    monkeypatch.setattr(paged_ops, "_WALK_KV_BYTES", 1)
     case = _write_case(name)
     _assert_same_bytes(case, *_scatter_then_walk(case))
 
@@ -1013,16 +1178,19 @@ def test_no_top_level_name_is_defined_twice(module):
 
 def test_the_calls_that_walk_on_pools_of_k_and_v_write_their_rows():
     """``writing_calls``: what ``_geometry`` asks for ``_write_read`` and
-    what an engine reports as ``attn_writes_in_kernel``. The grid body, a
-    latent pool and a tensor-parallel mesh keep the scatter; the grid body
-    refuses rows."""
+    what an engine reports as ``attn_writes_in_kernel``: since ISSUE 61
+    the decode and verify calls of a block without window layers too. The
+    grid body (the chunk call of such a block), a latent pool and a
+    tensor-parallel mesh keep the scatter; the grid body refuses rows."""
     assert paged_ops.writing_calls(False, block_len=4) == ["block"]
     assert paged_ops.writing_calls(False, windowed=True) == [
         "decode", "chunk"]
-    assert paged_ops.writing_calls(False) == []
+    assert paged_ops.writing_calls(False) == ["decode", "verify"]
     assert paged_ops.writing_calls(True) == []
     assert paged_ops.writing_calls(False, block_len=4, tp=2) == []
-    case = _write_case("decode")
+    assert paged_ops.writing_calls(False, tp=2) == []
+    # the chunk call of a block without window layers: the grid body's
+    case = _write_case("chunk_full")
     call, q, pools, operands, _, k_new, v_new, page_idx, _, _ = case
     with pytest.raises(ValueError, match="only the walking body"):
         call(q, *pools, *operands, 1, write=(k_new, v_new, page_idx))

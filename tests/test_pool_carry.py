@@ -134,7 +134,18 @@ def _assert_pool_is_carry_only(jaxpr, n_loops_expected):
         for out in eqn.outvars:
             assert shape(out) not in layer_shapes, \
                 f"{prim} makes one layer of the pool: {eqn}"
-            if shape(out) == POOL_SHAPE:
+            if shape(out) == POOL_SHAPE and prim == "pallas_call":
+                # the kernel that walks a slot's pages writes the call's
+                # rows where they lie (ISSUE 53; the decode and verify
+                # calls since ISSUE 61): a pool is its result only as
+                # the ALIAS of the pool it was handed
+                aliased = dict(eqn.params["input_output_aliases"])
+                at = eqn.outvars.index(out)
+                assert at in aliased.values(), \
+                    f"a kernel makes a pool it was not handed: {eqn}"
+                assert all(shape(eqn.invars[i]) == POOL_SHAPE
+                           for i, o in aliased.items() if o == at)
+            elif shape(out) == POOL_SHAPE:
                 assert prim in _MAY_RETURN_POOL, \
                     f"{prim} makes a pool-shaped value: {eqn}"
         if prim == "scan":

@@ -738,8 +738,12 @@ def test_decode_program_carries_scope_and_kernel_names():
         lambda *a: eng._decode_impl(*a, 2), eng.params, eng.kv, eng._pt_dev,
         eng._sl_dev, jnp.zeros((5,), jnp.int32), eng._rng, eng._temps_dev,
         idx)
-    assert LAYER_SCOPES | {"decode_block", "gather_state", "decode_step",
-                           "scatter_state", "sample"} <= scopes
+    # the decode call walks its slots' pages and writes their rows (ISSUE
+    # 61): the scope ``kv_write`` holds no operation of this program
+    assert LAYER_SCOPES - {"kv_write"} | {
+        "decode_block", "gather_state", "decode_step", "scatter_state",
+        "sample"} <= scopes
+    assert "kv_write" not in scopes
     assert kernels == {"paged_decode_attention"}
     # the jitted functions keep their Python names: the benchmark's
     # accepted readers find programs as jit__lambda / jit_impl / jit_step
@@ -770,7 +774,8 @@ def test_prefill_chunk_and_verify_programs_carry_scope_and_kernel_names():
         eng._verify_impl, eng.params, eng.kv, eng._pt_dev, eng._sl_dev,
         jnp.zeros((5,), jnp.int32), eng._rng, eng._temps_dev, idx,
         jnp.zeros((4, 2), jnp.int32))
-    assert LAYER_SCOPES | {"verify", "sample"} <= scopes
+    assert LAYER_SCOPES - {"kv_write"} | {"verify", "sample"} <= scopes
+    assert "kv_write" not in scopes         # the rows ride in the kernel
     assert kernels == {"paged_verify_attention"}
 
 

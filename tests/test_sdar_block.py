@@ -148,7 +148,8 @@ def test_the_walking_kernel_reads_no_page_past_a_slots_live_ones(
 
 @pytest.mark.parametrize("model,backend,want,writes", [
     (CFG, "pallas", ["block"], ["block"]), (CFG, "gather", [], []),
-    (llama.llama_tiny(vocab_size=512), "pallas", [], []),
+    (llama.llama_tiny(vocab_size=512), "pallas", ["decode", "verify"],
+     ["decode", "verify"]),
     (joyai.joyai_tiny(), "pallas", ["decode", "verify", "chunk"], [])],
     ids=["sdar-pallas", "sdar-gather", "dense-pallas", "latent-pallas"])
 def test_attn_walks_live_names_the_calls_that_walk(model, backend, want,
@@ -158,7 +159,8 @@ def test_attn_walks_live_names_the_calls_that_walk(model, backend, want,
     attn_table_pages_total`` is read only where it applies; and
     ``attn_writes_in_kernel``, those whose kernel also writes the call's
     own rows of K and V (ISSUE 53: the calls that walk on pools of K and V
-    per head; a dense and a latent engine scatter every call's)."""
+    per head, since ISSUE 61 the dense block's decode and verify calls
+    too; a latent engine scatters every call's)."""
     eng = LLMEngine(LLMConfig(model_config=model,
                               **{**ENGINE, "attention_kernel": backend}))
     assert eng.engine_stats()["attn_walks_live"] == want

@@ -28,6 +28,7 @@ from unittest import mock
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from ray_tpu.models import afmoe, falcon_h1, llama, mimo, sdar_moe
@@ -99,15 +100,15 @@ def one_chip():
     compilation_cache.reset_cache()
 
 
-def _stand_in(cell):
+def _stand_in(cell, backend="pallas", page=PAGE):
     """What the engine's program bodies read off the engine."""
     cfg = cell["model"]()
     spec = block_of(cfg).cache_spec(cfg)
     eng = types.SimpleNamespace(
         _jax=jax, _jnp=jnp, _kvc=kvc, model_cfg=cfg, _mesh=None,
-        _attn_backend="pallas", _cache_spec=spec,
+        _attn_backend=backend, _cache_spec=spec,
         _block_len=spec.block_length,
-        cfg=types.SimpleNamespace(page_size=PAGE, top_k=0, decode_block=8,
+        cfg=types.SimpleNamespace(page_size=page, top_k=0, decode_block=8,
                                   max_batch_size=cell["batch"]))
     for name in ("_experts_touched", "_blocks_of", "_run_steps",
                  "_decode_one", "_block_one"):
@@ -236,6 +237,113 @@ def test_a_decode_step_moves_the_recurrent_state_in_its_kernel_alone(
              if re.search(r"= \(?f32\[\d+,32,256,128\]\S* (copy|fusion)\(",
                           line)]
     assert state == []
+
+
+# ---- the scanned dense block's decode program walks and writes (ISSUE 61) ---
+
+# Mistral's pool at depth 2: [layers, KV heads, pages, page, head_dim]
+_POOL = f"bf16[2,8,{MISTRAL['pages']},{PAGE},128]"
+_POOL_WRITES = re.compile(
+    r"= \(?" + re.escape(_POOL)
+    + r"\S* (copy|fusion|scatter|dynamic-update-slice)\(")
+
+
+@pytest.mark.parametrize("width", [32, 4])
+def test_the_scanned_decode_program_writes_a_pool_in_its_kernel_alone(
+        width, one_chip):
+    """Mistral's layers are a ``lax.scan`` with the pools as the carry
+    inside the ``while`` over a dispatch's steps: the decode call walks and
+    WRITES there, the pools outputs aliased to inputs of the kernel inside
+    both loops. The program compiled for a described v5e holds ONE kernel,
+    whose results are the read and both pools, and no ``copy``, fusion,
+    scatter or update of a pool's shape (PR 26 was such a copy: seconds a
+    step)."""
+    text, _ = _compiled_text(MISTRAL, "decode", width, 0, one_chip)
+    kernel = [line for line in text.splitlines()
+              if "tpu_custom_call" in line]
+    assert len(kernel) == 1 and "paged_decode_attention" in kernel[0]
+    assert kernel[0].count(_POOL) >= 4                      # in and out
+    assert "output_to_operand_aliasing" in kernel[0]
+    assert [line.strip()[:160] for line in text.splitlines()
+            if _POOL_WRITES.search(line)] == []
+
+
+@pytest.mark.parametrize("seq", [16384, 32768])
+def test_the_dense_decode_program_compiles_at_a_wide_table(seq, one_chip):
+    """Mistral declares 32,768 positions. Every KV head's pages of such a
+    table (both halves, K and V) are 268 MB where the chip has 128 MiB of
+    VMEM, which the interpreter does not have and so no other test sees:
+    the walk then takes a group of KV heads a grid step (two of the eight
+    at 16,384, one at 32,768: tests/test_paged_kernels.py counts the grid
+    steps), and the program compiled for a described v5e still holds the
+    one kernel that reads and writes both pools."""
+    wide = dict(MISTRAL, name="wide", seq=seq, batch=4, pages=257,
+                model=lambda: llama.LlamaConfig(
+                    vocab_size=32768, dim=4096, n_layers=2, n_heads=32,
+                    n_kv_heads=8, ffn_dim=14336, max_seq_len=seq,
+                    rope_theta=1e6, dtype=jnp.bfloat16))
+    text, _ = _compiled_text(wide, "decode", 4, 0, one_chip)
+    kernel = [line for line in text.splitlines()
+              if "tpu_custom_call" in line]
+    assert len(kernel) == 1 and "paged_decode_attention" in kernel[0]
+    assert "output_to_operand_aliasing" in kernel[0]
+
+
+TINY_DENSE = dict(name="tiny", batch=4, pages=25, seq=48, chunk=16,
+                  model=lambda: llama.llama_tiny(vocab_size=512))
+
+
+@pytest.mark.parametrize("width", [1, 4])
+def test_the_scanned_decode_program_gives_the_gather_backends_tokens(width):
+    """The engine's decode program of the scanned dense block, a dispatch
+    of 8 steps at widths 1 and 4 (three live slots at ragged depths, one of
+    which crosses a page, and the trash row's idle lane), under the pallas
+    backend (the walking body, the rows riding in) and the gather backend
+    (the scatter, then the gathered view): the SAME greedy tokens, the
+    lengths, and pools that agree on every page but the trash page (to the
+    last ULPs: what a layer writes follows what the layer before read),
+    EXACTLY the bytes they had on every page no slot wrote."""
+    page, steps, b = 8, 8, TINY_DENSE["batch"]
+    cfg = TINY_DENSE["model"]()
+    params = block_of(cfg).init_params(jax.random.PRNGKey(0), cfg)
+    rs = np.random.RandomState(61)
+    kv0 = kvc.init_paged_cache(cfg, TINY_DENSE["pages"], page)
+    kv0 = {name: jnp.asarray(0.5 * rs.randn(*pool.shape), pool.dtype)
+           for name, pool in kv0.items()}
+    mp = TINY_DENSE["seq"] // page
+    tables = np.zeros((b + 1, mp), np.int32)
+    tables[:b] = 1 + rs.permutation(b * mp).reshape(b, mp)
+    lens = np.asarray([3, 13, 30, 5, 0], np.int32)     # the trash row's: 0
+    toks = jnp.asarray(rs.randint(0, 512, size=b + 1), jnp.int32)
+    idx = jnp.asarray([1] if width == 1 else [0, 1, 2, b], jnp.int32)
+
+    def run(backend):
+        eng = _stand_in(TINY_DENSE, backend, page)
+        fn = jax.jit(functools.partial(LLMEngine._decode_impl, eng))
+        all_toks, _, kv, sl, _ = fn(
+            params, dict(kv0), jnp.asarray(tables), jnp.asarray(lens), toks,
+            jax.random.PRNGKey(1), jnp.zeros((b + 1,), jnp.float32), idx,
+            jnp.int32(steps))
+        return np.asarray(all_toks[:steps]), np.asarray(sl), kv
+
+    got_toks, got_lens, got = run("pallas")
+    want_toks, want_lens, want = run("gather")
+    assert (got_toks == want_toks).all()
+    assert (got_lens == want_lens).all()
+    live = np.asarray(idx)[np.asarray(idx) < b]
+    assert (got_lens[live] == lens[live] + steps).all()
+    written = np.unique(np.concatenate([
+        tables[s, lens[s] // page:(lens[s] + steps - 1) // page + 1]
+        for s in live]))
+    untouched = np.setdiff1d(np.arange(1, TINY_DENSE["pages"]), written)
+    for name in "kv":
+        a, w = np.asarray(got[name]), np.asarray(want[name])
+        np.testing.assert_allclose(a[:, :, 1:], w[:, :, 1:], atol=1e-5)
+        assert (a[:, :, untouched] == np.asarray(kv0[name])[
+            :, :, untouched]).all()
+        assert (a[:, :, written] != np.asarray(kv0[name])[
+            :, :, written]).any()
+        assert np.isfinite(a[:, :, 0]).all()
 
 
 def test_the_dense_chunk_copies_no_more_than_it_did(one_chip):
